@@ -18,7 +18,4 @@ configuration, not code. Hence: ONE model library (`models/`), ONE trainer
 
 __version__ = "0.1.0"
 
-# compat first: aligns old-jax defaults (partitionable RNG) with the modern
-# API surface the package is written against, before any jax program runs
-from distributed_pytorch_tpu import compat  # noqa: F401
 from distributed_pytorch_tpu.config import LLMConfig, TrainConfig  # noqa: F401

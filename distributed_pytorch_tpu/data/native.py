@@ -6,10 +6,11 @@ pybind11 in this image; plain `extern "C"` + ctypes) and cached next to
 the source, keyed by a content hash of the source plus the compiler
 version — never by mtime, so a fresh clone always compiles from the
 committed source and an edited sampler.cpp always rebuilds. The build
-directory is untracked (.gitignore). Environments without a toolchain fall
-back to `philox_offsets` / pure-numpy gathers transparently — the
-DataLoader behaves identically either way because both implementations
-compute the same Philox4x32-10 stream (asserted by tests/test_native.py).
+directory is untracked (.gitignore). Environments without a toolchain use
+`philox_offsets` / pure-numpy gathers — the DataLoader behaves identically
+either way because both implementations compute the same Philox4x32-10
+stream (asserted by tests/test_native.py) — and which of the two a process
+got is said once on stderr (`_say_sampler`).
 """
 
 from __future__ import annotations
@@ -19,6 +20,7 @@ import functools
 import hashlib
 import os
 import subprocess
+import sys
 import threading
 from typing import Optional
 
@@ -102,6 +104,11 @@ def _build_lib() -> Optional[str]:
         return None
 
 
+def _say_sampler(which: str) -> None:
+    """Once per process (callers hold the load-once lock's first pass)."""
+    print(f"[data] sampler: {which}", file=sys.stderr)
+
+
 def _load_lib() -> Optional[ctypes.CDLL]:
     global _lib, _lib_failed
     with _lib_lock:
@@ -110,12 +117,17 @@ def _load_lib() -> Optional[ctypes.CDLL]:
         path = _build_lib()
         if path is None:
             _lib_failed = True
+            _say_sampler("numpy (csrc/sampler.cpp did not build: no g++ "
+                         "or the compile failed) — bit-identical, slower")
             return None
         try:
             lib = ctypes.CDLL(path)
-        except OSError:
+        except OSError as e:
             _lib_failed = True
+            _say_sampler(f"numpy ({os.path.basename(path)} did not load: "
+                         f"{e}) — bit-identical, slower")
             return None
+        _say_sampler(f"native ({os.path.basename(path)})")
         lib.dl_open.restype = ctypes.c_void_p
         lib.dl_open.argtypes = [ctypes.c_char_p]
         lib.dl_close.argtypes = [ctypes.c_void_p]

@@ -843,6 +843,33 @@ class DecodeEngine:
             self._aot_origin = prev
         return self.aot_store.stats()
 
+    def describe_programs(self) -> dict:
+        """Compile — ahead of the first request — the step programs this
+        configuration serves with (the plain decode step; the fused
+        chunk+decode step when `prefill_chunk` is set) and describe each
+        (obs/paths.compile_and_describe: compile seconds, Pallas kernels
+        BY NAME, memory accounting, dispatcher choices). The trace is
+        shared with the later calls (the guards still count one); the
+        executable is shared when the call's argument placement matches
+        these avals and otherwise comes from the persistent compile cache
+        — today the engine's first calls re-lower once or twice as its
+        state arrays go from uncommitted to committed (seen with
+        jax_log_compiles; PERF.md open questions). Store-built programs
+        (an AOT hit has no lowering to read) are skipped."""
+        from distributed_pytorch_tpu.obs import paths
+        out = {}
+        getters = {"step": self._get_step_fn}
+        if self.prefill_chunk:
+            getters["fused_step"] = self._get_fused_step_fn
+        for family, get in getters.items():
+            fn = get()
+            if not hasattr(fn, "lower"):
+                continue
+            with self._ctx():
+                out[f"engine.{family}"] = paths.compile_and_describe(
+                    fn, *self._aot_avals(family))
+        return out
+
     @property
     def aot_stats(self) -> dict:
         return self.aot_store.stats() if self.aot_store is not None \

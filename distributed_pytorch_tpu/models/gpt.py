@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from distributed_pytorch_tpu.config import LLMConfig
 from distributed_pytorch_tpu.models.attention import Attention, init_attn_cache
 from distributed_pytorch_tpu.models.mlp import MLP, MoE
+from distributed_pytorch_tpu.obs import paths
 from distributed_pytorch_tpu.ops.losses import (fused_cross_entropy,
                                                 sp_fused_cross_entropy,
                                                 unchunked_cross_entropy)
@@ -220,20 +221,33 @@ class LLM(nn.Module):
                 # shards V and the kernel's logsumexp is per-shard-local),
                 # no live 'seq' axis (T is sequence-sharded), shapes the
                 # kernel tiles, and a TPU backend (interpret on CPU is
-                # test-only slow). Otherwise degrade to the chunked path.
+                # test-only slow). The kernel was asked for BY NAME, so a
+                # gate that declines is an error naming it, never a quiet
+                # 'fused' run under the kernel's name.
                 from distributed_pytorch_tpu.ops.fused_ce import (
-                    pallas_ce_usable, pallas_cross_entropy)
+                    pallas_ce_decline, pallas_cross_entropy)
                 mesh = context.get_mesh()
                 tp = mesh.shape.get("model", 1) if mesh is not None else 1
                 dp = mesh.shape.get("data", 1) if mesh is not None else 1
-                n_local = (x.shape[0] // dp) * x.shape[1]
-                if (context.seq_axis_size() <= 1 and tp == 1
-                        and x.shape[0] % dp == 0
-                        and jax.default_backend() == "tpu"
-                        and pallas_ce_usable(n_local, x.shape[-1], x.dtype)):
-                    main_loss = pallas_cross_entropy(x, emb_mat, targets)
+                if jax.default_backend() != "tpu":
+                    why = f"backend is {jax.default_backend()}, not tpu"
+                elif context.seq_axis_size() > 1 or tp > 1:
+                    why = (f"live 'seq' ({context.seq_axis_size()}) or "
+                           f"'model' ({tp}) mesh axis")
+                elif x.shape[0] % dp != 0:
+                    why = f"batch {x.shape[0]} not divisible by dp={dp}"
                 else:
-                    loss_impl = "fused"
+                    why = pallas_ce_decline(
+                        (x.shape[0] // dp) * x.shape[1], x.shape[-1],
+                        x.dtype)
+                if why is not None:
+                    raise paths.declined("loss_impl='pallas'",
+                                         "pallas_ce_usable", why)
+                paths.note("loss", "pallas streaming CE",
+                           "loss_impl=pallas")
+                main_loss = pallas_cross_entropy(x, emb_mat, targets)
+            else:
+                paths.note("loss", loss_impl, f"loss_impl={loss_impl}")
             if loss_impl == "fused" and context.seq_axis_size() > 1:
                 # live 'seq' axis: chunk over the LOCAL T shard inside
                 # shard_map (ops/losses.py sp_fused_cross_entropy) instead

@@ -22,6 +22,12 @@ single-gpu/model.py:149). Implementations:
              parity with CUDA SDPA dropout, reference model.py:149-151);
              non-flash shapes / non-TPU fall back to naive.
 
+No path hides the device: every choice is recorded (obs/paths.py `note`,
+printed beside each compiled program by the trainer and the serve CLI),
+a kernel asked for BY NAME that its gate declines is an error naming the
+gate (`attn_impl='pallas'`; `FLASH_DECODE=on` on a TPU backend), and on a
+TPU backend no kernel call carries `interpret=True`.
+
 Layout convention: q (B, T, nh, hs); k, v (B, S, n_kv, hs) — "BTNH", the
 layout jax.nn.dot_product_attention and the Pallas kernel both want, avoiding
 the reference's transpose dance to (B, nh, T, hs).
@@ -35,12 +41,37 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from distributed_pytorch_tpu.obs import paths
+
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
+    # no except: a backend that fails to start must fail the program, not
+    # answer "not a TPU" and turn the run into an interpreted or XLA one
+    return jax.default_backend() == "tpu"
+
+
+def _decode_kernel_wanted(kernel: str, why_not) -> bool:
+    """FLASH_DECODE routing for one decode-shaped call: True = run
+    `kernel`. `why_not` is the usable gate's reason (None = usable).
+    'auto' takes the kernel on a TPU when the gate allows and says which
+    way it went; 'on' is a request by name — declined on a TPU backend it
+    is an error naming the gate. Off-TPU 'on' means "interpret mode for
+    the parity tests": a decline there hides no device, so the reference
+    path carries the call and the choice is noted."""
+    from distributed_pytorch_tpu.ops.flash_decode import decode_mode
+    mode = decode_mode()
+    if mode == "off" or (mode == "auto" and not _on_tpu()):
+        paths.note("decode_attention", "gather+naive",
+                   f"FLASH_DECODE={mode}")
         return False
+    if why_not is None:
+        paths.note("decode_attention", kernel, f"FLASH_DECODE={mode}")
+        return True
+    if mode == "on" and _on_tpu():
+        raise paths.declined("FLASH_DECODE=on", f"{kernel}_usable", why_not)
+    paths.note("decode_attention", "gather+naive",
+               f"{kernel}_usable declined: {why_not}")
+    return False
 
 
 def _shard_map_over_data(fn, q, has_rng: bool = False):
@@ -167,10 +198,10 @@ def sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         if (decode and causal and q.shape[1] == 1 and not use_dropout
                 and impl in ("auto", "pallas", "xla")):
             from distributed_pytorch_tpu.ops.flash_decode import (
-                decode_mode, paged_flash_decode, paged_flash_decode_usable)
-            mode = decode_mode()
-            if (mode == "on" or (mode == "auto" and _on_tpu())) \
-                    and paged_flash_decode_usable(q, k, v, block_tables):
+                paged_flash_decode, paged_flash_decode_decline)
+            if _decode_kernel_wanted(
+                    "paged_flash_decode",
+                    paged_flash_decode_decline(q, k, v, block_tables)):
                 cl = jnp.broadcast_to(jnp.reshape(
                     jnp.asarray(q_offset, jnp.int32), (-1,)) + 1,
                     (q.shape[0],))
@@ -187,10 +218,10 @@ def sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         if (decode and causal and q.shape[1] > 1 and q.shape[0] == 1
                 and not use_dropout and impl in ("auto", "pallas", "xla")):
             from distributed_pytorch_tpu.ops.flash_decode import (
-                decode_mode, paged_flash_prefill, paged_flash_prefill_usable)
-            mode = decode_mode()
-            if (mode == "on" or (mode == "auto" and _on_tpu())) \
-                    and paged_flash_prefill_usable(q, k, v, block_tables):
+                paged_flash_prefill, paged_flash_prefill_decline)
+            if _decode_kernel_wanted(
+                    "paged_flash_prefill",
+                    paged_flash_prefill_decline(q, k, v, block_tables)):
                 off = jnp.reshape(jnp.asarray(q_offset, jnp.int32), (-1,))[0]
                 return paged_flash_prefill(q, k, v, block_tables, off,
                                            scale=scale, k_scale=k_scale,
@@ -210,15 +241,14 @@ def sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     # split-KV Pallas kernel (ops/flash_decode.py) streams each sequence's
     # VALID cache rows exactly once (per-sequence cache_len scalar-prefetch
     # skips dead slots entirely) instead of the naive einsum's full-buffer
-    # read + per-query-head K/V repeat. Same degrade-don't-crash contract
-    # as loss_impl='pallas': the usable gate falls back to the naive path.
+    # read + per-query-head K/V repeat. _decode_kernel_wanted holds the
+    # FLASH_DECODE contract (auto chooses and says so; on is by name).
     if (decode and causal and q.shape[1] == 1 and not use_dropout
             and impl in ("auto", "pallas", "xla")):
         from distributed_pytorch_tpu.ops.flash_decode import (
-            decode_mode, flash_decode, flash_decode_usable)
-        mode = decode_mode()
-        if (mode == "on" or (mode == "auto" and _on_tpu())) \
-                and flash_decode_usable(q, k, v):
+            flash_decode, flash_decode_decline)
+        if _decode_kernel_wanted("flash_decode",
+                                 flash_decode_decline(q, k, v)):
             # valid rows per sequence: the query's global position + 1,
             # capped at the buffer length (ring cache wrapped)
             cl = jnp.minimum(
@@ -299,6 +329,46 @@ def sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                 "parallelism without any signal.")
         impl = "auto"  # shapes don't allow sp (e.g. decode steps)
 
+    static_zero = isinstance(q_offset, int) and q_offset == 0
+
+    def flash_why_not():
+        """Why the flash kernel cannot take this call (None = it can)."""
+        from distributed_pytorch_tpu.ops.flash_attention import \
+            flash_attention_decline
+        if not static_zero:
+            return "q_offset is traced or nonzero (a KV-cached call)"
+        return flash_attention_decline(q, k, v, causal=causal)
+
+    def run_flash():
+        from distributed_pytorch_tpu.ops.flash_attention import \
+            flash_attention
+        paths.note("attention", "pallas flash", f"attn_impl={impl}")
+        if use_dropout:
+            def fn(a, b, c, rng):
+                return flash_attention(a, b, c, scale=scale, causal=causal,
+                                       dropout_rate=dropout_rate,
+                                       dropout_rng=rng)
+            wrapped = _shard_map_over_data(fn, q, has_rng=True)
+            if wrapped is not None:
+                return wrapped(q, k, v, dropout_rng)
+            return fn(q, k, v, dropout_rng)
+        fn = functools.partial(flash_attention, scale=scale, causal=causal)
+        wrapped = _shard_map_over_data(fn, q)
+        if wrapped is not None:
+            return wrapped(q, k, v)
+        return fn(q, k, v)
+
+    if impl == "pallas" and not decode:
+        # asked for BY NAME on a training/prefill-shaped call: honoured
+        # or an error naming the gate — never a quiet XLA run under the
+        # kernel's name. (KV-cached decode calls are outside the flash
+        # kernel's contract; FLASH_DECODE governs their kernels above.)
+        why = flash_why_not()
+        if why is not None:
+            raise paths.declined("attn_impl='pallas'",
+                                 "flash_attention_usable", why)
+        return run_flash()
+
     if use_dropout:
         # the flash kernel applies attention-weight dropout IN-KERNEL
         # (round-5: mask bits regenerated per tile, never in HBM) — the
@@ -307,42 +377,41 @@ def sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         # the naive einsum path; honoring the caller's dropout beats
         # honoring their impl choice.
         if impl in ("auto", "pallas") and _on_tpu():
-            from distributed_pytorch_tpu.ops.flash_attention import (
-                flash_attention, flash_attention_usable)
-            static_zero = isinstance(q_offset, int) and q_offset == 0
-            if static_zero and flash_attention_usable(q, k, v, causal=causal):
-                def fn(a, b, c, rng):
-                    return flash_attention(a, b, c, scale=scale,
-                                           causal=causal,
-                                           dropout_rate=dropout_rate,
-                                           dropout_rng=rng)
-                wrapped = _shard_map_over_data(fn, q, has_rng=True)
-                if wrapped is not None:
-                    return wrapped(q, k, v, dropout_rng)
-                return fn(q, k, v, dropout_rng)
+            why = flash_why_not()
+            if why is None:
+                return run_flash()
+            paths.note("attention", "naive einsum",
+                       f"dropout; flash_attention_usable declined: {why}")
+        else:
+            paths.note("attention", "naive einsum",
+                       f"dropout; attn_impl={impl}, backend "
+                       f"{jax.default_backend()}")
         impl = "naive"
     elif impl == "auto":
         # XLA's fused attention is at parity with the Pallas kernel for
         # short sequences; beyond ~4k keys XLA materializes the O(T*S)
         # score matrix (OOM by 32k) while the flash kernel stays O(T).
-        long_seq = k.shape[1] > 4096
-        impl = "pallas" if (_on_tpu() and long_seq) else "xla"
-
-    if impl == "pallas":
-        from distributed_pytorch_tpu.ops.flash_attention import flash_attention_usable, flash_attention
-        static_zero = isinstance(q_offset, int) and q_offset == 0
-        if static_zero and flash_attention_usable(q, k, v, causal=causal):
-            fn = functools.partial(flash_attention, scale=scale,
-                                   causal=causal)
-            wrapped = _shard_map_over_data(fn, q)
-            if wrapped is not None:
-                return wrapped(q, k, v)
-            return fn(q, k, v)
+        if _on_tpu() and k.shape[1] > 4096:
+            why = flash_why_not()
+            if why is None:
+                return run_flash()
+            paths.note("attention", "xla",
+                       f"auto: {k.shape[1]} keys > 4096 but "
+                       f"flash_attention_usable declined: {why}")
+        elif not decode:
+            paths.note("attention", "xla",
+                       f"auto: {k.shape[1]} keys <= 4096" if _on_tpu()
+                       else f"auto: backend {jax.default_backend()}")
+        impl = "xla"
+    elif impl == "pallas":
+        # decode=True: a KV-cached prefill may still use the flash kernel
+        # when its offset is a static 0 and the shapes tile
+        if flash_why_not() is None:
+            return run_flash()
         impl = "xla"
 
     if impl == "xla":
-        is_static_zero_offset = isinstance(q_offset, int) and q_offset == 0
-        if is_static_zero_offset:
+        if static_zero:
             return jax.nn.dot_product_attention(
                 q, k, v, scale=scale, is_causal=causal, implementation="xla")
         impl = "naive"  # offset masks -> explicit path

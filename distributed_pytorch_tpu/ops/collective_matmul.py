@@ -31,7 +31,7 @@ Dispatch: `maybe_overlap_matmul` returns None (caller keeps its plain
 GSPMD matmul, bit-identical to before this module existed) unless ALL of:
 `OVERLAP` resolves to 'on' (env var wins over TrainConfig.overlap; 'auto'
 currently falls back to the known-good GSPMD path until a hardware number
-exists — flip `_AUTO_RESOLVES_TO` after the first TPU window), the ambient
+exists — flip `_AUTO_RESOLVES_TO` after the first chip measurement), the ambient
 recipe is ZeRO-3-family, the mesh has a live 'data' axis, the param's
 recipe spec actually shards it over 'data', shapes divide, and we are not
 inside an sp shard_map region or a hoisted-gather scan (train/step.py).
@@ -59,7 +59,7 @@ from distributed_pytorch_tpu.parallel.sharding import spec_for_param
 # re-declared here so an import cycle can't form through parallel.sharding).
 _ZERO3_RECIPES = ("fsdp", "fsdp_tp", "sp")
 
-# What 'auto' means today: GSPMD. The first TPU window that measures
+# What 'auto' means today: GSPMD. The first chip run that measures
 # OVERLAP=on faster flips this to "on" (bench.py / mfu_sweep.py carry the
 # A/B legs so no code change is needed to take the measurement).
 _AUTO_RESOLVES_TO = "off"

@@ -180,10 +180,7 @@ def _sds(shape, dtype, like):
     """ShapeDtypeStruct carrying `like`'s varying-manual-axes set: pallas
     calls inside shard_map (the ring-attention hop path) must declare how
     their outputs vary across mesh axes."""
-    vma = vma_of(like)
-    if vma is None:  # jax without vma tracking
-        return jax.ShapeDtypeStruct(shape, dtype)
-    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma_of(like))
 
 
 def _kv_spec(rep: int, g: int, block_q: int, block_k: int, D: int,
@@ -203,23 +200,37 @@ def _kv_spec(rep: int, g: int, block_q: int, block_k: int, D: int,
 
 
 # VMEM budget for one grid step's tiles + scratch + f32 score intermediates.
-# v5e has ~128 MiB VMEM/core; leave half for Mosaic's own buffers and
-# double-buffering slack so an oversized block/group config degrades (smaller
-# row group, or XLA fallback via the usable gate) instead of hard-failing
-# compilation with a Mosaic VMEM-exceeded error (round-4 ADVICE).
+# It is ALSO the scoped-VMEM limit the kernels hand Mosaic
+# (compat.tpu_compiler_params), so a config this gate passes is one the
+# compiler was given room for: an oversized block/group config degrades
+# (smaller row group, or the gate declines) instead of failing compilation
+# with "exceeded scoped vmem limit".
 _VMEM_BUDGET = config.knob("FLASH_VMEM_BUDGET_MB") * 2 ** 20
+
+_LANE = 128
 
 
 def _vmem_bytes(g: int, gk: int, bq: int, bk: int, D: int,
                 dsize: int) -> int:
-    """Worst-case-kernel (dkv backward) VMEM estimate for one grid step:
-    double-buffered I/O tiles + f32 accumulator scratch + the f32 score/
-    prob/dscore intermediates the kernel body materializes."""
+    """Worst-case-kernel (dkv backward) VMEM estimate for one grid step,
+    counting what Mosaic really allocates: the minor dim of every tile is
+    padded to the 128-lane register tile, so a D=64 head tile occupies a
+    D=128 one and each (bq, 1) lse/delta/m/l column occupies (bq, 128);
+    I/O tiles are double-buffered; plus the f32 accumulator scratch and
+    the f32 score/prob/dscore intermediates the body materializes.
+    Checked against the v5e compiler's own minimum (device-free compile,
+    binary search on the limit): flagship g=8, 256x512, D=64 needs 24 MiB
+    — the pre-padding estimate said 19 and the gate passed configs the
+    compiler refused — this says 30; it stays at or above the compiler's
+    need from 128x128 to 512x1024 tiles and D in {64, 128, 256}."""
+    Dp = -(-D // _LANE) * _LANE
+    col = g * bq * _LANE * 4                    # one (g, bq, 1) f32 column
     score = 3 * g * bq * bk * 4
-    fwd = (2 * (2 * g * bq * D + 2 * gk * bk * D) * dsize
-           + (g * bq * D + 2 * g * bq) * 4 + score)
-    bwd = (2 * (2 * g * bq * D + 2 * gk * bk * D + 2 * g * bk * D) * dsize
-           + 2 * g * bk * D * 4 + 4 * g * bq * 4 + score)
+    fwd = (2 * ((2 * g * bq * Dp + 2 * gk * bk * Dp) * dsize + col)
+           + g * bq * Dp * 4 + 2 * col + score)
+    bwd = (2 * ((2 * g * bq * Dp + 2 * gk * bk * Dp + 2 * g * bk * Dp)
+                * dsize + 2 * col)
+           + 2 * g * bk * Dp * 4 + score)
     return max(fwd, bwd)
 
 
@@ -402,6 +413,7 @@ def _fwd(q, k, v, seed, scale, block_q, block_k, g, interpret, causal=True,
             pltpu.VMEM((g, block_q, 1), jnp.float32),
         ],
         compiler_params=_SEMANTICS,
+        name="flash_fwd",
         interpret=interpret,
     )(seed, q, k, v)
     return out, lse
@@ -496,6 +508,7 @@ def _bwd_impl(scale, block_q, block_k, g, interpret, causal, rate, res, do,
         out_shape=_sds((N, T, D), q.dtype, q),
         scratch_shapes=[pltpu.VMEM((g, block_q, D), jnp.float32)],
         compiler_params=_SEMANTICS,
+        name="flash_bwd_dq",
         interpret=interpret,
     )(seed, q, k, v, do, lse, delta)
 
@@ -542,6 +555,7 @@ def _bwd_impl(scale, block_q, block_k, g, interpret, causal, rate, res, do,
             pltpu.VMEM((g, block_k, D), jnp.float32),
         ],
         compiler_params=_SEMANTICS,
+        name="flash_bwd_dkv",
         interpret=interpret,
     )(seed, q, k, v, do, lse, delta)
     if rep > 1:
@@ -700,6 +714,7 @@ def _slab_fwd(q, k, v, seed, scale, block_q, block_k, interpret,
             pltpu.VMEM((nh, block_q, 1), jnp.float32),
         ],
         compiler_params=_SEMANTICS,
+        name="flash_slab_fwd",
         interpret=interpret,
     )(seed, q, k, v)
 
@@ -742,6 +757,7 @@ def _slab_bwd(scale, block_q, block_k, interpret, causal, rate, nh, nkv, D,
         out_shape=_sds((B, T, nh * D), q.dtype, q),
         scratch_shapes=[pltpu.VMEM((nh, block_q, D), jnp.float32)],
         compiler_params=_SEMANTICS,
+        name="flash_slab_bwd_dq",
         interpret=interpret,
     )(seed, q, k, v, do, lse, delta)
 
@@ -780,6 +796,7 @@ def _slab_bwd(scale, block_q, block_k, interpret, causal, rate, nh, nkv, D,
             pltpu.VMEM((nh, block_k, D), jnp.float32),
         ],
         compiler_params=_SEMANTICS,
+        name="flash_slab_bwd_dkv",
         interpret=interpret,
     )(seed, q, k, v, do, lse, delta)
     return dq, dk, dv, None
@@ -816,11 +833,18 @@ def _slab_lse_for(nh: int, nkv: int, D: int):
 
 
 def slab_attention_usable(B, T, S, nh, nkv, hs, dtype,
-                          block_q: int = 0, block_k: int = 0) -> bool:
+                          block_q: int = 0, block_k: int = 0,
+                          interpret: bool = False) -> bool:
     """Gate for the slab layout: lane-aligned head slabs ((n*hs) % 128),
     sublane-aligned blocks, and the (nh, bq, bk) f32 score tile + scratch
-    within the VMEM budget."""
+    within the VMEM budget. COMPILED, the head dim itself must be a lane
+    multiple: Mosaic refuses `_load_hbd`'s in-VMEM (t, n*hs) -> (n, t, hs)
+    split for hs < 128 ("infer-vector-layout: unsupported shape cast",
+    device-free v5e compile at hs=64), so every 64-wide-head preset leaves
+    this layout; interpret mode (the CPU parity tests) has no such limit."""
     if (nh * hs) % 128 != 0 or (nkv * hs) % 128 != 0 or hs % 8 != 0:
+        return False
+    if not interpret and hs % _LANE != 0:
         return False
     bq = block_q or _pick_block(T, DEFAULT_BLOCK_Q)
     bk = block_k or _pick_block(S, DEFAULT_BLOCK_K)
@@ -828,9 +852,8 @@ def slab_attention_usable(B, T, S, nh, nkv, hs, dtype,
         return False
     dsize = jnp.dtype(dtype).itemsize
     # GQA: _load_hbd jnp.repeat-expands K/V to nh heads IN VMEM (only the
-    # HBM tiles stay at nkv), so the budget must count the post-repeat
-    # intermediates at nh — gk=nkv here under-estimated exactly the
-    # overflow this gate exists to prevent (round-5 ADVICE)
+    # HBM tiles stay at nkv), so the budget counts the post-repeat
+    # intermediates at nh (round-5 ADVICE)
     return _vmem_bytes(nh, nh, bq, bk, hs, dsize) <= _VMEM_BUDGET
 
 
@@ -876,29 +899,38 @@ def _pick_block(n: int, preferred: int) -> int:
     return b if n % b == 0 else 0
 
 
-def flash_attention_usable(q, k, v, *, causal: bool = True) -> bool:
-    """Static gate for the dispatcher: shapes/dtypes this kernel handles
-    (causal and full attention both supported since round 4)."""
+def flash_attention_decline(q, k, v, *, causal: bool = True):
+    """Why the dispatcher may NOT send this call to the kernel — None
+    when it may. Static (shapes/dtypes only)."""
     B, T, nh, hs = q.shape
     S = k.shape[1]
     if q.dtype not in (jnp.float32, jnp.bfloat16):
-        return False
+        return f"dtype {q.dtype} (kernel handles float32 / bfloat16)"
     if T < 8 or S < 8:
-        return False  # decode-step shapes: the naive path is fine
+        return f"T={T}, S={S}: decode-step shapes take the naive path"
     if hs % 8 != 0:
-        return False
+        return f"head dim {hs} is not a sublane (8) multiple"
     bq = _pick_block(T, DEFAULT_BLOCK_Q)
     bk = _pick_block(S, DEFAULT_BLOCK_K)
     if not (bq and bk):
-        return False
+        return f"no block split (multiple of 8) divides T={T}, S={S}"
     # even a group of 1 must fit the per-step VMEM budget
     dsize = jnp.dtype(q.dtype).itemsize
-    rows_ok = _vmem_bytes(1, 1, bq, bk, hs, dsize) <= _VMEM_BUDGET
-    if DEFAULT_LAYOUT == "slab":
-        nkv = k.shape[2]
-        return rows_ok or slab_attention_usable(B, T, S, nh, nkv, hs,
-                                                q.dtype)
-    return rows_ok
+    need = _vmem_bytes(1, 1, bq, bk, hs, dsize)
+    if need <= _VMEM_BUDGET:
+        return None
+    if DEFAULT_LAYOUT == "slab" and slab_attention_usable(
+            B, T, S, nh, k.shape[2], hs, q.dtype):
+        return None
+    return (f"one ({bq}, {bk}) tile step at head dim {hs} needs "
+            f"{need >> 20} MiB of VMEM, over the {_VMEM_BUDGET >> 20} MiB "
+            "scoped limit (FLASH_VMEM_BUDGET_MB)")
+
+
+def flash_attention_usable(q, k, v, *, causal: bool = True) -> bool:
+    """Static gate for the dispatcher: shapes/dtypes this kernel handles
+    (causal and full attention both supported since round 4)."""
+    return flash_attention_decline(q, k, v, causal=causal) is None
 
 
 def flash_attention_lse(q, k, v, *, scale: float, causal: bool = True,
@@ -950,7 +982,7 @@ def flash_attention_lse(q, k, v, *, scale: float, causal: bool = True,
     if layout is None:
         layout = DEFAULT_LAYOUT
     if layout == "slab" and slab_attention_usable(
-            B, T, S, nh, nkv, hs, q.dtype, block_q, block_k):
+            B, T, S, nh, nkv, hs, q.dtype, block_q, block_k, interpret):
         # (B, T, N, H) -> (B, T, N*H) is a FREE reshape of the model's
         # natural layout: zero HBM transposes in or out
         fn = _slab_lse_for(nh, nkv, hs)

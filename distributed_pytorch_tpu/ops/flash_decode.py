@@ -41,12 +41,14 @@ single-token attention):
   chunk+decode step (engine/decode.py `prefill_chunk`); bf16 and int8
   pools ride the same block-table index map.
 
-Contract (mirrors `loss_impl='pallas'` / `grouped_usable` /
-`flash_attention_usable`): gate with `flash_decode_usable` first; callers
-fall back to the naive path — identical semantics, more HBM traffic —
-never to a crash. `FLASH_DECODE=auto|on|off` (read per call, so tests can
-flip it): 'auto' uses the kernel on TPU only, 'on' forces it (interpret
-mode off-TPU — the CPU parity tests), 'off' pins the naive path.
+Contract: gate with `flash_decode_usable` (or its `*_decline` twin, which
+says WHY) first. `FLASH_DECODE=auto|on|off` (read per call, so tests can
+flip it): 'auto' uses the kernel on TPU only — where the gate declines
+the naive path carries the call (identical semantics, more HBM traffic)
+and the choice is recorded (obs/paths.py); 'on' asks for the kernel by
+name — on a TPU backend a decline is then an error naming the gate
+(ops/attention_core.py `_decode_kernel_wanted`), off-TPU it means
+interpret mode for the CPU parity tests; 'off' pins the naive path.
 """
 
 from __future__ import annotations
@@ -256,6 +258,7 @@ def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((B, nkv, rep, hs), q.dtype),
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
+        name="flash_decode" + ("_q8" if quantized else ""),
         interpret=interpret,
     )(cl, *operands)
     return out.reshape(B, nh, hs)
@@ -353,6 +356,7 @@ def paged_flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((B, nkv, rep, hs), q.dtype),
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
+        name="paged_flash_decode" + ("_q8" if quantized else ""),
         interpret=interpret,
     )(cl, bt, *operands)
     return out.reshape(B, nh, hs)
@@ -537,110 +541,126 @@ def paged_flash_prefill(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((nkv, T * rep, hs), q.dtype),
         compiler_params=tpu_compiler_params(
             dimension_semantics=("arbitrary",)),
+        name="paged_flash_prefill" + ("_q8" if quantized else ""),
         interpret=interpret,
     )(meta, bt, *operands)
     return out.reshape(nkv, T, rep, hs).transpose(1, 0, 2, 3) \
         .reshape(1, T, nh, hs)
 
 
-def paged_flash_prefill_usable(q, k, v, block_tables) -> bool:
-    """Static gate for the chunk-prefill kernel, mirroring
-    `paged_flash_decode_usable`: one sequence's (1, T>1, nh, hs) chunk,
-    whole-block pool pages the hardware tiles, T a multiple of the
-    sublane step, and the packed query tile + f32 accumulator within the
-    VMEM budget. Callers fall back to paged_gather + the naive masked
-    path — identical semantics."""
-    if q.ndim != 4 or q.shape[0] != 1 or q.shape[1] <= 1:
-        return False
-    _, T, nh, hs = q.shape
-    bs, nkv = k.shape[1], k.shape[2]
+def _common_decline(q, k, nh, nkv, hs, bs, what: str):
+    """Checks shared by the three gates (dtypes, head geometry, the tile
+    the hardware splits the KV length by, no live multi-device mesh)."""
     if q.dtype not in (jnp.float32, jnp.bfloat16):
-        return False
+        return f"query dtype {q.dtype} (kernel handles float32 / bfloat16)"
     if k.dtype != q.dtype and k.dtype != jnp.int8:
-        return False
-    if hs % 8 != 0 or nh % nkv != 0 or T % 8 != 0:
-        return False
-    on_tpu = jax.default_backend() == "tpu"
-    if bs % (128 if on_tpu else 8) != 0:
-        return False
+        return f"cache dtype {k.dtype} is neither {q.dtype} nor int8"
+    if hs % 8 != 0 or nh % nkv != 0:
+        return f"head geometry nh={nh}, n_kv={nkv}, hs={hs}"
+    step = 128 if jax.default_backend() == "tpu" else 8
+    if bs % step != 0:
+        return (f"{what} is not a multiple of {step} (the KV tile the "
+                f"{jax.default_backend()} backend splits by)")
     from distributed_pytorch_tpu.parallel import context
     mesh = context.get_mesh()
     if mesh is not None and any(s > 1 for s in mesh.devices.shape):
-        return False
-    dsize = jnp.dtype(k.dtype).itemsize
-    rep = nh // nkv
-    rows = T * rep
-    tiles = 2 * 2 * bs * nkv * hs * dsize               # double-buffered k+v
+        return ("a live multi-device mesh (GSPMD cannot partition a "
+                "pallas_call)")
+    return None
+
+
+def _budget_decline(need: int):
+    if need <= _VMEM_BUDGET:
+        return None
+    return (f"one grid step needs {need >> 20} MiB of VMEM, over the "
+            f"{_VMEM_BUDGET >> 20} MiB scoped limit (FLASH_VMEM_BUDGET_MB)")
+
+
+def _kv_tile_bytes(k, bs: int, nkv: int, hs: int) -> int:
+    """Double-buffered k+v tiles (+ f32 scale rows for an int8 cache)."""
+    tiles = 2 * 2 * bs * nkv * hs * jnp.dtype(k.dtype).itemsize
     if k.dtype == jnp.int8:
-        tiles += 2 * 2 * bs * nkv * 4                   # f32 scale rows
+        tiles += 2 * 2 * bs * nkv * 4
+    return tiles
+
+
+def paged_flash_prefill_decline(q, k, v, block_tables):
+    """Why the chunk-prefill kernel cannot take this call (None = it
+    can), mirroring `paged_flash_decode_decline`: one sequence's
+    (1, T>1, nh, hs) chunk, whole-block pool pages the hardware tiles, T
+    a multiple of the sublane step, and the packed query tile + f32
+    accumulator within the VMEM budget. The fallback is paged_gather +
+    the naive masked path — identical semantics."""
+    if q.ndim != 4 or q.shape[0] != 1 or q.shape[1] <= 1:
+        return f"query shape {q.shape} is not one sequence's (1, T>1) chunk"
+    _, T, nh, hs = q.shape
+    bs, nkv = k.shape[1], k.shape[2]
+    if T % 8 != 0:
+        return f"chunk length {T} is not a sublane (8) multiple"
+    why = _common_decline(q, k, nh, nkv, hs, bs, f"pool block size {bs}")
+    if why is not None:
+        return why
+    rows = T * (nh // nkv)
+    dsize = jnp.dtype(k.dtype).itemsize
     qtile = nkv * rows * hs * dsize
     scratch = nkv * rows * (hs + 2) * 4
     scores = 3 * nkv * rows * bs * 4
-    return tiles + qtile + scratch + scores <= _VMEM_BUDGET
+    return _budget_decline(_kv_tile_bytes(k, bs, nkv, hs) + qtile + scratch
+                           + scores)
+
+
+def paged_flash_decode_decline(q, k, v, block_tables):
+    """Why the paged kernel cannot take this call (None = it can),
+    mirroring `flash_decode_decline`: decode-shaped (B, 1, nh, hs) query,
+    pool block size the hardware tiles (multiples of 128 rows on TPU —
+    small CPU-test pages run in interpret mode at multiples of 8), no live
+    multi-device mesh. The fallback is paged_gather + the naive path —
+    identical semantics."""
+    if q.ndim != 4 or q.shape[1] != 1:
+        return f"query shape {q.shape} is not decode-shaped (B, 1, nh, hs)"
+    _, _, nh, hs = q.shape
+    bs, nkv = k.shape[1], k.shape[2]
+    why = _common_decline(q, k, nh, nkv, hs, bs, f"pool block size {bs}")
+    if why is not None:
+        return why
+    rep = nh // nkv
+    scratch = nkv * rep * (hs + 2) * 4
+    scores = 3 * nkv * rep * bs * 4
+    return _budget_decline(_kv_tile_bytes(k, bs, nkv, hs) + scratch + scores)
+
+
+def flash_decode_decline(q, k, v):
+    """Why the contiguous kernel cannot take this call (None = it can):
+    (B, 1, nh, hs)-shaped decode query, dtypes/shapes the kernel tiles,
+    no live multi-device mesh (GSPMD cannot partition a pallas_call; a
+    shard_map wrap over 'data' is future work — the naive path handles
+    sharded decode meanwhile). An int8 k/v (the quantized cache's codes)
+    is accepted — `_kernel_q8` carries it."""
+    if q.ndim != 4 or q.shape[1] != 1:
+        return f"query shape {q.shape} is not decode-shaped (B, 1, nh, hs)"
+    _, _, nh, hs = q.shape
+    S, nkv = k.shape[1], k.shape[2]
+    step = 128 if jax.default_backend() == "tpu" else 8
+    block_s = _pick_block(S, DEFAULT_BLOCK_S, step)
+    if not block_s:
+        return f"cache length {S} has no tile split in multiples of {step}"
+    why = _common_decline(q, k, nh, nkv, hs, block_s, f"kv tile {block_s}")
+    if why is not None:
+        return why
+    rep = nh // nkv
+    scratch = nkv * rep * (hs + 2) * 4
+    scores = 3 * nkv * rep * block_s * 4                # s, p, mask temps
+    return _budget_decline(_kv_tile_bytes(k, block_s, nkv, hs) + scratch
+                           + scores)
+
+
+def paged_flash_prefill_usable(q, k, v, block_tables) -> bool:
+    return paged_flash_prefill_decline(q, k, v, block_tables) is None
 
 
 def paged_flash_decode_usable(q, k, v, block_tables) -> bool:
-    """Static gate for the paged kernel, mirroring `flash_decode_usable`:
-    decode-shaped (B, 1, nh, hs) query, pool block size the hardware
-    tiles (multiples of 128 rows on TPU — small CPU-test pages run in
-    interpret mode at multiples of 8), no live multi-device mesh. Callers
-    fall back to paged_gather + the naive path — identical semantics."""
-    if q.ndim != 4 or q.shape[1] != 1:
-        return False
-    B, _, nh, hs = q.shape
-    bs, nkv = k.shape[1], k.shape[2]
-    if q.dtype not in (jnp.float32, jnp.bfloat16):
-        return False
-    if k.dtype != q.dtype and k.dtype != jnp.int8:
-        return False
-    if hs % 8 != 0 or nh % nkv != 0:
-        return False
-    on_tpu = jax.default_backend() == "tpu"
-    if bs % (128 if on_tpu else 8) != 0:
-        return False
-    from distributed_pytorch_tpu.parallel import context
-    mesh = context.get_mesh()
-    if mesh is not None and any(s > 1 for s in mesh.devices.shape):
-        return False
-    dsize = jnp.dtype(k.dtype).itemsize
-    rep = nh // nkv
-    tiles = 2 * 2 * bs * nkv * hs * dsize               # double-buffered k+v
-    if k.dtype == jnp.int8:
-        tiles += 2 * 2 * bs * nkv * 4                   # f32 scale rows
-    scratch = nkv * rep * (hs + 2) * 4
-    scores = 3 * nkv * rep * bs * 4
-    return tiles + scratch + scores <= _VMEM_BUDGET
+    return paged_flash_decode_decline(q, k, v, block_tables) is None
 
 
 def flash_decode_usable(q, k, v) -> bool:
-    """Static gate for the dispatcher: (B, 1, nh, hs)-shaped decode query,
-    dtypes/shapes the kernel tiles, no live multi-device mesh (GSPMD
-    cannot partition a pallas_call; a shard_map wrap over 'data' is future
-    work — the naive path handles sharded decode meanwhile). An int8 k/v
-    (the quantized cache's codes) is accepted — `_kernel_q8` carries it."""
-    if q.ndim != 4 or q.shape[1] != 1:
-        return False
-    B, _, nh, hs = q.shape
-    S, nkv = k.shape[1], k.shape[2]
-    if q.dtype not in (jnp.float32, jnp.bfloat16):
-        return False
-    if k.dtype != q.dtype and k.dtype != jnp.int8:
-        return False
-    if hs % 8 != 0 or nh % nkv != 0:
-        return False
-    on_tpu = jax.default_backend() == "tpu"
-    block_s = _pick_block(S, DEFAULT_BLOCK_S, 128 if on_tpu else 8)
-    if not block_s:
-        return False
-    from distributed_pytorch_tpu.parallel import context
-    mesh = context.get_mesh()
-    if mesh is not None and any(s > 1 for s in mesh.devices.shape):
-        return False
-    dsize = jnp.dtype(k.dtype).itemsize
-    rep = nh // nkv
-    tiles = 2 * 2 * block_s * nkv * hs * dsize          # double-buffered k+v
-    if k.dtype == jnp.int8:
-        tiles += 2 * 2 * block_s * nkv * 4              # f32 scale rows
-    scratch = nkv * rep * (hs + 2) * 4
-    scores = 3 * nkv * rep * block_s * 4                # s, p, mask temps
-    return tiles + scratch + scores <= _VMEM_BUDGET
+    return flash_decode_decline(q, k, v) is None

@@ -31,7 +31,8 @@ Sharding: tokens are independent, so under a live mesh the wrapper runs
 the kernel inside shard_map over the 'data' axis (W replicated in-spec;
 shard_map's transpose psums the W cotangent across shards). Vocab-parallel
 lm_head (tp) and sequence-parallel T are NOT supported — callers gate on
-model==1 and seq==1 (gpt.py does) and fall back to the chunked path.
+model==1 and seq==1 (gpt.py does; `loss_impl='pallas'` that cannot be
+honoured is an error there, naming the gate — never a quiet 'fused' run).
 """
 
 from __future__ import annotations
@@ -134,6 +135,7 @@ def _fwd(x, w_pad, t, bn, bv, vocab_size, interpret):
             pltpu.VMEM((bn, 1), jnp.float32),
         ],
         compiler_params=_SEMANTICS,
+        name="ce_fwd",
         interpret=interpret,
     )(x, w_pad, t)
     return nll, lse
@@ -204,6 +206,7 @@ def _bwd(x, w_pad, t, lse, coef, bn, bv, vocab_size, interpret):
         out_shape=jax.ShapeDtypeStruct((n, c), x.dtype),
         scratch_shapes=[pltpu.VMEM((bn, c), jnp.float32)],
         compiler_params=_SEMANTICS,
+        name="ce_bwd_dx",
         interpret=interpret,
     )(x, w_pad, t, lse, coef)
 
@@ -221,6 +224,7 @@ def _bwd(x, w_pad, t, lse, coef, bn, bv, vocab_size, interpret):
         out_shape=jax.ShapeDtypeStruct((v_pad, c), w_pad.dtype),
         scratch_shapes=[pltpu.VMEM((bv, c), jnp.float32)],
         compiler_params=_SEMANTICS,
+        name="ce_bwd_dw",
         interpret=interpret,
     )(x, w_pad, t, lse, coef)
     return dx, dw
@@ -278,13 +282,21 @@ def _pick(n: int, preferred: int) -> int:
     return b if (n % b == 0 and b % 8 == 0) else 0
 
 
+def pallas_ce_decline(n_tokens: int, n_embd: int, dtype):
+    """Why the kernel cannot take these shapes (None = it can)."""
+    if dtype not in (jnp.float32, jnp.bfloat16):
+        return f"dtype {dtype} (kernel handles float32 / bfloat16)"
+    if n_embd % 128 != 0:          # lane-dim multiple (C is the minor dim)
+        return f"n_embd {n_embd} is not a lane (128) multiple"
+    if not _pick(n_tokens, DEFAULT_BLOCK_N):
+        return (f"{n_tokens} local tokens have no tile divisor (multiple "
+                f"of 8, <= {DEFAULT_BLOCK_N})")
+    return None
+
+
 def pallas_ce_usable(n_tokens: int, n_embd: int, dtype) -> bool:
     """Static gate: shapes/dtypes the kernel handles."""
-    if dtype not in (jnp.float32, jnp.bfloat16):
-        return False
-    if n_embd % 128 != 0:          # lane-dim multiple (C is the minor dim)
-        return False
-    return bool(_pick(n_tokens, DEFAULT_BLOCK_N))
+    return pallas_ce_decline(n_tokens, n_embd, dtype) is None
 
 
 def pallas_cross_entropy(x: jnp.ndarray, embedding: jnp.ndarray,

@@ -171,6 +171,7 @@ def _fwd_call(x_pad, w, scales, tile_group, bm, interpret):
         out_shape=jax.ShapeDtypeStruct((P, N), x_pad.dtype),
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=("parallel", "parallel")),
+        name="gmm_fwd",
         interpret=interpret,
     )(*args)
 
@@ -201,6 +202,7 @@ def _dx_call(dy, w, scales, tile_group, bm, interpret):
         out_shape=jax.ShapeDtypeStruct((P, K), dy.dtype),
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=("parallel", "parallel")),
+        name="gmm_dx",
         interpret=interpret,
     )(*args)
 
@@ -233,6 +235,7 @@ def _dw_call_impl(x_pad, dy, scales, tile_group, tile_first, n_experts,
         out_shape=jax.ShapeDtypeStruct((n_experts, K, N), jnp.float32),
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="gmm_dw",
         interpret=interpret,
     )(*args)
 

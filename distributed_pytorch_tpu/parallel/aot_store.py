@@ -46,6 +46,7 @@ CLI (also the supervisor's re-mesh pre-warm hook)::
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import hashlib
 import json
@@ -140,6 +141,48 @@ def aval_fingerprint(avals) -> list:
     return out
 
 
+@contextlib.contextmanager
+def _no_persistent_cache():
+    """Compile past XLA's persistent compilation cache. A store entry has
+    to be a SELF-CONTAINED executable: one the persistent cache handed
+    back (itself a deserialized executable) can serialize into a blob that
+    loads and then cannot find its own functions when it runs (XLA:CPU:
+    "Function bitcast_gather_fusion.2 not found" — seen whenever another
+    process had put the same program in the cache first). A store miss is
+    rare by design, so it always pays for a real compile. The flag alone
+    is not enough: jax latches "cache in use" on first check, hence
+    reset_cache() on both sides."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = bool(jax.config.jax_enable_compilation_cache)
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()
+
+
+def _serialize(compiled) -> bytes:
+    """One blob: the executable, its pytrees, and the ids of the devices
+    it was compiled for. jax 0.9.0's `deserialize_and_load` loads onto
+    EVERY device of the backend unless told otherwise, and a one-device
+    program loaded that way rejects its inputs ("expected 8 shards")."""
+    from jax.experimental import serialize_executable as se
+    payload, in_tree, out_tree = se.serialize(compiled)
+    ids = [d.id for d in compiled.runtime_executable().local_devices()]
+    return pickle.dumps((payload, in_tree, out_tree, ids))
+
+
+def _deserialize(blob: bytes):
+    from jax.experimental import serialize_executable as se
+    payload, in_tree, out_tree, ids = pickle.loads(blob)
+    by_id = {d.id: d for d in jax.devices()}
+    return se.deserialize_and_load(
+        payload, in_tree, out_tree,
+        execution_devices=[by_id[i] for i in ids])
+
+
 class AOTStore:
     """Content-addressed on-disk store of serialized XLA executables.
 
@@ -188,21 +231,19 @@ class AOTStore:
 
     def load(self, key: str):
         """Deserialize the stored executable for `key`, or None (absent
-        OR unreadable — a corrupt entry counts `load_errors` and the
-        caller falls back to JIT; a wrong program is impossible by
-        keying, so the only failure mode is a miss)."""
+        OR unreadable — a corrupt entry counts `load_errors`, is logged,
+        and the caller compiles as on any miss; a wrong program is
+        impossible by keying, so the only failure mode is a miss)."""
         bin_path, man_path = self._paths(key)
         if not (os.path.exists(bin_path) and os.path.exists(man_path)):
             return None
         try:
             with open(bin_path, "rb") as f:
-                payload, in_tree, out_tree = pickle.load(f)
-            from jax.experimental import serialize_executable as se
-            return se.deserialize_and_load(payload, in_tree, out_tree)
-        except Exception as e:  # corrupt/incompatible blob -> JIT
+                return _deserialize(f.read())
+        except Exception as e:  # corrupt/incompatible blob -> a miss
             self.load_errors += 1
-            log.warning("[aot] unreadable entry %s (%s: %s) — falling "
-                        "back to JIT", key, type(e).__name__, e)
+            log.warning("[aot] unreadable entry %s (%s: %s) — counted as "
+                        "a miss", key, type(e).__name__, e)
             return None
 
     def save(self, key: str, compiled, manifest: dict) -> bool:
@@ -214,9 +255,8 @@ class AOTStore:
         every future replica, so an unloadable blob is rejected here
         (build() then retries the compile with that cache bypassed)."""
         try:
-            from jax.experimental import serialize_executable as se
-            blob = pickle.dumps(se.serialize(compiled))
-            se.deserialize_and_load(*pickle.loads(blob))
+            blob = _serialize(compiled)
+            _deserialize(blob)
         except Exception as e:  # unserializable backend — store disabled
             log.warning("[aot] cannot serialize %s (%s: %s)", key,
                         type(e).__name__, e)
@@ -259,34 +299,27 @@ class AOTStore:
         if self.strict == "warn":
             log.warning("[aot] miss: compiling %s (%s)", family, key)
         t0 = time.perf_counter()
-        compiled = jitted.lower(*avals).compile()
-        ms = (time.perf_counter() - t0) * 1e3
-        manifest = {
-            "key": key, "family": family, "origin": origin, "env": env,
-            "avals": aval_fingerprint(avals),
-            "knobs": knob_fingerprint(),
-            "runtime": self._runtime or runtime_fingerprint(),
-            "compile_ms": round(ms, 3),
-        }
-        if not self.save(key, compiled, manifest):
-            # save() rejects a blob that fails its serialize round-trip
-            # — seen when jax's persistent compilation cache hands back
-            # an executable compiled under other flags. One retry with
-            # the cache bypassed yields a self-contained executable;
-            # clear_caches() is required too, else the in-memory
-            # compilation memo returns the same stale executable and
-            # the flag flip never reaches the compiler.
-            prev = bool(jax.config.jax_enable_compilation_cache)
-            t1 = time.perf_counter()
-            try:
-                jax.config.update("jax_enable_compilation_cache", False)
+        with _no_persistent_cache():
+            compiled = jitted.lower(*avals).compile()
+            ms = (time.perf_counter() - t0) * 1e3
+            manifest = {
+                "key": key, "family": family, "origin": origin, "env": env,
+                "avals": aval_fingerprint(avals),
+                "knobs": knob_fingerprint(),
+                "runtime": self._runtime or runtime_fingerprint(),
+                "compile_ms": round(ms, 3),
+            }
+            if not self.save(key, compiled, manifest):
+                # save() rejects a blob that fails its serialize
+                # round-trip. One retry past the in-memory compilation
+                # memo too (clear_caches), which can hand back the same
+                # executable again.
+                t1 = time.perf_counter()
                 jax.clear_caches()
                 compiled = jitted.lower(*avals).compile()
-            finally:
-                jax.config.update("jax_enable_compilation_cache", prev)
-            ms += (time.perf_counter() - t1) * 1e3
-            manifest["compile_ms"] = round(ms, 3)
-            self.save(key, compiled, manifest)
+                ms += (time.perf_counter() - t1) * 1e3
+                manifest["compile_ms"] = round(ms, 3)
+                self.save(key, compiled, manifest)
         self.compile_ms += ms
         self.events.append({"family": family, "phase": "compile",
                             "ms": round(ms, 3), "key": key})
@@ -317,14 +350,19 @@ class AOTStore:
                 "entries": len(self.manifests()), "root": self.root}
 
 
+class AOTInputMismatch(RuntimeError):
+    """AOT_STRICT=require: a stored program rejected its live inputs."""
+
+
 class SafeCompiled:
-    """A store-built executable with a JIT escape hatch: a ``Compiled``
-    rejects inputs whose layout/sharding drifted from the stored avals
-    (it cannot re-trace), so the first call failure permanently reroutes
-    to the original jitted fn and counts ``fallbacks`` — serving
-    degrades to cold-start JIT instead of crashing. Trace counts expose
-    the reroute (the fallback traces), so CI parity checks still fail
-    loudly on an aval-derivation bug."""
+    """A store-built executable beside the jitted fn it was built from.
+    A ``Compiled`` rejects inputs whose layout/sharding drifted from the
+    stored avals (it cannot re-trace). That is a store bug, not a slow
+    start: under AOT_STRICT=require the rejection is raised as
+    `AOTInputMismatch`; otherwise it is LOGGED and COUNTED — the load no
+    longer counts as a hit, `misses` and `fallbacks` go up — and the call
+    (and every later one) goes to the jitted fn, whose trace count
+    exposes the reroute to the CI parity checks."""
 
     def __init__(self, compiled, jitted, store: AOTStore, family: str):
         self._compiled = compiled
@@ -338,11 +376,18 @@ class SafeCompiled:
             try:
                 return self._compiled(*args)
             except Exception as e:
+                if self._store.strict == "require":
+                    raise AOTInputMismatch(
+                        f"AOT_STRICT=require: stored {self._family} "
+                        f"rejected its live inputs ({type(e).__name__}: "
+                        f"{e})") from e
                 self._broken = True
                 self._store.fallbacks += 1
+                self._store.misses += 1
+                self._store.hits = max(0, self._store.hits - 1)
                 log.warning("[aot] stored %s rejected live inputs (%s: "
-                            "%s) — JIT fallback", self._family,
-                            type(e).__name__, e)
+                            "%s) — counted as a miss, compiling",
+                            self._family, type(e).__name__, e)
         return self._jitted(*args)
 
 

@@ -108,7 +108,7 @@ GOLDEN_PATH = os.path.join(os.path.dirname(__file__),
 #: reduce-scatter; pmin/pmax are all-reduce-shaped (tiny, but on the wire).
 COLLECTIVE_FAMILY = {
     "psum": "all_reduce",
-    "psum2": "all_reduce",   # shard_map's rewritten psum (check_rep)
+    "psum_invariant": "all_reduce",  # psum under shard_map's check_vma
     "pmax": "all_reduce",
     "pmin": "all_reduce",
     "all_gather": "all_gather",

@@ -5,9 +5,12 @@ trainer or script ever calls it (SURVEY.md §3.4 "capability exists only as
 API surface"); this CLI closes that gap: load a checkpoint written by the
 trainer (`--save_model` / `--ckpt_interval`), tokenize a prompt, decode.
 
-Tokenization uses tiktoken's GPT-2 BPE when available (the prepare scripts'
-vocabulary); otherwise the prompt must be comma-separated token ids and
-output is printed as ids.
+A prompt of comma-separated token ids is taken as ids and answered in
+ids. A TEXT prompt is tokenized with tiktoken's GPT-2 BPE (the prepare
+scripts' vocabulary), which is resolved only then: `get_encoding` fetches
+its vocabulary over the network on first use, and neither this CLI nor
+the server may wait on (or die of) that attempt on a machine without one
+when the caller sends ids.
 """
 
 from __future__ import annotations
@@ -16,6 +19,7 @@ import argparse
 import contextlib
 import dataclasses
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +31,50 @@ def _encoder():
         return tiktoken.get_encoding("gpt2")
     except Exception:
         return None
+
+
+class TokenizerUnavailable(RuntimeError):
+    """A text prompt arrived and the GPT-2 BPE files cannot be had."""
+
+
+class LazyEncoder:
+    """The GPT-2 BPE, resolved when a text prompt FIRST needs it — never
+    at start-up (module docstring). `decode` answers only once a text
+    prompt has resolved the encoder: clients that send ids get ids."""
+
+    def __init__(self):
+        self._enc = None
+        self._tried = False
+
+    def encode(self, text: str, **kw) -> list:
+        if not self._tried:
+            self._tried = True
+            t0 = time.perf_counter()
+            self._enc = _encoder()
+            print(f"tokenizer: gpt2 BPE "
+                  f"{'ready' if self._enc is not None else 'UNAVAILABLE'} "
+                  f"after {time.perf_counter() - t0:.2f}s (resolved on the "
+                  "first text prompt)")
+        if self._enc is None:
+            raise TokenizerUnavailable(
+                "no tokenizer available (tiktoken could not load the gpt2 "
+                "vocabulary — no network?); send the prompt as token ids")
+        return self._enc.encode(text, **kw)
+
+    def decode(self, toks: list) -> str:
+        if self._enc is None:
+            raise TokenizerUnavailable("no text prompt has resolved the "
+                                       "tokenizer yet")
+        return self._enc.decode(toks)
+
+
+def parse_ids(prompt: str):
+    """`prompt` as a list of token ids when it is comma-separated
+    integers, else None (it is text)."""
+    parts = [t.strip() for t in prompt.split(",") if t.strip()]
+    if parts and all(t.isdigit() for t in parts):
+        return [int(t) for t in parts]
+    return None
 
 
 def load_for_inference(ckpt: str, *, shard: bool = False, log=print):
@@ -123,8 +171,9 @@ def main(argv=None) -> None:
                    help="checkpoint dir (checkpoints/<name>/step_N or the "
                         "<name> root, in which case the newest step is used)")
     p.add_argument("--prompt", type=str, default="\n",
-                   help="text prompt (or comma-separated token ids when no "
-                        "tokenizer is available)")
+                   help="text prompt, or comma-separated token ids (then "
+                        "the output is ids too and no tokenizer is "
+                        "touched)")
     p.add_argument("--max_new_tokens", type=int, default=200)
     p.add_argument("--temperature", type=float, default=1.0)
     p.add_argument("--top_k", type=int, default=50)
@@ -154,17 +203,22 @@ def main(argv=None) -> None:
                         "prefill (the baseline)")
     args = p.parse_args(argv)
 
+    from distributed_pytorch_tpu.config import enable_compile_cache
     from distributed_pytorch_tpu.models.generate import make_generate_fn
+    enable_compile_cache()
 
+    from distributed_pytorch_tpu.obs.paths import device_record
+    device = device_record()
+    print(f"backend {device['platform']}: {device['count']} device(s) of "
+          f"kind {device['kind']!r}")
     model, variables, model_cfg, train_cfg, mesh, _, _ = load_for_inference(
         args.ckpt, shard=args.shard)
 
-    enc = _encoder()
-    if enc is not None:
-        ids = enc.encode(args.prompt, allowed_special="all")
-    else:
-        ids = [int(t) for t in args.prompt.split(",") if t.strip()]
-        ids = ids or [0]
+    enc = None
+    ids = parse_ids(args.prompt)
+    if ids is None:
+        enc = LazyEncoder()
+        ids = enc.encode(args.prompt, allowed_special="all") or [0]
     ids = ids[-model_cfg.block_size:]
     T0 = len(ids)
     # Bucket the prompt length to the next power of two (right-padded;
@@ -177,7 +231,6 @@ def main(argv=None) -> None:
     bucket = min(bucket, model_cfg.block_size)
     prompt = jnp.asarray(ids + [0] * (bucket - T0), jnp.int32)[None]
 
-    import time
     n_new = args.num_samples * args.max_new_tokens
     if args.cache_dtype or args.quant_weights or args.prefill_chunk:
         # quantized serving / chunked-prefill knobs route through the

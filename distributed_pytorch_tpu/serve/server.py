@@ -58,6 +58,7 @@ from typing import Optional
 
 from distributed_pytorch_tpu.obs import profile as obs_profile
 from distributed_pytorch_tpu.obs import trace as obs_trace
+from distributed_pytorch_tpu.sample import TokenizerUnavailable
 from distributed_pytorch_tpu.serve.control import normalize_class
 from distributed_pytorch_tpu.serve.scheduler import (RequestHandle,
                                                      Scheduler, ShedError)
@@ -365,7 +366,11 @@ class ServeApp:
                     400, {"error": "no tokenizer available; send 'prompt' "
                                    "as a list of token ids"}))
                 return
-            prompt = self.encoder.encode(prompt, allowed_special="all")
+            try:
+                prompt = self.encoder.encode(prompt, allowed_special="all")
+            except TokenizerUnavailable as e:
+                writer.write(_json_response(400, {"error": str(e)}))
+                return
         if not isinstance(prompt, list) or not prompt \
                 or not all(isinstance(t, int) for t in prompt):
             writer.write(_json_response(

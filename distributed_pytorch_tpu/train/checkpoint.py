@@ -345,12 +345,12 @@ def restore_checkpoint(path: str, abstract_state: Any,
     with ocp.StandardCheckpointer() as ckptr:
         state = ckptr.restore(os.path.join(_abs(path), "state"),
                               abstract_state)
-    # Re-buffer through XLA before the trainer donates this state into the
-    # jitted step: orbax's restore can hand back arrays whose buffers XLA
-    # does not own, and donating those corrupts the heap on jax 0.4.x
-    # (observed: "corrupted double-linked list" aborts right after resume).
-    return jax.tree_util.tree_map(
-        lambda x: x.copy() if isinstance(x, jax.Array) else x, state)
+    # The restored arrays go straight into the (donating) train step. An
+    # older jax needed a defensive `.copy()` of every leaf here — donating
+    # orbax-restored buffers corrupted the heap; on jax 0.9.0 / orbax
+    # 0.11.32 the resume, elastic and offload suites pass without it, and
+    # the copy cost a transient second params+moments in device memory.
+    return state
 
 
 def restore_for_inference(path: str, abstract_state: Any,

@@ -1,7 +1,7 @@
 """Static HBM budget estimator + micro-batch / remat planner.
 
 Opens the 350M-1.5B config ladder (BASELINE.json) without burning a
-hardware window on OOM bisection: given a model config, a recipe, and a
+chip time on OOM bisection: given a model config, a recipe, and a
 per-chip HBM budget, `plan_memory` estimates the resident bytes of every
 tensor class the recipe implies (fp32 params / AdamW moments / grad
 accumulator — each divided by dp exactly when the recipe's sharding tables
@@ -14,7 +14,7 @@ Everything here is closed-form or jax.eval_shape (trace-only): no compile,
 no allocation — `--dryrun` prints a 1.5B plan from a laptop CPU in
 seconds. The estimate is deliberately conservative (activation bytes use a
 per-token-per-layer formula derived from what the backward actually keeps
-alive, times a 15% fragmentation/XLA-temp fudge); the first TPU window
+alive, times a 15% fragmentation/XLA-temp fudge); a chip run
 validates the constants against `peak_bytes_in_use` and PERF.md records
 the delta.
 """
@@ -32,17 +32,10 @@ from distributed_pytorch_tpu.parallel.sharding import (_GRAD_SHARDED,
                                                        _OPT_SHARDED,
                                                        _PARAM_SHARDED)
 
-# Per-chip HBM by device-kind substring (GiB, spec-sheet numbers; first
-# match wins — same matching scheme as metrics._PEAK_FLOPS).
-_HBM_GB = (
-    ("v6", 32.0),       # Trillium
-    ("v5p", 95.0),
-    ("v5", 16.0),       # v5e
-    ("v4", 32.0),
-    ("v3", 32.0),
-    ("v2", 16.0),
-)
-_DEFAULT_HBM_GB = 16.0  # plan for a v5e when the backend is CPU/unknown
+# The device-free planner (`--dryrun`, CPU rehearsals) has no chip to ask,
+# so it plans for this one — by name, through the same table an attached
+# chip is looked up in (train/metrics.CHIP_SPECS).
+PLANNING_DEVICE_KIND = "TPU v5 lite"    # v5e, 16 GiB
 
 # optimizer moment multiplier (x param bytes, fp32)
 _OPT_MULT = {"adamw": 2.0, "lion": 1.0, "adafactor": 0.1}
@@ -59,14 +52,13 @@ _RUNTIME_RESERVE_GB = 0.9
 
 
 def device_hbm_gb() -> float:
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:  # pragma: no cover
-        return _DEFAULT_HBM_GB
-    for key, val in _HBM_GB:
-        if key in kind:
-            return val
-    return _DEFAULT_HBM_GB
+    """Per-chip HBM (GiB) to plan against: the attached accelerator's —
+    an unknown device_kind is an error (metrics.chip_spec), never a
+    silent 16 — or, on the CPU backend, the v5e the device-free planner
+    names above."""
+    from distributed_pytorch_tpu.train.metrics import CHIP_SPECS, chip_spec
+    spec = chip_spec() or CHIP_SPECS[PLANNING_DEVICE_KIND]
+    return spec.hbm_gib
 
 
 def param_count(cfg: LLMConfig) -> int:
@@ -451,16 +443,17 @@ def predicted_train_peak_gb(model_cfg: LLMConfig, train_cfg: TrainConfig,
 
 def watermark_report(predicted_gb: Optional[float]) -> list[dict]:
     """Per-device `{device, memplan_predicted_gb, measured_peak_gb,
-    delta}` rows from the live `peak_bytes_in_use` watermark — the
-    record stats.json / bench JSON / the mfu_sweep carry so a hardware
-    window validates the planner constants without re-running anything.
+    delta}` rows from the live watermark (metrics.hbm_watermark's
+    `peak_bytes`: in use + reserved) — the record stats.json / bench JSON
+    / the mfu_sweep carry so a chip run validates the planner constants
+    without re-running anything.
     Keys are always present; values are None where the backend reports
     no memory stats (CPU) so the schema is stable across backends."""
     from distributed_pytorch_tpu.train.metrics import hbm_watermark
 
     rows = []
     for d in hbm_watermark():
-        peak = d.get("peak_bytes_in_use")
+        peak = d.get("peak_bytes")
         measured = round(peak / 2 ** 30, 3) if peak else None
         delta = round(measured - predicted_gb, 3) \
             if (measured is not None and predicted_gb is not None) else None

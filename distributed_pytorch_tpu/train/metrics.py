@@ -16,55 +16,62 @@ not a matmul and is excluded.
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 
 from distributed_pytorch_tpu.config import LLMConfig
 
-# Peak dense bf16 TFLOP/s per chip, by `jax.devices()[0].device_kind`
-# substring (public spec-sheet numbers).
-_PEAK_FLOPS = (
-    ("v6", 918e12),        # Trillium
-    ("v5p", 459e12),
-    ("v5", 197e12),        # v5e ("v5 lite")
-    ("v4", 275e12),
-    ("v3", 123e12),
-    ("v2", 45e12),
-)
+@dataclasses.dataclass(frozen=True)
+class ChipSpec:
+    peak_flops: float      # dense bf16 FLOP/s per chip
+    hbm_bw: float          # HBM bytes/s per chip
+    hbm_gib: float         # HBM capacity per chip
+
+
+# Published per-chip peaks (Google Cloud TPU documentation, one page per
+# generation), keyed by the EXACT `jax.devices()[0].device_kind` string —
+# the strings below are what this installation's libtpu reports for each
+# generation's topology. Exact keys, not substrings: the v5e chip reports
+# "TPU v5 lite" and v5p reports "TPU v5", so substring order used to decide
+# which row a device got, and a kind without its letter fell through to
+# the v5e row. v2/v3 are absent on purpose: there a jax device is one CORE
+# of a two-core chip, so a per-chip peak would be wrong per device.
+CHIP_SPECS = {
+    "TPU v5 lite": ChipSpec(197e12, 8.19e11, 16.0),     # v5e
+    "TPU v5": ChipSpec(459e12, 2.765e12, 95.0),         # v5p
+    "TPU v6 lite": ChipSpec(918e12, 1.64e12, 32.0),     # v6e (Trillium)
+    "TPU v4": ChipSpec(275e12, 1.228e12, 32.0),
+}
+
+
+def chip_spec() -> ChipSpec | None:
+    """The attached accelerator's published peaks. None on the CPU backend
+    (no peak: MFU / MBU are not computed there). An accelerator whose
+    device_kind is not in the table is an ERROR, not a default — a wrong
+    peak makes every utilization downstream wrong without a sign of it."""
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return None
+    if dev.device_kind not in CHIP_SPECS:
+        raise KeyError(
+            f"device_kind {dev.device_kind!r} (platform {dev.platform!r}) "
+            "is not in train/metrics.CHIP_SPECS; add its published peaks "
+            f"there, keyed by that exact string (known: "
+            f"{sorted(CHIP_SPECS)})")
+    return CHIP_SPECS[dev.device_kind]
 
 
 def peak_flops_per_chip() -> float | None:
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:  # pragma: no cover
-        return None
-    for key, val in _PEAK_FLOPS:
-        if key in kind:
-            return val
-    return None
-
-
-# Peak HBM bandwidth (bytes/s) per chip, same spec-sheet sourcing as
-# _PEAK_FLOPS. Decode is memory-bound, so its utilization metric is MBU
-# (memory-bandwidth utilization), not MFU.
-_PEAK_HBM_BW = (
-    ("v6", 1.64e12),       # Trillium
-    ("v5p", 2.765e12),
-    ("v5", 8.19e11),       # v5e
-    ("v4", 1.228e12),
-    ("v3", 9.0e11),
-    ("v2", 7.0e11),
-)
+    spec = chip_spec()
+    return spec.peak_flops if spec else None
 
 
 def peak_hbm_bw_per_chip() -> float | None:
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:  # pragma: no cover
-        return None
-    for key, val in _PEAK_HBM_BW:
-        if key in kind:
-            return val
-    return None
+    """Decode is memory-bound, so its utilization metric is MBU
+    (memory-bandwidth utilization), not MFU."""
+    spec = chip_spec()
+    return spec.hbm_bw if spec else None
 
 
 def kv_bytes_per_token(cfg: LLMConfig, cache_dtype_size: int = 2, *,
@@ -237,42 +244,45 @@ def mfu(cfg: LLMConfig, tokens_per_step: int, seq_len: int,
     return achieved / (peak * n_chips)
 
 
+def _peak_bytes(st: dict) -> int | None:
+    """Peak device memory out of one `memory_stats()` dict. This runtime
+    (libtpu 0.0.34) keeps a compiled program's temporaries in a RESERVED
+    region that `peak_bytes_in_use` does not count: the 124M step at
+    16x1024 on a v5e read peak_bytes_in_use = 1.52 GB (the state and the
+    batch) beside peak_bytes_reserved = 14.01 GB, and their sum is the
+    compiler's own argument + temp bytes within 1% (PERF.md, PR 21). The
+    sum is what the chip really held."""
+    peak = st.get("peak_bytes_in_use") or st.get("bytes_in_use")
+    if not peak:
+        return None
+    return peak + (st.get("peak_bytes_reserved") or 0)
+
+
 def hbm_watermark() -> list[dict]:
     """Per-LOCAL-device memory watermark: one dict per device with
-    `peak_bytes_in_use` / `bytes_in_use` (None-valued where the backend
-    doesn't report memory_stats — CPU). The sampling half of the
-    ROADMAP's "validate train/memplan.py estimates against
-    peak_bytes_in_use" item: the train loop probes this at compile,
-    first step, and log boundaries, and memplan.watermark_report turns
+    `peak_bytes` (in use + reserved, `_peak_bytes`), the raw
+    `peak_bytes_in_use` / `peak_bytes_reserved` it is made of, and
+    `bytes_in_use` (None-valued where the backend doesn't report
+    memory_stats — CPU). The sampling half of the "validate
+    train/memplan.py against the chip" item: the train loop probes this
+    after init and at log boundaries, and memplan.watermark_report turns
     it into the predicted-vs-measured delta."""
-    try:
-        devices = jax.local_devices()
-    except Exception:  # pragma: no cover — backend init failed
-        return []
     out = []
-    for d in devices:
-        try:
-            st = d.memory_stats() or {}
-        except Exception:  # noqa: BLE001 — CPU backends raise/return None
-            st = {}
-        out.append({"device": f"{getattr(d, 'platform', '?')}"
-                              f":{getattr(d, 'id', '?')}",
+    for d in jax.local_devices():
+        st = d.memory_stats() or {}       # the CPU backend reports None
+        out.append({"device": f"{d.platform}:{d.id}",
+                    "peak_bytes": _peak_bytes(st),
                     "peak_bytes_in_use": st.get("peak_bytes_in_use"),
+                    "peak_bytes_reserved": st.get("peak_bytes_reserved"),
                     "bytes_in_use": st.get("bytes_in_use")})
     return out
 
 
 def device_memory_gb() -> float | None:
-    """Peak device-memory use in GiB on the first local device, or None
-    when the backend doesn't report it (CPU). The TPU equivalent of the
-    reference's per-step `torch.cuda.memory_reserved()` print
-    (single-gpu/train.py:356) — the number that justifies batch-size
-    choices when chasing MFU (round-3 VERDICT #6)."""
-    try:
-        stats = jax.local_devices()[0].memory_stats()
-    except Exception:  # pragma: no cover
-        return None
-    if not stats:
-        return None
-    b = stats.get("peak_bytes_in_use") or stats.get("bytes_in_use")
+    """Peak device-memory use in GiB on the first local device
+    (`_peak_bytes`: in use + reserved), or None when the backend doesn't
+    report it (CPU). The TPU equivalent of the reference's per-step
+    `torch.cuda.memory_reserved()` print (single-gpu/train.py:356) — the
+    number that justifies batch-size choices when chasing MFU."""
+    b = _peak_bytes(jax.local_devices()[0].memory_stats() or {})
     return b / 2 ** 30 if b else None

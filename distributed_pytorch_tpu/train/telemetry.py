@@ -343,7 +343,8 @@ class TrainTelemetry:
                              "MFU over the last boundary window")
             m.register_gauge("train_hbm_peak_gb",
                              lambda: self.last["hbm_gb"] or 0.0,
-                             "peak_bytes_in_use watermark (GiB, device 0)")
+                             "peak HBM watermark, in use + reserved (GiB, "
+                             "device 0)")
 
     def record_step(self, **fields) -> None:
         """Append one per-step record (callers pre-filter Nones and

@@ -1,7 +1,7 @@
 #!/usr/bin/env bash
 # Pod-scale 7B launcher (round 23): supervisor-fronted rows of the
 # gpt2_7b recipe grid — pp (interleaved-1F1B) x fsdp x fsdp_tp —
-# mirroring hw_window.sh conventions (timeout-capped legs, tee'd logs,
+# timeout-capped legs, tee'd logs,
 # one timestamped capture dir).
 #
 # There is no torchrun on TPU, and since round 13 there is no bare

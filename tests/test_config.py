@@ -120,3 +120,53 @@ def test_cli_main_smoke(tmp_path, monkeypatch):
           "--up_dim", "48", "--max_iters", "3", "--batch_size", "2",
           "--total_batch_size_str", "8*2*32", "--parallelism", "dp",
           "--no-save_stats"])
+
+
+# ---------------------------------------------------------------------------
+# the one compile-cache placement rule (config.enable_compile_cache)
+# ---------------------------------------------------------------------------
+
+_CACHE_PROBE = (
+    "import jax\n"
+    "from distributed_pytorch_tpu import config\n"
+    "before = jax.config.jax_compilation_cache_dir\n"
+    "got = config.enable_compile_cache()\n"
+    "print(repr((before, got, jax.config.jax_compilation_cache_dir)))\n")
+
+
+def _cache_probe(env_dir):
+    import ast
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=root, JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    r = subprocess.run([sys.executable, "-c", _CACHE_PROBE], env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr[-2000:]
+    return root, ast.literal_eval(r.stdout.strip().splitlines()[-1])
+
+
+def test_compile_cache_env_var_wins(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: JAX's own handling of it stands and
+    the helper sets NO directory in code."""
+    d = str(tmp_path / "from_env")
+    _, (before, got, after) = _cache_probe(d)
+    assert before == d          # jax read the variable itself
+    assert got == d and after == d
+
+
+def test_compile_cache_default_is_fixed_checkout_path():
+    """Unset: `<checkout>/.jax_cache` — a fixed path (the path is part of
+    the cache key), equal across two processes, never /tmp, a pid or a
+    tempfile."""
+    import os
+    root, (before, got, after) = _cache_probe(None)
+    _, second = _cache_probe(None)
+    assert before is None
+    assert got == after == os.path.join(root, ".jax_cache")
+    assert second[1:] == (got, after)
+    assert "/tmp" not in got and str(os.getpid()) not in got

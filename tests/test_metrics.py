@@ -66,3 +66,63 @@ def test_flagship_flops_order_of_magnitude():
     n_params = M.matmul_params_per_token(cfg)
     assert 110e6 < n_params < 135e6         # a true ~124M matmul census
     assert 0.9e13 < flops < 1.5e13
+
+# ---------------------------------------------------------------------------
+# one table of published peaks, keyed by the exact device_kind
+# ---------------------------------------------------------------------------
+
+class _FakeDevice:
+    def __init__(self, platform, kind):
+        self.platform, self.device_kind = platform, kind
+
+
+def test_chip_spec_cpu_has_no_peak():
+    """The CPU backend: no peak, MFU/MBU not computed — and the device-free
+    planner names the v5e it plans for."""
+    from distributed_pytorch_tpu.train import memplan
+    assert M.chip_spec() is None
+    assert M.peak_flops_per_chip() is None
+    assert M.peak_hbm_bw_per_chip() is None
+    assert M.mfu(flagship_gpt124m(), 16384, 1024, 0.3, 1) is None
+    assert memplan.PLANNING_DEVICE_KIND == "TPU v5 lite"
+    assert memplan.device_hbm_gb() == 16.0
+
+
+def test_chip_spec_keys_are_exact(monkeypatch):
+    """The v5e reports 'TPU v5 lite' and the v5p 'TPU v5': exact keys, so
+    neither can land on the other's row (substring order used to decide)."""
+    import jax
+    for kind, flops, gib in (("TPU v5 lite", 197e12, 16.0),
+                             ("TPU v5", 459e12, 95.0),
+                             ("TPU v6 lite", 918e12, 32.0)):
+        monkeypatch.setattr(jax, "devices",
+                            lambda k=kind: [_FakeDevice("tpu", k)])
+        assert M.chip_spec().peak_flops == flops
+        assert M.chip_spec().hbm_gib == gib
+
+
+def test_unknown_accelerator_kind_is_an_error(monkeypatch):
+    """An accelerator that is not in the table is an ERROR in the MFU path
+    and in the memory planner — never the v5e row by default."""
+    import jax
+    import pytest
+    from distributed_pytorch_tpu.train import memplan
+    monkeypatch.setattr(jax, "devices",
+                        lambda: [_FakeDevice("tpu", "TPU v9 mega")])
+    with pytest.raises(KeyError, match="TPU v9 mega"):
+        M.peak_flops_per_chip()
+    with pytest.raises(KeyError, match="CHIP_SPECS"):
+        memplan.device_hbm_gb()
+
+
+def test_peak_bytes_counts_the_reserved_region():
+    """The counters a v5e reported after 6 dp steps of the 124M model at
+    16x1024 (PR 21): the program's 14 GB of temporaries sit in the RESERVED
+    region, outside peak_bytes_in_use."""
+    st = {"bytes_in_use": 1515849728, "peak_bytes_in_use": 1515982336,
+          "bytes_reserved": 14010826752, "peak_bytes_reserved": 14010826752,
+          "bytes_limit": 16909334528}
+    assert M._peak_bytes(st) == 1515982336 + 14010826752
+    # a backend that keeps no reserved region: in-use alone
+    assert M._peak_bytes({"peak_bytes_in_use": 5}) == 5
+    assert M._peak_bytes({}) is None and M.device_memory_gb() is None

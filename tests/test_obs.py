@@ -201,3 +201,100 @@ def test_profile_capture_and_busy_guard(tmp_path):
     # the capture left a jax profiler artifact tree behind
     assert any(files for _, _, files in os.walk(out)), \
         "profiler capture wrote nothing"
+
+
+# ----------------------------------------------------------------------
+# obs/paths.py: which path a compiled program took, said where it is read
+# ----------------------------------------------------------------------
+
+_HLO = '''
+  %jvp_flash_fwd_.1 = (bf16[96,1024,64]{2,1,0}) custom-call(%a, %b), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/jvp(flash_fwd)/pallas_call" stack_frame_id=11}
+  %t.3 = bf16[96,1024,64]{2,1,0} custom-call(%a, %b), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/transpose(jvp(flash_bwd_dq))/pallas_call" stack_frame_id=9}
+  %t.4 = bf16[96,1024,64]{2,1,0} custom-call(%a, %b), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/transpose(jvp(flash_bwd_dq))/pallas_call" stack_frame_id=9}
+  %d.1 = bf16[8,12,1,64]{3,2,1,0} custom-call(%q), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/paged_flash_decode/pallas_call"}
+  %x.1 = f32[8]{0} custom-call(%q), custom_call_target="Sharding"
+%all-reduce-scatter.39 (input.39: bf16[768,4096]) -> bf16[768,1024] {
+  %ag.1 = bf16[768,4096]{1,0} all-gather-start(%p), dimensions={1}
+  %ag.2 = bf16[768,4096]{1,0} all-gather-done(%ag.1)
+  %ar.1 = f32[] all-reduce(%l), to_apply=%add
+'''
+
+
+def test_kernel_census_reads_kernel_names_off_compiled_text():
+    from distributed_pytorch_tpu.obs import paths
+    assert paths.kernel_census(_HLO) == {
+        "flash_fwd": 1, "flash_bwd_dq": 2, "paged_flash_decode": 1}
+    assert paths.kernel_census("ENTRY main { ROOT %a = f32[] add(x, y) }") \
+        == {}
+
+
+def test_collective_census_counts_async_pairs_once_and_fused_rs():
+    from distributed_pytorch_tpu.obs import paths
+    assert paths.collective_census(_HLO) == {
+        "all-gather": 1, "all-reduce": 1, "reduce-scatter": 1}
+
+
+def test_auto_choice_is_recorded_per_program():
+    import jax.numpy as jnp
+    from distributed_pytorch_tpu.obs import paths
+    from distributed_pytorch_tpu.ops.attention_core import sdpa
+    q = jnp.zeros((1, 16, 2, 8), jnp.float32)
+    paths.note("loss", "fused", "left over from an earlier trace")
+    paths.reset()
+    sdpa(q, q, q, impl="auto")
+    assert paths.choices() == {"attention": "xla (auto: backend cpu)"}
+
+
+def test_attn_impl_pallas_declined_is_an_error_naming_the_gate():
+    """Asked for BY NAME and refused by the usable gate: an error that
+    names the gate and its reason — never a quiet XLA run."""
+    import jax.numpy as jnp
+    import pytest
+    from distributed_pytorch_tpu.obs import paths
+    from distributed_pytorch_tpu.ops.attention_core import sdpa
+    q = jnp.zeros((1, 16, 2, 12), jnp.float32)       # head dim 12
+    with pytest.raises(paths.PathDeclined,
+                       match="flash_attention_usable declined: head dim 12"):
+        sdpa(q, q, q, impl="pallas")
+    # a KV-cached call is outside the flash kernel's contract: no error
+    out = sdpa(q, q, q, impl="pallas", decode=True, q_offset=0)
+    assert out.shape == q.shape
+
+
+def test_loss_impl_pallas_on_a_cpu_is_an_error_not_fused():
+    import jax
+    import jax.numpy as jnp
+    import pytest
+    from distributed_pytorch_tpu.config import LLMConfig
+    from distributed_pytorch_tpu.models.gpt import LLM
+    from distributed_pytorch_tpu.obs import paths
+    cfg = LLMConfig(vocab_size=64, block_size=16, n_embd=128, n_head=4,
+                    n_kv_heads=4, attn="mha", n_layer=1, up_dim=32,
+                    loss_impl="pallas")
+    x = jnp.zeros((2, 16), jnp.int32)
+    with pytest.raises(paths.PathDeclined,
+                       match="pallas_ce_usable declined: backend is cpu"):
+        jax.eval_shape(lambda r: LLM(cfg).init({"params": r, "dropout": r},
+                                               x, x), jax.random.PRNGKey(0))
+
+
+def test_flash_decode_on_declined_is_an_error_on_a_tpu(monkeypatch):
+    """FLASH_DECODE=on that its gate declines: an error on a TPU backend;
+    off-TPU ('on' = interpret mode for the parity tests) the reference path
+    carries the call and the choice is noted (tests/test_flash_decode.py)."""
+    import jax.numpy as jnp
+    import pytest
+    from distributed_pytorch_tpu.obs import paths
+    from distributed_pytorch_tpu.ops import attention_core as core
+    q = jnp.zeros((2, 1, 4, 16), jnp.float32)
+    kv = jnp.zeros((2, 9, 2, 16), jnp.float32)        # S=9: no tile split
+    pos = jnp.array([3, 8], jnp.int32)
+    monkeypatch.setenv("FLASH_DECODE", "on")
+    paths.reset()
+    core.sdpa(q, kv, kv, q_offset=pos, decode=True)
+    assert "flash_decode_usable declined" in \
+        paths.choices()["decode_attention"]
+    monkeypatch.setattr(core, "_on_tpu", lambda: True)
+    with pytest.raises(paths.PathDeclined,
+                       match="FLASH_DECODE=on .* flash_decode_usable"):
+        core.sdpa(q, kv, kv, q_offset=pos, decode=True)

@@ -46,28 +46,6 @@ def in_tmp(tmp_path, monkeypatch):
     return tmp_path
 
 
-@pytest.fixture(autouse=True)
-def _private_compile_cache(tmp_path):
-    """Point the persistent XLA compile cache at a fresh per-test dir.
-
-    The suite-wide cache (conftest.py, /tmp/jax_test_ccache) persists
-    across runs, and on jax 0.4.37 an executable DESERIALIZED from it
-    can mis-handle the train step's donated buffers — observed as the
-    optimizer update silently not landing (params returned unchanged
-    with correct metrics), which is indistinguishable from the exact
-    regression the skip-mode tests assert against. A fresh empty dir
-    forces a real compile, making the bitwise assertions deterministic;
-    everything is restored for the rest of the suite."""
-    from jax.experimental.compilation_cache import compilation_cache as cc
-    prev = jax.config.jax_compilation_cache_dir
-    cc.reset_cache()
-    jax.config.update("jax_compilation_cache_dir",
-                      str(tmp_path / "ccache"))
-    yield
-    cc.reset_cache()
-    jax.config.update("jax_compilation_cache_dir", prev)
-
-
 def _tree_equal(a, b):
     for x, y in zip(jax.tree_util.tree_leaves(a),
                     jax.tree_util.tree_leaves(b)):

@@ -429,17 +429,19 @@ def test_collective_inventory_bytes_hand_computed():
     """One psum over a 2-device data axis: the inventory must price it at
     exactly the PER-SHARD operand aval (shard_map bodies see shard
     shapes), here (4, 4) f32 = 64 bytes."""
-    from jax.experimental.shard_map import shard_map
     mesh = build_mesh(MeshPlan(data=2))
 
     def f(x):
         return jax.lax.psum(x, "data")
 
-    sm = shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P())
+    # check_vma on (jax.shard_map's default) spells the psum
+    # `psum_invariant`; the repo's own traces (check off) emit plain psum
+    sm = jax.shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P())
     jaxpr = jax.make_jaxpr(sm)(jnp.zeros((8, 4), jnp.float32))
     inv = commscheck.collective_inventory(jaxpr)
     assert [(c["family"], c["prim"], c["axes"], c["count"], c["bytes"])
-            for c in inv] == [("all_reduce", "psum2", ["data"], 1, 64)]
+            for c in inv] == [("all_reduce", "psum_invariant", ["data"],
+                               1, 64)]
 
 
 def test_collective_inventory_scan_weighting():

@@ -1,0 +1,129 @@
+"""Everything BENCHMARK.json names resolves to a file, and a cell, a
+configuration, a mix, a runner kind, a per-layer metric and a reader are
+each one new file plus one new entry."""
+
+import json
+import os
+import re
+
+import pytest
+
+from benchmark.lib import harness
+
+BENCH = harness.load_benchmark()
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
+def test_every_cell_resolves(cell):
+    res = harness.resolve_cell(BENCH, cell)
+    assert hasattr(res["runner"], "run")
+    assert res["config"]["llm_config"]
+    assert res["traffic"]["why"]
+    e2e = [m["name"] for m in harness.metrics_of_cell(BENCH, "end_to_end",
+                                                      cell)]
+    assert "setup_s" in e2e and len(e2e) >= 2
+    layer = harness.metrics_of_cell(BENCH, "per_layer", cell)
+    assert layer
+    for m in layer:
+        assert m["moves"] in e2e, (m["name"], m["moves"])
+
+
+@pytest.mark.parametrize("metric", [m["name"] for m in BENCH["per_layer"]])
+def test_every_layer_metric_resolves(metric):
+    spec, reader = harness.load_layer_metric(metric)
+    entry = next(m for m in BENCH["per_layer"] if m["name"] == metric)
+    assert callable(reader.read)
+    for key in ("layer", "unit", "moves"):
+        assert spec[key] == entry[key], key
+    # the file's kinds and the entry's cells say the same thing
+    kinds = {harness.resolve_cell(BENCH, c)["traffic"]["kind"]
+             for c in entry.get("workloads",
+                                [w["name"] for w in BENCH["workloads"]])}
+    assert kinds <= set(spec["kinds"])
+    assert reader.read({}, spec.get("args", {})) is None   # nothing to read
+
+
+def test_contract_shape():
+    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+                          "workloads", "end_to_end", "per_layer"}
+    names = [m["name"] for g in ("end_to_end", "per_layer")
+             for m in BENCH[g]]
+    names += [w["name"] for w in BENCH["workloads"]]
+    names += [c["name"] for c in BENCH["configs"]]
+    assert len(names) == len(set(names))
+    for n in names:
+        assert NAME.match(n), n
+    for m in BENCH["end_to_end"]:
+        assert 0 < m["bound"] <= 0.1
+        assert m["source"] in ("host_clock", "device_trace")
+    for m in BENCH["per_layer"]:
+        assert set(m) <= {"name", "unit", "better", "source", "layer",
+                          "moves", "workloads"}
+        assert "_roofline" not in m["name"] or m["unit"] == "%"
+    four = [w for w in BENCH["workloads"] if w["chips"] == 4]
+    assert len(four) <= max(1, len(BENCH["workloads"]) // 4)
+    for w in BENCH["workloads"]:
+        assert len(w["why"]) <= 200
+    for c in BENCH["configs"]:
+        assert c["file"].startswith(tuple(p + "/" for p in BENCH["paths"]))
+    assert os.path.getsize(os.path.join(harness.ROOT,
+                                        "BENCHMARK.json")) < 64 * 1024
+
+
+def test_missing_pieces_name_their_path(tmp_path):
+    with pytest.raises(harness.Unresolved, match="no workload"):
+        harness.resolve_cell(BENCH, "no_such_cell")
+    bench = json.loads(json.dumps(BENCH))
+    bench["workloads"].append({"name": "x", "config": bench["configs"][0]
+                               ["name"], "traffic": "no_such_mix",
+                               "chips": 1, "why": "test"})
+    with pytest.raises(harness.Unresolved,
+                       match=r"benchmark/traffic/no_such_mix\.json"):
+        harness.resolve_cell(bench, "x")
+    with pytest.raises(harness.Unresolved,
+                       match=r"benchmark/layer_metrics/nope\.json"):
+        harness.load_layer_metric("nope")
+
+
+def test_new_pieces_are_new_files_only(tmp_path, monkeypatch):
+    """A mix of a new kind with a new per-layer metric read by a new
+    reader: five new files and three new entries, no edit to a file that
+    is there."""
+    made = []
+
+    def put(rel, text):
+        path = os.path.join(harness.BENCH_DIR, rel)
+        assert not os.path.exists(path)
+        with open(path, "w") as f:
+            f.write(text)
+        made.append(path)
+
+    try:
+        put("traffic/zz_test_mix.json",
+            json.dumps({"kind": "zz_test_kind", "why": "resolution test"}))
+        put("runners/zz_test_kind.py",
+            "def run(ctx):\n    return {'observations': {'zz': 4.0}}\n")
+        put("layer_metrics/zz_test_metric.json",
+            json.dumps({"name": "zz_test_metric", "layer": "device",
+                        "unit": "ms", "moves": "setup_s",
+                        "kinds": ["zz_test_kind"], "reader": "zz_test_reader",
+                        "args": {"key": "zz"}}))
+        put("readers/zz_test_reader.py",
+            "def read(obs, args):\n    return obs.get(args['key'])\n")
+        bench = json.loads(json.dumps(BENCH))
+        bench["workloads"].append(
+            {"name": "zz_cell", "config": bench["configs"][0]["name"],
+             "traffic": "zz_test_mix", "chips": 1, "why": "test"})
+        bench["per_layer"].append(
+            {"name": "zz_test_metric", "unit": "ms", "better": "lower",
+             "source": "host_clock", "layer": "device", "moves": "setup_s",
+             "workloads": ["zz_cell"]})
+        res = harness.resolve_cell(bench, "zz_cell")
+        out = res["runner"].run({})
+        got = harness.read_layer_metrics(bench, "zz_cell",
+                                         out["observations"])
+        assert got == {"zz_test_metric": {"value": 4.0, "unit": "ms"}}
+    finally:
+        for path in made:
+            os.remove(path)

@@ -118,6 +118,7 @@ from distributed_pytorch_tpu.models.generate import sample_token
 from distributed_pytorch_tpu.models.gpt import init_paged_cache
 from distributed_pytorch_tpu.obs.flight import FlightRecorder
 from distributed_pytorch_tpu.obs.retrace import TraceGuard
+from distributed_pytorch_tpu.obs.trace import phase
 from distributed_pytorch_tpu.ops import kv_tier
 from distributed_pytorch_tpu.ops.block_pool import (BlockPool, NoFreeBlocks,
                                                     _child_digest, chain_keys)
@@ -146,14 +147,15 @@ def make_step_fn(model, sample_fn, *, on_trace=None):
         if on_trace is not None:
             on_trace()  # trace-time side effect
         from distributed_pytorch_tpu.ops.quant import use_quantized_params
-        with use_quantized_params(qparams):
+        with use_quantized_params(qparams), jax.named_scope("decode"):
             # quantized weights (when a store is active): decode
             # matmuls read int8 codes instead of the bf16 kernels —
             # the unused bf16 leaves are pruned from the compiled step
             logits, _, caches = model.apply(
                 variables, tok[:, None], None, caches, pos,
                 deterministic=True, block_tables=bt)
-        nxt = sample_fn(logits[:, -1, :], jax.random.fold_in(rng, t))
+        with jax.named_scope("sample"):
+            nxt = sample_fn(logits[:, -1, :], jax.random.fold_in(rng, t))
         # dead slots: freeze the token and position (their table row is
         # zeroed, so the write lands in the null block — nothing reads
         # it, no cleanup needed)
@@ -186,17 +188,20 @@ def make_fused_step_fn(model, sample_fn, n_slots: int, table_width: int,
         # weight-only int8.
         bt_row = jax.lax.dynamic_slice(
             bt, (cslot, jnp.int32(0)), (1, W))
-        clogits, _, caches = model.apply(
-            variables, ctoks, None, caches, coff, deterministic=True,
-            logits_idx=clen - 1, block_tables=bt_row)
-        first = sample_fn(clogits[:, -1, :],
-                          jax.random.fold_in(rng, 2 ** 21 + t))
+        with jax.named_scope("chunk_prefill"):
+            clogits, _, caches = model.apply(
+                variables, ctoks, None, caches, coff, deterministic=True,
+                logits_idx=clen - 1, block_tables=bt_row)
+        with jax.named_scope("sample"):
+            first = sample_fn(clogits[:, -1, :],
+                              jax.random.fold_in(rng, 2 ** 21 + t))
         from distributed_pytorch_tpu.ops.quant import use_quantized_params
-        with use_quantized_params(qparams):
+        with use_quantized_params(qparams), jax.named_scope("decode"):
             logits, _, caches = model.apply(
                 variables, tok[:, None], None, caches, pos,
                 deterministic=True, block_tables=bt)
-        nxt = sample_fn(logits[:, -1, :], jax.random.fold_in(rng, t))
+        with jax.named_scope("sample"):
+            nxt = sample_fn(logits[:, -1, :], jax.random.fold_in(rng, t))
         # dead/parked slots freeze their token; parked positions point
         # at the null block so the decode write above was harmless
         nxt = jnp.where(live, nxt, tok)
@@ -231,7 +236,8 @@ def make_admit_fn(model, sample_fn, *, on_trace=None):
             variables, prompt, None, caches, prefix_len,
             deterministic=True, logits_idx=true_len - 1,
             block_tables=bt_row)
-        first = sample_fn(logits[:, -1, :], rng)
+        with jax.named_scope("sample"):
+            first = sample_fn(logits[:, -1, :], rng)
         tok = tok.at[slot].set(first[0])
         pos = pos.at[slot].set(prefix_len + true_len[0])
         live = live.at[slot].set(True)
@@ -285,7 +291,7 @@ def make_spec_step_fn(model, sample_fn, spec_k: int, *, on_trace=None):
             on_trace()  # trace-time side effect
         from distributed_pytorch_tpu.ops.quant import use_quantized_params
         seq = jnp.concatenate([tok[:, None], draft], axis=1)  # (B, K+1)
-        with use_quantized_params(qparams):
+        with use_quantized_params(qparams), jax.named_scope("decode"):
             logits, _, caches = model.apply(
                 variables, seq, None, caches, pos, deterministic=True,
                 block_tables=bt, all_logits=True)          # (B, K+1, V)
@@ -294,8 +300,9 @@ def make_spec_step_fn(model, sample_fn, spec_k: int, *, on_trace=None):
         # greedy targets at every position, through the SAME sample_fn as
         # the plain step (argmax at temperature 0 — rng is ignored, so
         # the fold_in choice cannot perturb parity)
-        g = sample_fn(logits.reshape(B * (K + 1), V),
-                      jax.random.fold_in(rng, t)).reshape(B, K + 1)
+        with jax.named_scope("sample"):
+            g = sample_fn(logits.reshape(B * (K + 1), V),
+                          jax.random.fold_in(rng, t)).reshape(B, K + 1)
         # accept length: longest draft prefix matching the targets,
         # masked to each slot's valid draft length
         valid = jnp.arange(K)[None, :] < draft_len[:, None]
@@ -1222,75 +1229,79 @@ class DecodeEngine:
         keeps the admission-bound contract), and the prompt is chunked
         into subsequent fused steps — `first_token` is None and arrives
         via `StepResult.emitted` when the last chunk runs."""
-        free = self.free_slots
-        assert free, "no free slot — step()/retire before admitting"
-        assert max_new_tokens >= 1
-        slot = free[0]
-        toks = [int(t) for t in prompt]
-        # keep at least one free cache row to decode into
-        toks = toks[-(self.max_len - 1):]
-        L = len(toks)
-        bs = self.block_size
-        prefix_len, matched = self._match_prefix(toks)
-        if self.prefill_chunk:
-            return self._admit_chunked(slot, toks, L, prefix_len, matched,
-                                       max_new_tokens, seq_id)
-        suffix = toks[prefix_len:]
-        bucket = min(self.prefill_bucket(len(suffix)),
-                     self.max_len - prefix_len)
-        # matched blocks arrive referenced from the tier-aware walk
-        # (alloc below may evict from the LRU, and a matched block must
-        # not be the one evicted — or demoted)
-        new_ids = self.block_pool.alloc_many(bucket // bs)
-        if new_ids is None:
-            self.block_pool.release_all(matched)
-            raise NoFreeBlocks(
-                f"pool exhausted: {self.block_pool.n_referenced} of "
-                f"{self.block_pool.capacity} blocks referenced by "
-                f"{self.n_live} live sequences; admit after a retirement")
-        blocks = matched + new_ids
-        self._tables_h[slot, :] = 0
-        self._tables_h[slot, :len(blocks)] = blocks
-        self._tables_dirty = True
-        self._sync_tables()
+        with phase("engine.admit",
+                   chunked=int(self.prefill_chunk > 0)) as admitting:
+            free = self.free_slots
+            assert free, "no free slot — step()/retire before admitting"
+            assert max_new_tokens >= 1
+            slot = free[0]
+            toks = [int(t) for t in prompt]
+            # keep at least one free cache row to decode into
+            toks = toks[-(self.max_len - 1):]
+            L = len(toks)
+            bs = self.block_size
+            prefix_len, matched = self._match_prefix(toks)
+            if self.prefill_chunk:
+                return self._admit_chunked(slot, toks, L, prefix_len,
+                                           matched, max_new_tokens, seq_id)
+            suffix = toks[prefix_len:]
+            bucket = min(self.prefill_bucket(len(suffix)),
+                         self.max_len - prefix_len)
+            admitting.set(bucket=bucket)
+            # matched blocks arrive referenced from the tier-aware walk
+            # (alloc below may evict from the LRU, and a matched block must
+            # not be the one evicted — or demoted)
+            new_ids = self.block_pool.alloc_many(bucket // bs)
+            if new_ids is None:
+                self.block_pool.release_all(matched)
+                raise NoFreeBlocks(
+                    f"pool exhausted: {self.block_pool.n_referenced} of "
+                    f"{self.block_pool.capacity} blocks referenced by "
+                    f"{self.n_live} live sequences; admit after a "
+                    f"retirement")
+            blocks = matched + new_ids
+            self._tables_h[slot, :] = 0
+            self._tables_h[slot, :len(blocks)] = blocks
+            self._tables_dirty = True
+            self._sync_tables()
 
-        padded = jnp.asarray(suffix + [0] * (bucket - len(suffix)),
-                             jnp.int32)[None]
-        if seq_id is None:
-            seq_id = self._next_id
-        self._next_id = max(self._next_id, seq_id) + 1
-        rng = jax.random.fold_in(self._rng, 2 ** 20 + self._n_admits)
-        self._n_admits += 1
-        with self._ctx():
-            out = self._get_admit_fn(bucket)(
-                self.variables, self.caches, self.tok, self.pos, self.live,
-                self.block_tables, padded, jnp.int32(prefix_len),
-                jnp.asarray([len(suffix)], jnp.int32),
-                jnp.int32(slot), rng)
-        self.caches, self.tok, self.pos, self.live, first = out
-        # THE admit sync boundary: the first sampled token must reach the
-        # host to stream it to the caller
-        first_tok = int(jax.device_get(first)[0])  # lint: allow(host-sync)
-        self._slots[slot] = _Slot(seq_id=seq_id, tokens=toks + [first_tok],
-                                  prompt_len=L, n_new=1,
-                                  max_new=max_new_tokens, pos=L,
-                                  blocks=blocks, order=self.n_admitted)
-        self.n_admitted += 1
-        self.prompt_tokens += L
-        self.prefix_hit_tokens += prefix_len
-        self.prefilled_tokens += len(suffix)
-        # publish the prompt's full blocks now — immutable as of this
-        # prefill — so concurrent same-prefix requests hit immediately
-        self._register_blocks(toks, L // bs, blocks)
-        # a 1-token request (or instant EOS) finishes at admission
-        retired = None
-        reason = self._retire_reason(slot, first_tok)
-        if reason is not None:
-            retired = self._retire(slot, reason)
-            self.live = self.live.at[slot].set(False)
-        return Admission(seq_id=seq_id, first_token=first_tok,
-                         retired=retired, prefix_len=prefix_len,
-                         prefilled=len(suffix))
+            padded = jnp.asarray(suffix + [0] * (bucket - len(suffix)),
+                                 jnp.int32)[None]
+            if seq_id is None:
+                seq_id = self._next_id
+            self._next_id = max(self._next_id, seq_id) + 1
+            rng = jax.random.fold_in(self._rng, 2 ** 20 + self._n_admits)
+            self._n_admits += 1
+            with self._ctx():
+                out = self._get_admit_fn(bucket)(
+                    self.variables, self.caches, self.tok, self.pos, self.live,
+                    self.block_tables, padded, jnp.int32(prefix_len),
+                    jnp.asarray([len(suffix)], jnp.int32),
+                    jnp.int32(slot), rng)
+            self.caches, self.tok, self.pos, self.live, first = out
+            # THE admit sync boundary: the first sampled token must reach the
+            # host to stream it to the caller
+            first_tok = int(jax.device_get(first)[0])  # lint: allow(host-sync)
+            self._slots[slot] = _Slot(seq_id=seq_id, tokens=toks + [first_tok],
+                                      prompt_len=L, n_new=1,
+                                      max_new=max_new_tokens, pos=L,
+                                      blocks=blocks, order=self.n_admitted)
+            self.n_admitted += 1
+            self.prompt_tokens += L
+            self.prefix_hit_tokens += prefix_len
+            self.prefilled_tokens += len(suffix)
+            # publish the prompt's full blocks now — immutable as of this
+            # prefill — so concurrent same-prefix requests hit immediately
+            self._register_blocks(toks, L // bs, blocks)
+            # a 1-token request (or instant EOS) finishes at admission
+            retired = None
+            reason = self._retire_reason(slot, first_tok)
+            if reason is not None:
+                retired = self._retire(slot, reason)
+                self.live = self.live.at[slot].set(False)
+            return Admission(seq_id=seq_id, first_token=first_tok,
+                             retired=retired, prefix_len=prefix_len,
+                             prefilled=len(suffix))
 
     def _admit_chunked(self, slot: int, toks: list, L: int,
                        prefix_len: int, matched: list, max_new_tokens: int,
@@ -1468,47 +1479,63 @@ class DecodeEngine:
         them)."""
         if not self._slots:
             return StepResult({}, {})
+        # the step's four host phases (obs/trace.py PHASES): leaves in the
+        # profiler's trace, joined by `step`, and the split of step_ms in
+        # the flight record, from the same stamps
+        step = self._t
+        acc: dict = {}
         t_step0 = time.perf_counter()
-        preempted = self._ensure_blocks()
-        chunk = self._next_chunk(preempted) if self.prefill_chunk else None
-        if not self._slots or (chunk is None and not self._live_slots()):
-            return StepResult({}, preempted)
-        n_live_in = len(self._live_slots())    # decoding slots this step
-        # speculative drafting happens BEFORE the table sync (it may grow
-        # block lists to cover accepted rows); a chunked step never
-        # speculates — the chunk already owns the step's spare compute
-        spec = None
-        if self.spec_decode and chunk is None:
-            spec = self._spec_drafts()
-        self._sync_tables()
-        chunk_done = False
-        if chunk is not None:
-            slot_c, take = chunk
-            seq_c = self._slots[slot_c]
-            off = seq_c.prefix_len + seq_c.suffix_done
-            chunk_done = seq_c.suffix_done + take == len(seq_c.suffix)
-            buf = seq_c.suffix[seq_c.suffix_done:seq_c.suffix_done + take]
-            padded = jnp.asarray(
-                buf + [0] * (self.prefill_chunk - take), jnp.int32)[None]
-            with self._ctx():
+        with phase("engine.prepare", acc, step=step):
+            preempted = self._ensure_blocks()
+            chunk = self._next_chunk(preempted) if self.prefill_chunk \
+                else None
+            if not self._slots or (chunk is None
+                                   and not self._live_slots()):
+                return StepResult({}, preempted)
+            n_live_in = len(self._live_slots())  # decoding slots this step
+            # speculative drafting happens BEFORE the table sync (it may
+            # grow block lists to cover accepted rows); a chunked step
+            # never speculates — the chunk already owns the step's spare
+            # compute
+            spec = None
+            if self.spec_decode and chunk is None:
+                spec = self._spec_drafts()
+            self._sync_tables()
+            chunk_done = False
+            prefill_tokens = 0
+            if chunk is not None:
+                slot_c, take = chunk
+                prefill_tokens = take
+                seq_c = self._slots[slot_c]
+                off = seq_c.prefix_len + seq_c.suffix_done
+                chunk_done = seq_c.suffix_done + take == len(seq_c.suffix)
+                buf = seq_c.suffix[seq_c.suffix_done:
+                                   seq_c.suffix_done + take]
+                padded = jnp.asarray(
+                    buf + [0] * (self.prefill_chunk - take),
+                    jnp.int32)[None]
+        kind = ("fused" if chunk is not None
+                else "spec" if spec is not None else "decode")
+        with phase("engine.dispatch", acc, step=step, kind=kind,
+                   n_live=n_live_in, prefill_tokens=prefill_tokens), \
+                self._ctx():
+            if chunk is not None:
                 out = self._get_fused_step_fn()(
                     self.variables, self.caches, self.tok, self.pos,
                     self.live, self.block_tables, self._rng,
                     jnp.int32(self._t), self._qparams, padded,
                     jnp.int32(slot_c), jnp.int32(off),
                     jnp.asarray([take], jnp.int32), jnp.bool_(chunk_done))
-            self.caches, self.tok, self.pos, self.live = out
-        elif spec is not None:
-            draft_h, dlen_h = spec
-            with self._ctx():
+                self.caches, self.tok, self.pos, self.live = out
+            elif spec is not None:
+                draft_h, dlen_h = spec
                 out = self._get_spec_step_fn()(
                     self.variables, self.caches, self.tok, self.pos,
                     self.live, self.block_tables, self._rng,
                     jnp.int32(self._t), self._qparams,
                     jnp.asarray(draft_h), jnp.asarray(dlen_h))
-            self.caches, self.tok, self.pos, acc_dev = out
-        else:
-            with self._ctx():
+                self.caches, self.tok, self.pos, acc_dev = out
+            else:
                 self.caches, self.tok, self.pos = self._get_step_fn()(
                     self.variables, self.caches, self.tok, self.pos,
                     self.live, self.block_tables, self._rng,
@@ -1517,86 +1544,97 @@ class DecodeEngine:
         # THE step sync boundary: every slot's sampled token drains to the
         # host once per fused step (plus the per-slot accept lengths on a
         # speculative step — one transfer, not two)
-        if spec is not None:
-            sampled, accepted_h = \
-                jax.device_get((self.tok, acc_dev))  # lint: allow(host-sync)
-        else:
-            sampled = jax.device_get(self.tok)  # lint: allow(host-sync)
-        emitted: dict[int, list] = {}
-        retired: dict[int, Retired] = dict(preempted)
-        prefill_tokens = 0
-        drafted = accepted = 0
-        if chunk is not None:
-            # host mirror of the chunk: progress the partial, publish the
-            # blocks that just became full+immutable into the radix index
-            # (register is first-writer-wins, so re-publishing earlier
-            # ones is a no-op), and — on the final chunk — promote the
-            # slot to live with its first sampled token, exactly where a
-            # wave admit would have left it
-            prefill_tokens = take
-            seq_c.suffix_done += take
-            seq_c.pos = seq_c.prefix_len + seq_c.suffix_done
-            self.prefilled_tokens += take
-            full = min(seq_c.pos, len(seq_c.blocks) * self.block_size) \
-                // self.block_size
-            self._register_blocks(seq_c.tokens, full, seq_c.blocks)
-            if chunk_done:
-                first_tok = int(sampled[slot_c])
-                seq_c.tokens.append(first_tok)
-                seq_c.n_new = 1
-                seq_c.pos = seq_c.prompt_len
-        for slot in list(self._slots):
-            seq = self._slots[slot]
-            if self._is_partial(seq):
-                continue                       # still parked: no token
-            nxt = int(sampled[slot])
-            if chunk is not None and slot == slot_c and chunk_done:
-                toks = [nxt]                   # bookkeeping done above
-            elif spec is not None:
-                # accepted draft prefix + the correction token, in
-                # stream order. EOS inside the accepted span ends the
-                # stream AT the EOS token: everything past it is dropped
-                # (the device pos runs ahead, but the slot retires this
-                # step and its zeroed table row makes the overshoot
-                # unreachable — the next occupant rewrites those rows
-                # before they could ever be attended)
-                acc_s = int(accepted_h[slot])
-                toks = [int(draft_h[slot, j])
-                        for j in range(acc_s)] + [nxt]
-                if self.eos_id is not None and self.eos_id in toks:
-                    toks = toks[:toks.index(self.eos_id) + 1]
-                seq.tokens.extend(toks)
-                seq.n_new += len(toks)
-                seq.pos += len(toks)
-                accepted += acc_s
+        with phase("engine.wait", acc, step=step):
+            if spec is not None:
+                sampled, accepted_h = jax.device_get(  # lint: allow(host-sync)
+                    (self.tok, acc_dev))
             else:
-                toks = [nxt]
-                seq.tokens.append(nxt)
-                seq.n_new += 1
-                seq.pos += 1
-            emitted[seq.seq_id] = toks
-            reason = self._retire_reason(slot, toks[-1])
-            if reason is not None:
-                retired[seq.seq_id] = self._retire(slot, reason)
-        # drop retired slots from the live mask (their table rows are
-        # zeroed, so any residual write lands in the null block)
-        if len(retired) > len(preempted):
-            self._rebuild_live()
-        n_emitted = sum(len(v) for v in emitted.values())
-        self.emitted_tokens += n_emitted
-        if spec is not None:
-            drafted = int(dlen_h.sum())
-            self.spec_drafted_tokens += drafted
-            self.spec_accepted_tokens += accepted
-        self.flight.record(
-            step=self._t,
-            step_ms=round((time.perf_counter() - t_step0) * 1e3, 3),
-            n_live=n_live_in, prefill_tokens=prefill_tokens,
-            emitted=n_emitted,
-            retired=len(retired) - len(preempted),
-            blocks_in_use=self.block_pool.n_referenced,
-            preemptions=len(preempted),
-            drafted=drafted, accepted=accepted)
+                sampled = jax.device_get(self.tok)  # lint: allow(host-sync)
+        with phase("engine.retire", acc, step=step) as retire:
+            emitted: dict[int, list] = {}
+            retired: dict[int, Retired] = dict(preempted)
+            drafted = accepted = 0
+            if chunk is not None:
+                # host mirror of the chunk: progress the partial, publish
+                # the blocks that just became full+immutable into the
+                # radix index (register is first-writer-wins, so
+                # re-publishing earlier ones is a no-op), and — on the
+                # final chunk — promote the slot to live with its first
+                # sampled token, exactly where a wave admit would have
+                # left it
+                seq_c.suffix_done += take
+                seq_c.pos = seq_c.prefix_len + seq_c.suffix_done
+                self.prefilled_tokens += take
+                full = min(seq_c.pos,
+                           len(seq_c.blocks) * self.block_size) \
+                    // self.block_size
+                self._register_blocks(seq_c.tokens, full, seq_c.blocks)
+                if chunk_done:
+                    first_tok = int(sampled[slot_c])
+                    seq_c.tokens.append(first_tok)
+                    seq_c.n_new = 1
+                    seq_c.pos = seq_c.prompt_len
+            for slot in list(self._slots):
+                seq = self._slots[slot]
+                if self._is_partial(seq):
+                    continue                       # still parked: no token
+                nxt = int(sampled[slot])
+                if chunk is not None and slot == slot_c and chunk_done:
+                    toks = [nxt]                   # bookkeeping done above
+                elif spec is not None:
+                    # accepted draft prefix + the correction token, in
+                    # stream order. EOS inside the accepted span ends the
+                    # stream AT the EOS token: everything past it is
+                    # dropped (the device pos runs ahead, but the slot
+                    # retires this step and its zeroed table row makes
+                    # the overshoot unreachable — the next occupant
+                    # rewrites those rows before they could ever be
+                    # attended)
+                    acc_s = int(accepted_h[slot])
+                    toks = [int(draft_h[slot, j])
+                            for j in range(acc_s)] + [nxt]
+                    if self.eos_id is not None and self.eos_id in toks:
+                        toks = toks[:toks.index(self.eos_id) + 1]
+                    seq.tokens.extend(toks)
+                    seq.n_new += len(toks)
+                    seq.pos += len(toks)
+                    accepted += acc_s
+                else:
+                    toks = [nxt]
+                    seq.tokens.append(nxt)
+                    seq.n_new += 1
+                    seq.pos += 1
+                emitted[seq.seq_id] = toks
+                reason = self._retire_reason(slot, toks[-1])
+                if reason is not None:
+                    retired[seq.seq_id] = self._retire(slot, reason)
+            # drop retired slots from the live mask (their table rows are
+            # zeroed, so any residual write lands in the null block)
+            if len(retired) > len(preempted):
+                self._rebuild_live()
+            n_emitted = sum(len(v) for v in emitted.values())
+            self.emitted_tokens += n_emitted
+            if spec is not None:
+                drafted = int(dlen_h.sum())
+                self.spec_drafted_tokens += drafted
+                self.spec_accepted_tokens += accepted
+            # `step` here counts completed steps (the phases' `step` + 1);
+            # retire_ms runs to this stamp, so the four parts sum to
+            # step_ms less the few microseconds between phases
+            t_rec = time.perf_counter()
+            self.flight.record(
+                step=self._t,
+                step_ms=round((t_rec - t_step0) * 1e3, 3),
+                prepare_ms=round(acc["engine.prepare"] * 1e3, 3),
+                dispatch_ms=round(acc["engine.dispatch"] * 1e3, 3),
+                wait_ms=round(acc["engine.wait"] * 1e3, 3),
+                retire_ms=round((t_rec - retire.t0) * 1e3, 3),
+                n_live=n_live_in, prefill_tokens=prefill_tokens,
+                emitted=n_emitted,
+                retired=len(retired) - len(preempted),
+                blocks_in_use=self.block_pool.n_referenced,
+                preemptions=len(preempted),
+                drafted=drafted, accepted=accepted)
         return StepResult(emitted=emitted, retired=retired,
                           prefill_tokens=prefill_tokens,
                           drafted=drafted, accepted=accepted)

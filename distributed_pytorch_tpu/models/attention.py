@@ -176,29 +176,32 @@ class GQA(nn.Module):
                 from distributed_pytorch_tpu.ops.quant import quantize_kv
                 k_q, k_s = quantize_kv(k)
                 v_q, v_s = quantize_kv(v)
-                k = upd(cache["k"], k_q, pos)
-                v = upd(cache["v"], v_q, pos)
-                k_scale = upd(cache["k_scale"], k_s, pos)
-                v_scale = upd(cache["v_scale"], v_s, pos)
+                with jax.named_scope("kv_update"):
+                    k = upd(cache["k"], k_q, pos)
+                    v = upd(cache["v"], v_q, pos)
+                    k_scale = upd(cache["k_scale"], k_s, pos)
+                    v_scale = upd(cache["v_scale"], v_s, pos)
                 new_cache = {"k": k, "k_scale": k_scale,
                              "v": v, "v_scale": v_scale}
             else:
-                k = upd(cache["k"], k, pos)
-                v = upd(cache["v"], v, pos)
+                with jax.named_scope("kv_update"):
+                    k = upd(cache["k"], k, pos)
+                    v = upd(cache["v"], v, pos)
                 new_cache = {"k": k, "v": v}
             q_offset = pos
 
         drop_rng = None
         if cfg.dropout > 0.0 and not deterministic:
             drop_rng = self.make_rng("dropout")
-        y = sdpa(q, k if (k_scale is not None or block_tables is not None)
-                 else k.astype(q.dtype),
-                 v if (v_scale is not None or block_tables is not None)
-                 else v.astype(q.dtype),
-                 causal=True, q_offset=q_offset, dropout_rate=cfg.dropout,
-                 dropout_rng=drop_rng, impl=self.attn_impl,
-                 decode=cache is not None, k_scale=k_scale, v_scale=v_scale,
-                 block_tables=block_tables)
+        raw = k_scale is not None or block_tables is not None
+        with jax.named_scope("attn_core"):
+            y = sdpa(q, k if raw else k.astype(q.dtype),
+                     v if raw else v.astype(q.dtype),
+                     causal=True, q_offset=q_offset,
+                     dropout_rate=cfg.dropout, dropout_rng=drop_rng,
+                     impl=self.attn_impl, decode=cache is not None,
+                     k_scale=k_scale, v_scale=v_scale,
+                     block_tables=block_tables)
         y = y.reshape(B, T, C)
         y = _OverlapDense(C, x.dtype, name="c_proj")(y)
         y = nn.Dropout(cfg.dropout, deterministic=deterministic)(y)
@@ -308,29 +311,34 @@ class NaiveMLA(nn.Module):
             drop_rng = None
             if cfg.dropout > 0.0 and not deterministic:
                 drop_rng = self.make_rng("dropout")
-            y = sdpa(q, k, v, causal=True, dropout_rate=cfg.dropout,
-                     dropout_rng=drop_rng, impl=self.attn_impl)
+            with jax.named_scope("attn_core"):
+                y = sdpa(q, k, v, causal=True, dropout_rate=cfg.dropout,
+                         dropout_rng=drop_rng, impl=self.attn_impl)
             y = y.reshape(B, T, C)
             new_cache = None
         else:
             if block_tables is not None:
                 from distributed_pytorch_tpu.ops.block_pool import (
                     paged_gather, paged_update)
-                pool = paged_update(cache["c_kv"], new_c_kv, pos,
-                                    block_tables)
+                with jax.named_scope("kv_update"):
+                    pool = paged_update(cache["c_kv"], new_c_kv, pos,
+                                        block_tables)
                 new_cache = {"c_kv": pool}
                 # absorbed decode attends the logical view; rows past each
                 # sequence's extent are causally masked to weight 0
-                c_kv = paged_gather(pool, block_tables)
+                with jax.named_scope("attn_core"):
+                    c_kv = paged_gather(pool, block_tables)
             else:
-                c_kv = _update_cache(cache["c_kv"], new_c_kv, pos)
+                with jax.named_scope("kv_update"):
+                    c_kv = _update_cache(cache["c_kv"], new_c_kv, pos)
                 new_cache = {"c_kv": c_kv}
             from distributed_pytorch_tpu.ops.quant import \
                 maybe_dequantized_param
             kuk = maybe_dequantized_param((*self.path, "W_uk"), ks["W_uk"])
             kuv = maybe_dequantized_param((*self.path, "W_uv"), ks["W_uv"])
-            y = _absorbed_decode(q, c_kv, kuk, kuv, pos,
-                                 1.0 / jnp.sqrt(float(hs)))
+            with jax.named_scope("attn_core"):
+                y = _absorbed_decode(q, c_kv, kuk, kuv, pos,
+                                     1.0 / jnp.sqrt(float(hs)))
 
         y = _qmm(self, y, ks["W_o"], "W_o")
         y = nn.Dropout(cfg.dropout, deterministic=deterministic)(y)
@@ -388,34 +396,40 @@ class FullMLA(nn.Module):
             drop_rng = None
             if cfg.dropout > 0.0 and not deterministic:
                 drop_rng = self.make_rng("dropout")
-            y = sdpa(q_cat, k_cat, v_pad, causal=True, scale=scale,
-                     dropout_rate=cfg.dropout, dropout_rng=drop_rng,
-                     impl=self.attn_impl)
+            with jax.named_scope("attn_core"):
+                y = sdpa(q_cat, k_cat, v_pad, causal=True, scale=scale,
+                         dropout_rate=cfg.dropout, dropout_rng=drop_rng,
+                         impl=self.attn_impl)
             y = y[..., :hs].reshape(B, T, C)
             new_cache = None
         else:
             if block_tables is not None:
                 from distributed_pytorch_tpu.ops.block_pool import (
                     paged_gather, paged_update)
-                ckv_pool = paged_update(cache["c_kv"], new_c_kv, pos,
-                                        block_tables)
-                kr_pool = paged_update(cache["k_r"], new_k_r, pos,
-                                       block_tables)
+                with jax.named_scope("kv_update"):
+                    ckv_pool = paged_update(cache["c_kv"], new_c_kv, pos,
+                                            block_tables)
+                    kr_pool = paged_update(cache["k_r"], new_k_r, pos,
+                                           block_tables)
                 new_cache = {"c_kv": ckv_pool, "k_r": kr_pool}
-                c_kv = paged_gather(ckv_pool, block_tables)
-                k_r = paged_gather(kr_pool, block_tables)
+                with jax.named_scope("attn_core"):
+                    c_kv = paged_gather(ckv_pool, block_tables)
+                    k_r = paged_gather(kr_pool, block_tables)
             else:
-                c_kv = _update_cache(cache["c_kv"], new_c_kv, pos)
-                k_r = _update_cache(cache["k_r"], new_k_r, pos)
+                with jax.named_scope("kv_update"):
+                    c_kv = _update_cache(cache["c_kv"], new_c_kv, pos)
+                    k_r = _update_cache(cache["k_r"], new_k_r, pos)
                 new_cache = {"c_kv": c_kv, "k_r": k_r}
-            # decoupled-rotary scores; single shared key head broadcasts
-            attn_r = jnp.einsum("btnh,bskh->bnts", q_r, k_r.astype(dt))
             from distributed_pytorch_tpu.ops.quant import \
                 maybe_dequantized_param
             kuk = maybe_dequantized_param((*self.path, "W_uk"), ks["W_uk"])
             kuv = maybe_dequantized_param((*self.path, "W_uv"), ks["W_uv"])
-            y = _absorbed_decode(q_c, c_kv, kuk, kuv, pos,
-                                 scale, extra_scores=attn_r)
+            with jax.named_scope("attn_core"):
+                # decoupled-rotary scores; single shared key head
+                # broadcasts
+                attn_r = jnp.einsum("btnh,bskh->bnts", q_r, k_r.astype(dt))
+                y = _absorbed_decode(q_c, c_kv, kuk, kuv, pos,
+                                     scale, extra_scores=attn_r)
 
         y = _qmm(self, y, ks["W_o"], "W_o")
         y = nn.Dropout(cfg.dropout, deterministic=deterministic)(y)

@@ -1,39 +1,49 @@
-"""Observability subsystem: structured request tracing, the step-level
-flight recorder, and shared on-demand device profiling.
+"""Observability: what the program was doing, on a clock that can be laid
+beside the device's.
 
-Three tools, one package (ISSUE 9):
+**One clock for program and device: the profiler's.** A `jax.profiler`
+capture (`obs.profile`: the trainer's `--profile`, a replica's
+`POST /admin/profile`, the benchmark's traced slice) stamps the device's
+ops and the host's threads on one clock. The program writes itself into
+that trace in two ways, both tabled in `obs.trace`:
 
-* `obs.trace` — a near-zero-overhead span/event recorder. Every serving
-  request carries a trace id (minted at the router, or at the replica
-  server when unfronted, propagated via the `X-Trace-Id` header) and its
-  lifecycle — router dispatch, queue wait, chunked prefill, decode,
-  failover re-dispatch, retire — lands as spans in a bounded ring,
-  exportable as Chrome-trace/Perfetto JSON or JSONL.
-* `obs.flight` — the engine's step-level flight recorder: one compact
-  record per fused step ({step_ms, n_live, prefill_tokens, emitted,
-  blocks_in_use, preemptions}) in a bounded ring, served at
-  `GET /debug/timeline` and dumpable to `runs/*.jsonl` — the post-hoc
-  tool for ITL-p99 spikes the aggregate histograms only hint at.
-* `obs.profile` — the one shared `jax.profiler` wrapper (train loop,
-  serve `POST /admin/profile`, bench legs) with a `runs/<run>/profile`
-  output convention, replacing the hardcoded train-loop trace dir.
+* **host phases** (`obs.trace.phase`, names in `PHASES`): leaf spans per
+  engine step (`engine.prepare` / `engine.dispatch` / `engine.wait` /
+  `engine.retire`, `engine.admit`), per scheduler pass (`sched.admit` /
+  `sched.emit` / `sched.idle`) and per train iteration (`train.dispatch` /
+  `train.data` / `train.sync` / `train.drain` / `train.eval` /
+  `train.ckpt`), joined by a `step` stat. A phase is a TraceMe: one atomic
+  load when no capture runs. An idle gap of the device is named after the
+  phase that covers it.
+* **named scopes** (`jax.named_scope`, names in `SCOPES`) in the compiled
+  steps where no flax module name reaches: `kv_update`, `attn_core`,
+  `lm_head`, `loss`, `optimizer`, `grad_norm`, `sample`, `chunk_prefill`,
+  `decode`. They land in every device op's name path, so device time has
+  an owner in the program's own words.
 
-The TRAINING side (ISSUE 10) builds on the same primitives:
-`train/telemetry.py` wraps a FlightRecorder ring with step-phase
-records ({it, loss, grad_norm, step_ms, data_ms, sync_ms, ckpt_ms}),
-a Prometheus registry on serve/metrics.py machinery, the loss/grad
-anomaly monitor, and an opt-in live HTTP endpoint — dumped to
-`runs/<run>/train_timeline.jsonl` like the serve legs' timelines.
+`scripts/profile_step.py --analyze_only --trace_dir <dir>` and the
+benchmark's per-layer metrics read both back with one reduction
+(benchmark/lib/trace_reduce.py, trace_spans.py).
 
-The FLEET side (ISSUE 14) closes the loop across processes:
+**The always-on recorders keep their own clocks, and take their numbers
+from the same stamps.** They answer questions a capture is too short for:
 
-* `obs.slo` — declarative SLO targets (TTFT/ITL p99, availability)
-  with multi-window burn rates and error-budget gauges, computed from
-  the router's federated metrics and exported on its `/metrics`.
-* `obs.replay` — the deterministic read side of every recorder: loads
-  any `runs/<run>/` timeline set, computes per-phase distributions,
-  fits the PERF.md latency models, and emits `report.md` +
-  `cost_model.json` (the trace-replay simulator's cost tables).
+* `obs.flight` — one compact record per engine step in a bounded ring
+  (`step_ms` and its split `prepare_ms` / `dispatch_ms` / `wait_ms` /
+  `retire_ms` from the phases' `perf_counter` stamps, `n_live`,
+  `prefill_tokens`, ...), on a wall-anchored monotonic clock, served at
+  `GET /debug/timeline`. `train/telemetry.py` wraps the same ring for the
+  trainer (`step_ms`, `data_ms`, `dispatch_ms`, `sync_ms`, `ckpt_ms`),
+  dumped to `runs/<run>/train_timeline.jsonl`.
+* `obs.trace.TraceRecorder` — per-REQUEST spans on `perf_counter` (router
+  dispatch, queue wait, prefill, decode, failover, retire) under an
+  `X-Trace-Id`, recorded at terminal events only, exportable as
+  Chrome-trace/Perfetto JSON or JSONL.
+* `obs.retrace`, `obs.paths` — trace-count guards and which path a
+  compiled program took.
+* `obs.slo` — SLO targets with multi-window burn rates from the router's
+  federated metrics. `obs.replay` is the offline read side of the
+  recorders' dumps (timeline fits, cost tables for sim/fleetsim.py).
 """
 
 from distributed_pytorch_tpu.obs.flight import FlightRecorder

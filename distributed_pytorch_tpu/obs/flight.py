@@ -3,8 +3,10 @@
 The serving histograms (serve/metrics.py) answer "what were the
 quantiles"; they cannot answer "what happened around 14:03:07 when ITL
 p99 spiked". The flight recorder can: every fused step appends one
-compact record — `{step, step_ms, n_live, prefill_tokens, emitted,
-blocks_in_use, preemptions}` — to a bounded ring, so the last few
+compact record — `{step, step_ms, prepare_ms, dispatch_ms, wait_ms,
+retire_ms, n_live, prefill_tokens, emitted, blocks_in_use, preemptions}`
+(the four parts of step_ms are the engine's host phases, obs/trace.py) —
+to a bounded ring, so the last few
 thousand steps are always reconstructable, at the cost of one dict
 append per multi-millisecond device step. Served live at
 `GET /debug/timeline` (serve/server.py) and dumped to `runs/*.jsonl` by

@@ -19,9 +19,12 @@ entry points:
   executor thread; `jax.profiler` is process-global, so one capture at a
   time — concurrent requests get a clean `ProfilerBusy`).
 
-Open a capture with Perfetto (ui.perfetto.dev -> Open trace file on the
-`.xplane.pb` via xprof, or `scripts/profile_step.py --analyze_only
---trace_dir <dir>` for the terminal op-time table).
+A capture holds the device's ops and, on the same clock, the program's
+host phases and named scopes (obs/trace.py PHASES, SCOPES). Read it with
+`scripts/profile_step.py --analyze_only --trace_dir <dir>`: busy/idle, op
+time, device time by scope and idle time by phase, through the benchmark's
+reduction (benchmark/lib/trace_reduce.py, trace_spans.py); or open the
+`.xplane.pb` with xprof / Perfetto.
 """
 
 from __future__ import annotations

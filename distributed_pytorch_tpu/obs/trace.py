@@ -23,6 +23,15 @@ serve hot path must be indistinguishable from the recorder compiled out):
 A span is a plain dict:
     {"trace": id, "span": n, "parent": n|None, "name": str, "cat": str,
      "t0": perf_counter_seconds, "dur": seconds, "attrs": {...}}
+
+The recorder above follows REQUESTS, on `perf_counter`. What the PROGRAM
+was doing — which part of an engine step, scheduler pass or train
+iteration the host was in, which region of a compiled step a device op
+belongs to — goes into the profiler's own trace, on the clock the profiler
+stamps the device with: `phase` (host, `PHASES`) and the `jax.named_scope`
+names of `SCOPES` (device), both at the end of this file. The benchmark's
+readers (benchmark/lib/trace_spans.py) and `scripts/profile_step.py
+--analyze_only` read them back by these names.
 """
 
 from __future__ import annotations
@@ -34,6 +43,8 @@ import threading
 import time
 import uuid
 from typing import Optional
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 TRACE_HEADER = "X-Trace-Id"
 
@@ -258,3 +269,104 @@ def set_recorder(rec: TraceRecorder) -> TraceRecorder:
     global _default
     _default = rec
     return rec
+
+
+# ----------------------------------------------------------------------
+# the program in the profiler's trace: host phases and device scopes
+# ----------------------------------------------------------------------
+
+#: Host phases. LEAVES: contiguous on their thread, never nested in one
+#: another, no enclosing "whole step" span (an idle gap of the device is
+#: named after the host event that covers most of it, and an enclosing
+#: span would take every gap and say nothing). A step is recovered from
+#: the phases' shared `step` stat. Per engine step, scheduler pass or
+#: train iteration; never per token or per request.
+PHASES = {
+    # engine executor thread, DecodeEngine.step, in this order
+    "engine.prepare": "block growth/preemption, chunk pick, drafts, "
+                      "table sync, chunk padding",
+    "engine.dispatch": "the jitted step call (enqueue; step marker)",
+    "engine.wait": "device_get of the sampled tokens",
+    "engine.retire": "host bookkeeping up to and including flight.record",
+    # engine executor thread, DecodeEngine.admit
+    "engine.admit": "one admission: prefix match, blocks, wave prefill "
+                    "(stat bucket) or chunked bookkeeping (stat chunked)",
+    # scheduler event loop, Scheduler._run only
+    "sched.admit": "cancellations, shedding, class preemption, the "
+                   "admission wave with its executor hop, tier/AOT sync",
+    "sched.emit": "a step's result fanned out to the handles, retire "
+                  "and requeue handling",
+    "sched.idle": "parked on the wake event: no live and no queued work",
+    # trainer main thread, train()
+    "train.dispatch": "the jitted train_step call (enqueue; step marker)",
+    "train.data": "next_batch: the host's fetch of the next batch",
+    "train.sync": "device_get of the window's queued step metrics",
+    "train.drain": "anomaly monitor, telemetry records, the log line",
+    "train.eval": "estimate_loss at an eval boundary",
+    "train.ckpt": "the interval checkpoint's synchronous part",
+}
+
+#: The phases that launch a step's device work: opened as a
+#: StepTraceAnnotation (`step_num` = the phase's `step`), so XProf groups
+#: the device work they enqueue under that step.
+STEP_PHASES = frozenset({"engine.dispatch", "train.dispatch"})
+
+#: `jax.named_scope` names inside the compiled steps, where no flax
+#: module name reaches (modules name themselves: `block_<i>/attn`,
+#: `block_<i>/mlp`, `ln_f`, `tkn_emb`). They land in every op's `op_name`;
+#: the backward pass carries them as `transpose(jvp(<scope>))`. None may
+#: contain a kernel's name (`paged_flash_decode`, `flash_*`): the
+#: benchmark finds kernels by pattern on the op's name.
+SCOPES = {
+    "kv_update": "every KV-cache write (models/attention.py)",
+    "attn_core": "the attention core: sdpa, or gather + absorbed decode "
+                 "for MLA (models/attention.py)",
+    "lm_head": "the inference logits matmul (models/gpt.py)",
+    "loss": "the cross-entropy branch, head matmul included, every "
+            "loss_impl (models/gpt.py)",
+    "optimizer": "tx.update + apply_updates (train/step.py)",
+    "grad_norm": "optax.global_norm of the gradients (train/step.py)",
+    "sample": "each sample_fn call of the engine's programs",
+    "chunk_prefill": "the chunk's model.apply in fused_step",
+    "decode": "the decode model.apply in step, fused_step, spec_step",
+}
+
+
+class phase:
+    """One host phase: a TraceMe on the profiler's clock (one atomic load
+    when no profile runs; its stats are encoded only while one does) whose
+    `perf_counter` duration is also added to `acc[name]` (seconds), the
+    per-step accumulator the caller hands to its flight record.
+
+    >>> acc = {}
+    >>> with phase("engine.wait", acc, step=7):
+    ...     sampled = jax.device_get(tok)
+    >>> acc["engine.wait"]
+    """
+
+    __slots__ = ("name", "acc", "t0", "_ann")
+
+    def __init__(self, name: str, acc: Optional[dict] = None, **stats):
+        self.name = name
+        self.acc = acc
+        if name in STEP_PHASES:
+            self._ann = StepTraceAnnotation(name, step_num=stats["step"],
+                                            **stats)
+        else:
+            self._ann = TraceAnnotation(name, **stats)
+
+    def set(self, **stats) -> None:
+        """Stats known only inside the phase (an admission's bucket)."""
+        self._ann.set_metadata(**stats)
+
+    def __enter__(self) -> "phase":
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        dt = time.perf_counter() - self.t0
+        self._ann.__exit__(*exc)
+        if self.acc is not None:
+            self.acc[self.name] = self.acc.get(self.name, 0.0) + dt
+        return False

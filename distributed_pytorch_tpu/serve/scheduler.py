@@ -799,18 +799,27 @@ class Scheduler:
     async def _run(self) -> None:
         loop = asyncio.get_running_loop()
         try:
+            # the loop's phases (obs/trace.py PHASES) are leaves on this
+            # thread: the awaited engine step lies between sched.admit
+            # and sched.emit (the engine's own phases cover it, on the
+            # executor thread), and the yield to the clients after
+            # sched.emit stays unspanned — the hole is what it is
             while True:
-                now = time.perf_counter()
-                self._apply_cancellations()
-                self._shed_expired(now)
-                if self._stopping:
-                    break
-                # class preemption BEFORE admission: evicted batch slots
-                # free up for the interactive backlog in this same pass
-                await self._preempt_for_interactive(loop)
-                await self._admit_wave(loop)
-                self._tier_sync()      # admits demote (preempt) + promote
-                self._aot_sync()       # admits can build fresh buckets
+                with obs_trace.phase("sched.admit",
+                                     queued=len(self._queue),
+                                     live=len(self._live)):
+                    now = time.perf_counter()
+                    self._apply_cancellations()
+                    self._shed_expired(now)
+                    if self._stopping:
+                        break
+                    # class preemption BEFORE admission: evicted batch
+                    # slots free up for the interactive backlog in this
+                    # same pass
+                    await self._preempt_for_interactive(loop)
+                    await self._admit_wave(loop)
+                    self._tier_sync()  # admits demote (preempt) + promote
+                    self._aot_sync()   # admits can build fresh buckets
                 if not self._live:
                     if not self._queue:        # idle: park until work
                         self._wake.clear()
@@ -818,7 +827,9 @@ class Scheduler:
                         # have landed between the test and the clear)
                         if not self._queue and not self._cancel_live \
                                 and not self._stopping:
-                            await self._wake.wait()
+                            with obs_trace.phase("sched.idle", queued=0,
+                                                 live=0):
+                                await self._wake.wait()
                     continue
                 # admissions may have taken a while — free freshly
                 # cancelled slots before paying for a step
@@ -828,50 +839,57 @@ class Scheduler:
                 self.metrics.observe_occupancy(self.engine.occupancy)
                 res = await loop.run_in_executor(self._exec,
                                                  self.engine.step)
-                now = time.perf_counter()
-                self._tier_sync()      # steps demote via _ensure_blocks
-                self._aot_sync()       # first step builds its program
-                if getattr(self.engine, "prefill_chunk", 0):
-                    # per-step chunk budget use: the chunk-size tuning
-                    # signal (p50 ~ budget => prefill-bound, ~0 => slack)
-                    self.metrics.prefill_tokens_per_step.observe(
-                        res.prefill_tokens)
-                if res.drafted:
-                    # speculative-decoding ledger: acceptance rate is
-                    # accepted/drafted; the spec bench leg pins it > 0
-                    self.metrics.inc("spec_drafted_tokens", res.drafted)
-                    self.metrics.inc("spec_accepted_tokens", res.accepted)
-                for sid, toks in res.emitted.items():
-                    req = self._live.get(sid)
-                    if req is None:            # cancelled mid-flight
-                        continue
-                    # a spec step emits a LIST (accepted prefix + the
-                    # correction token); fanning them out one at a time
-                    # preserves stream order and the served-count/TTFT
-                    # bookkeeping (first-ever token is still the TTFT;
-                    # later tokens in the same step are ~0 ITL samples)
-                    for tok in toks:
-                        self._emit_token(req, tok, now)
-                requeued: list[_Request] = []
-                for sid, ret in res.retired.items():
-                    req = self._live.pop(sid, None)
-                    if req is None:
-                        continue
-                    if ret.reason == "preempted":
-                        if self._requeue_preempted(req, ret):
-                            req.preempted_at = now
-                            requeued.append(req)
-                    else:
-                        self._finish(req, ret, now)
-                # front of the request's CLASS section, original order: a
-                # preempted request outranks everything of its class that
-                # arrived after it, but a preempted batch request never
-                # jumps a waiting interactive one
-                for req in requeued:
-                    idx = ClassPolicy.insert_index(self._queue,
-                                                   req.slo_class,
-                                                   resumed=True)
-                    self._queue.insert(idx, req)
+                with obs_trace.phase("sched.emit",
+                                     queued=len(self._queue),
+                                     live=len(self._live)):
+                    now = time.perf_counter()
+                    self._tier_sync()  # steps demote via _ensure_blocks
+                    self._aot_sync()   # first step builds its program
+                    if getattr(self.engine, "prefill_chunk", 0):
+                        # per-step chunk budget use: the chunk-size
+                        # tuning signal (p50 ~ budget => prefill-bound,
+                        # ~0 => slack)
+                        self.metrics.prefill_tokens_per_step.observe(
+                            res.prefill_tokens)
+                    if res.drafted:
+                        # speculative-decoding ledger: acceptance rate is
+                        # accepted/drafted; the spec bench leg pins it > 0
+                        self.metrics.inc("spec_drafted_tokens",
+                                         res.drafted)
+                        self.metrics.inc("spec_accepted_tokens",
+                                         res.accepted)
+                    for sid, toks in res.emitted.items():
+                        req = self._live.get(sid)
+                        if req is None:        # cancelled mid-flight
+                            continue
+                        # a spec step emits a LIST (accepted prefix + the
+                        # correction token); fanning them out one at a
+                        # time preserves stream order and the
+                        # served-count/TTFT bookkeeping (first-ever token
+                        # is still the TTFT; later tokens in the same
+                        # step are ~0 ITL samples)
+                        for tok in toks:
+                            self._emit_token(req, tok, now)
+                    requeued: list[_Request] = []
+                    for sid, ret in res.retired.items():
+                        req = self._live.pop(sid, None)
+                        if req is None:
+                            continue
+                        if ret.reason == "preempted":
+                            if self._requeue_preempted(req, ret):
+                                req.preempted_at = now
+                                requeued.append(req)
+                        else:
+                            self._finish(req, ret, now)
+                    # front of the request's CLASS section, original
+                    # order: a preempted request outranks everything of
+                    # its class that arrived after it, but a preempted
+                    # batch request never jumps a waiting interactive one
+                    for req in requeued:
+                        idx = ClassPolicy.insert_index(self._queue,
+                                                       req.slo_class,
+                                                       resumed=True)
+                        self._queue.insert(idx, req)
                 # one cooperative yield so consumers drain between steps
                 await asyncio.sleep(0)
         except Exception as exc:               # crash guard: error, not hang

@@ -39,6 +39,7 @@ from distributed_pytorch_tpu.config import LLMConfig, TrainConfig
 from distributed_pytorch_tpu.data.loader import DataLoader, make_synthetic_bin
 from distributed_pytorch_tpu.models.gpt import count_params
 from distributed_pytorch_tpu.obs import paths as obs_paths
+from distributed_pytorch_tpu.obs.trace import phase
 from distributed_pytorch_tpu.parallel import sharding as shd
 from distributed_pytorch_tpu.parallel.mesh import mesh_for
 from distributed_pytorch_tpu.train import checkpoint as ckpt
@@ -610,7 +611,10 @@ def train(model_cfg: LLMConfig, train_cfg: TrainConfig,
             f"{prog.get('argument_bytes', 0) / 2 ** 30:.2f} GiB")
     pending: list = []                         # metric futures since last sync
     win_t0 = time.perf_counter()
-    win_data_s = 0.0                           # host batch-fetch time this window
+    # the iteration's host phases (obs/trace.py PHASES): leaves in the
+    # profiler's trace, and this window's seconds per phase for the
+    # timeline records (data_ms, dispatch_ms, sync_ms)
+    win: dict = {}
     stopped_early = False
     with _graceful_stop() as stop:
         for it in range(start_step, train_cfg.max_iters + 1):
@@ -643,31 +647,30 @@ def train(model_cfg: LLMConfig, train_cfg: TrainConfig,
                 break
 
             if train_cfg.eval and it % train_cfg.eval_interval == 0:
-                t0 = time.perf_counter()
-                ev = estimate_loss(eval_step, eval_view(state),
-                                   {"train": eval_train_loader,
-                                    "val": val_loader},
-                                   train_cfg.eval_iters)
-                stats["val_losses"].append((it, ev["val"]))
-                if tel.enabled:
-                    tel.metrics.inc("evals")
-                say(f"iter {it}: train {ev['train']:.4f} val {ev['val']:.4f} "
-                    f"({time.perf_counter() - t0:.1f}s)")
+                with phase("train.eval", step=it):
+                    t0 = time.perf_counter()
+                    ev = estimate_loss(eval_step, eval_view(state),
+                                       {"train": eval_train_loader,
+                                        "val": val_loader},
+                                       train_cfg.eval_iters)
+                    stats["val_losses"].append((it, ev["val"]))
+                    if tel.enabled:
+                        tel.metrics.inc("evals")
+                    say(f"iter {it}: train {ev['train']:.4f} val "
+                        f"{ev['val']:.4f} ({time.perf_counter() - t0:.1f}s)")
                 win_t0 = time.perf_counter()       # eval time isn't step time
 
-            if step_guard is not None:
-                with step_guard.expect(0 if step_guard.count else 1):
+            with phase("train.dispatch", win, step=it):
+                if step_guard is not None:
+                    with step_guard.expect(0 if step_guard.count else 1):
+                        state, m = train_step(state, x, y)
+                else:
                     state, m = train_step(state, x, y)
-            else:
-                state, m = train_step(state, x, y)
             pending.append(m)
             if it < train_cfg.max_iters:  # no wasted sample on the final iter
-                if tel.enabled:            # data_ms: the host-side fetch cost
-                    t_d = time.perf_counter()
-                    x, y = train_loader.next_batch(step=it + 1)  # host prefetch while device runs
-                    win_data_s += time.perf_counter() - t_d
-                else:
-                    x, y = train_loader.next_batch(step=it + 1)  # host prefetch while device runs
+                with phase("train.data", win, step=it):
+                    # host prefetch while the device runs
+                    x, y = train_loader.next_batch(step=it + 1)
 
             ckpt_due = bool(train_cfg.ckpt_interval and it
                             and it % train_cfg.ckpt_interval == 0)
@@ -676,138 +679,152 @@ def train(model_cfg: LLMConfig, train_cfg: TrainConfig,
             sync_due = (it % train_cfg.log_interval == 0 or ckpt_due
                         or eval_next or it == train_cfg.max_iters)
             if sync_due:
-                t_s0 = time.perf_counter()
-                got = jax.device_get(pending)      # blocks on all queued steps
+                with phase("train.sync", win, step=it,
+                           n_steps=len(pending)):
+                    got = jax.device_get(pending)  # blocks on all queued steps
                 t_now = time.perf_counter()
-                sync_s = t_now - t_s0              # host blocked on the drain
+                sync_s = win["train.sync"]         # host blocked on the drain
                 dt = (t_now - win_t0) / len(pending)
                 win_t0 = t_now
-                first_window = not stats["train_losses"]
-                win_first_it = it - len(got) + 1   # window is contiguous iters
-                for g in got:
-                    stats["train_losses"].append(float(g["loss"]))
-                    if "moe_dropped_frac" in g:
-                        stats["moe_dropped_frac"].append(
-                            float(g["moe_dropped_frac"]))
-                pending.clear()
-                if not first_window:               # first window includes compile
-                    for _ in got:
-                        stats["step_times"].append(dt)
-                        stats["tokens_per_sec"].append(tokens_per_step / dt)
-                        if peak:
-                            stats["mfu"].append(
-                                flops_per_step / dt / (peak * n_chips))
-                # ---- anomaly + telemetry drain: the boundary already --
-                # paid the device sync; everything below is host floats
-                mfu_now = (flops_per_step / dt / (peak * n_chips)
-                           if peak else None)
-                hbm_now = M.device_memory_gb()     # watermark: compile is in
-                for k, g in enumerate(got):        # the first window's sample
-                    it_k = win_first_it + k
-                    loss_k = float(g["loss"])
-                    gn_k = float(g["grad_norm"])
-                    ev = tel.anomalies.observe(
-                        it=it_k, loss=loss_k, grad_norm=gn_k,
-                        skipped=bool(g.get("update_skipped", 0.0)),
-                        coords={**data_coords, "batch_step": it_k})
-                    if ev is not None:
-                        tel.record_anomaly(ev)
-                        stats.setdefault("anomalies", []).append(ev)
-                        say(f"[anomaly] iter {it_k}: {ev['kind']} "
-                            f"(loss {loss_k:.4g}, grad_norm {gn_k:.4g}"
-                            f"{', update skipped' if ev['skipped'] else ''}"
-                            f") — batch from {ev.get('data_coords')}")
+                with phase("train.drain", step=it):
+                    n_got = len(got)               # window is contiguous iters
+                    data_s = win.get("train.data", 0.0) / n_got
+                    first_window = not stats["train_losses"]
+                    win_first_it = it - n_got + 1
+                    for g in got:
+                        stats["train_losses"].append(float(g["loss"]))
+                        if "moe_dropped_frac" in g:
+                            stats["moe_dropped_frac"].append(
+                                float(g["moe_dropped_frac"]))
+                    pending.clear()
+                    if not first_window:    # first window includes compile
+                        for _ in got:
+                            stats["step_times"].append(dt)
+                            stats["tokens_per_sec"].append(
+                                tokens_per_step / dt)
+                            if peak:
+                                stats["mfu"].append(
+                                    flops_per_step / dt / (peak * n_chips))
+                    # ---- anomaly + telemetry drain: the boundary already
+                    # paid the device sync; everything below is host floats
+                    mfu_now = (flops_per_step / dt / (peak * n_chips)
+                               if peak else None)
+                    # watermark: compile is in the first window's sample
+                    hbm_now = M.device_memory_gb()
+                    for k, g in enumerate(got):
+                        it_k = win_first_it + k
+                        loss_k = float(g["loss"])
+                        gn_k = float(g["grad_norm"])
+                        ev = tel.anomalies.observe(
+                            it=it_k, loss=loss_k, grad_norm=gn_k,
+                            skipped=bool(g.get("update_skipped", 0.0)),
+                            coords={**data_coords, "batch_step": it_k})
+                        if ev is not None:
+                            tel.record_anomaly(ev)
+                            stats.setdefault("anomalies", []).append(ev)
+                            skip_s = (", update skipped" if ev["skipped"]
+                                      else "")
+                            say(f"[anomaly] iter {it_k}: {ev['kind']} "
+                                f"(loss {loss_k:.4g}, grad_norm {gn_k:.4g}"
+                                f"{skip_s}) — batch from "
+                                f"{ev.get('data_coords')}")
+                        if tel.enabled:
+                            rec = {"it": it_k, "loss": loss_k,
+                                   "grad_norm": gn_k,
+                                   "data_ms": round(data_s * 1e3, 3)}
+                            if first_window:   # compile-inclusive window:
+                                rec["compile_window"] = True  # no step_ms
+                            else:
+                                rec["step_ms"] = round(dt * 1e3, 3)
+                                rec["tokens_per_s"] = round(
+                                    tokens_per_step / dt, 1)
+                                if mfu_now is not None:
+                                    rec["mfu"] = round(mfu_now, 4)
+                            if k == n_got - 1:
+                                # the boundary record carries the drain,
+                                # the window's mean enqueue and the
+                                # watermark
+                                rec["sync_ms"] = round(sync_s * 1e3, 3)
+                                rec["dispatch_ms"] = round(
+                                    win["train.dispatch"] / n_got * 1e3, 3)
+                                if hbm_now:
+                                    rec["hbm_gb"] = round(hbm_now, 3)
+                            tel.record_step(**rec)
                     if tel.enabled:
-                        rec = {"it": it_k, "loss": loss_k, "grad_norm": gn_k,
-                               "data_ms": round(win_data_s / len(got) * 1e3,
-                                                3)}
-                        if first_window:           # compile-inclusive window:
-                            rec["compile_window"] = True   # no honest step_ms
-                        else:
-                            rec["step_ms"] = round(dt * 1e3, 3)
-                            rec["tokens_per_s"] = round(
-                                tokens_per_step / dt, 1)
-                            if mfu_now is not None:
-                                rec["mfu"] = round(mfu_now, 4)
-                        if k == len(got) - 1:      # boundary record carries
-                            rec["sync_ms"] = round(sync_s * 1e3, 3)  # drain +
-                            if hbm_now:                              # watermark
-                                rec["hbm_gb"] = round(hbm_now, 3)
-                        tel.record_step(**rec)
-                if tel.enabled:
-                    tel.metrics.inc("steps", len(got))
-                    tel.metrics.observe_phases(
-                        step_s=None if first_window else dt,
-                        data_s=win_data_s / len(got), sync_s=sync_s)
-                    tel.last.update(
-                        it=it, loss=float(got[-1]["loss"]),
-                        tokens_per_s=(0.0 if first_window
-                                      else tokens_per_step / dt),
-                        mfu=None if first_window else mfu_now,
-                        hbm_gb=hbm_now)
-                win_data_s = 0.0
-                if it % train_cfg.log_interval == 0:
-                    loss = stats["train_losses"][-1]
-                    tps = tokens_per_step / dt
-                    mfu_s = (f" | mfu "
-                             f"{flops_per_step / dt / (peak * n_chips):6.2%}"
-                             if peak else "")
-                    # reference reserved-GB print (train.py:356); hbm_now
-                    # was sampled at this same boundary above
-                    hbm_s = f" | hbm {hbm_now:5.2f}GB" if hbm_now else ""
-                    drop_s = ""
-                    if stats.get("moe_dropped_frac"):
-                        # silent GShard-style drops (scatter mode) become a
-                        # visible per-step number; dense/grouped print 0
-                        drop_s = (f" | moe_drop "
-                                  f"{stats['moe_dropped_frac'][-1]:6.2%}")
-                    say(f"iter {it:5d} | loss {loss:.4f} | "
-                        f"dt {dt * 1e3:7.1f}ms | "
-                        f"tok/s/chip {tps / n_chips:10.0f}{mfu_s}{hbm_s}"
-                        f"{drop_s}")
+                        tel.metrics.inc("steps", n_got)
+                        tel.metrics.observe_phases(
+                            step_s=None if first_window else dt,
+                            data_s=data_s, sync_s=sync_s)
+                        tel.last.update(
+                            it=it, loss=float(got[-1]["loss"]),
+                            tokens_per_s=(0.0 if first_window
+                                          else tokens_per_step / dt),
+                            mfu=None if first_window else mfu_now,
+                            hbm_gb=hbm_now)
+                    win.clear()
+                    if it % train_cfg.log_interval == 0:
+                        loss = stats["train_losses"][-1]
+                        tps = tokens_per_step / dt
+                        mfu_s = (f" | mfu {mfu_now:6.2%}" if peak else "")
+                        # reference reserved-GB print (train.py:356);
+                        # hbm_now was sampled at this same boundary above
+                        hbm_s = f" | hbm {hbm_now:5.2f}GB" if hbm_now else ""
+                        drop_s = ""
+                        if stats.get("moe_dropped_frac"):
+                            # silent GShard-style drops (scatter mode)
+                            # become a visible per-step number;
+                            # dense/grouped print 0
+                            drop_s = (f" | moe_drop "
+                                      f"{stats['moe_dropped_frac'][-1]:6.2%}")
+                        say(f"iter {it:5d} | loss {loss:.4f} | "
+                            f"dt {dt * 1e3:7.1f}ms | "
+                            f"tok/s/chip {tps / n_chips:10.0f}{mfu_s}{hbm_s}"
+                            f"{drop_s}")
 
             if ckpt_due:
-                # interval saves are async: serialization overlaps the next
-                # steps instead of stalling them (train/checkpoint.py)
-                path = ckpt.save_checkpoint_async(
-                    os.path.join(ckpt_root, f"step_{it}"), state,
-                    model_cfg, train_cfg)
-                # the pre-save snapshot copy is the one synchronous cost an
-                # async save keeps; track it so the 1.5B step-time dent is
-                # visible (ROADMAP async-checkpoint item)
-                stats.setdefault("ckpt_snapshot_ms", []).append(
-                    round(ckpt.last_snapshot_ms, 2))
-                if tel.enabled:
-                    tel.metrics.inc("checkpoints")
-                    tel.metrics.observe_phases(
-                        ckpt_s=ckpt.last_snapshot_ms / 1e3)
-                    tel.record_step(event="ckpt", it=it,
-                                    ckpt_ms=round(ckpt.last_snapshot_ms, 2))
-                # refresh the on-disk run record at EVERY checkpoint
-                # boundary (atomic tmp+rename): a preempted or killed
-                # run leaves a usable stats.json + timeline behind, not
-                # only the copy written at exit
-                if train_cfg.save_stats and is_main:
-                    _write_stats_files(stats, model_cfg, train_cfg,
-                                       ckpt_root, run_dir,
-                                       memplan_pred_gb, memplan_breakdown)
-                if tel.enabled and is_main:
-                    tel.dump(timeline_path)
-                say(f"checkpoint (async) -> {path} "
-                    f"(snapshot {ckpt.last_snapshot_ms:.0f}ms)")
-                # retention: this save's manifest is still pending (its
-                # durability lands at the next wait), so pruning here only
-                # ever deletes OLDER verified dirs — the in-flight one is
-                # untouchable by construction
-                _prune_ckpts(ckpt_root, train_cfg, say)
+                with phase("train.ckpt", step=it):
+                    # interval saves are async: serialization overlaps the next
+                    # steps instead of stalling them (train/checkpoint.py)
+                    path = ckpt.save_checkpoint_async(
+                        os.path.join(ckpt_root, f"step_{it}"), state,
+                        model_cfg, train_cfg)
+                    # the pre-save snapshot copy is the one synchronous cost an
+                    # async save keeps; track it so the 1.5B step-time dent is
+                    # visible (ROADMAP async-checkpoint item)
+                    stats.setdefault("ckpt_snapshot_ms", []).append(
+                        round(ckpt.last_snapshot_ms, 2))
+                    if tel.enabled:
+                        tel.metrics.inc("checkpoints")
+                        tel.metrics.observe_phases(
+                            ckpt_s=ckpt.last_snapshot_ms / 1e3)
+                        tel.record_step(
+                            event="ckpt", it=it,
+                            ckpt_ms=round(ckpt.last_snapshot_ms, 2))
+                    # refresh the on-disk run record at EVERY checkpoint
+                    # boundary (atomic tmp+rename): a preempted or killed
+                    # run leaves a usable stats.json + timeline behind, not
+                    # only the copy written at exit
+                    if train_cfg.save_stats and is_main:
+                        _write_stats_files(stats, model_cfg, train_cfg,
+                                           ckpt_root, run_dir,
+                                           memplan_pred_gb, memplan_breakdown)
+                    if tel.enabled and is_main:
+                        tel.dump(timeline_path)
+                    say(f"checkpoint (async) -> {path} "
+                        f"(snapshot {ckpt.last_snapshot_ms:.0f}ms)")
+                    # retention: this save's manifest is still pending (its
+                    # durability lands at the next wait), so pruning here only
+                    # ever deletes OLDER verified dirs — the in-flight one is
+                    # untouchable by construction
+                    _prune_ckpts(ckpt_root, train_cfg, say)
                 win_t0 = time.perf_counter()       # ckpt time isn't step time
 
     if train_cfg.profile and is_main:
         from distributed_pytorch_tpu.obs import profile as obs_profile
         obs_profile.stop_profile()
-        say(f"profiler trace -> {prof_dir} (open with Perfetto, or "
-            f"scripts/profile_step.py --analyze_only --trace_dir "
-            f"{prof_dir})")
+        say(f"profiler trace -> {prof_dir} (device time by scope and idle "
+            f"time by train.* phase: scripts/profile_step.py "
+            f"--analyze_only --trace_dir {prof_dir})")
         stats["profile_dir"] = prof_dir
 
     ckpt.wait_for_saves()  # async interval saves must be durable

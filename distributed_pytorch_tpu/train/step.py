@@ -220,12 +220,17 @@ def make_train_step(model, tx: optax.GradientTransformation,
     def _train_step_body(state: TrainState, x: jnp.ndarray, y: jnp.ndarray):
         grads, new_moe, losses = grads_fn(state.params, state.moe_state,
                                           state.step, x, y)
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        # scopes of obs/trace.py SCOPES: no module name reaches these ops
+        with jax.named_scope("optimizer"):
+            updates, new_opt = tx.update(grads, state.opt_state,
+                                         state.params)
+            new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("grad_norm"):
+            grad_norm = optax.global_norm(grads)
 
         metrics = {
             "loss": losses.mean(),
-            "grad_norm": optax.global_norm(grads),
+            "grad_norm": grad_norm,
         }
         if anomaly != "off":
             finite = (jnp.isfinite(metrics["loss"])

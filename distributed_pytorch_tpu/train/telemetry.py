@@ -7,7 +7,8 @@ loss/dt/tok-s/MFU to stdout and one terminal stats.json. This module
 closes the training half (ISSUE 10), reusing the round-14 primitives:
 
 * `TrainTelemetry` — per-logged-step records `{it, loss, grad_norm,
-  step_ms, data_ms, sync_ms, ckpt_ms, tokens_per_s, mfu}` land in an
+  step_ms, data_ms, dispatch_ms, sync_ms, ckpt_ms, tokens_per_s, mfu}`
+  (data/dispatch/sync are the loop's host phases, obs/trace.py) land in an
   `obs.flight.FlightRecorder` ring, dumped to
   `runs/<run>/train_timeline.jsonl` at checkpoint boundaries and exit.
   Everything is fed at the loop's existing SYNC BOUNDARIES (the

@@ -1,9 +1,12 @@
 """Capture + analyze a TPU profile of the flagship train step (VERDICT #1a).
 
-Runs a few steps of the bench config under jax.profiler, then parses the
-xplane protobuf with tensorboard_plugin_profile's converter and prints the
-op-level time breakdown — no TensorBoard UI needed (this container has no
-browser). The output is the evidence for which kernel eats the step.
+Runs a few steps of the bench config under jax.profiler, then reduces the
+capture with the benchmark's own code (benchmark/lib/trace_reduce.py and
+trace_spans.py) and prints busy/idle, the op-level time breakdown, and the
+breakdown by the program's named scopes and host phases — no TensorBoard
+UI needed (this container has no browser). `--analyze_only --trace_dir D`
+reduces any capture: the trainer's `--profile`, a replica's
+`POST /admin/profile`, a benchmark run's `.bench_work/<cell>/trace`.
 
 Usage: python scripts/profile_step.py [--batch 16] [--attn auto] [--remat]
 """
@@ -11,14 +14,11 @@ Usage: python scripts/profile_step.py [--batch 16] [--attn auto] [--remat]
 from __future__ import annotations
 
 import argparse
-import glob
 import os
 import sys
-import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 
 def capture(batch: int, attn_impl: str, remat: bool, loss_impl: str,
@@ -51,44 +51,52 @@ def capture(batch: int, attn_impl: str, remat: bool, loss_impl: str,
 
 
 def analyze(trace_dir: str, top: int = 25) -> None:
-    """Parse the newest xplane.pb and print per-op device time.
+    """The benchmark's reduction on the newest capture under `trace_dir`
+    (benchmark/lib/trace_reduce.py + trace_spans.py, so this table and the
+    benchmark's are one arithmetic): busy and idle time of the devices,
+    self time by op family, the longest idle gaps by host event; then by
+    the PROGRAM's names (obs/trace.py): device self time a step by named
+    scope, idle time by host phase, and each phase's median."""
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from benchmark.lib import trace_reduce, trace_spans
+    from benchmark.readers import trace_idle_owner, trace_scope_ms
 
-    Reads the XSpace proto directly (tensorflow.tsl xplane_pb2 — the
-    tensorboard-plugin converter in this image is ABI-mismatched with its
-    TF build): for the device plane, aggregate event durations by op name
-    on each line and print the busiest line's breakdown."""
-    from tensorflow.tsl.profiler.protobuf import xplane_pb2
-
-    xplanes = sorted(glob.glob(os.path.join(
-        trace_dir, "plugins/profile/*/*.xplane.pb")))
-    assert xplanes, f"no xplane.pb under {trace_dir}"
-    space = xplane_pb2.XSpace()
-    with open(xplanes[-1], "rb") as f:
-        space.ParseFromString(f.read())
-
-    device_planes = [p for p in space.planes
-                     if "TPU" in p.name or "/device" in p.name.lower()]
-    planes = device_planes or list(space.planes)
-    for plane in planes:
-        ev_names = {m.id: m.name for m in plane.event_metadata.values()}
-        best_line, best_tot = None, 0
-        per_line = {}
-        for line in plane.lines:
-            agg: dict[str, float] = {}
-            for ev in line.events:
-                name = ev_names.get(ev.metadata_id, "?")
-                agg[name] = agg.get(name, 0.0) + ev.duration_ps / 1e6  # us
-            tot = sum(agg.values())
-            per_line[line.name] = (tot, agg)
-            if tot > best_tot:
-                best_line, best_tot = line.name, tot
-        if not best_line:
-            continue
-        print(f"\n=== plane {plane.name!r}: busiest line {best_line!r} "
-              f"({best_tot / 1e3:.1f} ms total) ===")
-        tot, agg = per_line[best_line]
-        for name, t in sorted(agg.items(), key=lambda kv: -kv[1])[:top]:
-            print(f"{t:12.1f} us  {100 * t / tot:5.1f}%  {name[:90]}")
+    path = trace_reduce.find_xplane(trace_dir)
+    planes = trace_reduce.load_planes(path)
+    print(f"capture {path}")
+    n_dev = len(trace_reduce.device_lines(planes))
+    if n_dev:
+        summary = trace_reduce.summarize(planes, n_dev)
+        busy, window = summary["busy_s"], summary["window_s"]
+        print(f"devices {summary['devices']}: busy {busy:.4f}s of "
+              f"{window:.4f}s, idle {100 * (1 - busy / window):.2f}%")
+        print("self time by op family, device 0:")
+        for name, sec in trace_reduce.top_ops(summary["ops_dev0"], top=top,
+                                              by_family=True):
+            print(f"  {sec * 1e3:10.3f} ms  {100 * sec / busy:5.1f}%  "
+                  f"{name[:100]}")
+        print("longest idle gaps, by the host event that covers most:")
+        for name, sec in summary["breakdown"]["idle_gaps"]:
+            print(f"  {sec * 1e3:10.3f} ms  {name[:100]}")
+    else:
+        print("no device plane (a CPU capture): host phases only")
+    sl = trace_spans.load(trace_dir)
+    if sl["ops"]:
+        scopes = trace_scope_ms.table(sl, [r"step\("])
+        if scopes is not None:
+            trace_scope_ms.say_table(scopes)
+        idle = trace_idle_owner.table(sl, trace_spans.PHASE_LAYERS)
+        if idle is not None:
+            trace_idle_owner.say_table(idle)
+    by_name: dict = {}
+    for layer in trace_spans.PHASE_LAYERS:
+        for name, _, dur, _ in trace_spans.phase_events(sl, layer):
+            by_name.setdefault(name, []).append(dur / 1e6)
+    if by_name:
+        print("host phases (obs/trace.py PHASES): count, median ms")
+    for name, ms in by_name.items():
+        print(f"  {name:<16} {len(ms):6d} {trace_spans.median(ms):10.3f}")
 
 
 def main():
@@ -103,10 +111,8 @@ def main():
     ap.add_argument("--analyze_only", action="store_true")
     args = ap.parse_args()
     if not args.trace_dir:
-        import os as _os
-        import sys as _sys
-        _sys.path.insert(0, _os.path.dirname(_os.path.dirname(
-            _os.path.abspath(__file__))))
+        sys.path.insert(0, os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
         from distributed_pytorch_tpu.obs.profile import profile_dir
         args.trace_dir = profile_dir("profile_step")
 

@@ -1,7 +1,8 @@
 """obs/ subsystem unit tests: trace-recorder ring bounds and
 disabled-mode overhead, Chrome-trace/Perfetto export schema validity,
 cross-process span stitching (ingest/re-base), the flight recorder's
-ring + JSONL dump, and the shared jax.profiler wrapper's guard rails."""
+ring + JSONL dump, the shared jax.profiler wrapper's guard rails, and the
+program's phases and named scopes in the profiler's trace."""
 
 import json
 import os
@@ -201,6 +202,182 @@ def test_profile_capture_and_busy_guard(tmp_path):
     # the capture left a jax profiler artifact tree behind
     assert any(files for _, _, files in os.walk(out)), \
         "profiler capture wrote nothing"
+
+
+# ----------------------------------------------------------------------
+# obs/trace.py phases and scopes: the program in the profiler's trace
+# ----------------------------------------------------------------------
+
+_TINY = dict(vocab_size=256, block_size=64, n_embd=32, n_head=2,
+             n_kv_heads=2, n_layer=2, up_dim=64, attn="mha",
+             pos_emb="learn", non_linearity="gelu")
+_ENGINE4 = ["engine.prepare", "engine.dispatch", "engine.wait",
+            "engine.retire"]
+
+
+def _tiny_engine():
+    import jax
+    from distributed_pytorch_tpu.config import LLMConfig
+    from distributed_pytorch_tpu.engine import DecodeEngine
+    from distributed_pytorch_tpu.models.gpt import LLM
+    model = LLM(LLMConfig(**_TINY))
+    key = jax.random.PRNGKey(0)
+    variables = jax.jit(model.init)({"params": key, "dropout": key},
+                                    jnp.zeros((1, 8), jnp.int32))
+    return model, DecodeEngine(model, variables, n_slots=2, max_len=64,
+                               block_size=8, prefill_chunk=16,
+                               temperature=0.0, min_bucket=8)
+
+
+def test_phase_without_a_capture_times_and_opens_no_file(tmp_path,
+                                                         monkeypatch):
+    from distributed_pytorch_tpu.obs.trace import PHASES, STEP_PHASES, phase
+    monkeypatch.chdir(tmp_path)
+    assert STEP_PHASES <= set(PHASES)
+    acc = {}
+    with phase("engine.wait", acc, step=3):
+        time.sleep(0.002)
+    with phase("engine.wait", acc, step=4):
+        time.sleep(0.002)
+    with phase("engine.dispatch", acc, step=4, kind="decode") as ph:
+        ph.set(n_live=1)
+    with phase("sched.idle"):                 # no accumulator: trace only
+        pass
+    assert set(acc) == {"engine.wait", "engine.dispatch"}
+    assert 0.004 <= acc["engine.wait"] < 0.5
+    assert 0 <= acc["engine.dispatch"] < 0.1
+    assert os.listdir(tmp_path) == []
+
+
+def test_engine_step_phases_in_a_capture_and_in_the_flight_record(tmp_path):
+    """Per `step` exactly one of each of the four leaves, in order,
+    disjoint; the flight record splits step_ms by the same stamps."""
+    import jax
+    from jax.profiler import ProfileData
+    _, eng = _tiny_engine()
+    eng.run([[5, 6, 7, 8, 9]], 3)             # compile both programs
+    n0 = len(eng.flight.entries())
+    out = str(tmp_path / "cap")
+    d = obs_profile.start_profile(out)
+    try:
+        eng.admit(list(range(1, 31)), 6)      # two chunks, then decode
+        eng.admit([3, 4, 5], 4)
+        while eng.n_live or eng.n_free < eng.n_slots:
+            eng.step()
+    finally:
+        obs_profile.stop_profile()
+    hits = [os.path.join(r, f) for r, _, fs in os.walk(d) for f in fs
+            if f.endswith(".xplane.pb")]
+    assert len(hits) == 1
+    evs = []
+    for plane in ProfileData.from_file(hits[0]).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("engine."):
+                    evs.append((e.start_ns, e.start_ns + e.duration_ns,
+                                e.name, dict(e.stats)))
+    evs.sort()
+    admits = [e for e in evs if e[2] == "engine.admit"]
+    assert [e[3]["chunked"] for e in admits] == [1, 1]
+    steps = [e for e in evs if e[2] != "engine.admit"]
+    recs = eng.flight.entries()[n0:]
+    assert len(steps) == 4 * len(recs) and len(recs) >= 6
+    for i, rec in enumerate(recs):
+        four = steps[4 * i:4 * i + 4]
+        assert [e[2] for e in four] == _ENGINE4
+        assert {e[3]["step"] for e in four} == {rec["step"] - 1}
+        assert all(a[1] <= b[0] for a, b in zip(four, four[1:]))
+        disp = four[1][3]
+        assert disp["step_num"] == disp["step"]
+        assert disp["kind"] in ("fused", "decode")
+        assert disp["prefill_tokens"] == rec["prefill_tokens"]
+        parts = [rec[k] for k in ("prepare_ms", "dispatch_ms", "wait_ms",
+                                  "retire_ms")]
+        assert all(p >= 0 for p in parts)
+        assert sum(parts) == pytest.approx(rec["step_ms"], rel=0.1,
+                                           abs=0.02)
+    # rising steps, and the chunk-carrying ones are the fused program
+    nums = [e[3]["step"] for e in steps if e[2] == "engine.dispatch"]
+    assert nums == sorted(set(nums))
+    kinds = [e[3]["kind"] for e in steps if e[2] == "engine.dispatch"]
+    assert kinds[:2] == ["fused", "fused"] and kinds[-1] == "decode"
+
+
+def _compiled_train_step():
+    import jax
+    from distributed_pytorch_tpu.config import LLMConfig, TrainConfig
+    from distributed_pytorch_tpu.train.state import create_train_state
+    from distributed_pytorch_tpu.train.step import make_train_step
+    mc = LLMConfig(**_TINY)
+    tc = TrainConfig(batch_size=2, total_batch_size=128,
+                     parallelism="single", dataset="synthetic")
+    model, tx, state, _ = create_train_state(mc, tc, None)
+    step = make_train_step(model, tx, mc, tc, None, None)
+    x = jnp.zeros((1, 2, 64), jnp.int32)
+    lowered = step.lower(state, x, x)
+    # `grad_norm` is there as the program is lowered; the optimizer's own
+    # global-norm clip computes the same reduction, so XLA folds the two
+    # and the compiled text keeps `optimizer`'s name for it (which is why
+    # one metric reads the two scopes together)
+    assert "grad_norm" in lowered.as_text(debug_info=True)
+    return lowered.compile().as_text()
+
+
+def _compiled_engine_step(fused: bool):
+    import jax
+    from distributed_pytorch_tpu.engine.decode import (make_fused_step_fn,
+                                                       make_step_fn)
+    model, eng = _tiny_engine()
+    args = (eng.variables, eng.caches, eng.tok, eng.pos, eng.live,
+            eng.block_tables, eng._rng, jnp.int32(0), eng._qparams)
+    if not fused:
+        fn = make_step_fn(model, eng._sample)
+    else:
+        fn = make_fused_step_fn(model, eng._sample, eng.n_slots,
+                                eng.table_width)
+        args += (jnp.zeros((1, eng.prefill_chunk), jnp.int32), jnp.int32(0),
+                 jnp.int32(0), jnp.asarray([4], jnp.int32), jnp.bool_(True))
+    with eng._ctx():
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+
+_SCOPES_OF = {
+    "train_step": {"attn_core", "loss", "optimizer", "grad_norm"},
+    "step": {"decode", "kv_update", "attn_core", "lm_head", "sample"},
+    "fused_step": {"chunk_prefill", "decode", "kv_update", "attn_core",
+                   "lm_head", "sample"},
+}
+
+
+@pytest.mark.parametrize("program", sorted(_SCOPES_OF))
+def test_named_scopes_reach_the_compiled_op_names(program):
+    """Every scope of the table is in some compiled program's `op_name`s,
+    beside the module names; the backward pass carries the model's as
+    `transpose(jvp(...))`; none contains a kernel's name."""
+    import re
+    from distributed_pytorch_tpu.obs.trace import SCOPES
+    assert set().union(*_SCOPES_OF.values()) == set(SCOPES)
+    for name in SCOPES:
+        assert not re.search(r"flash|paged|pallas", name), name
+    text = (_compiled_train_step() if program == "train_step"
+            else _compiled_engine_step(program == "fused_step"))
+    paths = set(re.findall(r'op_name="([^"]+)"', text))
+    assert all(p.startswith(f"jit({program})") for p in paths
+               if p.startswith("jit("))
+    parts = [set(re.split(r"[/()]", p)) for p in paths]
+    for scope in _SCOPES_OF[program] - {"grad_norm"}:
+        assert any(scope in p for p in parts), scope
+    for module in ("attn", "mlp", "ln_f"):
+        assert any(module in p for p in parts), module
+    if program == "train_step":
+        for scope in ("attn_core", "loss"):
+            assert any(scope in p and "jvp" in p and "transpose" not in p
+                       for p in parts), scope
+            assert any(scope in p and "transpose" in p for p in parts), scope
+    else:
+        # the cache write and the attention core are apart, in every layer
+        assert any({"kv_update", "attn", "block_1"} <= p for p in parts)
+        assert not any({"kv_update", "attn_core"} <= p for p in parts)
 
 
 # ----------------------------------------------------------------------

@@ -594,7 +594,8 @@ def kernel_numerics(seed: int, platform: str, w: dict = FLAGSHIP_WIDTHS,
     from distributed_pytorch_tpu.ops import fused_ce
     from distributed_pytorch_tpu.ops import grouped_matmul as gm
     from distributed_pytorch_tpu.ops.attention_core import _naive_sdpa
-    from distributed_pytorch_tpu.ops.block_pool import paged_gather
+    from distributed_pytorch_tpu.ops.block_pool import (kv_lanes, merge_heads,
+                                                        paged_gather)
     from distributed_pytorch_tpu.ops.losses import unchunked_cross_entropy
     from distributed_pytorch_tpu.ops.quant import dequantize_int8, quantize_kv
 
@@ -708,16 +709,19 @@ def kernel_numerics(seed: int, platform: str, w: dict = FLAGSHIP_WIDTHS,
     kp, vp = (normal((n_blocks, bs, nh, hs)) for _ in range(2))
     kpq, kps = quantize_kv(kp)
     vpq, vps = quantize_kv(vp)
+    # float pools as the engine declares them: heads merged into lanes
+    kpm, vpm = (merge_heads(a, kv_lanes(nh, hs)) for a in (kp, vp))
     for name, (k_, v_, kw) in {
-            "paged_flash_decode": (kp, vp, {}),
+            "paged_flash_decode": (kpm, vpm, {"n_kv_heads": nh}),
             "paged_flash_decode int8": (kpq, vpq, {"k_scale": kps,
                                                    "v_scale": vps})
     }.items():
         t0 = time.perf_counter()
         got = jax.jit(lambda q, k, v, bt, cl, kw=kw: fd.paged_flash_decode(
             q[:, 0], k, v, bt, cl, scale=scale, **kw))(q1, k_, v_, bt, cl)
-        kf = dequantize_int8(kpq, kps, f32) if kw else kp.astype(f32)
-        vf = dequantize_int8(vpq, vps, f32) if kw else vp.astype(f32)
+        q8 = "k_scale" in kw
+        kf = dequantize_int8(kpq, kps, f32) if q8 else kp.astype(f32)
+        vf = dequantize_int8(vpq, vps, f32) if q8 else vp.astype(f32)
         ref = _naive_sdpa(q1.astype(f32), paged_gather(kf, bt),
                           paged_gather(vf, bt), scale=scale,
                           q_offset=cl - 1)[:, 0]
@@ -728,13 +732,14 @@ def kernel_numerics(seed: int, platform: str, w: dict = FLAGSHIP_WIDTHS,
     chunk, off = w["chunk"], 2 * bs                 # two prior blocks
     qc = normal((1, chunk, nh, hs))
     got = jax.jit(lambda q, k, v, bt, o: fd.paged_flash_prefill(
-        q, k, v, bt, o, scale=scale))(qc, kp, vp, bt[:1], jnp.int32(off))
+        q, k, v, bt, o, scale=scale, n_kv_heads=nh))(
+            qc, kpm, vpm, bt[:1], jnp.int32(off))
     ref = _naive_sdpa(qc.astype(f32), paged_gather(kp.astype(f32), bt[:1]),
                       paged_gather(vp.astype(f32), bt[:1]), scale=scale,
                       q_offset=off)
     record("paged_flash_prefill", [chunk, W, bs, nh, hs],
            {"out": (got, ref, "out")}, time.perf_counter() - t0)
-    del kc, vc, kq, vq, kp, vp, kpq, vpq
+    del kc, vc, kq, vq, kp, vp, kpm, vpm, kpq, vpq
 
     # ---- grouped matmul: the dropless MoE dispatch, forward + dx + dW
     t0 = time.perf_counter()

@@ -10,7 +10,11 @@ actual sequence length, and a full prefill per request even when
 thousands of requests share a system prompt:
 
 * **Paged pool + block tables**: ONE (n_blocks, block_size, ...) pool set
-  per layer lives for the engine's lifetime; each live sequence owns an
+  per layer lives for the engine's lifetime — float k/v pools as
+  (n_blocks, block_size, L), the kv heads merged into L = n_kv * head_size
+  lanes rounded up to 128, the one shape the donated argument, the
+  in-place row write and the kernels all hold in the same dense layout,
+  so no step copies a pool (ops/block_pool.py); each live sequence owns an
   ordered list of blocks recorded in a per-sequence row of the
   (n_slots, max_blocks) block table. Cache writes indirect through the
   table (`paged_update`); the flash-decode kernel prefetches the table
@@ -39,8 +43,9 @@ thousands of requests share a system prompt:
   from round 8: suffixes are right-padded to pow2 buckets (one compiled
   prefill per bucket — prefix length is traced, so reuse does not add
   traces), every live slot advances in a single jitted step traced once,
-  and under a mesh the pools shard kv heads over 'model' and blocks over
-  'data' via `sharding.decode_cache_pspec`.
+  and under a mesh the pools shard kv heads over 'model' (a merged-lane
+  pool: its lanes, when they carry no pad) and blocks over 'data' via
+  `sharding.decode_cache_pspec`.
 * **Chunked prefill fused into the decode step** (`prefill_chunk=N`,
   round 12 — Sarathi-style): instead of one monolithic bucket prefill
   per admission that stalls every live decode stream, each admitted
@@ -634,9 +639,12 @@ class DecodeEngine:
         if mesh is not None:
             from distributed_pytorch_tpu.parallel import sharding as shd
             from jax.sharding import NamedSharding
+            kv_heads = ((cfg.n_kv_heads, cfg.head_size)
+                        if cfg.attn != "mla" else None)
             caches = jax.tree_util.tree_map(
                 lambda c: jax.device_put(c, NamedSharding(
-                    mesh, shd.decode_cache_pspec(tuple(c.shape), mesh))),
+                    mesh, shd.decode_cache_pspec(tuple(c.shape), mesh,
+                                                 kv_heads))),
                 caches)
         self.caches = caches
         self.tok = jnp.zeros((n_slots,), jnp.int32)

@@ -41,8 +41,14 @@ TPU-first design notes (intentional divergences, documented per SURVEY §7):
    `paged_update` replaces the ring write, the flash kernel prefetches the
    table, and the naive/absorbed paths read a `paged_gather`ed logical view
    (identical values at identical logical positions, so they are
-   bit-compatible with the contiguous cache). The contiguous path below
-   stays for training and the one-shot generate oracle.
+   bit-compatible with the contiguous cache). A float k/v pool is
+   (n_blocks, block_size, L): the kv heads merged into one lane axis, L =
+   n_kv_heads * head_size rounded up to a multiple of 128 — the shape
+   whose default device layout, XLA's in-place row write and the Pallas
+   operand layout are one and the same dense row-major order, so a
+   serving step never copies a pool (`block_pool.kv_lanes` has the
+   reasoning). The contiguous path below stays for training and the
+   one-shot generate oracle.
 """
 
 from __future__ import annotations
@@ -201,7 +207,7 @@ class GQA(nn.Module):
                      dropout_rate=cfg.dropout, dropout_rng=drop_rng,
                      impl=self.attn_impl, decode=cache is not None,
                      k_scale=k_scale, v_scale=v_scale,
-                     block_tables=block_tables)
+                     block_tables=block_tables, n_kv_heads=nkvh)
         y = y.reshape(B, T, C)
         y = _OverlapDense(C, x.dtype, name="c_proj")(y)
         y = nn.Dropout(cfg.dropout, deterministic=deterministic)(y)
@@ -481,20 +487,27 @@ def init_attn_cache(config: LLMConfig, batch_size: int, max_len: int,
 
 def init_paged_attn_cache(config: LLMConfig, n_blocks: int, block_size: int,
                           dtype=jnp.float32) -> Cache:
-    """Per-layer paged KV POOL buffers (module docstring note 4): the same
-    leaves as `init_attn_cache` with the (B, S) row axes replaced by
-    (n_blocks, block_size) — `sharding.decode_cache_pspec` still places
-    the kv-head axis over 'model' and the leading (now block) axis over
-    'data'. Block 0 is the null block (ops/block_pool.py)."""
+    """Per-layer paged KV POOL buffers (module docstring note 4), the
+    (B, S) row axes of `init_attn_cache` replaced by (n_blocks,
+    block_size). Float k/v pools merge the kv heads into one lane axis,
+    (n_blocks, block_size, L) with L = `block_pool.kv_lanes` (n_kv_heads *
+    head_size rounded up to 128: gpt2-xl 1600 -> 1664, gpt2 768): the one
+    shape whose device layout, in-place write and kernel operand agree.
+    The int8 pools keep the head axis, codes (.., n_kv, hs) and float32
+    scale sidecars (.., n_kv, 1), with their head-major kernels; MLA
+    latent pools reach no kernel and keep theirs. Block 0 is the null
+    block (ops/block_pool.py)."""
     nb, bs = n_blocks, block_size
     if config.attn in ("mha", "mqa", "gqa"):
-        shape = (nb, bs, config.n_kv_heads, config.head_size)
         if jnp.dtype(dtype) == jnp.int8:
+            shape = (nb, bs, config.n_kv_heads, config.head_size)
             sc = (nb, bs, config.n_kv_heads, 1)
             return {"k": jnp.zeros(shape, jnp.int8),
                     "k_scale": jnp.zeros(sc, jnp.float32),
                     "v": jnp.zeros(shape, jnp.int8),
                     "v_scale": jnp.zeros(sc, jnp.float32)}
+        from distributed_pytorch_tpu.ops.block_pool import kv_lanes
+        shape = (nb, bs, kv_lanes(config.n_kv_heads, config.head_size))
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
     if jnp.dtype(dtype) == jnp.int8:
         raise ValueError(

@@ -158,7 +158,8 @@ def sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
          decode: bool = False,
          k_scale: Optional[jnp.ndarray] = None,
          v_scale: Optional[jnp.ndarray] = None,
-         block_tables: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+         block_tables: Optional[jnp.ndarray] = None,
+         n_kv_heads: int = 0) -> jnp.ndarray:
     """Scaled dot-product attention over (B, T, N, H)-layout tensors.
 
     `q_offset` is the global position of q[:, 0] (nonzero during KV-cached
@@ -180,6 +181,9 @@ def sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     logical per-sequence view with one `paged_gather` and proceeds
     unchanged — the gathered view holds identical values at identical
     logical positions, so downstream numerics match the contiguous cache.
+    Float pools are the merged-lane (n_blocks, bs, L) leaves
+    (`block_pool.kv_lanes`), whose shape no longer says how many heads
+    share the lanes: `n_kv_heads` does (int8 pools keep the head axis).
     """
     hs = q.shape[-1]
     scale = (1.0 / hs ** 0.5) if scale is None else scale
@@ -201,12 +205,14 @@ def sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                 paged_flash_decode, paged_flash_decode_decline)
             if _decode_kernel_wanted(
                     "paged_flash_decode",
-                    paged_flash_decode_decline(q, k, v, block_tables)):
+                    paged_flash_decode_decline(q, k, v, block_tables,
+                                               n_kv_heads)):
                 cl = jnp.broadcast_to(jnp.reshape(
                     jnp.asarray(q_offset, jnp.int32), (-1,)) + 1,
                     (q.shape[0],))
                 out = paged_flash_decode(q[:, 0], k, v, block_tables, cl,
-                                         scale=scale, k_scale=k_scale,
+                                         scale=scale, n_kv_heads=n_kv_heads,
+                                         k_scale=k_scale,
                                          v_scale=v_scale,
                                          interpret=not _on_tpu())
                 return out[:, None]
@@ -221,15 +227,19 @@ def sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                 paged_flash_prefill, paged_flash_prefill_decline)
             if _decode_kernel_wanted(
                     "paged_flash_prefill",
-                    paged_flash_prefill_decline(q, k, v, block_tables)):
+                    paged_flash_prefill_decline(q, k, v, block_tables,
+                                                n_kv_heads)):
                 off = jnp.reshape(jnp.asarray(q_offset, jnp.int32), (-1,))[0]
                 return paged_flash_prefill(q, k, v, block_tables, off,
-                                           scale=scale, k_scale=k_scale,
+                                           scale=scale,
+                                           n_kv_heads=n_kv_heads,
+                                           k_scale=k_scale,
                                            v_scale=v_scale,
                                            interpret=not _on_tpu())
         from distributed_pytorch_tpu.ops.block_pool import paged_gather
-        k = paged_gather(k, block_tables)
-        v = paged_gather(v, block_tables)
+        heads = (n_kv_heads or k.shape[2], q.shape[-1])
+        k = paged_gather(k, block_tables, heads)
+        v = paged_gather(v, block_tables, heads)
         if k_scale is not None:
             k_scale = paged_gather(k_scale, block_tables)
             v_scale = paged_gather(v_scale, block_tables)

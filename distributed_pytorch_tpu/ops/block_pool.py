@@ -11,8 +11,8 @@ resolve to the *same* physical blocks.
 
 Three layers, smallest first:
 
-* **Device ops** (`paged_update`, `paged_gather`): the (n_blocks, bs, ...)
-  pool is a plain jax array; a token write is a 2-index scatter through
+* **Device ops** (`paged_update`, `paged_gather`): a pool is a plain jax
+  array (n_blocks, bs, ...); a token write is a 2-index scatter through
   the block table (the paged generalization of models/attention.py's O(1)
   ring write), a logical view for the naive/einsum attention paths is one
   advanced-indexing gather — the same bytes the slot cache streamed. The
@@ -22,6 +22,24 @@ Three layers, smallest first:
   are zeroed, so the fused step's unavoidable dead-slot write lands in a
   row nothing ever reads — the paged replacement for "masked until the
   next occupant overwrites".
+
+  **One layout for a k/v pool** (`kv_lanes`): a float GQA-family pool is
+  (n_blocks, bs, L) with the kv heads MERGED into one lane axis, L =
+  n_kv * head_size rounded up to a multiple of 128. The TPU compiler
+  lays a donated entry parameter out in the dimension order that pads
+  nothing, XLA's in-place scatter wants the written window minor-most,
+  and a Pallas operand is row-major: only a shape whose minor-most
+  dimension already fills whole 128-lane tiles gets the same dense
+  row-major layout from all three, so the write happens in place and no
+  whole-pool `copy` stands between the donated argument, the scatter and
+  the kernel (a (.., 25, 64) pool paid two such copies per pool per step,
+  104 of a 124.6 ms step at gpt2-xl). `paged_update` flattens a row's
+  heads into lanes and zero-pads them on the way in, `paged_gather`
+  slices and reshapes on the way out; the indices are the same. Pad
+  lanes stay zero and no head's slice reads them. Leaves whose trailing
+  shape already equals the rows' (MLA latents, the int8 codes and their
+  scale sidecars, which keep (n_blocks, bs, n_kv, ...)) pass through
+  unchanged.
 * **`BlockPool`**: free-list allocator with per-block refcounts. Blocks
   referenced by live sequences can be shared (a reused prefix); blocks at
   refcount 0 that are *registered* in the prefix index are retained on an
@@ -69,11 +87,27 @@ class NoFreeBlocks(RuntimeError):
 # device-side paged-cache ops
 # ---------------------------------------------------------------------------
 
+def kv_lanes(n_kv_heads: int, head_size: int) -> int:
+    """Lane width L of a merged k/v pool leaf (module docstring): the kv
+    heads side by side, rounded up to whole 128-lane tiles."""
+    return -(-n_kv_heads * head_size // 128) * 128
+
+
+def merge_heads(rows: jnp.ndarray, lanes: int) -> jnp.ndarray:
+    """(a, b, n_kv, hs) rows -> (a, b, lanes): the heads side by side in
+    one lane axis, zero-padded to `lanes` (`kv_lanes`) — a merged-lane
+    pool's row format."""
+    flat = rows.reshape(rows.shape[:2] + (-1,))
+    return jnp.pad(flat, ((0, 0), (0, 0), (0, lanes - flat.shape[2])))
+
+
 def paged_update(pool: jnp.ndarray, new: jnp.ndarray, pos,
                  block_tables: jnp.ndarray) -> jnp.ndarray:
     """Write `new` (B, T, ...) rows into the (n_blocks, bs, ...) pool at
     logical positions [pos, pos+T) of each sequence, addressed through
-    `block_tables` (B, max_blocks) int32.
+    `block_tables` (B, max_blocks) int32. A merged-lane pool
+    (n_blocks, bs, L) takes (B, T, n_kv, hs) rows: heads flattened into
+    lanes, zero-padded to L (module docstring).
 
     Three shapes, mirroring `_update_cache`'s prefill/decode split plus
     the spec-verify short window:
@@ -93,6 +127,9 @@ def paged_update(pool: jnp.ndarray, new: jnp.ndarray, pos,
       in the slot cache.
     """
     new = new.astype(pool.dtype)
+    if new.shape[2:] != pool.shape[2:]:         # a merged-lane pool
+        assert pool.ndim == 3, (new.shape, pool.shape)
+        new = merge_heads(new, pool.shape[2])
     B, T = new.shape[:2]
     bs = pool.shape[1]
     if T == 1:
@@ -121,15 +158,23 @@ def paged_update(pool: jnp.ndarray, new: jnp.ndarray, pos,
     return pool.at[blks].set(vals, mode="drop")
 
 
-def paged_gather(pool: jnp.ndarray, block_tables: jnp.ndarray) -> jnp.ndarray:
+def paged_gather(pool: jnp.ndarray, block_tables: jnp.ndarray,
+                 row_shape: Optional[tuple] = None) -> jnp.ndarray:
     """Materialize the logical (B, max_blocks*bs, ...) view of each
-    sequence's cache for the naive/einsum attention paths. Rows past a
-    sequence's extent map through null/stale blocks and carry garbage —
+    sequence's cache for the naive/einsum attention paths. `row_shape`
+    (n_kv, hs) undoes a merged-lane pool's flattening (`paged_update`):
+    the pad lanes are sliced off and the heads split back out. Rows past
+    a sequence's extent map through null/stale blocks and carry garbage —
     exactly like the slot cache's retired rows, they are causally masked
     to weight 0.0 before they can touch the output."""
     B, n_max = block_tables.shape
     g = pool[block_tables]                      # (B, n_max, bs, ...)
-    return g.reshape((B, n_max * pool.shape[1]) + pool.shape[2:])
+    g = g.reshape((B, n_max * pool.shape[1]) + pool.shape[2:])
+    if row_shape is None or tuple(row_shape) == pool.shape[2:]:
+        return g
+    assert pool.ndim == 3, (row_shape, pool.shape)
+    return g[..., :int(np.prod(row_shape))].reshape(
+        g.shape[:2] + tuple(row_shape))
 
 
 # ---------------------------------------------------------------------------

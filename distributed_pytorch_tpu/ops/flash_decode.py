@@ -40,6 +40,23 @@ single-token attention):
   in the mask. This is the device half of the engine's fused
   chunk+decode step (engine/decode.py `prefill_chunk`); bf16 and int8
   pools ride the same block-table index map.
+* **The paged kernels read merged-lane pools** (ops/block_pool.py
+  `kv_lanes`): a float k/v pool is (n_blocks, bs, L), every kv head's hs
+  lanes side by side and L rounded up to a multiple of 128, because that
+  is the one shape whose default device layout, XLA's in-place row write
+  and a Pallas operand agree on — so the kernel's operand IS the buffer
+  the step's write produced (a (.., 25, 64) pool was copied whole, twice
+  a layer, every step). A (bs, L) tile holds no padding between heads
+  (the head-major tile padded 25 -> 32 and 64 -> 128: 2.56x the bytes),
+  and no body slices it by head or transposes it: the query rows are
+  zero-extended to the tile's lanes instead (a row holds its head's hs
+  values in its kv head's lanes, zeros elsewhere), one product yields
+  every head's scores, one more accumulates p @ v over all lanes, and
+  each row keeps its own head's lanes at the end. Decode does that over
+  the whole L (memory-bound: the surplus multiplies are free); the
+  chunk kernel, which is not, per 128-lane group of whole heads, where a
+  zero-extended 64-wide head costs the same MXU pass as the bare one.
+  The int8 pools keep the head axis and the head-major `_q8` bodies.
 
 Contract: gate with `flash_decode_usable` (or its `*_decline` twin, which
 says WHY) first. `FLASH_DECODE=auto|on|off` (read per call, so tests can
@@ -264,11 +281,69 @@ def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return out.reshape(B, nh, hs)
 
 
-def _paged_body(cl_ref, bt_ref, *args, scale: float, block_s: int):
-    """Paged bf16 kernel: identical online-softmax body — the block table
-    ref is consumed by the index maps only."""
+def _lane_head(shape, hs: int) -> jnp.ndarray:
+    """int32 `shape`: the head whose hs lanes each position's lane (last
+    axis) falls in, `lane // hs`. The merged-lane bodies use it to pick
+    each head's own output lanes out of a full-width accumulator."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+    return jax.lax.div(lane, jnp.int32(hs))
+
+
+def _paged_kernel(cl_ref, bt_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
+                  m_ref, l_ref, *, scale: float, bs: int, hs: int,
+                  rep: int):
+    """Paged decode over MERGED-LANE pools (ops/block_pool.py `kv_lanes`):
+    a k/v tile is (bs, L), every kv head's hs lanes side by side. The
+    query rows arrive zero-extended to L lanes — row (r, g) holds query
+    head g*rep + r in kv head g's lanes and zeros elsewhere — so ONE
+    (R, L) x (L, bs) product gives every head's scores (the other heads'
+    lanes multiply zeros, exactly) and ONE (R, bs) x (bs, L) product
+    accumulates p @ v for all lanes; each row keeps only its own head's
+    lanes at the end. No per-head slice at a 64-lane offset, no in-VMEM
+    transpose of the tile, and the output is written lane-dense in the
+    model's (.., n_kv * hs) order. The block table ref is consumed by
+    the index maps only."""
     del bt_ref
-    _kernel(cl_ref, *args, scale=scale, block_s=block_s)
+    b, j = pl.program_id(0), pl.program_id(1)
+    n = cl_ref[b]
+    last_j = jax.lax.div(jnp.maximum(n, 1) - 1, bs)
+
+    @pl.when(j == 0)
+    def _():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    @pl.when(j <= last_j)
+    def _():
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]  # (R, L), (bs, L), (bs, L)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale      # (R, bs) f32
+        kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(kpos < n, s, _NEG_INF)
+        m_prev, l_prev = m_ref[:], l_ref[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        m_ref[:] = m_new
+        l_ref[:] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)              # (R, L) f32
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        out = acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
+        g_pad = out.shape[0] // rep
+        shape = (g_pad, out.shape[1])
+        own = _lane_head(shape, hs) == jax.lax.broadcasted_iota(
+            jnp.int32, shape, 0)                # row g keeps kv head g's lanes
+        for r in range(rep):                    # rows (r, g): one slab per r
+            slab = out[r * g_pad:(r + 1) * g_pad]
+            o_ref[0, r:r + 1, :] = jnp.sum(
+                jnp.where(own, slab, 0.0), axis=0,
+                keepdims=True).astype(o_ref.dtype)
 
 
 def _paged_body_q8(cl_ref, bt_ref, *args, scale: float, block_s: int):
@@ -276,15 +351,47 @@ def _paged_body_q8(cl_ref, bt_ref, *args, scale: float, block_s: int):
     _kernel_q8(cl_ref, *args, scale=scale, block_s=block_s)
 
 
+def _zero_extend_q(q: jnp.ndarray, nkv: int, g_pad: int,
+                   lanes: int) -> jnp.ndarray:
+    """q (B, nh, hs) -> (B, rep * g_pad, lanes): row (r, g) is query head
+    g*rep + r laid into kv head g's hs lanes of a merged-lane row, zeros
+    everywhere else (`_paged_kernel`). g_pad >= nkv pads each r-slab to a
+    sublane multiple."""
+    B, nh, hs = q.shape
+    rep = nh // nkv
+    q4 = q.reshape(B, nkv, rep, hs).transpose(0, 2, 1, 3)   # (B, rep, g, hs)
+    own = jnp.eye(nkv, dtype=bool)[None, None, :, :, None]
+    qz = jnp.where(own, q4[:, :, :, None, :], 0) \
+        .reshape(B, rep, nkv, nkv * hs)
+    qz = jnp.pad(qz, ((0, 0), (0, 0), (0, g_pad - nkv),
+                      (0, lanes - nkv * hs)))
+    return qz.reshape(B, rep * g_pad, lanes)
+
+
+# The two paged entry points are jitted on their own: a model calls them
+# once a layer, and a jitted callee is traced and lowered once per program
+# where a plain function is once per call site (48 layers x up to two
+# kernels a step program) — seconds of every engine start, cached or cold.
+@functools.partial(jax.jit, static_argnames=("scale", "n_kv_heads",
+                                             "interpret"))
 def paged_flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                        block_tables: jnp.ndarray, cache_len: jnp.ndarray, *,
-                       scale: float, k_scale: jnp.ndarray = None,
+                       scale: float, n_kv_heads: int = 0,
+                       k_scale: jnp.ndarray = None,
                        v_scale: jnp.ndarray = None,
                        interpret: bool = False) -> jnp.ndarray:
     """Single-token cached attention over a PAGED cache: q (B, nh, hs)
-    against (n_blocks, bs, n_kv, hs) pool buffers (ops/block_pool.py),
-    with per-sequence block tables (B, max_blocks) int32 and valid
-    lengths `cache_len` (B,). Returns (B, nh, hs).
+    against the pool buffers (ops/block_pool.py), with per-sequence block
+    tables (B, max_blocks) int32 and valid lengths `cache_len` (B,).
+    Returns (B, nh, hs).
+
+    The pools are the merged-lane (n_blocks, bs, L) leaves
+    (`block_pool.kv_lanes`; `n_kv_heads` says how many heads share the L
+    lanes) — dense row-major on the device, so the kernel reads the very
+    buffer the step's in-place write produced, one (1, bs, L) tile per
+    grid step with no padding in it. int8 pools keep the head axis,
+    (n_blocks, bs, n_kv, hs) codes + (.., n_kv, 1) float32 scale
+    sidecars, and the `_q8` body.
 
     This is the contiguous kernel's `cache_len` scalar-prefetch
     generalized by ONE indirection: the grid walks each sequence's
@@ -293,23 +400,20 @@ def paged_flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     table. The dead-block machinery is unchanged — steps past a
     sequence's last valid block clamp to it, the revolving-buffer DMA
     sees an unchanged physical index and fetches nothing, and the last
-    partial block masks `kpos >= cache_len`. int8 pools bring their
-    scale-sidecar pools through the same index map. Gate with
+    partial block masks `kpos >= cache_len`. Gate with
     `paged_flash_decode_usable`."""
     B, nh, hs = q.shape
-    bs, nkv = k.shape[1], k.shape[2]
+    bs = k.shape[1]
     n_max = block_tables.shape[1]
-    rep = nh // nkv
     quantized = k_scale is not None
     assert quantized == (v_scale is not None), \
         "int8 cache needs both k_scale and v_scale"
+    nkv = k.shape[2] if quantized else n_kv_heads
+    assert nkv and nh % nkv == 0, (nh, nkv)
+    rep = nh // nkv
 
     cl = jnp.asarray(cache_len, jnp.int32).reshape(B)
     bt = jnp.asarray(block_tables, jnp.int32)
-    q4 = q.reshape(B, nkv, rep, hs)
-
-    def q_idx(b, j, cl_ref, bt_ref):
-        return (b, 0, 0, 0)
 
     def kv_idx(b, j, cl_ref, bt_ref):
         # clamp skipped steps to the last valid LOGICAL block, then map to
@@ -317,61 +421,92 @@ def paged_flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         # index -> no DMA for dead blocks (same trick as the contiguous
         # kernel, one table lookup deeper)
         last = jax.lax.div(jnp.maximum(cl_ref[b], 1) - 1, bs)
-        return (bt_ref[b, jnp.minimum(j, last)], 0, 0, 0)
+        return (bt_ref[b, jnp.minimum(j, last)],) + (0,) * (k.ndim - 1)
 
-    in_specs = [pl.BlockSpec((1, nkv, rep, hs), q_idx)]
-    operands = [q4]
     if quantized:
-        in_specs += [
-            pl.BlockSpec((1, bs, nkv, hs), kv_idx),
-            pl.BlockSpec((1, bs, nkv, 1), kv_idx),
-            pl.BlockSpec((1, bs, nkv, hs), kv_idx),
-            pl.BlockSpec((1, bs, nkv, 1), kv_idx),
-        ]
-        operands += [k, k_scale.astype(jnp.float32),
-                     v, v_scale.astype(jnp.float32)]
-        body = _paged_body_q8
-    else:
-        in_specs += [
-            pl.BlockSpec((1, bs, nkv, hs), kv_idx),
-            pl.BlockSpec((1, bs, nkv, hs), kv_idx),
-        ]
-        operands += [k, v]
-        body = _paged_body
+        def q_idx(b, j, cl_ref, bt_ref):
+            return (b, 0, 0, 0)
+
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, n_max),
+            in_specs=[pl.BlockSpec((1, nkv, rep, hs), q_idx),
+                      pl.BlockSpec((1, bs, nkv, hs), kv_idx),
+                      pl.BlockSpec((1, bs, nkv, 1), kv_idx),
+                      pl.BlockSpec((1, bs, nkv, hs), kv_idx),
+                      pl.BlockSpec((1, bs, nkv, 1), kv_idx)],
+            out_specs=pl.BlockSpec((1, nkv, rep, hs), q_idx),
+            scratch_shapes=[
+                pltpu.VMEM((nkv, rep, hs), jnp.float32),
+                pltpu.VMEM((nkv, rep, 1), jnp.float32),
+                pltpu.VMEM((nkv, rep, 1), jnp.float32),
+            ],
+        )
+        out = pl.pallas_call(
+            functools.partial(_paged_body_q8, scale=float(scale),
+                              block_s=bs),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((B, nkv, rep, hs), q.dtype),
+            compiler_params=tpu_compiler_params(
+                dimension_semantics=("parallel", "arbitrary")),
+            name="paged_flash_decode_q8",
+            interpret=interpret,
+        )(cl, bt, q.reshape(B, nkv, rep, hs), k,
+          k_scale.astype(jnp.float32), v, v_scale.astype(jnp.float32))
+        return out.reshape(B, nh, hs)
+
+    L = k.shape[2]
+    g_pad = -(-nkv // 8) * 8
+    R = rep * g_pad
+
+    def q_idx(b, j, cl_ref, bt_ref):
+        return (b, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, n_max),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, nkv, rep, hs), q_idx),
+        in_specs=[pl.BlockSpec((1, R, L), q_idx),
+                  pl.BlockSpec((1, bs, L), kv_idx),
+                  pl.BlockSpec((1, bs, L), kv_idx)],
+        out_specs=pl.BlockSpec((1, rep, L), q_idx),
         scratch_shapes=[
-            pltpu.VMEM((nkv, rep, hs), jnp.float32),
-            pltpu.VMEM((nkv, rep, 1), jnp.float32),
-            pltpu.VMEM((nkv, rep, 1), jnp.float32),
+            pltpu.VMEM((R, L), jnp.float32),
+            pltpu.VMEM((R, 1), jnp.float32),
+            pltpu.VMEM((R, 1), jnp.float32),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(body, scale=float(scale), block_s=bs),
+        functools.partial(_paged_kernel, scale=float(scale), bs=bs, hs=hs,
+                          rep=rep),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, nkv, rep, hs), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, rep, L), q.dtype),
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
-        name="paged_flash_decode" + ("_q8" if quantized else ""),
+        name="paged_flash_decode",
         interpret=interpret,
-    )(cl, bt, *operands)
-    return out.reshape(B, nh, hs)
+    )(cl, bt, _zero_extend_q(q, nkv, g_pad, L), k, v)
+    # (B, rep, L): lane l of row r is head (l // hs) * rep + r
+    return out[:, :, :nkv * hs].reshape(B, rep, nkv, hs) \
+        .transpose(0, 2, 1, 3).reshape(B, nh, hs)
 
 
 def _prefill_kernel(meta_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
                     acc_ref, m_ref, l_ref, *, scale: float, bs: int,
-                    rep: int):
-    """Chunked-prefill body: T queries of ONE sequence (packed (t, rep)
-    into the sublane dim) against its own paged blocks, causal against
-    the global positions `off + t`. Same online-softmax state as the
-    decode kernels — only the mask gains the per-row query position."""
-    j = pl.program_id(0)
+                    hs: int, rep: int):
+    """Chunked-prefill body over MERGED-LANE pools: T queries of ONE
+    sequence against its own paged blocks, causal against the global
+    positions `off + t`. Grid (lane groups, logical blocks): a group is
+    the max(hs, 128) lanes of a k/v tile that hold whole heads (two
+    64-wide heads, or one head of 128+), so a step moves a (bs, lanes)
+    tile of exactly the heads it computes. Per head of the group the
+    (t, rep)-packed query rows arrive zero-extended to the group's lanes
+    (`_paged_kernel`'s trick at group width: the contraction is one MXU
+    pass deep either way), the online-softmax state is indexed by head,
+    and the output block is written lane-dense, each head's own lanes
+    picked out of its accumulator."""
+    j = pl.program_id(1)
     off = meta_ref[0]
-    n_rows = q_ref.shape[1]                     # T * rep (static)
+    hpg, n_rows = q_ref.shape[1], q_ref.shape[2]    # heads a group, T * rep
     T = n_rows // rep
     last_j = jax.lax.div(jnp.maximum(off + T, 1) - 1, bs)
 
@@ -383,30 +518,34 @@ def _prefill_kernel(meta_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(j <= last_j)
     def _():
-        q = q_ref[:]                            # (nkv, T*rep, hs)
-        k = k_ref[0].transpose(1, 0, 2)         # (nkv, bs, hs)
-        v = v_ref[0].transpose(1, 0, 2)
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale  # (nkv, T*rep, bs)
+        k, v = k_ref[0], v_ref[0]               # (bs, lanes)
         qpos = off + jax.lax.div(
-            jax.lax.broadcasted_iota(jnp.int32, s.shape, 1), rep)
-        kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        s = jnp.where(kpos <= qpos, s, _NEG_INF)
-        m_prev, l_prev = m_ref[:], l_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        m_ref[:] = m_new
-        l_ref[:] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
+            jax.lax.broadcasted_iota(jnp.int32, (n_rows, bs), 0), rep)
+        kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (n_rows, bs), 1)
+        visible = kpos <= qpos
+        for i in range(hpg):
+            s = jax.lax.dot_general(
+                q_ref[0, i], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # (T*rep, bs)
+            s = jnp.where(visible, s, _NEG_INF)
+            m_prev, l_prev = m_ref[i], l_ref[i]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            m_ref[i] = m_new
+            l_ref[i] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[i] = acc_ref[i] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
-    @pl.when(j == pl.num_programs(0) - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _():
-        o_ref[:] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
-                    ).astype(o_ref.dtype)
+        head = _lane_head(o_ref.shape, hs)
+        out = jnp.zeros(o_ref.shape, jnp.float32)
+        for i in range(hpg):
+            out = jnp.where(
+                head == i, acc_ref[i] / jnp.maximum(l_ref[i], 1e-30), out)
+        o_ref[:] = out.astype(o_ref.dtype)
 
 
 def _prefill_kernel_q8(meta_ref, bt_ref, q_ref, k_ref, ks_ref, v_ref,
@@ -459,18 +598,23 @@ def _prefill_kernel_q8(meta_ref, bt_ref, q_ref, k_ref, ks_ref, v_ref,
                     ).astype(o_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=("scale", "n_kv_heads",
+                                             "interpret"))
 def paged_flash_prefill(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         block_tables: jnp.ndarray, q_offset, *,
-                        scale: float, k_scale: jnp.ndarray = None,
+                        scale: float, n_kv_heads: int = 0,
+                        k_scale: jnp.ndarray = None,
                         v_scale: jnp.ndarray = None,
                         interpret: bool = False) -> jnp.ndarray:
     """Mixed-path chunk attention over a PAGED cache: q (1, T, nh, hs) —
     a prefill chunk of ONE sequence whose rows sit at global positions
-    [q_offset, q_offset+T) — against the (n_blocks, bs, n_kv, hs) pool,
-    addressed through the sequence's block table (1, max_blocks) int32.
-    The chunk's rows must already be written to the pool (the attention
-    path writes before it reads, exactly like the wave prefill). Returns
-    (1, T, nh, hs).
+    [q_offset, q_offset+T) — against the merged-lane (n_blocks, bs, L)
+    pool (`block_pool.kv_lanes`, `n_kv_heads` heads in the L lanes; int8
+    pools keep (n_blocks, bs, n_kv, hs) + scale sidecars and the `_q8`
+    body), addressed through the sequence's block table (1, max_blocks)
+    int32. The chunk's rows must already be written to the pool (the
+    attention path writes before it reads, exactly like the wave
+    prefill). Returns (1, T, nh, hs).
 
     This is `paged_flash_decode` generalized from one query row to a
     (t, rep)-packed query tile: the grid still walks logical blocks with
@@ -479,73 +623,111 @@ def paged_flash_prefill(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     each row's global position `q_offset + t` against the block's key
     positions — so a chunk at an arbitrary block-aligned offset attends
     the sequence's own prior blocks and its own in-chunk prefix, never a
-    neighbor's. int8 pools ride the same index map (`k_scale`/`v_scale`
-    sidecar pools). Gate with `paged_flash_prefill_usable`."""
+    neighbor's. Gate with `paged_flash_prefill_usable`."""
     B, T, nh, hs = q.shape
     assert B == 1, "chunk prefill attends one sequence at a time"
-    bs, nkv = k.shape[1], k.shape[2]
+    bs = k.shape[1]
     n_max = block_tables.shape[1]
-    rep = nh // nkv
     quantized = k_scale is not None
     assert quantized == (v_scale is not None), \
         "int8 cache needs both k_scale and v_scale"
+    nkv = k.shape[2] if quantized else n_kv_heads
+    assert nkv and nh % nkv == 0, (nh, nkv)
+    rep = nh // nkv
+    rows = T * rep
 
     meta = jnp.reshape(jnp.asarray(q_offset, jnp.int32), (1,))
     bt = jnp.asarray(block_tables, jnp.int32).reshape(n_max)
     # pack (t, rep) into the sublane dim: row r of kv head g is query
     # head g*rep + r%rep at chunk position r//rep
     q3 = q[0].reshape(T, nkv, rep, hs).transpose(1, 0, 2, 3) \
-        .reshape(nkv, T * rep, hs)
+        .reshape(nkv, rows, hs)
 
-    def q_idx(j, meta_ref, bt_ref):
-        return (0, 0, 0)
+    def last_block(meta_ref):
+        return jax.lax.div(jnp.maximum(meta_ref[0] + T, 1) - 1, bs)
 
-    def kv_idx(j, meta_ref, bt_ref):
-        last = jax.lax.div(jnp.maximum(meta_ref[0] + T, 1) - 1, bs)
-        return (bt_ref[jnp.minimum(j, last)], 0, 0, 0)
-
-    in_specs = [pl.BlockSpec((nkv, T * rep, hs), q_idx)]
-    operands = [q3]
     if quantized:
-        in_specs += [
-            pl.BlockSpec((1, bs, nkv, hs), kv_idx),
-            pl.BlockSpec((1, bs, nkv, 1), kv_idx),
-            pl.BlockSpec((1, bs, nkv, hs), kv_idx),
-            pl.BlockSpec((1, bs, nkv, 1), kv_idx),
-        ]
-        operands += [k, k_scale.astype(jnp.float32),
-                     v, v_scale.astype(jnp.float32)]
-        body = _prefill_kernel_q8
-    else:
-        in_specs += [
-            pl.BlockSpec((1, bs, nkv, hs), kv_idx),
-            pl.BlockSpec((1, bs, nkv, hs), kv_idx),
-        ]
-        operands += [k, v]
-        body = _prefill_kernel
+        def q_idx(j, meta_ref, bt_ref):
+            return (0, 0, 0)
+
+        def kv_idx(j, meta_ref, bt_ref):
+            return (bt_ref[jnp.minimum(j, last_block(meta_ref))], 0, 0, 0)
+
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n_max,),
+            in_specs=[pl.BlockSpec((nkv, rows, hs), q_idx),
+                      pl.BlockSpec((1, bs, nkv, hs), kv_idx),
+                      pl.BlockSpec((1, bs, nkv, 1), kv_idx),
+                      pl.BlockSpec((1, bs, nkv, hs), kv_idx),
+                      pl.BlockSpec((1, bs, nkv, 1), kv_idx)],
+            out_specs=pl.BlockSpec((nkv, rows, hs), q_idx),
+            scratch_shapes=[
+                pltpu.VMEM((nkv, rows, hs), jnp.float32),
+                pltpu.VMEM((nkv, rows, 1), jnp.float32),
+                pltpu.VMEM((nkv, rows, 1), jnp.float32),
+            ],
+        )
+        out = pl.pallas_call(
+            functools.partial(_prefill_kernel_q8, scale=float(scale),
+                              bs=bs, rep=rep),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((nkv, rows, hs), q.dtype),
+            compiler_params=tpu_compiler_params(
+                dimension_semantics=("arbitrary",)),
+            name="paged_flash_prefill_q8",
+            interpret=interpret,
+        )(meta, bt, q3, k, k_scale.astype(jnp.float32),
+          v, v_scale.astype(jnp.float32))
+        return out.reshape(nkv, T, rep, hs).transpose(1, 0, 2, 3) \
+            .reshape(1, T, nh, hs)
+
+    L = k.shape[2]
+    gl = max(hs, 128)                           # lanes of one head group
+    hpg, n_groups = gl // hs, L // gl
+    # zero-extend each head's rows to its group's lanes (own hs lanes,
+    # zeros in its neighbours'); heads past n_kv are the pool's pad lanes
+    q3 = jnp.pad(q3, ((0, n_groups * hpg - nkv), (0, 0), (0, 0))) \
+        .reshape(n_groups, hpg, rows, hs)
+    own = jnp.eye(hpg, dtype=bool)[None, :, None, :, None]
+    qz = jnp.where(own, q3[:, :, :, None, :], 0) \
+        .reshape(n_groups, hpg, rows, gl)
+
+    def q_idx(g, j, meta_ref, bt_ref):
+        return (g, 0, 0, 0)
+
+    def kv_idx(g, j, meta_ref, bt_ref):
+        return (bt_ref[jnp.minimum(j, last_block(meta_ref))], 0, g)
+
+    def o_idx(g, j, meta_ref, bt_ref):
+        return (0, g)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(n_max,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((nkv, T * rep, hs), q_idx),
+        grid=(n_groups, n_max),
+        in_specs=[pl.BlockSpec((1, hpg, rows, gl), q_idx),
+                  pl.BlockSpec((1, bs, gl), kv_idx),
+                  pl.BlockSpec((1, bs, gl), kv_idx)],
+        out_specs=pl.BlockSpec((rows, gl), o_idx),
         scratch_shapes=[
-            pltpu.VMEM((nkv, T * rep, hs), jnp.float32),
-            pltpu.VMEM((nkv, T * rep, 1), jnp.float32),
-            pltpu.VMEM((nkv, T * rep, 1), jnp.float32),
+            pltpu.VMEM((hpg, rows, gl), jnp.float32),
+            pltpu.VMEM((hpg, rows, 1), jnp.float32),
+            pltpu.VMEM((hpg, rows, 1), jnp.float32),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(body, scale=float(scale), bs=bs, rep=rep),
+        functools.partial(_prefill_kernel, scale=float(scale), bs=bs,
+                          hs=hs, rep=rep),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nkv, T * rep, hs), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((rows, L), q.dtype),
         compiler_params=tpu_compiler_params(
-            dimension_semantics=("arbitrary",)),
-        name="paged_flash_prefill" + ("_q8" if quantized else ""),
+            dimension_semantics=("parallel", "arbitrary")),
+        name="paged_flash_prefill",
         interpret=interpret,
-    )(meta, bt, *operands)
-    return out.reshape(nkv, T, rep, hs).transpose(1, 0, 2, 3) \
-        .reshape(1, T, nh, hs)
+    )(meta, bt, qz, k, v)
+    # (T * rep, L): lane l of row (t, r) is head (l // hs) * rep + r
+    return out[:, :nkv * hs].reshape(T, rep, nkv, hs) \
+        .transpose(0, 2, 1, 3).reshape(1, T, nh, hs)
 
 
 def _common_decline(q, k, nh, nkv, hs, bs, what: str):
@@ -555,7 +737,7 @@ def _common_decline(q, k, nh, nkv, hs, bs, what: str):
         return f"query dtype {q.dtype} (kernel handles float32 / bfloat16)"
     if k.dtype != q.dtype and k.dtype != jnp.int8:
         return f"cache dtype {k.dtype} is neither {q.dtype} nor int8"
-    if hs % 8 != 0 or nh % nkv != 0:
+    if hs % 8 != 0 or not nkv or nh % nkv != 0:
         return f"head geometry nh={nh}, n_kv={nkv}, hs={hs}"
     step = 128 if jax.default_backend() == "tpu" else 8
     if bs % step != 0:
@@ -576,57 +758,83 @@ def _budget_decline(need: int):
             f"{_VMEM_BUDGET >> 20} MiB scoped limit (FLASH_VMEM_BUDGET_MB)")
 
 
-def _kv_tile_bytes(k, bs: int, nkv: int, hs: int) -> int:
-    """Double-buffered k+v tiles (+ f32 scale rows for an int8 cache)."""
-    tiles = 2 * 2 * bs * nkv * hs * jnp.dtype(k.dtype).itemsize
+def _kv_tile_bytes(k, rows: int, width: int) -> int:
+    """Double-buffered k+v tiles of (rows, width) elements — width is the
+    merged lanes a step moves, or n_kv * hs of a head-major tile (+ its
+    f32 scale rows for an int8 cache)."""
+    tiles = 2 * 2 * rows * width * jnp.dtype(k.dtype).itemsize
     if k.dtype == jnp.int8:
-        tiles += 2 * 2 * bs * nkv * 4
+        tiles += 2 * 2 * rows * k.shape[2] * 4
     return tiles
 
 
-def paged_flash_prefill_decline(q, k, v, block_tables):
+def _pool_heads(k, n_kv_heads: int) -> int:
+    """kv heads of a pool leaf: the int8 pools carry the axis, the
+    merged-lane ones are told (`block_pool.kv_lanes`)."""
+    return k.shape[2] if k.ndim == 4 else n_kv_heads
+
+
+def paged_flash_prefill_decline(q, k, v, block_tables, n_kv_heads: int = 0):
     """Why the chunk-prefill kernel cannot take this call (None = it
     can), mirroring `paged_flash_decode_decline`: one sequence's
     (1, T>1, nh, hs) chunk, whole-block pool pages the hardware tiles, T
-    a multiple of the sublane step, and the packed query tile + f32
-    accumulator within the VMEM budget. The fallback is paged_gather +
-    the naive masked path — identical semantics."""
+    a multiple of the sublane step, heads that tile the merged lanes in
+    whole 128-lane groups, and one group's query tile + f32 accumulator
+    within the VMEM budget. The fallback is paged_gather + the naive
+    masked path — identical semantics."""
     if q.ndim != 4 or q.shape[0] != 1 or q.shape[1] <= 1:
         return f"query shape {q.shape} is not one sequence's (1, T>1) chunk"
     _, T, nh, hs = q.shape
-    bs, nkv = k.shape[1], k.shape[2]
+    bs, nkv = k.shape[1], _pool_heads(k, n_kv_heads)
     if T % 8 != 0:
         return f"chunk length {T} is not a sublane (8) multiple"
     why = _common_decline(q, k, nh, nkv, hs, bs, f"pool block size {bs}")
     if why is not None:
         return why
     rows = T * (nh // nkv)
-    dsize = jnp.dtype(k.dtype).itemsize
-    qtile = nkv * rows * hs * dsize
-    scratch = nkv * rows * (hs + 2) * 4
-    scores = 3 * nkv * rows * bs * 4
-    return _budget_decline(_kv_tile_bytes(k, bs, nkv, hs) + qtile + scratch
+    if k.ndim == 4:                 # int8 head-major tiles: every head a step
+        heads, qw, width = nkv, hs, nkv * hs
+    elif 128 % hs != 0 and hs % 128 != 0:
+        return (f"head size {hs} neither divides nor is a multiple of the "
+                "128 lanes a head group is cut by")
+    else:                           # one lane group: its heads, one at a time
+        width = qw = max(hs, 128)
+        heads = width // hs
+    qtile = heads * rows * qw * jnp.dtype(q.dtype).itemsize
+    scratch = heads * rows * (qw + 2) * 4
+    scores = 3 * (heads if k.ndim == 4 else 1) * rows * bs * 4
+    return _budget_decline(_kv_tile_bytes(k, bs, width) + qtile + scratch
                            + scores)
 
 
-def paged_flash_decode_decline(q, k, v, block_tables):
+def paged_flash_decode_decline(q, k, v, block_tables, n_kv_heads: int = 0):
     """Why the paged kernel cannot take this call (None = it can),
     mirroring `flash_decode_decline`: decode-shaped (B, 1, nh, hs) query,
     pool block size the hardware tiles (multiples of 128 rows on TPU —
     small CPU-test pages run in interpret mode at multiples of 8), no live
-    multi-device mesh. The fallback is paged_gather + the naive path —
-    identical semantics."""
+    multi-device mesh, and the (bs, L) tiles + zero-extended query rows +
+    full-width accumulator within the VMEM budget. The fallback is
+    paged_gather + the naive path — identical semantics."""
     if q.ndim != 4 or q.shape[1] != 1:
         return f"query shape {q.shape} is not decode-shaped (B, 1, nh, hs)"
     _, _, nh, hs = q.shape
-    bs, nkv = k.shape[1], k.shape[2]
+    bs, nkv = k.shape[1], _pool_heads(k, n_kv_heads)
     why = _common_decline(q, k, nh, nkv, hs, bs, f"pool block size {bs}")
     if why is not None:
         return why
     rep = nh // nkv
-    scratch = nkv * rep * (hs + 2) * 4
-    scores = 3 * nkv * rep * bs * 4
-    return _budget_decline(_kv_tile_bytes(k, bs, nkv, hs) + scratch + scores)
+    if k.ndim == 4:                             # int8: head-major tiles
+        scratch = nkv * rep * (hs + 2) * 4
+        scores = 3 * nkv * rep * bs * 4
+        return _budget_decline(_kv_tile_bytes(k, bs, nkv * hs) + scratch
+                               + scores)
+    L = k.shape[2]
+    R = rep * (-(-nkv // 8) * 8)                # zero-extended query rows
+    qtile = 2 * R * L * jnp.dtype(q.dtype).itemsize
+    scratch = R * (L + 2) * 4 + R * L * 4       # accumulator + its epilogue
+    scores = 3 * R * bs * 4
+    return _budget_decline(_kv_tile_bytes(k, bs, L) + qtile + scratch
+                           + scores)
 
 
 def flash_decode_decline(q, k, v):
@@ -650,16 +858,20 @@ def flash_decode_decline(q, k, v):
     rep = nh // nkv
     scratch = nkv * rep * (hs + 2) * 4
     scores = 3 * nkv * rep * block_s * 4                # s, p, mask temps
-    return _budget_decline(_kv_tile_bytes(k, block_s, nkv, hs) + scratch
+    return _budget_decline(_kv_tile_bytes(k, block_s, nkv * hs) + scratch
                            + scores)
 
 
-def paged_flash_prefill_usable(q, k, v, block_tables) -> bool:
-    return paged_flash_prefill_decline(q, k, v, block_tables) is None
+def paged_flash_prefill_usable(q, k, v, block_tables,
+                               n_kv_heads: int = 0) -> bool:
+    return paged_flash_prefill_decline(q, k, v, block_tables,
+                                       n_kv_heads) is None
 
 
-def paged_flash_decode_usable(q, k, v, block_tables) -> bool:
-    return paged_flash_decode_decline(q, k, v, block_tables) is None
+def paged_flash_decode_usable(q, k, v, block_tables,
+                              n_kv_heads: int = 0) -> bool:
+    return paged_flash_decode_decline(q, k, v, block_tables,
+                                      n_kv_heads) is None
 
 
 def flash_decode_usable(q, k, v) -> bool:

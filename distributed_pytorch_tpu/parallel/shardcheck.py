@@ -387,19 +387,22 @@ def check_config(model_cfg: LLMConfig, recipe: str,
         shape_counts: dict[tuple, int] = {}
         for shape in cache_shapes(model_cfg):
             shape_counts[shape] = shape_counts.get(shape, 0) + 1
+        # float GQA pools merge their heads into lanes (block_pool.kv_lanes)
+        kv_heads = ((model_cfg.n_kv_heads, model_cfg.head_size)
+                    if model_cfg.attn != "mla" else None)
         for shape, n_buf in shape_counts.items():
-            cspec = shd.decode_cache_pspec(shape, mesh)
+            cspec = shd.decode_cache_pspec(shape, mesh, kv_heads)
             findings += check_spec(cspec, shape, sizes, table="cache",
                                    path=f"kv{shape}x{n_buf}")
             entries = _spec_entries(cspec)
-            if (len(shape) == 4 and sizes["model"] > 1 and shape[2] > 1
-                    and not entries[2]):
+            heads = kv_heads[0] if kv_heads else 1
+            if sizes["model"] > 1 and heads > 1 and not entries[2]:
                 findings.append(Finding(
                     "cache", "warn", "cache", f"kv{shape}x{n_buf}",
-                    f"kv-head axis ({shape[2]} heads) replicated across "
+                    f"kv heads ({heads}) replicated across "
                     f"model={sizes['model']} — every model shard holds "
-                    f"the full cache ({shape[2]} % {sizes['model']} != "
-                    f"0)"))
+                    f"the full cache ({heads} % {sizes['model']} != 0, or "
+                    f"the {shape[2]} merged lanes carry pad)"))
 
     # MoE dispatch specs are static — validate their axis names/shapes
     if model_cfg.moe:

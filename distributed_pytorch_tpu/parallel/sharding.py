@@ -246,19 +246,36 @@ def moe_dispatch_specs() -> tuple[P, P, P]:
     return tok, w, P("data", None)
 
 
-def decode_cache_pspec(shape: tuple[int, ...], mesh: Mesh) -> P:
+def decode_cache_pspec(shape: tuple[int, ...], mesh: Mesh,
+                       kv_heads: Optional[tuple[int, int]] = None) -> P:
     """PartitionSpec for one decode KV-cache buffer (engine.DecodeEngine).
 
-    GQA buffers (B_slots, S, n_kv, hs) shard the kv-head axis over 'model'
-    (the megatron layout: the qkv projection already emits head-sharded
-    activations under tp, so cache reads/writes stay local) and the slot
-    axis over 'data'; MLA latent buffers (B_slots, S, latent[, dhr])
-    have no head axis — slots over 'data' only. One definition here so the
-    engine's cache layout cannot drift from the recipe tables above."""
+    The kv heads go over 'model' (the megatron layout: the qkv projection
+    already emits head-sharded activations under tp, so cache reads/writes
+    stay local) and the leading (block / slot) axis over 'data'. Where the
+    heads live depends on the leaf:
+    * int8 codes and scale sidecars (n_blocks, bs, n_kv, hs|1): the head
+      axis is index 2 of the 4-D shape;
+    * float GQA pools (n_blocks, bs, L) merge the heads into lanes
+      (`ops.block_pool.kv_lanes`) — the caller says so with `kv_heads` =
+      (n_kv_heads, head_size). A lane split is a head split only when the
+      lanes carry no pad (L == n_kv * hs) and the heads divide, so those
+      pools shard axis 2; a padded one (gpt2-xl's 25 x 64 -> 1664) stays
+      whole on every model shard, as its 25 heads did before;
+    * MLA latent buffers (.., latent[, dhr]) have no head axis (`kv_heads`
+      None) — blocks over 'data' only.
+    One definition here so the engine's cache layout cannot drift from the
+    recipe tables above."""
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     axes: list[Optional[str]] = [None] * len(shape)
-    if (len(shape) == 4 and sizes.get("model", 1) > 1
-            and shape[2] % sizes["model"] == 0 and shape[2] > 1):
+    tp = sizes.get("model", 1)
+    if len(shape) == 4:
+        heads = shape[2]
+    elif kv_heads is not None and shape[2] == kv_heads[0] * kv_heads[1]:
+        heads = kv_heads[0]
+    else:
+        heads = 1
+    if tp > 1 and heads > 1 and heads % tp == 0:
         axes[2] = "model"
     if sizes.get("data", 1) > 1 and shape[0] % sizes["data"] == 0:
         axes[0] = "data"

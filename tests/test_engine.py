@@ -121,13 +121,14 @@ def test_cache_full_retires_before_wrap():
 
 def test_engine_tp_mesh_sharded_cache():
     """The engine decodes under a tensor-parallel CPU mesh: params laid
-    out by the tp recipe tables, cache kv-head axis sharded over 'model',
-    and greedy outputs identical to the unsharded engine."""
+    out by the tp recipe tables, the merged-lane pools' lanes (2 kv heads
+    x 64 = 128, no pad: a lane split is a head split) sharded over
+    'model', and greedy outputs identical to the unsharded engine."""
     from distributed_pytorch_tpu.parallel.mesh import mesh_for
 
     if len(jax.devices()) < 2:
         pytest.skip("needs multi-device CPU platform")
-    cfg = tiny_cfg(attn="gqa", n_kv_heads=2, n_head=4)
+    cfg = tiny_cfg(attn="gqa", n_kv_heads=2, n_head=4, n_embd=256)
     model, variables = build(cfg)
     ref_eng = DecodeEngine(model, variables, n_slots=2, temperature=0.0,
                            min_bucket=8)
@@ -136,11 +137,33 @@ def test_engine_tp_mesh_sharded_cache():
     mesh = mesh_for("tp", tp_size=2)
     eng = DecodeEngine(model, variables, n_slots=2, temperature=0.0,
                        min_bucket=8, mesh=mesh, recipe="tp")
-    k_cache = eng.caches[0]["k"]  # (slots, S, n_kv, hs)
+    k_cache = eng.caches[0]["k"]  # (n_blocks, bs, n_kv * hs)
+    assert k_cache.shape[2] == 128
     spec = k_cache.sharding.spec
-    assert spec[2] == "model", f"kv-head axis not tp-sharded: {spec}"
+    assert spec[2] == "model", f"kv heads not tp-sharded: {spec}"
     outs = eng.run(PROMPTS[:4], max_new_tokens=5)
     assert outs == refs
+
+
+def test_engine_tp_mesh_padded_lanes_stay_whole():
+    """2 kv heads x 12 = 24 lanes padded to 128: a lane split would cut
+    across heads and pad, so under tp the pool is whole on every model
+    shard (sharding.decode_cache_pspec), and decoding still agrees."""
+    from distributed_pytorch_tpu.parallel.mesh import mesh_for
+
+    if len(jax.devices()) < 2:
+        pytest.skip("needs multi-device CPU platform")
+    cfg = tiny_cfg(attn="gqa", n_kv_heads=2, n_head=4)
+    model, variables = build(cfg)
+    refs = DecodeEngine(model, variables, n_slots=2, temperature=0.0,
+                        min_bucket=8).run(PROMPTS[:3], max_new_tokens=4)
+    eng = DecodeEngine(model, variables, n_slots=2, temperature=0.0,
+                       min_bucket=8, mesh=mesh_for("tp", tp_size=2),
+                       recipe="tp")
+    k_cache = eng.caches[0]["k"]
+    assert k_cache.shape[2] == 128
+    assert "model" not in tuple(k_cache.sharding.spec)
+    assert eng.run(PROMPTS[:3], max_new_tokens=4) == refs
 
 
 @pytest.mark.parametrize("kw", [

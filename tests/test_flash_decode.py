@@ -217,10 +217,20 @@ def test_int8_dead_slot_tail_blocks_fully_skipped():
 # paged kernel (block-table scalar prefetch over the pool)
 # ----------------------------------------------------------------------
 
-def _mk_paged(B, n_max, bs, nh, nkv, hs, seed=0, extra_blocks=4):
+def _merge(rows):
+    """(n_blocks, bs, n_kv, hs) head-major rows -> the merged-lane float
+    pool leaf (n_blocks, bs, L) the engine declares: heads side by side in
+    lanes, zero-padded to a multiple of 128."""
+    from distributed_pytorch_tpu.ops.block_pool import kv_lanes, merge_heads
+    return merge_heads(rows, kv_lanes(*rows.shape[2:]))
+
+
+def _mk_paged(B, n_max, bs, nh, nkv, hs, seed=0, extra_blocks=4,
+              merged=True):
     """Random pool + shuffled non-contiguous block tables: the logical
     view the kernel must reproduce comes from paged_gather (the oracle
-    path the engine's naive fallback uses)."""
+    path the engine's naive fallback uses). `merged=False` keeps the
+    head-major rows (what the int8 pools quantize from)."""
     import numpy as np_
 
     from distributed_pytorch_tpu.ops.block_pool import paged_gather
@@ -232,25 +242,72 @@ def _mk_paged(B, n_max, bs, nh, nkv, hs, seed=0, extra_blocks=4):
     rng = np_.random.default_rng(seed)
     bt = jnp.asarray(rng.permutation(np_.arange(1, 1 + B * n_max))
                      .reshape(B, n_max).astype(np_.int32))
-    return q, kp, vp, bt, paged_gather(kp, bt), paged_gather(vp, bt)
+    kl, vl = paged_gather(kp, bt), paged_gather(vp, bt)
+    if merged:
+        kp, vp = _merge(kp), _merge(vp)
+    return q, kp, vp, bt, kl, vl
 
 
-@pytest.mark.parametrize("nkv", [8, 4, 2, 1], ids=lambda n: f"nkv{n}")
-def test_paged_parity_gqa_ratios(nkv):
+# (nh, nkv, hs): MHA through MQA at 16-wide heads (128 lanes or fewer),
+# then shapes whose n_kv * hs is NOT a multiple of 128, so pad lanes and a
+# head starting at lane 64 of a tile are exercised: gpt2-xl's 25 x 64
+# (1600 -> 1664 lanes, the last lane group half pad) and 6 kv heads x 64
+# under rep 4 (384 lanes, no pad, rep > 1)
+_HEAD_SHAPES = [(8, 8, 16), (8, 4, 16), (8, 2, 16), (8, 1, 16),
+                (25, 25, 64), (24, 6, 64), (6, 3, 64)]
+_ids = lambda t: "nh%d_nkv%d_hs%d" % t  # noqa: E731
+
+
+@pytest.mark.parametrize("shape", _HEAD_SHAPES, ids=_ids)
+def test_paged_parity_gqa_ratios(shape):
     """Paged kernel vs the naive path on the GATHERED logical cache:
     <= 1e-5 for MHA through MQA at ragged per-sequence lengths, through
     shuffled (non-contiguous, non-monotone) block tables."""
     from distributed_pytorch_tpu.ops.flash_decode import (
         paged_flash_decode, paged_flash_decode_usable)
-    B, n_max, bs, nh, hs = 4, 8, 8, 8, 16
+    nh, nkv, hs = shape
+    B, n_max, bs = 4, 8, 8
     q, kp, vp, bt, kl, vl = _mk_paged(B, n_max, bs, nh, nkv, hs)
+    assert kp.ndim == 3 and kp.shape[2] % 128 == 0
     cl = jnp.array([1, 7, 33, 64], jnp.int32)
-    assert paged_flash_decode_usable(q, kp, vp, bt)
+    assert paged_flash_decode_usable(q, kp, vp, bt, nkv)
     out = paged_flash_decode(q[:, 0], kp, vp, bt, cl, scale=hs ** -0.5,
-                             interpret=True)
+                             n_kv_heads=nkv, interpret=True)
     ref = _naive_sdpa(q, kl, vl, scale=hs ** -0.5, q_offset=cl - 1)[:, 0]
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("shape", _HEAD_SHAPES, ids=_ids)
+@pytest.mark.parametrize("write", ["decode", "spec", "prefill"])
+def test_paged_update_gather_round_trip(shape, write):
+    """`paged_gather(paged_update(pool, rows))` hands back exactly the
+    rows written, through each of the three write branches, and the pad
+    lanes of a merged-lane pool stay zero."""
+    from distributed_pytorch_tpu.ops.block_pool import (
+        kv_lanes, paged_gather, paged_update)
+    _, nkv, hs = shape
+    B, n_max, bs = 3, 4, 8
+    T = {"decode": 1, "spec": 3, "prefill": 2 * bs}[write]
+    if write == "prefill":
+        B = 1
+    rng = np.random.default_rng(nkv * hs + T)
+    bt = jnp.asarray(rng.permutation(np.arange(1, 1 + B * n_max))
+                     .reshape(B, n_max).astype(np.int32))
+    pool = jnp.zeros((1 + B * n_max, bs, kv_lanes(nkv, hs)), jnp.float32)
+    rows = jnp.asarray(rng.standard_normal((B, T, nkv, hs)), jnp.float32)
+    pos = (jnp.int32(bs) if write == "prefill"
+           else jnp.asarray([0, 6, 13][:B], jnp.int32))   # 6 + 3 crosses a block
+    pool = paged_update(pool, rows, pos, bt)
+    view = paged_gather(pool, bt, (nkv, hs))
+    assert view.shape == (B, n_max * bs, nkv, hs)
+    for b in range(B):
+        p0 = int(np.asarray(pos).reshape(-1)[b if write != "prefill" else 0])
+        np.testing.assert_array_equal(np.asarray(view[b, p0:p0 + T]),
+                                      np.asarray(rows[b]))
+    assert float(jnp.abs(pool[..., nkv * hs:]).sum()) == 0.0
+    assert float(jnp.abs(view).sum()) == pytest.approx(
+        float(jnp.abs(rows).sum()), rel=1e-6)     # nothing written elsewhere
 
 
 @pytest.mark.parametrize("nkv", [8, 4, 2, 1], ids=lambda n: f"nkv{n}")
@@ -261,7 +318,8 @@ def test_paged_parity_int8(nkv):
     from distributed_pytorch_tpu.ops.flash_decode import paged_flash_decode
     from distributed_pytorch_tpu.ops.quant import dequantize_int8, quantize_kv
     B, n_max, bs, nh, hs = 4, 8, 8, 8, 16
-    q, kp, vp, bt, _, _ = _mk_paged(B, n_max, bs, nh, nkv, hs, seed=3)
+    q, kp, vp, bt, _, _ = _mk_paged(B, n_max, bs, nh, nkv, hs, seed=3,
+                                    merged=False)
     from distributed_pytorch_tpu.ops.block_pool import paged_gather
     kq, ks_ = quantize_kv(kp)
     vq, vs = quantize_kv(vp)
@@ -285,33 +343,41 @@ def test_paged_dead_blocks_fully_skipped():
     q, kp, vp, bt, _, _ = _mk_paged(B, n_max, bs, nh, nkv, hs)
     own = int(bt[0, 0])
     mask = jnp.arange(kp.shape[0]) != own
-    kp = jnp.where(mask[:, None, None, None], jnp.nan, kp)
-    vp = jnp.where(mask[:, None, None, None], jnp.inf, vp)
+    kp = jnp.where(mask[:, None, None], jnp.nan, kp)
+    vp = jnp.where(mask[:, None, None], jnp.inf, vp)
     out = paged_flash_decode(q[:, 0], kp, vp, bt,
                              jnp.array([1], jnp.int32), scale=hs ** -0.5,
-                             interpret=True)
+                             n_kv_heads=nkv, interpret=True)
     assert bool(jnp.isfinite(out).all())
     # one fully-attended row: softmax weight 1.0 on the owned block's row 0
-    np.testing.assert_allclose(np.asarray(out[0]), np.asarray(vp[own, 0]),
-                               atol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(out[0]),
+        np.asarray(vp[own, 0, :nkv * hs].reshape(nkv, hs)), atol=1e-5)
 
 
 def test_paged_usable_gate_declines():
-    from distributed_pytorch_tpu.ops.flash_decode import \
-        paged_flash_decode_usable
+    from distributed_pytorch_tpu.ops.flash_decode import (
+        paged_flash_decode_decline, paged_flash_decode_usable)
     q, kp, vp, bt, _, _ = _mk_paged(2, 4, 8, 8, 4, 16)
-    assert paged_flash_decode_usable(q, kp, vp, bt)
+    assert paged_flash_decode_usable(q, kp, vp, bt, 4)
+    # a merged-lane pool does not say how many heads share its lanes
+    assert not paged_flash_decode_usable(q, kp, vp, bt)
     # prefill-shaped query
-    assert not paged_flash_decode_usable(jnp.zeros((2, 4, 8, 16)), kp, vp, bt)
+    assert not paged_flash_decode_usable(jnp.zeros((2, 4, 8, 16)), kp, vp,
+                                         bt, 4)
     # block size the hardware cannot tile (9 rows)
     q2, kp2, vp2, bt2, _, _ = _mk_paged(2, 4, 9, 8, 4, 16)
-    assert not paged_flash_decode_usable(q2, kp2, vp2, bt2)
+    assert not paged_flash_decode_usable(q2, kp2, vp2, bt2, 4)
+    # (bs, L) tiles past the scoped-VMEM budget: 64 heads x 128 lanes
+    wide = jnp.zeros((8, 512, 64 * 128))
+    assert "VMEM" in paged_flash_decode_decline(
+        jnp.zeros((2, 1, 64, 128)), wide, wide, bt, 64)
     # live multi-device mesh -> gather + naive carries sharded decode
     from distributed_pytorch_tpu.parallel import context
     from distributed_pytorch_tpu.parallel.mesh import mesh_for
     with context.use_mesh(mesh_for("dp")):
-        assert not paged_flash_decode_usable(q, kp, vp, bt)
-    assert paged_flash_decode_usable(q, kp, vp, bt)
+        assert not paged_flash_decode_usable(q, kp, vp, bt, 4)
+    assert paged_flash_decode_usable(q, kp, vp, bt, 4)
 
 
 def test_sdpa_paged_routes_kernel_vs_gather(monkeypatch):
@@ -324,12 +390,14 @@ def test_sdpa_paged_routes_kernel_vs_gather(monkeypatch):
     pos = jnp.array([4, 20, 63], jnp.int32)
     monkeypatch.setenv("FLASH_DECODE", "on")
     out = sdpa(q, kp, vp, causal=True, q_offset=pos, decode=True,
-               block_tables=bt)
+               block_tables=bt, n_kv_heads=nkv)
     monkeypatch.setenv("FLASH_DECODE", "off")
     ref = sdpa(q, kp, vp, causal=True, q_offset=pos, decode=True,
-               block_tables=bt)
+               block_tables=bt, n_kv_heads=nkv)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
+    _, kp, vp, _, _, _ = _mk_paged(B, n_max, bs, nh, nkv, hs, seed=11,
+                                   merged=False)
     kq, ks_ = quantize_kv(kp)
     vq, vs = quantize_kv(vp)
     monkeypatch.setenv("FLASH_DECODE", "on")
@@ -363,7 +431,7 @@ def test_sdpa_decode_scalar_offset_under_jit(monkeypatch):
 # chunk-prefill kernel (mixed prefill+decode path, round 12)
 # ----------------------------------------------------------------------
 
-def _mk_chunk(T, n_max, bs, nh, nkv, hs, seed=0):
+def _mk_chunk(T, n_max, bs, nh, nkv, hs, seed=0, merged=True):
     """One sequence's pool + shuffled block table for the chunk kernel:
     (1, T, nh, hs) query rows at global positions [off, off+T)."""
     import numpy as np_
@@ -377,23 +445,32 @@ def _mk_chunk(T, n_max, bs, nh, nkv, hs, seed=0):
     rng = np_.random.default_rng(seed)
     bt = jnp.asarray(rng.permutation(np_.arange(1, 1 + n_max))
                      .reshape(1, n_max).astype(np_.int32))
-    return q, kp, vp, bt, paged_gather(kp, bt), paged_gather(vp, bt)
+    kl, vl = paged_gather(kp, bt), paged_gather(vp, bt)
+    if merged:
+        kp, vp = _merge(kp), _merge(vp)
+    return q, kp, vp, bt, kl, vl
 
 
 @pytest.mark.parametrize("off", [0, 8, 24], ids=lambda o: f"off{o}")
-@pytest.mark.parametrize("nkv", [8, 4, 1], ids=lambda n: f"nkv{n}")
-def test_chunk_prefill_parity_offsets(nkv, off):
+@pytest.mark.parametrize(
+    "shape", [(8, 8, 16), (8, 4, 16), (8, 1, 16), (25, 25, 64), (24, 6, 64),
+              (4, 2, 128)], ids=_ids)
+def test_chunk_prefill_parity_offsets(shape, off):
     """paged_flash_prefill vs the naive path on the gathered logical
     view: a 16-row chunk at block-aligned offsets (fresh sequence, one
     prior block, three prior blocks) attends its prior context plus its
-    own in-chunk causal prefix — MHA through MQA, shuffled tables."""
+    own in-chunk causal prefix — MHA through MQA, shuffled tables; 25 x 64
+    ends in a half-pad lane group, 6 x 64 packs rep 4 into the rows, 2 x
+    128 is one head a group."""
     from distributed_pytorch_tpu.ops.flash_decode import (
         paged_flash_prefill, paged_flash_prefill_usable)
-    T, n_max, bs, nh, hs = 16, 8, 8, 8, 16
+    nh, nkv, hs = shape
+    T, n_max, bs = 16, 8, 8
     q, kp, vp, bt, kl, vl = _mk_chunk(T, n_max, bs, nh, nkv, hs, seed=off)
-    assert paged_flash_prefill_usable(q, kp, vp, bt)
+    assert paged_flash_prefill_usable(q, kp, vp, bt, nkv)
     out = paged_flash_prefill(q, kp, vp, bt, jnp.int32(off),
-                              scale=hs ** -0.5, interpret=True)
+                              scale=hs ** -0.5, n_kv_heads=nkv,
+                              interpret=True)
     ref = _naive_sdpa(q, kl, vl, scale=hs ** -0.5, q_offset=off)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
@@ -407,7 +484,8 @@ def test_chunk_prefill_parity_int8():
     from distributed_pytorch_tpu.ops.flash_decode import paged_flash_prefill
     from distributed_pytorch_tpu.ops.quant import dequantize_int8, quantize_kv
     T, n_max, bs, nh, nkv, hs = 16, 8, 8, 8, 4, 16
-    q, kp, vp, bt, _, _ = _mk_chunk(T, n_max, bs, nh, nkv, hs, seed=3)
+    q, kp, vp, bt, _, _ = _mk_chunk(T, n_max, bs, nh, nkv, hs, seed=3,
+                                    merged=False)
     kq, ks_ = quantize_kv(kp)
     vq, vs = quantize_kv(vp)
     out = paged_flash_prefill(q, kq, vq, bt, jnp.int32(8),
@@ -430,10 +508,11 @@ def test_chunk_prefill_trailing_blocks_fully_skipped():
     off = 8                                      # rows [8, 24): blocks 0..2
     needed = {int(bt[0, j]) for j in range(3)}
     mask = ~jnp.isin(jnp.arange(kp.shape[0]), jnp.asarray(list(needed)))
-    kp = jnp.where(mask[:, None, None, None], jnp.nan, kp)
-    vp = jnp.where(mask[:, None, None, None], jnp.inf, vp)
+    kp = jnp.where(mask[:, None, None], jnp.nan, kp)
+    vp = jnp.where(mask[:, None, None], jnp.inf, vp)
     out = paged_flash_prefill(q, kp, vp, bt, jnp.int32(off),
-                              scale=hs ** -0.5, interpret=True)
+                              scale=hs ** -0.5, n_kv_heads=nkv,
+                              interpret=True)
     assert bool(jnp.isfinite(out).all())
 
 
@@ -441,20 +520,23 @@ def test_chunk_prefill_usable_gate_declines():
     from distributed_pytorch_tpu.ops.flash_decode import \
         paged_flash_prefill_usable
     q, kp, vp, bt, _, _ = _mk_chunk(16, 8, 8, 8, 4, 16)
-    assert paged_flash_prefill_usable(q, kp, vp, bt)
+    assert paged_flash_prefill_usable(q, kp, vp, bt, 4)
     # single-token (decode-shaped) query -> the decode kernel's job
-    assert not paged_flash_prefill_usable(q[:, :1], kp, vp, bt)
+    assert not paged_flash_prefill_usable(q[:, :1], kp, vp, bt, 4)
     # chunk not a sublane multiple
-    assert not paged_flash_prefill_usable(q[:, :12], kp, vp, bt)
+    assert not paged_flash_prefill_usable(q[:, :12], kp, vp, bt, 4)
     # batched chunks: one sequence at a time only
     q2 = jnp.concatenate([q, q], axis=0)
-    assert not paged_flash_prefill_usable(q2, kp, vp, bt)
+    assert not paged_flash_prefill_usable(q2, kp, vp, bt, 4)
     # block size the hardware cannot tile (9 rows)
     q3, kp3, vp3, bt3, _, _ = _mk_chunk(16, 8, 9, 8, 4, 16)
-    assert not paged_flash_prefill_usable(q3, kp3, vp3, bt3)
+    assert not paged_flash_prefill_usable(q3, kp3, vp3, bt3, 4)
+    # heads that straddle the 128-lane groups the grid is cut by (96 wide)
+    q4, kp4, vp4, bt4, _, _ = _mk_chunk(16, 8, 8, 4, 4, 96)
+    assert not paged_flash_prefill_usable(q4, kp4, vp4, bt4, 4)
     # live multi-device mesh -> gather + naive carries sharded decode
     from distributed_pytorch_tpu.parallel import context
     from distributed_pytorch_tpu.parallel.mesh import mesh_for
     with context.use_mesh(mesh_for("dp")):
-        assert not paged_flash_prefill_usable(q, kp, vp, bt)
-    assert paged_flash_prefill_usable(q, kp, vp, bt)
+        assert not paged_flash_prefill_usable(q, kp, vp, bt, 4)
+    assert paged_flash_prefill_usable(q, kp, vp, bt, 4)

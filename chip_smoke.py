@@ -12,9 +12,10 @@ from --seed, synthetic data from the loader, no network):
            the chip, against its plain jax.numpy reference (max abs error
            bounded) — interpret mode never ran the compiled arithmetic;
   train    `python -m distributed_pytorch_tpu --preset gpt2_124m ...`
-           (9 steps XLA attention + fused CE, a verified checkpoint), then
-           2 steps with `--attn_impl pallas --loss_impl pallas` whose first
-           loss must agree with the XLA run's;
+           (9 steps with `--attn_impl xla` + fused CE, a verified
+           checkpoint), then 2 steps with `--attn_impl pallas --loss_impl
+           pallas` whose first loss must agree with the XLA run's (the
+           default `auto` takes the flash kernels here, so both are named);
   serve    `python -m distributed_pytorch_tpu.serve --ckpt <that one>`,
            six HTTP completions (three in flight), greedy determinism,
            prefix reuse, SIGTERM; then `python -m
@@ -224,7 +225,11 @@ def phase_train(out: str, *, seed: int, platform: str,
     run_child(phase, _train_cmd(shape, platform, seed, name="smoke_xla",
                                 recipe="single", batch=batch,
                                 global_batch=batch, max_iters=iters,
-                                log_interval=2, extra=("--save_model",)),
+                                log_interval=2,
+                                # by name: `auto` takes the flash kernels at
+                                # this shape, and the leg below is compared
+                                # with an XLA run
+                                extra=("--save_model", "--attn_impl", "xla")),
               cwd=out, log=log, env=env, timeout=900)
     st = _train_stats(phase, out, "smoke_xla", platform, 1, log)
     losses = st["train_losses"]

@@ -95,12 +95,13 @@ def knobs_table() -> str:
 
 # --- kernel tile sizes (read at import by their owner modules so
 # mfu_sweep can A/B them per subprocess) ---
-register_knob("FLASH_BLOCK_Q", "256", int,
+register_knob("FLASH_BLOCK_Q", "1024", int,
               "flash-attention query tile rows (ops/flash_attention.py)")
-register_knob("FLASH_BLOCK_K", "512", int,
+register_knob("FLASH_BLOCK_K", "1024", int,
               "flash-attention kv tile length")
-register_knob("FLASH_BLOCK_H", "8", int,
-              "flash-attention rows per grid group")
+register_knob("FLASH_BLOCK_H", "1", int,
+              "flash-attention rows per grid group at a full Q x K tile "
+              "(proportionally more at smaller tiles)")
 register_knob("FLASH_LAYOUT", "rows", lambda s: s.strip().lower(),
               "flash kernel layout: rows (BTNH transpose) | slab (compiled "
               "only for 128-multiple head dims: Mosaic refuses its in-VMEM "

@@ -17,10 +17,15 @@ single-gpu/model.py:149). Implementations:
              Pallas flash-decode kernel (ops/flash_decode.py) when
              `flash_decode_usable` holds (FLASH_DECODE=auto|on|off;
              'auto' = TPU only), else fall through to 'naive'.
-* 'auto'   — pallas on TPU when shapes allow, else xla. dropout>0 routes
-             to the pallas kernel's IN-KERNEL dropout on TPU (round 5 —
-             parity with CUDA SDPA dropout, reference model.py:149-151);
-             non-flash shapes / non-TPU fall back to naive.
+* 'auto'   — on a TPU, a training-shaped call (not KV-cached, static
+             offset 0) takes the pallas kernel whenever its gate passes and
+             it would run on each shard's own operands (`_per_shard_decline`),
+             from `_FLASH_MIN_KEYS` keys up (measured at T = 256..2048,
+             PERF.md section 6, PR 29); xla otherwise, with the reason
+             noted. dropout>0 routes to the pallas kernel's IN-KERNEL
+             dropout on TPU (round 5 — parity with CUDA SDPA dropout,
+             reference model.py:149-151); non-flash shapes / non-TPU fall
+             back to naive.
 
 No path hides the device: every choice is recorded (obs/paths.py `note`,
 printed beside each compiled program by the trainer and the serve CLI),
@@ -42,6 +47,22 @@ import jax
 import jax.numpy as jnp
 
 from distributed_pytorch_tpu.obs import paths
+
+
+# The crossover of XLA's fused attention against the flash kernels, forward
+# + backward at 16,384 tokens a call, 12 heads of 64, bf16, on a v5e (PERF.md
+# section 6, PR 29): XLA 2.17 ms to the kernels' 2.72 at T = 256, 4.14 to
+# 2.98 at 512, 7.87 to 3.78 at 1024, 15.10 to 5.80 at 2048. Below this many
+# keys a training-shaped `auto` call keeps XLA.
+_FLASH_MIN_KEYS = 512
+
+# Memory guard for the `auto` calls no chip run has measured (KV-cached
+# prefill; a live 'model' or 'pipe' mesh axis): XLA's fused attention
+# materialises the O(T*S) score matrix (OOM by 32k keys) while the flash
+# kernel stays O(T), so beyond this many keys they take the kernel anyway.
+# Not a statement about speed. Training-shaped calls on one device or a
+# data-only mesh do not read it.
+_XLA_SCORES_MAX_KEYS = 4096
 
 
 def _on_tpu() -> bool:
@@ -74,6 +95,27 @@ def _decode_kernel_wanted(kernel: str, why_not) -> bool:
     return False
 
 
+def _per_shard_decline(q) -> Optional[str]:
+    """Why a Pallas call on `q` would NOT run on each shard's own operands
+    under the ambient mesh — None when it would: no mesh, one device, a
+    shard_map body already, or a mesh whose batch divides over 'data' with
+    'model' and 'pipe' at 1 (what `_shard_map_over_data` wraps). Under a
+    live 'model' or 'pipe' axis GSPMD would all-gather the operands and
+    replicate the kernel on every device."""
+    from distributed_pytorch_tpu.parallel import context
+    mesh = context.get_mesh()
+    if mesh is None or context.in_sp_region():
+        return None
+    for axis in ("model", "pipe"):
+        if mesh.shape.get(axis, 1) > 1:
+            return (f"mesh axis {axis!r} is live ({mesh.shape[axis]}): the "
+                    "kernel would be replicated over gathered operands")
+    dp = mesh.shape.get("data", 1)
+    if q.shape[0] % dp != 0:
+        return f"batch {q.shape[0]} does not divide over data={dp}"
+    return None
+
+
 def _shard_map_over_data(fn, q, has_rng: bool = False):
     """Batch-parallel shard_map wrapper for a pallas call under a live
     multi-device mesh: GSPMD cannot partition a pallas_call (it would
@@ -84,12 +126,9 @@ def _shard_map_over_data(fn, q, has_rng: bool = False):
     not divisible) — those paths keep the unwrapped call/XLA fallback."""
     from distributed_pytorch_tpu.parallel import context
     mesh = context.get_mesh()
-    if mesh is None or context.in_sp_region():
-        return None
-    dp = mesh.shape.get("data", 1)
-    if (dp <= 1 or mesh.shape.get("model", 1) > 1
-            or mesh.shape.get("pipe", 1) > 1
-            or q.shape[0] % dp != 0 or q.shape[0] // dp < 1):
+    if (mesh is None or context.in_sp_region()
+            or mesh.shape.get("data", 1) <= 1
+            or _per_shard_decline(q) is not None):
         return None
     from jax.sharding import PartitionSpec as P
     spec = P("data", None, None, None)
@@ -398,20 +437,32 @@ def sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                        f"{jax.default_backend()}")
         impl = "naive"
     elif impl == "auto":
-        # XLA's fused attention is at parity with the Pallas kernel for
-        # short sequences; beyond ~4k keys XLA materializes the O(T*S)
-        # score matrix (OOM by 32k) while the flash kernel stays O(T).
-        if _on_tpu() and k.shape[1] > 4096:
+        if _on_tpu():
+            long = k.shape[1] > _XLA_SCORES_MAX_KEYS
             why = flash_why_not()
+            if why is not None:
+                why = f"flash_attention_usable declined: {why}"
+            elif not long:
+                # a training-shaped call takes the kernel wherever it runs
+                # on each shard's own operands: from _FLASH_MIN_KEYS up,
+                # forward + backward beat XLA's materialised [B, nh, T, S]
+                # scores at every measured T (512..2048 at 12 x 64, 1024 at
+                # 25 x 64). A KV-cached call and a live 'model'/'pipe' axis
+                # were measured by nobody and keep XLA up to the guard.
+                if decode:
+                    why = "a KV-cached call"
+                elif k.shape[1] < _FLASH_MIN_KEYS:
+                    why = (f"{k.shape[1]} keys < {_FLASH_MIN_KEYS}: XLA's "
+                           "fused attention measured faster")
+                else:
+                    why = _per_shard_decline(q)
             if why is None:
                 return run_flash()
-            paths.note("attention", "xla",
-                       f"auto: {k.shape[1]} keys > 4096 but "
-                       f"flash_attention_usable declined: {why}")
+            if long or not decode:
+                paths.note("attention", "xla", f"auto: {why}")
         elif not decode:
             paths.note("attention", "xla",
-                       f"auto: {k.shape[1]} keys <= 4096" if _on_tpu()
-                       else f"auto: backend {jax.default_backend()}")
+                       f"auto: backend {jax.default_backend()}")
         impl = "xla"
     elif impl == "pallas":
         # decode=True: a KV-cached prefill may still use the flash kernel
@@ -419,6 +470,8 @@ def sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         if flash_why_not() is None:
             return run_flash()
         impl = "xla"
+    elif impl == "xla" and not decode:
+        paths.note("attention", "xla", "attn_impl=xla")
 
     if impl == "xla":
         if static_zero:

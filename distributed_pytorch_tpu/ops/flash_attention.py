@@ -55,9 +55,16 @@ from jax.experimental.pallas import tpu as pltpu
 from distributed_pytorch_tpu import config
 
 # Tile-size knobs (read at import so scripts/mfu_sweep.py --variants blocks
-# can A/B them per subprocess without an API change). 256x512 q/kv tiles and
-# an 8-row group are the provisional v5e winners pending the on-hardware
-# block sweep (PERF.md round 4).
+# can A/B them per subprocess without an API change). 1024 x 1024 q/kv tiles
+# (the whole sequence in one tile at T = 1024: no online-softmax rescale
+# between kv tiles) and one row a grid step are what won on a v5e at the
+# train cell's shape (192 rows, T = 1024, D = 64, bf16), forward + backward:
+# 3.83 ms a call against 4.20 for the former 256 x 512 x 8 over a 47-point
+# sweep, and 95,793 against 93,327 tokens/s/chip end to end in
+# `gpt2_train_b16` (PERF.md section 6, PR 29). Shorter sequences take the
+# largest tiles that divide them (`_pick_block`) and as many more rows a
+# step as the tile is smaller (`flash_attention_lse`); wider heads fewer
+# (`_pick_group`, the VMEM budget).
 DEFAULT_BLOCK_Q = config.knob("FLASH_BLOCK_Q")
 DEFAULT_BLOCK_K = config.knob("FLASH_BLOCK_K")
 DEFAULT_BLOCK_H = config.knob("FLASH_BLOCK_H")
@@ -70,7 +77,9 @@ DEFAULT_BLOCK_H = config.knob("FLASH_BLOCK_H")
 # materialized K/V repeat in HBM, group-sum of dk/dv at the write step).
 # Default stays 'rows' — the only layout that has compiled on real TPU
 # hardware so far — until the on-hardware sweep (mfu_sweep --variants
-# blocks, FLASH_LAYOUT legs) proves the slab path.
+# blocks, FLASH_LAYOUT legs) proves the slab path. A slab step holds every
+# head's tiles at once, so at the rows layout's 1024 x 1024 default its gate
+# declines from 8 heads of 128 up: ask for it with smaller FLASH_BLOCK_Q/K.
 DEFAULT_LAYOUT = config.knob("FLASH_LAYOUT")
 
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps masked rows NaN-free
@@ -375,6 +384,16 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
 _SEED_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
+# Jitted on their own (as the paged kernels are, ops/flash_decode.py): a
+# model calls them once a layer, and a jitted callee is traced and lowered
+# once per process and program where a plain function is once per call site
+# — 12 layers x 3 kernels in model.init, the memory plan's shape probe and
+# the train step cost the train cell 3.5-4 s of cached `setup_s`.
+_KERNEL_STATICS = ("scale", "block_q", "block_k", "g", "interpret", "causal",
+                   "rate")
+
+
+@functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
 def _fwd(q, k, v, seed, scale, block_q, block_k, g, interpret, causal=True,
          rate=0.0):
     """q (N, T, D) rows = flattened (B, H); k/v (Nkv, S, D) with
@@ -469,6 +488,7 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
 def _bwd_impl(scale, block_q, block_k, g, interpret, causal, rate, res, do,
               dlse=None):
     """Shared backward: dlse (N, T, 1) is the cotangent of the logsumexp
@@ -991,8 +1011,14 @@ def flash_attention_lse(q, k, v, *, scale: float, causal: bool = True,
                       block_q, block_k, interpret, causal, rate)
         return out.reshape(B, T, nh, hs), lse
 
-    g = block_h or _pick_group(B * nh, rep, DEFAULT_BLOCK_H, block_q,
-                               block_k, hs, jnp.dtype(q.dtype).itemsize)
+    # FLASH_BLOCK_H rows a grid step at the full FLASH_BLOCK_Q x K tile,
+    # proportionally more where the sequence is shorter than the tile: a
+    # grid step keeps its work (a 512 x 512 tile ran 12% slower in a group
+    # of 1 than of 4, a 256 x 256 one 34%: PERF.md section 6, PR 29)
+    want = DEFAULT_BLOCK_H * max(
+        1, (DEFAULT_BLOCK_Q * DEFAULT_BLOCK_K) // (block_q * block_k))
+    g = block_h or _pick_group(B * nh, rep, want, block_q, block_k, hs,
+                               jnp.dtype(q.dtype).itemsize)
     assert (B * nh) % g == 0 and (g == 1 or rep == 1), (
         f"row group {g} must divide B*nh={B * nh} and needs nh == n_kv")
 

@@ -109,7 +109,7 @@ def test_knob_skew_changes_the_key(tmp_path, monkeypatch):
     s = AOTStore(str(tmp_path))
     _, avals = _trivial()
     base = s.key("step", avals, {"kind": "engine"})
-    monkeypatch.setenv("FLASH_BLOCK_Q", "128")  # default is 256
+    monkeypatch.setenv("FLASH_BLOCK_Q", "128")  # default is 1024
     assert s.key("step", avals, {"kind": "engine"}) != base
 
 

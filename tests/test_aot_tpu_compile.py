@@ -148,7 +148,7 @@ CASES = {
                          ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]),
     "flash_bwd_16x1024": (lambda: _flash(16, 1024, True),
                           ["flash_bwd_dq", "flash_bwd_dkv"]),
-    # the shape attn_impl='auto' itself sends to the kernel (> 4096 keys)
+    # beyond the XLA memory guard every `auto` call takes the kernel
     "flash_fwd_1x8192": (lambda: _flash(1, 8192, False), ["flash_fwd"]),
     "flash_bwd_1x8192": (lambda: _flash(1, 8192, True),
                          ["flash_bwd_dq", "flash_bwd_dkv"]),
@@ -196,6 +196,34 @@ def test_kernel_compiles_for_v5e(case, v5e):
         assert census.get(name), (
             f"{case}: compiled, but no tpu_custom_call named {name!r} in "
             f"the program (census {census})")
+
+
+# ---------------------------------------------------------------------------
+# what `auto` selects for a training call: the flash kernels, no score tensor
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("shape", [(16, 1024, 12, 64), (2, 1024, 25, 64)],
+                         ids=["gpt2_b16", "gpt2xl_b2"])
+def test_auto_trains_on_the_flash_kernels(shape, v5e, monkeypatch):
+    """`jax.grad` through `sdpa(impl='auto')` at the train cell's shape
+    and at gpt2-xl's: the dispatcher (told it is on a TPU; the compile is
+    for the described chip) notes the kernel, the program holds all three
+    and no [B, nh, T, T] score tensor in any dtype."""
+    from distributed_pytorch_tpu.ops import attention_core as core
+    monkeypatch.setattr(core, "_on_tpu", lambda: True)
+    B, T, nh, hs = shape
+
+    def bwd(q, k, v):
+        return jax.grad(lambda *a: core.sdpa(*a, impl="auto").astype(
+            F32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    paths.reset()
+    text = _compile(bwd, [(shape, BF16)] * 3, v5e).as_text()
+    assert paths.choices()["attention"] == "pallas flash (attn_impl=auto)"
+    census = paths.kernel_census(text)
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert census.get(name) == 1, census
+    assert f"[{B},{nh},{T},{T}]" not in text, "a score tensor in the program"
 
 
 # ---------------------------------------------------------------------------
@@ -302,13 +330,17 @@ def test_gates_decline_what_the_compiler_refuses(v5e):
         _compile(lambda q, k, v, s: slab(q, k, v, s, SCALE, 256, 512, False,
                                          True, 0.0),
                  [((B, T, NH * HS), BF16)] * 3 + [((2,), I32)], v5e)
-    assert fa.slab_attention_usable(B, T, T, 8, 8, 128, BF16)  # and compiles
-    _compile(lambda q, k, v: fa.flash_attention(q, k, v, scale=SCALE,
-                                                layout="slab"),
-             [((B, T, 8, 128), BF16)] * 3, v5e)
+    # and compiles, at tiles whose all-heads step fits the VMEM budget
+    assert fa.slab_attention_usable(B, T, T, 8, 8, 128, BF16, 256, 512)
+    census = paths.kernel_census(_compile(
+        lambda q, k, v: fa.flash_attention(q, k, v, scale=SCALE,
+                                           layout="slab", block_q=256,
+                                           block_k=512),
+        [((B, T, 8, 128), BF16)] * 3, v5e).as_text())
+    assert census.get("flash_slab_fwd"), census
     # (2) rows layout at a head dim whose single tile step busts the
     # scoped-VMEM limit the kernel hands Mosaic
-    wide = [((1, 512, 1, 8192), BF16)] * 3
+    wide = [((1, 1024, 1, 8192), BF16)] * 3
     q = jax.ShapeDtypeStruct(*wide[0])
     assert not fa.flash_attention_usable(q, q, q)
     assert "VMEM" in fa.flash_attention_decline(q, q, q)

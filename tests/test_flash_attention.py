@@ -471,13 +471,18 @@ class TestSlabLayout:
         from distributed_pytorch_tpu.ops.flash_attention import (
             slab_attention_usable)
         # compiled: Mosaic refuses the in-VMEM head split below a full
-        # 128-lane head (tests/test_aot_tpu_compile.py holds the compile)
-        assert slab_attention_usable(2, 1024, 1024, 8, 8, 128, jnp.bfloat16)
-        assert not slab_attention_usable(2, 1024, 1024, 12, 12, 64,
+        # 128-lane head (tests/test_aot_tpu_compile.py holds the compile).
+        # A slab step holds ALL heads' tiles, so it is asked at 256 x 512:
+        # the rows layout's 1024 x 1024 default is over the VMEM budget here
+        assert slab_attention_usable(2, 1024, 1024, 8, 8, 128, jnp.bfloat16,
+                                     256, 512)
+        assert not slab_attention_usable(2, 1024, 1024, 8, 8, 128,
                                          jnp.bfloat16)
+        assert not slab_attention_usable(2, 1024, 1024, 12, 12, 64,
+                                         jnp.bfloat16, 256, 512)
         # interpret mode (these CPU tests) keeps the 64-wide heads
         assert slab_attention_usable(2, 1024, 1024, 12, 12, 64, jnp.bfloat16,
-                                     interpret=True)
+                                     256, 512, interpret=True)
         assert not slab_attention_usable(2, 1024, 1024, 3, 3, 24,
                                          jnp.bfloat16)  # 72 lanes
 
@@ -526,3 +531,76 @@ def test_pallas_dp_mesh_shard_map_wrap(monkeypatch):
     assert np.isfinite(np.asarray(outd)).all()
     assert not np.allclose(np.asarray(outd), np.asarray(out))
     assert calls == [True, True], calls
+
+
+# ---------------------------------------------------------------------------
+# which path `auto` takes on a TPU: decided from the call's shapes, `decode`,
+# `q_offset` and the ambient mesh, at trace time (nothing runs on a kernel)
+# ---------------------------------------------------------------------------
+
+# id -> (mesh plan | None, q/k/v shape overrides, sdpa kwargs,
+#        expected "attention" choice: exact string, or (path, reason part),
+#        or None for "no note")
+AUTO_CASES = {
+    "train_no_mesh": (None, {}, {}, "pallas flash (attn_impl=auto)"),
+    "train_one_device_mesh": (dict(), {}, {},
+                              "pallas flash (attn_impl=auto)"),
+    "train_data_mesh": (dict(data=4), {}, {},
+                        "pallas flash (attn_impl=auto)"),
+    "train_T512": (None, dict(T=512), {}, "pallas flash (attn_impl=auto)"),
+    "train_T256": (None, dict(T=256), {},
+                   ("xla", "256 keys < 512: XLA's fused attention measured "
+                           "faster")),
+    "gpt2xl_25_heads": (None, dict(B=2, nh=25), {},
+                        "pallas flash (attn_impl=auto)"),
+    "model_axis_live": (dict(data=2, model=2), {}, {},
+                        ("xla", "mesh axis 'model' is live")),
+    "pipe_axis_live": (dict(data=2, pipe=2), {}, {},
+                       ("xla", "mesh axis 'pipe' is live")),
+    "batch_not_over_data": (dict(data=8), dict(B=12), {},
+                            ("xla", "batch 12 does not divide over data=8")),
+    "decode": (None, {}, dict(decode=True), None),
+    "traced_q_offset": (None, {}, dict(q_offset="traced"),
+                        ("xla", "q_offset is traced or nonzero")),
+    "head_dim_60": (None, dict(hs=60), {},
+                    ("xla", "head dim 60 is not a sublane (8) multiple")),
+    # beyond the XLA memory guard the unmeasured calls take the kernel too
+    "model_axis_live_8192_keys": (dict(data=2, model=2), dict(B=2, T=8192),
+                                  {}, "pallas flash (attn_impl=auto)"),
+    "decode_8192_keys": (None, dict(B=2, T=8192), dict(decode=True),
+                         "pallas flash (attn_impl=auto)"),
+}
+
+
+@pytest.mark.parametrize("case", list(AUTO_CASES))
+def test_auto_path_on_a_tpu(case, monkeypatch):
+    from distributed_pytorch_tpu.obs import paths
+    from distributed_pytorch_tpu.ops import attention_core as core
+    from distributed_pytorch_tpu.parallel import context
+    from distributed_pytorch_tpu.parallel.mesh import MeshPlan, build_mesh
+
+    plan, dims, kwargs, want = AUTO_CASES[case]
+    monkeypatch.setattr(core, "_on_tpu", lambda: True)
+    d = {"B": 16, "T": 1024, "nh": 12, "hs": 64, **dims}
+    x = jax.ShapeDtypeStruct((d["B"], d["T"], d["nh"], d["hs"]),
+                             jnp.bfloat16)
+    kwargs = dict(kwargs)
+    traced = kwargs.pop("q_offset", None) == "traced"
+
+    def call(q, k, v, off):
+        if traced:
+            kwargs["q_offset"] = off
+        return core.sdpa(q, k, v, impl="auto", **kwargs)
+
+    mesh = None if plan is None else build_mesh(MeshPlan(**plan))
+    paths.reset()
+    with context.use_mesh(mesh):
+        out = jax.eval_shape(call, x, x, x,
+                             jax.ShapeDtypeStruct((), jnp.int32))
+    assert out.shape == x.shape and out.dtype == x.dtype
+    got = paths.choices().get("attention")
+    if want is None or isinstance(want, str):
+        assert got == want
+    else:
+        path, reason = want
+        assert got.startswith(f"{path} (auto: ") and reason in got, got

@@ -44,7 +44,7 @@ _spec.loader.exec_module(lint)
 # ---------------------------------------------------------------------------
 
 def test_knob_defaults_read_without_env():
-    assert config.knob("FLASH_BLOCK_Q") == 256
+    assert config.knob("FLASH_BLOCK_Q") == 1024
     assert config.knob("TRACE_GUARD") == "warn"
     assert config.knob("FLASH_DECODE") == "auto"
 
@@ -55,7 +55,7 @@ def test_knob_env_override_is_live(monkeypatch):
     monkeypatch.setenv("FLASH_BLOCK_Q", "128")
     assert config.knob("FLASH_BLOCK_Q") == 128
     monkeypatch.delenv("FLASH_BLOCK_Q")
-    assert config.knob("FLASH_BLOCK_Q") == 256
+    assert config.knob("FLASH_BLOCK_Q") == 1024
 
 
 def test_knob_unregistered_name_fails_loudly():

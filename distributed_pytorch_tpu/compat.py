@@ -12,8 +12,8 @@ keep their import names; ROADMAP D6 deletes it outright.
 * `vma_of(x)` / `pcast_varying(x, vma)` — varying-manual-axes
   introspection and promotion for pallas calls inside shard_map.
 * `tpu_compiler_params(**kw)` — `pltpu.CompilerParams`, with the scoped-
-  VMEM limit every kernel's usable-gate budgets against handed to Mosaic
-  (see below).
+  VMEM limit every kernel's gate budgets against (`VMEM_LIMIT_BYTES`)
+  handed to Mosaic.
 * `request_cpu_devices(n)` — `jax_num_cpu_devices`, before any device op.
 """
 
@@ -22,8 +22,6 @@ from __future__ import annotations
 from typing import Any
 
 import jax
-
-from distributed_pytorch_tpu import config
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
@@ -49,20 +47,25 @@ def distributed_is_initialized() -> bool:
     return bool(jax.distributed.is_initialized())
 
 
+# The scoped-VMEM limit every Pallas kernel in ops/ hands Mosaic AND the
+# budget their `*_decline` gates check (ops/flash_attention.py and
+# ops/flash_decode.py import this name): half of a v5e core's 128 MiB, the
+# one chip this tree supports. One number, so "the gate says it fits" and
+# "the compiler accepts it" stay one statement.
+VMEM_LIMIT_BYTES = 64 * 2 ** 20
+
+
 def tpu_compiler_params(**kwargs):
     """`pltpu.CompilerParams` for every Pallas kernel in ops/.
 
     Mosaic's default scoped-VMEM limit on v5e is 16 MiB, an eighth of the
-    core's 128 MiB; the kernels' tile sizes and their `*_usable` gates
-    were budgeted against FLASH_VMEM_BUDGET_MB (64). Left at the default,
-    flash backward, the CE backward, the int8 contiguous decode and the
-    512-row chunk prefill are all refused at flagship widths ("Scoped
-    allocation ... exceeded scoped vmem limit", device-free v5e compile).
-    Handing Mosaic the SAME number the gates check makes "the gate says
-    it fits" and "the compiler accepts it" one statement."""
+    core's 128 MiB; the kernels' tile sizes and their gates were budgeted
+    against VMEM_LIMIT_BYTES (64 MiB). Left at the default, flash
+    backward, the CE backward, the int8 contiguous decode and the 512-row
+    chunk prefill are all refused at flagship widths ("Scoped allocation
+    ... exceeded scoped vmem limit", device-free v5e compile)."""
     from jax.experimental.pallas import tpu as pltpu
-    kwargs.setdefault("vmem_limit_bytes",
-                      config.knob("FLASH_VMEM_BUDGET_MB") * 2 ** 20)
+    kwargs.setdefault("vmem_limit_bytes", VMEM_LIMIT_BYTES)
     return pltpu.CompilerParams(**kwargs)
 
 

@@ -31,9 +31,8 @@ from typing import Any, Callable, Literal, Optional
 # doc — and read through `knob()`. Modules never touch os.environ
 # directly (scripts/lint.py's env-read rule enforces this), so the full
 # tunable surface is one table: `python -m distributed_pytorch_tpu
-# --knobs` prints it, and a bench/sweep leg can grep it instead of the
-# source. Values are parsed PER READ (never cached here) so tests and
-# sweep subprocesses can monkeypatch the environment; modules that want
+# --knobs` prints it. Values are parsed PER READ (never cached here) so
+# tests can monkeypatch the environment; modules that want
 # import-time freezing (kernel tile sizes) assign the result to a module
 # constant exactly as before.
 # ---------------------------------------------------------------------------
@@ -93,23 +92,10 @@ def knobs_table() -> str:
                      for r in rows)
 
 
-# --- kernel tile sizes (read at import by their owner modules so
-# mfu_sweep can A/B them per subprocess) ---
-register_knob("FLASH_BLOCK_Q", "1024", int,
-              "flash-attention query tile rows (ops/flash_attention.py)")
-register_knob("FLASH_BLOCK_K", "1024", int,
-              "flash-attention kv tile length")
-register_knob("FLASH_BLOCK_H", "1", int,
-              "flash-attention rows per grid group at a full Q x K tile "
-              "(proportionally more at smaller tiles)")
-register_knob("FLASH_LAYOUT", "rows", lambda s: s.strip().lower(),
-              "flash kernel layout: rows (BTNH transpose) | slab (compiled "
-              "only for 128-multiple head dims: Mosaic refuses its in-VMEM "
-              "head split at 64; the gate then takes rows)")
-register_knob("FLASH_VMEM_BUDGET_MB", "64", int,
-              "scoped-VMEM limit handed to Mosaic for every Pallas kernel "
-              "AND the budget their usable gates check (half of a v5e "
-              "core's VMEM; compat.tpu_compiler_params)")
+# --- kernel tile sizes (read at import by their owner modules). The
+# flash-attention tiles and the scoped-VMEM limit are constants since the
+# chip chose them (ops/flash_attention.py, compat.py); each of these
+# becomes one with the ROADMAP item that measures it (S4, R2, S5) ---
 register_knob("CE_BLOCK_N", "512", int,
               "pallas fused-CE token tile (ops/fused_ce.py)")
 register_knob("CE_BLOCK_V", "2048", int,
@@ -328,7 +314,7 @@ register_knob("SIM_BOOT_S", "2.0",
 
 
 # --- persistent compilation cache (one placement rule for every entry
-# point: trainer, serve and sample CLIs, bench workers, chip_smoke
+# point: trainer, serve and sample CLIs, benchmark/run.py, chip_smoke
 # children, tests/conftest.py) ---
 COMPILE_CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
@@ -517,9 +503,8 @@ class LLMConfig:
 def flagship_gpt124m(**overrides) -> "LLMConfig":
     """The headline GPT-2-124M-class benchmark model (BASELINE.json north
     star; the config the reference's single-gpu/train.sh trains at
-    block_size 1024). One definition shared by bench.py, the MFU sweep and
-    profiler scripts, and the driver entry — so every measurement measures
-    the same model.
+    block_size 1024). One definition shared by the `gpt2_124m` preset,
+    scripts/profile_step.py and the driver entry (__graft_entry__.py).
 
     up_dim is 2048, not GPT-2's 3072: with the gated swiglu FFN the fused
     up projection is (C, 2*up_dim), so 2048 reproduces exactly GPT-2's
@@ -572,8 +557,9 @@ def gpt2_7b(**overrides) -> "LLMConfig":
     return _gpt2_preset(4096, 32, 32, 10880, **overrides)
 
 
-# name -> factory; the CLI's --preset flag and bench.py's ladder legs both
-# resolve through this table so a rung cannot drift between them.
+# name -> factory; the CLI's --preset flag, memplan, shardcheck and
+# commscheck all resolve through this table so a rung cannot drift between
+# them.
 PRESETS = {
     "gpt2_124m": flagship_gpt124m,
     "gpt2_350m": gpt2_350m,
@@ -632,7 +618,7 @@ class TrainConfig:
     # (ops/collective_matmul.py): 'on' fuses param all-gathers / grad
     # reduce-scatters into ppermute rings overlapped with the matmuls;
     # 'auto' keeps the known-good GSPMD schedule until a hardware number
-    # exists. The OVERLAP env var overrides this field (bench/sweep A/B).
+    # exists. The OVERLAP env var overrides this field.
     overlap: str = "auto"            # auto | on | off
     # checkpoint/resume (exceeds reference save-only; SURVEY.md §5)
     ckpt_interval: int = 0           # 0 = end-of-run only
@@ -662,7 +648,7 @@ class TrainConfig:
     # HBM pays params+grads+activations only, the optimizer costs PCIe
     # bandwidth. 'auto' = on iff memplan prices the in-HBM plan over
     # budget AND the offload plan under it; the OFFLOAD env knob
-    # overrides this field (bench/sweep A/B legs).
+    # overrides this field.
     offload: str = "auto"            # auto | on | off
 
     def __post_init__(self):

@@ -29,8 +29,9 @@ def make_synthetic_bin(path: str, n_tokens: int = 2 ** 20,
                        vocab_size: int = 50304, seed: int = 1729) -> str:
     """Write a synthetic uint16 token file with mild Markov structure (so
     loss can actually decrease — pure uniform noise has nothing to learn).
-    Used by tests and by bench.py when no prepared dataset exists (this
-    environment has no network egress for the real downloads)."""
+    Used by tests and by the trainer's `--dataset synthetic` when no
+    prepared dataset exists (this environment has no network egress for
+    the real downloads)."""
     os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
     rng = np.random.default_rng(seed)
     eff_vocab = min(vocab_size, 1024)

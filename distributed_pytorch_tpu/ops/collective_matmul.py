@@ -60,8 +60,9 @@ from distributed_pytorch_tpu.parallel.sharding import spec_for_param
 _ZERO3_RECIPES = ("fsdp", "fsdp_tp", "sp")
 
 # What 'auto' means today: GSPMD. The first chip run that measures
-# OVERLAP=on faster flips this to "on" (bench.py / mfu_sweep.py carry the
-# A/B legs so no code change is needed to take the measurement).
+# OVERLAP=on faster flips this to "on": the four-chip fsdp cell PERF.md
+# section 7 lists (`gpt2xl_train_fsdp4`), run once with OVERLAP=on; no
+# code change is needed to take the measurement.
 _AUTO_RESOLVES_TO = "off"
 
 
@@ -69,7 +70,7 @@ def resolve_mode(config_mode: str = "auto") -> str:
     """'on' | 'off' after applying env-var precedence and the auto default.
 
     The OVERLAP env var (on/off/auto) wins over the TrainConfig field so
-    bench/sweep legs can A/B without a config plumb-through."""
+    a run can A/B without a config plumb-through."""
     mode = config.knob("OVERLAP") or config_mode
     if mode not in ("auto", "on", "off"):
         raise ValueError(f"OVERLAP must be auto|on|off, got {mode!r}")

@@ -37,6 +37,11 @@ single-gpu/model.py:149). Design (per the Pallas TPU playbook):
   per-query-row dk/dv and group-sums them host-side. Head dims must be
   sublane multiples (hs % 8); there is no padding path — odd head dims
   fall back to the XLA impl via `flash_attention_usable`.
+* One layout. The kernels take (B*H, T, D) rows, so `flash_attention_lse`
+  transposes the model's BTNH operands in HBM on the way in and the output
+  on the way out: 6.6 ms of the 42.3 ms attention core in `gpt2_train_b16`
+  (PERF.md section 6, PR 29). Head-major projections are the repair
+  (ROADMAP S2b), not a second kernel family.
 
 The public entry points keep the interface the dispatcher
 (ops/attention_core.py) fixed while this was a stub: `flash_attention` and
@@ -46,45 +51,32 @@ The public entry points keep the interface the dispatcher
 from __future__ import annotations
 
 import functools
+import warnings
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from distributed_pytorch_tpu import config
+from distributed_pytorch_tpu.compat import (VMEM_LIMIT_BYTES,
+                                            tpu_compiler_params, vma_of)
 
-# Tile-size knobs (read at import so scripts/mfu_sweep.py --variants blocks
-# can A/B them per subprocess without an API change). 1024 x 1024 q/kv tiles
-# (the whole sequence in one tile at T = 1024: no online-softmax rescale
-# between kv tiles) and one row a grid step are what won on a v5e at the
-# train cell's shape (192 rows, T = 1024, D = 64, bf16), forward + backward:
-# 3.83 ms a call against 4.20 for the former 256 x 512 x 8 over a 47-point
-# sweep, and 95,793 against 93,327 tokens/s/chip end to end in
-# `gpt2_train_b16` (PERF.md section 6, PR 29). Shorter sequences take the
-# largest tiles that divide them (`_pick_block`) and as many more rows a
-# step as the tile is smaller (`flash_attention_lse`); wider heads fewer
-# (`_pick_group`, the VMEM budget).
-DEFAULT_BLOCK_Q = config.knob("FLASH_BLOCK_Q")
-DEFAULT_BLOCK_K = config.knob("FLASH_BLOCK_K")
-DEFAULT_BLOCK_H = config.knob("FLASH_BLOCK_H")
-
-# Kernel layout (round 5): 'rows' flattens (B, H) into grid rows and needs
-# a BTNH -> (B*H, T, D) HBM transpose per operand per call — the profile's
-# 44 ms/step "layout copies" bucket (PERF.md r4). 'slab' reads the model's
-# natural (B, T, N*H) slabs directly (contiguous DMA, zero HBM transposes)
-# and relayouts head-major in VMEM; it also handles GQA in-kernel (no
-# materialized K/V repeat in HBM, group-sum of dk/dv at the write step).
-# Default stays 'rows' — the only layout that has compiled on real TPU
-# hardware so far — until the on-hardware sweep (mfu_sweep --variants
-# blocks, FLASH_LAYOUT legs) proves the slab path. A slab step holds every
-# head's tiles at once, so at the rows layout's 1024 x 1024 default its gate
-# declines from 8 heads of 128 up: ask for it with smaller FLASH_BLOCK_Q/K.
-DEFAULT_LAYOUT = config.knob("FLASH_LAYOUT")
+# The tiles. 1024 x 1024 q/kv tiles (the whole sequence in one tile at
+# T = 1024: no online-softmax rescale between kv tiles) and one row a grid
+# step are what won on a v5e at the train cell's shape (192 rows, T = 1024,
+# D = 64, bf16), forward + backward: 3.83 ms a call against 4.20 for the
+# former 256 x 512 x 8 over a 47-point sweep, and 95,793 against 93,327
+# tokens/s/chip end to end in `gpt2_train_b16` (PERF.md section 6, PR 29).
+# Shorter sequences take the largest tiles that divide them (`_pick_block`)
+# and as many more rows a step as the tile is smaller, wider heads fewer
+# (`_pick_group`, the VMEM limit). The `block_q/k/h` arguments are for the
+# parity tests, which hold the kernels to the oracle at tilings small
+# enough to exercise the tile-to-tile paths; the program passes none.
+BLOCK_Q = 1024
+BLOCK_K = 1024
+BLOCK_H = 1
 
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps masked rows NaN-free
-
-from distributed_pytorch_tpu.compat import tpu_compiler_params, vma_of
 
 _SEMANTICS = tpu_compiler_params(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
@@ -208,80 +200,68 @@ def _kv_spec(rep: int, g: int, block_q: int, block_k: int, D: int,
     return pl.BlockSpec((g if rep == 1 else 1, block_k, D), kv_idx)
 
 
-# VMEM budget for one grid step's tiles + scratch + f32 score intermediates.
-# It is ALSO the scoped-VMEM limit the kernels hand Mosaic
-# (compat.tpu_compiler_params), so a config this gate passes is one the
-# compiler was given room for: an oversized block/group config degrades
-# (smaller row group, or the gate declines) instead of failing compilation
-# with "exceeded scoped vmem limit".
-_VMEM_BUDGET = config.knob("FLASH_VMEM_BUDGET_MB") * 2 ** 20
-
 _LANE = 128
 
 
-def _vmem_bytes(g: int, gk: int, bq: int, bk: int, D: int,
-                dsize: int) -> int:
-    """Worst-case-kernel (dkv backward) VMEM estimate for one grid step,
-    counting what Mosaic really allocates: the minor dim of every tile is
-    padded to the 128-lane register tile, so a D=64 head tile occupies a
-    D=128 one and each (bq, 1) lse/delta/m/l column occupies (bq, 128);
-    I/O tiles are double-buffered; plus the f32 accumulator scratch and
-    the f32 score/prob/dscore intermediates the body materializes.
-    Checked against the v5e compiler's own minimum (device-free compile,
-    binary search on the limit): flagship g=8, 256x512, D=64 needs 24 MiB
-    — the pre-padding estimate said 19 and the gate passed configs the
-    compiler refused — this says 30; it stays at or above the compiler's
-    need from 128x128 to 512x1024 tiles and D in {64, 128, 256}."""
+def _vmem_bytes(g: int, bq: int, bk: int, D: int, dsize: int) -> int:
+    """Worst-case-kernel (dkv backward) VMEM estimate for one grid step of
+    `g` rows, counting what Mosaic really allocates: the minor dim of every
+    tile is padded to the 128-lane register tile, so a D=64 head tile
+    occupies a D=128 one and each (bq, 1) lse/delta/m/l column occupies
+    (bq, 128); I/O tiles are double-buffered; plus the f32 accumulator
+    scratch and the f32 score/prob/dscore intermediates the body
+    materializes. It is held against VMEM_LIMIT_BYTES, the scoped-VMEM
+    limit the kernels hand Mosaic, so an oversized block/group config
+    degrades (smaller row group, or the gate declines) instead of failing
+    compilation with "exceeded scoped vmem limit". Checked against the v5e
+    compiler's own minimum (device-free compile, binary search on the
+    limit): g=8, 256x512, D=64 needs 24 MiB — the pre-padding estimate
+    said 19 and the gate passed configs the compiler refused — this says
+    30; it stays at or above the compiler's need from 128x128 to 512x1024
+    tiles and D in {64, 128, 256}."""
     Dp = -(-D // _LANE) * _LANE
     col = g * bq * _LANE * 4                    # one (g, bq, 1) f32 column
     score = 3 * g * bq * bk * 4
-    fwd = (2 * ((2 * g * bq * Dp + 2 * gk * bk * Dp) * dsize + col)
+    fwd = (2 * ((2 * g * bq * Dp + 2 * g * bk * Dp) * dsize + col)
            + g * bq * Dp * 4 + 2 * col + score)
-    bwd = (2 * ((2 * g * bq * Dp + 2 * gk * bk * Dp + 2 * g * bk * Dp)
+    bwd = (2 * ((2 * g * bq * Dp + 2 * g * bk * Dp + 2 * g * bk * Dp)
                 * dsize + 2 * col)
            + 2 * g * bk * Dp * 4 + score)
     return max(fwd, bwd)
 
 
-def _pick_group(n_rows: int, rep: int, preferred: int,
-                block_q: int = 0, block_k: int = 0, D: int = 0,
-                dsize: int = 2) -> int:
-    """Row-group size: a divisor of n_rows, 1 unless kv rows map 1:1
-    (rep == 1 — with grouped rows a GQA group would need strided kv
-    tiles). When block sizes are known, the group shrinks until the
-    per-step VMEM estimate fits the budget."""
+def _pick_group(n_rows: int, rep: int, block_q: int, block_k: int, D: int,
+                dsize: int) -> int:
+    """Rows a grid step. BLOCK_H at the full BLOCK_Q x BLOCK_K tile and
+    proportionally more where the sequence is shorter than the tile, so a
+    grid step keeps its work (a 512 x 512 tile ran 12% slower in a group of
+    1 than of 4, a 256 x 256 one 34%: PERF.md section 6, PR 29); a divisor
+    of n_rows; shrunk until the per-step VMEM estimate fits the limit; 1
+    unless kv rows map 1:1 (rep == 1 — with grouped rows a GQA group would
+    need strided kv tiles)."""
     if rep != 1:
         return 1
-    g = min(preferred, n_rows)
-    while g > 1 and n_rows % g != 0:
-        g -= 1
-    g = max(g, 1)
-    if block_q and block_k and D:
-        req = g
-        while g > 1 and _vmem_bytes(g, g, block_q, block_k, D,
-                                    dsize) > _VMEM_BUDGET:
-            g -= 1
-            while g > 1 and n_rows % g != 0:
-                g -= 1
-        if g != req and (req, g, block_q, block_k) not in _SHRINK_WARNED:
-            # once per unique config: this runs at TRACE time, and repeated
-            # jit traces / vmap would otherwise spam a bare stderr print
-            # for every retrace (round-5 ADVICE)
-            _SHRINK_WARNED.add((req, g, block_q, block_k))
-            import warnings
-            warnings.warn(
-                f"[flash] row group shrunk {req} -> {g} to fit the "
-                f"{_VMEM_BUDGET >> 20} MiB VMEM budget at blocks "
-                f"({block_q}, {block_k})", RuntimeWarning, stacklevel=2)
-    return max(g, 1)
+    want = BLOCK_H * max(1, (BLOCK_Q * BLOCK_K) // (block_q * block_k))
+    divisors = [g for g in range(min(want, n_rows), 0, -1) if n_rows % g == 0]
+    req = divisors[0]
+    g = next((g for g in divisors if _vmem_bytes(
+        g, block_q, block_k, D, dsize) <= VMEM_LIMIT_BYTES), 1)
+    if g != req and (req, g, block_q, block_k) not in _SHRINK_WARNED:
+        # once per unique config: this runs at TRACE time, and repeated
+        # jit traces / vmap would otherwise warn on every retrace
+        _SHRINK_WARNED.add((req, g, block_q, block_k))
+        warnings.warn(
+            f"[flash] row group shrunk {req} -> {g} to fit the "
+            f"{VMEM_LIMIT_BYTES >> 20} MiB VMEM limit at blocks "
+            f"({block_q}, {block_k})", RuntimeWarning, stacklevel=2)
+    return g
 
 
 _SHRINK_WARNED: set = set()
 
 
 # ---------------------------------------------------------------------------
-# shared tile math (ONE copy of the FlashAttention-2 numerics — the rows
-# and slab kernel faces differ only in how tiles are loaded/stored)
+# tile math (the FlashAttention-2 numerics; the kernels below load and store)
 # ---------------------------------------------------------------------------
 
 def _fwd_tile(q, k, v, r, i, j, seed_ref, m_ref, l_ref, acc_ref, *, scale,
@@ -586,297 +566,6 @@ def _bwd_impl(scale, block_q, block_k, g, interpret, causal, rate, res, do,
     return dq, dk, dv, None  # seed (int32) gets no cotangent
 
 
-# ---------------------------------------------------------------------------
-# slab layout: kernels read (B, T, N*H) directly — no HBM transposes
-# ---------------------------------------------------------------------------
-
-def _load_hbd(ref, n: int, D: int, rep: int = 1):
-    """(1, t, n*D) ref -> (n*rep, t, D) head-major tile: the VMEM relayout
-    that replaces the rows layout's per-call HBM transpose. GQA expands the
-    kv heads here, in VMEM, where the repeat costs bandwidth the MXU pass
-    was going to spend anyway — never in HBM."""
-    t = ref[0].reshape(ref.shape[1], n, D).transpose(1, 0, 2)
-    if rep > 1:
-        t = jnp.repeat(t, rep, axis=0)
-    return t
-
-
-def _slab_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
-                     m_ref, l_ref, *, scale, block_q, block_k, nh, nkv, D,
-                     causal, rate):
-    b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    last_j = _last_visible_kv(i, block_q, block_k) if causal \
-        else pl.num_programs(2) - 1
-
-    @pl.when(j == 0)
-    def _():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
-    @pl.when(j <= last_j)
-    def _():
-        # dropout keying: tile row index b with group nh gives row0 = b*nh
-        # — the same absolute attention row as the rows layout, so the two
-        # layouts draw identical masks
-        _fwd_tile(_load_hbd(q_ref, nh, D), _load_hbd(k_ref, nkv, D, nh // nkv),
-                  _load_hbd(v_ref, nkv, D, nh // nkv), b, i, j, seed_ref,
-                  m_ref, l_ref, acc_ref, scale=scale, block_q=block_q,
-                  block_k=block_k, causal=causal, rate=rate)
-
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _():
-        o, lse = _fwd_finalize(m_ref, l_ref, acc_ref)   # (nh, bq, D)
-        o_ref[0] = o.transpose(1, 0, 2).reshape(
-            o.shape[1], nh * D).astype(o_ref.dtype)
-        lse_ref[0] = lse[:, :, 0].T
-
-
-def _slab_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                        delta_ref, dq_ref, dq_acc, *, scale, block_q,
-                        block_k, nh, nkv, D, causal, rate):
-    b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    last_j = _last_visible_kv(i, block_q, block_k) if causal \
-        else pl.num_programs(2) - 1
-
-    @pl.when(j == 0)
-    def _():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
-
-    @pl.when(j <= last_j)
-    def _():
-        _dq_tile(_load_hbd(q_ref, nh, D), _load_hbd(k_ref, nkv, D, nh // nkv),
-                 _load_hbd(v_ref, nkv, D, nh // nkv), _load_hbd(do_ref, nh, D),
-                 lse_ref[0].T[:, :, None], delta_ref[0].T[:, :, None],
-                 b, i, j, seed_ref, dq_acc, scale=scale, block_q=block_q,
-                 block_k=block_k, causal=causal, rate=rate)
-
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _():
-        dq = (dq_acc[:] * scale).transpose(1, 0, 2)
-        dq_ref[0] = dq.reshape(dq.shape[0], nh * D).astype(dq_ref.dtype)
-
-
-def _slab_bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                         delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
-                         scale, block_q, block_k, nh, nkv, D, causal, rate):
-    b, j, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    first_i = _first_visible_q(j, block_q, block_k) if causal else 0
-    rep = nh // nkv
-
-    @pl.when(i == 0)
-    def _():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
-
-    @pl.when(i >= first_i)
-    def _():
-        _dkv_tile(_load_hbd(q_ref, nh, D), _load_hbd(k_ref, nkv, D, rep),
-                  _load_hbd(v_ref, nkv, D, rep), _load_hbd(do_ref, nh, D),
-                  lse_ref[0].T[:, :, None], delta_ref[0].T[:, :, None],
-                  b, i, j, seed_ref, dk_acc, dv_acc, scale=scale,
-                  block_q=block_q, block_k=block_k, causal=causal,
-                  rate=rate)
-
-    @pl.when(i == pl.num_programs(2) - 1)
-    def _():
-        dk = dk_acc[:] * scale                          # (nh, bk, D)
-        dv = dv_acc[:]
-        if rep > 1:
-            # GQA group-sum folded into the write step (the rows layout
-            # does this host-side over per-query-row HBM outputs)
-            dk = dk.reshape(nkv, rep, dk.shape[1], D).sum(axis=1)
-            dv = dv.reshape(nkv, rep, dv.shape[1], D).sum(axis=1)
-        dk_ref[0] = dk.transpose(1, 0, 2).reshape(
-            dk.shape[1], nkv * D).astype(dk_ref.dtype)
-        dv_ref[0] = dv.transpose(1, 0, 2).reshape(
-            dv.shape[1], nkv * D).astype(dv_ref.dtype)
-
-
-def _slab_fwd(q, k, v, seed, scale, block_q, block_k, interpret,
-              causal, rate, nh, nkv, D):
-    """q (B, T, nh*D) slabs; k/v (B, S, nkv*D) -> out (B, T, nh*D),
-    lse (B, T, nh)."""
-    B, T, _ = q.shape
-    S = k.shape[1]
-    nq, nk = T // block_q, S // block_k
-
-    def q_row(b, i, j):
-        return (b, i, 0)
-
-    def kv_row(b, i, j):
-        jc = j if not causal \
-            else jnp.minimum(j, _last_visible_kv(i, block_q, block_k))
-        return (b, jc, 0)
-
-    return pl.pallas_call(
-        functools.partial(_slab_fwd_kernel, scale=scale, block_q=block_q,
-                          block_k=block_k, nh=nh, nkv=nkv, D=D,
-                          causal=causal, rate=rate),
-        grid=(B, nq, nk),
-        in_specs=[
-            _SEED_SPEC,
-            pl.BlockSpec((1, block_q, nh * D), q_row),
-            pl.BlockSpec((1, block_k, nkv * D), kv_row),
-            pl.BlockSpec((1, block_k, nkv * D), kv_row),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, nh * D), q_row),
-            pl.BlockSpec((1, block_q, nh), q_row),
-        ],
-        out_shape=[
-            _sds((B, T, nh * D), q.dtype, q),
-            _sds((B, T, nh), jnp.float32, q),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((nh, block_q, D), jnp.float32),
-            pltpu.VMEM((nh, block_q, 1), jnp.float32),
-            pltpu.VMEM((nh, block_q, 1), jnp.float32),
-        ],
-        compiler_params=_SEMANTICS,
-        name="flash_slab_fwd",
-        interpret=interpret,
-    )(seed, q, k, v)
-
-
-def _slab_bwd(scale, block_q, block_k, interpret, causal, rate, nh, nkv, D,
-              res, do, dlse=None):
-    q, k, v, seed, out, lse = res
-    B, T, _ = q.shape
-    S = k.shape[1]
-    nq, nk = T // block_q, S // block_k
-    do3 = do.reshape(B, T, nh, D).astype(jnp.float32)
-    out3 = out.reshape(B, T, nh, D).astype(jnp.float32)
-    delta = jnp.sum(do3 * out3, axis=-1)                # (B, T, nh) f32
-    if dlse is not None:
-        delta = delta - dlse.astype(jnp.float32)
-
-    def q_row(b, i, j):
-        return (b, i, 0)
-
-    def kv_clamped(b, i, j):
-        jc = j if not causal \
-            else jnp.minimum(j, _last_visible_kv(i, block_q, block_k))
-        return (b, jc, 0)
-
-    dq = pl.pallas_call(
-        functools.partial(_slab_bwd_dq_kernel, scale=scale, block_q=block_q,
-                          block_k=block_k, nh=nh, nkv=nkv, D=D,
-                          causal=causal, rate=rate),
-        grid=(B, nq, nk),
-        in_specs=[
-            _SEED_SPEC,
-            pl.BlockSpec((1, block_q, nh * D), q_row),
-            pl.BlockSpec((1, block_k, nkv * D), kv_clamped),
-            pl.BlockSpec((1, block_k, nkv * D), kv_clamped),
-            pl.BlockSpec((1, block_q, nh * D), q_row),
-            pl.BlockSpec((1, block_q, nh), q_row),
-            pl.BlockSpec((1, block_q, nh), q_row),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, nh * D), q_row),
-        out_shape=_sds((B, T, nh * D), q.dtype, q),
-        scratch_shapes=[pltpu.VMEM((nh, block_q, D), jnp.float32)],
-        compiler_params=_SEMANTICS,
-        name="flash_slab_bwd_dq",
-        interpret=interpret,
-    )(seed, q, k, v, do, lse, delta)
-
-    def kv_row(b, j, i):
-        return (b, j, 0)
-
-    def q_clamped(b, j, i):
-        ic = i if not causal \
-            else jnp.maximum(i, _first_visible_q(j, block_q, block_k))
-        return (b, ic, 0)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_slab_bwd_dkv_kernel, scale=scale,
-                          block_q=block_q, block_k=block_k, nh=nh, nkv=nkv,
-                          D=D, causal=causal, rate=rate),
-        grid=(B, nk, nq),
-        in_specs=[
-            _SEED_SPEC,
-            pl.BlockSpec((1, block_q, nh * D), q_clamped),
-            pl.BlockSpec((1, block_k, nkv * D), kv_row),
-            pl.BlockSpec((1, block_k, nkv * D), kv_row),
-            pl.BlockSpec((1, block_q, nh * D), q_clamped),
-            pl.BlockSpec((1, block_q, nh), q_clamped),
-            pl.BlockSpec((1, block_q, nh), q_clamped),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, nkv * D), kv_row),
-            pl.BlockSpec((1, block_k, nkv * D), kv_row),
-        ],
-        out_shape=[
-            _sds((B, S, nkv * D), k.dtype, q),
-            _sds((B, S, nkv * D), v.dtype, q),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((nh, block_k, D), jnp.float32),
-            pltpu.VMEM((nh, block_k, D), jnp.float32),
-        ],
-        compiler_params=_SEMANTICS,
-        name="flash_slab_bwd_dkv",
-        interpret=interpret,
-    )(seed, q, k, v, do, lse, delta)
-    return dq, dk, dv, None
-
-
-def _make_slab_lse(nh: int, nkv: int, D: int):
-    """custom_vjp closure over the static head geometry (cached per
-    geometry via _slab_lse_for so jit tracing reuses one vjp instance)."""
-
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
-    def slab_lse(q, k, v, seed, scale, block_q, block_k, interpret, causal,
-                 rate):
-        return _slab_fwd(q, k, v, seed, scale, block_q, block_k, interpret,
-                         causal, rate, nh, nkv, D)
-
-    def fwd(q, k, v, seed, scale, block_q, block_k, interpret, causal,
-            rate):
-        out, lse = _slab_fwd(q, k, v, seed, scale, block_q, block_k,
-                             interpret, causal, rate, nh, nkv, D)
-        return (out, lse), (q, k, v, seed, out, lse)
-
-    def bwd(scale, block_q, block_k, interpret, causal, rate, res, cts):
-        do, dlse = cts
-        return _slab_bwd(scale, block_q, block_k, interpret, causal, rate,
-                         nh, nkv, D, res, do, dlse=dlse)
-
-    slab_lse.defvjp(fwd, bwd)
-    return slab_lse
-
-
-@functools.lru_cache(maxsize=64)
-def _slab_lse_for(nh: int, nkv: int, D: int):
-    return _make_slab_lse(nh, nkv, D)
-
-
-def slab_attention_usable(B, T, S, nh, nkv, hs, dtype,
-                          block_q: int = 0, block_k: int = 0,
-                          interpret: bool = False) -> bool:
-    """Gate for the slab layout: lane-aligned head slabs ((n*hs) % 128),
-    sublane-aligned blocks, and the (nh, bq, bk) f32 score tile + scratch
-    within the VMEM budget. COMPILED, the head dim itself must be a lane
-    multiple: Mosaic refuses `_load_hbd`'s in-VMEM (t, n*hs) -> (n, t, hs)
-    split for hs < 128 ("infer-vector-layout: unsupported shape cast",
-    device-free v5e compile at hs=64), so every 64-wide-head preset leaves
-    this layout; interpret mode (the CPU parity tests) has no such limit."""
-    if (nh * hs) % 128 != 0 or (nkv * hs) % 128 != 0 or hs % 8 != 0:
-        return False
-    if not interpret and hs % _LANE != 0:
-        return False
-    bq = block_q or _pick_block(T, DEFAULT_BLOCK_Q)
-    bk = block_k or _pick_block(S, DEFAULT_BLOCK_K)
-    if not (bq and bk):
-        return False
-    dsize = jnp.dtype(dtype).itemsize
-    # GQA: _load_hbd jnp.repeat-expands K/V to nh heads IN VMEM (only the
-    # HBM tiles stay at nkv), so the budget counts the post-repeat
-    # intermediates at nh (round-5 ADVICE)
-    return _vmem_bytes(nh, nh, bq, bk, hs, dsize) <= _VMEM_BUDGET
-
-
 # One custom_vjp serves both public entries: (out, lse) with the lse
 # output differentiable (the ring merge needs d/dlse; when a caller
 # ignores lse, jax hands back a zero cotangent and the backward reduces
@@ -922,7 +611,7 @@ def _pick_block(n: int, preferred: int) -> int:
 def flash_attention_decline(q, k, v, *, causal: bool = True):
     """Why the dispatcher may NOT send this call to the kernel — None
     when it may. Static (shapes/dtypes only)."""
-    B, T, nh, hs = q.shape
+    T, hs = q.shape[1], q.shape[3]
     S = k.shape[1]
     if q.dtype not in (jnp.float32, jnp.bfloat16):
         return f"dtype {q.dtype} (kernel handles float32 / bfloat16)"
@@ -930,21 +619,18 @@ def flash_attention_decline(q, k, v, *, causal: bool = True):
         return f"T={T}, S={S}: decode-step shapes take the naive path"
     if hs % 8 != 0:
         return f"head dim {hs} is not a sublane (8) multiple"
-    bq = _pick_block(T, DEFAULT_BLOCK_Q)
-    bk = _pick_block(S, DEFAULT_BLOCK_K)
+    bq = _pick_block(T, BLOCK_Q)
+    bk = _pick_block(S, BLOCK_K)
     if not (bq and bk):
         return f"no block split (multiple of 8) divides T={T}, S={S}"
-    # even a group of 1 must fit the per-step VMEM budget
+    # even a group of 1 must fit the per-step VMEM limit
     dsize = jnp.dtype(q.dtype).itemsize
-    need = _vmem_bytes(1, 1, bq, bk, hs, dsize)
-    if need <= _VMEM_BUDGET:
-        return None
-    if DEFAULT_LAYOUT == "slab" and slab_attention_usable(
-            B, T, S, nh, k.shape[2], hs, q.dtype):
+    need = _vmem_bytes(1, bq, bk, hs, dsize)
+    if need <= VMEM_LIMIT_BYTES:
         return None
     return (f"one ({bq}, {bk}) tile step at head dim {hs} needs "
-            f"{need >> 20} MiB of VMEM, over the {_VMEM_BUDGET >> 20} MiB "
-            "scoped limit (FLASH_VMEM_BUDGET_MB)")
+            f"{need >> 20} MiB of VMEM, over the "
+            f"{VMEM_LIMIT_BYTES >> 20} MiB scoped limit")
 
 
 def flash_attention_usable(q, k, v, *, causal: bool = True) -> bool:
@@ -955,9 +641,8 @@ def flash_attention_usable(q, k, v, *, causal: bool = True) -> bool:
 
 def flash_attention_lse(q, k, v, *, scale: float, causal: bool = True,
                         block_q: int = 0, block_k: int = 0,
-                        block_h: int = 0, layout: str | None = None,
-                        dropout_rate: float = 0.0, dropout_rng=None,
-                        interpret: bool = False):
+                        block_h: int = 0, dropout_rate: float = 0.0,
+                        dropout_rng=None, interpret: bool = False):
     """Flash attention returning (out, lse) over BTNH-layout tensors.
 
     out: (B, T, nh, hs); lse: (B, T, nh) f32 logsumexp of the scaled
@@ -983,8 +668,8 @@ def flash_attention_lse(q, k, v, *, scale: float, causal: bool = True,
     assert nh % nkv == 0, "query heads must be a multiple of kv heads"
     rep = nh // nkv
 
-    block_q = block_q or _pick_block(T, DEFAULT_BLOCK_Q)
-    block_k = block_k or _pick_block(S, DEFAULT_BLOCK_K)
+    block_q = block_q or _pick_block(T, BLOCK_Q)
+    block_k = block_k or _pick_block(S, BLOCK_K)
     assert block_q and T % block_q == 0 and block_k and S % block_k == 0, (
         f"no usable block split for T={T}, S={S} — gate with "
         f"flash_attention_usable first")
@@ -999,25 +684,7 @@ def flash_attention_lse(q, k, v, *, scale: float, causal: bool = True,
     else:
         seed = jnp.zeros((2,), jnp.int32)
 
-    if layout is None:
-        layout = DEFAULT_LAYOUT
-    if layout == "slab" and slab_attention_usable(
-            B, T, S, nh, nkv, hs, q.dtype, block_q, block_k, interpret):
-        # (B, T, N, H) -> (B, T, N*H) is a FREE reshape of the model's
-        # natural layout: zero HBM transposes in or out
-        fn = _slab_lse_for(nh, nkv, hs)
-        out, lse = fn(q.reshape(B, T, nh * hs), k.reshape(B, S, nkv * hs),
-                      v.reshape(B, S, nkv * hs), seed, float(scale),
-                      block_q, block_k, interpret, causal, rate)
-        return out.reshape(B, T, nh, hs), lse
-
-    # FLASH_BLOCK_H rows a grid step at the full FLASH_BLOCK_Q x K tile,
-    # proportionally more where the sequence is shorter than the tile: a
-    # grid step keeps its work (a 512 x 512 tile ran 12% slower in a group
-    # of 1 than of 4, a 256 x 256 one 34%: PERF.md section 6, PR 29)
-    want = DEFAULT_BLOCK_H * max(
-        1, (DEFAULT_BLOCK_Q * DEFAULT_BLOCK_K) // (block_q * block_k))
-    g = block_h or _pick_group(B * nh, rep, want, block_q, block_k, hs,
+    g = block_h or _pick_group(B * nh, rep, block_q, block_k, hs,
                                jnp.dtype(q.dtype).itemsize)
     assert (B * nh) % g == 0 and (g == 1 or rep == 1), (
         f"row group {g} must divide B*nh={B * nh} and needs nh == n_kv")
@@ -1035,9 +702,8 @@ def flash_attention_lse(q, k, v, *, scale: float, causal: bool = True,
 
 def flash_attention(q, k, v, *, scale: float, causal: bool = True,
                     q_offset=0, block_q: int = 0, block_k: int = 0,
-                    block_h: int = 0, layout: str | None = None,
-                    dropout_rate: float = 0.0, dropout_rng=None,
-                    interpret: bool = False) -> jnp.ndarray:
+                    block_h: int = 0, dropout_rate: float = 0.0,
+                    dropout_rng=None, interpret: bool = False) -> jnp.ndarray:
     """Flash attention over BTNH-layout tensors.
 
     q: (B, T, nh, hs); k, v: (B, S, nkv, hs) with nkv | nh. `q_offset`
@@ -1052,7 +718,7 @@ def flash_attention(q, k, v, *, scale: float, causal: bool = True,
         "offsets must use the naive path")
     out, _ = flash_attention_lse(q, k, v, scale=scale, causal=causal,
                                  block_q=block_q, block_k=block_k,
-                                 block_h=block_h, layout=layout,
+                                 block_h=block_h,
                                  dropout_rate=dropout_rate,
                                  dropout_rng=dropout_rng,
                                  interpret=interpret)

@@ -78,17 +78,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from distributed_pytorch_tpu import config
-from distributed_pytorch_tpu.compat import tpu_compiler_params
+from distributed_pytorch_tpu.compat import (VMEM_LIMIT_BYTES,
+                                            tpu_compiler_params)
 
-# KV-length tile (lane dimension of the score tiles). Env knob so
-# `mfu_sweep --variants decode` can ablate it per subprocess, like
-# FLASH_BLOCK_* / GMM_BLOCK_*.
+# KV-length tile (lane dimension of the score tiles), read from the
+# environment at import; ROADMAP S5 measures it on the chip and makes it a
+# constant.
 DEFAULT_BLOCK_S = config.knob("FLASH_DECODE_BLOCK")
 
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps masked rows NaN-free
-
-# one grid step's buffers: double-buffered kv tiles + f32 scratch + scores
-_VMEM_BUDGET = config.knob("FLASH_VMEM_BUDGET_MB") * 2 ** 20
 
 
 def decode_mode() -> str:
@@ -752,10 +750,10 @@ def _common_decline(q, k, nh, nkv, hs, bs, what: str):
 
 
 def _budget_decline(need: int):
-    if need <= _VMEM_BUDGET:
+    if need <= VMEM_LIMIT_BYTES:
         return None
     return (f"one grid step needs {need >> 20} MiB of VMEM, over the "
-            f"{_VMEM_BUDGET >> 20} MiB scoped limit (FLASH_VMEM_BUDGET_MB)")
+            f"{VMEM_LIMIT_BYTES >> 20} MiB scoped limit")
 
 
 def _kv_tile_bytes(k, rows: int, width: int) -> int:

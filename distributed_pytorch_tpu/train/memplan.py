@@ -444,9 +444,9 @@ def predicted_train_peak_gb(model_cfg: LLMConfig, train_cfg: TrainConfig,
 def watermark_report(predicted_gb: Optional[float]) -> list[dict]:
     """Per-device `{device, memplan_predicted_gb, measured_peak_gb,
     delta}` rows from the live watermark (metrics.hbm_watermark's
-    `peak_bytes`: in use + reserved) — the record stats.json / bench JSON
-    / the mfu_sweep carry so a chip run validates the planner constants
-    without re-running anything.
+    `peak_bytes`: in use + reserved) — the record stats.json carries so
+    a chip run validates the planner constants without re-running
+    anything.
     Keys are always present; values are None where the backend reports
     no memory stats (CPU) so the schema is stable across backends."""
     from distributed_pytorch_tpu.train.metrics import hbm_watermark

@@ -19,11 +19,11 @@ Modes: `--mode kill` (default) SIGKILLs the victim mid-drive;
 slots retire, then replace) and additionally asserts ZERO shed — a
 drain must be lossless. `--mode none` is the fault-free control.
 
-Used three ways: standalone (`python scripts/fault_inject.py`), as the
-2-replica kill-and-replace leg in scripts/serve_smoke.sh, and by the
-bench.py `serve_load_router` leg (`--json` prints one machine-readable
-line). This is a CORRECTNESS harness and it runs on the CPU backend: the
-offline greedy reference runs in this process and every replica is a
+Used two ways: standalone (`python scripts/fault_inject.py`; `--json`
+prints one machine-readable line) and as the 2-replica kill-and-replace
+leg in scripts/serve_smoke.sh. This is a CORRECTNESS harness and it runs
+on the CPU backend: the offline greedy reference runs in this process and
+every replica is a
 child process, so both pin `JAX_PLATFORMS=cpu` — a chip belongs to one
 process at a time, and a parent that held it would starve its own
 replicas. One process driving N one-chip replicas is ROADMAP R6.
@@ -70,8 +70,7 @@ def build_args(argv=None):
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--timeout-s", type=float, default=420.0)
     p.add_argument("--json", action="store_true",
-                   help="print one JSON line (for bench.py) instead of "
-                        "the human log")
+                   help="print one JSON line instead of the human log")
     p.add_argument("--log-dir", type=str, default="",
                    help="keep replica logs here (default: a tempdir)")
     return p.parse_args(argv)

@@ -21,7 +21,7 @@ mid-run, and assert the ROADMAP's pod-scale exit criteria:
   reorders reductions (tests/test_multihost.py pins that to ~rtol 2e-4).
 
 `--mode none` is the fault-free control. `--json` prints one
-machine-readable line (bench/CI); artifacts (supervisor timeline,
+machine-readable line (CI); artifacts (supervisor timeline,
 worker logs, stats.json) stay under --log-dir.
 """
 
@@ -55,8 +55,8 @@ def build_args(argv=None):
     p.add_argument("--remesh-deadline-s", type=float, default=2.0)
     p.add_argument("--timeout-s", type=float, default=600.0)
     p.add_argument("--json", action="store_true",
-                   help="print one JSON line (for bench/CI) instead of "
-                        "the human log")
+                   help="print one JSON line (for CI) instead of the "
+                        "human log")
     p.add_argument("--log-dir", type=str, default="",
                    help="working dir for checkpoints/runs/logs "
                         "(default: runs/fault_inject_train_<ts>)")

@@ -1,6 +1,6 @@
 """Capture + analyze a TPU profile of the flagship train step (VERDICT #1a).
 
-Runs a few steps of the bench config under jax.profiler, then reduces the
+Runs a few steps of the flagship config under jax.profiler, then reduces the
 capture with the benchmark's own code (benchmark/lib/trace_reduce.py and
 trace_spans.py) and prints busy/idle, the op-level time breakdown, and the
 breakdown by the program's named scopes and host phases — no TensorBoard

@@ -104,12 +104,12 @@ def test_any_skew_changes_the_key(tmp_path):
 
 
 def test_knob_skew_changes_the_key(tmp_path, monkeypatch):
-    """PROGRAM_KNOBS are key material: flipping one (here a flash block
+    """PROGRAM_KNOBS are key material: flipping one (here a fused-CE tile
     size that changes the compiled kernel) re-keys every program."""
     s = AOTStore(str(tmp_path))
     _, avals = _trivial()
     base = s.key("step", avals, {"kind": "engine"})
-    monkeypatch.setenv("FLASH_BLOCK_Q", "128")  # default is 1024
+    monkeypatch.setenv("CE_BLOCK_N", "128")  # default is 512
     assert s.key("step", avals, {"kind": "engine"}) != base
 
 
@@ -216,6 +216,7 @@ def test_warmed_engine_bit_identical_zero_traces(tiny_model, warm_root):
     # ...and NOTHING was traced/JIT-compiled in the warmed process
     assert warm.step_traces == 0
     assert warm.fused_step_traces == 0
+    assert warm.spec_step_traces == 0 and warm.promote_traces == 0
     assert sum(warm.admit_traces.values()) == 0
 
 

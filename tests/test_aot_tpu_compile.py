@@ -59,8 +59,8 @@ def v5e():
     cc.reset_cache()
 
 
-def _flash(B, T, grad):
-    shapes = [((B, T, NH, HS), BF16)] * 3
+def _flash(B, T, grad, nh=NH, hs=HS):
+    shapes = [((B, T, nh, hs), BF16)] * 3
 
     def fwd(q, k, v):
         return fa.flash_attention(q, k, v, scale=SCALE)
@@ -152,6 +152,10 @@ CASES = {
     "flash_fwd_1x8192": (lambda: _flash(1, 8192, False), ["flash_fwd"]),
     "flash_bwd_1x8192": (lambda: _flash(1, 8192, True),
                          ["flash_bwd_dq", "flash_bwd_dkv"]),
+    # a full-lane head: 8 heads of 128 (twice the tile bytes of 64-wide)
+    "flash_bwd_2x1024_8x128": (lambda: _flash(2, 1024, True, 8, 128),
+                               ["flash_fwd", "flash_bwd_dq",
+                                "flash_bwd_dkv"]),
     "ce_fwd": (lambda: _ce(False), ["ce_fwd"]),
     "ce_bwd": (lambda: _ce(True), ["ce_fwd", "ce_bwd_dx", "ce_bwd_dw"]),
     "flash_decode_bf16": (lambda: _decode(8, False), ["flash_decode"]),
@@ -321,25 +325,8 @@ def test_gates_decline_what_the_compiler_refuses(v5e):
     must already say no — so the dispatcher never sends that shape, and
     `attn_impl='pallas'` there is an error naming the gate, not a compile
     failure half a minute into a run."""
-    # (1) slab layout at the flagship's 64-wide heads: the in-VMEM head
-    # split has no lowering below a full 128-lane head
-    B, T = 2, 1024
-    slab = fa._slab_lse_for(NH, NH, HS)
-    assert not fa.slab_attention_usable(B, T, T, NH, NH, HS, BF16)
-    with pytest.raises(Exception, match="shape cast|Mosaic"):
-        _compile(lambda q, k, v, s: slab(q, k, v, s, SCALE, 256, 512, False,
-                                         True, 0.0),
-                 [((B, T, NH * HS), BF16)] * 3 + [((2,), I32)], v5e)
-    # and compiles, at tiles whose all-heads step fits the VMEM budget
-    assert fa.slab_attention_usable(B, T, T, 8, 8, 128, BF16, 256, 512)
-    census = paths.kernel_census(_compile(
-        lambda q, k, v: fa.flash_attention(q, k, v, scale=SCALE,
-                                           layout="slab", block_q=256,
-                                           block_k=512),
-        [((B, T, 8, 128), BF16)] * 3, v5e).as_text())
-    assert census.get("flash_slab_fwd"), census
-    # (2) rows layout at a head dim whose single tile step busts the
-    # scoped-VMEM limit the kernel hands Mosaic
+    # a head dim whose single tile step busts the scoped-VMEM limit the
+    # kernel hands Mosaic
     wide = [((1, 1024, 1, 8192), BF16)] * 3
     q = jax.ShapeDtypeStruct(*wide[0])
     assert not fa.flash_attention_usable(q, q, q)

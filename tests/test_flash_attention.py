@@ -46,14 +46,16 @@ def test_forward_matches_naive(T, S, nh, nkv, hs, block):
                                rtol=2e-5, atol=2e-5)
 
 
-def test_backward_matches_naive():
-    T, nh, nkv, hs = 128, 4, 2, 64
+@pytest.mark.parametrize("nh,nkv,hs", [(4, 4, 32), (4, 2, 64), (8, 1, 16)],
+                         ids=["mha_32", "gqa2_64", "mqa_16"])
+def test_backward_matches_naive(nh, nkv, hs):
+    T = 128
     q, k, v = rand_qkv(jax.random.PRNGKey(1), 2, T, T, nh, nkv, hs)
     scale = 1.0 / hs ** 0.5
     w = jax.random.normal(jax.random.PRNGKey(2), q.shape)
 
     def loss_flash(q, k, v):
-        out = flash_attention(q, k, v, scale=scale, block_q=64, block_k=64,
+        out = flash_attention(q, k, v, scale=scale, block_q=64, block_k=32,
                               interpret=True)
         return jnp.sum(out * w)
 
@@ -201,7 +203,7 @@ def test_flash_lse_gradients_including_dlse(causal):
 def test_rectangular_blocks_fwd_bwd(bq, bk):
     """block_q != block_k exercises the causal-frontier math on
     rectangular tiles (_last_visible_kv/_first_visible_q and the
-    DMA-clamp index maps) — the production default is 256x512."""
+    DMA-clamp index maps)."""
     T, nh, nkv, hs = 128, 4, 2, 32
     q, k, v = rand_qkv(jax.random.PRNGKey(5), 2, T, T, nh, nkv, hs)
     scale = 1.0 / hs ** 0.5
@@ -263,6 +265,34 @@ def test_row_group_defaults_to_one_for_gqa():
     with pytest.raises(AssertionError):
         flash_attention(q, k, v, scale=0.18, block_q=32, block_k=32,
                         block_h=4, interpret=True)
+
+
+# (T, nh, nkv, hs) -> rows a grid step at B = 16, bf16: one at the full
+# 1024 x 1024 tile, as many more as the sequence's one tile is smaller
+# (PERF.md section 6, PR 29), one under GQA, fewer where the VMEM limit binds
+GROUP_CASES = {
+    "T1024": ((1024, 12, 12, 64), 1),
+    "T512": ((512, 12, 12, 64), 4),
+    "T256": ((256, 12, 12, 64), 16),
+    "T256_gqa": ((256, 12, 4, 64), 1),
+    "T256_head512_vmem_binds": ((256, 12, 12, 512), 12),
+}
+
+
+@pytest.mark.parametrize("case", list(GROUP_CASES))
+def test_default_row_group_scales_with_the_tile(case):
+    import warnings
+
+    from distributed_pytorch_tpu.ops import flash_attention as fa
+    (T, nh, nkv, hs), want = GROUP_CASES[case]
+    bq, bk = fa._pick_block(T, fa.BLOCK_Q), fa._pick_block(T, fa.BLOCK_K)
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)  # the shrink notice
+        g = fa._pick_group(16 * nh, nh // nkv, bq, bk, hs, 2)
+    assert g == want
+    assert fa._vmem_bytes(g, bq, bk, hs, 2) <= fa.VMEM_LIMIT_BYTES
+    if case.endswith("vmem_binds"):
+        assert fa._vmem_bytes(16, bq, bk, hs, 2) > fa.VMEM_LIMIT_BYTES
 
 
 class TestDropout:
@@ -369,122 +399,21 @@ class TestDropout:
                                    atol=2e-5)
 
 
-class TestSlabLayout:
-    """'slab' kernel layout (round 5): reads (B, T, N*H) slabs directly —
-    no HBM transposes — with in-VMEM head-major relayout, in-kernel GQA
-    expansion, and write-step dk/dv group-sum. Must be numerically
-    identical in semantics to the rows layout and the naive oracle.
-    Head-slab widths are chosen lane-aligned ((n*hs) % 128 == 0)."""
-
-    CASES = [(4, 4, 32), (4, 2, 64), (8, 1, 16)]  # (nh, nkv, hs)
-
-    @pytest.mark.parametrize("nh,nkv,hs", CASES)
-    def test_forward_matches_naive(self, nh, nkv, hs):
-        q, k, v = rand_qkv(jax.random.PRNGKey(0), 2, 128, 128, nh, nkv, hs)
-        scale = 1.0 / hs ** 0.5
-        out = flash_attention(q, k, v, scale=scale, block_q=64, block_k=32,
-                              layout="slab", interpret=True)
-        ref = _naive_sdpa(q, k, v, scale=scale, q_offset=0, causal=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)
-
-    @pytest.mark.parametrize("nh,nkv,hs", CASES)
-    def test_grads_match_naive(self, nh, nkv, hs):
-        q, k, v = rand_qkv(jax.random.PRNGKey(1), 2, 128, 128, nh, nkv, hs)
-        scale = 1.0 / hs ** 0.5
-        w = jax.random.normal(jax.random.PRNGKey(2), q.shape)
-
-        def f(q, k, v):
-            return jnp.sum(flash_attention(
-                q, k, v, scale=scale, block_q=64, block_k=32,
-                layout="slab", interpret=True) * w)
-
-        def n(q, k, v):
-            return jnp.sum(_naive_sdpa(q, k, v, scale=scale, q_offset=0,
-                                       causal=True) * w)
-
-        gf = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
-        gn = jax.grad(n, argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(gf, gn):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=2e-4, atol=2e-4)
-
-    def test_prefill_longer_cache(self):
-        """S > T (prefill into a longer zero-padded cache): positional
-        causal mask must hide the tail, as in the rows layout."""
-        q, k, v = rand_qkv(jax.random.PRNGKey(3), 2, 64, 256, 4, 4, 32)
-        out = flash_attention(q, k, v, scale=0.18, block_q=32, block_k=32,
-                              layout="slab", interpret=True)
-        ref = _naive_sdpa(q, k, v, scale=0.18, q_offset=0, causal=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)
-
-    def test_noncausal(self):
-        q, k, v = rand_qkv(jax.random.PRNGKey(4), 2, 64, 64, 4, 2, 32)
-        out = flash_attention(q, k, v, scale=0.18, block_q=32, block_k=32,
-                              layout="slab", causal=False, interpret=True)
-        ref = _naive_sdpa(q, k, v, scale=0.18, q_offset=0, causal=False)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)
-
-    def test_dropout_identical_masks_across_layouts(self):
-        """The dropout hash is keyed on absolute positions, so rows and
-        slab layouts must produce bit-identical dropped outputs."""
-        q, k, v = rand_qkv(jax.random.PRNGKey(5), 2, 64, 64, 4, 4, 32)
-        rng = jax.random.PRNGKey(9)
-        a = flash_attention(q, k, v, scale=0.18, block_q=32, block_k=32,
-                            layout="rows", dropout_rate=0.3,
-                            dropout_rng=rng, interpret=True)
-        b = flash_attention(q, k, v, scale=0.18, block_q=16, block_k=64,
-                            layout="slab", dropout_rate=0.3,
-                            dropout_rng=rng, interpret=True)
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-5, atol=2e-5)
-
-    def test_lse_and_dlse_match_rows(self):
-        """The differentiable-lse contract (ring merge) must hold for the
-        slab path too: same lse values, same d/dlse folding."""
-        from distributed_pytorch_tpu.ops.flash_attention import (
-            flash_attention_lse)
-        q, k, v = rand_qkv(jax.random.PRNGKey(6), 2, 64, 64, 4, 4, 32)
-        wl = jax.random.normal(jax.random.PRNGKey(7), (2, 64, 4))
-        wo = jax.random.normal(jax.random.PRNGKey(8), q.shape)
-
-        def loss(layout):
-            def f(q, k, v):
-                out, lse = flash_attention_lse(
-                    q, k, v, scale=0.18, block_q=32, block_k=32,
-                    layout=layout, interpret=True)
-                return jnp.sum(out * wo) + jnp.sum(lse * wl)
-            return f
-
-        (la, ga) = jax.value_and_grad(loss("rows"), argnums=(0, 1, 2))(
-            q, k, v)
-        (lb, gb) = jax.value_and_grad(loss("slab"), argnums=(0, 1, 2))(
-            q, k, v)
-        np.testing.assert_allclose(float(la), float(lb), rtol=1e-5)
-        for a, b in zip(ga, gb):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=2e-4, atol=2e-4)
-
-    def test_usable_gate_slab(self):
-        from distributed_pytorch_tpu.ops.flash_attention import (
-            slab_attention_usable)
-        # compiled: Mosaic refuses the in-VMEM head split below a full
-        # 128-lane head (tests/test_aot_tpu_compile.py holds the compile).
-        # A slab step holds ALL heads' tiles, so it is asked at 256 x 512:
-        # the rows layout's 1024 x 1024 default is over the VMEM budget here
-        assert slab_attention_usable(2, 1024, 1024, 8, 8, 128, jnp.bfloat16,
-                                     256, 512)
-        assert not slab_attention_usable(2, 1024, 1024, 8, 8, 128,
-                                         jnp.bfloat16)
-        assert not slab_attention_usable(2, 1024, 1024, 12, 12, 64,
-                                         jnp.bfloat16, 256, 512)
-        # interpret mode (these CPU tests) keeps the 64-wide heads
-        assert slab_attention_usable(2, 1024, 1024, 12, 12, 64, jnp.bfloat16,
-                                     256, 512, interpret=True)
-        assert not slab_attention_usable(2, 1024, 1024, 3, 3, 24,
-                                         jnp.bfloat16)  # 72 lanes
+@pytest.mark.parametrize("bq,bk,bh", [(32, 32, 1), (16, 64, 4), (64, 16, 8)])
+def test_dropout_same_mask_whatever_the_tiling(bq, bk, bh):
+    """The dropout bits are keyed on the absolute (row, query, key)
+    position, so the q/kv tile sizes and the row group must not move the
+    mask: every tiling drops the weights the one-tile call drops. (A
+    different mask moves outputs by O(1); the tolerance is the online
+    softmax's rounding between tilings.)"""
+    q, k, v = rand_qkv(jax.random.PRNGKey(5), 2, 64, 64, 4, 4, 32)
+    f = functools.partial(flash_attention, q, k, v, scale=0.18,
+                          dropout_rate=0.3, dropout_rng=jax.random.PRNGKey(9),
+                          interpret=True)
+    one_tile = f(block_q=64, block_k=64, block_h=1)
+    np.testing.assert_allclose(
+        np.asarray(f(block_q=bq, block_k=bk, block_h=bh)),
+        np.asarray(one_tile), rtol=2e-5, atol=2e-5)
 
 
 def test_pallas_dp_mesh_shard_map_wrap(monkeypatch):

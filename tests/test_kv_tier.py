@@ -124,6 +124,10 @@ def test_promote_hit_parity_vs_never_evicted(kw, cache_dtype):
     assert base.host_tier is None and base.promote_traces == 0
     for (p, _), out, ref in zip(SCHEDULE, outs, refs):
         assert out == ref, f"promote path diverged for prompt {p[:4]}..."
+    # the tier holds the warm pool's prefix hit rate on a pool that evicts,
+    # and a hit costs a host->HBM copy, never a re-prefill
+    assert eng.prefix_hit_tokens == base.prefix_hit_tokens > 0
+    assert eng.prefilled_tokens == base.prefilled_tokens
 
 
 def test_promote_hit_matches_offline_generate():

@@ -44,23 +44,23 @@ _spec.loader.exec_module(lint)
 # ---------------------------------------------------------------------------
 
 def test_knob_defaults_read_without_env():
-    assert config.knob("FLASH_BLOCK_Q") == 1024
+    assert config.knob("CE_BLOCK_N") == 512
     assert config.knob("TRACE_GUARD") == "warn"
     assert config.knob("FLASH_DECODE") == "auto"
 
 
 def test_knob_env_override_is_live(monkeypatch):
     """Knob.read consults os.environ per call, so monkeypatch.setenv works
-    mid-process — the property mfu_sweep and the tests depend on."""
-    monkeypatch.setenv("FLASH_BLOCK_Q", "128")
-    assert config.knob("FLASH_BLOCK_Q") == 128
-    monkeypatch.delenv("FLASH_BLOCK_Q")
-    assert config.knob("FLASH_BLOCK_Q") == 1024
+    mid-process — the property the tests depend on."""
+    monkeypatch.setenv("CE_BLOCK_N", "128")
+    assert config.knob("CE_BLOCK_N") == 128
+    monkeypatch.delenv("CE_BLOCK_N")
+    assert config.knob("CE_BLOCK_N") == 512
 
 
 def test_knob_unregistered_name_fails_loudly():
     with pytest.raises(KeyError):
-        config.knob("FLASH_BLOK_Q")  # typo'd name must not silently default
+        config.knob("CE_BLOK_N")  # typo'd name must not silently default
 
 
 def test_knob_onoff_validation(monkeypatch):
@@ -72,12 +72,12 @@ def test_knob_onoff_validation(monkeypatch):
 
 
 def test_knobs_table_marks_overrides(monkeypatch):
-    monkeypatch.setenv("FLASH_BLOCK_K", "1024")
+    monkeypatch.setenv("CE_BLOCK_V", "1024")
     table = config.knobs_table()
     lines = {ln.split()[0]: ln for ln in table.splitlines()[1:]}
     assert set(lines) == set(config.ENV_KNOBS)
-    assert "1024*" in lines["FLASH_BLOCK_K"]      # override marker
-    assert "*" not in lines["FLASH_BLOCK_Q"].split()[2]
+    assert "1024*" in lines["CE_BLOCK_V"]         # override marker
+    assert "*" not in lines["CE_BLOCK_N"].split()[2]
 
 
 def test_register_knob_round_trip(monkeypatch):

@@ -102,6 +102,32 @@ thousands of requests share a system prompt:
   exports a compact radix-prefix digest (`kv_digest`) that
   serve/router.py uses for cache-aware sticky dispatch across replicas.
 
+* **One step program in flight** (PR 31): `step()` plans and dispatches
+  program k+1 BEFORE it drains program k, so the device always has its
+  next program queued while the host fetches tokens, retires, and the
+  scheduler emits, yields to its clients and admits. What makes that
+  possible: almost everything the host plans with is a COUNT — positions,
+  generated tokens against the budget, prefill progress, the blocks a
+  write needs, the chunk's slot/offset/length, `budget` and `cache_full`
+  retirement — and `tok`/`pos`/`live`/`caches` already flow from program
+  to program as device arrays. Only four things need the tokens' VALUES,
+  and they wait for the drain: the stream append, the `eos` test,
+  publishing generated rows' blocks, and the `Retired` record. So `_Slot`
+  is split into planned counts (advanced at dispatch) and observed tokens
+  (appended at the drain). Retirement by count is planned: the slot is
+  out of k+1's mask. Retirement by value (`eos`) lags one program: the
+  slot runs one token too far, the token is dropped, its row lies in the
+  sequence's own block and is released with it (`overrun_tokens`). A
+  result belongs to the occupant that was planned, not to the slot
+  (`_Program.occupants`), so `cancel()` and `admit()` between calls stay
+  legal while a program runs. The lookahead declines itself where the
+  plan needs values — a speculative engine (drafts read the tokens), a
+  dry pool (a preemption hands out the victim's tokens), a host-tier
+  promotion, wave mode — and the turn is then the same code with nothing
+  queued ahead, recorded as the dispatch's `drain_reason`;
+  `overlap_share` is the fraction of programs that had a predecessor
+  running. The rng fold `t` is the program's number either way.
+
 Host/device split as before: sampling, cache writes, and positions are
 device-side; the allocator, radix index, and retirement logic are plain
 Python on the host thread that owns the engine.
@@ -433,17 +459,26 @@ class StepResult:
 
 @dataclasses.dataclass
 class _Slot:
-    """Host-side bookkeeping for one occupied table row."""
+    """Host-side bookkeeping for one occupied table row. Split by what
+    the host knows when: `n_new`, `pos`, `suffix_done` and `done` are
+    PLANNED, advanced when a program is dispatched (they are counts, so
+    the next program can be planned while this one runs); `tokens` is
+    OBSERVED, appended when the program's sampled tokens drain. Between
+    the two the planned counts run one program ahead of `tokens`."""
 
     seq_id: int
-    tokens: list          # prompt + generated so far
+    tokens: list          # prompt + generated tokens drained so far
     prompt_len: int
-    n_new: int            # generated tokens recorded so far
+    n_new: int            # generated tokens planned so far
     max_new: int
     pos: int              # device pos mirror: next cache write position
-                          # (for a partial slot: prefill rows written)
+                          # (for a partial slot: prefill rows planned)
     blocks: list          # owned physical block ids, logical order
     order: int            # admission counter (preemption picks the max)
+    # retirement by count ('budget' | 'cache_full'), planned with the
+    # slot's last program: out of every later program's live mask, in its
+    # slot until that program drains and hands out the `Retired` record
+    done: Optional[str] = None
     # chunked-prefill progress (prefill_chunk > 0): the suffix left to
     # compute after the prefix-cache hit, and how much of it has been
     # chunked into the cache so far. suffix_done < len(suffix) marks the
@@ -451,6 +486,40 @@ class _Slot:
     suffix: Optional[list] = None
     suffix_done: int = 0
     prefix_len: int = 0
+
+
+@dataclasses.dataclass
+class _Program:
+    """One dispatched step program whose sampled tokens have not drained
+    yet: what the host planned for it by count, and the device array its
+    tokens arrive in. A result belongs to the OCCUPANT that was planned,
+    not to the slot: `occupants` carries slot -> seq_id, and a token for
+    an occupant that left meanwhile (eos one program late, a cancel) is
+    dropped at the drain, never credited to the slot's next one."""
+
+    t: int                  # the program's number (its rng fold, n_steps)
+    kind: str               # 'decode' | 'fused' | 'spec'
+    occupants: dict         # slot -> seq_id receiving a token, stream order
+    retiring: dict          # slot -> 'budget' | 'cache_full' with this one
+    preempted: dict         # seq_id -> Retired, yielded before it ran
+    n_live: int             # decoding slots in its mask
+    # the fused chunk: (slot, seq_id, take, prefill rows after it)
+    chunk: Optional[tuple] = None
+    # a speculative step: (draft, draft_len, device accept lengths)
+    spec: Optional[tuple] = None
+    overlapped: bool = False        # dispatched behind a running program
+    drain_reason: Optional[str] = None   # where it was not: why
+    # what it runs with, from the plan to the dispatch: (block tables,
+    # a rebuilt live mask or None for the last program's own, the chunk's
+    # traced arguments or ())
+    inputs: Optional[tuple] = None
+    tok: Any = None         # device (n_slots,) sampled tokens, once queued
+
+
+class _WouldPreempt(Exception):
+    """Planning the next program behind a running one met a dry pool: a
+    preemption hands out the victim's tokens, which the running program
+    is still producing — the lookahead declines and the turn drains."""
 
 
 class DecodeEngine:
@@ -659,8 +728,21 @@ class DecodeEngine:
 
         self._slots: dict[int, _Slot] = {}     # slot index -> bookkeeping
         self._next_id = 0
-        self._t = 0                            # global step counter (rng)
+        self._t = 0                            # programs dispatched (rng)
         self._n_admits = 0
+        # one step program in flight (class docstring): dispatched by the
+        # last step() call behind the one it drained, drained by the next
+        self._inflight: Optional[_Program] = None
+        # why the last call queued nothing behind the program it drained:
+        # the `drain_reason` of the next dispatch
+        self._declined: Optional[str] = None
+        # the planned live set moved where no program's own output
+        # follows (a retirement, a cancel): the mask is rebuilt from the
+        # host's plan before the next dispatch
+        self._live_dirty = False
+        # a host-tier promotion rewrote the pools outside the step
+        # programs since the last dispatch
+        self._pools_rewritten = False
         # donation keeps the big pool in place on TPU; CPU jit warns on
         # unusable donations, so skip it there
         self._donate = (1,) if jax.default_backend() == "tpu" else ()
@@ -710,6 +792,12 @@ class DecodeEngine:
         self.spec_drafted_tokens = 0  # drafter proposals sent to verify
         self.spec_accepted_tokens = 0  # of those, accepted by the target
         self.emitted_tokens = 0       # tokens emitted across all steps
+        # lookahead accounting (`overlap_share`, /metrics, flight record)
+        self.overlapped_programs = 0  # dispatched behind a running one
+        self.drain_reasons: dict[str, int] = {}  # the others, by why not
+        # tokens computed for an occupant that had left by the drain: an
+        # `eos` seen one program late, a cancel while its program ran
+        self.overrun_tokens = 0
         # step-level flight recorder (obs/flight.py): one record per
         # fused step in a bounded ring — the /debug/timeline payload and
         # the runs/*.jsonl post-hoc artifact
@@ -980,24 +1068,34 @@ class DecodeEngine:
         return self.emitted_tokens / self._t if self._t else 0.0
 
     @property
+    def overlap_share(self) -> float:
+        """Lifetime fraction of step programs dispatched behind a running
+        one — the device had its next program queued before it finished
+        the last. The rest are counted by cause in `drain_reasons`."""
+        return self.overlapped_programs / self._t if self._t else 0.0
+
+    @property
     def free_slots(self) -> list[int]:
         return [s for s in range(self.n_slots) if s not in self._slots]
 
     @staticmethod
     def _is_partial(seq: _Slot) -> bool:
         """A chunked admission whose prompt is not fully in the cache yet
-        — parked out of the decode batch until its last chunk runs."""
+        (by plan) — parked out of the decode batch until its last chunk
+        runs."""
         return seq.suffix is not None and seq.suffix_done < len(seq.suffix)
 
     def _live_slots(self) -> list[int]:
-        """Slots decoding this step (occupied and not mid-prefill)."""
+        """Slots decoding in the next program to be planned: occupied,
+        not mid-prefill, not retired by count."""
         return [s for s, seq in self._slots.items()
-                if not self._is_partial(seq)]
+                if seq.done is None and not self._is_partial(seq)]
 
-    def _rebuild_live(self) -> None:
+    def _live_mask(self):
+        """The planned live set as a device mask."""
         mask = np.zeros((self.n_slots,), bool)
         mask[self._live_slots()] = True
-        self.live = jnp.asarray(mask)
+        return jnp.asarray(mask)
 
     @property
     def n_live(self) -> int:
@@ -1039,8 +1137,10 @@ class DecodeEngine:
 
     @property
     def n_steps(self) -> int:
-        """Fused decode steps executed so far (serving tests bound slot
-        release latency in steps, not wall-clock)."""
+        """Step programs dispatched so far (serving tests bound slot
+        release latency in steps, not wall-clock). Every one ran for a
+        slot that was live by plan; rows an `eos` or a cancel made
+        useless after the fact are counted in `overrun_tokens`."""
         return self._t
 
     @property
@@ -1050,9 +1150,17 @@ class DecodeEngine:
     def set_budget(self, seq_id: int, max_new_tokens: int) -> None:
         """Re-budget a live sequence (bench ragged windows re-arm the warm
         slots this way instead of poking `_slots`)."""
-        for seq in self._slots.values():
+        for slot, seq in self._slots.items():
             if seq.seq_id == seq_id:
                 seq.max_new = max_new_tokens
+                fl = self._inflight
+                if fl is not None and fl.occupants.get(slot) == seq_id:
+                    # the running program's token is already planned:
+                    # whether it is the last follows the new budget
+                    fl.retiring.pop(slot, None)
+                    seq.done = None
+                    self._plan_retirement(slot, seq, fl.retiring)
+                    self._live_dirty = True
                 return
         raise KeyError(f"seq {seq_id} is not live")
 
@@ -1118,27 +1226,36 @@ class DecodeEngine:
     def promote_traces(self) -> int:
         return self.trace_guards["promote"].count
 
-    def _retire_reason(self, slot: int, last_tok: int) -> Optional[str]:
-        seq = self._slots[slot]
-        if self.eos_id is not None and last_tok == self.eos_id:
-            return "eos"
+    def _plan_retirement(self, slot: int, seq: _Slot,
+                         retiring: dict) -> None:
+        """Retirement by COUNT, decided when the slot's token is planned:
+        the budget or the table ends with this program, so the slot is
+        out of every later program's mask (`done`) and the program that
+        produces the token carries the reason to its drain (`retiring`).
+        Retirement by VALUE (`eos`) is seen at the drain and wins."""
         if seq.n_new >= seq.max_new:
-            return "budget"
-        if seq.pos >= self.max_len:  # table capacity: no next row exists
-            return "cache_full"
-        return None
+            seq.done = "budget"
+        elif seq.pos >= self.max_len:  # table capacity: no next row exists
+            seq.done = "cache_full"
+        if seq.done is not None:
+            retiring[slot] = seq.done
+            self._live_dirty = True
 
     def _retire(self, slot: int, reason: str) -> Retired:
         seq = self._slots.pop(slot)
         self.retire_counts[reason] += 1
+        self._live_dirty = True
         # publish the sequence's full blocks into the prefix cache before
         # releasing: refcount-0 registered blocks land on the LRU, so a
         # follow-up (or a preemption resume) re-admits with a prefix hit
         # — and with the host tier on, a later eviction demotes instead
         # of dropping, so even a preempted-under-pressure prefix resumes
-        # from cache
-        full = min(seq.pos, len(seq.blocks) * self.block_size) \
-            // self.block_size
+        # from cache. Only rows whose tokens have drained are published:
+        # the row a program still in flight writes for this sequence
+        # (`pos` runs one ahead of `tokens` then) holds a token the host
+        # has not seen, and lies past the last full block counted here.
+        full = min(seq.pos, len(seq.tokens) - 1,
+                   len(seq.blocks) * self.block_size) // self.block_size
         self._register_blocks(seq.tokens, full, seq.blocks)
         self.block_pool.release_all(seq.blocks)
         self._tables_h[slot, :] = 0
@@ -1153,9 +1270,11 @@ class DecodeEngine:
         won the race)."""
         for slot, seq in self._slots.items():
             if seq.seq_id == seq_id:
-                ret = self._retire(slot, "cancelled")
-                self.live = self.live.at[slot].set(False)
-                return ret
+                # legal while a program runs for this occupant: its token
+                # is dropped at the drain (`_Program.occupants`), and its
+                # row write into the released block precedes, in device
+                # order, every write of the block's next owner
+                return self._retire(slot, "cancelled")
         return None
 
     def _demote_block(self, blk: int, key: tuple) -> None:
@@ -1175,6 +1294,7 @@ class DecodeEngine:
         time, before the slot's first prefill/step — the promote cost
         lands in queue-wait, and the step families never trace anything
         new for it."""
+        self._pools_rewritten = True
         rows_dev = jax.device_put([rows for _, rows in staged])
         fn = self._get_promote_fn()
         with self._ctx():
@@ -1303,10 +1423,11 @@ class DecodeEngine:
             self._register_blocks(toks, L // bs, blocks)
             # a 1-token request (or instant EOS) finishes at admission
             retired = None
-            reason = self._retire_reason(slot, first_tok)
+            seq = self._slots[slot]
+            self._plan_retirement(slot, seq, {})
+            reason = "eos" if first_tok == self.eos_id else seq.done
             if reason is not None:
                 retired = self._retire(slot, reason)
-                self.live = self.live.at[slot].set(False)
             return Admission(seq_id=seq_id, first_token=first_tok,
                              retired=retired, prefix_len=prefix_len,
                              prefilled=len(suffix))
@@ -1354,7 +1475,8 @@ class DecodeEngine:
         return Admission(seq_id=seq_id, first_token=None,
                          prefix_len=prefix_len, prefilled=len(suffix))
 
-    def _next_chunk(self, preempted: dict) -> Optional[tuple[int, int]]:
+    def _next_chunk(self, preempted: dict,
+                    ahead: bool) -> Optional[tuple[int, int]]:
         """Pick this step's prefill work: the OLDEST partial prompt gets
         the leftover token budget (decode tokens have strict priority),
         rounded down to whole blocks and floored at one block so a
@@ -1362,7 +1484,10 @@ class DecodeEngine:
         slot's block list to cover the chunk, preempting youngest-first
         when the pool is dry (the partial itself is usually youngest —
         then the next-oldest partial gets its turn). Returns
-        (slot, take) or None; preemption victims land in `preempted`."""
+        (slot, take) or None; preemption victims land in `preempted`.
+        Planning `ahead` of a running program never preempts: it raises
+        `_WouldPreempt` (the blocks grown so far stay; the drained turn
+        that follows needs them too)."""
         bs = self.block_size
         while True:
             partials = [(seq.order, slot) for slot, seq in
@@ -1381,11 +1506,12 @@ class DecodeEngine:
             while len(seq.blocks) < need:
                 blk = self.block_pool.alloc()
                 if blk is None:
+                    if ahead:
+                        raise _WouldPreempt
                     victim = self._pick_victim()
                     vseq = self._slots[victim]
                     preempted[vseq.seq_id] = self._retire(victim,
                                                           "preempted")
-                    self._rebuild_live()
                     if victim == slot:
                         ok = False
                         break
@@ -1402,17 +1528,19 @@ class DecodeEngine:
         decode work and the best chance of a prefix hit on resume."""
         return max(self._slots, key=lambda s: self._slots[s].order)
 
-    def _ensure_blocks(self) -> dict:
+    def _ensure_blocks(self, preempted: dict, ahead: bool) -> None:
         """Grow every live sequence's block list to cover its next write;
         when the pool is dry (all blocks referenced), preempt
-        youngest-first until the allocation succeeds. Returns
-        {seq_id: Retired(reason='preempted')} for the victims."""
-        preempted: dict[int, Retired] = {}
+        youngest-first until the allocation succeeds — or, planning
+        `ahead` of a running program, raise `_WouldPreempt`. The victims
+        land in `preempted` as {seq_id: Retired(reason='preempted')}."""
         for slot in sorted(self._slots):
             seq = self._slots.get(slot)
             # partial slots don't decode-write; their growth is per-chunk
-            # (_next_chunk) so idle prefill rows never hold blocks
-            if seq is not None and self._is_partial(seq):
+            # (_next_chunk) so idle prefill rows never hold blocks. A slot
+            # retired by count has no next write.
+            if seq is not None and (seq.done is not None
+                                    or self._is_partial(seq)):
                 continue
             while seq is not None and \
                     seq.pos >= len(seq.blocks) * self.block_size:
@@ -1422,14 +1550,13 @@ class DecodeEngine:
                     seq.blocks.append(blk)
                     self._tables_dirty = True
                     continue
+                if ahead:
+                    raise _WouldPreempt
                 victim = self._pick_victim()
                 vseq = self._slots[victim]
                 preempted[vseq.seq_id] = self._retire(victim, "preempted")
                 if victim == slot:
                     seq = None       # preempted itself; stop growing it
-        if preempted:
-            self._rebuild_live()
-        return preempted
 
     def _spec_drafts(self) -> Optional[tuple]:
         """Host-side drafting for one speculative step: an (n_slots, K)
@@ -1474,122 +1601,174 @@ class DecodeEngine:
             return None                  # nothing to verify: plain step
         return draft, dlen
 
-    def step(self) -> StepResult:
-        """Advance every live slot one token — or, on a speculative step
-        (`spec_decode` on, drafts available), up to `spec_k`+1 tokens —
-        fusing in one prefill chunk of the oldest partial prompt when
-        `prefill_chunk` is set. Returns a `StepResult`:
-        {seq_id: [tokens]} emitted this step in stream order (including
-        the first token of a prompt whose LAST chunk ran), plus
-        {seq_id: Retired} for the sequences that finished (with WHY —
-        eos | budget | cache_full | preempted; preempted ones yielded
-        their blocks BEFORE the step and emit no token — requeue
-        them)."""
-        if not self._slots:
-            return StepResult({}, {})
-        # the step's four host phases (obs/trace.py PHASES): leaves in the
-        # profiler's trace, joined by `step`, and the split of step_ms in
-        # the flight record, from the same stamps
-        step = self._t
-        acc: dict = {}
-        t_step0 = time.perf_counter()
-        with phase("engine.prepare", acc, step=step):
-            preempted = self._ensure_blocks()
-            chunk = self._next_chunk(preempted) if self.prefill_chunk \
-                else None
-            if not self._slots or (chunk is None
-                                   and not self._live_slots()):
-                return StepResult({}, preempted)
-            n_live_in = len(self._live_slots())  # decoding slots this step
-            # speculative drafting happens BEFORE the table sync (it may
-            # grow block lists to cover accepted rows); a chunked step
-            # never speculates — the chunk already owns the step's spare
-            # compute
-            spec = None
-            if self.spec_decode and chunk is None:
-                spec = self._spec_drafts()
-            self._sync_tables()
-            chunk_done = False
-            prefill_tokens = 0
-            if chunk is not None:
-                slot_c, take = chunk
-                prefill_tokens = take
-                seq_c = self._slots[slot_c]
-                off = seq_c.prefix_len + seq_c.suffix_done
-                chunk_done = seq_c.suffix_done + take == len(seq_c.suffix)
-                buf = seq_c.suffix[seq_c.suffix_done:
-                                   seq_c.suffix_done + take]
-                padded = jnp.asarray(
-                    buf + [0] * (self.prefill_chunk - take),
-                    jnp.int32)[None]
-        kind = ("fused" if chunk is not None
-                else "spec" if spec is not None else "decode")
-        with phase("engine.dispatch", acc, step=step, kind=kind,
-                   n_live=n_live_in, prefill_tokens=prefill_tokens), \
-                self._ctx():
-            if chunk is not None:
-                out = self._get_fused_step_fn()(
-                    self.variables, self.caches, self.tok, self.pos,
-                    self.live, self.block_tables, self._rng,
-                    jnp.int32(self._t), self._qparams, padded,
-                    jnp.int32(slot_c), jnp.int32(off),
-                    jnp.asarray([take], jnp.int32), jnp.bool_(chunk_done))
-                self.caches, self.tok, self.pos, self.live = out
-            elif spec is not None:
-                draft_h, dlen_h = spec
-                out = self._get_spec_step_fn()(
-                    self.variables, self.caches, self.tok, self.pos,
-                    self.live, self.block_tables, self._rng,
-                    jnp.int32(self._t), self._qparams,
-                    jnp.asarray(draft_h), jnp.asarray(dlen_h))
-                self.caches, self.tok, self.pos, acc_dev = out
-            else:
-                self.caches, self.tok, self.pos = self._get_step_fn()(
-                    self.variables, self.caches, self.tok, self.pos,
-                    self.live, self.block_tables, self._rng,
-                    jnp.int32(self._t), self._qparams)
+    def _lookahead_declined(self) -> Optional[str]:
+        """Why the next program cannot be planned while one runs, from
+        what the engine can observe — or None. `wave`: admission runs its
+        own prefill program and drains its first token, so the turn is
+        synchronous anyway. `spec`: the drafter reads the tokens the
+        running program is still producing. `tier`: a host-tier promotion
+        rewrote the pools outside the step programs since the last
+        dispatch. (A dry pool, `preempt`, shows only while planning:
+        `_WouldPreempt`.)"""
+        if not self.prefill_chunk:
+            return "wave"
+        if self.spec_decode:
+            return "spec"
+        if self._pools_rewritten:
+            return "tier"
+        return None
+
+    def _plan(self, preempted: dict, ahead: bool) -> Optional[_Program]:
+        """Plan the next step program, by count alone: block growth, the
+        chunk pick, the live mask and the table it will run with, then
+        the planned advance of every slot it serves (`_Slot`). Host work
+        only: `_dispatch` enqueues it. `ahead` = it will queue behind a
+        program that has not drained: the plan then may not preempt
+        (`_WouldPreempt`). Returns None when there is nothing to run —
+        every slot retires, by count, with the running program, or the
+        preemptions emptied the engine."""
+        self._ensure_blocks(preempted, ahead)
+        chunk = self._next_chunk(preempted, ahead) \
+            if self.prefill_chunk else None
+        live = self._live_slots()           # decoding slots this program
+        if chunk is None and not live:
+            return None
+        # speculative drafting happens BEFORE the table sync (it may
+        # grow block lists to cover accepted rows); a chunked step
+        # never speculates — the chunk already owns the step's spare
+        # compute
+        spec = None
+        if self.spec_decode and chunk is None:
+            spec = self._spec_drafts()
+        reason = None if ahead else (
+            self._declined or self._lookahead_declined() or "first")
+        prog = _Program(
+            t=self._t, occupants={}, retiring={}, preempted=preempted,
+            n_live=len(live), spec=spec, overlapped=ahead,
+            drain_reason=reason,
+            kind=("fused" if chunk is not None
+                  else "spec" if spec is not None else "decode"))
         self._t += 1
+        self._pools_rewritten = False
+        if ahead:
+            self.overlapped_programs += 1
+        else:
+            self.drain_reasons[reason] = \
+                self.drain_reasons.get(reason, 0) + 1
+        # what the program runs with, fixed now: a later plan may move
+        # the host's tables and mask before this one is enqueued. A mask
+        # is rebuilt only where the planned live set moved outside the
+        # programs (a retirement, a cancel); a chunk's last program
+        # activates its slot itself, on the device and in the plan alike
+        live_in = self._live_mask() if self._live_dirty else None
+        self._live_dirty = False
+        self._sync_tables()
+        chunk_in = ()
+        # the planned advance: what this program does to every slot it
+        # serves is a count. A speculative program's stride is a value
+        # (its accept lengths) and advances at the drain.
+        for slot in live:
+            seq = self._slots[slot]
+            prog.occupants[slot] = seq.seq_id
+            if spec is None:
+                seq.n_new += 1
+                seq.pos += 1
+                self._plan_retirement(slot, seq, prog.retiring)
+        if chunk is not None:
+            slot_c, take = chunk
+            seq_c = self._slots[slot_c]
+            off = seq_c.prefix_len + seq_c.suffix_done
+            buf = seq_c.suffix[seq_c.suffix_done:seq_c.suffix_done + take]
+            # the chunk's progress; its last one promotes the slot to
+            # live with its first sampled token, exactly where a wave
+            # admit would have left it
+            seq_c.suffix_done += take
+            seq_c.pos = off + take
+            chunk_done = not self._is_partial(seq_c)
+            prog.chunk = (slot_c, seq_c.seq_id, take, seq_c.pos)
+            chunk_in = (
+                jnp.asarray(buf + [0] * (self.prefill_chunk - take),
+                            jnp.int32)[None],
+                jnp.int32(slot_c), jnp.int32(off),
+                jnp.asarray([take], jnp.int32), jnp.bool_(chunk_done))
+            if chunk_done:
+                seq_c.n_new = 1
+                prog.occupants[slot_c] = seq_c.seq_id
+                self._plan_retirement(slot_c, seq_c, prog.retiring)
+        prog.inputs = (self.block_tables, live_in, chunk_in)
+        return prog
+
+    def _dispatch(self, prog: _Program) -> None:
+        """Enqueue a planned program behind whatever the device is
+        running: `tok`/`pos`/`live`/`caches` flow from program to program
+        as device arrays (only the caches are donated, so an earlier
+        program's `tok` stays readable for its drain)."""
+        tables, live, chunk_in = prog.inputs
+        if live is None:
+            live = self.live
+        args = (self.variables, self.caches, self.tok, self.pos, live,
+                tables, self._rng, jnp.int32(prog.t), self._qparams)
+        if prog.kind == "fused":
+            out = self._get_fused_step_fn()(*args, *chunk_in)
+            self.caches, self.tok, self.pos, self.live = out
+        elif prog.kind == "spec":
+            draft_h, dlen_h = prog.spec
+            out = self._get_spec_step_fn()(
+                *args, jnp.asarray(draft_h), jnp.asarray(dlen_h))
+            self.caches, self.tok, self.pos, acc_dev = out
+            prog.spec = (draft_h, dlen_h, acc_dev)
+            self.live = live
+        else:
+            self.caches, self.tok, self.pos = self._get_step_fn()(*args)
+            self.live = live
+        prog.tok, prog.inputs = self.tok, None
+
+    def _holds(self, slot: int, seq_id: int) -> bool:
+        """Whether the occupant a program was planned for still holds its
+        slot."""
+        seq = self._slots.get(slot)
+        return seq is not None and seq.seq_id == seq_id
+
+    def _drain(self, prog: _Program, step: int, acc: dict,
+               t_step0: float) -> StepResult:
+        """Fetch one program's sampled tokens and do what needs their
+        VALUES: append them to their occupants' streams, test `eos`,
+        publish the blocks that became full, hand out the `Retired`
+        records (retirements by count carry the reason the plan gave
+        them, `_Program.retiring`)."""
         # THE step sync boundary: every slot's sampled token drains to the
-        # host once per fused step (plus the per-slot accept lengths on a
-        # speculative step — one transfer, not two)
-        with phase("engine.wait", acc, step=step):
-            if spec is not None:
+        # host once per program (plus the per-slot accept lengths of a
+        # speculative one — one transfer, not two)
+        with phase("engine.wait", acc, step=step, program=prog.t):
+            if prog.spec is not None:
+                draft_h, dlen_h, acc_dev = prog.spec
                 sampled, accepted_h = jax.device_get(  # lint: allow(host-sync)
-                    (self.tok, acc_dev))
+                    (prog.tok, acc_dev))
             else:
-                sampled = jax.device_get(self.tok)  # lint: allow(host-sync)
+                sampled = jax.device_get(prog.tok)  # lint: allow(host-sync)
         with phase("engine.retire", acc, step=step) as retire:
             emitted: dict[int, list] = {}
-            retired: dict[int, Retired] = dict(preempted)
-            drafted = accepted = 0
-            if chunk is not None:
-                # host mirror of the chunk: progress the partial, publish
-                # the blocks that just became full+immutable into the
-                # radix index (register is first-writer-wins, so
-                # re-publishing earlier ones is a no-op), and — on the
-                # final chunk — promote the slot to live with its first
-                # sampled token, exactly where a wave admit would have
-                # left it
-                seq_c.suffix_done += take
-                seq_c.pos = seq_c.prefix_len + seq_c.suffix_done
-                self.prefilled_tokens += take
-                full = min(seq_c.pos,
-                           len(seq_c.blocks) * self.block_size) \
-                    // self.block_size
-                self._register_blocks(seq_c.tokens, full, seq_c.blocks)
-                if chunk_done:
-                    first_tok = int(sampled[slot_c])
-                    seq_c.tokens.append(first_tok)
-                    seq_c.n_new = 1
-                    seq_c.pos = seq_c.prompt_len
-            for slot in list(self._slots):
+            retired: dict[int, Retired] = dict(prog.preempted)
+            drafted = accepted = overrun = prefill_tokens = 0
+            if prog.chunk is not None:
+                # publish the chunk's blocks that just became
+                # full+immutable into the radix index (register is
+                # first-writer-wins, so re-publishing earlier ones is a
+                # no-op)
+                slot_c, sid_c, prefill_tokens, rows = prog.chunk
+                self.prefilled_tokens += prefill_tokens
+                if self._holds(slot_c, sid_c):
+                    seq_c = self._slots[slot_c]
+                    full = min(rows, len(seq_c.blocks) * self.block_size) \
+                        // self.block_size
+                    self._register_blocks(seq_c.tokens, full, seq_c.blocks)
+            for slot, sid in prog.occupants.items():
+                if not self._holds(slot, sid):
+                    overrun += 1       # eos one program late, or a cancel
+                    continue
                 seq = self._slots[slot]
-                if self._is_partial(seq):
-                    continue                       # still parked: no token
-                nxt = int(sampled[slot])
-                if chunk is not None and slot == slot_c and chunk_done:
-                    toks = [nxt]                   # bookkeeping done above
-                elif spec is not None:
+                toks = [int(sampled[slot])]
+                if prog.spec is not None:
                     # accepted draft prefix + the correction token, in
                     # stream order. EOS inside the accepted span ends the
                     # stream AT the EOS token: everything past it is
@@ -1600,52 +1779,134 @@ class DecodeEngine:
                     # attended)
                     acc_s = int(accepted_h[slot])
                     toks = [int(draft_h[slot, j])
-                            for j in range(acc_s)] + [nxt]
+                            for j in range(acc_s)] + toks
                     if self.eos_id is not None and self.eos_id in toks:
                         toks = toks[:toks.index(self.eos_id) + 1]
-                    seq.tokens.extend(toks)
                     seq.n_new += len(toks)
                     seq.pos += len(toks)
                     accepted += acc_s
-                else:
-                    toks = [nxt]
-                    seq.tokens.append(nxt)
-                    seq.n_new += 1
-                    seq.pos += 1
-                emitted[seq.seq_id] = toks
-                reason = self._retire_reason(slot, toks[-1])
+                    self._plan_retirement(slot, seq, prog.retiring)
+                seq.tokens.extend(toks)
+                emitted[sid] = toks
+                reason = prog.retiring.get(slot)
+                if self.eos_id is not None and toks[-1] == self.eos_id:
+                    reason = "eos"
                 if reason is not None:
-                    retired[seq.seq_id] = self._retire(slot, reason)
-            # drop retired slots from the live mask (their table rows are
-            # zeroed, so any residual write lands in the null block)
-            if len(retired) > len(preempted):
-                self._rebuild_live()
+                    retired[sid] = self._retire(slot, reason)
             n_emitted = sum(len(v) for v in emitted.values())
             self.emitted_tokens += n_emitted
-            if spec is not None:
+            self.overrun_tokens += overrun
+            if prog.spec is not None:
                 drafted = int(dlen_h.sum())
                 self.spec_drafted_tokens += drafted
                 self.spec_accepted_tokens += accepted
-            # `step` here counts completed steps (the phases' `step` + 1);
-            # retire_ms runs to this stamp, so the four parts sum to
-            # step_ms less the few microseconds between phases
+            # one record per drained program: `step` counts completed
+            # programs (this one's number + 1), its own n_live, chunk,
+            # `overlapped` and `drain_reason`; the four times are this
+            # CALL's phases (prepare and dispatch: of the program the call
+            # queued, this one's on a drained turn), and retire_ms runs
+            # to this stamp, so they sum to step_ms less the few
+            # microseconds between phases
             t_rec = time.perf_counter()
             self.flight.record(
-                step=self._t,
+                step=prog.t + 1,
                 step_ms=round((t_rec - t_step0) * 1e3, 3),
-                prepare_ms=round(acc["engine.prepare"] * 1e3, 3),
-                dispatch_ms=round(acc["engine.dispatch"] * 1e3, 3),
+                prepare_ms=round(acc.get("engine.prepare", 0.0) * 1e3, 3),
+                dispatch_ms=round(acc.get("engine.dispatch", 0.0) * 1e3, 3),
                 wait_ms=round(acc["engine.wait"] * 1e3, 3),
                 retire_ms=round((t_rec - retire.t0) * 1e3, 3),
-                n_live=n_live_in, prefill_tokens=prefill_tokens,
+                n_live=prog.n_live, prefill_tokens=prefill_tokens,
                 emitted=n_emitted,
-                retired=len(retired) - len(preempted),
+                retired=len(retired) - len(prog.preempted),
                 blocks_in_use=self.block_pool.n_referenced,
-                preemptions=len(preempted),
-                drafted=drafted, accepted=accepted)
+                preemptions=len(prog.preempted),
+                drafted=drafted, accepted=accepted,
+                overlapped=prog.overlapped,
+                drain_reason=prog.drain_reason, overrun=overrun)
         return StepResult(emitted=emitted, retired=retired,
                           prefill_tokens=prefill_tokens,
                           drafted=drafted, accepted=accepted)
+
+    def step(self) -> StepResult:
+        """Advance every live slot one token — or, on a speculative step
+        (`spec_decode` on, drafts available), up to `spec_k`+1 tokens —
+        fusing in one prefill chunk of the oldest partial prompt when
+        `prefill_chunk` is set. Returns a `StepResult`:
+        {seq_id: [tokens]} emitted this step in stream order (including
+        the first token of a prompt whose LAST chunk ran), plus
+        {seq_id: Retired} for the sequences that finished (with WHY —
+        eos | budget | cache_full | preempted; preempted ones yielded
+        their blocks BEFORE the step and emit no token — requeue
+        them).
+
+        One program is kept in flight: a call plans and dispatches
+        program k+1 BEFORE it drains program k, so the device has its
+        next program queued while the host fetches k's tokens, retires,
+        and the caller emits and admits. The call still returns k's
+        result, one program's per call. The lookahead declines itself
+        (`_lookahead_declined`, `_WouldPreempt`) in the turns whose plan
+        needs the running program's tokens; the turn is then the same
+        code with nothing queued ahead — the next call dispatches AND
+        drains its program, and records why (`drain_reason`)."""
+        cur, self._inflight = self._inflight, None
+        if cur is not None and not (
+                any(self._holds(s, i) for s, i in cur.occupants.items())
+                or (cur.chunk is not None
+                    and self._holds(*cur.chunk[:2]))):
+            # everyone it ran for was cancelled meanwhile: nothing to
+            # hand out (its writes precede, in device order, whatever
+            # comes next)
+            self.overrun_tokens += len(cur.occupants)
+            cur = None
+        if cur is None and not self._slots:
+            return StepResult({}, {})
+        # the call's host phases (obs/trace.py PHASES): leaves in the
+        # profiler's trace, joined by `step` = the number of the program
+        # this call drains, and the split of step_ms in the flight
+        # record, from the same stamps
+        step = cur.t if cur is not None else self._t
+        acc: dict = {}
+        t_step0 = time.perf_counter()
+        queue: list[_Program] = []
+        nxt = None
+        with phase("engine.prepare", acc, step=step):
+            if cur is None:                 # a drained turn: plan k too
+                preempted: dict[int, Retired] = {}
+                cur = self._plan(preempted, ahead=False)
+                if cur is None:
+                    return StepResult({}, preempted)
+                queue.append(cur)
+            why = self._lookahead_declined()
+            if why is None:
+                try:
+                    nxt = self._plan({}, ahead=True)
+                except _WouldPreempt:
+                    why = "preempt"
+            if nxt is not None:
+                queue.append(nxt)
+        if queue:
+            # stats of the program that stays queued when the call
+            # returns (on a burst's first call the one it drains goes
+            # out first, under the same phase)
+            last = queue[-1]
+            with phase("engine.dispatch", acc, step=step, program=last.t,
+                       kind=last.kind, n_live=last.n_live,
+                       prefill_tokens=last.chunk[2] if last.chunk else 0,
+                       overlapped=int(last.overlapped),
+                       drain_reason=last.drain_reason or "none"), \
+                    self._ctx():
+                for prog in queue:
+                    self._dispatch(prog)
+        res = self._drain(cur, step, acc, t_step0)
+        if not self._slots:
+            # end of work. By count the plan queues nothing behind a
+            # program that retires the last slot; a program that ran on
+            # because an `eos` showed only now served no one else
+            if nxt is not None:
+                self.overrun_tokens += len(nxt.occupants)
+            nxt = why = None
+        self._inflight, self._declined = nxt, why
+        return res
 
     def run(self, prompts, max_new_tokens,
             progress=None) -> list[list]:

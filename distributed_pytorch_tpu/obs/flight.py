@@ -8,7 +8,13 @@ retire_ms, n_live, prefill_tokens, emitted, blocks_in_use, preemptions}`
 (the four parts of step_ms are the engine's host phases, obs/trace.py) —
 to a bounded ring, so the last few
 thousand steps are always reconstructable, at the cost of one dict
-append per multi-millisecond device step. Served live at
+append per multi-millisecond device step. A record is one drained step
+program: `overlapped` says whether it was queued behind a running one
+(the device did not wait for the host before it), `drain_reason` why not
+(`first` | `wave` | `spec` | `tier` | `preempt`), `overrun` how many of
+its tokens were for an occupant that had left (an `eos` seen one program
+late, a cancel). The four times are those of the `step()` call that
+drained it. Served live at
 `GET /debug/timeline` (serve/server.py) and dumped to `runs/*.jsonl` by
 the bench legs and the fault-injection harness for post-hoc analysis
 against the PERF.md latency models.

@@ -282,12 +282,21 @@ def set_recorder(rec: TraceRecorder) -> TraceRecorder:
 #: the phases' shared `step` stat. Per engine step, scheduler pass or
 #: train iteration; never per token or per request.
 PHASES = {
-    # engine executor thread, DecodeEngine.step, in this order
+    # engine executor thread, DecodeEngine.step, in this order. One call
+    # = one `step` stat = the number of the program the call DRAINS
+    # (wait, retire). With a program in flight, prepare and dispatch are
+    # the NEXT program's (stat `program` on dispatch and wait says whose);
+    # on a drained turn all four are one program's. The first call of a
+    # burst plans and enqueues two (its own and the one behind it) under
+    # its one prepare and one dispatch; the last enqueues none
     "engine.prepare": "block growth/preemption, chunk pick, drafts, "
-                      "table sync, chunk padding",
-    "engine.dispatch": "the jitted step call (enqueue; step marker)",
-    "engine.wait": "device_get of the sampled tokens",
-    "engine.retire": "host bookkeeping up to and including flight.record",
+                      "live mask, table sync, chunk padding",
+    "engine.dispatch": "the jitted step call (enqueue; step marker); "
+                       "stats program, overlapped, drain_reason",
+    "engine.wait": "device_get of a program's sampled tokens (stat "
+                   "program)",
+    "engine.retire": "host bookkeeping that needs the tokens' values, up "
+                     "to and including flight.record",
     # engine executor thread, DecodeEngine.admit
     "engine.admit": "one admission: prefix match, blocks, wave prefill "
                     "(stat bucket) or chunked bookkeeping (stat chunked)",

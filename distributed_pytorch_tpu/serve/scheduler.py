@@ -297,6 +297,14 @@ class Scheduler:
             "serve_engine_tokens_per_step",
             lambda: getattr(self.engine, "tokens_per_step", 1.0),
             "mean tokens emitted per fused step (spec decode > 1)")
+        # one program in flight (DecodeEngine.step): the share of step
+        # programs queued behind a running one. Near 1 the device never
+        # waits for this loop's emit/yield/admit turn; the flight record
+        # (/debug/timeline) says per program why one was not
+        self.metrics.register_gauge(
+            "serve_engine_overlap_share",
+            lambda: getattr(self.engine, "overlap_share", 0.0),
+            "fraction of step programs dispatched behind a running one")
         # host-RAM KV tier (ops/kv_tier.py via engine.host_tier): live
         # occupancy/save-rate gauges here, block-movement counters
         # delta-synced in _tier_sync() after every engine call. Tier
@@ -803,7 +811,13 @@ class Scheduler:
             # thread: the awaited engine step lies between sched.admit
             # and sched.emit (the engine's own phases cover it, on the
             # executor thread), and the yield to the clients after
-            # sched.emit stays unspanned — the hole is what it is
+            # sched.emit stays unspanned — the hole is what it is.
+            # The engine keeps one program in flight: step() returns
+            # program k's result with k+1 already queued, so this loop's
+            # emit, yield and admit run while the device works. What is
+            # admitted or cancelled here takes effect in k+2; a token
+            # k+1 computes for a request cancelled here never arrives
+            # (the engine drops it: its sid is no longer in _live either)
             while True:
                 with obs_trace.phase("sched.admit",
                                      queued=len(self._queue),

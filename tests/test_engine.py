@@ -402,3 +402,310 @@ def test_engine_fsdp_mesh_runs():
     ref_eng = DecodeEngine(model, variables, n_slots=2, temperature=0.0,
                            min_bucket=8)
     assert outs == ref_eng.run(PROMPTS[:2], max_new_tokens=4)
+
+
+# ----------------------------------------------------------------------
+# one step program in flight (PR 31): the next program is planned from
+# counts and queued behind the running one; the tokens do not change
+# ----------------------------------------------------------------------
+
+_MV: dict = {}
+
+
+def _mv():
+    if not _MV:
+        cfg = tiny_cfg()
+        _MV["cfg"], (_MV["model"], _MV["variables"]) = cfg, build(cfg)
+    return _MV["cfg"], _MV["model"], _MV["variables"]
+
+
+#: name -> (prompt, budget). A: three chunks of 16; D is cancelled while a
+#: program runs for it and E takes its slot at once; F ends with its first
+#: token; budgets end on different steps.
+LOOKAHEAD_REQS = {
+    "A": (list(range(1, 40)), 14),
+    "B": ([1, 2, 3], 5),
+    "D": ([7, 8, 9, 10], 30),
+    "C": ([5, 6, 7, 8, 9, 10, 11], 12),
+    "E": ([20] * 17 + [3, 4], 6),
+    "F": ([42, 43], 1),
+    "G": ([9], 7),
+}
+
+
+def _drive_lookahead_schedule(eng, chunked):
+    """The schedule of the equivalence test, call by call: admissions
+    BETWEEN calls (two before the first, then one a call as slots free),
+    a cancel of D once it holds three tokens with E admitted into its
+    slot in the same gap. Per request: its stream, its `Retired` record,
+    and for each token where it was sampled (kind, rng fold index, slot)
+    so a sampling oracle can replay the engine's keys."""
+    reqs = LOOKAHEAD_REQS
+    queue = [n for n in reqs if n != "E"]
+    name_of, sid_of, slot_of = {}, {}, {}
+    streams = {n: [] for n in reqs}
+    events = {n: [] for n in reqs}
+    retired = {}
+    seen = {"cancel_in_flight": None}
+
+    def admit(name):
+        slot = eng.free_slots[0]
+        n_adm = eng._n_admits
+        adm = eng.admit(*reqs[name])
+        name_of[adm.seq_id], sid_of[name], slot_of[name] = \
+            name, adm.seq_id, slot
+        if adm.first_token is not None:           # wave: the TTFT token
+            streams[name].append(adm.first_token)
+            events[name].append(("admit", n_adm, 0))
+        if adm.retired is not None:
+            retired[name] = adm.retired
+
+    calls = 0
+    while queue or eng.n_live:
+        for _ in range(2 if calls == 0 else 1):
+            if queue and eng.free_slots:
+                admit(queue.pop(0))
+        n_rec = eng.flight.total
+        res = eng.step()
+        calls += 1
+        assert calls < 400, "the schedule does not end"
+        if eng.flight.total == n_rec:
+            assert not res.emitted
+            continue
+        t = eng.flight.entries()[-1]["step"] - 1   # the program drained
+        for sid, toks in res.emitted.items():
+            name = name_of[sid]
+            assert len(toks) == 1
+            first = chunked and not streams[name]
+            streams[name] += toks
+            events[name].append(("first", t, 0) if first
+                                else ("decode", t, slot_of[name]))
+        for sid, ret in res.retired.items():
+            retired[name_of[sid]] = ret
+        if "D" not in retired and len(streams["D"]) >= 3:
+            fl = eng._inflight
+            seen["cancel_in_flight"] = fl is not None and \
+                fl.occupants.get(slot_of["D"]) == sid_of["D"]
+            retired["D"] = eng.cancel(sid_of["D"])
+            assert eng.free_slots == [slot_of["D"]]
+            admit("E")                      # the same slot, the same gap
+            assert slot_of["E"] == slot_of["D"]
+    return streams, retired, events, seen
+
+
+def _replay_sampling(model, variables, eng, prompt, events):
+    """The request decoded alone on the host, with the engine's own keys:
+    every token is `sample_token` of the full forward's last logits under
+    `fold_in(rng, t)` of the program that sampled it (2**21 + t for a
+    chunk's first token, 2**20 + n for a wave admission's), in the row of
+    its slot."""
+    from distributed_pytorch_tpu.models.generate import sample_token
+    T = model.config.block_size
+    if "last_logits" not in _MV:              # one trace for the module
+        _MV["last_logits"] = jax.jit(
+            lambda idx, n: model.apply(variables, idx, None, None, 0,
+                                       logits_idx=n[None] - 1)[0][:, -1, :])
+    last_logits = _MV["last_logits"]
+
+    toks, out = list(prompt), []
+    for kind, idx, slot in events:
+        row = last_logits(jnp.asarray([toks + [0] * (T - len(toks))],
+                                      jnp.int32), jnp.int32(len(toks)))
+        if kind == "decode":
+            logits = jnp.zeros((eng.n_slots, row.shape[-1])).at[slot].set(
+                row[0])
+            key = jax.random.fold_in(eng._rng, idx)
+        else:
+            logits, slot = row, 0
+            key = jax.random.fold_in(
+                eng._rng, (2 ** 21 if kind == "first" else 2 ** 20) + idx)
+        tok = int(sample_token(logits, key, temperature=eng.temperature,
+                               top_k=eng.top_k)[slot])
+        out.append(tok)
+        toks.append(tok)
+    return out
+
+
+@pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "seeded"])
+@pytest.mark.parametrize("prefix_cache", [True, False],
+                         ids=["prefix-on", "prefix-off"])
+@pytest.mark.parametrize("chunk", [16, 0], ids=["chunked", "wave"])
+def test_one_program_in_flight_gives_every_request_its_own_tokens(
+        chunk, prefix_cache, sampled):
+    """One seeded schedule with admissions between calls, a prompt of
+    three chunks, budgets that end on different steps, an `eos_id` that
+    fires mid-stream, a cancel while a program runs for the cancelled
+    occupant and a re-admission into the same slot in the same gap: every
+    request's stream and `Retired` record equal the request decoded alone
+    (greedy: `generate`; seeded sampling: the engine's `fold_in(rng, t)`
+    replayed on the host), and no token of the cancelled occupant reaches
+    the slot's next one. The wave engine runs the same code with the
+    lookahead declined."""
+    cfg, model, variables = _mv()
+    temperature = 0.8 if sampled else 0.0
+
+    def engine(eos_id):
+        return DecodeEngine(
+            model, variables, n_slots=3, temperature=temperature,
+            min_bucket=8, block_size=8, prefill_chunk=chunk,
+            prefix_cache=prefix_cache, eos_id=eos_id,
+            rng=jax.random.PRNGKey(7))
+
+    # a dry pass finds a token some stream emits mid-way: the eos id
+    dry, _, _, _ = _drive_lookahead_schedule(engine(None), bool(chunk))
+    eos = dry["C"][3]
+    eng = engine(eos)
+    streams, retired, events, seen = _drive_lookahead_schedule(
+        eng, bool(chunk))
+    assert seen["cancel_in_flight"] == bool(chunk)
+    mid_stream_eos = 0
+    for name, (prompt, budget) in LOOKAHEAD_REQS.items():
+        got, ret = streams[name], retired[name]
+        if sampled:
+            want = _replay_sampling(model, variables, eng, prompt,
+                                    events[name])
+        else:
+            want = generate(model, variables,
+                            jnp.asarray(prompt, jnp.int32)[None], budget,
+                            temperature=0.0)[0].tolist()[len(prompt):]
+        if eos in want:
+            want = want[:want.index(eos) + 1]
+        if name == "D":
+            assert 3 <= len(got) < budget and got == want[:len(got)]
+            assert ret.reason == "cancelled"
+        else:
+            assert got == want, f"{name} did not receive its own tokens"
+            assert ret.reason == ("eos" if got[-1] == eos else "budget")
+            assert len(got) == budget or got[-1] == eos
+            mid_stream_eos += int(got[-1] == eos and len(got) < budget)
+        assert ret.tokens == prompt + got and ret.prompt_len == len(prompt)
+    assert mid_stream_eos >= 1, "the schedule was built to see an eos"
+    # nothing left behind: no program queued, no slot, no block
+    assert eng._inflight is None and eng.free_slots == [0, 1, 2]
+    assert eng.block_pool.n_referenced == 0
+    recs = eng.flight.entries()
+    if chunk:
+        assert eng.overlap_share > 0.8
+        assert set(eng.drain_reasons) == {"first"}
+        # D's token in flight at the cancel, and one for every stream
+        # whose eos showed a program late
+        n_eos = sum(r.reason == "eos" for r in retired.values())
+        assert eng.overrun_tokens == 1 + n_eos
+        assert sum(r["overrun"] for r in recs) <= eng.overrun_tokens
+        assert eng.fused_step_traces == 1 and eng.step_traces == 1
+    else:
+        assert eng.overlap_share == 0.0 and eng.overrun_tokens == 0
+        assert eng.drain_reasons == {"wave": eng.n_steps}
+        assert not any(r["overlapped"] for r in recs)
+
+
+def test_end_of_work_leaves_no_program_queued():
+    """By count the engine knows when nothing will be live: nothing is
+    queued behind the program that retires the last slot, and `n_steps`
+    counts only programs that ran for a slot live by plan. An `eos` shows
+    a program late: that overrun is counted (`n_steps`) and named
+    (`overrun_tokens`), and dropped at the end of work."""
+    cfg, model, variables = _mv()
+    prompt = [1, 2, 3]
+    # seeded sampling: this model's greedy streams repeat one token
+    kw = dict(n_slots=2, temperature=0.8, rng=jax.random.PRNGKey(3),
+              min_bucket=8, block_size=8, prefill_chunk=16)
+    eng = DecodeEngine(model, variables, **kw)
+    ref = eng.run([prompt], 8)[0]
+    gen = ref[len(prompt):]
+    # 1 fused program + 7 decode programs, each with a record; only the
+    # first had no running program to queue behind
+    assert eng.n_steps == eng.flight.total == 8
+    assert eng._inflight is None and eng.overrun_tokens == 0
+    assert eng.overlap_share == 7 / 8 and eng.drain_reasons == {"first": 1}
+    j = next(i for i in range(2, 8) if gen[i] not in gen[:i])
+    eng = DecodeEngine(model, variables, eos_id=gen[j], **kw)
+    assert eng.run([prompt], 8) == [ref[:len(prompt) + j + 1]]
+    # j + 1 programs handed out tokens; one more ran on for the slot and
+    # was dropped when the eos emptied the engine
+    assert eng.flight.total == j + 1 and eng.n_steps == j + 2
+    assert eng.overrun_tokens == 1 and eng._inflight is None
+    assert eng.retire_counts["eos"] == 1 and eng.free_slots == [0, 1]
+
+
+def test_lookahead_declines_for_a_speculative_engine():
+    """The drafter reads the tokens the running program is producing:
+    every turn of a speculative engine is drained (`spec`), results as
+    ever."""
+    cfg, model, variables = _mv()
+    prompts = [[3, 4, 5, 3, 4, 5, 3, 4], [7, 7, 7, 7, 7, 7]]
+    kw = dict(n_slots=2, temperature=0.0, min_bucket=8, block_size=8,
+              prefill_chunk=16)
+    plain = DecodeEngine(model, variables, **kw)
+    eng = DecodeEngine(model, variables, spec_decode=True, spec_k=3, **kw)
+    assert eng.spec_decode
+    assert eng.run(prompts, 16) == plain.run(prompts, 16)
+    assert eng.spec_drafted_tokens > 0
+    assert eng.overlap_share == 0.0
+    assert eng.drain_reasons == {"spec": eng.n_steps}
+    assert plain.overlap_share > 0.8
+
+
+def test_lookahead_declines_before_a_preemption():
+    """A preemption hands out the victim's tokens, which a running
+    program would still be producing: the turn that meets a dry pool
+    queues nothing ahead (`preempt`), preempts with the engine drained,
+    and the tokens are the oracle's."""
+    cfg, model, variables = _mv()
+    eng = DecodeEngine(model, variables, n_slots=2, temperature=0.0,
+                       min_bucket=8, prefill_chunk=16, block_size=8,
+                       n_blocks=9)
+    prompts = [[1, 2, 3], list(range(1, 40))]
+    outs = eng.run([list(p) for p in prompts], max_new_tokens=20)
+    assert eng.retire_counts["preempted"] >= 1
+    for p, o in zip(prompts, outs):
+        assert o == generate(model, variables,
+                             jnp.asarray(p, jnp.int32)[None], 20,
+                             temperature=0.0)[0].tolist()
+    recs = eng.flight.entries()
+    hit = [r for r in recs if r["preemptions"]]
+    assert hit and all(not r["overlapped"]
+                       and r["drain_reason"] == "preempt" for r in hit)
+    assert eng.drain_reasons["preempt"] >= len(hit)
+    assert 0 < eng.overlap_share < 1
+    assert eng.block_pool.n_referenced == 0 and eng._inflight is None
+
+
+def test_lookahead_declines_after_a_host_tier_promotion():
+    """A promotion rewrites the pools outside the step programs: the
+    call after it queues nothing ahead, and the next program is
+    dispatched with the engine drained (`tier`)."""
+    cfg, model, variables = _mv()
+    a = [(7 * i + 3) % 97 for i in range(33)]
+    churn = [[(11 * i + j + 1) % 97 for i in range(33)] for j in range(3)]
+    eng = DecodeEngine(model, variables, n_slots=2, temperature=0.0,
+                       min_bucket=8, block_size=8, prefill_chunk=16,
+                       n_blocks=12, host_tier=True, host_blocks=64)
+    ref = generate(model, variables, jnp.asarray([a], jnp.int32), 6,
+                   temperature=0.0)[0].tolist()
+    assert eng.run([a], 6) == [ref]
+    for c in churn:                           # evict a's chain to the host
+        eng.run([c], 8)
+    assert eng.host_tier.counters()["demoted"] > 0
+    assert "tier" not in eng.drain_reasons
+    long = eng.admit([5, 6, 7], 30)           # a stream that keeps running
+    for _ in range(3):
+        eng.step()
+    assert eng._inflight is not None          # a program runs ...
+    promoted = eng.host_tier.counters()["promoted"]
+    adm = eng.admit(a, 6)                     # ... while a's chain returns
+    assert eng.host_tier.counters()["promoted"] > promoted
+    assert adm.prefix_len > 0
+    n0 = eng.flight.total
+    out = None
+    while out is None:
+        out = eng.step().retired.get(adm.seq_id)
+    assert out.tokens == ref and out.reason == "budget"
+    recs = eng.flight.entries()[n0 - eng.flight.total:]
+    # the first call drains the program that was running and queues
+    # nothing; the second dispatches with nothing ahead, and says why
+    assert recs[0]["overlapped"] and not recs[1]["overlapped"]
+    assert recs[1]["drain_reason"] == "tier"
+    assert all(r["overlapped"] for r in recs[2:])
+    assert eng.drain_reasons["tier"] == 1
+    eng.cancel(long.seq_id)

@@ -142,7 +142,11 @@ def test_cancel_mid_decode_frees_slot_within_one_step(mv):
     assert ret.reason == "cancelled"
     # the loop free-runs, so one step may be in flight when cancel lands
     # and one more may start before the flag is applied — but never the
-    # remaining ~37 steps of budget
+    # remaining ~37 steps of budget. With one program queued ahead (PR
+    # 31) the bound stands: `n_steps` counts programs DISPATCHED, the
+    # call that is running when the cancel lands has already queued the
+    # one behind it (counted in s0 or the first of the two), and the
+    # cancelled occupant's token in that program is dropped, not emitted
     assert s1 - s0 <= 2, f"cancel took {s1 - s0} steps to free the slot"
     assert eng.retire_counts["cancelled"] == 1
     assert len(h.tokens) < 10          # nowhere near the 40-token budget

@@ -327,7 +327,12 @@ def test_debug_timeline_after_load_burst(mv):
     assert entries and body["n_steps"] == flight.total
     for e in entries:
         assert {"t", "step", "step_ms", "n_live", "prefill_tokens",
-                "emitted", "blocks_in_use", "preemptions"} <= set(e)
+                "emitted", "blocks_in_use", "preemptions",
+                "overlapped", "drain_reason", "overrun"} <= set(e)
+    # a wave engine never queues a program behind a running one, and
+    # the record says why
+    assert {(e["overlapped"], e["drain_reason"]) for e in entries} == \
+        {(False, "wave")}
     # the burst genuinely batched: some step decoded >= 2 streams and
     # tokens were emitted across the window
     assert max(e["n_live"] for e in entries) >= 2
@@ -341,6 +346,88 @@ def test_debug_timeline_after_load_burst(mv):
     path = flight.dump_jsonl(
         os.path.join("runs", "ci_trace_e2e", "timeline.jsonl"))
     assert os.path.getsize(path) > 0
+
+
+def test_lookahead_counter_where_operators_look(mv, tmp_path):
+    """One program in flight (PR 31), seen from outside: `/debug/timeline`
+    says per program whether it was queued behind a running one and why
+    not, `/metrics` carries `serve_engine_overlap_share`, and in a traced
+    run the four `engine.*` phases of one `step()` call share one `step`
+    stat (dispatch and wait also say which program they are about), so
+    the benchmark's three readings of them (benchmark/lib/trace_spans.py:
+    extent, sum, turnaround) stay positive and under one turn of the
+    loop."""
+    from benchmark.lib import trace_spans as ts
+    four = ["engine.prepare", "engine.dispatch", "engine.wait",
+            "engine.retire"]
+
+    async def main():
+        rep = await Rep(mv, n_slots=3, prefill_chunk=16).start()
+        rep.eng.run([list(range(1, 20))], 3)      # compile both programs
+        n0 = rep.eng.n_steps
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            handles = [rep.sched.submit(list(range(i + 1, i + n)), 12)
+                       for i, n in enumerate((9, 20, 30, 12, 5))]
+            await asyncio.gather(*(h.result() for h in handles))
+        finally:
+            jax.profiler.stop_trace()
+        _, timeline = await http_get(rep.app.port, "/debug/timeline?n=512")
+        _, metrics = await http_get(rep.app.port, "/metrics")
+        eng = rep.eng
+        await rep.stop()
+        return json.loads(timeline)["entries"], metrics, eng, n0
+
+    entries, metrics, eng, n0 = run_async(main())
+    mine = [e for e in entries if e["step"] > n0]
+    assert len(mine) >= 12
+    assert {e["drain_reason"] for e in mine if not e["overlapped"]} == \
+        {"first"}
+    share = sum(e["overlapped"] for e in mine) / len(mine)
+    assert share > 0.8
+    gauge = next(ln for ln in metrics.splitlines()
+                 if ln.startswith("serve_engine_overlap_share "))
+    assert float(gauge.split()[1]) == pytest.approx(eng.overlap_share)
+    assert eng.overlap_share > 0.5
+
+    sl = ts.load(str(tmp_path))
+    evs = sorted((e for e in ts.phase_events(sl, "engine.")
+                  if e[0] != "engine.admit"), key=lambda e: e[1])
+    # the calls, as runs of phases on the engine's thread: each starts
+    # with a prepare and shares one `step` stat
+    calls = []
+    for e in evs:
+        if e[0] == "engine.prepare":
+            calls.append([])
+        calls[-1].append(e)
+    assert len(calls) >= 12
+    for phases in calls:
+        names = [e[0] for e in phases]
+        assert names in (four, four[:1] + four[2:], four[:1]), names
+        assert len({e[3]["step"] for e in phases}) == 1
+        step = phases[0][3]["step"]
+        for e in phases:
+            if e[0] == "engine.wait":
+                assert e[3]["program"] == step      # the call drains it
+            if e[0] == "engine.dispatch":
+                assert e[3]["program"] == step + int(e[3]["overlapped"])
+                assert e[3]["drain_reason"] == (
+                    "none" if e[3]["overlapped"] else "first")
+    steps = ts.steps_by_stat(evs, four)
+    pairs = [k for k in sorted(steps) if k + 1 in steps]
+    assert len(pairs) >= 8
+    turn = {k: (steps[k + 1]["engine.prepare"][0]
+                - steps[k]["engine.prepare"][0]) / 1e6 for k in pairs}
+    sub = {k: steps[k] for k in pairs}
+    extent = ts.step_extent_ms(sub, "engine.prepare", "engine.retire")
+    host = ts.step_sum_ms(sub, ["engine.prepare", "engine.dispatch",
+                                "engine.retire"])
+    around = ts.step_turnaround_ms(steps, "engine.retire", "engine.prepare")
+    assert len(around) == len(pairs)
+    for k, ext, h, a in zip(pairs, extent, host, around):
+        assert 0 < h < ext < turn[k], (k, h, ext, turn[k])
+        assert 0 < a < turn[k], (k, a, turn[k])
+        assert ext + a == pytest.approx(turn[k], rel=1e-6)
 
 
 def test_build_info_gauges_on_metrics(mv):

@@ -337,11 +337,11 @@ def enable_compile_cache():
 
 ACTIVATIONS = (
     "relu", "gelu", "swish", "mish", "silu", "selu", "celu", "elu",
-    "glu", "sigmoid", "lrelu", "tanh", "swiglu",
+    "glu", "sigmoid", "lrelu", "tanh", "swiglu", "relu2",
 )
 
 ATTENTION_KINDS = ("mha", "mqa", "gqa", "mla")
-POS_EMB_KINDS = ("learn", "sin", "rope")
+POS_EMB_KINDS = ("learn", "sin", "rope", "none")
 # The reference realizes these as five separate trainer scripts
 # (single-gpu/train.py, multi-gpu/ddp/train.py, kaggle-zero1.py,
 # kaggle-zero2.py, kaggle-fsdp.py); here each is a sharding recipe name.
@@ -434,7 +434,53 @@ class LLMConfig:
     pp_schedule: str = "auto"  # 'auto' | 'carry' | '1f1b'
     pp_vpp: int = 0
 
+    # a per-layer pattern of ONE-mixer blocks, `x + mixer(norm(x))`, one
+    # character a layer: 'M' a Mamba-2 state-space mixer (models/ssm.py),
+    # 'E' sigmoid-routed experts of which this chip holds a share
+    # (models/mlp.py RoutedExperts), '*' attention (GQA). Empty = the
+    # attention + FFN block above for every layer. `n_layer` is its
+    # length. A patterned model has RMSNorms, no FFN biases, and its
+    # parameters are created in `LLM.param_dtype`.
+    layer_pattern: str = ""
+    norm_eps: float = 1e-5       # the RMSNorms of a patterned model
+    tie_head: bool = True        # False: an `lm_head` (V, C) of its own
+    head_dim: int = 0            # attention head size; 0 = n_embd // n_head
+    attn_bias: bool = True       # biases on the qkv and output projections
+    # 'E' layers: `n_exp` - `n_shared` is the ROUTER's width and `n_act` -
+    # `n_shared` its top-k, as above; `experts_held` = (first id, count) is
+    # the slice of routed experts this chip holds (empty: all), what the
+    # others would add is left out; `shared_up_dim` the shared expert's
+    # width (0 = up_dim); `routed_scale` multiplies the renormalised
+    # weights
+    experts_held: tuple = ()
+    shared_up_dim: int = 0
+    routed_scale: float = 1.0
+    # 'M' layers (Mamba-2): heads x head size = d_inner, groups share B/C
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state: int = 0
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+
     def __post_init__(self):
+        object.__setattr__(self, "experts_held", tuple(self.experts_held))
+        if self.layer_pattern:
+            assert set(self.layer_pattern) <= set("ME*"), self.layer_pattern
+            assert len(self.layer_pattern) == self.n_layer, (
+                f"layer_pattern has {len(self.layer_pattern)} layers, "
+                f"n_layer is {self.n_layer}")
+            assert self.pp_stages == 1 and not self.moe
+            if "M" in self.layer_pattern:
+                assert self.ssm_heads and self.ssm_head_dim and \
+                    self.ssm_state and \
+                    self.ssm_heads % self.ssm_groups == 0
+            if "E" in self.layer_pattern:
+                assert self.n_act > self.n_shared and \
+                    self.n_exp > self.n_shared
+                if self.experts_held:
+                    lo, n = self.experts_held
+                    assert 0 <= lo and n >= 1 and lo + n <= self.n_routed
         # Cross-field normalization, mirroring reference
         # single-gpu/train.py:198-206 (mha -> n_kv_heads=n_head, mqa -> 1,
         # mla requires latent dims; rope-mla additionally rope_head_dim).
@@ -452,7 +498,8 @@ class LLMConfig:
                 assert self.rope_head_dim is not None, "Need dim of Rotary heads"
         else:
             raise ValueError(f"unknown attention kind {self.attn!r}")
-        assert self.n_embd % self.n_head == 0, "n_embd must be divisible by n_head"
+        assert self.head_dim or self.n_embd % self.n_head == 0, \
+            "n_embd must be divisible by n_head"
         assert self.pos_emb in POS_EMB_KINDS, f"unknown pos_emb {self.pos_emb!r}"
         assert self.non_linearity.lower() in ACTIVATIONS, \
             f"unknown non_linearity {self.non_linearity!r}"
@@ -489,7 +536,14 @@ class LLMConfig:
 
     @property
     def head_size(self) -> int:
-        return self.n_embd // self.n_head
+        return self.head_dim or self.n_embd // self.n_head
+
+    @property
+    def recurrent(self) -> bool:
+        """Whether some layer keeps per-sequence state that is no block of
+        the paged cache (what prefix reuse, the host tier and speculative
+        roll-back cannot snapshot yet)."""
+        return "M" in self.layer_pattern
 
     @property
     def n_routed(self) -> int:

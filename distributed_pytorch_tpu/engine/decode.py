@@ -147,6 +147,7 @@ import numpy as np
 
 from distributed_pytorch_tpu.models.generate import sample_token
 from distributed_pytorch_tpu.models.gpt import init_paged_cache
+from distributed_pytorch_tpu.obs import paths
 from distributed_pytorch_tpu.obs.flight import FlightRecorder
 from distributed_pytorch_tpu.obs.retrace import TraceGuard
 from distributed_pytorch_tpu.obs.trace import phase
@@ -171,6 +172,13 @@ RETIRE_REASONS = ("eos", "budget", "cache_full", "cancelled", "preempted")
 # trace-guard side effect; the auditor passes None (its traces must not
 # count against a live engine's budget).
 
+def _ctx_kw(model, **ctx) -> dict:
+    """`state_ctx` for a patterned model's layers that keep per-slot state
+    or count per row (models/gpt.py MixerBlock); nothing for the others,
+    whose programs stay as they were."""
+    return {"state_ctx": ctx} if model.config.layer_pattern else {}
+
+
 def make_step_fn(model, sample_fn, *, on_trace=None):
     """Plain decode step: advance every live slot by one token."""
 
@@ -184,7 +192,8 @@ def make_step_fn(model, sample_fn, *, on_trace=None):
             # the unused bf16 leaves are pruned from the compiled step
             logits, _, caches = model.apply(
                 variables, tok[:, None], None, caches, pos,
-                deterministic=True, block_tables=bt)
+                deterministic=True, block_tables=bt,
+                **_ctx_kw(model, live=live))
         with jax.named_scope("sample"):
             nxt = sample_fn(logits[:, -1, :], jax.random.fold_in(rng, t))
         # dead slots: freeze the token and position (their table row is
@@ -222,7 +231,8 @@ def make_fused_step_fn(model, sample_fn, n_slots: int, table_width: int,
         with jax.named_scope("chunk_prefill"):
             clogits, _, caches = model.apply(
                 variables, ctoks, None, caches, coff, deterministic=True,
-                logits_idx=clen - 1, block_tables=bt_row)
+                logits_idx=clen - 1, block_tables=bt_row,
+                **_ctx_kw(model, slot=cslot, valid_len=clen))
         with jax.named_scope("sample"):
             first = sample_fn(clogits[:, -1, :],
                               jax.random.fold_in(rng, 2 ** 21 + t))
@@ -230,7 +240,8 @@ def make_fused_step_fn(model, sample_fn, n_slots: int, table_width: int,
         with use_quantized_params(qparams), jax.named_scope("decode"):
             logits, _, caches = model.apply(
                 variables, tok[:, None], None, caches, pos,
-                deterministic=True, block_tables=bt)
+                deterministic=True, block_tables=bt,
+                **_ctx_kw(model, live=live))
         with jax.named_scope("sample"):
             nxt = sample_fn(logits[:, -1, :], jax.random.fold_in(rng, t))
         # dead/parked slots freeze their token; parked positions point
@@ -266,7 +277,8 @@ def make_admit_fn(model, sample_fn, *, on_trace=None):
         logits, _, caches = model.apply(
             variables, prompt, None, caches, prefix_len,
             deterministic=True, logits_idx=true_len - 1,
-            block_tables=bt_row)
+            block_tables=bt_row,
+            **_ctx_kw(model, slot=slot, valid_len=true_len))
         with jax.named_scope("sample"):
             first = sample_fn(logits[:, -1, :], rng)
         tok = tok.at[slot].set(first[0])
@@ -514,6 +526,10 @@ class _Program:
     # traced arguments or ())
     inputs: Optional[tuple] = None
     tok: Any = None         # device (n_slots,) sampled tokens, once queued
+    # a patterned model's: its chunk starts a slot's recurrent state anew;
+    # the device arrays its expert layers' routing counts arrive in
+    state_reset: bool = False
+    expert_stats: Any = None
 
 
 class _WouldPreempt(Exception):
@@ -608,6 +624,30 @@ class DecodeEngine:
         self.spec_decode = (quant.resolve_gate(knob("SPEC_DECODE"),
                                                bool(spec_decode))
                             and self.spec_k > 0 and temperature == 0.0)
+        # a model with recurrent layers (`cfg.recurrent`) keeps per-slot
+        # state that is no block of the pool. Resident blocks are then
+        # NOT a prefix's state, and a rejected draft cannot be rolled
+        # back: until state snapshots exist, prefix reuse, the host tier
+        # and speculation stand down, aloud (`features_declined`, the
+        # paths log) and counted (`prefix_reuse_declined`, an admission
+        # each), never silently. A preempted sequence resumes by
+        # recomputing from its tokens.
+        self.features_declined: list[str] = []
+        self.prefix_reuse_declined = 0
+        self._prefix_asked = bool(prefix_cache)
+        if cfg.recurrent:
+            assert mesh is None, \
+                "per-slot state leaves have no sharding rule yet"
+            for name, asked in (("prefix_cache", prefix_cache),
+                                ("spec_decode", self.spec_decode),
+                                ("host_tier", host_tier)):
+                if asked:
+                    self.features_declined.append(name)
+                    paths.note(name, "declined",
+                               "recurrent layers keep per-slot state with "
+                               "no snapshot or roll-back yet")
+            prefix_cache, host_tier, host_blocks = False, False, 0
+            self.spec_decode = False
         self._rng = rng if rng is not None else jax.random.PRNGKey(0)
         self._mesh = mesh
         self._recipe = recipe
@@ -704,7 +744,13 @@ class DecodeEngine:
             with self._ctx():
                 self._qparams = jax.jit(quantize_params)(variables["params"])
 
-        caches = init_paged_cache(cfg, n_blocks, bs, dtype=self.cache_dtype)
+        caches = init_paged_cache(cfg, n_blocks, bs, dtype=self.cache_dtype,
+                                  n_slots=n_slots)
+        # an 'E' layer's cache slot carries a program's routing counts
+        # OUT (models/gpt.py merge_expert_stats): `_dispatch` takes them
+        # off the tree before it is donated to the next program
+        self._expert_layers = [i for i, kind in
+                               enumerate(cfg.layer_pattern) if kind == "E"]
         if mesh is not None:
             from distributed_pytorch_tpu.parallel import sharding as shd
             from jax.sharding import NamedSharding
@@ -798,6 +844,17 @@ class DecodeEngine:
         # tokens computed for an occupant that had left by the drain: an
         # `eos` seen one program late, a cancel while its program ran
         self.overrun_tokens = 0
+        # what a patterned model's layers did (/metrics, flight record):
+        # a slot's recurrent state began anew (an admission's first
+        # chunk); per call of an expert layer: how many held experts were
+        # hit; assignments of real rows to held and to absent experts
+        self.state_resets = 0
+        self.expert_calls = 0
+        self.experts_hit = 0
+        self.held_assignments = 0
+        self.absent_assignments = 0
+        n_held = (cfg.experts_held or (0, cfg.n_routed))[1]
+        self.expert_tokens = np.zeros((n_held,), np.int64)
         # step-level flight recorder (obs/flight.py): one record per
         # fused step in a bounded ring — the /debug/timeline payload and
         # the runs/*.jsonl post-hoc artifact
@@ -1368,6 +1425,8 @@ class DecodeEngine:
             toks = toks[-(self.max_len - 1):]
             L = len(toks)
             bs = self.block_size
+            if self._prefix_asked and not self.prefix_cache:
+                self.prefix_reuse_declined += 1   # match length 0, counted
             prefix_len, matched = self._match_prefix(toks)
             if self.prefill_chunk:
                 return self._admit_chunked(slot, toks, L, prefix_len,
@@ -1407,9 +1466,14 @@ class DecodeEngine:
                     jnp.asarray([len(suffix)], jnp.int32),
                     jnp.int32(slot), rng)
             self.caches, self.tok, self.pos, self.live, first = out
+            self.state_resets += int(self.cfg.recurrent)
             # THE admit sync boundary: the first sampled token must reach the
-            # host to stream it to the caller
-            first_tok = int(jax.device_get(first)[0])  # lint: allow(host-sync)
+            # host to stream it to the caller (a patterned model's routing
+            # counts ride the same transfer)
+            first, stats = jax.device_get(  # lint: allow(host-sync)
+                (first, self._take_expert_stats()))
+            self._count_experts(stats)
+            first_tok = int(first[0])
             self._slots[slot] = _Slot(seq_id=seq_id, tokens=toks + [first_tok],
                                       prompt_len=L, n_new=1,
                                       max_new=max_new_tokens, pos=L,
@@ -1686,6 +1750,7 @@ class DecodeEngine:
             seq_c.pos = off + take
             chunk_done = not self._is_partial(seq_c)
             prog.chunk = (slot_c, seq_c.seq_id, take, seq_c.pos)
+            prog.state_reset = self.cfg.recurrent and off == 0
             chunk_in = (
                 jnp.asarray(buf + [0] * (self.prefill_chunk - take),
                             jnp.int32)[None],
@@ -1722,6 +1787,36 @@ class DecodeEngine:
             self.caches, self.tok, self.pos = self._get_step_fn()(*args)
             self.live = live
         prog.tok, prog.inputs = self.tok, None
+        prog.expert_stats = self._take_expert_stats()
+
+    def _take_expert_stats(self) -> Optional[list]:
+        """The routing counts the program just enqueued will have written
+        into its 'E' layers' cache slots, taken off the tree: the next
+        program gets None there, and these stay readable for the drain."""
+        if not self._expert_layers:
+            return None
+        caches = list(self.caches)
+        stats = [caches[i] for i in self._expert_layers]
+        for i in self._expert_layers:
+            caches[i] = None
+        self.caches = caches
+        return stats
+
+    def _count_experts(self, stats: Optional[list]) -> tuple[int, int]:
+        """Fold one program's FETCHED routing counts (host arrays) into
+        the lifetime counters; (experts hit, absent assignments) of the
+        program."""
+        hit = absent = 0
+        for layer in stats or ():
+            tokens = layer["tokens"]                        # (calls, held)
+            self.expert_calls += tokens.shape[0]
+            self.expert_tokens += tokens.sum(axis=0)
+            self.held_assignments += int(tokens.sum())
+            hit += int((tokens > 0).sum())
+            absent += int(layer["absent"].sum())
+        self.experts_hit += hit
+        self.absent_assignments += absent
+        return hit, absent
 
     def _holds(self, slot: int, seq_id: int) -> bool:
         """Whether the occupant a program was planned for still holds its
@@ -1745,8 +1840,12 @@ class DecodeEngine:
                 sampled, accepted_h = jax.device_get(  # lint: allow(host-sync)
                     (prog.tok, acc_dev))
             else:
-                sampled = jax.device_get(prog.tok)  # lint: allow(host-sync)
+                sampled, stats = jax.device_get(  # lint: allow(host-sync)
+                    (prog.tok, prog.expert_stats))
         with phase("engine.retire", acc, step=step) as retire:
+            hit, absent = self._count_experts(
+                stats if prog.spec is None else None)
+            self.state_resets += int(prog.state_reset)
             emitted: dict[int, list] = {}
             retired: dict[int, Retired] = dict(prog.preempted)
             drafted = accepted = overrun = prefill_tokens = 0
@@ -1822,7 +1921,10 @@ class DecodeEngine:
                 preemptions=len(prog.preempted),
                 drafted=drafted, accepted=accepted,
                 overlapped=prog.overlapped,
-                drain_reason=prog.drain_reason, overrun=overrun)
+                drain_reason=prog.drain_reason, overrun=overrun,
+                **({"experts_hit": hit, "absent_assignments": absent,
+                    "state_reset": int(prog.state_reset)}
+                   if self.cfg.layer_pattern else {}))
         return StepResult(emitted=emitted, retired=retired,
                           prefill_tokens=prefill_tokens,
                           drafted=drafted, accepted=accepted)

@@ -82,13 +82,16 @@ class _OverlapDense(nn.Module):
 
     features: int
     dtype: Any = jnp.float32
+    use_bias: bool = True
+    param_dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, x):
         kernel = self.param("kernel", _DENSE_INIT,
-                            (x.shape[-1], self.features), jnp.float32)
+                            (x.shape[-1], self.features), self.param_dtype)
         bias = self.param("bias", nn.initializers.zeros,
-                          (self.features,), jnp.float32)
+                          (self.features,), self.param_dtype) \
+            if self.use_bias else None
         kd = kernel.astype(self.dtype)
         # weight-only int8 decode (ops/quant.py): when the engine's step
         # runs under use_quantized_params, the matmul reads int8 codes +
@@ -102,7 +105,7 @@ class _OverlapDense(nn.Module):
             y = maybe_overlap_matmul(x, kd, names=(self.name, "kernel"))
         if y is None:
             y = x @ kd
-        return y + bias.astype(self.dtype)
+        return y if bias is None else y + bias.astype(self.dtype)
 
 
 def _update_cache(cache_arr: jnp.ndarray, new: jnp.ndarray, pos) -> jnp.ndarray:
@@ -143,6 +146,7 @@ class GQA(nn.Module):
 
     config: LLMConfig
     attn_impl: str = "auto"
+    param_dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, x, freqs, cache: Optional[Cache] = None, pos=0, *,
@@ -150,9 +154,12 @@ class GQA(nn.Module):
         cfg = self.config
         B, T, C = x.shape
         nh, nkvh, hs = cfg.n_head, cfg.n_kv_heads, cfg.head_size
+        qw = nh * hs            # = C unless the config sets `head_dim`
+        dense = dict(use_bias=cfg.attn_bias, param_dtype=self.param_dtype)
 
-        qkv = _OverlapDense(C + 2 * nkvh * hs, x.dtype, name="c_attn")(x)
-        q, k, v = jnp.split(qkv, [C, C + nkvh * hs], axis=-1)
+        qkv = _OverlapDense(qw + 2 * nkvh * hs, x.dtype, name="c_attn",
+                            **dense)(x)
+        q, k, v = jnp.split(qkv, [qw, qw + nkvh * hs], axis=-1)
         q = q.reshape(B, T, nh, hs)
         k = k.reshape(B, T, nkvh, hs)
         v = v.reshape(B, T, nkvh, hs)
@@ -208,8 +215,8 @@ class GQA(nn.Module):
                      impl=self.attn_impl, decode=cache is not None,
                      k_scale=k_scale, v_scale=v_scale,
                      block_tables=block_tables, n_kv_heads=nkvh)
-        y = y.reshape(B, T, C)
-        y = _OverlapDense(C, x.dtype, name="c_proj")(y)
+        y = y.reshape(B, T, qw)
+        y = _OverlapDense(C, x.dtype, name="c_proj", **dense)(y)
         y = nn.Dropout(cfg.dropout, deterministic=deterministic)(y)
         return y, new_cache
 

@@ -32,8 +32,10 @@ import jax
 import jax.numpy as jnp
 
 from distributed_pytorch_tpu.config import LLMConfig
-from distributed_pytorch_tpu.models.attention import Attention, init_attn_cache
-from distributed_pytorch_tpu.models.mlp import MLP, MoE
+from distributed_pytorch_tpu.models.attention import (GQA, Attention,
+                                                      init_attn_cache)
+from distributed_pytorch_tpu.models.mlp import MLP, MoE, RoutedExperts
+from distributed_pytorch_tpu.models.ssm import Mamba2, init_ssm_cache
 from distributed_pytorch_tpu.obs import paths
 from distributed_pytorch_tpu.ops.losses import (fused_cross_entropy,
                                                 sp_fused_cross_entropy,
@@ -41,6 +43,68 @@ from distributed_pytorch_tpu.ops.losses import (fused_cross_entropy,
 from distributed_pytorch_tpu.ops.rope import precompute_rope_freqs, slice_rows
 
 _EMBED_INIT = nn.initializers.normal(stddev=0.02)
+
+
+class RMSNorm(nn.Module):
+    """x / rms(x) * weight, the mean in float32."""
+
+    eps: float = 1e-5
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        w = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                       self.param_dtype)
+        xf = x.astype(jnp.float32)
+        xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                                + self.eps)
+        return (xf * w.astype(jnp.float32)).astype(x.dtype)
+
+
+def merge_expert_stats(before: Optional[dict], new: dict) -> dict:
+    """An 'E' layer's cache slot carries what its calls of ONE program
+    routed: a row a call (a fused step makes two)."""
+    if before is None:
+        return new
+    return {k: jnp.concatenate([before[k], new[k]]) for k in new}
+
+
+class MixerBlock(nn.Module):
+    """One layer of a patterned model: `x + mixer(RMSNorm(x))`, the mixer
+    one of 'M' (models/ssm.py), 'E' (models/mlp.py RoutedExperts) or '*'
+    (GQA). What each keeps between calls sits in the layer's cache slot:
+    per-slot state leaves, this program's routing counts, block pools.
+    `state_ctx` (the engine's: which rows are live, or which slot a chunk
+    belongs to and how many of its rows are real) reaches the two kinds
+    that have no null block to land a pad in."""
+
+    config: LLMConfig
+    kind: str
+    attn_impl: str = "auto"
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, freqs, cache=None, pos=0, block_tables=None,
+                 state_ctx=None):
+        cfg = self.config
+        pd = self.param_dtype
+        h = RMSNorm(cfg.norm_eps, pd, name="norm")(x)
+        if self.kind == "M":
+            y, new_cache = Mamba2(cfg, pd, name="ssm")(h, cache, pos,
+                                                       state_ctx)
+        elif self.kind == "E":
+            mask = None
+            if state_ctx is not None:
+                mask = state_ctx["live"] if "live" in state_ctx else \
+                    jnp.arange(x.shape[1]) < state_ctx["valid_len"][0]
+            y, stats = RoutedExperts(cfg, pd, name="moe")(h, mask)
+            new_cache = None if stats is None \
+                else merge_expert_stats(cache, stats)
+        else:
+            y, new_cache = GQA(cfg, self.attn_impl, pd, name="attn")(
+                h, freqs, cache, pos, deterministic=True,
+                block_tables=block_tables)
+        return x + y, new_cache, jnp.float32(0.0)
 
 
 class Block(nn.Module):
@@ -110,11 +174,15 @@ class LLM(nn.Module):
     config: LLMConfig
     compute_dtype: Any = jnp.float32
     attn_impl: str = "auto"
+    # a patterned model (`config.layer_pattern`) creates its parameters
+    # in this dtype: bf16 weights that never exist in float32
+    param_dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, idx, targets=None, caches=None, pos=0, *,
                  deterministic: bool = True, logits_idx=None,
-                 block_tables=None, all_logits: bool = False):
+                 block_tables=None, all_logits: bool = False,
+                 state_ctx=None):
         """`pos` is the global position of idx[:, 0] — a static int, a
         traced scalar, or a per-sequence (B,) array (slot-based ragged
         decode; each sequence in the batch sits at its own cache
@@ -125,14 +193,20 @@ class LLM(nn.Module):
         speculative verify step scores all K+1 draft positions at once).
         `block_tables` (B, max_blocks) int32 marks the caches as PAGED
         pools (init_paged_cache); reads and writes then indirect through
-        the table (ops/block_pool.py)."""
+        the table (ops/block_pool.py). `state_ctx` is the engine's word
+        to the layers of a patterned model that keep per-slot state or
+        count per-row (`MixerBlock`): {"live": (B,) bool} for one token
+        of every slot, {"slot": i, "valid_len": (1,)} for a chunk of one
+        sequence."""
         cfg = self.config
         B, T = idx.shape
         dt = self.compute_dtype
+        patterned = bool(cfg.layer_pattern)
+        pd = self.param_dtype if patterned else jnp.float32
 
         tkn_emb = nn.Embed(cfg.vocab_size, cfg.n_embd,
                            embedding_init=_EMBED_INIT,
-                           param_dtype=jnp.float32, dtype=dt, name="tkn_emb")
+                           param_dtype=pd, dtype=dt, name="tkn_emb")
         x = tkn_emb(idx)
         freqs = None
 
@@ -182,16 +256,29 @@ class LLM(nn.Module):
             new_caches = []
             total_aux = jnp.float32(0.0)
             for i in range(cfg.n_layer):
-                blk = block_cls(cfg, self.attn_impl, deterministic,
-                                remat_attn, name=f"block_{i}")
-                x, new_cache, aux = blk(x, freqs, caches[i], pos,
-                                        block_tables=block_tables)
+                if patterned:
+                    blk = MixerBlock(cfg, cfg.layer_pattern[i],
+                                     self.attn_impl, pd, name=f"block_{i}")
+                    x, new_cache, aux = blk(x, freqs, caches[i], pos,
+                                            block_tables, state_ctx)
+                else:
+                    blk = block_cls(cfg, self.attn_impl, deterministic,
+                                    remat_attn, name=f"block_{i}")
+                    x, new_cache, aux = blk(x, freqs, caches[i], pos,
+                                            block_tables=block_tables)
                 new_caches.append(new_cache)
                 total_aux = total_aux + aux
 
-        x = nn.LayerNorm(dtype=dt, param_dtype=jnp.float32, name="ln_f")(x)
+        if patterned:
+            x = RMSNorm(cfg.norm_eps, pd, name="ln_f")(x)
+        else:
+            x = nn.LayerNorm(dtype=dt, param_dtype=jnp.float32,
+                             name="ln_f")(x)
+        head = None if cfg.tie_head else self.param(
+            "lm_head", _EMBED_INIT, (cfg.vocab_size, cfg.n_embd), pd)
 
         if targets is not None:
+            assert head is None, "the training loss runs the tied head only"
             # Weight-tied CE with ignore_index=-1 (reference :559-560, :689),
             # fp32-accumulated. The fused path never materializes the
             # (B, T, V) logits (ops/losses.py); under a live 'seq' axis the
@@ -296,8 +383,11 @@ class LLM(nn.Module):
             from distributed_pytorch_tpu.ops.quant import \
                 maybe_quantized_matmul
             with jax.named_scope("lm_head"):
-                logits = maybe_quantized_matmul(
-                    sel, ("tkn_emb", "embedding"), transpose_b=True)
+                if head is not None:
+                    logits = jnp.einsum("btc,vc->btv", sel, head.astype(dt))
+                else:
+                    logits = maybe_quantized_matmul(
+                        sel, ("tkn_emb", "embedding"), transpose_b=True)
                 if logits is None:
                     logits = tkn_emb.attend(sel)   # (B, 1, V)
             loss = None
@@ -322,13 +412,25 @@ def init_cache(config: LLMConfig, batch_size: int,
 
 
 def init_paged_cache(config: LLMConfig, n_blocks: int, block_size: int,
-                     dtype=jnp.float32):
+                     dtype=jnp.float32, n_slots: int = 0):
     """Per-layer paged KV-cache pytree: one (n_blocks, block_size, ...)
     pool set per layer, shared by every sequence through per-sequence
     block tables (engine/decode.py owns the tables; one table serves all
     layers because block ids are allocated for the whole layer stack at
-    once). Pass the tables to `LLM.__call__(block_tables=...)`."""
+    once). Pass the tables to `LLM.__call__(block_tables=...)`.
+
+    A patterned model holds two kinds of state in the one tree: block
+    pools for its '*' layers, a row a slot (`n_slots`) of convolution tail
+    and state for its 'M' layers (models/ssm.py), nothing for 'E' layers
+    (their slot carries a program's routing counts out, never in)."""
     from distributed_pytorch_tpu.models.attention import init_paged_attn_cache
+    if config.layer_pattern:
+        assert n_slots > 0 or not config.recurrent, \
+            "state-space layers keep a row a slot: pass n_slots"
+        return [init_ssm_cache(config, n_slots, dtype) if kind == "M"
+                else init_paged_attn_cache(config, n_blocks, block_size,
+                                           dtype) if kind == "*" else None
+                for kind in config.layer_pattern]
     return [init_paged_attn_cache(config, n_blocks, block_size, dtype)
             for _ in range(config.n_layer)]
 

@@ -62,7 +62,7 @@ TPU-first design (SURVEY §7 hard part (a)):
 from __future__ import annotations
 
 import math
-from typing import Callable
+from typing import Any, Callable
 
 import flax.linen as nn
 import jax
@@ -88,6 +88,7 @@ def _activation(name: str) -> Callable[[jnp.ndarray], jnp.ndarray]:
         "sigmoid": jax.nn.sigmoid,
         "lrelu": lambda x: jax.nn.leaky_relu(x, negative_slope=0.01),
         "tanh": jnp.tanh,
+        "relu2": lambda x: jnp.square(jax.nn.relu(x)),
     }
     return table.get(name, lambda x: jax.nn.gelu(x, approximate=False))
 
@@ -382,3 +383,105 @@ class MoE(nn.Module):
 
         y = (shared_out + routed_out).reshape(B, T, C)
         return y, aux_loss.astype(jnp.float32) * sw
+
+
+def route_sigmoid(scores_in: jnp.ndarray, gate: jnp.ndarray,
+                  bias: jnp.ndarray, k: int, scale: float):
+    """The router of the 'E' layers. s = sigmoid(x W_g) in float32 over
+    every routed expert; the top k of s + b are chosen (the correction
+    bias moves the SELECTION only); their weights are the unbiased s of
+    the chosen, divided by their sum, times `scale`. Returns (ids (N, k),
+    weights (N, k) float32)."""
+    # float32 in earnest: a TPU rounds a float32 product's operands to
+    # bfloat16 unless told otherwise, and the selection is discontinuous
+    s = jax.nn.sigmoid(jnp.dot(scores_in.astype(jnp.float32),
+                               gate.astype(jnp.float32),
+                               precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), k)
+    w = jnp.take_along_axis(s, idx, axis=1)
+    return idx, w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20) * scale
+
+
+class RoutedExperts(nn.Module):
+    """An 'E' layer of a patterned model: sigmoid-routed ungated experts
+    of which this chip holds a share, plus one shared expert of another
+    width that every token takes.
+
+    The router is as wide as the model's routed experts (`cfg.n_routed`)
+    and picks `cfg.n_act_routed` of them; `cfg.experts_held` = (first,
+    count) says which of them live here (`experts_up` / `experts_down`
+    hold `count`). A token's result is the part its held experts give:
+    what the absent ones would add is left out, here as in a deployment
+    before the exchange that adds the shares up. The routed part runs the
+    grouped kernels of ops/grouped_matmul.py (`held_experts_ffn`); the
+    shared expert is two plain matmuls (its width differs, so it cannot
+    ride the grouped kernel as a group).
+
+    With `row_mask` (N,) only the rows that are real are sent to routed
+    experts (the others get the shared expert's part alone), and the layer
+    also returns what the routing did for them: tokens a held expert,
+    assignments to absent experts (`stats`).
+    """
+
+    config: LLMConfig
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, row_mask=None):
+        cfg = self.config
+        B, T, C = x.shape
+        dt = x.dtype
+        pd = self.param_dtype
+        F = cfg.up_dim
+        Fs = cfg.shared_up_dim or F
+        first, n_held = cfg.experts_held or (0, cfg.n_routed)
+        act = _activation(cfg.non_linearity)
+        gate = self.param("gate", _DENSE_INIT, (C, cfg.n_routed), pd)
+        # drawn, not zeros: a trained model's correction bias moves the
+        # selection, and a zero one would leave that path untested
+        bias = self.param("gate_bias", nn.initializers.normal(stddev=0.1),
+                          (cfg.n_routed,), jnp.float32)
+        # (held, F, C), out by in: ops/grouped_matmul.py says why
+        w_up = self.param("experts_up", _DENSE_INIT, (n_held, F, C), pd)
+        w_down = self.param("experts_down", _DENSE_INIT, (n_held, F, C), pd)
+        s_up = self.param("shared_up", _DENSE_INIT, (C, Fs), pd)
+        s_down = self.param("shared_down", _DENSE_INIT, (Fs, C), pd)
+
+        x_flat = x.reshape(-1, C)
+        with jax.named_scope("moe_route"):
+            idx, w = route_sigmoid(x_flat, gate, bias, cfg.n_act_routed,
+                                   cfg.routed_scale)
+        # rows that are not real (a chunk's pads, dead slots) are routed
+        # nowhere: identical garbage rows all pick the same six experts and
+        # would cost those experts tile after tile of weight reads (128 pad
+        # rows: ~2 ms of a 38 ms chunk-carrying step, more or less by the
+        # seed's luck in which of the six are held; my chip run, PR 33)
+        sent = idx if row_mask is None else \
+            jnp.where(row_mask[:, None], idx, -1)
+        with jax.named_scope("moe_experts"):
+            if cfg.non_linearity.lower() == "relu2":
+                from distributed_pytorch_tpu.ops.grouped_matmul import \
+                    held_experts_ffn
+                routed = held_experts_ffn(x_flat, sent, w, w_up, w_down,
+                                          first=first)
+            else:
+                local = sent - first
+                comb = (jax.nn.one_hot(local, n_held, dtype=jnp.float32)
+                        * w[..., None]).sum(axis=1)              # (N, held)
+                h = act(jnp.einsum("nc,efc->enf", x_flat, w_up.astype(dt)))
+                routed = jnp.einsum("enf,efc,ne->nc", h, w_down.astype(dt),
+                                    comb.astype(dt)).astype(jnp.float32)
+        with jax.named_scope("moe_shared"):
+            shared = act(x_flat @ s_up.astype(dt)) @ s_down.astype(dt)
+        y = (routed + shared.astype(jnp.float32)).astype(dt).reshape(B, T, C)
+        stats = None
+        if row_mask is not None:
+            local = idx - first
+            held = (local >= 0) & (local < n_held) & row_mask[:, None]
+            tokens = jnp.zeros((n_held,), jnp.int32).at[
+                jnp.where(held, local, n_held).reshape(-1)].add(
+                    1, mode="drop")
+            stats = {"tokens": tokens[None],
+                     "absent": (jnp.sum(row_mask) * idx.shape[1]
+                                - jnp.sum(tokens)).astype(jnp.int32)[None]}
+        return y, stats

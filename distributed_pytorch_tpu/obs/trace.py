@@ -341,6 +341,30 @@ SCOPES = {
 }
 
 
+#: The scopes of a patterned model's mixers (`LLMConfig.layer_pattern`),
+#: under the flax module names `ssm` and `moe`. A table of their own for
+#: one reason: the benchmark's `lib/trace_spans.SCOPE_NAMES` is held to
+#: `SCOPES` by a test of the benchmark, and the PR that brought these
+#: (33, a `model_config`) may add benchmark files and edit none. They are
+#: read through `benchmark/readers/trace_scope_named_ms.py`, which takes
+#: the names as an argument; a `benchmark` PR appends them to SCOPE_NAMES
+#: and merges the two tables.
+MIXER_SCOPES = {
+    "ssm_conv": "the depthwise causal convolution and its silu, chunk and "
+                "one-token forms (models/ssm.py)",
+    "ssm_scan": "the chunked state-space scan of a prefill chunk "
+                "(ops/ssm_scan.py ssd_chunked)",
+    "ssm_step": "the one-token recurrence of a decode step "
+                "(ops/ssm_scan.py ssm_step)",
+    "moe_route": "sigmoid scores, bias-corrected top-k, renormalised "
+                 "weights (models/mlp.py route_sigmoid)",
+    "moe_experts": "the held routed experts: packing, the two "
+                   "expert_matmul kernels, the combine "
+                   "(ops/grouped_matmul.py held_experts_ffn)",
+    "moe_shared": "the shared expert's two matmuls (models/mlp.py)",
+}
+
+
 class phase:
     """One host phase: a TraceMe on the profiler's clock (one atomic load
     when no profile runs; its stats are encoded only while one does) whose

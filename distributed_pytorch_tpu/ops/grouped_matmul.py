@@ -472,3 +472,168 @@ def grouped_dispatch(x_flat: jnp.ndarray, topk_idx: jnp.ndarray,
         out_specs=out_spec,
     )
     return body(x_flat, topk_idx, topk_gates, experts_fc, experts_proj)
+
+
+# ---------------------------------------------------------------------------
+# a chip's share of the experts, at serving batch sizes
+# ---------------------------------------------------------------------------
+# The dispatch above is the trainer's: every assignment is local or pads the
+# last group, K is resident whole and the out-feature tile is a multiple of
+# 128. A serving step hands an expert layer 64 to 320 tokens, of which a
+# held expert sees a handful: the work is reading each HIT expert's two
+# matrices once, at the HBM rate. So: one token tile an expert (nearly
+# always), the weights streamed in large blocks under it, assignments to
+# experts this chip does not hold dropped before the packing (they cost
+# nothing, not even a pad row), and the trailing unused tiles mapped onto
+# the last used block so that they move no bytes. Widths need not be
+# multiples of 128: the 1856-wide hidden axis is a block's full extent, and
+# it is never an array's minor axis. The up matrices are held (E, F, C), out
+# by in, for that reason: the device lays a parameter out with a minor axis
+# that is a multiple of 128 where it has one, so an (E, C, 1856) operand
+# reaches the kernel through a whole-stack relayout copy (device-free
+# compile, ISSUE 33: 639 MB a call), and an (E, 1856, C) one as it lies.
+
+def _held_up_kernel(g_ref, n_ref, x_ref, w_ref, o_ref, acc_ref, *, nk: int):
+    del g_ref
+    i, k = pl.program_id(0), pl.program_id(1)
+    used = i < n_ref[0]
+
+    @pl.when(jnp.logical_and(used, k == 0))
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(used)
+    def _():
+        acc_ref[...] += _dot_nt(x_ref[...], w_ref[0])
+
+    @pl.when(k == nk - 1)
+    def _():
+        h = jnp.maximum(acc_ref[...], 0.0)       # relu(.)^2, in float32
+        o_ref[...] = jnp.where(used, h * h, 0.0).astype(o_ref.dtype)
+
+
+def _held_down_kernel(g_ref, n_ref, h_ref, w_ref, s_ref, o_ref):
+    del g_ref
+    used = pl.program_id(0) < n_ref[0]
+
+    @pl.when(used)
+    def _():
+        o_ref[...] = (_dot(h_ref[...], w_ref[0]) * s_ref[...]
+                      ).astype(o_ref.dtype)
+
+    @pl.when(jnp.logical_not(used))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def _split(n: int, parts: int, step: int) -> int:
+    """n // parts where that is a whole multiple of `step`, else n."""
+    return n // parts if n % (parts * step) == 0 else n
+
+
+def _held_up_call(x_pad, w, tile_group, n_used, bm, interpret):
+    P, K = x_pad.shape
+    F = w.shape[1]
+    bk = _split(K, 3, 128)
+    nk = K // bk
+    # an unused tile asks for the block the last used one held: no DMA
+    last = nk - 1
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(P // bm, nk),
+        in_specs=[
+            pl.BlockSpec((bm, bk), lambda i, k, g, n: (
+                i, jnp.where(i < n[0], k, last))),
+            pl.BlockSpec((1, F, bk), lambda i, k, g, n: (
+                g[i], 0, jnp.where(i < n[0], k, last))),
+        ],
+        out_specs=pl.BlockSpec((bm, F), lambda i, k, g, n: (i, 0)),
+        scratch_shapes=[pltpu.VMEM((bm, F), jnp.float32)])
+    return pl.pallas_call(
+        functools.partial(_held_up_kernel, nk=nk), grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((P, F), x_pad.dtype),
+        compiler_params=compat.tpu_compiler_params(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        name="expert_matmul_up", interpret=interpret,
+    )(tile_group, n_used, x_pad, w)
+
+
+def _held_down_call(h, w, gates, tile_group, n_used, bm, interpret):
+    P, F = h.shape
+    _, _, C = w.shape
+    bn = _split(C, 3, 128)
+    nn_ = C // bn
+    last = nn_ - 1
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(P // bm, nn_),
+        in_specs=[
+            pl.BlockSpec((bm, F), lambda i, j, g, n: (i, 0)),
+            pl.BlockSpec((1, F, bn), lambda i, j, g, n: (
+                g[i], 0, jnp.where(i < n[0], j, last))),
+            pl.BlockSpec((bm, 1), lambda i, j, g, n: (i, 0)),
+        ],
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, g, n: (i, j)))
+    return pl.pallas_call(
+        _held_down_kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((P, C), jnp.float32),
+        compiler_params=compat.tpu_compiler_params(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        name="expert_matmul_down", interpret=interpret,
+    )(tile_group, n_used, h, w, gates)
+
+
+def held_tile_rows(n_tokens: int) -> int:
+    """Token rows a tile: a held expert sees a few tokens of a decode
+    batch and a dozen of a prefill chunk, and a second tile of one expert
+    reads its matrices a second time."""
+    return 16 if n_tokens <= 64 else 32
+
+
+def held_experts_ffn(x_flat: jnp.ndarray, topk_idx: jnp.ndarray,
+                     topk_gates: jnp.ndarray, w_up: jnp.ndarray,
+                     w_down: jnp.ndarray, *, first: int,
+                     interpret: Optional[bool] = None) -> jnp.ndarray:
+    """sum over a token's top-k of gate * W_down[e] relu(W_up[e] x)^2, for
+    the experts e in [first, first + n_held) that `w_up` (n_held, F, C: out
+    by in) and `w_down` (n_held, F, C) hold. `topk_idx` (N, k) are ids over ALL
+    routed experts; what the absent ones would add is left out. Dropless.
+    Returns (N, C) float32."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    N, C = x_flat.shape
+    k = topk_idx.shape[1]
+    n_held = w_up.shape[0]
+    bm = held_tile_rows(N)
+    A = N * k
+    n_tiles = -(-A // bm) + n_held
+    P = n_tiles * bm
+
+    e = topk_idx.reshape(-1).astype(jnp.int32) - first
+    held = (e >= 0) & (e < n_held)
+    e = jnp.where(held, e, n_held)            # absent: sorted last, dropped
+    tok = jnp.repeat(jnp.arange(N, dtype=jnp.int32), k)
+    order = jnp.argsort(e, stable=True)
+    se = e[order]
+    counts = jnp.zeros((n_held + 1,), jnp.int32).at[e].add(1)
+    padded = -(-counts // bm) * bm
+    pstart = jnp.cumsum(padded) - padded
+    starts = jnp.cumsum(counts) - counts
+    slot = pstart[se] + jnp.arange(A, dtype=jnp.int32) - starts[se]
+    slot = jnp.where(se < n_held, slot, P)    # out of range: dropped
+    row_tok = jnp.zeros((P,), jnp.int32).at[slot].set(tok[order],
+                                                      mode="drop")
+    row_gate = jnp.zeros((P, 1), jnp.float32).at[slot, 0].set(
+        topk_gates.reshape(-1).astype(jnp.float32)[order], mode="drop")
+    tile_start = pstart // bm
+    n_used = tile_start[n_held]
+    t = jnp.arange(n_tiles, dtype=jnp.int32)
+    group = jnp.clip(jnp.searchsorted(tile_start[:n_held], t, side="right")
+                     - 1, 0, n_held - 1).astype(jnp.int32)
+    group = jnp.where(t < n_used, group, group[jnp.maximum(n_used - 1, 0)])
+    n_used = n_used.reshape(1)
+
+    dt = x_flat.dtype
+    h = _held_up_call(x_flat[row_tok], w_up.astype(dt), group, n_used, bm,
+                      interpret)
+    y = _held_down_call(h, w_down.astype(dt), row_gate, group, n_used, bm,
+                        interpret)
+    return jnp.zeros((N, C), jnp.float32).at[row_tok].add(y)

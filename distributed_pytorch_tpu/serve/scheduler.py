@@ -305,6 +305,40 @@ class Scheduler:
             "serve_engine_overlap_share",
             lambda: getattr(self.engine, "overlap_share", 0.0),
             "fraction of step programs dispatched behind a running one")
+        # a patterned model's layers (engine/decode.py): how many of the
+        # held experts a call of an expert layer hits (the weight bytes
+        # it must read), how evenly the held experts are loaded, what
+        # share of the routing falls on experts another chip holds, how
+        # often a slot's recurrent state began anew, and how many
+        # admissions were refused a prefix match because resident blocks
+        # are not a recurrent model's state. 0 for every other model.
+        eng = self.engine
+        self.metrics.register_gauge(
+            "serve_experts_hit_per_call",
+            lambda: getattr(eng, "experts_hit", 0)
+            / max(getattr(eng, "expert_calls", 0), 1),
+            "held experts that received a token, per expert-layer call")
+        self.metrics.register_gauge(
+            "serve_expert_tokens_max_over_mean",
+            lambda: (float(eng.expert_tokens.max())
+                     / max(float(eng.expert_tokens.mean()), 1.0))
+            if getattr(eng, "expert_calls", 0) else 0.0,
+            "most-loaded held expert's tokens over the mean of the held")
+        self.metrics.register_gauge(
+            "serve_expert_absent_assignments_share",
+            lambda: getattr(eng, "absent_assignments", 0)
+            / max(getattr(eng, "absent_assignments", 0)
+                  + getattr(eng, "held_assignments", 0), 1),
+            "share of routed assignments that fall on experts not held")
+        self.metrics.register_gauge(
+            "serve_state_resets_total",
+            lambda: getattr(eng, "state_resets", 0),
+            "recurrent state started anew (one per admission's first chunk)")
+        self.metrics.register_gauge(
+            "serve_prefix_reuse_declined_total",
+            lambda: getattr(eng, "prefix_reuse_declined", 0),
+            "admissions refused a prefix match: recurrent state has no "
+            "snapshot")
         # host-RAM KV tier (ops/kv_tier.py via engine.host_tier): live
         # occupancy/save-rate gauges here, block-movement counters
         # delta-synced in _tier_sync() after every engine call. Tier

@@ -86,6 +86,16 @@ def analyze(trace_dir: str, top: int = 25) -> None:
         scopes = trace_scope_ms.table(sl, [r"step\("])
         if scopes is not None:
             trace_scope_ms.say_table(scopes)
+            # a patterned model's mixers have scopes of their own
+            # (obs/trace.py MIXER_SCOPES): the same table over them too
+            from benchmark.readers import trace_scope_named_ms
+            from distributed_pytorch_tpu.obs.trace import MIXER_SCOPES
+            named = trace_scope_named_ms.table(
+                ("ssm", "norm", *MIXER_SCOPES), [r"step\("], trace_dir)
+            if named is not None and set(named["owners"]) & (
+                    set(MIXER_SCOPES) | {"ssm"}):
+                print("with the mixers' scopes (obs/trace.py MIXER_SCOPES):")
+                trace_scope_ms.say_table(named)
         idle = trace_idle_owner.table(sl, trace_spans.PHASE_LAYERS)
         if idle is not None:
             trace_idle_owner.say_table(idle)

@@ -54,10 +54,14 @@ thousands of requests share a system prompt:
   (`_get_fused_step_fn`). The chunk buffer is a fixed (1, N) trace; the
   slot, write offset, and valid length are TRACED arguments — no new
   traces per prompt length, and the pow2 buckets retire to a chunk-size
-  pad. Decode tokens get strict priority: the per-step prefill take is
-  the chunk budget minus the live decode count (floored at one block so
-  prefill can't starve), rounded down to a whole number of blocks so
-  every chunk writes at a block-aligned offset. While a slot prefills it
+  pad. The program computes all N chunk rows beside the decode rows
+  whatever they hold, so a step's cost does not depend on the take: the
+  oldest partial prompt fills the buffer, min(N, what is left of it) ids
+  (`_next_chunk`), and a prompt of up to N ids is ONE chunk-carrying
+  program. N is a multiple of the block size and a reused prefix is whole
+  blocks, so every chunk writes at a block-aligned offset. What a chunk
+  costs the live streams is set by N, the program's shape; how full the
+  programs' chunk rows ran is `chunk_fill_share`. While a slot prefills it
   is PARKED: live=False (token frozen) and its device position points at
   the always-empty last table column, so the fused decode write lands in
   the null block, never in its real cache. Per-slot prefill progress
@@ -209,11 +213,11 @@ def make_step_fn(model, sample_fn, *, on_trace=None):
 def make_fused_step_fn(model, sample_fn, n_slots: int, table_width: int,
                        *, on_trace=None):
     """The chunked-prefill step: ONE program that runs <=N prefill tokens
-    of one partial prompt plus every live decode token. The chunk buffer
-    is a fixed (1, prefill_chunk) shape; the target slot, block-aligned
-    write offset, and valid length are traced, so the whole serving mix
-    shares this single trace (the chunked analogue of `prefix_len` being
-    traced in the wave admit)."""
+    of one partial prompt plus every live decode token, at the cost of N
+    rows whatever the take. The chunk buffer is a fixed (1, prefill_chunk)
+    shape; the target slot, block-aligned write offset, and valid length
+    are traced, so the whole serving mix shares this single trace (the
+    chunked analogue of `prefix_len` being traced in the wave admit)."""
     W = table_width
 
     def fused_step(variables, caches, tok, pos, live, bt, rng, t,
@@ -555,11 +559,12 @@ class DecodeEngine:
     disables content-addressed reuse (the A/B baseline).
 
     `prefill_chunk=N` fuses Sarathi-style chunked prefill into the step
-    (module docstring): each fused step runs <=N prefill tokens of the
-    oldest partial prompt plus all live decode tokens in ONE trace —
-    bounded ITL under prefill-heavy load. N must be a multiple of
-    `block_size`; pick N >= n_slots + block_size so decode priority
-    leaves the prefill budget at least one block. 0 (default) keeps the
+    (module docstring): each fused step runs the next min(N, what is
+    left) prompt ids of the oldest partial prompt plus all live decode
+    tokens in ONE trace — bounded ITL under prefill-heavy load. N must be
+    a multiple of `block_size`. The chunk buffer is N rows whatever it
+    holds, so N sets what a chunk-carrying step costs: a smaller N is a
+    cheaper step and more of them a prompt. 0 (default) keeps the
     all-or-nothing bucketed wave prefill (the A/B baseline).
 
     Quantized serving (ops/quant.py) is unchanged: `cache_dtype='int8'`
@@ -699,9 +704,9 @@ class DecodeEngine:
             collections.OrderedDict()
         self._digest_cap = max(64, 8 * self._digest_k)
 
-        # chunked prefill (module docstring): the per-step prefill token
-        # budget. Chunks must be whole blocks so every chunk's write
-        # offset stays block-aligned (paged_update's prefill contract).
+        # chunked prefill (module docstring): the rows of the fused step's
+        # chunk buffer. Whole blocks, so every chunk's write offset stays
+        # block-aligned (paged_update's prefill contract).
         if prefill_chunk:
             assert prefill_chunk % bs == 0 and prefill_chunk >= bs, (
                 f"prefill_chunk {prefill_chunk} must be a positive "
@@ -841,6 +846,9 @@ class DecodeEngine:
         # lookahead accounting (`overlap_share`, /metrics, flight record)
         self.overlapped_programs = 0  # dispatched behind a running one
         self.drain_reasons: dict[str, int] = {}  # the others, by why not
+        # chunk accounting (`chunk_fill_share`, /metrics, /debug/timeline)
+        self.chunk_programs = 0       # drained programs that carried a chunk
+        self.chunked_prompts = 0      # prompts whose last chunk has run
         # tokens computed for an occupant that had left by the drain: an
         # `eos` seen one program late, a cancel while its program ran
         self.overrun_tokens = 0
@@ -1130,6 +1138,22 @@ class DecodeEngine:
         one — the device had its next program queued before it finished
         the last. The rest are counted by cause in `drain_reasons`."""
         return self.overlapped_programs / self._t if self._t else 0.0
+
+    @property
+    def chunk_fill_share(self) -> float:
+        """Lifetime share of the chunk rows the fused programs computed
+        that held a real prompt id: ids prefilled / (chunk-carrying
+        programs x `prefill_chunk`). The rest were pads, computed all the
+        same. 0 for a wave engine."""
+        rows = self.chunk_programs * self.prefill_chunk
+        return self.prefilled_tokens / rows if rows else 0.0
+
+    @property
+    def chunk_programs_per_prompt(self) -> float:
+        """Lifetime chunk-carrying programs per prompt chunked in: 1 when
+        no prompt's suffix is longer than `prefill_chunk`."""
+        return (self.chunk_programs / self.chunked_prompts
+                if self.chunked_prompts else 0.0)
 
     @property
     def free_slots(self) -> list[int]:
@@ -1541,10 +1565,9 @@ class DecodeEngine:
 
     def _next_chunk(self, preempted: dict,
                     ahead: bool) -> Optional[tuple[int, int]]:
-        """Pick this step's prefill work: the OLDEST partial prompt gets
-        the leftover token budget (decode tokens have strict priority),
-        rounded down to whole blocks and floored at one block so a
-        saturated slot table can't starve prefill forever. Grows the
+        """Pick this step's prefill work: the OLDEST partial prompt fills
+        the chunk buffer, `min(prefill_chunk, what is left of it)` ids —
+        the rows the fused program computes whatever they hold. Grows the
         slot's block list to cover the chunk, preempting youngest-first
         when the pool is dry (the partial itself is usually youngest —
         then the next-oldest partial gets its turn). Returns
@@ -1560,11 +1583,7 @@ class DecodeEngine:
                 return None
             slot = min(partials)[1]
             seq = self._slots[slot]
-            remaining = len(seq.suffix) - seq.suffix_done
-            avail = self.prefill_chunk - len(self._live_slots())
-            avail -= avail % bs
-            avail = min(max(avail, bs), self.prefill_chunk)
-            take = min(avail, remaining)
+            take = min(self.prefill_chunk, len(seq.suffix) - seq.suffix_done)
             need = -(-(seq.prefix_len + seq.suffix_done + take) // bs)
             ok = True
             while len(seq.blocks) < need:
@@ -1856,6 +1875,10 @@ class DecodeEngine:
                 # no-op)
                 slot_c, sid_c, prefill_tokens, rows = prog.chunk
                 self.prefilled_tokens += prefill_tokens
+                self.chunk_programs += 1
+                # only a prompt's last chunk makes its slot an occupant
+                self.chunked_prompts += int(
+                    prog.occupants.get(slot_c) == sid_c)
                 if self._holds(slot_c, sid_c):
                     seq_c = self._slots[slot_c]
                     full = min(rows, len(seq_c.blocks) * self.block_size) \

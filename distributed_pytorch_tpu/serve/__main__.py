@@ -85,8 +85,9 @@ def build_args(argv=None):
                    help="fuse Sarathi-style chunked prefill into the "
                         "decode step: <=N prefill tokens ride each fused "
                         "step so live streams never stall on a prompt "
-                        "(multiple of --kv-block; pick N >= slots + "
-                        "kv-block). 0 = legacy all-or-nothing wave "
+                        "(multiple of --kv-block; a chunk-carrying step "
+                        "costs N rows however full). 0 = legacy "
+                        "all-or-nothing wave "
                         "prefill (the A/B baseline)")
     p.add_argument("--aot-store", "--aot_store", dest="aot_store",
                    type=str, default="",

@@ -33,7 +33,7 @@ LATENCY_BUCKETS = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
                    0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0, 120.0)
 
 # Per-step prefill token counts (chunked prefill): pow2 grid up to the
-# largest plausible chunk budget — the knob this histogram tunes.
+# largest plausible `prefill_chunk` — the knob this histogram tunes.
 PREFILL_TOKEN_BUCKETS = (0, 8, 16, 32, 64, 128, 256, 512, 1024, 2048, 4096)
 
 # Host-tier promote transport sizes (ops/kv_tier.py): pow4 byte grid from
@@ -414,9 +414,12 @@ class ServeMetrics:
             "serve_queue_wait_seconds", "submit to slot admission")
         # chunked-prefill observability (round 12): the per-step prefill
         # token distribution is the chunk-size knob's tuning signal —
-        # p50 near the chunk budget means prefill-bound, near 0 means the
-        # budget is slack — and decode_stall tracks how long live decode
-        # streams sat behind monolithic (wave) prefill work.
+        # p50 near `prefill_chunk` means every step carries a full chunk
+        # (prefill-bound), near 0 means few steps carry one; a step that
+        # carries one costs the same however full (the engine's
+        # `serve_chunk_fill_share` says how full they ran) — and
+        # decode_stall tracks how long live decode streams sat behind
+        # monolithic (wave) prefill work.
         self.prefill_tokens_per_step = Histogram(
             "serve_prefill_tokens_per_step",
             "prefill tokens executed per fused step (chunked mode) or "

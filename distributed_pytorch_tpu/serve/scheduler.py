@@ -22,10 +22,11 @@ module gives the host side:
   bucket, so grouping maximizes warm-trace reuse without reordering
   across waves). With a CHUNKED engine (`prefill_chunk > 0`) admission
   is bookkeeping only — no prefill runs, no bucket traces exist — so the
-  wave stays pure FCFS and the prompt chunks into subsequent fused steps
-  under the engine's token budget (decode tokens keep strict priority;
-  the request's first token arrives via `StepResult.emitted` when its
-  last chunk runs). TTFT is therefore observed when the FIRST TOKEN is
+  wave stays pure FCFS and the prompt chunks into subsequent fused steps,
+  the oldest partial prompt filling each step's chunk buffer (the decode
+  tokens ride the same program whatever the chunk holds; the request's
+  first token arrives via `StepResult.emitted` when its last chunk
+  runs). TTFT is therefore observed when the FIRST TOKEN is
   pushed, not at admission — identical timing in wave mode, and the only
   correct point in chunked mode.
 * **One background step loop**: a single task owns the engine; every
@@ -305,6 +306,18 @@ class Scheduler:
             "serve_engine_overlap_share",
             lambda: getattr(self.engine, "overlap_share", 0.0),
             "fraction of step programs dispatched behind a running one")
+        # chunked prefill (DecodeEngine._next_chunk): a chunk-carrying
+        # program computes `prefill_chunk` rows whatever they hold. The
+        # share of them that held a prompt id, and how many such programs
+        # a prompt took (1 while prompts fit the chunk)
+        self.metrics.register_gauge(
+            "serve_chunk_fill_share",
+            lambda: getattr(self.engine, "chunk_fill_share", 0.0),
+            "prompt ids prefilled / chunk rows the fused programs computed")
+        self.metrics.register_gauge(
+            "serve_chunk_programs_per_prompt",
+            lambda: getattr(self.engine, "chunk_programs_per_prompt", 0.0),
+            "chunk-carrying step programs per prompt chunked in")
         # a patterned model's layers (engine/decode.py): how many of the
         # held experts a call of an expert layer hits (the weight bytes
         # it must read), how evenly the held experts are loaded, what
@@ -894,9 +907,9 @@ class Scheduler:
                     self._tier_sync()  # steps demote via _ensure_blocks
                     self._aot_sync()   # first step builds its program
                     if getattr(self.engine, "prefill_chunk", 0):
-                        # per-step chunk budget use: the chunk-size
-                        # tuning signal (p50 ~ budget => prefill-bound,
-                        # ~0 => slack)
+                        # ids in this step's chunk buffer: the
+                        # chunk-size tuning signal (p50 ~ N => every
+                        # step carries a full chunk, ~0 => few carry one)
                         self.metrics.prefill_tokens_per_step.observe(
                             res.prefill_tokens)
                     if res.drafted:

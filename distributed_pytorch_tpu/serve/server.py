@@ -33,7 +33,9 @@ Observability plane (ISSUE 9):
   full set (`?fmt=chrome` for a Perfetto-loadable file).
 * `GET /debug/timeline` — the engine's step-level flight recorder: the
   last N fused steps' `{step_ms, n_live, prefill_tokens, emitted,
-  blocks_in_use, preemptions}` records (`?n=` bounds the count).
+  blocks_in_use, preemptions}` records (`?n=` bounds the count), beside
+  the engine's lifetime `overlap_share`, `chunk_fill_share` and
+  `chunk_programs_per_prompt`.
 * `POST /admin/profile?duration_ms=N` — on-demand `jax.profiler` capture
   on a live replica (obs/profile.py, output under `runs/.../profile`);
   one capture at a time — a concurrent request gets 409.
@@ -288,7 +290,8 @@ class ServeApp:
         """`GET /debug/timeline[?n=512]`: the engine flight recorder's
         last n per-step records — the post-hoc ITL-spike diagnosis feed
         the aggregate histograms can't provide."""
-        fl = getattr(self.scheduler.engine, "flight", None)
+        eng = self.scheduler.engine
+        fl = getattr(eng, "flight", None)
         if fl is None:
             return _json_response(404, {"error": "engine has no flight "
                                                  "recorder"})
@@ -298,7 +301,13 @@ class ServeApp:
             return _json_response(400, {"error": "bad n"})
         return _json_response(200, {
             "entries": fl.entries(n), "n_steps": fl.total,
-            "dropped": fl.dropped, "capacity": fl.capacity})
+            "dropped": fl.dropped, "capacity": fl.capacity,
+            # the engine's lifetime shares, beside the per-program
+            # `overlapped` / `prefill_tokens` they are made of
+            "overlap_share": getattr(eng, "overlap_share", 0.0),
+            "chunk_fill_share": getattr(eng, "chunk_fill_share", 0.0),
+            "chunk_programs_per_prompt":
+                getattr(eng, "chunk_programs_per_prompt", 0.0)})
 
     async def _admin_profile(self, writer, query: dict) -> None:
         """`POST /admin/profile?duration_ms=N`: capture a jax.profiler

@@ -366,6 +366,90 @@ def test_chunked_single_fused_trace_across_prompt_mix():
     assert eng.admit_traces == {}
 
 
+@pytest.mark.parametrize("n_ids, takes", [
+    (16, [16]), (17, [16, 1]), (39, [16, 16, 7]),
+], ids=["one-chunk", "one-id-over", "three-chunks"])
+def test_chunk_fills_the_rows_its_program_computes(n_ids, takes):
+    """The fused program computes `prefill_chunk` chunk rows whatever they
+    hold, so the pick fills them: beside two live decoding slots a prompt
+    of `prefill_chunk` ids is ONE chunk-carrying program and one id more
+    makes two, every chunk starts on a block boundary, both live streams
+    emit on every step of the chunk-in, every stream is the one-shot
+    oracle's, and the fill counter is real ids over rows computed."""
+    cfg, model, variables = _mv()
+    eng = DecodeEngine(model, variables, n_slots=3, temperature=0.0,
+                       min_bucket=8, block_size=8, prefill_chunk=16)
+    picks = []
+    pick = eng._next_chunk
+
+    def recording_pick(preempted, ahead):
+        out = pick(preempted, ahead)
+        if out is not None:
+            seq = eng._slots[out[0]]
+            picks.append((seq.prefix_len + seq.suffix_done, out[1]))
+        return out
+
+    eng._next_chunk = recording_pick
+    prompts = {"A": [50, 51, 52], "B": [60, 61, 62, 63],
+               "P": list(range(1, n_ids + 1))}
+    budgets = {"A": 30, "B": 30, "P": 6}
+    sid = {n: eng.admit(prompts[n], budgets[n]).seq_id for n in "AB"}
+    streams = {n: [] for n in prompts}
+    name_of = {v: k for k, v in sid.items()}
+
+    def step():
+        res = eng.step()
+        for s, toks in res.emitted.items():
+            streams[name_of[s]] += toks
+        return res
+
+    while not (streams["A"] and streams["B"]):
+        step()                              # both decode from here on
+    assert (eng.chunk_programs, eng.chunked_prompts) == (2, 2)
+    assert picks == [(0, 3), (0, 4)]
+    del picks[:]
+    sid["P"] = eng.admit(prompts["P"], budgets["P"]).seq_id
+    name_of[sid["P"]] = "P"
+    carried = []
+    while eng.n_live:
+        res = step()
+        if res.prefill_tokens:
+            carried.append((res.prefill_tokens, set(res.emitted)))
+    assert picks == [(16 * i, t) for i, t in enumerate(takes)]
+    assert all(off % eng.block_size == 0 for off, _ in picks)
+    assert [t for t, _ in carried] == takes
+    for _, emitted in carried[:-1]:
+        assert emitted == {sid["A"], sid["B"]}, "a live stream stalled"
+    assert carried[-1][1] == set(sid.values())   # P's first token with it
+    for n, p in prompts.items():
+        want = generate(model, variables, jnp.asarray(p, jnp.int32)[None],
+                        budgets[n], temperature=0.0)[0].tolist()
+        assert streams[n] == want[len(p):], n
+    assert eng.chunk_programs == 2 + len(takes)
+    assert eng.chunked_prompts == 3
+    assert eng.prefilled_tokens == 7 + n_ids
+    assert eng.chunk_fill_share == pytest.approx(
+        (7 + n_ids) / ((2 + len(takes)) * 16))
+    assert eng.chunk_programs_per_prompt == pytest.approx(
+        (2 + len(takes)) / 3)
+    recs = eng.flight.entries()
+    assert sum(r["prefill_tokens"] > 0 for r in recs) == eng.chunk_programs
+    assert eng.fused_step_traces == 1 and eng.step_traces == 1
+
+
+def test_a_wave_engine_carries_no_chunk():
+    """`chunk_fill_share` counts fused programs' ids only: a wave engine
+    prefills at admission and reads 0."""
+    cfg, model, variables = _mv()
+    eng = DecodeEngine(model, variables, n_slots=2, temperature=0.0,
+                       min_bucket=8, block_size=8)
+    eng.run([[1, 2, 3], list(range(1, 20))], 3)
+    assert eng.prefilled_tokens == 22
+    assert (eng.chunk_programs, eng.chunked_prompts) == (0, 0)
+    assert eng.chunk_fill_share == 0.0
+    assert eng.chunk_programs_per_prompt == 0.0
+
+
 def test_chunked_engine_kernel_matches_naive(monkeypatch):
     """FLASH_DECODE=on drives the fused chunk through the paged chunk-
     prefill kernel (interpret off-TPU) and decode through the paged
@@ -419,7 +503,8 @@ def _mv():
     return _MV["cfg"], _MV["model"], _MV["variables"]
 
 
-#: name -> (prompt, budget). A: three chunks of 16; D is cancelled while a
+#: name -> (prompt, budget). A: three chunks (16, 16 and 7 ids, whatever
+#: decodes beside them) and E two; D is cancelled while a
 #: program runs for it and E takes its slot at once; F ends with its first
 #: token; budgets end on different steps.
 LOOKAHEAD_REQS = {
@@ -593,6 +678,14 @@ def test_one_program_in_flight_gives_every_request_its_own_tokens(
         assert eng.overrun_tokens == 1 + n_eos
         assert sum(r["overrun"] for r in recs) <= eng.overrun_tokens
         assert eng.fused_step_traces == 1 and eng.step_traces == 1
+        # a prompt is cut at every 16 ids, beside whatever decodes
+        carried = [r["prefill_tokens"] for r in recs if r["prefill_tokens"]]
+        if not prefix_cache:
+            assert sorted(carried) == sorted(
+                min(16, len(p) - off) for p, _ in LOOKAHEAD_REQS.values()
+                for off in range(0, len(p), 16))
+        assert eng.chunk_programs == len(carried)
+        assert eng.chunked_prompts == len(LOOKAHEAD_REQS)
     else:
         assert eng.overlap_share == 0.0 and eng.overrun_tokens == 0
         assert eng.drain_reasons == {"wave": eng.n_steps}

@@ -157,6 +157,45 @@ def test_engine_matches_the_reference_through_reused_slots(mv,
     assert {"experts_hit", "absent_assignments", "state_reset"} <= set(rec)
 
 
+@pytest.mark.parametrize("n_ids, takes", [(16, [16]), (17, [16, 1])],
+                         ids=["one-chunk", "one-id-over"])
+def test_a_chunk_fills_its_rows_beside_a_live_slot(mv, n_ids, takes):
+    """A recurrent model's chunk is filled like any other: beside a slot
+    that decodes, a prompt of `prefill_chunk` ids is one chunk-carrying
+    program (one state reset, at position 0) and one id more carries the
+    state into a second; the live stream emits with each, and every
+    emitted token is the reference's choice."""
+    cfg, model, variables = mv
+    live, prompt = _prompts((5, n_ids), seed=n_ids)
+    eng = _engine(model, variables)
+    streams = {}
+    with HI:
+        a = eng.admit(live, 12).seq_id
+        while a not in streams:
+            for s, toks in eng.step().emitted.items():
+                streams.setdefault(s, []).extend(toks)
+        resets = eng.state_resets
+        p = eng.admit(prompt, 6).seq_id
+        carried = []
+        while eng.n_live:
+            res = eng.step()
+            for s, toks in res.emitted.items():
+                streams.setdefault(s, []).extend(toks)
+            if res.prefill_tokens:
+                carried.append((res.prefill_tokens, set(res.emitted)))
+        assert [t for t, _ in carried] == takes
+        assert all(a in emitted for _, emitted in carried)
+        assert p in carried[-1][1]
+        assert eng.state_resets == resets + 1 == 2
+        assert _worst_gap(variables, [live], [live + streams[a]],
+                          12) < 1e-5
+        assert _worst_gap(variables, [prompt], [prompt + streams[p]],
+                          6) < 1e-5
+    assert eng.chunk_programs == 1 + len(takes)
+    assert eng.chunk_fill_share == pytest.approx(
+        (5 + n_ids) / ((1 + len(takes)) * 16))
+
+
 def test_a_state_not_zeroed_shows(mv, monkeypatch):
     """The classic fault, put into the program: a chunk at position 0 that
     starts from what the slot's last occupant left there."""

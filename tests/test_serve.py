@@ -504,15 +504,16 @@ def test_streams_match_offline_engine_greedy(mv):
 
 
 # ----------------------------------------------------------------------
-# chunked prefill through the scheduler (round 12: decode priority)
+# chunked prefill through the scheduler (round 12: live streams never stall)
 # ----------------------------------------------------------------------
 
 def test_chunked_decode_priority_live_stream_never_stalls(mv):
     """The chunked-prefill contract end-to-end: while a long prompt
     chunks into the fused step, every already-live stream emits a token
-    on EVERY step — decode work is never preempted by prefill work. The
-    per-step emission log is recorded inside the engine-step wrapper, so
-    the assertion is exact, not timing-based."""
+    on EVERY step — decode work is never preempted by prefill work — and
+    the chunks are full: the program computes 16 chunk rows whatever
+    decodes beside them. The per-step emission log is recorded inside the
+    engine-step wrapper, so the assertion is exact, not timing-based."""
 
     async def main():
         eng = make_engine(mv, n_slots=2, prefill_chunk=16, block_size=8)
@@ -538,11 +539,12 @@ def test_chunked_decode_priority_live_stream_never_stalls(mv):
     eng, sched, a, b, log = run_async(main())
     a_id, b_id = a._req.seq_id, b._req.seq_id
     b_first = next(i for i, (em, _) in enumerate(log) if b_id in em)
-    # B's 39-token prompt chunked in over several steps (decode priority
-    # shrinks the 16-token budget to one 8-row block while A decodes)
+    # B's 39-token prompt chunked in over three steps: the oldest partial
+    # prompt fills the 16-row chunk buffer while A decodes beside it
     chunk_steps = [i for i, (_, pt) in enumerate(log[:b_first + 1]) if pt]
-    assert len(chunk_steps) >= 3, \
-        f"expected a multi-chunk prefill, got {chunk_steps}"
+    assert [log[i][1] for i in chunk_steps] == [3, 16, 16, 7], \
+        f"expected A's chunk and three of B's, got {chunk_steps}"
+    chunk_steps = chunk_steps[1:]
     # the pinned property: A emitted on every step of B's chunk-in
     # window (A retires on budget later, so it is live throughout)
     for i in range(chunk_steps[0], b_first + 1):
@@ -555,6 +557,13 @@ def test_chunked_decode_priority_live_stream_never_stalls(mv):
     assert h["count"] == len(log)
     assert sched.metrics.prefill_tokens_per_step.sum == \
         eng.prefilled_tokens
+    # 42 ids in four chunk-carrying programs of 16 rows, two prompts
+    gauges = sched.metrics.summary()["gauges"]
+    assert gauges["serve_chunk_fill_share"] == \
+        pytest.approx(42 / 64, abs=1e-4)
+    assert gauges["serve_chunk_programs_per_prompt"] == pytest.approx(2.0)
+    assert "serve_chunk_fill_share 0.65625" in \
+        sched.metrics.render_prometheus()
     # greedy parity with the offline chunked engine
     ref_eng = make_engine(mv, n_slots=2, prefill_chunk=16, block_size=8)
     refs = ref_eng.run([[1, 2, 3], list(range(1, 40))], [30, 4])

@@ -376,9 +376,18 @@ def test_lookahead_counter_where_operators_look(mv, tmp_path):
         _, metrics = await http_get(rep.app.port, "/metrics")
         eng = rep.eng
         await rep.stop()
-        return json.loads(timeline)["entries"], metrics, eng, n0
+        return json.loads(timeline), metrics, eng, n0
 
-    entries, metrics, eng, n0 = run_async(main())
+    timeline, metrics, eng, n0 = run_async(main())
+    entries = timeline["entries"]
+    # the engine's lifetime shares ride the payload beside the records
+    assert timeline["overlap_share"] == pytest.approx(eng.overlap_share)
+    assert timeline["chunk_fill_share"] == \
+        pytest.approx(eng.chunk_fill_share)
+    assert 0 < eng.chunk_fill_share <= 1
+    assert timeline["chunk_programs_per_prompt"] == \
+        pytest.approx(eng.chunk_programs_per_prompt) == \
+        pytest.approx(9 / 6)       # 19, 19 and 29 ids: two chunks of 16 each
     mine = [e for e in entries if e["step"] > n0]
     assert len(mine) >= 12
     assert {e["drain_reason"] for e in mine if not e["overlapped"]} == \

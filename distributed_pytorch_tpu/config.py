@@ -341,6 +341,7 @@ ACTIVATIONS = (
 )
 
 ATTENTION_KINDS = ("mha", "mqa", "gqa", "mla")
+ROUTER_KINDS = ("sigmoid", "softmax_topk")   # of a patterned model's 'E'
 POS_EMB_KINDS = ("learn", "sin", "rope", "none")
 # The reference realizes these as five separate trainer scripts
 # (single-gpu/train.py, multi-gpu/ddp/train.py, kaggle-zero1.py,
@@ -436,8 +437,8 @@ class LLMConfig:
 
     # a per-layer pattern of ONE-mixer blocks, `x + mixer(norm(x))`, one
     # character a layer: 'M' a Mamba-2 state-space mixer (models/ssm.py),
-    # 'E' sigmoid-routed experts of which this chip holds a share
-    # (models/mlp.py RoutedExperts), '*' attention (GQA). Empty = the
+    # 'E' routed experts of which this chip holds a share (models/mlp.py
+    # RoutedExperts; `router` says how), '*' attention (GQA). Empty = the
     # attention + FFN block above for every layer. `n_layer` is its
     # length. A patterned model has RMSNorms, no FFN biases, and its
     # parameters are created in `LLM.param_dtype`.
@@ -451,10 +452,23 @@ class LLMConfig:
     # the slice of routed experts this chip holds (empty: all), what the
     # others would add is left out; `shared_up_dim` the shared expert's
     # width (0 = up_dim); `routed_scale` multiplies the renormalised
-    # weights
+    # weights. `router`: 'sigmoid' (scores sigmoid over every expert, the
+    # top k of score + correction bias, weights renormalised) or
+    # 'softmax_topk' (the top k LOGITS, softmax over those k, no bias and
+    # no scale). A gated `non_linearity` ('swiglu', 'glu') makes the routed
+    # and the shared experts gated: an up stack of 2 x up_dim, [a | b].
     experts_held: tuple = ()
     shared_up_dim: int = 0
     routed_scale: float = 1.0
+    router: str = "sigmoid"
+    # a patterned model's scalar multipliers (1 = the program is as
+    # without them): the embedding's output times `embed_mult`, every
+    # block `x + resid_mult * mixer(norm(x))`, attention's softmax at
+    # `attn_scale` (0 = 1/sqrt(head size)), the logits over `logits_div`
+    embed_mult: float = 1.0
+    resid_mult: float = 1.0
+    attn_scale: float = 0.0
+    logits_div: float = 1.0
     # 'M' layers (Mamba-2): heads x head size = d_inner, groups share B/C
     ssm_heads: int = 0
     ssm_head_dim: int = 0
@@ -478,9 +492,14 @@ class LLMConfig:
             if "E" in self.layer_pattern:
                 assert self.n_act > self.n_shared and \
                     self.n_exp > self.n_shared
+                assert self.router in ROUTER_KINDS, self.router
                 if self.experts_held:
                     lo, n = self.experts_held
                     assert 0 <= lo and n >= 1 and lo + n <= self.n_routed
+        else:
+            assert (self.embed_mult, self.resid_mult, self.attn_scale,
+                    self.logits_div) == (1.0, 1.0, 0.0, 1.0), \
+                "the multipliers are a patterned model's"
         # Cross-field normalization, mirroring reference
         # single-gpu/train.py:198-206 (mha -> n_kv_heads=n_head, mqa -> 1,
         # mla requires latent dims; rope-mla additionally rope_head_dim).

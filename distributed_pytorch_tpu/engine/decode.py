@@ -855,12 +855,22 @@ class DecodeEngine:
         # what a patterned model's layers did (/metrics, flight record):
         # a slot's recurrent state began anew (an admission's first
         # chunk); per call of an expert layer: how many held experts were
-        # hit; assignments of real rows to held and to absent experts
+        # hit; assignments of real rows to held and to absent experts;
+        # tiles of the expert kernels beyond a hit expert's first (each
+        # reads the expert's matrices again; the kernels' own count,
+        # carried out of the program), the calls that had one, and the
+        # calls, by what the call carried ("chunk" | "decode"); the routing
+        # weights that fell on held experts (`held_gate_share`): both of
+        # softmax-routed programs alone, 0 elsewhere
         self.state_resets = 0
         self.expert_calls = 0
         self.experts_hit = 0
         self.held_assignments = 0
         self.absent_assignments = 0
+        self.expert_calls_by = {"chunk": 0, "decode": 0}
+        self.expert_second_tiles_by = {"chunk": 0, "decode": 0}
+        self.expert_second_tile_calls_by = {"chunk": 0, "decode": 0}
+        self.held_gate_sum = 0.0
         n_held = (cfg.experts_held or (0, cfg.n_routed))[1]
         self.expert_tokens = np.zeros((n_held,), np.int64)
         # step-level flight recorder (obs/flight.py): one record per
@@ -1496,7 +1506,7 @@ class DecodeEngine:
             # counts ride the same transfer)
             first, stats = jax.device_get(  # lint: allow(host-sync)
                 (first, self._take_expert_stats()))
-            self._count_experts(stats)
+            self._count_experts(stats, ("chunk",))
             first_tok = int(first[0])
             self._slots[slot] = _Slot(seq_id=seq_id, tokens=toks + [first_tok],
                                       prompt_len=L, n_new=1,
@@ -1821,21 +1831,50 @@ class DecodeEngine:
         self.caches = caches
         return stats
 
-    def _count_experts(self, stats: Optional[list]) -> tuple[int, int]:
+    def _count_experts(self, stats: Optional[list],
+                       kinds: tuple = ()) -> tuple[int, int, int]:
         """Fold one program's FETCHED routing counts (host arrays) into
-        the lifetime counters; (experts hit, absent assignments) of the
-        program."""
-        hit = absent = 0
+        the lifetime counters; (experts hit, absent assignments, second
+        tiles) of the program. `kinds` = what each call an expert layer
+        made in the program carried, in the program's order: "chunk" |
+        "decode". A layer that carries its kernels' tile count out (a
+        `tiles` leaf) has its second tiles counted: the tiles beyond one an
+        expert hit."""
+        hit = absent = second = 0
         for layer in stats or ():
             tokens = layer["tokens"]                        # (calls, held)
             self.expert_calls += tokens.shape[0]
             self.expert_tokens += tokens.sum(axis=0)
             self.held_assignments += int(tokens.sum())
-            hit += int((tokens > 0).sum())
+            hits = (tokens > 0).sum(axis=1)                 # (calls,)
+            hit += int(hits.sum())
             absent += int(layer["absent"].sum())
+            self.held_gate_sum += float(layer["held_gate"].sum()) \
+                if "held_gate" in layer else 0.0
+            if "tiles" not in layer:
+                continue
+            for what, extra in zip(kinds, layer["tiles"] - hits):
+                self.expert_calls_by[what] += 1
+                self.expert_second_tiles_by[what] += int(extra)
+                self.expert_second_tile_calls_by[what] += int(extra > 0)
+                second += int(extra)
         self.experts_hit += hit
         self.absent_assignments += absent
-        return hit, absent
+        return hit, absent, second
+
+    @property
+    def expert_second_tiles(self) -> int:
+        return sum(self.expert_second_tiles_by.values())
+
+    @property
+    def held_gate_share(self) -> float:
+        """Mean share of a real row's routing weights that fell on experts
+        held here: the part of an expert layer's routed output this chip
+        computes. 0 where the router renormalises nothing to compare with
+        (`route_sigmoid` carries no such count)."""
+        rows = (self.held_assignments + self.absent_assignments) \
+            / max(self.cfg.n_act_routed, 1)
+        return self.held_gate_sum / rows if rows else 0.0
 
     def _holds(self, slot: int, seq_id: int) -> bool:
         """Whether the occupant a program was planned for still holds its
@@ -1862,8 +1901,9 @@ class DecodeEngine:
                 sampled, stats = jax.device_get(  # lint: allow(host-sync)
                     (prog.tok, prog.expert_stats))
         with phase("engine.retire", acc, step=step) as retire:
-            hit, absent = self._count_experts(
-                stats if prog.spec is None else None)
+            hit, absent, second = self._count_experts(
+                stats if prog.spec is None else None,
+                ("chunk",) * (prog.chunk is not None) + ("decode",))
             self.state_resets += int(prog.state_reset)
             emitted: dict[int, list] = {}
             retired: dict[int, Retired] = dict(prog.preempted)
@@ -1946,6 +1986,7 @@ class DecodeEngine:
                 overlapped=prog.overlapped,
                 drain_reason=prog.drain_reason, overrun=overrun,
                 **({"experts_hit": hit, "absent_assignments": absent,
+                    "expert_second_tiles": second,
                     "state_reset": int(prog.state_reset)}
                    if self.cfg.layer_pattern else {}))
         return StepResult(emitted=emitted, retired=retired,

@@ -214,7 +214,8 @@ class GQA(nn.Module):
                      dropout_rate=cfg.dropout, dropout_rng=drop_rng,
                      impl=self.attn_impl, decode=cache is not None,
                      k_scale=k_scale, v_scale=v_scale,
-                     block_tables=block_tables, n_kv_heads=nkvh)
+                     block_tables=block_tables, n_kv_heads=nkvh,
+                     scale=cfg.attn_scale or None)
         y = y.reshape(B, T, qw)
         y = _OverlapDense(C, x.dtype, name="c_proj", **dense)(y)
         y = nn.Dropout(cfg.dropout, deterministic=deterministic)(y)

@@ -70,9 +70,10 @@ def merge_expert_stats(before: Optional[dict], new: dict) -> dict:
 
 
 class MixerBlock(nn.Module):
-    """One layer of a patterned model: `x + mixer(RMSNorm(x))`, the mixer
-    one of 'M' (models/ssm.py), 'E' (models/mlp.py RoutedExperts) or '*'
-    (GQA). What each keeps between calls sits in the layer's cache slot:
+    """One layer of a patterned model: `x + r * mixer(RMSNorm(x))` (r =
+    `cfg.resid_mult`), the mixer one of 'M' (models/ssm.py), 'E'
+    (models/mlp.py RoutedExperts) or '*' (GQA). What each keeps between
+    calls sits in the layer's cache slot:
     per-slot state leaves, this program's routing counts, block pools.
     `state_ctx` (the engine's: which rows are live, or which slot a chunk
     belongs to and how many of its rows are real) reaches the two kinds
@@ -104,6 +105,9 @@ class MixerBlock(nn.Module):
             y, new_cache = GQA(cfg, self.attn_impl, pd, name="attn")(
                 h, freqs, cache, pos, deterministic=True,
                 block_tables=block_tables)
+        if cfg.resid_mult != 1.0:
+            # in float32: 0.22 is no bfloat16 number (0.2197 is)
+            y = (y.astype(jnp.float32) * cfg.resid_mult).astype(y.dtype)
         return x + y, new_cache, jnp.float32(0.0)
 
 
@@ -208,6 +212,8 @@ class LLM(nn.Module):
                            embedding_init=_EMBED_INIT,
                            param_dtype=pd, dtype=dt, name="tkn_emb")
         x = tkn_emb(idx)
+        if cfg.embed_mult != 1.0:
+            x = x * jnp.asarray(cfg.embed_mult, x.dtype)
         freqs = None
 
         if cfg.pos_emb == "rope":
@@ -278,7 +284,8 @@ class LLM(nn.Module):
             "lm_head", _EMBED_INIT, (cfg.vocab_size, cfg.n_embd), pd)
 
         if targets is not None:
-            assert head is None, "the training loss runs the tied head only"
+            assert head is None and cfg.logits_div == 1.0, \
+                "the training loss runs the tied head only, logits undivided"
             # Weight-tied CE with ignore_index=-1 (reference :559-560, :689),
             # fp32-accumulated. The fused path never materializes the
             # (B, T, V) logits (ops/losses.py); under a live 'seq' axis the
@@ -390,6 +397,9 @@ class LLM(nn.Module):
                         sel, ("tkn_emb", "embedding"), transpose_b=True)
                 if logits is None:
                     logits = tkn_emb.attend(sel)   # (B, 1, V)
+                if cfg.logits_div != 1.0:
+                    logits = logits / jnp.asarray(cfg.logits_div,
+                                                  logits.dtype)
             loss = None
 
         return logits, loss, new_caches

@@ -402,10 +402,24 @@ def route_sigmoid(scores_in: jnp.ndarray, gate: jnp.ndarray,
     return idx, w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20) * scale
 
 
+def route_softmax_topk(scores_in: jnp.ndarray, gate: jnp.ndarray, k: int):
+    """The other router of the 'E' layers (`cfg.router` 'softmax_topk'):
+    l = x W_r in float32 over every routed expert; the top k by LOGIT; their
+    weights are the softmax over THOSE k logits. No bias, no scale. Returns
+    (ids (N, k), weights (N, k) float32, each row summing to one)."""
+    logits = jnp.dot(scores_in.astype(jnp.float32), gate.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    top, idx = jax.lax.top_k(logits, k)
+    return idx, jax.nn.softmax(top, axis=-1)
+
+
 class RoutedExperts(nn.Module):
-    """An 'E' layer of a patterned model: sigmoid-routed ungated experts
-    of which this chip holds a share, plus one shared expert of another
-    width that every token takes.
+    """An 'E' layer of a patterned model: routed experts of which this
+    chip holds a share, plus one shared expert of another width that every
+    token takes. `cfg.router` picks the router (`route_sigmoid`, with its
+    `gate_bias` leaf, or `route_softmax_topk`, without); a gated
+    `cfg.non_linearity` ('swiglu': silu(a) * b) makes both kinds of expert
+    gated, their up matrices 2 x the width, [a | b].
 
     The router is as wide as the model's routed experts (`cfg.n_routed`)
     and picks `cfg.n_act_routed` of them; `cfg.experts_held` = (first,
@@ -420,7 +434,9 @@ class RoutedExperts(nn.Module):
     With `row_mask` (N,) only the rows that are real are sent to routed
     experts (the others get the shared expert's part alone), and the layer
     also returns what the routing did for them: tokens a held expert,
-    assignments to absent experts (`stats`).
+    assignments to absent experts and, for the softmax router (whose
+    weights are not renormalised over the held), the sum of the weights that
+    fell on held experts and the tiles the expert kernels ran (`stats`).
     """
 
     config: LLMConfig
@@ -428,6 +444,8 @@ class RoutedExperts(nn.Module):
 
     @nn.compact
     def __call__(self, x, row_mask=None):
+        from distributed_pytorch_tpu.ops.grouped_matmul import (
+            _apply_activation, held_experts_ffn)
         cfg = self.config
         B, T, C = x.shape
         dt = x.dtype
@@ -435,22 +453,30 @@ class RoutedExperts(nn.Module):
         F = cfg.up_dim
         Fs = cfg.shared_up_dim or F
         first, n_held = cfg.experts_held or (0, cfg.n_routed)
-        act = _activation(cfg.non_linearity)
+        nl = cfg.non_linearity.lower()
+        fan = 2 if _is_gated(nl) else 1
+        sigmoid = cfg.router == "sigmoid"
         gate = self.param("gate", _DENSE_INIT, (C, cfg.n_routed), pd)
-        # drawn, not zeros: a trained model's correction bias moves the
-        # selection, and a zero one would leave that path untested
-        bias = self.param("gate_bias", nn.initializers.normal(stddev=0.1),
-                          (cfg.n_routed,), jnp.float32)
-        # (held, F, C), out by in: ops/grouped_matmul.py says why
-        w_up = self.param("experts_up", _DENSE_INIT, (n_held, F, C), pd)
+        if sigmoid:
+            # drawn, not zeros: a trained model's correction bias moves the
+            # selection, and a zero one would leave that path untested
+            bias = self.param("gate_bias",
+                              nn.initializers.normal(stddev=0.1),
+                              (cfg.n_routed,), jnp.float32)
+        # (held, fan x F, C), out by in: ops/grouped_matmul.py says why
+        w_up = self.param("experts_up", _DENSE_INIT, (n_held, fan * F, C),
+                          pd)
         w_down = self.param("experts_down", _DENSE_INIT, (n_held, F, C), pd)
-        s_up = self.param("shared_up", _DENSE_INIT, (C, Fs), pd)
+        s_up = self.param("shared_up", _DENSE_INIT, (C, fan * Fs), pd)
         s_down = self.param("shared_down", _DENSE_INIT, (Fs, C), pd)
 
         x_flat = x.reshape(-1, C)
         with jax.named_scope("moe_route"):
-            idx, w = route_sigmoid(x_flat, gate, bias, cfg.n_act_routed,
-                                   cfg.routed_scale)
+            if sigmoid:
+                idx, w = route_sigmoid(x_flat, gate, bias, cfg.n_act_routed,
+                                       cfg.routed_scale)
+            else:
+                idx, w = route_softmax_topk(x_flat, gate, cfg.n_act_routed)
         # rows that are not real (a chunk's pads, dead slots) are routed
         # nowhere: identical garbage rows all pick the same six experts and
         # would cost those experts tile after tile of weight reads (128 pad
@@ -458,21 +484,23 @@ class RoutedExperts(nn.Module):
         # seed's luck in which of the six are held; my chip run, PR 33)
         sent = idx if row_mask is None else \
             jnp.where(row_mask[:, None], idx, -1)
+        tiles = None                  # the dense path runs no tiles
         with jax.named_scope("moe_experts"):
-            if cfg.non_linearity.lower() == "relu2":
-                from distributed_pytorch_tpu.ops.grouped_matmul import \
-                    held_experts_ffn
-                routed = held_experts_ffn(x_flat, sent, w, w_up, w_down,
-                                          first=first)
+            if nl in ("relu2", "swiglu"):
+                routed, tiles = held_experts_ffn(
+                    x_flat, sent, w, w_up, w_down, first=first,
+                    n_routed=cfg.n_routed, gated=nl == "swiglu")
             else:
                 local = sent - first
                 comb = (jax.nn.one_hot(local, n_held, dtype=jnp.float32)
                         * w[..., None]).sum(axis=1)              # (N, held)
-                h = act(jnp.einsum("nc,efc->enf", x_flat, w_up.astype(dt)))
+                h = _apply_activation(
+                    jnp.einsum("nc,efc->enf", x_flat, w_up.astype(dt)), nl)
                 routed = jnp.einsum("enf,efc,ne->nc", h, w_down.astype(dt),
                                     comb.astype(dt)).astype(jnp.float32)
         with jax.named_scope("moe_shared"):
-            shared = act(x_flat @ s_up.astype(dt)) @ s_down.astype(dt)
+            shared = _apply_activation(x_flat @ s_up.astype(dt), nl) \
+                @ s_down.astype(dt)
         y = (routed + shared.astype(jnp.float32)).astype(dt).reshape(B, T, C)
         stats = None
         if row_mask is not None:
@@ -484,4 +512,10 @@ class RoutedExperts(nn.Module):
             stats = {"tokens": tokens[None],
                      "absent": (jnp.sum(row_mask) * idx.shape[1]
                                 - jnp.sum(tokens)).astype(jnp.int32)[None]}
+            if not sigmoid:
+                # leaves of this router alone: the sigmoid-routed programs
+                # keep the text they had (PR 36)
+                stats["held_gate"] = jnp.sum(jnp.where(held, w, 0.0))[None]
+                if tiles is not None:
+                    stats["tiles"] = tiles
         return y, stats

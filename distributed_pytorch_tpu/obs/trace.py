@@ -356,10 +356,12 @@ MIXER_SCOPES = {
                 "(ops/ssm_scan.py ssd_chunked)",
     "ssm_step": "the one-token recurrence of a decode step "
                 "(ops/ssm_scan.py ssm_step)",
-    "moe_route": "sigmoid scores, bias-corrected top-k, renormalised "
-                 "weights (models/mlp.py route_sigmoid)",
-    "moe_experts": "the held routed experts: packing, the two "
-                   "expert_matmul kernels, the combine "
+    "moe_route": "the router: sigmoid scores, bias-corrected top-k, "
+                 "renormalised weights (models/mlp.py route_sigmoid), or "
+                 "the top-k logits and their softmax (route_softmax_topk)",
+    "moe_experts": "the held routed experts: packing, the two kernels "
+                   "(expert_matmul_up or expert_matmul_gated_up, "
+                   "expert_matmul_down), the combine "
                    "(ops/grouped_matmul.py held_experts_ffn)",
     "moe_shared": "the shared expert's two matmuls (models/mlp.py)",
 }

@@ -56,6 +56,7 @@ and one combine scatter-add.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -493,7 +494,11 @@ def grouped_dispatch(x_flat: jnp.ndarray, topk_idx: jnp.ndarray,
 # reaches the kernel through a whole-stack relayout copy (device-free
 # compile, ISSUE 33: 639 MB a call), and an (E, 1856, C) one as it lies.
 
-def _held_up_kernel(g_ref, n_ref, x_ref, w_ref, o_ref, acc_ref, *, nk: int):
+def _held_up_kernel(g_ref, n_ref, x_ref, w_ref, o_ref, acc_ref, *, nk: int,
+                    gated: bool = False):
+    """relu(x W^T)^2 of one tile; `gated`: the block is the expert's whole
+    (2F, bk) slab of [a | b] as published, and the epilogue silu(a) * b
+    over the two halves of the accumulator. In float32 either way."""
     del g_ref
     i, k = pl.program_id(0), pl.program_id(1)
     used = i < n_ref[0]
@@ -508,8 +513,14 @@ def _held_up_kernel(g_ref, n_ref, x_ref, w_ref, o_ref, acc_ref, *, nk: int):
 
     @pl.when(k == nk - 1)
     def _():
-        h = jnp.maximum(acc_ref[...], 0.0)       # relu(.)^2, in float32
-        o_ref[...] = jnp.where(used, h * h, 0.0).astype(o_ref.dtype)
+        if gated:
+            F = o_ref.shape[1]
+            a, b = acc_ref[:, :F], acc_ref[:, F:]
+            h = a * jax.nn.sigmoid(a) * b
+        else:
+            h = jnp.maximum(acc_ref[...], 0.0)   # relu(.)^2, in float32
+            h = h * h
+        o_ref[...] = jnp.where(used, h, 0.0).astype(o_ref.dtype)
 
 
 def _held_down_kernel(g_ref, n_ref, h_ref, w_ref, s_ref, o_ref):
@@ -526,15 +537,24 @@ def _held_down_kernel(g_ref, n_ref, h_ref, w_ref, s_ref, o_ref):
         o_ref[...] = jnp.zeros_like(o_ref)
 
 
-def _split(n: int, parts: int, step: int) -> int:
-    """n // parts where that is a whole multiple of `step`, else n."""
-    return n // parts if n % (parts * step) == 0 else n
+def _split(n: int, step: int) -> int:
+    """n over 3, else over 4, where that is a whole multiple of `step`,
+    else n: the streamed axis of an expert's matrix in a few blocks, so
+    that the next block's DMA runs under this one's product."""
+    for parts in (3, 4):
+        if n % (parts * step) == 0:
+            return n // parts
+    return n
 
 
-def _held_up_call(x_pad, w, tile_group, n_used, bm, interpret):
+def _held_up_call(x_pad, w, tile_group, n_used, bm, interpret, gated=False):
+    """relu(x W_up^T)^2 a tile, or for a gated stack (2F rows an expert,
+    [a | b]) silu(a) * b: (P, F) either way, under a kernel name of its
+    own each."""
     P, K = x_pad.shape
-    F = w.shape[1]
-    bk = _split(K, 3, 128)
+    F2 = w.shape[1]
+    F = F2 // 2 if gated else F2
+    bk = _split(K, 128)
     nk = K // bk
     # an unused tile asks for the block the last used one held: no DMA
     last = nk - 1
@@ -543,24 +563,26 @@ def _held_up_call(x_pad, w, tile_group, n_used, bm, interpret):
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, k, g, n: (
                 i, jnp.where(i < n[0], k, last))),
-            pl.BlockSpec((1, F, bk), lambda i, k, g, n: (
+            pl.BlockSpec((1, F2, bk), lambda i, k, g, n: (
                 g[i], 0, jnp.where(i < n[0], k, last))),
         ],
         out_specs=pl.BlockSpec((bm, F), lambda i, k, g, n: (i, 0)),
-        scratch_shapes=[pltpu.VMEM((bm, F), jnp.float32)])
+        scratch_shapes=[pltpu.VMEM((bm, F2), jnp.float32)])
     return pl.pallas_call(
-        functools.partial(_held_up_kernel, nk=nk), grid_spec=grid_spec,
+        functools.partial(_held_up_kernel, nk=nk, gated=gated),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((P, F), x_pad.dtype),
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=("arbitrary", "arbitrary")),
-        name="expert_matmul_up", interpret=interpret,
+        name="expert_matmul_gated_up" if gated else "expert_matmul_up",
+        interpret=interpret,
     )(tile_group, n_used, x_pad, w)
 
 
 def _held_down_call(h, w, gates, tile_group, n_used, bm, interpret):
     P, F = h.shape
     _, _, C = w.shape
-    bn = _split(C, 3, 128)
+    bn = _split(C, 128)
     nn_ = C // bn
     last = nn_ - 1
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -581,28 +603,40 @@ def _held_down_call(h, w, gates, tile_group, n_used, bm, interpret):
     )(tile_group, n_used, h, w, gates)
 
 
-def held_tile_rows(n_tokens: int) -> int:
-    """Token rows a tile: a held expert sees a few tokens of a decode
-    batch and a dozen of a prefill chunk, and a second tile of one expert
-    reads its matrices a second time."""
-    return 16 if n_tokens <= 64 else 32
+def held_tile_rows(n_tokens: int, k: int, n_routed: int) -> int:
+    """Token rows a tile, from the rows a held expert expects of a call (m
+    = tokens x k / router width): the power of two that holds m + 3
+    sqrt(m), a count's mean and three of its deviations, 16 at the least (a
+    bf16 tile's sublanes) and 128 at the most. A second tile of one expert
+    reads its matrices a second time, and a tile's rows cost next to
+    nothing beside that (the kernels are bound by the weight bytes); every
+    tile row is also a row of the packed buffers, so no larger than that.
+    Top 6 of 128: 16 rows for 64 tokens (3 expected), 32 for a 256-row
+    chunk (12); top 10 of 72: 32 (8.9) and 64 (35.6)."""
+    m = n_tokens * k / n_routed
+    return int(min(128, max(16, 2 ** math.ceil(math.log2(
+        m + 3.0 * math.sqrt(m))))))
 
 
 def held_experts_ffn(x_flat: jnp.ndarray, topk_idx: jnp.ndarray,
                      topk_gates: jnp.ndarray, w_up: jnp.ndarray,
-                     w_down: jnp.ndarray, *, first: int,
-                     interpret: Optional[bool] = None) -> jnp.ndarray:
+                     w_down: jnp.ndarray, *, first: int, n_routed: int,
+                     gated: bool = False,
+                     interpret: Optional[bool] = None):
     """sum over a token's top-k of gate * W_down[e] relu(W_up[e] x)^2, for
     the experts e in [first, first + n_held) that `w_up` (n_held, F, C: out
     by in) and `w_down` (n_held, F, C) hold. `topk_idx` (N, k) are ids over ALL
-    routed experts; what the absent ones would add is left out. Dropless.
-    Returns (N, C) float32."""
+    `n_routed` experts; what the absent ones would add is left out. Dropless.
+    `gated`: `w_up` is (n_held, 2F, C), [a | b], and an expert computes
+    W_down[e] (silu(a) * b). Returns ((N, C) float32, the tiles the two
+    kernels ran (1,) int32: one for every expert hit and one more for
+    every further `held_tile_rows` rows of its own)."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     N, C = x_flat.shape
     k = topk_idx.shape[1]
     n_held = w_up.shape[0]
-    bm = held_tile_rows(N)
+    bm = held_tile_rows(N, k, n_routed)
     A = N * k
     n_tiles = -(-A // bm) + n_held
     P = n_tiles * bm
@@ -633,7 +667,7 @@ def held_experts_ffn(x_flat: jnp.ndarray, topk_idx: jnp.ndarray,
 
     dt = x_flat.dtype
     h = _held_up_call(x_flat[row_tok], w_up.astype(dt), group, n_used, bm,
-                      interpret)
+                      interpret, gated)
     y = _held_down_call(h, w_down.astype(dt), row_gate, group, n_used, bm,
                         interpret)
-    return jnp.zeros((N, C), jnp.float32).at[row_tok].add(y)
+    return jnp.zeros((N, C), jnp.float32).at[row_tok].add(y), n_used

@@ -344,6 +344,17 @@ class Scheduler:
                   + getattr(eng, "held_assignments", 0), 1),
             "share of routed assignments that fall on experts not held")
         self.metrics.register_gauge(
+            "serve_expert_second_tiles_per_call",
+            lambda: getattr(eng, "expert_second_tiles", 0)
+            / max(getattr(eng, "expert_calls", 0), 1),
+            "expert-kernel tiles beyond a hit expert's first (each reads "
+            "the expert's matrices again), per expert-layer call")
+        self.metrics.register_gauge(
+            "serve_expert_held_gate_share",
+            lambda: getattr(eng, "held_gate_share", 0.0),
+            "mean share of a token's routing weights that fell on held "
+            "experts (routers that do not renormalise over the held)")
+        self.metrics.register_gauge(
             "serve_state_resets_total",
             lambda: getattr(eng, "state_resets", 0),
             "recurrent state started anew (one per admission's first chunk)")

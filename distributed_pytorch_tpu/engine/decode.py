@@ -54,8 +54,12 @@ thousands of requests share a system prompt:
   (`_get_fused_step_fn`). The chunk buffer is a fixed (1, N) trace; the
   slot, write offset, and valid length are TRACED arguments — no new
   traces per prompt length, and the pow2 buckets retire to a chunk-size
-  pad. The program computes all N chunk rows beside the decode rows
-  whatever they hold, so a step's cost does not depend on the take: the
+  pad. A patterned model's program walks its layers once with both row
+  sets, so that an expert layer reads its held experts once for the
+  chunk's rows and the decode rows together (`make_fused_step_fn`,
+  `merged_program_share`). The program computes all N chunk rows beside
+  the decode rows whatever they hold, so a step's cost does not depend on
+  the take: the
   oldest partial prompt fills the buffer, min(N, what is left of it) ids
   (`_next_chunk`), and a prompt of up to N ids is ONE chunk-carrying
   program. N is a multiple of the block size and a reused prefix is whole
@@ -150,7 +154,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from distributed_pytorch_tpu.models.generate import sample_token
-from distributed_pytorch_tpu.models.gpt import init_paged_cache
+from distributed_pytorch_tpu.models.gpt import Rows, init_paged_cache
 from distributed_pytorch_tpu.obs import paths
 from distributed_pytorch_tpu.obs.flight import FlightRecorder
 from distributed_pytorch_tpu.obs.retrace import TraceGuard
@@ -217,7 +221,16 @@ def make_fused_step_fn(model, sample_fn, n_slots: int, table_width: int,
     rows whatever the take. The chunk buffer is a fixed (1, prefill_chunk)
     shape; the target slot, block-aligned write offset, and valid length
     are traced, so the whole serving mix shares this single trace (the
-    chunked analogue of `prefix_len` being traced in the wave admit)."""
+    chunked analogue of `prefix_len` being traced in the wave admit).
+
+    A patterned model whose weights are not quantised walks its layers
+    ONCE with both row sets (models/gpt.py `Rows`): the chunk and the
+    decode tokens touch disjoint slots, block tables and state rows, each
+    goes through a state-space or attention layer on its own, chunk
+    first, and an expert layer makes one call over all their rows, so the
+    held experts are read once a program. A classic model, and a
+    quantised engine (whose chunk runs outside the store and whose decode
+    tokens inside it: two sets of weights), run the model twice."""
     W = table_width
 
     def fused_step(variables, caches, tok, pos, live, bt, rng, t,
@@ -232,20 +245,37 @@ def make_fused_step_fn(model, sample_fn, n_slots: int, table_width: int,
         # weight-only int8.
         bt_row = jax.lax.dynamic_slice(
             bt, (cslot, jnp.int32(0)), (1, W))
-        with jax.named_scope("chunk_prefill"):
-            clogits, _, caches = model.apply(
-                variables, ctoks, None, caches, coff, deterministic=True,
-                logits_idx=clen - 1, block_tables=bt_row,
-                **_ctx_kw(model, slot=cslot, valid_len=clen))
-        with jax.named_scope("sample"):
-            first = sample_fn(clogits[:, -1, :],
-                              jax.random.fold_in(rng, 2 ** 21 + t))
-        from distributed_pytorch_tpu.ops.quant import use_quantized_params
-        with use_quantized_params(qparams), jax.named_scope("decode"):
-            logits, _, caches = model.apply(
-                variables, tok[:, None], None, caches, pos,
-                deterministic=True, block_tables=bt,
-                **_ctx_kw(model, live=live))
+
+        def sample_first(clogits):
+            with jax.named_scope("sample"):
+                return sample_fn(clogits[:, -1, :],
+                                 jax.random.fold_in(rng, 2 ** 21 + t))
+
+        if model.config.layer_pattern and qparams is None:
+            with jax.named_scope("decode"):
+                (clogits, logits), _, caches = model.apply(
+                    variables,
+                    (Rows(ctoks, coff, bt_row,
+                          {"slot": cslot, "valid_len": clen}, clen - 1,
+                          scope="chunk_prefill"),
+                     Rows(tok[:, None], pos, bt, {"live": live})),
+                    None, caches, deterministic=True)
+            first = sample_first(clogits)
+        else:
+            with jax.named_scope("chunk_prefill"):
+                clogits, _, caches = model.apply(
+                    variables, ctoks, None, caches, coff,
+                    deterministic=True, logits_idx=clen - 1,
+                    block_tables=bt_row,
+                    **_ctx_kw(model, slot=cslot, valid_len=clen))
+            first = sample_first(clogits)
+            from distributed_pytorch_tpu.ops.quant import \
+                use_quantized_params
+            with use_quantized_params(qparams), jax.named_scope("decode"):
+                logits, _, caches = model.apply(
+                    variables, tok[:, None], None, caches, pos,
+                    deterministic=True, block_tables=bt,
+                    **_ctx_kw(model, live=live))
         with jax.named_scope("sample"):
             nxt = sample_fn(logits[:, -1, :], jax.random.fold_in(rng, t))
         # dead/parked slots freeze their token; parked positions point
@@ -849,6 +879,9 @@ class DecodeEngine:
         # chunk accounting (`chunk_fill_share`, /metrics, /debug/timeline)
         self.chunk_programs = 0       # drained programs that carried a chunk
         self.chunked_prompts = 0      # prompts whose last chunk has run
+        # of those programs, the ones whose expert layers made ONE call
+        # over the chunk's rows and the decode rows (`make_fused_step_fn`)
+        self.merged_programs = 0
         # tokens computed for an occupant that had left by the drain: an
         # `eos` seen one program late, a cancel while its program ran
         self.overrun_tokens = 0
@@ -859,7 +892,8 @@ class DecodeEngine:
         # tiles of the expert kernels beyond a hit expert's first (each
         # reads the expert's matrices again; the kernels' own count,
         # carried out of the program), the calls that had one, and the
-        # calls, by what the call carried ("chunk" | "decode"); the routing
+        # calls, by what the call carried ("chunk": a chunk's rows, alone
+        # or with the decode rows | "decode": those alone); the routing
         # weights that fell on held experts (`held_gate_share`): both of
         # softmax-routed programs alone, 0 elsewhere
         self.state_resets = 0
@@ -1157,6 +1191,14 @@ class DecodeEngine:
         same. 0 for a wave engine."""
         rows = self.chunk_programs * self.prefill_chunk
         return self.prefilled_tokens / rows if rows else 0.0
+
+    @property
+    def merged_program_share(self) -> float:
+        """Lifetime share of the chunk-carrying programs that read the held
+        experts once (one expert call a layer): 1.0 for a patterned model,
+        0 for a classic or a quantised engine, which run the model twice."""
+        return (self.merged_programs / self.chunk_programs
+                if self.chunk_programs else 0.0)
 
     @property
     def chunk_programs_per_prompt(self) -> float:
@@ -1835,11 +1877,14 @@ class DecodeEngine:
                        kinds: tuple = ()) -> tuple[int, int, int]:
         """Fold one program's FETCHED routing counts (host arrays) into
         the lifetime counters; (experts hit, absent assignments, second
-        tiles) of the program. `kinds` = what each call an expert layer
-        made in the program carried, in the program's order: "chunk" |
-        "decode". A layer that carries its kernels' tile count out (a
-        `tiles` leaf) has its second tiles counted: the tiles beyond one an
-        expert hit."""
+        tiles) of the program. A layer's leaves hold a row a
+        CALL of its kernels: one a program, its chunk's rows and its decode
+        rows together where it carried a chunk, or two of a fused step
+        that ran the model twice. `kinds` = what each of a layer's calls
+        carried, in the program's order: "chunk" (a call with a chunk's
+        rows in it) | "decode". A layer that carries its kernels' tile
+        count out (a `tiles` leaf) has its second tiles counted: the tiles
+        beyond one an expert hit."""
         hit = absent = second = 0
         for layer in stats or ():
             tokens = layer["tokens"]                        # (calls, held)
@@ -1901,9 +1946,11 @@ class DecodeEngine:
                 sampled, stats = jax.device_get(  # lint: allow(host-sync)
                     (prog.tok, prog.expert_stats))
         with phase("engine.retire", acc, step=step) as retire:
+            calls_before = self.expert_calls
             hit, absent, second = self._count_experts(
                 stats if prog.spec is None else None,
                 ("chunk",) * (prog.chunk is not None) + ("decode",))
+            calls = self.expert_calls - calls_before
             self.state_resets += int(prog.state_reset)
             emitted: dict[int, list] = {}
             retired: dict[int, Retired] = dict(prog.preempted)
@@ -1916,6 +1963,9 @@ class DecodeEngine:
                 slot_c, sid_c, prefill_tokens, rows = prog.chunk
                 self.prefilled_tokens += prefill_tokens
                 self.chunk_programs += 1
+                self.merged_programs += int(
+                    bool(self._expert_layers)
+                    and calls == len(self._expert_layers))
                 # only a prompt's last chunk makes its slot an occupant
                 self.chunked_prompts += int(
                     prog.occupants.get(slot_c) == sid_c)
@@ -1986,7 +2036,7 @@ class DecodeEngine:
                 overlapped=prog.overlapped,
                 drain_reason=prog.drain_reason, overrun=overrun,
                 **({"experts_hit": hit, "absent_assignments": absent,
-                    "expert_second_tiles": second,
+                    "expert_calls": calls, "expert_second_tiles": second,
                     "state_reset": int(prog.state_reset)}
                    if self.cfg.layer_pattern else {}))
         return StepResult(emitted=emitted, retired=retired,

@@ -24,8 +24,9 @@ TPU-first notes:
 
 from __future__ import annotations
 
+import contextlib
 import math
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 import flax.linen as nn
 import jax
@@ -63,10 +64,37 @@ class RMSNorm(nn.Module):
 
 def merge_expert_stats(before: Optional[dict], new: dict) -> dict:
     """An 'E' layer's cache slot carries what its calls of ONE program
-    routed: a row a call (a fused step makes two)."""
+    routed: a row a call. One call a program, but for a fused step that
+    runs the model twice (a quantised engine's: engine/decode.py)."""
     if before is None:
         return new
     return {k: jnp.concatenate([before[k], new[k]]) for k in new}
+
+
+class Rows(NamedTuple):
+    """One row set of a program: what `LLM.__call__` takes apart as `idx`,
+    `pos`, `block_tables`, `state_ctx` and `logits_idx`, together. A tuple
+    of them as `idx` is ONE walk over the layers for all (a fused step's
+    chunk (1, N) and its decode tokens (n_slots, 1)): `scope` names what a
+    set runs alone."""
+
+    idx: Any
+    pos: Any = 0
+    block_tables: Any = None
+    state_ctx: Optional[dict] = None
+    logits_idx: Any = None
+    scope: Optional[str] = None
+
+
+def _scope(name: Optional[str]):
+    return jax.named_scope(name) if name else contextlib.nullcontext()
+
+
+def _real_rows(x, state_ctx: dict):
+    """(B x T,) bool: the rows of `x` the engine says are real."""
+    if "live" in state_ctx:
+        return state_ctx["live"]
+    return jnp.arange(x.shape[1]) < state_ctx["valid_len"][0]
 
 
 class MixerBlock(nn.Module):
@@ -77,7 +105,12 @@ class MixerBlock(nn.Module):
     per-slot state leaves, this program's routing counts, block pools.
     `state_ctx` (the engine's: which rows are live, or which slot a chunk
     belongs to and how many of its rows are real) reaches the two kinds
-    that have no null block to land a pad in."""
+    that have no null block to land a pad in.
+
+    `xs` are the hidden rows of the program's row sets (`rows`, one or
+    several). An 'M' or '*' layer takes them in turn, the cache flowing
+    from one to the next; an 'E' layer is position-wise and makes ONE call
+    over all their rows, so its experts' matrices are read once."""
 
     config: LLMConfig
     kind: str
@@ -85,30 +118,48 @@ class MixerBlock(nn.Module):
     param_dtype: Any = jnp.float32
 
     @nn.compact
-    def __call__(self, x, freqs, cache=None, pos=0, block_tables=None,
-                 state_ctx=None):
+    def __call__(self, xs, rows, freqs, cache=None):
         cfg = self.config
         pd = self.param_dtype
-        h = RMSNorm(cfg.norm_eps, pd, name="norm")(x)
-        if self.kind == "M":
-            y, new_cache = Mamba2(cfg, pd, name="ssm")(h, cache, pos,
-                                                       state_ctx)
-        elif self.kind == "E":
-            mask = None
-            if state_ctx is not None:
-                mask = state_ctx["live"] if "live" in state_ctx else \
-                    jnp.arange(x.shape[1]) < state_ctx["valid_len"][0]
-            y, stats = RoutedExperts(cfg, pd, name="moe")(h, mask)
+        norm = RMSNorm(cfg.norm_eps, pd, name="norm")
+        hs = []
+        for x, r in zip(xs, rows):
+            with _scope(r.scope):
+                hs.append(norm(x))
+        if self.kind == "E":
+            moe = RoutedExperts(cfg, pd, name="moe")
+            masks = [None if r.state_ctx is None
+                     else _real_rows(h, r.state_ctx)
+                     for h, r in zip(hs, rows)]
+            # every set's rows in one call of the held experts' kernels:
+            # their matrices are read once, and a row gets what it would
+            # get alone (an expert layer carries nothing from row to row)
+            ys, stats = moe(hs, masks)
             new_cache = None if stats is None \
                 else merge_expert_stats(cache, stats)
         else:
-            y, new_cache = GQA(cfg, self.attn_impl, pd, name="attn")(
-                h, freqs, cache, pos, deterministic=True,
-                block_tables=block_tables)
-        if cfg.resid_mult != 1.0:
-            # in float32: 0.22 is no bfloat16 number (0.2197 is)
-            y = (y.astype(jnp.float32) * cfg.resid_mult).astype(y.dtype)
-        return x + y, new_cache, jnp.float32(0.0)
+            mixer = Mamba2(cfg, pd, name="ssm") if self.kind == "M" else \
+                GQA(cfg, self.attn_impl, pd, name="attn")
+            ys, new_cache = [], cache
+            for h, r in zip(hs, rows):
+                with _scope(r.scope):
+                    if self.kind == "M":
+                        y, new_cache = mixer(h, new_cache, r.pos,
+                                             r.state_ctx)
+                    else:
+                        y, new_cache = mixer(
+                            h, freqs, new_cache, r.pos, deterministic=True,
+                            block_tables=r.block_tables)
+                ys.append(y)
+        out = []
+        for x, y, r in zip(xs, ys, rows):
+            with _scope(r.scope):
+                if cfg.resid_mult != 1.0:
+                    # in float32: 0.22 is no bfloat16 number (0.2197 is)
+                    y = (y.astype(jnp.float32)
+                         * cfg.resid_mult).astype(y.dtype)
+                out.append(x + y)
+        return out, new_cache
 
 
 class Block(nn.Module):
@@ -201,21 +252,27 @@ class LLM(nn.Module):
         to the layers of a patterned model that keep per-slot state or
         count per-row (`MixerBlock`): {"live": (B,) bool} for one token
         of every slot, {"slot": i, "valid_len": (1,)} for a chunk of one
-        sequence."""
+        sequence.
+
+        A patterned model also takes a tuple of `Rows` as `idx` (each
+        with its own `pos`, `block_tables`, `state_ctx`, `logits_idx`):
+        the row sets of one program, walked through the layers together
+        so that an expert layer makes one call over all of them; logits
+        come back as a tuple, a set each."""
         cfg = self.config
-        B, T = idx.shape
         dt = self.compute_dtype
         patterned = bool(cfg.layer_pattern)
         pd = self.param_dtype if patterned else jnp.float32
+        rows = idx if isinstance(idx, tuple) else \
+            (Rows(idx, pos, block_tables, state_ctx, logits_idx),)
+        assert len(rows) == 1 or (patterned and targets is None
+                                  and not all_logits), \
+            "several row sets: a patterned model's cached forward alone"
 
         tkn_emb = nn.Embed(cfg.vocab_size, cfg.n_embd,
                            embedding_init=_EMBED_INIT,
                            param_dtype=pd, dtype=dt, name="tkn_emb")
-        x = tkn_emb(idx)
-        if cfg.embed_mult != 1.0:
-            x = x * jnp.asarray(cfg.embed_mult, x.dtype)
-        freqs = None
-
+        freqs = pos_tab = None
         if cfg.pos_emb == "rope":
             d = cfg.rope_head_dim if cfg.attn == "mla" else cfg.head_size
             # constant under jit; XLA folds it (reference precomputes a
@@ -224,14 +281,22 @@ class LLM(nn.Module):
         elif cfg.pos_emb == "learn":
             pos_tab = self.param("pos_emb", _EMBED_INIT,
                                  (cfg.block_size, cfg.n_embd), jnp.float32)
-            p = slice_rows(pos_tab, pos, T).astype(dt)
-            x = x + (p if p.ndim == 3 else p[None])  # per-seq rows vs shared
         elif cfg.pos_emb == "sin":
-            tab = _sin_table(cfg.block_size, cfg.n_embd)
-            p = slice_rows(tab, pos, T).astype(dt)
-            x = x + (p if p.ndim == 3 else p[None])
-
-        x = nn.Dropout(cfg.dropout, deterministic=deterministic)(x)
+            pos_tab = _sin_table(cfg.block_size, cfg.n_embd)
+        drop = nn.Dropout(cfg.dropout, deterministic=deterministic)
+        xs = []
+        for r in rows:
+            with _scope(r.scope):
+                x = tkn_emb(r.idx)
+                if cfg.embed_mult != 1.0:
+                    x = x * jnp.asarray(cfg.embed_mult, x.dtype)
+                if pos_tab is not None:
+                    p = slice_rows(pos_tab, r.pos,
+                                   r.idx.shape[1]).astype(dt)
+                    # per-seq rows vs shared
+                    x = x + (p if p.ndim == 3 else p[None])
+                xs.append(drop(x))
+        x = xs[0]
 
         if cfg.pp_stages > 1:
             # pipeline-parallel block stack (models/pipeline.py): stacked
@@ -265,23 +330,60 @@ class LLM(nn.Module):
                 if patterned:
                     blk = MixerBlock(cfg, cfg.layer_pattern[i],
                                      self.attn_impl, pd, name=f"block_{i}")
-                    x, new_cache, aux = blk(x, freqs, caches[i], pos,
-                                            block_tables, state_ctx)
+                    xs, new_cache = blk(xs, rows, freqs, caches[i])
                 else:
                     blk = block_cls(cfg, self.attn_impl, deterministic,
                                     remat_attn, name=f"block_{i}")
                     x, new_cache, aux = blk(x, freqs, caches[i], pos,
                                             block_tables=block_tables)
+                    total_aux = total_aux + aux
                 new_caches.append(new_cache)
-                total_aux = total_aux + aux
 
-        if patterned:
-            x = RMSNorm(cfg.norm_eps, pd, name="ln_f")(x)
-        else:
-            x = nn.LayerNorm(dtype=dt, param_dtype=jnp.float32,
-                             name="ln_f")(x)
+        ln_f = RMSNorm(cfg.norm_eps, pd, name="ln_f") if patterned else \
+            nn.LayerNorm(dtype=dt, param_dtype=jnp.float32, name="ln_f")
+        if len(rows) == 1:
+            x = ln_f(xs[0] if patterned else x)
         head = None if cfg.tie_head else self.param(
             "lm_head", _EMBED_INIT, (cfg.vocab_size, cfg.n_embd), pd)
+
+        def last_logits(x, logits_idx):
+            """The cached forward's logits: of every position, of the
+            last, or of a row a sequence."""
+            if all_logits:
+                sel = x                            # every position (verify)
+            elif logits_idx is None:
+                sel = x[:, -1:, :]                 # last position only (:694)
+            else:
+                # bucketed prefill: each sequence's true last token sits at
+                # its own row of the right-padded buffer
+                sel = jnp.take_along_axis(
+                    x, jnp.reshape(logits_idx, (-1, 1, 1)).astype(jnp.int32),
+                    axis=1)
+            # weight-only int8 decode: the tied lm-head matmul — the
+            # single largest weight read of a decode step — reads int8
+            # codes + per-vocab-row scales when the engine's quantized
+            # store is active (ops/quant.py); otherwise the plain attend
+            from distributed_pytorch_tpu.ops.quant import \
+                maybe_quantized_matmul
+            with jax.named_scope("lm_head"):
+                if head is not None:
+                    logits = jnp.einsum("btc,vc->btv", sel, head.astype(dt))
+                else:
+                    logits = maybe_quantized_matmul(
+                        sel, ("tkn_emb", "embedding"), transpose_b=True)
+                if logits is None:
+                    logits = tkn_emb.attend(sel)   # (B, 1, V)
+                if cfg.logits_div != 1.0:
+                    logits = logits / jnp.asarray(cfg.logits_div,
+                                                  logits.dtype)
+            return logits
+
+        if len(rows) > 1:
+            out = []
+            for x, r in zip(xs, rows):
+                with _scope(r.scope):
+                    out.append(last_logits(ln_f(x), r.logits_idx))
+            return tuple(out), None, new_caches
 
         if targets is not None:
             assert head is None and cfg.logits_div == 1.0, \
@@ -373,33 +475,7 @@ class LLM(nn.Module):
             # dead-code-eliminates this matmul.
             logits = tkn_emb.attend(x)
         else:
-            if all_logits:
-                sel = x                            # every position (verify)
-            elif logits_idx is None:
-                sel = x[:, -1:, :]                 # last position only (:694)
-            else:
-                # bucketed prefill: each sequence's true last token sits at
-                # its own row of the right-padded buffer
-                sel = jnp.take_along_axis(
-                    x, jnp.reshape(logits_idx, (-1, 1, 1)).astype(jnp.int32),
-                    axis=1)
-            # weight-only int8 decode: the tied lm-head matmul — the
-            # single largest weight read of a decode step — reads int8
-            # codes + per-vocab-row scales when the engine's quantized
-            # store is active (ops/quant.py); otherwise the plain attend
-            from distributed_pytorch_tpu.ops.quant import \
-                maybe_quantized_matmul
-            with jax.named_scope("lm_head"):
-                if head is not None:
-                    logits = jnp.einsum("btc,vc->btv", sel, head.astype(dt))
-                else:
-                    logits = maybe_quantized_matmul(
-                        sel, ("tkn_emb", "embedding"), transpose_b=True)
-                if logits is None:
-                    logits = tkn_emb.attend(sel)   # (B, 1, V)
-                if cfg.logits_div != 1.0:
-                    logits = logits / jnp.asarray(cfg.logits_div,
-                                                  logits.dtype)
+            logits = last_logits(x, logits_idx)
             loss = None
 
         return logits, loss, new_caches

@@ -67,6 +67,7 @@ from typing import Any, Callable
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distributed_pytorch_tpu.config import LLMConfig
@@ -431,6 +432,20 @@ class RoutedExperts(nn.Module):
     shared expert is two plain matmuls (its width differs, so it cannot
     ride the grouped kernel as a group).
 
+    `x` may be a list of row sets (and `row_mask` the list of their masks:
+    a fused step's chunk and its decode tokens): the held experts' kernels
+    then run ONCE over all the rows (`held_experts_ffn(cuts=)`), which
+    reads each hit expert's matrices once, and the result comes back a set
+    each. The router, the shared expert and the sum of a row's parts stay
+    a call a set, in the shapes a set has alone: a decode row has to read
+    the same, bit for bit, whether or not a chunk rides beside it (greedy
+    streams part at the first near-tie otherwise), and the compiler
+    rounds and associates such a chain by the pattern it finds. Summed
+    over all rows at once, 11% of a decode row's bf16 elements moved; cut
+    out of one scatter-add, 0.002%; a scatter-add a set, none (my chip
+    runs, PR 37; the kernels themselves are bitwise blind to the rows
+    beside a row, to their number and to the tile).
+
     With `row_mask` (N,) only the rows that are real are sent to routed
     experts (the others get the shared expert's part alone), and the layer
     also returns what the routing did for them: tokens a held expert,
@@ -447,8 +462,11 @@ class RoutedExperts(nn.Module):
         from distributed_pytorch_tpu.ops.grouped_matmul import (
             _apply_activation, held_experts_ffn)
         cfg = self.config
-        B, T, C = x.shape
-        dt = x.dtype
+        listed = isinstance(x, (list, tuple))
+        xs, masks = (list(x), list(row_mask)) if listed else ([x], [row_mask])
+        many = len(xs) > 1
+        C = xs[0].shape[-1]
+        dt = xs[0].dtype
         pd = self.param_dtype
         F = cfg.up_dim
         Fs = cfg.shared_up_dim or F
@@ -470,13 +488,19 @@ class RoutedExperts(nn.Module):
         s_up = self.param("shared_up", _DENSE_INIT, (C, fan * Fs), pd)
         s_down = self.param("shared_down", _DENSE_INIT, (Fs, C), pd)
 
-        x_flat = x.reshape(-1, C)
+        flats = [x.reshape(-1, C) for x in xs]
         with jax.named_scope("moe_route"):
-            if sigmoid:
-                idx, w = route_sigmoid(x_flat, gate, bias, cfg.n_act_routed,
-                                       cfg.routed_scale)
-            else:
-                idx, w = route_softmax_topk(x_flat, gate, cfg.n_act_routed)
+            routes = [route_sigmoid(x_flat, gate, bias, cfg.n_act_routed,
+                                    cfg.routed_scale) if sigmoid else
+                      route_softmax_topk(x_flat, gate, cfg.n_act_routed)
+                      for x_flat in flats]
+        if many:
+            x_flat = jnp.concatenate(flats)
+            idx = jnp.concatenate([i for i, _ in routes])
+            w = jnp.concatenate([g for _, g in routes])
+            row_mask = jnp.concatenate(masks)
+        else:
+            x_flat, (idx, w), row_mask = flats[0], routes[0], masks[0]
         # rows that are not real (a chunk's pads, dead slots) are routed
         # nowhere: identical garbage rows all pick the same six experts and
         # would cost those experts tile after tile of weight reads (128 pad
@@ -485,11 +509,13 @@ class RoutedExperts(nn.Module):
         sent = idx if row_mask is None else \
             jnp.where(row_mask[:, None], idx, -1)
         tiles = None                  # the dense path runs no tiles
+        sizes = [f.shape[0] for f in flats]
         with jax.named_scope("moe_experts"):
             if nl in ("relu2", "swiglu"):
                 routed, tiles = held_experts_ffn(
                     x_flat, sent, w, w_up, w_down, first=first,
-                    n_routed=cfg.n_routed, gated=nl == "swiglu")
+                    n_routed=cfg.n_routed, gated=nl == "swiglu",
+                    cuts=tuple(sizes) if many else None)
             else:
                 local = sent - first
                 comb = (jax.nn.one_hot(local, n_held, dtype=jnp.float32)
@@ -498,10 +524,13 @@ class RoutedExperts(nn.Module):
                     jnp.einsum("nc,efc->enf", x_flat, w_up.astype(dt)), nl)
                 routed = jnp.einsum("enf,efc,ne->nc", h, w_down.astype(dt),
                                     comb.astype(dt)).astype(jnp.float32)
+                if many:
+                    routed = jnp.split(routed, np.cumsum(sizes)[:-1])
         with jax.named_scope("moe_shared"):
-            shared = _apply_activation(x_flat @ s_up.astype(dt), nl) \
-                @ s_down.astype(dt)
-        y = (routed + shared.astype(jnp.float32)).astype(dt).reshape(B, T, C)
+            shared = [_apply_activation(f @ s_up.astype(dt), nl)
+                      @ s_down.astype(dt) for f in flats]
+        ys = [(r + sh.astype(jnp.float32)).astype(dt).reshape(x.shape)
+              for r, sh, x in zip(routed if many else [routed], shared, xs)]
         stats = None
         if row_mask is not None:
             local = idx - first
@@ -518,4 +547,4 @@ class RoutedExperts(nn.Module):
                 stats["held_gate"] = jnp.sum(jnp.where(held, w, 0.0))[None]
                 if tiles is not None:
                     stats["tiles"] = tiles
-        return y, stats
+        return (ys if listed else ys[0]), stats

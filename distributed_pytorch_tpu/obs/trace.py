@@ -296,7 +296,10 @@ PHASES = {
     "engine.wait": "device_get of a program's sampled tokens (stat "
                    "program)",
     "engine.retire": "host bookkeeping that needs the tokens' values, up "
-                     "to and including flight.record",
+                     "to and including flight.record; folds the routing "
+                     "counts fetched with them into the engine's counters "
+                     "(expert_calls a program, merged_programs: the "
+                     "chunk-carrying ones with one expert call a layer)",
     # engine executor thread, DecodeEngine.admit
     "engine.admit": "one admission: prefix match, blocks, wave prefill "
                     "(stat bucket) or chunked bookkeeping (stat chunked)",
@@ -336,8 +339,12 @@ SCOPES = {
     "optimizer": "tx.update + apply_updates (train/step.py)",
     "grad_norm": "optax.global_norm of the gradients (train/step.py)",
     "sample": "each sample_fn call of the engine's programs",
-    "chunk_prefill": "the chunk's model.apply in fused_step",
-    "decode": "the decode model.apply in step, fused_step, spec_step",
+    "chunk_prefill": "the chunk's model.apply in fused_step; in a "
+                     "patterned model's one walk over both row sets, what "
+                     "the chunk's rows run alone (inside `decode`)",
+    "decode": "the decode model.apply in step, fused_step, spec_step; a "
+              "patterned model's fused_step whole, its expert layers' one "
+              "call over the chunk's rows and the decode rows included",
 }
 
 

@@ -607,21 +607,27 @@ def held_tile_rows(n_tokens: int, k: int, n_routed: int) -> int:
     """Token rows a tile, from the rows a held expert expects of a call (m
     = tokens x k / router width): the power of two that holds m + 3
     sqrt(m), a count's mean and three of its deviations, 16 at the least (a
-    bf16 tile's sublanes) and 128 at the most. A second tile of one expert
-    reads its matrices a second time, and a tile's rows cost next to
-    nothing beside that (the kernels are bound by the weight bytes); every
-    tile row is also a row of the packed buffers, so no larger than that.
-    Top 6 of 128: 16 rows for 64 tokens (3 expected), 32 for a 256-row
-    chunk (12); top 10 of 72: 32 (8.9) and 64 (35.6)."""
+    bf16 tile's sublanes) and 128 at the most, and 128 only where the mean
+    alone fills 64. A second tile of one expert reads its matrices a second
+    time, and a tile's rows cost next to nothing beside that in the kernels
+    (they are bound by the weight bytes); but every tile row is also a row
+    of the packed buffers, a tile a held expert whatever it got, and a tile
+    of 128 that the mean does not half fill costs more in those rows than
+    the second tiles it saves: 320 rows at top 10 of 72 (a fused step's
+    chunk and decode rows in one call, 44 expected) ran 4.7 ms a program
+    faster at 64 (5,504 packed rows, 2.0 second tiles a call) than at 128
+    (7,808, 0.06) (my chip run, PR 37). Top 6 of 128: 16 rows for 64 tokens
+    (3 expected), 32 for a 256-row chunk (12) and for 320 (15); top 10 of
+    72: 32 (8.9), 64 (35.6), 64 (44.4)."""
     m = n_tokens * k / n_routed
-    return int(min(128, max(16, 2 ** math.ceil(math.log2(
-        m + 3.0 * math.sqrt(m))))))
+    return int(min(128 if m >= 64 else 64, max(16, 2 ** math.ceil(
+        math.log2(m + 3.0 * math.sqrt(m))))))
 
 
 def held_experts_ffn(x_flat: jnp.ndarray, topk_idx: jnp.ndarray,
                      topk_gates: jnp.ndarray, w_up: jnp.ndarray,
                      w_down: jnp.ndarray, *, first: int, n_routed: int,
-                     gated: bool = False,
+                     gated: bool = False, cuts: Optional[tuple] = None,
                      interpret: Optional[bool] = None):
     """sum over a token's top-k of gate * W_down[e] relu(W_up[e] x)^2, for
     the experts e in [first, first + n_held) that `w_up` (n_held, F, C: out
@@ -630,7 +636,19 @@ def held_experts_ffn(x_flat: jnp.ndarray, topk_idx: jnp.ndarray,
     `gated`: `w_up` is (n_held, 2F, C), [a | b], and an expert computes
     W_down[e] (silu(a) * b). Returns ((N, C) float32, the tiles the two
     kernels ran (1,) int32: one for every expert hit and one more for
-    every further `held_tile_rows` rows of its own)."""
+    every further `held_tile_rows` rows of its own).
+
+    `cuts` = (n_0, n_1, ...), summing to N: the rows are several row sets,
+    one after the other, and the result comes back a set each. The kernels,
+    which read the weights, run once over all. Each set is then combined
+    by a scatter-add of its own, over its own assignments alone (n x k
+    packed rows, gathered in the packed order; no pad row), into its own
+    (n, C): the operation, the shape and the order of a row's float32
+    terms that sum the set when it is the whole call, so that what the
+    compiler makes of it is the same in both programs (cut out of ONE
+    scatter-add over all rows, a decode row beside a chunk parted from the
+    same row alone in 0.002% of its bf16 elements, and greedy streams
+    with it: my chip runs, PR 37)."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     N, C = x_flat.shape
@@ -670,4 +688,15 @@ def held_experts_ffn(x_flat: jnp.ndarray, topk_idx: jnp.ndarray,
                       interpret, gated)
     y = _held_down_call(h, w_down.astype(dt), row_gate, group, n_used, bm,
                         interpret)
-    return jnp.zeros((N, C), jnp.float32).at[row_tok].add(y), n_used
+    if cuts is None:
+        return jnp.zeros((N, C), jnp.float32).at[row_tok].add(y), n_used
+    outs, at, stok = [], 0, tok[order]
+    for n in cuts:
+        # the set's assignments, in the packed order; one that was dropped
+        # before the packing (slot P) is sent to no row
+        mine = jnp.nonzero((stok >= at) & (stok < at + n), size=n * k)[0]
+        row = jnp.where(slot[mine] < P, stok[mine] - at, n)
+        outs.append(jnp.zeros((n, C), jnp.float32).at[row].add(
+            y[jnp.minimum(slot[mine], P - 1)], mode="drop"))
+        at += n
+    return outs, n_used

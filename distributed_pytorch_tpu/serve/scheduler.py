@@ -318,6 +318,14 @@ class Scheduler:
             "serve_chunk_programs_per_prompt",
             lambda: getattr(self.engine, "chunk_programs_per_prompt", 0.0),
             "chunk-carrying step programs per prompt chunked in")
+        # which fused program the engine runs (make_fused_step_fn): 1.0
+        # where a chunk-carrying program walks a patterned model's layers
+        # once and its expert layers make one call, 0 where it runs the
+        # model twice (a classic model, a quantised engine)
+        self.metrics.register_gauge(
+            "serve_merged_program_share",
+            lambda: getattr(self.engine, "merged_program_share", 0.0),
+            "chunk-carrying step programs that read the held experts once")
         # a patterned model's layers (engine/decode.py): how many of the
         # held experts a call of an expert layer hits (the weight bytes
         # it must read), how evenly the held experts are loaded, what

@@ -34,8 +34,8 @@ Observability plane (ISSUE 9):
 * `GET /debug/timeline` — the engine's step-level flight recorder: the
   last N fused steps' `{step_ms, n_live, prefill_tokens, emitted,
   blocks_in_use, preemptions}` records (`?n=` bounds the count), beside
-  the engine's lifetime `overlap_share`, `chunk_fill_share` and
-  `chunk_programs_per_prompt`.
+  the engine's lifetime `overlap_share`, `chunk_fill_share`,
+  `merged_program_share` and `chunk_programs_per_prompt`.
 * `POST /admin/profile?duration_ms=N` — on-demand `jax.profiler` capture
   on a live replica (obs/profile.py, output under `runs/.../profile`);
   one capture at a time — a concurrent request gets 409.
@@ -306,6 +306,8 @@ class ServeApp:
             # `overlapped` / `prefill_tokens` they are made of
             "overlap_share": getattr(eng, "overlap_share", 0.0),
             "chunk_fill_share": getattr(eng, "chunk_fill_share", 0.0),
+            "merged_program_share":
+                getattr(eng, "merged_program_share", 0.0),
             "chunk_programs_per_prompt":
                 getattr(eng, "chunk_programs_per_prompt", 0.0)})
 

@@ -911,6 +911,12 @@ class DecodeEngine:
         # fused step in a bounded ring — the /debug/timeline payload and
         # the runs/*.jsonl post-hoc artifact
         self.flight = FlightRecorder(capacity=flight_capacity)
+        # a turn of the recorder begins at the last record while work
+        # waits for the caller: the last step() left live slots and
+        # nothing emptied them since. `_traces_seen`: the trace guards'
+        # sum at the turn's start
+        self._work_waits = False
+        self._traces_seen = 0
 
     # ------------------------------------------------------------------
     # jitted device programs
@@ -1163,6 +1169,9 @@ class DecodeEngine:
     @property
     def spec_step_traces(self) -> int:
         return self.trace_guards["spec_step"].count
+
+    def _n_traces(self) -> int:
+        return sum(g.count for g in self.trace_guards.values())
 
     @property
     def accepted_token_rate(self) -> float:
@@ -1490,6 +1499,8 @@ class DecodeEngine:
         keeps the admission-bound contract), and the prompt is chunked
         into subsequent fused steps — `first_token` is None and arrives
         via `StepResult.emitted` when the last chunk runs."""
+        if not self._slots:
+            self._work_waits = False    # an idle engine: nobody waited
         with phase("engine.admit",
                    chunked=int(self.prefill_chunk > 0)) as admitting:
             free = self.free_slots
@@ -2020,13 +2031,19 @@ class DecodeEngine:
             # to this stamp, so they sum to step_ms less the few
             # microseconds between phases
             t_rec = time.perf_counter()
-            self.flight.record(
+            parts = {"prepare": acc.get("engine.prepare", 0.0) * 1e3,
+                     "dispatch": acc.get("engine.dispatch", 0.0) * 1e3,
+                     "wait": acc["engine.wait"] * 1e3,
+                     "retire": (t_rec - retire.t0) * 1e3}
+            # the turn (obs/flight.py): this call and the caller's gap
+            # before it; a trace guard that fired inside is its `compile`
+            traces, seen = self._n_traces(), self._traces_seen
+            self._traces_seen = traces
+            self.flight.record_turn(
+                "engine", parts, t_rec, compiled=traces != seen,
                 step=prog.t + 1,
                 step_ms=round((t_rec - t_step0) * 1e3, 3),
-                prepare_ms=round(acc.get("engine.prepare", 0.0) * 1e3, 3),
-                dispatch_ms=round(acc.get("engine.dispatch", 0.0) * 1e3, 3),
-                wait_ms=round(acc["engine.wait"] * 1e3, 3),
-                retire_ms=round((t_rec - retire.t0) * 1e3, 3),
+                **{f"{k}_ms": round(v, 3) for k, v in parts.items()},
                 n_live=prog.n_live, prefill_tokens=prefill_tokens,
                 emitted=n_emitted,
                 retired=len(retired) - len(prog.preempted),
@@ -2075,6 +2092,7 @@ class DecodeEngine:
             self.overrun_tokens += len(cur.occupants)
             cur = None
         if cur is None and not self._slots:
+            self._work_waits = False
             return StepResult({}, {})
         # the call's host phases (obs/trace.py PHASES): leaves in the
         # profiler's trace, joined by `step` = the number of the program
@@ -2082,7 +2100,11 @@ class DecodeEngine:
         # record, from the same stamps
         step = cur.t if cur is not None else self._t
         acc: dict = {}
-        t_step0 = time.perf_counter()
+        # the flight record's turn: begun at the last record where that
+        # call left live slots (work waited for the caller), else here
+        t_step0 = self.flight.begin_turn(self._work_waits)
+        if not self._work_waits:
+            self._traces_seen = self._n_traces()
         queue: list[_Program] = []
         nxt = None
         with phase("engine.prepare", acc, step=step):
@@ -2122,6 +2144,7 @@ class DecodeEngine:
                 self.overrun_tokens += len(nxt.occupants)
             nxt = why = None
         self._inflight, self._declined = nxt, why
+        self._work_waits = bool(self._slots)
         return res
 
     def run(self, prompts, max_new_tokens,

@@ -529,8 +529,13 @@ class RoutedExperts(nn.Module):
         with jax.named_scope("moe_shared"):
             shared = [_apply_activation(f @ s_up.astype(dt), nl)
                       @ s_down.astype(dt) for f in flats]
-        ys = [(r + sh.astype(jnp.float32)).astype(dt).reshape(x.shape)
-              for r, sh, x in zip(routed if many else [routed], shared, xs)]
+        # under the combine's name: the compiler folds this add into the
+        # scatter-add of `held_experts_ffn` (its zeros become the shared
+        # expert's output), and a fused op goes by its root's scope
+        with jax.named_scope("moe_combine"):
+            ys = [(r + sh.astype(jnp.float32)).astype(dt).reshape(x.shape)
+                  for r, sh, x in zip(routed if many else [routed], shared,
+                                      xs)]
         stats = None
         if row_mask is not None:
             local = idx - first

@@ -14,12 +14,15 @@ that trace in two ways, both tabled in `obs.trace`:
   `train.data` / `train.sync` / `train.drain` / `train.eval` /
   `train.ckpt`), joined by a `step` stat. A phase is a TraceMe: one atomic
   load when no capture runs. An idle gap of the device is named after the
-  phase that covers it.
+  phase that covers it. `HOST_EVENTS` (`host.gc`) are spans that are no
+  phase of a loop and fall inside any.
 * **named scopes** (`jax.named_scope`, names in `SCOPES`) in the compiled
   steps where no flax module name reaches: `kv_update`, `attn_core`,
   `lm_head`, `loss`, `optimizer`, `grad_norm`, `sample`, `chunk_prefill`,
-  `decode`. They land in every device op's name path, so device time has
-  an owner in the program's own words.
+  `decode`; a patterned model's mixers have theirs in `MIXER_SCOPES`
+  (`ssm_*`, `moe_route`, `moe_experts` with `moe_pack` and `moe_combine`
+  inside, `moe_shared`). They land in every device op's name path, so
+  device time has an owner in the program's own words.
 
 `scripts/profile_step.py --analyze_only --trace_dir <dir>` and the
 benchmark's per-layer metrics read both back with one reduction
@@ -34,7 +37,23 @@ from the same stamps.** They answer questions a capture is too short for:
   `prefill_tokens`, ...), on a wall-anchored monotonic clock, served at
   `GET /debug/timeline`. `train/telemetry.py` wraps the same ring for the
   trainer (`step_ms`, `data_ms`, `dispatch_ms`, `sync_ms`, `ckpt_ms`),
-  dumped to `runs/<run>/train_timeline.jsonl`.
+  dumped to `runs/<run>/train_timeline.jsonl`. A record is also a TURN:
+  what it covers plus what preceded it since the last one (`t0`, `gap_ms`
+  = the caller's time between two `step()` calls while work waited), with
+  what else ran in it: `gc_ms` / `gc_gen` (collector pauses, from one
+  process-wide `gc.callbacks` hook that also writes each pause into the
+  profiler's trace as `host.gc`, `obs.trace.HOST_EVENTS`), `cpu_ms` (the
+  writer thread's own CPU time), `capturing`. A turn over 3x
+  the running median of the last 256, or one that compiled, is STALLED:
+  booked with its `owner` (`gap` or a phase), its `cause` (`compile` |
+  `capture` | `gc` | `host_busy` | `blocked` | `caller` | `mixed`) and its
+  excess into a process-wide log of 256 that ordinary records never evict
+  (`obs.flight.stall_log()`, `stall_totals()`). It is read at
+  `GET /debug/timeline` (`stalls`, `stall_totals`), at `/metrics`
+  (`serve_engine_stalls_total{cause}`, `..._stall_seconds_total{cause}`,
+  `serve_host_gc_pause_seconds_total{generation}`; `train_*` twins) and by
+  the benchmark's `stall_share_pct.*`, `stall_max_ms.*`,
+  `host_gc_ms_per_s.*`.
 * `obs.trace.TraceRecorder` — per-REQUEST spans on `perf_counter` (router
   dispatch, queue wait, prefill, decode, failover, retire) under an
   `X-Trace-Id`, recorded at terminal events only, exportable as

@@ -18,16 +18,153 @@ drained it. Served live at
 `GET /debug/timeline` (serve/server.py) and dumped to `runs/*.jsonl` by
 the bench legs and the fault-injection harness for post-hoc analysis
 against the PERF.md latency models.
+
+**Turns and stalls** (`begin_turn` / `record_turn`). A TURN is what one
+record covers plus what preceded it since the last one: for the engine from
+the previous record's stamp, while work waited for the caller, to this
+one's (`gap_ms` + `step_ms`), for the trainer one drained log window. A
+turn's record also says what else ran in it: `gc_ms` / `gc_gen` (collector
+pauses that ended inside it, any thread, from one process-wide
+`gc.callbacks` hook that also writes each pause into the profiler's trace
+as `host.gc`), `cpu_ms` (the writer thread's own CPU time over the turn:
+computing or asleep) and `capturing` (a profiler capture at either end, or
+one that went off inside the turn before: writing it out takes seconds). A
+turn longer than `STALL_FACTOR` x the running median of the recorder's last
+`MEDIAN_TURNS` unstalled turns, or one that compiled, is STALLED: booked
+with its `owner` (its largest part), its `cause` (`CAUSES`) and its
+`excess_ms` over the median into a process-wide log that ordinary records
+never evict (`stall_log()`), beside process-wide totals (`stall_totals()`).
+Process-wide because what is measured is: a collection or a descheduling
+stops every thread.
 """
 
 from __future__ import annotations
 
 import collections
+import gc
 import json
 import os
+import statistics
 import threading
 import time
 from typing import Optional
+
+from distributed_pytorch_tpu.obs.trace import HOST_GC, TraceAnnotation
+
+#: A turn is stalled beyond this many running medians. A chunk-carrying
+#: step program is 1.4-1.9x a plain one in every serving cell (24.4 / 17.5,
+#: 37.7 / 22.7, 27.2 / 14.6 ms; PERF.md section 5) and must never be
+#: flagged; the stalls hunted are 5-200x.
+STALL_FACTOR = 3.0
+MEDIAN_TURNS = 256      # unstalled turns the running median looks back over
+MEDIAN_EVERY = 64       # ... and is taken anew every so many of them
+#: unstalled turns before there is a median to judge by: a recorder's first
+#: few are its loop's warm-up (two slots live where sixty-four will be), and
+#: a median of four of them flagged every program of the load that followed
+MIN_TURNS = 16
+#: this many stalled turns in a row are no stalls but another load (a batch
+#: three times the size): the median is learnt anew from the turns after
+REGIME_TURNS = 16
+STALL_LOG = 256         # stalled turns the process keeps
+
+#: Why a turn stalled, the first that holds. `compile`: a trace guard fired
+#: inside it. `capture`: a profiler capture started, ran or stopped. `gc`:
+#: collector pauses of at least half the excess. `host_busy` / `blocked`:
+#: the owner is a phase of the writer's thread, which spent at least half /
+#: under a tenth of it on the CPU (Python computing / asleep in the runtime,
+#: on a lock, or off the CPU). `caller`: the owner is the gap between two
+#: calls. `mixed`: none of them.
+CAUSES = ("compile", "capture", "gc", "host_busy", "blocked", "caller",
+          "mixed")
+
+# process-wide: the collector's pauses, the stalled turns of every recorder
+# and the totals they are shares of
+_lock = threading.Lock()
+_stalls: collections.deque = collections.deque(maxlen=STALL_LOG)
+_totals: dict[str, dict] = {}           # source -> turns, seconds, causes
+_gc_seconds = [0.0, 0.0, 0.0]           # pause seconds by generation
+_gc_pauses = [0, 0, 0]
+_gc_open: Optional[tuple] = None        # (start stamp, annotation | None)
+
+
+def _on_gc(when: str, info: dict) -> None:
+    """The process's one `gc.callbacks` hook. Start and stop of a collection
+    run on the thread that triggered it, under the interpreter lock, and
+    collections do not nest: the open pause is one module slot."""
+    global _gc_open
+    if when == "start":
+        ann = None
+        if TraceAnnotation.is_enabled():
+            ann = TraceAnnotation(HOST_GC, generation=info["generation"])
+            ann.__enter__()
+        _gc_open = (time.perf_counter(), ann)
+    elif _gc_open is not None:
+        (t0, ann), _gc_open = _gc_open, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        _gc_seconds[info["generation"]] += time.perf_counter() - t0
+        _gc_pauses[info["generation"]] += 1
+
+
+def stall_log() -> list[dict]:
+    """The process's stalled turns (the last `STALL_LOG`), newest last: the
+    whole flight record plus `source`, `owner`, `cause`, `excess_ms`,
+    `median_ms`."""
+    with _lock:
+        return list(_stalls)
+
+
+def stall_totals() -> dict:
+    """What the stalls are shares of. Per source (`engine` | `train`):
+    `turns`, `turn_seconds`, `gc_seconds` (pauses that ended inside its
+    turns) and per cause `count`, `excess_seconds`, `longest_ms`; for the
+    process the collector's pause seconds and pauses by generation."""
+    with _lock:
+        return {"sources": {src: {**tot, "causes": {
+                    c: dict(v) for c, v in tot["causes"].items()}}
+                            for src, tot in _totals.items()},
+                "gc_pause_seconds": list(_gc_seconds),
+                "gc_pauses": list(_gc_pauses)}
+
+
+def metric_families(source: str, prefix: str, host_prefix: str) -> dict:
+    """The `/metrics` view of the totals, for `register_family` of a
+    registry: `<prefix>_stalls_total{cause}`, `<prefix>_stall_seconds_total
+    {cause}` (excess seconds) of `source`'s turns, and the process's
+    `<host_prefix>_gc_pause_seconds_total{generation}`."""
+    def causes(key):
+        return {c: v[key] for c, v in stall_totals()["sources"].get(
+            source, {}).get("causes", {}).items()}
+    return {
+        f"{prefix}_stalls_total": (
+            "cause", lambda: causes("count"),
+            f"stalled {source} turns (over {STALL_FACTOR:g}x the running "
+            "median, or compiling) by cause; /debug/timeline `stalls`"),
+        f"{prefix}_stall_seconds_total": (
+            "cause", lambda: causes("excess_seconds"),
+            f"seconds stalled {source} turns ran over the running median"),
+        f"{host_prefix}_gc_pause_seconds_total": (
+            "generation",
+            lambda: dict(enumerate(stall_totals()["gc_pause_seconds"])),
+            "seconds the cyclic collector paused the process"),
+    }
+
+
+def _cause(owner: str, owner_ms: float, excess_ms: float, rec: dict,
+           compiled: bool) -> str:
+    if compiled:
+        return "compile"
+    if rec["capturing"]:
+        return "capture"
+    if rec["gc_ms"] >= 0.5 * excess_ms:
+        return "gc"
+    if owner == "gap":
+        return "caller"
+    if rec["cpu_ms"] >= 0.5 * owner_ms:
+        return "host_busy"
+    if rec["cpu_ms"] < 0.1 * owner_ms:
+        return "blocked"
+    return "mixed"
 
 
 class FlightRecorder:
@@ -53,18 +190,140 @@ class FlightRecorder:
         # jump backwards or overlap
         self._wall0 = time.time()  # lint: allow(wall-clock)
         self._mono0 = time.monotonic()
+        # the open turn (one writer thread): its start, its entry, and the
+        # marks its record takes the growth of
+        self._t0: Optional[float] = None
+        self._t_in = 0.0
+        self._cpu0 = 0.0
+        self._gc0 = (0.0, (0, 0, 0))
+        # a capture is the open turn's | ran at the writer's last stamp
+        self._capturing = self._capture_on = False
+        # the running median of the unstalled turns, in ms
+        self._turns: collections.deque = collections.deque(
+            maxlen=MEDIAN_TURNS)
+        self._fed = 0
+        self._median: Optional[float] = None
+        self._stalled_in_a_row = 0
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
 
     def record(self, **fields) -> None:
         """Append one step record, stamped with `t` = the construction
         wall-clock anchor plus a monotonic delta."""
         if not self.enabled:
             return
+        self._append(fields)
+
+    def _append(self, fields: dict) -> None:
         fields["t"] = round(self._wall0 + (time.monotonic() - self._mono0), 4)
         with self._lock:
             if len(self._ring) == self.capacity:
                 self.dropped += 1
             self.total += 1
             self._ring.append(fields)
+
+    def begin_turn(self, waited: bool = False) -> float:
+        """The writer enters a turn's own work; returns the `perf_counter`
+        stamp of the entry. `waited`: work waited for this call since the
+        last record, so the turn began THERE (its `gap_ms`) and keeps that
+        record's collector mark; otherwise it begins here. A trainer calls
+        this once: each of its turns begins at the last one's record."""
+        self._t_in = t = time.perf_counter()
+        self._capture_on = on = TraceAnnotation.is_enabled()
+        if not waited or self._t0 is None:
+            self._t0 = t
+            self._cpu0 = time.thread_time()
+            self._gc0 = (sum(_gc_seconds), tuple(_gc_pauses))
+            self._capturing = on
+        else:
+            # a capture that ran at the last record and was stopped in the
+            # gap (writing it out takes seconds) is this turn's too
+            self._capturing = self._capturing or on
+        return t
+
+    def record_turn(self, source: str, phases: dict, t1: float, *,
+                    compiled: bool = False, **fields) -> None:
+        """Close the open turn at stamp `t1` with one record of `fields`
+        plus the turn's own (module docstring), judge it, and open the next
+        at `t1`. `phases` = the ms of each part an `owner` can be
+        (`gap` is added here); `compiled` = a trace guard fired inside."""
+        if not self.enabled:
+            return
+        turn_ms = (t1 - self._t0) * 1e3
+        cpu = time.thread_time()
+        on = TraceAnnotation.is_enabled()
+        gc_s, gc_n = sum(_gc_seconds), tuple(_gc_pauses)
+        rec = fields
+        rec["t0"] = round(self._t0, 6)
+        rec["turn_ms"] = round(turn_ms, 3)
+        if self._t_in > self._t0:
+            rec["gap_ms"] = round((self._t_in - self._t0) * 1e3, 3)
+        rec["gc_ms"] = round((gc_s - self._gc0[0]) * 1e3, 3)
+        if gc_n != self._gc0[1]:
+            rec["gc_gen"] = max(g for g in range(3)
+                                if gc_n[g] != self._gc0[1][g])
+        # one read a turn (a system call: ~6 us alone on the bench host, ~37
+        # beside the runtime's busy threads): a turn that waited takes the
+        # growth since the last record, its caller's gap on this thread
+        # included
+        rec["cpu_ms"] = round(max(cpu - self._cpu0, 0.0) * 1e3, 3)
+        rec["capturing"] = self._capturing or on
+        # the next turn opens here, whether or not its writer says so. A
+        # capture that went off since the writer's last stamp (another
+        # thread stopped it) is written out for seconds yet: the next
+        # turn's too
+        self._t0 = self._t_in = t1
+        self._cpu0, self._gc0 = cpu, (gc_s, gc_n)
+        self._capturing, self._capture_on = on or self._capture_on, on
+        median = self._median or 0.0
+        stall = self._judge(turn_ms, compiled)
+        if stall is not None:
+            parts = dict(phases, gap=rec.get("gap_ms", 0.0))
+            owner = max(parts, key=parts.get)
+            rec.update(source=source, owner=owner,
+                       cause=_cause(owner, parts[owner], stall, rec,
+                                    compiled),
+                       excess_ms=round(stall, 3),
+                       median_ms=round(median, 3))
+        self._append(rec)
+        with _lock:
+            tot = _totals.setdefault(source, {
+                "turns": 0, "turn_seconds": 0.0, "gc_seconds": 0.0,
+                "causes": {}})
+            tot["turns"] += 1
+            tot["turn_seconds"] += turn_ms / 1e3
+            tot["gc_seconds"] += rec["gc_ms"] / 1e3
+            if stall is not None:
+                by = tot["causes"].setdefault(rec["cause"], {
+                    "count": 0, "excess_seconds": 0.0, "longest_ms": 0.0})
+                by["count"] += 1
+                by["excess_seconds"] += stall / 1e3
+                by["longest_ms"] = max(by["longest_ms"], rec["excess_ms"])
+                _stalls.append(rec)
+
+    def _judge(self, turn_ms: float, compiled: bool) -> Optional[float]:
+        """The turn's excess over the running median where it is stalled,
+        else None; an unstalled turn feeds the median."""
+        if compiled:
+            return max(turn_ms - (self._median or 0.0), 0.0)
+        if self._median is not None \
+                and turn_ms > STALL_FACTOR * self._median:
+            self._stalled_in_a_row += 1
+            excess = turn_ms - self._median
+            if self._stalled_in_a_row >= REGIME_TURNS:
+                self._turns.clear()
+                self._fed, self._median = 0, None
+            return excess
+        self._stalled_in_a_row = 0
+        self._turns.append(turn_ms)
+        self._fed += 1
+        # early on whenever the count doubles, so that a young recorder
+        # judges by more than its first few turns
+        if self._fed % MEDIAN_EVERY == 0 or (
+                MIN_TURNS <= self._fed < MEDIAN_EVERY
+                and self._fed & (self._fed - 1) == 0):
+            self._median = statistics.median(self._turns)
+        return None
 
     def __len__(self) -> int:
         return len(self._ring)

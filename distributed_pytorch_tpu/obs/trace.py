@@ -318,6 +318,20 @@ PHASES = {
     "train.ckpt": "the interval checkpoint's synchronous part",
 }
 
+#: Host events that are no phase of a loop: they fall inside any phase, on
+#: any thread. Outside `PHASES` on purpose: the benchmark's readers of the
+#: phases (`benchmark/lib/trace_spans.PHASE_LAYERS`) read what they read
+#: without them, while whatever names an idle gap after every host event
+#: (`trace_reduce.attribute_gaps`, `scripts/profile_step.py`) can name one
+#: after these.
+HOST_GC = "host.gc"
+HOST_EVENTS = {
+    HOST_GC: "one pause of the cyclic collector (stat generation), opened "
+             "and closed on the thread that triggered it by the process's "
+             "gc.callbacks hook (obs/flight.py), which also books its "
+             "seconds into the flight records' gc_ms",
+}
+
 #: The phases that launch a step's device work: opened as a
 #: StepTraceAnnotation (`step_num` = the phase's `step`), so XProf groups
 #: the device work they enqueue under that step.
@@ -371,6 +385,15 @@ MIXER_SCOPES = {
                    "expert_matmul_down), the combine "
                    "(ops/grouped_matmul.py held_experts_ffn)",
     "moe_shared": "the shared expert's two matmuls (models/mlp.py)",
+    # inside `moe_experts`, around what is not a kernel (PR 38)
+    "moe_pack": "the sort of the assignments by held expert, the counts "
+                "and tile table, and the gather of the rows into the "
+                "packed (P, C) buffer (ops/grouped_matmul.py "
+                "held_experts_ffn)",
+    "moe_combine": "the float32 scatter-add(s) of the packed rows back "
+                   "to their tokens, one a row set (held_experts_ffn), "
+                   "and the add of the shared expert's output, which the "
+                   "compiler folds into them (models/mlp.py)",
 }
 
 

@@ -659,44 +659,52 @@ def held_experts_ffn(x_flat: jnp.ndarray, topk_idx: jnp.ndarray,
     n_tiles = -(-A // bm) + n_held
     P = n_tiles * bm
 
-    e = topk_idx.reshape(-1).astype(jnp.int32) - first
-    held = (e >= 0) & (e < n_held)
-    e = jnp.where(held, e, n_held)            # absent: sorted last, dropped
-    tok = jnp.repeat(jnp.arange(N, dtype=jnp.int32), k)
-    order = jnp.argsort(e, stable=True)
-    se = e[order]
-    counts = jnp.zeros((n_held + 1,), jnp.int32).at[e].add(1)
-    padded = -(-counts // bm) * bm
-    pstart = jnp.cumsum(padded) - padded
-    starts = jnp.cumsum(counts) - counts
-    slot = pstart[se] + jnp.arange(A, dtype=jnp.int32) - starts[se]
-    slot = jnp.where(se < n_held, slot, P)    # out of range: dropped
-    row_tok = jnp.zeros((P,), jnp.int32).at[slot].set(tok[order],
-                                                      mode="drop")
-    row_gate = jnp.zeros((P, 1), jnp.float32).at[slot, 0].set(
-        topk_gates.reshape(-1).astype(jnp.float32)[order], mode="drop")
-    tile_start = pstart // bm
-    n_used = tile_start[n_held]
-    t = jnp.arange(n_tiles, dtype=jnp.int32)
-    group = jnp.clip(jnp.searchsorted(tile_start[:n_held], t, side="right")
-                     - 1, 0, n_held - 1).astype(jnp.int32)
-    group = jnp.where(t < n_used, group, group[jnp.maximum(n_used - 1, 0)])
-    n_used = n_used.reshape(1)
+    # the two scopes (obs/trace.py MIXER_SCOPES) are names on the ops and
+    # nothing else: the programs' text less locations is the same
+    with jax.named_scope("moe_pack"):
+        e = topk_idx.reshape(-1).astype(jnp.int32) - first
+        held = (e >= 0) & (e < n_held)
+        e = jnp.where(held, e, n_held)        # absent: sorted last, dropped
+        tok = jnp.repeat(jnp.arange(N, dtype=jnp.int32), k)
+        order = jnp.argsort(e, stable=True)
+        se = e[order]
+        counts = jnp.zeros((n_held + 1,), jnp.int32).at[e].add(1)
+        padded = -(-counts // bm) * bm
+        pstart = jnp.cumsum(padded) - padded
+        starts = jnp.cumsum(counts) - counts
+        slot = pstart[se] + jnp.arange(A, dtype=jnp.int32) - starts[se]
+        slot = jnp.where(se < n_held, slot, P)    # out of range: dropped
+        row_tok = jnp.zeros((P,), jnp.int32).at[slot].set(tok[order],
+                                                          mode="drop")
+        row_gate = jnp.zeros((P, 1), jnp.float32).at[slot, 0].set(
+            topk_gates.reshape(-1).astype(jnp.float32)[order], mode="drop")
+        tile_start = pstart // bm
+        n_used = tile_start[n_held]
+        t = jnp.arange(n_tiles, dtype=jnp.int32)
+        group = jnp.clip(
+            jnp.searchsorted(tile_start[:n_held], t, side="right") - 1,
+            0, n_held - 1).astype(jnp.int32)
+        group = jnp.where(t < n_used, group,
+                          group[jnp.maximum(n_used - 1, 0)])
+        n_used = n_used.reshape(1)
+        packed = x_flat[row_tok]
 
     dt = x_flat.dtype
-    h = _held_up_call(x_flat[row_tok], w_up.astype(dt), group, n_used, bm,
-                      interpret, gated)
+    h = _held_up_call(packed, w_up.astype(dt), group, n_used, bm, interpret,
+                      gated)
     y = _held_down_call(h, w_down.astype(dt), row_gate, group, n_used, bm,
                         interpret)
-    if cuts is None:
-        return jnp.zeros((N, C), jnp.float32).at[row_tok].add(y), n_used
-    outs, at, stok = [], 0, tok[order]
-    for n in cuts:
-        # the set's assignments, in the packed order; one that was dropped
-        # before the packing (slot P) is sent to no row
-        mine = jnp.nonzero((stok >= at) & (stok < at + n), size=n * k)[0]
-        row = jnp.where(slot[mine] < P, stok[mine] - at, n)
-        outs.append(jnp.zeros((n, C), jnp.float32).at[row].add(
-            y[jnp.minimum(slot[mine], P - 1)], mode="drop"))
-        at += n
-    return outs, n_used
+    with jax.named_scope("moe_combine"):
+        if cuts is None:
+            return jnp.zeros((N, C), jnp.float32).at[row_tok].add(y), n_used
+        outs, at, stok = [], 0, tok[order]
+        for n in cuts:
+            # the set's assignments, in the packed order; one that was
+            # dropped before the packing (slot P) is sent to no row
+            mine = jnp.nonzero((stok >= at) & (stok < at + n),
+                               size=n * k)[0]
+            row = jnp.where(slot[mine] < P, stok[mine] - at, n)
+            outs.append(jnp.zeros((n, C), jnp.float32).at[row].add(
+                y[jnp.minimum(slot[mine], P - 1)], mode="drop"))
+            at += n
+        return outs, n_used

@@ -99,6 +99,22 @@ def _render_info(name: str, help_: str, info: dict) -> list[str]:
             f"{name}{{{labels}}} 1"]
 
 
+def render_families(families: dict) -> list[str]:
+    """Labelled counters read live at render time (`register_family` of
+    ServeMetrics and TrainMetrics): name -> (label, fn, help), `fn()` =
+    {label value: number}."""
+    lines: list[str] = []
+    for name, (label, fn, help_) in sorted(families.items()):
+        lines += [f"# HELP {name} {help_}", f"# TYPE {name} counter"]
+        try:
+            series = fn()
+        except Exception:  # pragma: no cover — source died mid-shutdown
+            continue
+        for value, n in sorted(series.items()):
+            lines.append(f"{name}{_labels({label: value})} {n}")
+    return lines
+
+
 class Histogram:
     """Prometheus-style cumulative histogram + exact quantiles."""
 
@@ -402,6 +418,7 @@ class ServeMetrics:
 
     def __init__(self):
         self._gauges: dict[str, tuple[Callable[[], float], str]] = {}
+        self._families: dict[str, tuple[str, Callable[[], dict], str]] = {}
         self.ttft = Histogram(
             "serve_ttft_seconds",
             "submit to first streamed token (queue wait + bucketed prefill)")
@@ -506,6 +523,13 @@ class ServeMetrics:
                        help_: str = "") -> None:
         """Register a live-read gauge (queue depth, slot occupancy)."""
         self._gauges[name] = (fn, help_)
+
+    def register_family(self, name: str, label: str,
+                        fn: Callable[[], dict], help_: str) -> None:
+        """Register a live-read counter with one label (`fn()` = {label
+        value: number}): totals kept elsewhere in the process, such as the
+        stalled turns of obs/flight.py by cause."""
+        self._families[name] = (label, fn, help_)
 
     def set_build_info(self, **info) -> None:
         """Merge provenance labels into the build-info gauge (model
@@ -623,6 +647,7 @@ class ServeMetrics:
                   "fraction over all fused steps",
                   "# TYPE serve_slot_occupancy_mean gauge",
                   f"serve_slot_occupancy_mean {self.mean_occupancy:.4f}"]
+        lines += render_families(self._families)
         for name, (fn, help_) in sorted(self._gauges.items()):
             if help_:
                 lines.append(f"# HELP {name} {help_}")

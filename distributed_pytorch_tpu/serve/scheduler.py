@@ -73,6 +73,7 @@ from typing import Optional
 
 from distributed_pytorch_tpu.config import knob
 from distributed_pytorch_tpu.engine.decode import Retired
+from distributed_pytorch_tpu.obs import flight as obs_flight
 from distributed_pytorch_tpu.obs import trace as obs_trace
 from distributed_pytorch_tpu.ops.block_pool import NoFreeBlocks
 from distributed_pytorch_tpu.serve.control import ClassPolicy, normalize_class
@@ -306,6 +307,12 @@ class Scheduler:
             "serve_engine_overlap_share",
             lambda: getattr(self.engine, "overlap_share", 0.0),
             "fraction of step programs dispatched behind a running one")
+        # the turns the engine's flight recorder judged stalled and the
+        # collector's pauses (obs/flight.py; process-wide, as what they
+        # measure is); each stall is in /debug/timeline's `stalls`
+        for name, family in obs_flight.metric_families(
+                "engine", "serve_engine", "serve_host").items():
+            self.metrics.register_family(name, *family)
         # chunked prefill (DecodeEngine._next_chunk): a chunk-carrying
         # program computes `prefill_chunk` rows whatever they hold. The
         # share of them that held a prompt id, and how many such programs

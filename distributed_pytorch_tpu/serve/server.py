@@ -58,6 +58,7 @@ import time
 import urllib.parse
 from typing import Optional
 
+from distributed_pytorch_tpu.obs import flight as obs_flight
 from distributed_pytorch_tpu.obs import profile as obs_profile
 from distributed_pytorch_tpu.obs import trace as obs_trace
 from distributed_pytorch_tpu.sample import TokenizerUnavailable
@@ -302,6 +303,10 @@ class ServeApp:
         return _json_response(200, {
             "entries": fl.entries(n), "n_steps": fl.total,
             "dropped": fl.dropped, "capacity": fl.capacity,
+            # the process's stalled turns, which the ring's ordinary
+            # records never evict, newest last, and what they are shares of
+            "stalls": obs_flight.stall_log(),
+            "stall_totals": obs_flight.stall_totals(),
             # the engine's lifetime shares, beside the per-program
             # `overlapped` / `prefill_tokens` they are made of
             "overlap_share": getattr(eng, "overlap_share", 0.0),

@@ -615,6 +615,13 @@ def train(model_cfg: LLMConfig, train_cfg: TrainConfig,
     # profiler's trace, and this window's seconds per phase for the
     # timeline records (data_ms, dispatch_ms, sync_ms)
     win: dict = {}
+    # the flight recorder's turn (obs/flight.py): one drained log window,
+    # from the last boundary's record to this one's, evals and the
+    # checkpoint between them included; the first window and any retrace
+    # are its `compile`
+    traces_seen = 0
+    if tel.enabled:
+        tel.flight.begin_turn()
     stopped_early = False
     with _graceful_stop() as stop:
         for it in range(start_step, train_cfg.max_iters + 1):
@@ -647,7 +654,7 @@ def train(model_cfg: LLMConfig, train_cfg: TrainConfig,
                 break
 
             if train_cfg.eval and it % train_cfg.eval_interval == 0:
-                with phase("train.eval", step=it):
+                with phase("train.eval", win, step=it):
                     t0 = time.perf_counter()
                     ev = estimate_loss(eval_step, eval_view(state),
                                        {"train": eval_train_loader,
@@ -686,7 +693,7 @@ def train(model_cfg: LLMConfig, train_cfg: TrainConfig,
                 sync_s = win["train.sync"]         # host blocked on the drain
                 dt = (t_now - win_t0) / len(pending)
                 win_t0 = t_now
-                with phase("train.drain", step=it):
+                with phase("train.drain", step=it) as draining:
                     n_got = len(got)               # window is contiguous iters
                     data_s = win.get("train.data", 0.0) / n_got
                     first_window = not stats["train_losses"]
@@ -749,7 +756,20 @@ def train(model_cfg: LLMConfig, train_cfg: TrainConfig,
                                     win["train.dispatch"] / n_got * 1e3, 3)
                                 if hbm_now:
                                     rec["hbm_gb"] = round(hbm_now, 3)
-                            tel.record_step(**rec)
+                                t_rec = time.perf_counter()
+                                parts = {name[len("train."):]: s * 1e3
+                                         for name, s in win.items()}
+                                parts["drain"] = (t_rec - draining.t0) * 1e3
+                                traces = (step_guard.count
+                                          if step_guard is not None else 0)
+                                tel.flight.record_turn(
+                                    "train", parts, t_rec,
+                                    compiled=(first_window
+                                              or traces != traces_seen),
+                                    **rec)
+                                traces_seen = traces
+                            else:
+                                tel.record_step(**rec)
                     if tel.enabled:
                         tel.metrics.inc("steps", n_got)
                         tel.metrics.observe_phases(
@@ -782,7 +802,7 @@ def train(model_cfg: LLMConfig, train_cfg: TrainConfig,
                             f"{drop_s}")
 
             if ckpt_due:
-                with phase("train.ckpt", step=it):
+                with phase("train.ckpt", win, step=it):
                     # interval saves are async: serialization overlaps the next
                     # steps instead of stalling them (train/checkpoint.py)
                     path = ckpt.save_checkpoint_async(

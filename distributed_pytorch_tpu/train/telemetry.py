@@ -46,8 +46,10 @@ from collections import deque
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Callable, Optional
 
+from distributed_pytorch_tpu.obs import flight as obs_flight
 from distributed_pytorch_tpu.obs.flight import FlightRecorder
-from distributed_pytorch_tpu.serve.metrics import (Histogram, _render_info)
+from distributed_pytorch_tpu.serve.metrics import (Histogram, _render_info,
+                                                   render_families)
 
 # Train steps span ~1 ms (tiny CPU smoke) to tens of seconds (1.5B with
 # remat); the serve grid covers the same decades.
@@ -85,6 +87,10 @@ class TrainMetrics:
         self.anomaly_counts: dict[str, int] = {}       # kind -> n
         self.build_info: dict[str, str] = {}
         self._gauges: dict[str, tuple[Callable[[], float], str]] = {}
+        # the trainer's stalled turns and the collector's pauses
+        # (obs/flight.py), the twins of serve_engine_stalls_total & co.
+        self._families = obs_flight.metric_families("train", "train",
+                                                    "train_host")
 
     def inc(self, name: str, n: int = 1) -> None:
         with self._lock:
@@ -131,6 +137,7 @@ class TrainMetrics:
                              f'{self.counters[name]}')
             for kind, n in sorted(self.anomaly_counts.items()):
                 lines.append(f'train_anomalies_total{{kind="{kind}"}} {n}')
+        lines += render_families(self._families)
         for name, (fn, help_) in sorted(self._gauges.items()):
             if help_:
                 lines.append(f"# HELP {name} {help_}")
@@ -438,8 +445,10 @@ class TelemetryServer:
                     fl = tel.flight
                     self._send(200, json.dumps(
                         {"entries": fl.entries(n), "n_steps": fl.total,
-                         "dropped": fl.dropped,
-                         "capacity": fl.capacity}).encode())
+                         "dropped": fl.dropped, "capacity": fl.capacity,
+                         "stalls": obs_flight.stall_log(),
+                         "stall_totals": obs_flight.stall_totals()}
+                    ).encode())
                 elif path == "/healthz":
                     try:
                         body = status()

@@ -96,15 +96,28 @@ def analyze(trace_dir: str, top: int = 25) -> None:
                     set(MIXER_SCOPES) | {"ssm"}):
                 print("with the mixers' scopes (obs/trace.py MIXER_SCOPES):")
                 trace_scope_ms.say_table(named)
-        idle = trace_idle_owner.table(sl, trace_spans.PHASE_LAYERS)
-        if idle is not None:
-            trace_idle_owner.say_table(idle)
+    # host events that are no phase of a loop (obs/trace.py HOST_EVENTS: a
+    # collector pause) lie inside the phases: they take the idle time they
+    # cover before the phase around them does
+    from distributed_pytorch_tpu.obs.trace import HOST_EVENTS
+    space = trace_spans.read_xspace(
+        path, lambda plane, name: plane == trace_reduce.HOST_PLANE
+        and name in HOST_EVENTS)
+    host = space.get(trace_reduce.HOST_PLANE, {"lines": {}, "meta": {}})
+    events = [(host["meta"][mid][0], start, dur, st)
+              for evs in host["lines"].values()
+              for mid, start, dur, st in evs if st is not None]
+    phases = [trace_spans.phase_events(sl, p)
+              for p in trace_spans.PHASE_LAYERS]
+    if sl["ops"] and (events or any(phases)):
+        trace_idle_owner.say_table(trace_spans.split_idle(
+            trace_spans.all_gaps(sl["ops"]), [events] + phases))
     by_name: dict = {}
-    for layer in trace_spans.PHASE_LAYERS:
-        for name, _, dur, _ in trace_spans.phase_events(sl, layer):
-            by_name.setdefault(name, []).append(dur / 1e6)
+    for name, _, dur, _ in events + [e for evs in phases for e in evs]:
+        by_name.setdefault(name, []).append(dur / 1e6)
     if by_name:
-        print("host phases (obs/trace.py PHASES): count, median ms")
+        print("host phases and events (obs/trace.py PHASES, HOST_EVENTS): "
+              "count, median ms")
     for name, ms in by_name.items():
         print(f"  {name:<16} {len(ms):6d} {trace_spans.median(ms):10.3f}")
 

@@ -316,19 +316,35 @@ def test_debug_timeline_after_load_burst(mv):
                                       "/debug/timeline?n=512")
         status2, body2 = await http_get(rep.app.port,
                                         "/debug/timeline?n=2")
+        _, metrics = await http_get(rep.app.port, "/metrics")
         flight = rep.eng.flight
         await rep.stop()
         return status, json.loads(body), status2, json.loads(body2), \
-            flight
+            flight, metrics
 
-    status, body, status2, body2, flight = run_async(main())
+    status, body, status2, body2, flight, metrics = run_async(main())
     assert status == 200
     entries = body["entries"]
     assert entries and body["n_steps"] == flight.total
     for e in entries:
         assert {"t", "step", "step_ms", "n_live", "prefill_tokens",
                 "emitted", "blocks_in_use", "preemptions",
-                "overlapped", "drain_reason", "overrun"} <= set(e)
+                "overlapped", "drain_reason", "overrun",
+                # the turn (obs/flight.py)
+                "t0", "turn_ms", "gc_ms", "cpu_ms", "capturing"} <= set(e)
+    # the process's stalled turns ride beside the ring, newest last: this
+    # engine's first step traced its program
+    stalls = [s for s in body["stalls"] if s["source"] == "engine"]
+    assert stalls and stalls == sorted(stalls, key=lambda s: s["t"])
+    assert {"owner", "cause", "excess_ms", "median_ms"} <= set(stalls[-1])
+    assert "compile" in {s["cause"] for s in stalls}
+    totals = body["stall_totals"]
+    assert totals["sources"]["engine"]["turns"] >= len(entries)
+    assert len(totals["gc_pause_seconds"]) == 3
+    assert "# TYPE serve_engine_stalls_total counter" in metrics
+    assert 'serve_engine_stalls_total{cause="compile"}' in metrics
+    assert 'serve_engine_stall_seconds_total{cause="compile"}' in metrics
+    assert 'serve_host_gc_pause_seconds_total{generation="2"}' in metrics
     # a wave engine never queues a program behind a running one, and
     # the record says why
     assert {(e["overlapped"], e["drain_reason"]) for e in entries} == \

@@ -236,6 +236,17 @@ def test_metrics_endpoint_serves_mid_run(in_tmp):
             f"http://127.0.0.1:{port}/debug/timeline?n=8"))
         assert tl["n_steps"] >= 1 and tl["entries"]
         assert {"it", "loss", "grad_norm"} <= set(tl["entries"][-1])
+        # the boundary record is a turn of the recorder (obs/flight.py),
+        # and the first window, which compiles, is in the stall log
+        assert {"t0", "turn_ms", "gc_ms", "cpu_ms", "sync_ms"} <= \
+            set(tl["entries"][-1]) and "gap_ms" not in tl["entries"][-1]
+        first = [s for s in tl["stalls"] if s.get("compile_window")][-1]
+        assert (first["source"], first["cause"]) == ("train", "compile")
+        assert first["owner"] in ("data", "dispatch", "sync", "drain")
+        assert tl["stall_totals"]["sources"]["train"]["turns"] >= 1
+        assert 'train_stalls_total{cause="compile"}' in text
+        assert "train_stall_seconds_total" in text
+        assert 'train_host_gc_pause_seconds_total{generation="0"}' in text
 
         hz = json.loads(_get(f"http://127.0.0.1:{port}/healthz"))
         assert hz["ok"] and hz["run"] == "telrun" and hz["it"] >= 0

@@ -436,15 +436,17 @@ class RoutedExperts(nn.Module):
     a fused step's chunk and its decode tokens): the held experts' kernels
     then run ONCE over all the rows (`held_experts_ffn(cuts=)`), which
     reads each hit expert's matrices once, and the result comes back a set
-    each. The router, the shared expert and the sum of a row's parts stay
-    a call a set, in the shapes a set has alone: a decode row has to read
-    the same, bit for bit, whether or not a chunk rides beside it (greedy
-    streams part at the first near-tie otherwise), and the compiler
-    rounds and associates such a chain by the pattern it finds. Summed
-    over all rows at once, 11% of a decode row's bf16 elements moved; cut
-    out of one scatter-add, 0.002%; a scatter-add a set, none (my chip
-    runs, PR 37; the kernels themselves are bitwise blind to the rows
-    beside a row, to their number and to the tile).
+    each. A decode row has to read the same, bit for bit, whether or not a
+    chunk rides beside it (greedy streams part at the first near-tie
+    otherwise). The routed part does by construction: a token gathers its
+    own k rows of the kernels' result and adds them in the router's order
+    by written-out float32 adds, and the kernels are bitwise blind to the
+    rows beside a row, to their number and to the tile (my chip runs, PR
+    37). The router and the shared expert are XLA matmuls, which the
+    compiler rounds and associates by the shapes it finds (summed over all
+    rows at once, 11% of a decode row's bf16 elements moved, PR 37): they
+    stay a call a set, in the shapes a set has alone, and the shared
+    expert's output is added to a set's routed sum by one float32 add.
 
     With `row_mask` (N,) only the rows that are real are sent to routed
     experts (the others get the shared expert's part alone), and the layer
@@ -529,9 +531,9 @@ class RoutedExperts(nn.Module):
         with jax.named_scope("moe_shared"):
             shared = [_apply_activation(f @ s_up.astype(dt), nl)
                       @ s_down.astype(dt) for f in flats]
-        # under the combine's name: the compiler folds this add into the
-        # scatter-add of `held_experts_ffn` (its zeros become the shared
-        # expert's output), and a fused op goes by its root's scope
+        # under the combine's name: one float32 add a row set after the
+        # token-side sum of `held_experts_ffn`, which the compiler may
+        # fuse into that sum's last op
         with jax.named_scope("moe_combine"):
             ys = [(r + sh.astype(jnp.float32)).astype(dt).reshape(x.shape)
                   for r, sh, x in zip(routed if many else [routed], shared,

@@ -390,10 +390,13 @@ MIXER_SCOPES = {
                 "and tile table, and the gather of the rows into the "
                 "packed (P, C) buffer (ops/grouped_matmul.py "
                 "held_experts_ffn)",
-    "moe_combine": "the float32 scatter-add(s) of the packed rows back "
-                   "to their tokens, one a row set (held_experts_ffn), "
-                   "and the add of the shared expert's output, which the "
-                   "compiler folds into them (models/mlp.py)",
+    "moe_combine": "the token-side sum: one gather of every token's k "
+                   "rows of the packed float32 result, by the packing's "
+                   "inverse, added in the router's order by written-out "
+                   "float32 adds, so a row is bitwise blind to the rows "
+                   "beside it by construction (held_experts_ffn); and the "
+                   "add of the shared expert's output, one a row set "
+                   "(models/mlp.py)",
 }
 
 

@@ -638,17 +638,25 @@ def held_experts_ffn(x_flat: jnp.ndarray, topk_idx: jnp.ndarray,
     kernels ran (1,) int32: one for every expert hit and one more for
     every further `held_tile_rows` rows of its own).
 
+    The combine is the token's: a row GATHERS the (at most k) gated rows
+    its held experts wrote into the packed (P, C) float32 result, by the
+    packing's inverse `slot_of` (N, k), and adds them left to right in the
+    order the router returned its assignments, k - 1 written-out float32
+    adds; an assignment that was dropped before the packing (absent
+    expert, masked row) adds exactly 0.0. No packed row is walked that no
+    token asks for (the pads), and nothing is scattered.
+
     `cuts` = (n_0, n_1, ...), summing to N: the rows are several row sets,
-    one after the other, and the result comes back a set each. The kernels,
-    which read the weights, run once over all. Each set is then combined
-    by a scatter-add of its own, over its own assignments alone (n x k
-    packed rows, gathered in the packed order; no pad row), into its own
-    (n, C): the operation, the shape and the order of a row's float32
-    terms that sum the set when it is the whole call, so that what the
-    compiler makes of it is the same in both programs (cut out of ONE
-    scatter-add over all rows, a decode row beside a chunk parted from the
-    same row alone in 0.002% of its bf16 elements, and greedy streams
-    with it: my chip runs, PR 37)."""
+    one after the other, and the result comes back a set each, summed a
+    set (the ops a set has in a call of its own). The kernels, which read
+    the weights, run once over all. A row's value depends on its own k
+    slots and its own rows of the kernels' result alone, and the kernels
+    are bitwise blind to the rows beside a row (my chip runs, PR 37), so a
+    set reads the same bit for bit in a call of its own and beside other
+    sets BY CONSTRUCTION: the order of a row's terms is the program's
+    text, not the pattern the compiler finds (a scatter-add over packed
+    rows, which this replaced, had to be one a set to match, PR 37, and
+    ran at a seventh to a quarter of the row gather's rate, PR 40)."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     N, C = x_flat.shape
@@ -660,7 +668,7 @@ def held_experts_ffn(x_flat: jnp.ndarray, topk_idx: jnp.ndarray,
     P = n_tiles * bm
 
     # the two scopes (obs/trace.py MIXER_SCOPES) are names on the ops and
-    # nothing else: the programs' text less locations is the same
+    # nothing else
     with jax.named_scope("moe_pack"):
         e = topk_idx.reshape(-1).astype(jnp.int32) - first
         held = (e >= 0) & (e < n_held)
@@ -688,23 +696,37 @@ def held_experts_ffn(x_flat: jnp.ndarray, topk_idx: jnp.ndarray,
                           group[jnp.maximum(n_used - 1, 0)])
         n_used = n_used.reshape(1)
         packed = x_flat[row_tok]
+        # the packing's inverse, in the router's order: where assignment j
+        # of token t went (P: nowhere)
+        slot_of = jnp.zeros((A,), jnp.int32).at[order].set(
+            slot, unique_indices=True).reshape(N, k)
 
     dt = x_flat.dtype
     h = _held_up_call(packed, w_up.astype(dt), group, n_used, bm, interpret,
                       gated)
     y = _held_down_call(h, w_down.astype(dt), row_gate, group, n_used, bm,
                         interpret)
+
+    def token_sum(slots):
+        """(n, k) slots of a row set -> its (n, C) float32 sum."""
+        n = slots.shape[0]
+        # one gather in k-major order, (k, n, C): term j of every token is
+        # g[j], no relayout of a k axis; a dropped assignment's index is
+        # clamped and its value selected away
+        at_j = slots.T
+        g = y[jnp.minimum(at_j.reshape(-1), P - 1)].reshape(k, n, C)
+        terms = [jnp.where((at_j[j] < P)[:, None], g[j], 0.0)
+                 for j in range(k)]
+        out = terms[0]
+        for term in terms[1:]:    # written out: the order is the text's
+            out = out + term
+        return out
+
     with jax.named_scope("moe_combine"):
         if cuts is None:
-            return jnp.zeros((N, C), jnp.float32).at[row_tok].add(y), n_used
-        outs, at, stok = [], 0, tok[order]
+            return token_sum(slot_of), n_used
+        outs, at = [], 0
         for n in cuts:
-            # the set's assignments, in the packed order; one that was
-            # dropped before the packing (slot P) is sent to no row
-            mine = jnp.nonzero((stok >= at) & (stok < at + n),
-                               size=n * k)[0]
-            row = jnp.where(slot[mine] < P, stok[mine] - at, n)
-            outs.append(jnp.zeros((n, C), jnp.float32).at[row].add(
-                y[jnp.minimum(slot[mine], P - 1)], mode="drop"))
+            outs.append(token_sum(slot_of[at:at + n]))
             at += n
         return outs, n_used

@@ -15,6 +15,7 @@ A compile that passes is not a chip run; numerics and times come from
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 # nothing is attached, so nothing is contended: without this, test workers
@@ -204,14 +205,17 @@ CASES = {
 GRANITE_EXPERTS = dict(C=4096, F=768, held=36, k=10, n_routed=72, gated=True)
 
 
-def _held_experts(N, C=2688, F=1856, held=64, k=6, n_routed=128,
+def _held_experts(rows, C=2688, F=1856, held=64, k=6, n_routed=128,
                   gated=False):
+    """`rows`: the call's tokens, or a tuple of row sets (`cuts`)."""
+    cuts = rows if isinstance(rows, tuple) else None
+    N = sum(cuts) if cuts else rows
     shapes = [((N, C), BF16), ((N, k), I32), ((N, k), F32),
               ((held, (2 if gated else 1) * F, C), BF16),
               ((held, F, C), BF16)]
     return (lambda x, i, g, wu, wd: gm.held_experts_ffn(
         x, i, g, wu, wd, first=0, gated=gated, n_routed=n_routed,
-        interpret=False)), shapes
+        cuts=cuts, interpret=False)), shapes
 
 
 def _compile(fn, shapes, chip):
@@ -348,9 +352,34 @@ def test_no_whole_pool_copy_in_a_serving_step(geometry, kind, kernels, v5e):
         assert census.get(name), (kind, census)
 
 
-@pytest.mark.parametrize("widths", [{}, GRANITE_EXPERTS],
-                         ids=["relu2_64of128", "gated_36of72"])
-@pytest.mark.parametrize("n_tokens", [64, 256])
+# a plain program's call, a chunk alone, a fused program's merged call
+EXPERT_CALLS = pytest.mark.parametrize("n_tokens", [64, 256, (256, 64)],
+                                       ids=["64", "256", "256+64"])
+
+
+EXPERT_WIDTHS = pytest.mark.parametrize(
+    "widths", [{}, GRANITE_EXPERTS], ids=["relu2_64of128", "gated_36of72"])
+_expert_programs = {}
+
+
+def _compiled_experts(n_tokens, widths, chip):
+    """(compiled `held_experts_ffn`, its text, its operands' shapes): one
+    compile a case, read by both tests below. The temporaries' bound rides
+    along: a 256-row chunk at top 10 packs 4,864 rows of 4096, the merged
+    call 5,504, ~100 MB of packed buffers, the price of the tile
+    (held_tile_rows)."""
+    key = (n_tokens, bool(widths))
+    if key not in _expert_programs:
+        fn, shapes = _held_experts(n_tokens, **widths)
+        compiled = _compile(fn, shapes, chip)
+        assert compiled.memory_analysis().temp_size_in_bytes < (
+            160 if widths and n_tokens != 64 else 64) * 2 ** 20
+        _expert_programs[key] = compiled.as_text(), shapes
+    return _expert_programs[key]
+
+
+@EXPERT_WIDTHS
+@EXPERT_CALLS
 def test_no_copy_of_an_expert_stack(n_tokens, widths, v5e):
     """The held experts' two stacks reach the kernels as they lie: no
     `copy` of a stack's shape and next to no temporaries. (With the up
@@ -358,18 +387,34 @@ def test_no_copy_of_an_expert_stack(n_tokens, widths, v5e):
     parameter out minor-in-2688 and every call began with a 639 MB
     relayout copy; out by in it has none. The gated stack is (36, 1536,
     4096) as published: out by in already.)"""
-    fn, shapes = _held_experts(n_tokens, **widths)
-    compiled = _compile(fn, shapes, v5e)
-    text = compiled.as_text()
+    text, shapes = _compiled_experts(n_tokens, widths, v5e)
     for stack in {shapes[3][0], shapes[4][0]}:
         stack = "bf16[%s]" % ",".join(map(str, stack))
         copies = [ln.strip()[:160] for ln in text.splitlines()
                   if f"= {stack}" in ln and " copy(" in ln]
         assert not copies, copies
-    # a 256-row chunk at top 10 packs 4,864 rows of 4096: ~100 MB of
-    # packed buffers, the price of the tile (held_tile_rows)
-    assert compiled.memory_analysis().temp_size_in_bytes < (
-        160 if widths and n_tokens == 256 else 64) * 2 ** 20
+
+
+@EXPERT_WIDTHS
+@EXPERT_CALLS
+def test_the_combine_gathers_a_tokens_rows_and_scatters_none(n_tokens,
+                                                             widths, v5e):
+    """The combine is a token's gather of its k rows of the packed float32
+    result and a chain of adds: no scatter into an (n, C) float32 result is
+    left (a row scatter-add ran at a seventh to a quarter of the row
+    gather's rate on the chip, PR 40), the gather is k-major, (k, n, C),
+    so no (n, k, C) array exists whose k axis the device would pad to a
+    tile (10 -> 16, behind a relayout), and its rows cost no temporaries
+    beyond the packed buffers."""
+    text, shapes = _compiled_experts(n_tokens, widths, v5e)
+    C, k = shapes[0][0][1], shapes[1][0][1]
+    scatters = [ln.strip()[:120] for ln in text.splitlines()
+                if re.search(rf"= f32\[\d+,{C}\]\S* scatter\(", ln)]
+    assert not scatters, scatters
+    for n in n_tokens if isinstance(n_tokens, tuple) else (n_tokens,):
+        assert f"f32[{n},{k},{C}]" not in text
+        assert re.search(rf"= f32\[{n * k},{C}\]\S* fusion\(.*"
+                         r"moe_combine/gather", text), (n, k, C)
 
 
 @pytest.mark.parametrize("H, G", [(64, 8), (128, 1)],

@@ -8,6 +8,8 @@ counter that says which program an engine runs. Both routers: the sigmoid
 top-k over relu^2 experts (tests/test_hybrid.py's fixture) and the softmax
 top-k over gated ones (tests/test_granite.py's)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -265,12 +267,97 @@ def test_the_layer_gives_each_row_set_what_it_gets_alone(mv):
         (ya, yb), stats = layer.apply(params, [xa, xb], [ma, mb])
         ya1, sa = layer.apply(params, xa, ma)
         yb1, sb = layer.apply(params, xb, mb)
-    np.testing.assert_allclose(ya, ya1, atol=1e-6, rtol=1e-6)
-    np.testing.assert_allclose(yb, yb1, atol=1e-6, rtol=1e-6)
+    np.testing.assert_array_equal(ya, ya1)
+    np.testing.assert_array_equal(yb, yb1)
     assert ya.shape == xa.shape and yb.shape == xb.shape
     np.testing.assert_array_equal(stats["tokens"],
                                   sa["tokens"] + sb["tokens"])
     assert int(stats["absent"][0]) == int(sa["absent"][0] + sb["absent"][0])
+
+
+# (c') the combine: a token gathers its experts' rows ------------------------
+
+@pytest.fixture(scope="module", params=["relu2_64of128_top6",
+                                        "gated_36of72_top10"])
+def combined(request):
+    """`held_experts_ffn` over 256 + 64 rows at the two cells' routing
+    (narrow widths, float32, interpret mode), beside the call over each set
+    alone and the kernels' own packed result `y` of the merged call. Among
+    the rows: masked ones (ids -1), tokens none of whose experts is held,
+    and absent experts in nearly every other row."""
+    gated = request.param.startswith("gated")
+    held, n_routed, k, first = (36, 72, 10, 0) if gated else (64, 128, 6, 64)
+    N, C, F, cuts = 320, 128, 64, (256, 64)
+    ks = jax.random.split(jax.random.PRNGKey(k), 5)
+    x = jax.random.normal(ks[0], (N, C))
+    w_up = jax.random.normal(ks[1], (held, (2 if gated else 1) * F, C)) * 0.1
+    w_down = jax.random.normal(ks[2], (held, F, C)) * 0.1
+    idx = jnp.argsort(jax.random.uniform(ks[3], (N, n_routed)),
+                      axis=1)[:, :k].astype(jnp.int32)
+    absent = (jnp.arange(n_routed - held)
+              + jnp.where(first, 0, held))[:k].astype(jnp.int32)
+    idx = idx.at[3::16].set(absent)                   # nothing held
+    idx = idx.at[5::8].set(-1)                        # pads, dead slots
+    gates = jax.random.uniform(ks[4], (N, k), minval=0.1)
+    kw = dict(first=first, n_routed=n_routed, gated=gated, interpret=True)
+
+    packed, down = [], gm._held_down_call
+
+    def keep(*a, **b):
+        packed.append(down(*a, **b))
+        return packed[-1]
+
+    with pytest.MonkeyPatch.context() as mp, HI:
+        mp.setattr(gm, "_held_down_call", keep)
+        sets, _ = gm.held_experts_ffn(x, idx, gates, w_up, w_down,
+                                      cuts=cuts, **kw)
+        alone, at = [], 0
+        for n in cuts:
+            alone.append(gm.held_experts_ffn(
+                x[at:at + n], idx[at:at + n], gates[at:at + n], w_up,
+                w_down, **kw)[0])
+            at += n
+    local = np.asarray(idx) - first
+    return dict(sets=[np.asarray(a) for a in sets],
+                alone=[np.asarray(a) for a in alone], y=np.asarray(packed[0]),
+                local=np.where((local >= 0) & (local < held), local, held),
+                held=held, tile=gm.held_tile_rows(N, k, n_routed))
+
+
+def test_a_row_set_beside_another_reads_what_it_reads_alone(combined):
+    """Bit for bit, float32: a row's sum is made of its own k rows of the
+    kernels' result, whatever rows the call holds beside it."""
+    for beside, alone in zip(combined["sets"], combined["alone"]):
+        assert beside.dtype == np.float32
+        np.testing.assert_array_equal(beside, alone)
+
+
+def test_a_row_is_the_sum_of_its_experts_rows_in_the_routers_order(combined):
+    """Against the kernels' own packed result: experts ascending, each
+    expert's rows in assignment order from a tile boundary; a token's row
+    is ((y[s_0] + y[s_1]) + ...) over its held assignments as the router
+    returned them, in float32, bit for bit."""
+    local, held, bm, y = (combined[n] for n in ("local", "held", "tile", "y"))
+    counts = np.bincount(local.reshape(-1), minlength=held + 1)[:held]
+    start = np.concatenate([[0], np.cumsum(-(-counts // bm) * bm)])
+    seen = np.zeros(held + 1, int)
+    want = np.zeros((local.shape[0], y.shape[1]), np.float32)
+    for t, row in enumerate(local):
+        terms = []
+        for e in row:
+            terms.append(np.zeros_like(y[0]) if e == held
+                         else y[start[e] + seen[e]])
+            seen[e] += 1
+        want[t] = functools.reduce(np.add, terms)
+    np.testing.assert_array_equal(np.concatenate(combined["sets"]), want)
+    assert np.abs(want).max() > 0.1
+
+
+def test_a_token_with_no_held_expert_gets_exactly_zero(combined):
+    out = np.concatenate(combined["sets"])
+    nothing = (combined["local"] == combined["held"]).all(axis=1)
+    assert nothing.sum() >= 40 + 20 and not out[nothing].any()
+    assert out[~nothing].any(axis=1).all()
 
 
 # (d) the tile at the merged call's rows -------------------------------------

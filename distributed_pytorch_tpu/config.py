@@ -106,6 +106,8 @@ register_knob("GMM_BLOCK_N", "512", int,
               "grouped-matmul out-feature tile")
 register_knob("GMM_BLOCK_K", "512", int,
               "grouped-matmul contraction tile")
+# the CONTIGUOUS decode kernel's tile; the paged kernels' tile is a pool
+# block and the paged float kernel sizes its own ring (`_walk_shape`)
 register_knob("FLASH_DECODE_BLOCK", "512", int,
               "flash-decode kv-length tile (ops/flash_decode.py)")
 

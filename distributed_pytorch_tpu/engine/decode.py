@@ -549,6 +549,11 @@ class _Program:
     retiring: dict          # slot -> 'budget' | 'cache_full' with this one
     preempted: dict         # seq_id -> Retired, yielded before it ran
     n_live: int             # decoding slots in its mask
+    # cache tiles of `block_size` rows its decode attention call walks for
+    # those slots, from the planned lengths: the paged kernel's grid step
+    # is a sequence and holds every one of its live tiles
+    # (`DecodeEngine.decode_tiles_per_grid_step`)
+    live_tiles: int = 0
     # the fused chunk: (slot, seq_id, take, prefill rows after it)
     chunk: Optional[tuple] = None
     # a speculative step: (draft, draft_len, device accept lengths)
@@ -882,6 +887,11 @@ class DecodeEngine:
         # of those programs, the ones whose expert layers made ONE call
         # over the chunk's rows and the decode rows (`make_fused_step_fn`)
         self.merged_programs = 0
+        # what the programs' decode attention calls walked, by the host's
+        # own lengths (`decode_tiles_per_grid_step`): live cache tiles and
+        # the grid steps that held them (one a decoding sequence)
+        self.decode_live_tiles = 0
+        self.decode_live_steps = 0
         # tokens computed for an occupant that had left by the drain: an
         # `eos` seen one program late, a cancel while its program ran
         self.overrun_tokens = 0
@@ -1208,6 +1218,17 @@ class DecodeEngine:
         0 for a classic or a quantised engine, which run the model twice."""
         return (self.merged_programs / self.chunk_programs
                 if self.chunk_programs else 0.0)
+
+    @property
+    def decode_tiles_per_grid_step(self) -> float:
+        """Lifetime live cache tiles a grid step of the paged decode
+        kernel held (ops/flash_decode.py: a grid step is one sequence and
+        walks all its live tiles with several fetches in flight), from
+        the planned lengths: 1.0 = every decoding sequence held one
+        tile, the kernel had nothing to overlap; at most the block
+        table's width. 0 before the first decode call."""
+        return (self.decode_live_tiles / self.decode_live_steps
+                if self.decode_live_steps else 0.0)
 
     @property
     def chunk_programs_per_prompt(self) -> float:
@@ -1819,6 +1840,8 @@ class DecodeEngine:
             if spec is None:
                 seq.n_new += 1
                 seq.pos += 1
+                # the rows its attention call reads, this token's included
+                prog.live_tiles += -(-seq.pos // self.block_size)
                 self._plan_retirement(slot, seq, prog.retiring)
         if chunk is not None:
             slot_c, take = chunk
@@ -1963,6 +1986,9 @@ class DecodeEngine:
                 ("chunk",) * (prog.chunk is not None) + ("decode",))
             calls = self.expert_calls - calls_before
             self.state_resets += int(prog.state_reset)
+            live_steps = prog.n_live if prog.spec is None else 0
+            self.decode_live_tiles += prog.live_tiles
+            self.decode_live_steps += live_steps
             emitted: dict[int, list] = {}
             retired: dict[int, Retired] = dict(prog.preempted)
             drafted = accepted = overrun = prefill_tokens = 0
@@ -2052,6 +2078,8 @@ class DecodeEngine:
                 drafted=drafted, accepted=accepted,
                 overlapped=prog.overlapped,
                 drain_reason=prog.drain_reason, overrun=overrun,
+                decode_live_tiles=prog.live_tiles,
+                decode_live_steps=live_steps,
                 **({"experts_hit": hit, "absent_assignments": absent,
                     "expert_calls": calls, "expert_second_tiles": second,
                     "state_reset": int(prog.state_reset)}

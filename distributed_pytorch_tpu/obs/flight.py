@@ -13,8 +13,10 @@ program: `overlapped` says whether it was queued behind a running one
 (the device did not wait for the host before it), `drain_reason` why not
 (`first` | `wave` | `spec` | `tier` | `preempt`), `overrun` how many of
 its tokens were for an occupant that had left (an `eos` seen one program
-late, a cancel). The four times are those of the `step()` call that
-drained it. Served live at
+late, a cancel), `decode_live_tiles` / `decode_live_steps` what its decode
+attention call walked by the planned lengths (live cache tiles, and the
+paged kernel's grid steps that held them: one a decoding sequence). The
+four times are those of the `step()` call that drained it. Served live at
 `GET /debug/timeline` (serve/server.py) and dumped to `runs/*.jsonl` by
 the bench legs and the fault-injection harness for post-hoc analysis
 against the PERF.md latency models.

@@ -24,14 +24,26 @@ single-token attention):
 * **Per-sequence `cache_len` scalar-prefetch**
   (`pltpu.PrefetchScalarGridSpec`, same idiom as the grouped-matmul
   dispatch's tile->expert map): the (B,) valid-length vector is in SMEM
-  before the body runs, so grid steps past a sequence's last valid block
-  are predicated off with `pl.when` AND their kv index map clamps to the
-  last visible block — the revolving-buffer DMA sees an unchanged index
-  and issues no fetch. A sequence three tokens into a 1024-slot cache
-  costs one grid step, not eight: padded slots cost zero compute and
-  zero HBM traffic.
+  before the body runs, so (contiguous and int8 paged kernels) grid steps
+  past a sequence's last valid block are predicated off with `pl.when`
+  AND their kv index map clamps to the last visible block — the
+  revolving-buffer DMA sees an unchanged index and issues no fetch. A
+  sequence three tokens into a 1024-slot cache costs one grid step, not
+  eight: padded slots cost zero compute and zero HBM traffic.
 * The last partial block masks `kpos >= cache_len` to a large negative
   (NaN-free) before the max/sum update.
+* **The paged float kernel walks tiles, not a grid** (`paged_flash_decode`,
+  `_paged_kernel`): grid (B,), a step is one sequence. The pools stay in
+  HBM and the kernel starts the tile fetches itself into a ring of
+  `depth` (bs, L) k/v pairs, a cursor in SMEM running ahead over the live
+  tiles of the whole batch, so fetches are in flight across a sequence's
+  end and no step exists for a dead block; up to `group` tiles of a
+  sequence share ONE softmax update. `_walk_shape` reads both from a
+  tile's bytes and the table's width. (With one (1, bs, L) BlockSpec tile
+  a grid step (B, max_blocks), the pipeline looked one step ahead and the
+  dead steps' clamp held it on the sequence's own last block: every
+  sequence's first fetch and last update ran with nothing beside them,
+  and 1.9 us a live tile came of 1.04 of DMA. PERF.md section 6, PR 44.)
 * **Chunked-prefill variant** (`paged_flash_prefill`, round 12): the
   paged decode kernel generalized from one query row per sequence to a
   (T, rep)-packed query tile of ONE sequence — a prefill chunk written
@@ -81,9 +93,11 @@ from distributed_pytorch_tpu import config
 from distributed_pytorch_tpu.compat import (VMEM_LIMIT_BYTES,
                                             tpu_compiler_params)
 
-# KV-length tile (lane dimension of the score tiles), read from the
-# environment at import; ROADMAP S5 measures it on the chip and makes it a
-# constant.
+# KV-length tile (lane dimension of the score tiles) of the CONTIGUOUS
+# kernel `flash_decode`, read from the environment at import. No serving
+# cell runs that kernel (the engine's caches are paged, and a paged tile is
+# a pool block), so ROADMAP S5, which was settled on the paged kernel
+# (PR 44), left it unmeasured and a knob.
 DEFAULT_BLOCK_S = config.knob("FLASH_DECODE_BLOCK")
 
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps masked rows NaN-free
@@ -287,9 +301,25 @@ def _lane_head(shape, hs: int) -> jnp.ndarray:
     return jax.lax.div(lane, jnp.int32(hs))
 
 
-def _paged_kernel(cl_ref, bt_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
-                  m_ref, l_ref, *, scale: float, bs: int, hs: int,
-                  rep: int):
+#: what the walk's tile ring may take of the scoped-VMEM limit
+_WALK_VMEM_BYTES = VMEM_LIMIT_BYTES // 8
+
+
+def _walk_shape(n_max: int, pair_bytes: int) -> tuple[int, int]:
+    """(group, depth) of `_paged_kernel`'s walk, from the shapes of the
+    call alone: `depth` k/v tile pairs of `pair_bytes` sit in VMEM, as
+    many as `_WALK_VMEM_BYTES` holds between 2 and 8, and one joint
+    softmax update takes up to `group` = half of them (never more than a
+    sequence can hold), so that as many fetches again fly behind the tiles
+    being computed. gpt2-xl's 852 KB pairs give (4, 8)."""
+    depth = max(2, min(8, _WALK_VMEM_BYTES // max(pair_bytes, 1)))
+    group = max(1, min(depth // 2, n_max))
+    return group, depth
+
+
+def _paged_kernel(cl_ref, bt_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf,
+                  sem, cur, acc_ref, m_ref, l_ref, *, scale: float, bs: int,
+                  hs: int, rep: int, group: int):
     """Paged decode over MERGED-LANE pools (ops/block_pool.py `kv_lanes`):
     a k/v tile is (bs, L), every kv head's hs lanes side by side. The
     query rows arrive zero-extended to L lanes — row (r, g) holds query
@@ -299,49 +329,118 @@ def _paged_kernel(cl_ref, bt_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
     accumulates p @ v for all lanes; each row keeps only its own head's
     lanes at the end. No per-head slice at a 64-lane offset, no in-VMEM
     transpose of the tile, and the output is written lane-dense in the
-    model's (.., n_kv * hs) order. The block table ref is consumed by
-    the index maps only."""
-    del bt_ref
-    b, j = pl.program_id(0), pl.program_id(1)
-    n = cl_ref[b]
-    last_j = jax.lax.div(jnp.maximum(n, 1) - 1, bs)
+    model's (.., n_kv * hs) order.
 
-    @pl.when(j == 0)
-    def _():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    A grid step is ONE SEQUENCE and walks all its live tiles. The pools
+    stay in HBM (`pl.ANY`) and the kernel starts the fetches itself, into
+    a ring of `depth` k/v tile pairs: a cursor (`cur`: sequence, block,
+    tiles issued, tiles done; SMEM, kept from grid step to grid step) runs
+    ahead over the live tiles of the WHOLE batch in the order the walk
+    consumes them, so the first tiles of the next sequence are in flight
+    while this one's last are computed. Nothing is fetched for a dead
+    block, and there is no grid step without a live tile. Up to `group`
+    tiles of the sequence go through ONE softmax update (all their score
+    tiles, one row max over them, one `alpha`, the sum of their p @ v, one
+    rescale of the accumulator): the tiles' products do not wait on each
+    other. What a sequence's output is computed from, and in which
+    order, is its own length's doing alone."""
+    b = pl.program_id(0)
+    depth, n_max = kbuf.shape[0], bt_ref.shape[1]
 
-    @pl.when(j <= last_j)
+    def n_blocks(i):
+        return jax.lax.min(
+            jax.lax.div(jax.lax.max(cl_ref[i], 1) - 1, bs) + 1, n_max)
+
+    def fetch(blk, slot):
+        return (pltpu.make_async_copy(k_hbm.at[blk], kbuf.at[slot],
+                                      sem.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[blk], vbuf.at[slot],
+                                      sem.at[1, slot]))
+
+    def issue(_, carry):
+        """Start the fetch of the tile under the cursor into the ring's
+        next slot and move the cursor on, while a sequence is left and the
+        ring has a slot whose tile is done."""
+        pb, pj, issued = cur[0], cur[1], cur[2]
+
+        @pl.when((pb < pl.num_programs(0)) & (issued - cur[3] < depth))
+        def _():
+            for copy in fetch(bt_ref[pb, pj], jax.lax.rem(issued, depth)):
+                copy.start()
+            cur[2] = issued + 1
+            end = pj + 1 >= n_blocks(pb)
+            cur[0] = jax.lax.select(end, pb + 1, pb)
+            cur[1] = jax.lax.select(end, 0, pj + 1)
+        return carry
+
+    @pl.when(b == 0)
     def _():
-        q, k, v = q_ref[0], k_ref[0], v_ref[0]  # (R, L), (bs, L), (bs, L)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale      # (R, bs) f32
-        kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos < n, s, _NEG_INF)
-        m_prev, l_prev = m_ref[:], l_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        for i in range(4):
+            cur[i] = 0
+
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    n, nb = cl_ref[b], n_blocks(b)
+
+    def update(live, first, done):
+        """One joint update over logical blocks [first, first + live): the
+        ring's slots from `done` on."""
+        slots = [jax.lax.rem(done + t, depth) for t in range(live)]
+        for slot in slots:
+            for copy in fetch(0, slot):
+                copy.wait()
+        q = q_ref[0]                                         # (R, L)
+        scores = []
+        for t, slot in enumerate(slots):
+            s = jax.lax.dot_general(
+                q, kbuf[slot], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # (R, bs) f32
+            kpos = (first + t) * bs + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            scores.append(jnp.where(kpos < n, s, _NEG_INF))
+        m_prev = m_ref[:]
+        m_new = m_prev
+        for s in scores:
+            m_new = jnp.maximum(m_new, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
+        l_new, pv = l_ref[:] * alpha, None
+        for s, slot in zip(scores, slots):
+            p = jnp.exp(s - m_new)
+            l_new = l_new + jnp.sum(p, axis=-1, keepdims=True)
+            v = vbuf[slot]
+            d = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)          # (R, L) f32
+            pv = d if pv is None else pv + d
         m_ref[:] = m_new
-        l_ref[:] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # (R, L) f32
+        l_ref[:] = l_new
+        acc_ref[:] = acc_ref[:] * alpha + pv
 
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _():
-        out = acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
-        g_pad = out.shape[0] // rep
-        shape = (g_pad, out.shape[1])
-        own = _lane_head(shape, hs) == jax.lax.broadcasted_iota(
-            jnp.int32, shape, 0)                # row g keeps kv head g's lanes
-        for r in range(rep):                    # rows (r, g): one slab per r
-            slab = out[r * g_pad:(r + 1) * g_pad]
-            o_ref[0, r:r + 1, :] = jnp.sum(
-                jnp.where(own, slab, 0.0), axis=0,
-                keepdims=True).astype(o_ref.dtype)
+    def body(g, carry):
+        # top the ring up: all of it at the batch's first tile, then what
+        # the last update freed
+        jax.lax.fori_loop(0, depth, issue, 0)
+        done, first = cur[3], g * group
+        size = jax.lax.min(nb - first, group)
+        for live in range(1, group + 1):
+            pl.when(size == live)(
+                functools.partial(update, live, first, done))
+        cur[3] = done + size
+        return carry
+
+    jax.lax.fori_loop(0, jax.lax.div(nb + group - 1, group), body, 0)
+
+    out = acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
+    g_pad = out.shape[0] // rep
+    shape = (g_pad, out.shape[1])
+    own = _lane_head(shape, hs) == jax.lax.broadcasted_iota(
+        jnp.int32, shape, 0)                    # row g keeps kv head g's lanes
+    for r in range(rep):                        # rows (r, g): one slab per r
+        slab = out[r * g_pad:(r + 1) * g_pad]
+        o_ref[0, r:r + 1, :] = jnp.sum(
+            jnp.where(own, slab, 0.0), axis=0,
+            keepdims=True).astype(o_ref.dtype)
 
 
 def _paged_body_q8(cl_ref, bt_ref, *args, scale: float, block_s: int):
@@ -386,20 +485,21 @@ def paged_flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     The pools are the merged-lane (n_blocks, bs, L) leaves
     (`block_pool.kv_lanes`; `n_kv_heads` says how many heads share the L
     lanes) — dense row-major on the device, so the kernel reads the very
-    buffer the step's in-place write produced, one (1, bs, L) tile per
-    grid step with no padding in it. int8 pools keep the head axis,
-    (n_blocks, bs, n_kv, hs) codes + (.., n_kv, 1) float32 scale
-    sidecars, and the `_q8` body.
+    buffer the step's in-place write produced, in (bs, L) tiles with no
+    padding in them. Grid (B,): a step is a sequence, and `_paged_kernel`
+    walks its live tiles through the prefetched table with fetches of its
+    own, several in flight and running ahead into the next sequence's
+    (`_walk_shape` says how many, from the table's width and a tile's
+    bytes); the last partial block masks `kpos >= cache_len`, a block
+    past it is neither fetched nor stepped over.
 
-    This is the contiguous kernel's `cache_len` scalar-prefetch
-    generalized by ONE indirection: the grid walks each sequence's
-    logical blocks (grid dim 1 = max_blocks) and the kv index map
-    resolves logical j -> physical pool block through the prefetched
-    table. The dead-block machinery is unchanged — steps past a
-    sequence's last valid block clamp to it, the revolving-buffer DMA
-    sees an unchanged physical index and fetches nothing, and the last
-    partial block masks `kpos >= cache_len`. Gate with
-    `paged_flash_decode_usable`."""
+    int8 pools keep the head axis, (n_blocks, bs, n_kv, hs) codes +
+    (.., n_kv, 1) float32 scale sidecars, the `_q8` body and the
+    contiguous kernel's machinery one indirection deeper: grid
+    (B, max_blocks), the kv index map resolves logical j -> physical pool
+    block, steps past a sequence's last valid block clamp to it and the
+    revolving-buffer DMA, seeing an unchanged index, fetches nothing.
+    Gate with `paged_flash_decode_usable`."""
     B, nh, hs = q.shape
     bs = k.shape[1]
     n_max = block_tables.shape[1]
@@ -413,17 +513,17 @@ def paged_flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     cl = jnp.asarray(cache_len, jnp.int32).reshape(B)
     bt = jnp.asarray(block_tables, jnp.int32)
 
-    def kv_idx(b, j, cl_ref, bt_ref):
-        # clamp skipped steps to the last valid LOGICAL block, then map to
-        # its physical pool block: the revolving buffer sees an unchanged
-        # index -> no DMA for dead blocks (same trick as the contiguous
-        # kernel, one table lookup deeper)
-        last = jax.lax.div(jnp.maximum(cl_ref[b], 1) - 1, bs)
-        return (bt_ref[b, jnp.minimum(j, last)],) + (0,) * (k.ndim - 1)
-
     if quantized:
         def q_idx(b, j, cl_ref, bt_ref):
             return (b, 0, 0, 0)
+
+        def kv_idx(b, j, cl_ref, bt_ref):
+            # clamp skipped steps to the last valid LOGICAL block, then map
+            # to its physical pool block: the revolving buffer sees an
+            # unchanged index -> no DMA for dead blocks (same trick as the
+            # contiguous kernel, one table lookup deeper)
+            last = jax.lax.div(jnp.maximum(cl_ref[b], 1) - 1, bs)
+            return (bt_ref[b, jnp.minimum(j, last)], 0, 0, 0)
 
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -456,18 +556,23 @@ def paged_flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     L = k.shape[2]
     g_pad = -(-nkv // 8) * 8
     R = rep * g_pad
+    group, depth = _walk_shape(n_max, _pair_bytes(k))
 
-    def q_idx(b, j, cl_ref, bt_ref):
+    def q_idx(b, cl_ref, bt_ref):
         return (b, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, n_max),
+        grid=(B,),
         in_specs=[pl.BlockSpec((1, R, L), q_idx),
-                  pl.BlockSpec((1, bs, L), kv_idx),
-                  pl.BlockSpec((1, bs, L), kv_idx)],
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((1, rep, L), q_idx),
         scratch_shapes=[
+            pltpu.VMEM((depth, bs, L), k.dtype),
+            pltpu.VMEM((depth, bs, L), v.dtype),
+            pltpu.SemaphoreType.DMA((2, depth)),
+            pltpu.SMEM((4,), jnp.int32),
             pltpu.VMEM((R, L), jnp.float32),
             pltpu.VMEM((R, 1), jnp.float32),
             pltpu.VMEM((R, 1), jnp.float32),
@@ -475,11 +580,13 @@ def paged_flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     )
     out = pl.pallas_call(
         functools.partial(_paged_kernel, scale=float(scale), bs=bs, hs=hs,
-                          rep=rep),
+                          rep=rep, group=group),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, rep, L), q.dtype),
+        # the cursor and the fetches in flight pass from a sequence to the
+        # next: the grid runs in order
         compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         name="paged_flash_decode",
         interpret=interpret,
     )(cl, bt, _zero_extend_q(q, nkv, g_pad, L), k, v)
@@ -756,6 +863,11 @@ def _budget_decline(need: int):
             f"{VMEM_LIMIT_BYTES >> 20} MiB scoped limit")
 
 
+def _pair_bytes(k) -> int:
+    """One k tile + one v tile of a merged-lane (n_blocks, bs, L) pool."""
+    return 2 * k.shape[1] * k.shape[2] * jnp.dtype(k.dtype).itemsize
+
+
 def _kv_tile_bytes(k, rows: int, width: int) -> int:
     """Double-buffered k+v tiles of (rows, width) elements — width is the
     merged lanes a step moves, or n_kv * hs of a head-major tile (+ its
@@ -810,8 +922,10 @@ def paged_flash_decode_decline(q, k, v, block_tables, n_kv_heads: int = 0):
     mirroring `flash_decode_decline`: decode-shaped (B, 1, nh, hs) query,
     pool block size the hardware tiles (multiples of 128 rows on TPU —
     small CPU-test pages run in interpret mode at multiples of 8), no live
-    multi-device mesh, and the (bs, L) tiles + zero-extended query rows +
-    full-width accumulator within the VMEM budget. The fallback is
+    multi-device mesh, and the walk's ring of (bs, L) tile pairs + the
+    zero-extended query rows + the full-width accumulator + the score
+    tiles of one joint update within the VMEM budget (`_walk_shape`; the
+    int8 body: its double-buffered head-major tiles). The fallback is
     paged_gather + the naive path — identical semantics."""
     if q.ndim != 4 or q.shape[1] != 1:
         return f"query shape {q.shape} is not decode-shaped (B, 1, nh, hs)"
@@ -826,13 +940,23 @@ def paged_flash_decode_decline(q, k, v, block_tables, n_kv_heads: int = 0):
         scores = 3 * nkv * rep * bs * 4
         return _budget_decline(_kv_tile_bytes(k, bs, nkv * hs) + scratch
                                + scores)
-    L = k.shape[2]
-    R = rep * (-(-nkv // 8) * 8)                # zero-extended query rows
+    return _budget_decline(_walk_vmem_bytes(q, k, block_tables, nkv))
+
+
+def _walk_vmem_bytes(q, k, block_tables, nkv: int) -> int:
+    """What one grid step of `_paged_kernel` holds in VMEM: the ring of
+    tile pairs (`_walk_shape`), the double-buffered zero-extended query
+    rows, the full-width accumulator with the p @ v sum and the epilogue
+    beside it, and the score tiles of one joint update."""
+    nh, hs = q.shape[-2:]
+    bs, L = k.shape[1], k.shape[2]
+    R = (nh // nkv) * (-(-nkv // 8) * 8)        # zero-extended query rows
+    pair = _pair_bytes(k)
+    group, depth = _walk_shape(block_tables.shape[1], pair)
     qtile = 2 * R * L * jnp.dtype(q.dtype).itemsize
-    scratch = R * (L + 2) * 4 + R * L * 4       # accumulator + its epilogue
-    scores = 3 * R * bs * 4
-    return _budget_decline(_kv_tile_bytes(k, bs, L) + qtile + scratch
-                           + scores)
+    scratch = R * (L + 2) * 4 + 2 * R * L * 4
+    scores = 3 * group * R * bs * 4
+    return depth * pair + qtile + scratch + scores
 
 
 def flash_decode_decline(q, k, v):

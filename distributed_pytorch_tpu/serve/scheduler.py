@@ -333,6 +333,14 @@ class Scheduler:
             "serve_merged_program_share",
             lambda: getattr(self.engine, "merged_program_share", 0.0),
             "chunk-carrying step programs that read the held experts once")
+        # the paged decode kernel's grid step is a sequence and walks all
+        # its live cache tiles (ops/flash_decode.py): how many it held, by
+        # the planned lengths. 1.0 = one tile a sequence, nothing for the
+        # kernel's fetches in flight to overlap
+        self.metrics.register_gauge(
+            "serve_decode_tiles_per_grid_step",
+            lambda: getattr(self.engine, "decode_tiles_per_grid_step", 0.0),
+            "live cache tiles a grid step of the paged decode kernel held")
         # a patterned model's layers (engine/decode.py): how many of the
         # held experts a call of an expert layer hits (the weight bytes
         # it must read), how evenly the held experts are loaded, what

@@ -35,7 +35,8 @@ Observability plane (ISSUE 9):
   last N fused steps' `{step_ms, n_live, prefill_tokens, emitted,
   blocks_in_use, preemptions}` records (`?n=` bounds the count), beside
   the engine's lifetime `overlap_share`, `chunk_fill_share`,
-  `merged_program_share` and `chunk_programs_per_prompt`.
+  `decode_tiles_per_grid_step`, `merged_program_share` and
+  `chunk_programs_per_prompt`.
 * `POST /admin/profile?duration_ms=N` — on-demand `jax.profiler` capture
   on a live replica (obs/profile.py, output under `runs/.../profile`);
   one capture at a time — a concurrent request gets 409.
@@ -311,6 +312,8 @@ class ServeApp:
             # `overlapped` / `prefill_tokens` they are made of
             "overlap_share": getattr(eng, "overlap_share", 0.0),
             "chunk_fill_share": getattr(eng, "chunk_fill_share", 0.0),
+            "decode_tiles_per_grid_step":
+                getattr(eng, "decode_tiles_per_grid_step", 0.0),
             "merged_program_share":
                 getattr(eng, "merged_program_share", 0.0),
             "chunk_programs_per_prompt":

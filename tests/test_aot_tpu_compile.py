@@ -319,6 +319,13 @@ def _serving_shapes(kind, nh, n_blocks, n_slots, width):
     return shapes
 
 
+def _pool_copies(text, pool_shape):
+    """The compiled program's `copy` ops that produce a whole bf16 pool."""
+    pool_type = "bf16[%s]" % ",".join(map(str, pool_shape))
+    return [ln.strip()[:160] for ln in text.splitlines()
+            if f"= {pool_type}" in ln and " copy(" in ln]
+
+
 @pytest.mark.parametrize("kind,kernels", [
     ("decode", ["paged_flash_decode"]),
     ("fused", ["paged_flash_prefill", "paged_flash_decode"]),
@@ -336,9 +343,7 @@ def test_no_whole_pool_copy_in_a_serving_step(geometry, kind, kernels, v5e):
     compiled = jax.jit(_serving_step(kind, nh),
                        donate_argnums=(0, 1)).lower(*avals).compile()
     text = compiled.as_text()
-    pool_type = "bf16[%s]" % ",".join(map(str, shapes[0][0]))
-    copies = [ln.strip()[:160] for ln in text.splitlines()
-              if f"= {pool_type}" in ln and " copy(" in ln]
+    copies = _pool_copies(text, shapes[0][0])
     assert not copies, f"whole-pool copies in the {kind} program: {copies}"
     pool_bytes = 2 * n_blocks * BS * bp.kv_lanes(nh, HS)
     mem = compiled.memory_analysis()
@@ -350,6 +355,53 @@ def test_no_whole_pool_copy_in_a_serving_step(geometry, kind, kernels, v5e):
     census = paths.kernel_census(text)
     for name in kernels:
         assert census.get(name), (kind, census)
+
+
+#: the serving cells' decode calls: slots, heads, kv heads, head size, table
+#: width (gpt2-xl; nemotron's 2 kv heads under rep 16; granite's 8 under 4)
+CELL_DECODES = {"24x25x64_w8": (24, 25, 25, 64, 8),
+                "64x2kvx128_rep16_w4": (64, 32, 2, 128, 4),
+                "64x8kvx128_rep4_w4": (64, 32, 8, 128, 4)}
+
+
+@pytest.mark.parametrize("cell", list(CELL_DECODES))
+def test_one_decode_call_a_layer_inside_the_gates_vmem(cell, v5e,
+                                                       monkeypatch):
+    """A layer's write + decode read at each serving cell's shape: ONE
+    custom call named `paged_flash_decode` (`paged_decode_roofline` divides
+    one layer's bytes by the mean time of the calls of that name), the
+    pools read where the write left them, and the kernel compiles with the
+    scoped-VMEM limit set to what its gate counts for it
+    (`_walk_vmem_bytes`): the gate never lets through what Mosaic would
+    refuse."""
+    n_slots, nh, nkv, hs, width = CELL_DECODES[cell]
+    n_blocks = n_slots * width + 1
+    pool = ((n_blocks, BS, bp.kv_lanes(nkv, hs)), BF16)
+    row = ((n_slots, 1, nkv, hs), BF16)
+    shapes = [pool, pool, ((n_slots, 1, nh, hs), BF16), row, row,
+              ((n_slots, width), I32), ((n_slots,), I32)]
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in shapes]
+    assert fd.paged_flash_decode_decline(avals[2], avals[0], avals[1],
+                                         avals[5], nkv) is None
+    need = fd._walk_vmem_bytes(avals[2], avals[0], avals[5], nkv)
+    assert need < fd.VMEM_LIMIT_BYTES // 4
+    monkeypatch.setattr(
+        fd, "tpu_compiler_params",
+        lambda **kw: fd.pltpu.CompilerParams(vmem_limit_bytes=need, **kw))
+    decode = fd.paged_flash_decode.__wrapped__      # traced under the patch
+
+    def step(kp, vp, q, k, v, bt, pos):
+        kp = bp.paged_update(kp, k, pos, bt)
+        vp = bp.paged_update(vp, v, pos, bt)
+        return kp, vp, decode(q[:, 0], kp, vp, bt, pos + 1,
+                              scale=hs ** -0.5, n_kv_heads=nkv)
+
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*avals).compile()
+    text = compiled.as_text()
+    assert paths.kernel_census(text).get("paged_flash_decode") == 1
+    assert not _pool_copies(text, pool[0])
+    pool_bytes = 2 * n_blocks * BS * bp.kv_lanes(nkv, hs)
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
 
 
 # a plain program's call, a chunk alone, a fused program's merged call

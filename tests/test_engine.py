@@ -437,6 +437,53 @@ def test_chunk_fills_the_rows_its_program_computes(n_ids, takes):
     assert eng.fused_step_traces == 1 and eng.step_traces == 1
 
 
+def _metric(sched, name) -> float:
+    for line in sched.metrics.render_prometheus().splitlines():
+        if line.startswith(name + " "):
+            return float(line.split()[1])
+    raise AssertionError(f"{name} is not at /metrics")
+
+
+@pytest.mark.parametrize("chunk", [0, 16], ids=["wave", "chunked"])
+def test_decode_tiles_per_grid_step_reads_what_the_lengths_give(chunk):
+    """`decode_live_tiles` / `decode_live_steps` are host arithmetic on the
+    planned lengths: a prompt of P ids with a budget of N takes N - 1
+    decode programs (its first token comes with the prefill), the k-th of
+    which reads P + k rows = ceil((P + k) / block_size) tiles in ONE grid
+    step of the paged kernel. The same numbers at /metrics, in
+    /debug/timeline and summed over the flight records."""
+    import json
+
+    from distributed_pytorch_tpu.serve.scheduler import Scheduler
+    from distributed_pytorch_tpu.serve.server import ServeApp
+    cfg, model, variables = _mv()
+    eng = DecodeEngine(model, variables, n_slots=3, temperature=0.0,
+                       min_bucket=8, block_size=8, prefill_chunk=chunk)
+    sched = Scheduler(eng, max_queue=4)
+    assert eng.decode_tiles_per_grid_step == 0.0    # nothing ran yet
+    reqs = {3: 9, 8: 2, 21: 12}                     # prompt ids -> budget
+    eng.run([list(range(1, p + 1)) for p in reqs], list(reqs.values()))
+    steps = sum(n - 1 for n in reqs.values())
+    tiles = sum(-(-(p + k) // 8) for p, n in reqs.items()
+                for k in range(1, n))
+    assert (eng.decode_live_tiles, eng.decode_live_steps) == (tiles, steps)
+    assert eng.decode_tiles_per_grid_step == pytest.approx(tiles / steps)
+    assert 1.0 < tiles / steps <= eng.table_width
+    assert _metric(sched, "serve_decode_tiles_per_grid_step") == \
+        pytest.approx(tiles / steps)
+    recs = eng.flight.entries()
+    assert sum(r["decode_live_tiles"] for r in recs) == tiles
+    assert sum(r["decode_live_steps"] for r in recs) == steps
+    assert all(r["decode_live_steps"] == r["n_live"] for r in recs)
+    app = ServeApp.__new__(ServeApp)
+    app.scheduler = type("S", (), {"engine": eng})()
+    payload = json.loads(app._debug_timeline({}).split(b"\r\n\r\n", 1)[1])
+    assert payload["decode_tiles_per_grid_step"] == \
+        pytest.approx(tiles / steps)
+    assert payload["entries"][-1]["decode_live_tiles"] == \
+        recs[-1]["decode_live_tiles"]
+
+
 def test_a_wave_engine_carries_no_chunk():
     """`chunk_fill_share` counts fused programs' ids only: a wave engine
     prefills at admission and reads 0."""

@@ -258,24 +258,97 @@ _HEAD_SHAPES = [(8, 8, 16), (8, 4, 16), (8, 2, 16), (8, 1, 16),
 _ids = lambda t: "nh%d_nkv%d_hs%d" % t  # noqa: E731
 
 
+# table width -> per-sequence lengths at 8-row blocks. A grid step of the
+# kernel is a sequence: it walks ceil(len / 8) live tiles, up to 4 of them a
+# joint update (`_walk_shape` at these tiny tiles: ring of 8, groups of
+# min(4, width)), so the cases cover 1, 2, 3 and all `width` live blocks,
+# a last group that is short, lengths that end ON a block edge and one row
+# past it, an odd width, and one-block sequences between full ones
+_PAGED_LENS = {
+    "ragged": (8, [1, 7, 33, 64]),
+    "block_edges": (8, [8, 16, 24, 64]),
+    "one_past_an_edge": (8, [9, 17, 25, 57]),
+    "odd_width": (5, [40, 1, 33, 17]),
+    "width_3": (3, [24, 1, 9, 17]),
+    "one_beside_full": (8, [1, 64, 1, 64]),
+}
+
+
+@pytest.mark.parametrize("lens", list(_PAGED_LENS))
 @pytest.mark.parametrize("shape", _HEAD_SHAPES, ids=_ids)
-def test_paged_parity_gqa_ratios(shape):
+def test_paged_parity_gqa_ratios(shape, lens):
     """Paged kernel vs the naive path on the GATHERED logical cache:
     <= 1e-5 for MHA through MQA at ragged per-sequence lengths, through
     shuffled (non-contiguous, non-monotone) block tables."""
     from distributed_pytorch_tpu.ops.flash_decode import (
         paged_flash_decode, paged_flash_decode_usable)
     nh, nkv, hs = shape
-    B, n_max, bs = 4, 8, 8
+    n_max, cl = _PAGED_LENS[lens]
+    B, bs = 4, 8
     q, kp, vp, bt, kl, vl = _mk_paged(B, n_max, bs, nh, nkv, hs)
     assert kp.ndim == 3 and kp.shape[2] % 128 == 0
-    cl = jnp.array([1, 7, 33, 64], jnp.int32)
+    cl = jnp.array(cl, jnp.int32)
     assert paged_flash_decode_usable(q, kp, vp, bt, nkv)
     out = paged_flash_decode(q[:, 0], kp, vp, bt, cl, scale=hs ** -0.5,
                              n_kv_heads=nkv, interpret=True)
     ref = _naive_sdpa(q, kl, vl, scale=hs ** -0.5, q_offset=cl - 1)[:, 0]
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("bs,want", [(64, (1, 2)), (32, (2, 4)),
+                                     (8, (3, 8))],
+                         ids=["ring2", "ring4", "ring8"])
+def test_paged_walk_follows_the_tile(bs, want):
+    """How many tile pairs sit in VMEM and how many go through one joint
+    update is read from a tile's bytes and the table's width
+    (`_walk_shape`): 64 float32 heads x 128 lanes at 64-row blocks leave
+    room for a ring of 2 (one tile an update), at 32 rows for 4 (two), and
+    a table of 3 blocks caps the update at 3. Same answers as the naive
+    path at each."""
+    from distributed_pytorch_tpu.ops import flash_decode as fd
+    nh = nkv = 64
+    hs, B, n_max = 128, 2, 3
+    q, kp, vp, bt, kl, vl = _mk_paged(B, n_max, bs, nh, nkv, hs,
+                                      extra_blocks=0)
+    assert fd._walk_shape(n_max, fd._pair_bytes(kp)) == want
+    cl = jnp.array([3 * bs, bs + 1], jnp.int32)
+    out = fd.paged_flash_decode(q[:, 0], kp, vp, bt, cl, scale=hs ** -0.5,
+                                n_kv_heads=nkv, interpret=True)
+    ref = _naive_sdpa(q, kl, vl, scale=hs ** -0.5, q_offset=cl - 1)[:, 0]
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("n_own", [1, 8, 33, 64],
+                         ids=lambda n: f"own{n}rows")
+@pytest.mark.parametrize("shape", [(25, 25, 64), (8, 2, 16)], ids=_ids)
+def test_paged_row_blind_to_neighbours(shape, n_own):
+    """A sequence's output BITS depend on its own query, blocks and length
+    alone: not on its row in the batch, on how many tiles its neighbours
+    hold (whose fetches share the ring with its own), or on a dead slot
+    beside it. The granite cell's repeat share replays a prompt in another
+    slot beside other neighbours and parts greedy streams at one bit."""
+    from distributed_pytorch_tpu.ops.flash_decode import paged_flash_decode
+    nh, nkv, hs = shape
+    B, n_max, bs = 4, 8, 8
+    q, kp, vp, bt, _, _ = _mk_paged(B, n_max, bs, nh, nkv, hs, seed=7)
+
+    def run(rows, lens):
+        rows = jnp.array(rows)
+        return paged_flash_decode(
+            q[rows, 0], kp, vp, bt[rows], jnp.array(lens, jnp.int32),
+            scale=hs ** -0.5, n_kv_heads=nkv, interpret=True)
+
+    # sequence 0: first of a batch of long neighbours; third, behind a dead
+    # slot (length 0) and a one-tile one, ahead of a slot at length 1; alone
+    # in a batch of itself repeated
+    a = run([0, 1, 2, 3], [n_own, 64, 40, 57])[0]
+    b = run([3, 1, 0, 2], [0, 5, n_own, 1])[2]
+    c = run([0, 0, 0, 0], [n_own] * 4)
+    for other in (b, c[0], c[3]):
+        np.testing.assert_array_equal(
+            np.asarray(a).view(np.uint32), np.asarray(other).view(np.uint32))
 
 
 @pytest.mark.parametrize("shape", _HEAD_SHAPES, ids=_ids)

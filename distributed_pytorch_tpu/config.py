@@ -439,8 +439,10 @@ class LLMConfig:
 
     # a per-layer pattern of ONE-mixer blocks, `x + mixer(norm(x))`, one
     # character a layer: 'M' a Mamba-2 state-space mixer (models/ssm.py),
-    # 'E' routed experts of which this chip holds a share (models/mlp.py
-    # RoutedExperts; `router` says how), '*' attention (GQA). Empty = the
+    # 'C' a gated short-convolution mixer (models/shortconv.py), 'E' routed
+    # experts of which this chip holds a share (models/mlp.py
+    # RoutedExperts; `router` says how), 'F' a dense FFN of its own width
+    # `dense_up_dim` (models/mlp.py MLP), '*' attention (GQA). Empty = the
     # attention + FFN block above for every layer. `n_layer` is its
     # length. A patterned model has RMSNorms, no FFN biases, and its
     # parameters are created in `LLM.param_dtype`.
@@ -449,6 +451,17 @@ class LLMConfig:
     tie_head: bool = True        # False: an `lm_head` (V, C) of its own
     head_dim: int = 0            # attention head size; 0 = n_embd // n_head
     attn_bias: bool = True       # biases on the qkv and output projections
+    # '*' layers of a patterned model: `qk_norm` puts an RMSNorm with one
+    # learned head-size vector over every q head and every k head, before
+    # the positions; with `pos_emb` 'rope' q and k are rotated at the
+    # slots' own positions and keys are cached rotated. `rope_theta` is
+    # the angles' base and `rope_pairing` which lanes turn together, in
+    # any model: 'adjacent' (2i with 2i + 1, the reference's) or 'half'
+    # (i with i + head size / 2, the published `rotate_half` of the
+    # Hugging Face families); ops/rope.py
+    qk_norm: bool = False
+    rope_theta: float = 10000.0
+    rope_pairing: str = "adjacent"
     # 'E' layers: `n_exp` - `n_shared` is the ROUTER's width and `n_act` -
     # `n_shared` its top-k, as above; `experts_held` = (first id, count) is
     # the slice of routed experts this chip holds (empty: all), what the
@@ -478,11 +491,18 @@ class LLMConfig:
     ssm_state: int = 0
     ssm_conv: int = 4
     ssm_chunk: int = 128
+    # 'C' layers: the depthwise convolution's length (published
+    # `conv_L_cache`); a slot carries its last `conv_len` - 1 inputs.
+    # 'F' layers: the dense FFN's width, gated as `non_linearity` says
+    conv_len: int = 3
+    dense_up_dim: int = 0
 
     def __post_init__(self):
         object.__setattr__(self, "experts_held", tuple(self.experts_held))
+        assert self.rope_pairing in ("adjacent", "half"), self.rope_pairing
         if self.layer_pattern:
-            assert set(self.layer_pattern) <= set("ME*"), self.layer_pattern
+            assert set(self.layer_pattern) <= set("MCEF*"), \
+                self.layer_pattern
             assert len(self.layer_pattern) == self.n_layer, (
                 f"layer_pattern has {len(self.layer_pattern)} layers, "
                 f"n_layer is {self.n_layer}")
@@ -491,6 +511,10 @@ class LLMConfig:
                 assert self.ssm_heads and self.ssm_head_dim and \
                     self.ssm_state and \
                     self.ssm_heads % self.ssm_groups == 0
+            if "C" in self.layer_pattern:
+                assert self.conv_len >= 2
+            if "F" in self.layer_pattern:
+                assert self.dense_up_dim > 0
             if "E" in self.layer_pattern:
                 assert self.n_act > self.n_shared and \
                     self.n_exp > self.n_shared
@@ -502,6 +526,7 @@ class LLMConfig:
             assert (self.embed_mult, self.resid_mult, self.attn_scale,
                     self.logits_div) == (1.0, 1.0, 0.0, 1.0), \
                 "the multipliers are a patterned model's"
+            assert not self.qk_norm, "QK-norm is a patterned model's"
         # Cross-field normalization, mirroring reference
         # single-gpu/train.py:198-206 (mha -> n_kv_heads=n_head, mqa -> 1,
         # mla requires latent dims; rope-mla additionally rope_head_dim).
@@ -562,9 +587,10 @@ class LLMConfig:
     @property
     def recurrent(self) -> bool:
         """Whether some layer keeps per-sequence state that is no block of
-        the paged cache (what prefix reuse, the host tier and speculative
-        roll-back cannot snapshot yet)."""
-        return "M" in self.layer_pattern
+        the paged cache: a state-space layer's state and tail, or a
+        convolution mixer's tail alone (what prefix reuse, the host tier
+        and speculative roll-back cannot snapshot yet)."""
+        return any(kind in "MC" for kind in self.layer_pattern)
 
     @property
     def n_routed(self) -> int:

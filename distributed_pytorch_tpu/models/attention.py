@@ -61,7 +61,8 @@ import jax.numpy as jnp
 
 from distributed_pytorch_tpu.config import LLMConfig
 from distributed_pytorch_tpu.ops.attention_core import sdpa
-from distributed_pytorch_tpu.ops.rope import apply_rotary_emb, slice_rows
+from distributed_pytorch_tpu.ops.rope import (apply_rotary_emb, rope_angles,
+                                              slice_rows)
 
 Cache = dict[str, jnp.ndarray]
 
@@ -108,6 +109,15 @@ class _OverlapDense(nn.Module):
         return y if bias is None else y + bias.astype(self.dtype)
 
 
+def _head_rms_norm(x: jnp.ndarray, weight: jnp.ndarray,
+                   eps: float) -> jnp.ndarray:
+    """RMSNorm over the lanes of every head of (B, T, heads, hs), in
+    float32, times one learned (hs,) vector."""
+    xf = x.astype(jnp.float32)
+    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (xf * weight.astype(jnp.float32)).astype(x.dtype)
+
+
 def _update_cache(cache_arr: jnp.ndarray, new: jnp.ndarray, pos) -> jnp.ndarray:
     """Write `new` (B, T, ...) into the static buffer at [:, pos:pos+T].
 
@@ -142,6 +152,14 @@ class GQA(nn.Module):
     Follows reference model.py:98-155: one fused qkv projection of width
     n_embd + 2*n_kv_heads*head_size (with bias, as reference :112-114), RoPE
     on q/k when pos_emb == 'rope', output projection + residual dropout.
+
+    A patterned model's '*' layer is this class too, configured: with
+    `cfg.qk_norm` an RMSNorm over the lanes of every q head and every k
+    head (leaves `q_norm`, `k_norm`, a head-size vector each) BEFORE the
+    positions; RoPE pairs the lanes as `cfg.rope_pairing` says, and where
+    no table is handed in (`freqs` None) takes its angles from the rows'
+    own positions at `cfg.rope_theta` (ops/rope.py). Keys go into the
+    cache normed and rotated.
     """
 
     config: LLMConfig
@@ -164,10 +182,22 @@ class GQA(nn.Module):
         k = k.reshape(B, T, nkvh, hs)
         v = v.reshape(B, T, nkvh, hs)
 
+        if cfg.qk_norm:
+            with jax.named_scope("qk_norm"):
+                ones = nn.initializers.ones
+                q = _head_rms_norm(q, self.param(
+                    "q_norm", ones, (hs,), self.param_dtype), cfg.norm_eps)
+                k = _head_rms_norm(k, self.param(
+                    "k_norm", ones, (hs,), self.param_dtype), cfg.norm_eps)
         if cfg.pos_emb == "rope":
-            f = slice_rows(freqs, pos, T)
-            q = apply_rotary_emb(q, f)
-            k = apply_rotary_emb(k, f)
+            with jax.named_scope("rope"):
+                # no table (a patterned model's context is its cache's,
+                # models/gpt.py): the angles of the rows' own positions
+                f = rope_angles(pos, T, hs, cfg.rope_theta) \
+                    if freqs is None else slice_rows(freqs, pos, T)
+                half = cfg.rope_pairing == "half"
+                q = apply_rotary_emb(q, f, half=half)
+                k = apply_rotary_emb(k, f, half=half)
 
         new_cache = None
         q_offset = 0

@@ -36,6 +36,8 @@ from distributed_pytorch_tpu.config import LLMConfig
 from distributed_pytorch_tpu.models.attention import (GQA, Attention,
                                                       init_attn_cache)
 from distributed_pytorch_tpu.models.mlp import MLP, MoE, RoutedExperts
+from distributed_pytorch_tpu.models.shortconv import (ShortConv,
+                                                      init_conv_cache)
 from distributed_pytorch_tpu.models.ssm import Mamba2, init_ssm_cache
 from distributed_pytorch_tpu.obs import paths
 from distributed_pytorch_tpu.ops.losses import (fused_cross_entropy,
@@ -99,18 +101,21 @@ def _real_rows(x, state_ctx: dict):
 
 class MixerBlock(nn.Module):
     """One layer of a patterned model: `x + r * mixer(RMSNorm(x))` (r =
-    `cfg.resid_mult`), the mixer one of 'M' (models/ssm.py), 'E'
-    (models/mlp.py RoutedExperts) or '*' (GQA). What each keeps between
-    calls sits in the layer's cache slot:
-    per-slot state leaves, this program's routing counts, block pools.
+    `cfg.resid_mult`), the mixer one of 'M' (models/ssm.py), 'C'
+    (models/shortconv.py), 'E' (models/mlp.py RoutedExperts), 'F'
+    (models/mlp.py MLP at `cfg.dense_up_dim`) or '*' (GQA). What each
+    keeps between calls sits in the layer's cache slot: per-slot state
+    leaves ('M': tail and state, 'C': tail), this program's routing
+    counts, block pools, nothing ('F').
     `state_ctx` (the engine's: which rows are live, or which slot a chunk
     belongs to and how many of its rows are real) reaches the two kinds
     that have no null block to land a pad in.
 
     `xs` are the hidden rows of the program's row sets (`rows`, one or
-    several). An 'M' or '*' layer takes them in turn, the cache flowing
-    from one to the next; an 'E' layer is position-wise and makes ONE call
-    over all their rows, so its experts' matrices are read once."""
+    several). An 'M', 'C', 'F' or '*' layer takes them in turn, the cache
+    flowing from one to the next; an 'E' layer is position-wise and makes
+    ONE call over all their rows, so its experts' matrices are read
+    once."""
 
     config: LLMConfig
     kind: str
@@ -138,14 +143,20 @@ class MixerBlock(nn.Module):
             new_cache = None if stats is None \
                 else merge_expert_stats(cache, stats)
         else:
-            mixer = Mamba2(cfg, pd, name="ssm") if self.kind == "M" else \
-                GQA(cfg, self.attn_impl, pd, name="attn")
+            mixer = {
+                "M": lambda: Mamba2(cfg, pd, name="ssm"),
+                "C": lambda: ShortConv(cfg, pd, name="conv"),
+                "F": lambda: MLP(cfg, cfg.dense_up_dim, pd, name="mlp"),
+                "*": lambda: GQA(cfg, self.attn_impl, pd, name="attn"),
+            }[self.kind]()
             ys, new_cache = [], cache
             for h, r in zip(hs, rows):
                 with _scope(r.scope):
-                    if self.kind == "M":
+                    if self.kind in "MC":
                         y, new_cache = mixer(h, new_cache, r.pos,
                                              r.state_ctx)
+                    elif self.kind == "F":
+                        y = mixer(h)
                     else:
                         y, new_cache = mixer(
                             h, freqs, new_cache, r.pos, deterministic=True,
@@ -277,7 +288,10 @@ class LLM(nn.Module):
             d = cfg.rope_head_dim if cfg.attn == "mla" else cfg.head_size
             # constant under jit; XLA folds it (reference precomputes a
             # complex buffer, model.py:567-577)
-            freqs = precompute_rope_freqs(d, cfg.block_size)
+            # (a patterned model computes its angles from the positions,
+            # models/attention.py GQA: no table as long as its context)
+            freqs = None if patterned else \
+                precompute_rope_freqs(d, cfg.block_size, cfg.rope_theta)
         elif cfg.pos_emb == "learn":
             pos_tab = self.param("pos_emb", _EMBED_INIT,
                                  (cfg.block_size, cfg.n_embd), jnp.float32)
@@ -507,15 +521,19 @@ def init_paged_cache(config: LLMConfig, n_blocks: int, block_size: int,
 
     A patterned model holds two kinds of state in the one tree: block
     pools for its '*' layers, a row a slot (`n_slots`) of convolution tail
-    and state for its 'M' layers (models/ssm.py), nothing for 'E' layers
-    (their slot carries a program's routing counts out, never in)."""
+    and state for its 'M' layers (models/ssm.py) or of the tail alone for
+    its 'C' layers (models/shortconv.py), nothing for 'F' and 'E' layers
+    (an 'E' slot carries a program's routing counts out, never in)."""
     from distributed_pytorch_tpu.models.attention import init_paged_attn_cache
     if config.layer_pattern:
         assert n_slots > 0 or not config.recurrent, \
-            "state-space layers keep a row a slot: pass n_slots"
-        return [init_ssm_cache(config, n_slots, dtype) if kind == "M"
-                else init_paged_attn_cache(config, n_blocks, block_size,
-                                           dtype) if kind == "*" else None
+            "state-space and convolution layers keep a row a slot: pass " \
+            "n_slots"
+        make = {"M": lambda: init_ssm_cache(config, n_slots, dtype),
+                "C": lambda: init_conv_cache(config, n_slots, dtype),
+                "*": lambda: init_paged_attn_cache(config, n_blocks,
+                                                   block_size, dtype)}
+        return [make[kind]() if kind in make else None
                 for kind in config.layer_pattern]
     return [init_paged_attn_cache(config, n_blocks, block_size, dtype)
             for _ in range(config.n_layer)]

@@ -151,17 +151,23 @@ def mlp_apply(x: jnp.ndarray, w_fc: jnp.ndarray, w_proj: jnp.ndarray,
 
 
 class MLP(nn.Module):
-    """Dense feed-forward block (reference model.py:365-398)."""
+    """Dense feed-forward block (reference model.py:365-398). An 'F' layer
+    of a patterned model is this block at a width of its own (`up_dim`,
+    0 = `cfg.up_dim`: `cfg.dense_up_dim` beside experts of `cfg.up_dim`)
+    with its leaves in `param_dtype`; gated, `c_fc` is [a | b] by columns
+    and the block `(silu(a) * b) c_proj`."""
 
     config: LLMConfig
+    up_dim: int = 0
+    param_dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, x, *, deterministic: bool = True):
         cfg = self.config
-        C, up = cfg.n_embd, cfg.up_dim
+        C, up = cfg.n_embd, self.up_dim or cfg.up_dim
         fc_out = 2 * up if _is_gated(cfg.non_linearity) else up
-        w_fc = self.param("c_fc", _DENSE_INIT, (C, fc_out), jnp.float32)
-        w_proj = self.param("c_proj", _DENSE_INIT, (up, C), jnp.float32)
+        w_fc = self.param("c_fc", _DENSE_INIT, (C, fc_out), self.param_dtype)
+        w_proj = self.param("c_proj", _DENSE_INIT, (up, C), self.param_dtype)
         y = mlp_apply(x, w_fc.astype(x.dtype), w_proj.astype(x.dtype),
                       cfg.non_linearity, overlap=True,
                       qnames=((*self.path, "c_fc"), (*self.path, "c_proj")))
@@ -417,7 +423,9 @@ def route_softmax_topk(scores_in: jnp.ndarray, gate: jnp.ndarray, k: int):
 class RoutedExperts(nn.Module):
     """An 'E' layer of a patterned model: routed experts of which this
     chip holds a share, plus one shared expert of another width that every
-    token takes. `cfg.router` picks the router (`route_sigmoid`, with its
+    token takes (none, and no leaves or work for one, where `cfg.n_shared`
+    is 0). Router and expert kind are independent choices: `cfg.router`
+    picks the router (`route_sigmoid`, with its
     `gate_bias` leaf, or `route_softmax_topk`, without); a gated
     `cfg.non_linearity` ('swiglu': silu(a) * b) makes both kinds of expert
     gated, their up matrices 2 x the width, [a | b].
@@ -453,7 +461,7 @@ class RoutedExperts(nn.Module):
     also returns what the routing did for them: tokens a held expert,
     assignments to absent experts and, for the softmax router (whose
     weights are not renormalised over the held), the sum of the weights that
-    fell on held experts and the tiles the expert kernels ran (`stats`).
+    fell on held experts; and the tiles the expert kernels ran (`stats`).
     """
 
     config: LLMConfig
@@ -487,8 +495,9 @@ class RoutedExperts(nn.Module):
         w_up = self.param("experts_up", _DENSE_INIT, (n_held, fan * F, C),
                           pd)
         w_down = self.param("experts_down", _DENSE_INIT, (n_held, F, C), pd)
-        s_up = self.param("shared_up", _DENSE_INIT, (C, fan * Fs), pd)
-        s_down = self.param("shared_down", _DENSE_INIT, (Fs, C), pd)
+        if cfg.n_shared:
+            s_up = self.param("shared_up", _DENSE_INIT, (C, fan * Fs), pd)
+            s_down = self.param("shared_down", _DENSE_INIT, (Fs, C), pd)
 
         flats = [x.reshape(-1, C) for x in xs]
         with jax.named_scope("moe_route"):
@@ -528,16 +537,19 @@ class RoutedExperts(nn.Module):
                                     comb.astype(dt)).astype(jnp.float32)
                 if many:
                     routed = jnp.split(routed, np.cumsum(sizes)[:-1])
-        with jax.named_scope("moe_shared"):
-            shared = [_apply_activation(f @ s_up.astype(dt), nl)
-                      @ s_down.astype(dt) for f in flats]
+        routed = routed if many else [routed]
+        shared = [None] * len(flats)
+        if cfg.n_shared:
+            with jax.named_scope("moe_shared"):
+                shared = [_apply_activation(f @ s_up.astype(dt), nl)
+                          @ s_down.astype(dt) for f in flats]
         # under the combine's name: one float32 add a row set after the
         # token-side sum of `held_experts_ffn`, which the compiler may
-        # fuse into that sum's last op
+        # fuse into that sum's last op (none where no expert is shared)
         with jax.named_scope("moe_combine"):
-            ys = [(r + sh.astype(jnp.float32)).astype(dt).reshape(x.shape)
-                  for r, sh, x in zip(routed if many else [routed], shared,
-                                      xs)]
+            ys = [(r if sh is None else r + sh.astype(jnp.float32)
+                   ).astype(dt).reshape(x.shape)
+                  for r, sh, x in zip(routed, shared, xs)]
         stats = None
         if row_mask is not None:
             local = idx - first
@@ -549,9 +561,9 @@ class RoutedExperts(nn.Module):
                      "absent": (jnp.sum(row_mask) * idx.shape[1]
                                 - jnp.sum(tokens)).astype(jnp.int32)[None]}
             if not sigmoid:
-                # leaves of this router alone: the sigmoid-routed programs
-                # keep the text they had (PR 36)
+                # a leaf of this router alone: `route_sigmoid` renormalises
+                # over the chosen, so there is no share to count
                 stats["held_gate"] = jnp.sum(jnp.where(held, w, 0.0))[None]
-                if tiles is not None:
-                    stats["tiles"] = tiles
+            if tiles is not None:
+                stats["tiles"] = tiles
         return (ys if listed else ys[0]), stats

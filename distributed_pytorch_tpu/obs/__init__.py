@@ -20,9 +20,10 @@ that trace in two ways, both tabled in `obs.trace`:
   steps where no flax module name reaches: `kv_update`, `attn_core`,
   `lm_head`, `loss`, `optimizer`, `grad_norm`, `sample`, `chunk_prefill`,
   `decode`; a patterned model's mixers have theirs in `MIXER_SCOPES`
-  (`ssm_*`, `moe_route`, `moe_experts` with `moe_pack` and `moe_combine`
-  inside, `moe_shared`). They land in every device op's name path, so
-  device time has an owner in the program's own words.
+  (`ssm_*`, `conv_chunk`, `conv_step`, `qk_norm`, `rope`, `moe_route`,
+  `moe_experts` with `moe_pack` and `moe_combine` inside, `moe_shared`).
+  They land in every device op's name path, so device time has an owner in
+  the program's own words.
 
 `scripts/profile_step.py --analyze_only --trace_dir <dir>` and the
 benchmark's per-layer metrics read both back with one reduction

@@ -362,8 +362,19 @@ SCOPES = {
 }
 
 
+#: The flax module names a patterned model's blocks bring beside the
+#: classic ones (`attn`, `mlp`: an 'F' block's dense FFN is module `mlp`,
+#: a '*' block's GQA module `attn`), each block's RMSNorm included.
+MIXER_MODULES = {
+    "ssm": "a Mamba-2 mixer's projections and gated norm (models/ssm.py)",
+    "conv": "a gated short-convolution mixer's two projections and its "
+            "two gates, B * x' and C * c (models/shortconv.py)",
+    "moe": "an expert layer outside its scopes (models/mlp.py)",
+    "norm": "the RMSNorm in front of every mixer (models/gpt.py)",
+}
+
 #: The scopes of a patterned model's mixers (`LLMConfig.layer_pattern`),
-#: under the flax module names `ssm` and `moe`. A table of their own for
+#: under the flax module names of `MIXER_MODULES`. A table of their own for
 #: one reason: the benchmark's `lib/trace_spans.SCOPE_NAMES` is held to
 #: `SCOPES` by a test of the benchmark, and the PR that brought these
 #: (33, a `model_config`) may add benchmark files and edit none. They are
@@ -377,6 +388,20 @@ MIXER_SCOPES = {
                 "(ops/ssm_scan.py ssd_chunked)",
     "ssm_step": "the one-token recurrence of a decode step "
                 "(ops/ssm_scan.py ssm_step)",
+    # a 'C' mixer's convolution, its two forms apart (PR 45): other ops
+    # than `ssm_conv`'s, no bias and no silu
+    "conv_chunk": "the depthwise causal convolution over a chunk's rows "
+                  "from the slot's tail, zeros at a first chunk "
+                  "(models/shortconv.py, ops/ssm_scan.py causal_conv)",
+    "conv_step": "one token of it for every slot, and the tail's shift "
+                 "(ops/ssm_scan.py conv_step)",
+    # inside module `attn`, in front of `kv_update` and `attn_core` (PR 45)
+    "qk_norm": "the RMSNorm over the lanes of every q head and every k "
+               "head (models/attention.py GQA, `cfg.qk_norm`)",
+    "rope": "rotary positions: the angles (a patterned model's from the "
+            "rows' own positions at `cfg.rope_theta`, a classic one's "
+            "rows of its table) and the rotation of q and k in the "
+            "pairing `cfg.rope_pairing` names (ops/rope.py)",
     "moe_route": "the router: sigmoid scores, bias-corrected top-k, "
                  "renormalised weights (models/mlp.py route_sigmoid), or "
                  "the top-k logits and their softmax (route_softmax_topk)",

@@ -95,23 +95,25 @@ def ssd_chunked(x, dt, A, B, C, D, h0=None, *, chunk: int = 128):
 
 def causal_conv(u, w, b, tail=None):
     """Depthwise causal convolution over time. u (B, T, D); w (K, D), w[k]
-    on the input K - 1 - k steps back; b (D,); tail (B, K - 1, D) the
-    inputs before u[:, 0] (zeros when None). Returns (out (B, T, D), the
-    input with its tail in front (B, K - 1 + T, D))."""
+    on the input K - 1 - k steps back; b (D,) or None for no bias; tail
+    (B, K - 1, D) the inputs before u[:, 0] (zeros when None). Returns (out
+    (B, T, D), the input with its tail in front (B, K - 1 + T, D))."""
     K = w.shape[0]
     if tail is None:
         tail = jnp.zeros((u.shape[0], K - 1, u.shape[2]), u.dtype)
     full = jnp.concatenate([tail.astype(u.dtype), u], axis=1)
     T = u.shape[1]
     out = sum(full[:, k:k + T] * w[k].astype(u.dtype) for k in range(K))
-    return out + b.astype(u.dtype), full
+    return (out if b is None else out + b.astype(u.dtype)), full
 
 
 def conv_step(u, w, b, tail, live=None):
     """One token of `causal_conv`. u (S, D); tail (S, K - 1, D). Returns
     (out (S, D), tail'). Rows where `live` is False keep their tail."""
     win = jnp.concatenate([tail.astype(u.dtype), u[:, None]], axis=1)
-    out = jnp.einsum("skd,kd->sd", win, w.astype(u.dtype)) + b.astype(u.dtype)
+    out = jnp.einsum("skd,kd->sd", win, w.astype(u.dtype))
+    if b is not None:
+        out = out + b.astype(u.dtype)
     new_tail = win[:, 1:].astype(tail.dtype)
     if live is not None:
         new_tail = jnp.where(live[:, None, None], new_tail, tail)

@@ -89,11 +89,12 @@ def analyze(trace_dir: str, top: int = 25) -> None:
             # a patterned model's mixers have scopes of their own
             # (obs/trace.py MIXER_SCOPES): the same table over them too
             from benchmark.readers import trace_scope_named_ms
-            from distributed_pytorch_tpu.obs.trace import MIXER_SCOPES
+            from distributed_pytorch_tpu.obs.trace import (MIXER_MODULES,
+                                                           MIXER_SCOPES)
             named = trace_scope_named_ms.table(
-                ("ssm", "norm", *MIXER_SCOPES), [r"step\("], trace_dir)
+                (*MIXER_MODULES, *MIXER_SCOPES), [r"step\("], trace_dir)
             if named is not None and set(named["owners"]) & (
-                    set(MIXER_SCOPES) | {"ssm"}):
+                    set(MIXER_SCOPES) | {"ssm", "conv"}):
                 print("with the mixers' scopes (obs/trace.py MIXER_SCOPES):")
                 trace_scope_ms.say_table(named)
     # host events that are no phase of a loop (obs/trace.py HOST_EVENTS: a

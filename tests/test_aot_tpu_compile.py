@@ -200,9 +200,20 @@ CASES = {
     "gated_experts_256tok": (lambda: _held_experts(256, **GRANITE_EXPERTS),
                              ["expert_matmul_gated_up",
                               "expert_matmul_down"]),
+    # every expert held at LFM2-24B-A2B's published widths: 2048 -> 2 x
+    # 1536 -> 2048, 64 of 64, top 4: a plain program's call of 128 decode
+    # rows and a chunk-carrying program's one call over both row sets
+    "lfm2_experts_128tok": (lambda: _held_experts(128, **LFM2_EXPERTS),
+                            ["expert_matmul_gated_up",
+                             "expert_matmul_down"]),
+    "lfm2_experts_256+128tok": (lambda: _held_experts((256, 128),
+                                                      **LFM2_EXPERTS),
+                                ["expert_matmul_gated_up",
+                                 "expert_matmul_down"]),
 }
 
 GRANITE_EXPERTS = dict(C=4096, F=768, held=36, k=10, n_routed=72, gated=True)
+LFM2_EXPERTS = dict(C=2048, F=1536, held=64, k=4, n_routed=64, gated=True)
 
 
 def _held_experts(rows, C=2688, F=1856, held=64, k=6, n_routed=128,
@@ -358,10 +369,12 @@ def test_no_whole_pool_copy_in_a_serving_step(geometry, kind, kernels, v5e):
 
 
 #: the serving cells' decode calls: slots, heads, kv heads, head size, table
-#: width (gpt2-xl; nemotron's 2 kv heads under rep 16; granite's 8 under 4)
+#: width (gpt2-xl; nemotron's 2 kv heads under rep 16; granite's 8 under 4;
+#: LFM2's 8 x 64 under rep 4 at 128 slots)
 CELL_DECODES = {"24x25x64_w8": (24, 25, 25, 64, 8),
                 "64x2kvx128_rep16_w4": (64, 32, 2, 128, 4),
-                "64x8kvx128_rep4_w4": (64, 32, 8, 128, 4)}
+                "64x8kvx128_rep4_w4": (64, 32, 8, 128, 4),
+                "128x8kvx64_rep4_w10": (128, 32, 8, 64, 10)}
 
 
 @pytest.mark.parametrize("cell", list(CELL_DECODES))

@@ -39,10 +39,22 @@ def _tc(**kw):
     return TrainConfig(**base)
 
 
+_STARTED: list = []     # (supervisor, its thread) of the test that runs
+
+
 @pytest.fixture()
 def in_tmp(tmp_path, monkeypatch):
     monkeypatch.chdir(tmp_path)
-    return tmp_path
+    yield tmp_path
+    # a supervisor that a failed test left running is stopped and joined
+    # BEFORE the chdir is undone: its run directory is relative
+    # (`runs/<run_name>`), so one that outlived its test would write its
+    # state, heartbeats and timeline into the repository
+    while _STARTED:
+        s, t = _STARTED.pop()
+        s._stop = True
+        t.join(timeout=30)
+        assert not t.is_alive(), "a supervisor thread outlived its test"
 
 
 # ---------------------------------------------------------------------------
@@ -262,6 +274,7 @@ def _run_supervisor(cfg, worker_cmd, timeout=30.0):
 
     t = threading.Thread(target=go, daemon=True)
     t.start()
+    _STARTED.append((s, t))
     return rc, t, s
 
 
@@ -283,11 +296,18 @@ def _state(run_dir):
 
 
 def _events(run_dir):
+    """The timeline's whole lines so far: a line the supervisor is still
+    writing ends the list, it does not empty it (a reader that saw an event
+    a moment ago must see it again)."""
+    events = []
     try:
         with open(os.path.join(run_dir, sup.TIMELINE_FILE)) as f:
-            return [json.loads(line) for line in f if line.strip()]
+            for line in f:
+                if line.strip():
+                    events.append(json.loads(line))
     except (OSError, ValueError):
-        return []
+        pass
+    return events
 
 
 @pytest.fixture()

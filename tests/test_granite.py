@@ -432,7 +432,9 @@ def _fingerprint(tree) -> str:
 def test_the_nemotron_shape_and_a_dense_model_are_asked_nothing_new():
     """The sigmoid-routed relu^2 model keeps its tree (a `gate_bias`, an
     untied head, ungated stacks), its seeded values and its outputs; a
-    dense model its tree; and neither carries the new count out."""
+    dense model its tree. Since PR 45 every layer that runs the expert
+    kernels carries their `tiles` out, this one too; the held weights' sum
+    stays the softmax router's."""
     from tests.test_hybrid import LLM_KW as NEMOTRON_KW
     cfg = LLMConfig(**NEMOTRON_KW)
     assert (cfg.router, cfg.embed_mult, cfg.resid_mult, cfg.attn_scale,
@@ -456,7 +458,7 @@ def test_the_nemotron_shape_and_a_dense_model_are_asked_nothing_new():
         NEMOTRON_LOGITS[4], rel=1e-5)
     y, stats = mlp_mod.RoutedExperts(cfg).apply(
         {"params": moe}, jnp.ones((1, 4, 64)), jnp.ones((4,), bool))
-    assert set(stats) == {"tokens", "absent"}
+    assert set(stats) == {"tokens", "absent", "tiles"}
 
     dense = LLMConfig(vocab_size=256, block_size=64, n_embd=64, n_head=4,
                       attn="mha", n_layer=2, up_dim=128,

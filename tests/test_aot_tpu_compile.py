@@ -274,6 +274,84 @@ def test_auto_trains_on_the_flash_kernels(shape, v5e, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# the FFN's exact gelu under a gradient: the erfc expansion once a layer
+# ---------------------------------------------------------------------------
+
+FFN_ACT = "16,1024,3072"            # the train cell's activation, B x T x up
+
+
+@pytest.fixture(scope="module")
+def ffn_step_fusions(v5e):
+    """Value-and-grad of one FFN at the train cell's shapes (16 x 1024 x
+    768 -> 3072, bf16 compute over float32 weights) compiled for the
+    described chip, read a fused computation at a time: name -> (ops of
+    each kind over the activation's shape, the entry fusion's results)."""
+    from distributed_pytorch_tpu.models.mlp import mlp_apply
+
+    def loss(x, w_fc, w_proj, t):
+        y = mlp_apply(x, w_fc.astype(BF16), w_proj.astype(BF16), "gelu")
+        return jnp.mean((y.astype(F32) - t) ** 2)
+
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                    [((16, 1024, C), BF16), ((C, 3072), F32),
+                     ((3072, C), F32), ((16, 1024, C), F32)], v5e).as_text()
+    bodies, name = {}, None
+    for ln in text.splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", ln)
+        if head:
+            name = "ENTRY" if head.group(1) else head.group(2)
+            bodies[name] = []
+        elif name and ln.startswith("}"):
+            name = None
+        elif name:
+            bodies[name].append(ln)
+    fusions = {}
+    for ln in bodies["ENTRY"]:
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) fusion\(.*"
+                     r"calls=%?([\w.\-]+)", ln)
+        if m:
+            results = re.findall(r"(\w+)\[([\d,]*)\]", m.group(1))
+            fusions[m.group(2)] = {
+                **{op: sum(bool(re.search(rf"= \w+\[{FFN_ACT}\]\S* {op}\(",
+                                          b)) for b in bodies[m.group(2)])
+                   for op in ("exponential", "divide")},
+                "dtypes": [d for d, _ in results],
+                "wrote": [d for d, shape in results if shape == FFN_ACT]}
+    return fusions
+
+
+def test_the_erfc_expansion_is_evaluated_once_a_layer(ffn_step_fusions):
+    """Left to autodiff the compiler cloned the expansion (its two divides
+    and an exponential) into the down-projection's forward, weight-gradient
+    and dgrad fusions: three evaluations a layer, each at the vector unit's
+    pace (PERF.md section 6, PR 46). The rule leaves ONE, in the
+    up-projection's epilogue; backward's own exponential (the density's,
+    no divide) rides in the dgrad. The whole step is read with the recipe
+    of PERF.md section 7."""
+    fs = ffn_step_fusions
+    erfc = [n for n, f in fs.items() if f["divide"]]
+    assert len(erfc) == 1, fs
+    assert (fs[erfc[0]]["exponential"], fs[erfc[0]]["divide"]) == (1, 2), fs
+    assert sum(bool(f["exponential"]) for f in fs.values()) == 2, fs
+
+
+def test_the_up_projection_writes_the_pair_and_nothing_float32(
+        ffn_step_fusions):
+    """What forward keeps for backward is two bf16 arrays a layer (the
+    pre-activation and its erfc), both written by the fusion that holds
+    the expansion; no float32 array of the activation's shape reaches
+    HBM, and no bit mask is kept."""
+    fs = ffn_step_fusions
+    assert [f["wrote"] for f in fs.values() if f["divide"]] == [
+        ["bf16", "bf16"]], fs
+    # the pair and the dgrad's result: nothing else of that shape, in
+    # any dtype
+    assert sorted(d for f in fs.values() for d in f["wrote"]) == [
+        "bf16"] * 3, fs
+    assert not any("u16" in f["dtypes"] for f in fs.values()), fs
+
+
+# ---------------------------------------------------------------------------
 # the paged pools are never copied: one layout from the donated argument
 # through the write and the kernel
 # ---------------------------------------------------------------------------

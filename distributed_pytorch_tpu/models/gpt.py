@@ -459,6 +459,9 @@ class LLM(nn.Module):
                                "loss_impl=pallas")
                     main_loss = pallas_cross_entropy(x, emb_mat, targets)
                 else:
+                    # 'fused': the chunk scan replaces this note with the
+                    # rule it ran, gradients in the forward scan under a
+                    # gradient and the plain scan otherwise (ops/losses.py)
                     paths.note("loss", loss_impl, f"loss_impl={loss_impl}")
                 if loss_impl == "fused" and context.seq_axis_size() > 1:
                     # live 'seq' axis: chunk over the LOCAL T shard inside

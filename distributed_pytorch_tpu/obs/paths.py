@@ -31,10 +31,15 @@ log = logging.getLogger("paths")
 _choices: dict[str, dict[str, str]] = {}
 
 
-def note(kind: str, path: str, why: str = "") -> None:
+def note(kind: str, path: str, why: str = "", *,
+         replaces: tuple[str, ...] = ()) -> None:
     """Record that `kind` (attention | decode_attention | loss | moe)
-    went down `path`; logged the first time each (kind, path) is seen."""
+    went down `path`; logged the first time each (kind, path) is seen.
+    `replaces` names earlier, coarser notes of the same call that this one
+    says more exactly (the loss: which rule the fused scan ran)."""
     seen = _choices.setdefault(kind, {})
+    for coarser in replaces:
+        seen.pop(coarser, None)
     if path not in seen:
         seen[path] = why
         log.info("[paths] %s -> %s%s", kind, path,

@@ -10,9 +10,15 @@ memory-bound tail was the prime suspect for the round-3 MFU gap
 
 `fused_cross_entropy` never materializes the full logits: the sequence axis
 is split into chunks and a `lax.scan` computes each chunk's
-`logsumexp(logits) - logit[target]` under `jax.checkpoint`, so both forward
-and backward hold at most one (B, chunk, V) block at a time. The lm-head
-matmul itself runs in the compute dtype with fp32 accumulation
+`logsumexp(logits) - logit[target]`, so at most one (B, chunk, V) block
+exists at a time. It has a differentiation rule of its own (PERF.md section
+6, PR 47): under a gradient the SAME scan pulls `1 / count` back through
+each block while its logits exist, emits `dx` chunk by chunk and carries
+`dW`; backward only scales the pair by the loss's cotangent. Left to
+autodiff under `jax.checkpoint` backward built and reduced every float32
+block a second time: a fourth head matmul and six passes over a block
+where four do. Undifferentiated (evaluation) it is one matmul a chunk. The
+lm-head matmul itself runs in the compute dtype with fp32 accumulation
 (`preferred_element_type`), which is MXU-native and slightly *better*
 numerics than the reference's cast-then-log_softmax.
 
@@ -22,17 +28,20 @@ every scan iteration) and GSPMD's handling of a sharded embedding (tp
 vocab-parallel psum, fsdp all-gather — hoisted out of the scan as
 loop-invariant) is unchanged. Under a live 'seq' axis
 `sp_fused_cross_entropy` runs the same chunk scan per device over the
-LOCAL T shard inside shard_map and psums the (sum, count) pair — no
-seq-sharded full-logits materialization (gpt.py routes on
-`context.seq_axis_size()`).
+LOCAL T shard inside shard_map, each over the psum of the valid counts,
+and psums the per-device sums — no seq-sharded full-logits
+materialization (gpt.py routes on `context.seq_axis_size()`).
 """
 
 from __future__ import annotations
 
-from typing import Optional
+import functools
 
 import jax
 import jax.numpy as jnp
+
+from distributed_pytorch_tpu import compat
+from distributed_pytorch_tpu.obs import paths
 
 
 def _default_logits(x: jnp.ndarray, embedding: jnp.ndarray) -> jnp.ndarray:
@@ -76,47 +85,107 @@ def _chunk_for(T: int, V: int, target_tokens: int = 128,
     return 0
 
 
-def _nll_sum_chunked(x: jnp.ndarray, embedding: jnp.ndarray,
-                     targets: jnp.ndarray, ignore_index: int,
-                     chunk: int, logits_fn=None):
-    """(sum of nll over valid targets, valid count) with the T axis chunked
-    through a rematerialized scan — the shared core of fused_cross_entropy
-    and the sequence-parallel local body. Falls back to one unchunked block
-    when chunking can't help (tiny T/V or non-dividing chunk)."""
-    B, T, C = x.shape
-    V = embedding.shape[0]
+def _resolve_chunk(T: int, V: int, chunk: int) -> int:
+    """The chunk the scan runs with: `chunk` (0 = `_chunk_for`'s choice) if
+    it splits T into more than one block, else 0 = nothing to chunk."""
     if chunk <= 0:
         chunk = _chunk_for(T, V)
+    return chunk if chunk > 0 and T % chunk == 0 and T // chunk > 1 else 0
 
-    def block_nll(x_c, t_c):
-        logits = (logits_fn or _default_logits)(x_c, embedding)
-        # (B, chunk, V) fp32
-        mask = t_c != ignore_index
-        safe = jnp.where(mask, t_c, 0)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        tgt = jnp.take_along_axis(logits, safe[..., None], axis=-1)[..., 0]
-        nll = lse - tgt
-        return jnp.where(mask, nll, 0.0).sum(), mask.sum()
 
-    if chunk <= 0 or T % chunk != 0 or T // chunk <= 1:
-        return block_nll(x, targets)
+def _block_nll_sum(x_c, embedding, t_c, ignore_index, logits_fn):
+    """Sum of `logsumexp(logits) - logit[target]` over one block's valid
+    targets; the (B, chunk, V) logits, their row max and lse in fp32."""
+    logits = (logits_fn or _default_logits)(x_c, embedding)
+    mask = t_c != ignore_index
+    safe = jnp.where(mask, t_c, 0)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    tgt = jnp.take_along_axis(logits, safe[..., None], axis=-1)[..., 0]
+    return jnp.where(mask, lse - tgt, 0.0).sum()
+
+
+def _as_chunks(x, targets, chunk):
+    """(n_chunks, B, chunk, ...): the scan iterates T-slices, B stays a real
+    dim so its 'data' sharding survives inside every chunk."""
+    B, T, C = x.shape
     n_chunks = T // chunk
+    return (jnp.moveaxis(x.reshape(B, n_chunks, chunk, C), 1, 0),
+            jnp.moveaxis(targets.reshape(B, n_chunks, chunk), 1, 0))
 
-    # (n_chunks, B, chunk, ...): scan iterates T-slices, B stays a real dim
-    # so its 'data' sharding survives inside every chunk.
-    xs = jnp.moveaxis(x.reshape(B, n_chunks, chunk, C), 1, 0)
-    ts = jnp.moveaxis(targets.reshape(B, n_chunks, chunk), 1, 0)
 
-    ckpt_nll = jax.checkpoint(block_nll)
+def _varying_like(a, like):
+    """`a` typed to vary over the mesh axes `like` varies over (a checked
+    shard_map's vma typing: a scan carry has its updates' type, a cotangent
+    its primal's); identity everywhere else."""
+    return compat.pcast_varying(a, compat.vma_of(like) - compat.vma_of(a))
+
+
+_PLAIN_SCAN = "fused, plain scan"
+_GRADS_IN_FORWARD = "fused, gradients in the forward scan"
+
+
+def _note(path, replaces, xs):
+    paths.note("loss", path, f"{xs.shape[0]} chunks of {xs.shape[2]} tokens",
+               replaces=replaces)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _scan_mean_nll(x, embedding, targets, denom, ignore_index, chunk,
+                   logits_fn):
+    """Sum of nll over valid targets / `denom`, one T-chunk at a time. This
+    body is the PRIMAL (no gradient taken: evaluation, the benchmark's
+    check): one head matmul a chunk and nothing kept."""
+    xs, ts = _as_chunks(x, targets, chunk)
+    _note(_PLAIN_SCAN, ("fused",), xs)
 
     # accumulate via stacked scan OUTPUTS, not the carry: a scalar-zero
     # carry would be unvarying over the mesh axes while the chunk sums vary
     # (shard_map vma typing), and (n_chunks,) scalars are free
     def body(carry, xt):
-        return carry, ckpt_nll(*xt)
+        return carry, _block_nll_sum(xt[0], embedding, xt[1], ignore_index,
+                                     logits_fn)
 
-    _, (sums, counts) = jax.lax.scan(body, None, (xs, ts))
-    return sums.sum(), counts.sum()
+    _, sums = jax.lax.scan(body, None, (xs, ts))
+    return sums.sum() / denom
+
+
+def _scan_mean_nll_fwd(x, embedding, targets, denom, ignore_index, chunk,
+                       logits_fn):
+    """The rule under a gradient: each chunk's `(softmax - onehot) * mask /
+    denom` is pulled back through the block WHILE its logits exist, so the
+    block the value read is the block the gradient reads. The loss is the
+    last thing forward does and its value a mean whose cotangent is a
+    scalar: nothing is gained by waiting for backward, which would have to
+    build every block again. `jax.vjp` of the block, not hand-written
+    matmuls: a `logits_fn` keeps its own transpose and a sharded embedding
+    its propagation."""
+    xs, ts = _as_chunks(x, targets, chunk)
+    _note(_GRADS_IN_FORWARD, ("fused", _PLAIN_SCAN), xs)
+    scale = 1.0 / denom
+
+    def body(dW, xt):
+        x_c, t_c = xt
+        s, pull = jax.vjp(
+            lambda a, e: _block_nll_sum(a, e, t_c, ignore_index, logits_fn),
+            x_c, embedding)
+        dx_c, dW_c = pull(_varying_like(scale, s))
+        return dW + dW_c, (s, dx_c)
+
+    # the accumulator in the embedding's dtype, as autodiff's own was: a
+    # float32 one is better numerics for 154 MB more traffic a chunk, and
+    # read +0.6 ms a step in the train cell (PERF.md section 6, PR 47)
+    dW0 = _varying_like(jnp.zeros_like(embedding), embedding)
+    dW, (sums, dxs) = jax.lax.scan(body, dW0, (xs, ts))
+    dx = jnp.moveaxis(dxs, 0, 1).reshape(x.shape)
+    return sums.sum() / denom, (dx, dW)
+
+
+def _scan_mean_nll_bwd(ignore_index, chunk, logits_fn, res, g):
+    dx, dW = res
+    return ((g * dx).astype(dx.dtype), (g * dW).astype(dW.dtype), None, None)
+
+
+_scan_mean_nll.defvjp(_scan_mean_nll_fwd, _scan_mean_nll_bwd)
 
 
 def sp_fused_cross_entropy(x: jnp.ndarray, embedding: jnp.ndarray,
@@ -124,8 +193,9 @@ def sp_fused_cross_entropy(x: jnp.ndarray, embedding: jnp.ndarray,
                            ignore_index: int = -1,
                            chunk: int = 0) -> jnp.ndarray:
     """Sequence-parallel chunked CE: each device chunk-scans its LOCAL
-    (B/dp, T/sp) token shard inside shard_map, then the sum/count pair is
-    psum'd over ('data', 'seq') for the global mean.
+    (B/dp, T/sp) token shard inside shard_map over the psum of the valid
+    counts, then the per-device sums are psum'd over ('data', 'seq') for
+    the global mean.
 
     This replaces the round-4 fallback where any live 'seq' axis demoted
     the loss to unchunked full-logits CE — a (B, T/sp, V) fp32
@@ -147,17 +217,23 @@ def sp_fused_cross_entropy(x: jnp.ndarray, embedding: jnp.ndarray,
         # shard_map the shard is T/sp, so a non-dividing chunk must be
         # re-derived locally (not silently degrade to one full-logits
         # block — the exact materialization this path removes)
-        t_local = x_l.shape[1]
-        c = chunk if (chunk > 0 and t_local % chunk == 0
-                      and t_local // chunk > 1) else 0
-        s, n = _nll_sum_chunked(x_l, emb, t_l, ignore_index, c)
-        s = jax.lax.psum(s, ("data", "seq"))
-        n = jax.lax.psum(n, ("data", "seq"))
-        return s / jnp.maximum(n, 1)
+        t_local, V = x_l.shape[1], emb.shape[0]
+        c = (_resolve_chunk(t_local, V, chunk)
+             or _resolve_chunk(t_local, V, 0))
+        # each device's sum over the GLOBAL valid count
+        n = jax.lax.psum((t_l != ignore_index).sum(), ("data", "seq"))
+        denom = jnp.maximum(n, 1)
+        # the replicated embedding typed as the shard's rows are: its
+        # gradient is then summed over the mesh once, behind the scan
+        emb = _varying_like(emb, x_l)
+        if c:
+            s = _scan_mean_nll(x_l, emb, t_l, denom, ignore_index, c, None)
+        else:   # tiny local T or V: one block under plain autodiff
+            s = _block_nll_sum(x_l, emb, t_l, ignore_index, None) / denom
+        return jax.lax.psum(s, ("data", "seq"))
 
     from jax.sharding import PartitionSpec as P
 
-    from distributed_pytorch_tpu import compat
     fn = compat.shard_map(
         local_body, mesh=mesh,
         in_specs=(P("data", "seq", None), P(None, None), P("data", "seq")),
@@ -169,8 +245,9 @@ def fused_cross_entropy(x: jnp.ndarray, embedding: jnp.ndarray,
                         targets: jnp.ndarray, *,
                         ignore_index: int = -1,
                         chunk: int = 0, logits_fn=None) -> jnp.ndarray:
-    """Chunked weight-tied CE: logits are computed (and re-computed in
-    backward) one T-chunk at a time; the (B, T, V) block never exists.
+    """Chunked weight-tied CE: logits are computed one T-chunk at a time,
+    ONCE (under a gradient the chunk's `dx` and `dW` are taken in the same
+    scan step, `_scan_mean_nll_fwd`); the (B, T, V) block never exists.
 
     x: (B, T, C) hidden states (compute dtype); embedding: (V, C);
     targets: (B, T) int with `ignore_index` masking. `chunk=0` picks a
@@ -178,14 +255,11 @@ def fused_cross_entropy(x: jnp.ndarray, embedding: jnp.ndarray,
     chunking can't help). `logits_fn(x_chunk, embedding)` overrides the
     per-chunk lm-head matmul (collective-matmul routing, gpt.py).
     """
-    B, T, C = x.shape
-    V = embedding.shape[0]
-    if chunk <= 0:
-        chunk = _chunk_for(T, V)
-    if chunk <= 0 or T % chunk != 0 or T // chunk <= 1:
+    chunk = _resolve_chunk(x.shape[1], embedding.shape[0], chunk)
+    if not chunk:
         return unchunked_cross_entropy(x, embedding, targets,
                                        ignore_index=ignore_index,
                                        logits_fn=logits_fn)
-    total, count = _nll_sum_chunked(x, embedding, targets, ignore_index,
-                                    chunk, logits_fn=logits_fn)
-    return total / jnp.maximum(count, 1)
+    denom = jnp.maximum((targets != ignore_index).sum(), 1)
+    return _scan_mean_nll(x, embedding, targets, denom, ignore_index, chunk,
+                          logits_fn)

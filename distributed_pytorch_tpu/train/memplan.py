@@ -238,9 +238,12 @@ def estimate_peak_gb(cfg: LLMConfig, recipe: str, micro_batch: int,
         act_b += tokens * _act_bytes_per_token_layer(cfg, "none")
     # embedding output + final-LN + rope residuals, bf16
     act_b += tokens * cfg.n_embd * 2 * 3
-    # fused-CE logits chunk (fp32), forward+backward block pair
+    # fused-CE: ONE fp32 logits chunk (its gradients are taken in the scan
+    # step that built it, ops/losses.py) beside the dW accumulator and the
+    # per-chunk dW it adds, both in the compute dtype
     chunk = cfg.loss_chunk or min(128, cfg.block_size)
-    loss_b = 2 * micro_batch * chunk * cfg.vocab_size * 4
+    loss_b = (micro_batch * chunk * cfg.vocab_size * 4
+              + 2 * cfg.vocab_size * cfg.n_embd * 2)
     # the ZeRO-3 gather working set: with OVERLAP rings or GSPMD streaming
     # gathers, roughly the largest layer's full params in compute dtype
     # live at once; with hoisted gathers (grad accum) the whole model does.

@@ -352,6 +352,37 @@ def test_the_up_projection_writes_the_pair_and_nothing_float32(
 
 
 # ---------------------------------------------------------------------------
+# the head's cross-entropy under a gradient: dx and dW in the forward scan
+# ---------------------------------------------------------------------------
+
+def test_the_loss_builds_each_logits_block_once(v5e):
+    """Value-and-grad of the chunked loss at the train cell's shapes (16 x
+    1024 x 768 bf16, a bf16 embedding of 50,304 rows, 8 chunks of 128):
+    ONE loop with three head-sized matmuls (logits, dx, dW). Left to
+    autodiff under `jax.checkpoint` it was two loops and four, every
+    float32 `[16,128,50304]` block built and reduced twice (PERF.md
+    section 6, PR 47), in 496,329,216 B of temp as ISSUE 47 read it."""
+    from distributed_pytorch_tpu.ops.losses import fused_cross_entropy
+
+    def value_and_grad(x, emb, t):
+        return jax.value_and_grad(
+            lambda a, e: fused_cross_entropy(a, e, t), argnums=(0, 1))(
+            x, emb)
+
+    compiled = _compile(value_and_grad, [((16, 1024, C), BF16),
+                                         ((V, C), BF16),
+                                         ((16, 1024), I32)], v5e)
+    text = compiled.as_text()
+    assert len(re.findall(r" while\(", text)) == 1
+    # the loss has no other matmul: every convolution is the head's
+    head = [ln for ln in text.splitlines() if " convolution(" in ln]
+    assert len(head) == 3, head
+    assert all("/while/body/" in ln for ln in head), head
+    assert f"f32[16,1024,{V}]" not in text, "the whole logits in the program"
+    assert compiled.memory_analysis().temp_size_in_bytes <= 496_329_216
+
+
+# ---------------------------------------------------------------------------
 # the paged pools are never copied: one layout from the donated argument
 # through the write and the kernel
 # ---------------------------------------------------------------------------

@@ -100,6 +100,194 @@ def test_model_loss_impl_parity():
 
 
 # ---------------------------------------------------------------------------
+# the rule under a gradient: dx and dW taken inside the forward chunk scan
+# ---------------------------------------------------------------------------
+
+def _checkpointed_scan_ce(x, emb, tgt, chunk, ignore_index=-1):
+    """What `fused_cross_entropy` was before it had a rule of its own: the
+    chunk scan under `jax.checkpoint`, left to autodiff (every logits block
+    built again in backward). Kept here as the yardstick of the rule's
+    rounding."""
+    B, T, C = x.shape
+    n = T // chunk
+    xs = jnp.moveaxis(x.reshape(B, n, chunk, C), 1, 0)
+    ts = jnp.moveaxis(tgt.reshape(B, n, chunk), 1, 0)
+
+    @jax.checkpoint
+    def block(x_c, t_c):
+        logits = jax.lax.dot_general(
+            x_c, emb, (((2,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        mask = t_c != ignore_index
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        tg = jnp.take_along_axis(
+            logits, jnp.where(mask, t_c, 0)[..., None], axis=-1)[..., 0]
+        return jnp.where(mask, lse - tg, 0.0).sum(), mask.sum()
+
+    _, (sums, counts) = jax.lax.scan(lambda c, xt: (c, block(*xt)), None,
+                                     (xs, ts))
+    return sums.sum() / jnp.maximum(counts.sum(), 1)
+
+
+def _float32_oracle_grads(x, emb, tgt):
+    def exact(a, e):
+        return jnp.einsum("btc,vc->btv", a, e, precision="highest")
+
+    return jax.grad(
+        lambda a, e: unchunked_cross_entropy(a, e, tgt, logits_fn=exact),
+        argnums=(0, 1))(x.astype(jnp.float32), emb.astype(jnp.float32))
+
+
+def _rel_rms(got, want):
+    got = np.asarray(got, np.float32)
+    return float(np.sqrt(np.mean((got - want) ** 2) / np.mean(want ** 2)))
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_rule_rounds_no_worse_than_the_checkpointed_scan_in_bf16(seed):
+    """bf16 operands at a vocabulary the auto chunking engages for: the
+    rule's dx and dW against the float32 oracle, no further off than the
+    autodiff of the scan it replaced (same matmuls, same prologue, a dW
+    accumulator of the same dtype)."""
+    x, emb, tgt = _data(B=2, T=256, C=32, V=8192, seed=seed)
+    x, emb = x.astype(jnp.bfloat16), emb.astype(jnp.bfloat16)
+    tgt = tgt.at[1, 200:].set(-1)
+    want = [np.asarray(g) for g in _float32_oracle_grads(x, emb, tgt)]
+    rule = jax.grad(lambda a, e: fused_cross_entropy(a, e, tgt),
+                    argnums=(0, 1))(x, emb)          # chunk 0 -> 128
+    was = jax.grad(lambda a, e: _checkpointed_scan_ce(a, e, tgt, 128),
+                   argnums=(0, 1))(x, emb)
+    for name, r, w, ref in zip(("dx", "dW"), rule, was, want):
+        assert r.dtype == jnp.bfloat16
+        err, err_was = _rel_rms(r, ref), _rel_rms(w, ref)
+        assert err <= 1.05 * err_was, (name, err, err_was)
+        assert err < 0.02, (name, err)
+
+
+@pytest.mark.parametrize("masked", ["part_of_a_chunk", "a_whole_chunk",
+                                    "everything"])
+def test_rule_gradients_under_ignore_index(masked):
+    x, emb, tgt = _data()
+    tgt = {"part_of_a_chunk": tgt.at[:, 11:16].set(-1),
+           "a_whole_chunk": tgt.at[:, 8:16].set(-1),
+           "everything": jnp.full_like(tgt, -1)}[masked]
+    ref, g_ref = jax.value_and_grad(
+        lambda a, e: unchunked_cross_entropy(a, e, tgt), argnums=(0, 1))(
+        x, emb)
+    got, g_got = jax.value_and_grad(
+        lambda a, e: fused_cross_entropy(a, e, tgt, chunk=8),
+        argnums=(0, 1))(x, emb)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-6)
+    for r, g in zip(g_ref, g_got):
+        assert np.isfinite(np.asarray(g)).all()
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=1e-5, atol=1e-6)
+    if masked == "everything":
+        assert float(got) == 0.0
+        assert all(not np.asarray(g).any() for g in g_got)
+    else:   # a masked row takes no gradient
+        np.testing.assert_array_equal(np.asarray(g_got[0][:, 11:16]), 0.0)
+
+
+def test_rule_scales_with_the_cotangent():
+    x, emb, tgt = _data()
+
+    def loss(a, e):
+        return fused_cross_entropy(a, e, tgt, chunk=8)
+
+    g1 = jax.grad(loss, argnums=(0, 1))(x, emb)
+    g3 = jax.grad(lambda a, e: 3.0 * loss(a, e) + 1.0, argnums=(0, 1))(
+        x, emb)
+    for a, b in zip(g1, g3):
+        np.testing.assert_allclose(np.asarray(b), 3.0 * np.asarray(a),
+                                   rtol=1e-6)
+
+
+def test_rule_inside_a_scan_over_microbatches():
+    """As `train/step.py::micro_step`: value_and_grad inside a `lax.scan`
+    over microbatches, gradients summed in the carry."""
+    x, emb, tgt = _data(B=4)
+    xm, tm = x.reshape(2, 2, *x.shape[1:]), tgt.reshape(2, 2, -1)
+
+    def accumulate(loss_fn):
+        def micro(acc, xt):
+            l, g = jax.value_and_grad(
+                lambda a, e: loss_fn(a, e, xt[1]), argnums=(0, 1))(
+                xt[0], emb)
+            return acc + g[1], (l, g[0])
+        return jax.jit(lambda: jax.lax.scan(
+            micro, jnp.zeros_like(emb), (xm, tm)))()
+
+    dW, (ls, dxs) = accumulate(
+        lambda a, e, t: fused_cross_entropy(a, e, t, chunk=8))
+    dW_r, (ls_r, dxs_r) = accumulate(unchunked_cross_entropy)
+    for got, ref in ((dW, dW_r), (ls, ls_r), (dxs, dxs_r)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-6)
+
+
+_V = 8192
+
+
+def _count_eqns(jaxpr, counts=None):
+    """{'scan': n, 'head_dot': n} over a jaxpr and everything it calls: a
+    `dot_general` counts if the vocabulary is in its shapes."""
+    counts = counts if counts is not None else {"scan": 0, "head_dot": 0}
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            counts["scan"] += 1
+        elif eqn.primitive.name == "dot_general" and any(
+                _V in v.aval.shape for v in (*eqn.invars, *eqn.outvars)):
+            counts["head_dot"] += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _count_eqns(sub, counts)
+    return counts
+
+
+@pytest.mark.parametrize("what, want", [
+    ("gradient", {"scan": 1, "head_dot": 3}),
+    ("primal", {"scan": 1, "head_dot": 1})])
+def test_rule_structure(what, want):
+    """Under a gradient ONE scan holds the head matmul, dx and dW (the
+    checkpointed scan held two loops and four); undifferentiated, one
+    matmul a chunk and nothing else."""
+    x, emb, tgt = _data(B=2, T=256, C=32, V=_V)
+
+    def loss(a, e):
+        return fused_cross_entropy(a, e, tgt)
+
+    fn = jax.grad(loss, argnums=(0, 1)) if what == "gradient" else loss
+    assert _count_eqns(jax.make_jaxpr(fn)(x, emb).jaxpr) == want
+    if what == "gradient":
+        was = jax.make_jaxpr(jax.grad(
+            lambda a, e: _checkpointed_scan_ce(a, e, tgt, 128),
+            argnums=(0, 1)))(x, emb)
+        assert _count_eqns(was.jaxpr) == {"scan": 2, "head_dot": 4}
+
+
+@pytest.mark.parametrize("differentiated", [True, False])
+def test_the_census_names_the_rule_that_ran(differentiated):
+    from distributed_pytorch_tpu.obs import paths
+    cfg = LLMConfig(vocab_size=96, block_size=32, n_embd=32, n_head=4,
+                    n_kv_heads=4, n_layer=1, up_dim=48, loss_impl="fused",
+                    loss_chunk=4)
+    idx = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, 96)
+    model = LLM(cfg)
+    variables = model.init(jax.random.PRNGKey(0), idx, idx)
+
+    def loss(p):
+        return model.apply({"params": p}, idx, idx)[1]
+
+    paths.reset()
+    jax.make_jaxpr(jax.grad(loss) if differentiated else loss)(
+        variables["params"])
+    want = ("fused, gradients in the forward scan" if differentiated
+            else "fused, plain scan")
+    assert paths.choices()["loss"] == f"{want} (4 chunks of 4 tokens)"
+    paths.reset()
+
+
+# ---------------------------------------------------------------------------
 # Pallas streaming CE (ops/fused_ce.py) vs the oracle, interpret mode on CPU
 # ---------------------------------------------------------------------------
 
@@ -183,12 +371,7 @@ def test_pallas_ce_dp_shard_map_parity():
                                    rtol=2e-5, atol=2e-6)
 
 
-@pytest.mark.parametrize("chunk", [0, 8])
-def test_sp_fused_ce_matches_oracle(chunk):
-    """Sequence-parallel chunked CE (round-5: replaces the unchunked
-    fallback under a live 'seq' axis): value and grads must match the
-    full-logits oracle on a data=4 x seq=2 mesh, with and without an
-    explicit chunk size, including masked targets."""
+def _sp_fused_ce_against_the_oracle(chunk):
     from distributed_pytorch_tpu.ops.losses import sp_fused_cross_entropy
     from distributed_pytorch_tpu.parallel import context
     from distributed_pytorch_tpu.parallel.mesh import mesh_for
@@ -207,6 +390,28 @@ def test_sp_fused_ce_matches_oracle(chunk):
     for r, g in zip(g_ref, g_got):
         np.testing.assert_allclose(np.asarray(g), np.asarray(r),
                                    rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("chunk", [0, 8])
+def test_sp_fused_ce_matches_oracle(chunk):
+    """Sequence-parallel chunked CE (round-5: replaces the unchunked
+    fallback under a live 'seq' axis): value and grads must match the
+    full-logits oracle on a data=4 x seq=2 mesh, with and without an
+    explicit chunk size, including masked targets."""
+    _sp_fused_ce_against_the_oracle(chunk)
+
+
+def test_sp_fused_ce_under_a_checked_shard_map(monkeypatch):
+    """The same under `check_vma=True` (the repo's shard_map leaves it off):
+    the dW carry, the pulled-back scalar and the embedding are typed to
+    vary as the shard's rows do, so the rule's types close."""
+    import functools
+
+    from distributed_pytorch_tpu import compat
+
+    monkeypatch.setattr(compat, "shard_map",
+                        functools.partial(compat.shard_map, check=True))
+    _sp_fused_ce_against_the_oracle(8)
 
 
 def test_sp_train_step_uses_chunked_loss():

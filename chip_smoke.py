@@ -13,9 +13,9 @@ from --seed, synthetic data from the loader, no network):
            bounded) — interpret mode never ran the compiled arithmetic;
   train    `python -m distributed_pytorch_tpu --preset gpt2_124m ...`
            (9 steps with `--attn_impl xla` + fused CE, a verified
-           checkpoint), then 2 steps with `--attn_impl pallas --loss_impl
-           pallas` whose first loss must agree with the XLA run's (the
-           default `auto` takes the flash kernels here, so both are named);
+           checkpoint), then 2 steps with `--attn_impl pallas` whose first
+           loss must agree with the XLA run's (the default `auto` takes
+           the flash kernels here, so both are named);
   serve    `python -m distributed_pytorch_tpu.serve --ckpt <that one>`,
            six HTTP completions (three in flight), greedy determinism,
            prefix reuse, SIGTERM; then `python -m
@@ -68,17 +68,15 @@ FLAGSHIP = Shape(("--preset", "gpt2_124m"), 1024, 50304)
 
 # kernels the compiled programs must hold at flagship widths on a TPU —
 # a gate that quietly declines is a failure of the smoke, not a slower pass
-PALLAS_TRAIN_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
-                        "ce_fwd", "ce_bwd_dx", "ce_bwd_dw")
+PALLAS_TRAIN_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
 ENGINE_KERNELS = {"engine.step": ("paged_flash_decode",),
                   "engine.fused_step": ("paged_flash_decode",
                                         "paged_flash_prefill")}
 
 LOSS_TOL = 2e-2          # bf16: pallas vs XLA first loss, fsdp vs dp per step
 # kernel numerics: max abs error over the reference's max abs value
-# (bf16 operands, f32 accumulation, an all-f32 reference), and absolute
-# for a scalar loss
-KERNEL_BOUNDS = {"out": 2e-2, "grad": 5e-2, "abs": 2e-2}
+# (bf16 operands, f32 accumulation, an all-f32 reference)
+KERNEL_BOUNDS = {"out": 2e-2, "grad": 5e-2}
 
 
 class PhaseFailed(RuntimeError):
@@ -254,12 +252,12 @@ def phase_train(out: str, *, seed: int, platform: str,
            "compile_seconds": prog["compile_s"]}
     if kernel_iters >= 0:
         # the same model and seed through flash attention forward AND
-        # backward and the streaming CE — executed, not just compiled
+        # backward — executed, not just compiled
         run_child(phase, _train_cmd(
             shape, platform, seed, name="smoke_pallas", recipe="single",
             batch=batch, global_batch=batch, max_iters=kernel_iters,
             log_interval=1,
-            extra=("--attn_impl", "pallas", "--loss_impl", "pallas")),
+            extra=("--attn_impl", "pallas")),
             cwd=out, log=log, env=env, timeout=900)
         sp = _train_stats(phase, out, "smoke_pallas", platform, 1, log)
         pprog = sp["programs"]["train.step"]
@@ -566,8 +564,8 @@ def phase_multichip(out: str, *, seed: int, platform: str,
 # that imports jax, and only in that child
 # ---------------------------------------------------------------------------
 
-FLAGSHIP_WIDTHS = dict(nh=12, hs=64, C=768, V=50304, bs=128, T=1024, B=16,
-                       T_long=8192, slots=8, S=1024, chunk=256, ce_rows=4,
+FLAGSHIP_WIDTHS = dict(nh=12, hs=64, C=768, bs=128, T=1024, B=16,
+                       T_long=8192, slots=8, S=1024, chunk=256,
                        moe_tokens=16384, moe_E=8, moe_up=1024, fence_n=8192)
 
 
@@ -596,12 +594,10 @@ def kernel_numerics(seed: int, platform: str, w: dict = FLAGSHIP_WIDTHS,
         f"{dev.platform!r} ({dev.device_kind})")
     from distributed_pytorch_tpu.ops import flash_attention as fa
     from distributed_pytorch_tpu.ops import flash_decode as fd
-    from distributed_pytorch_tpu.ops import fused_ce
     from distributed_pytorch_tpu.ops import grouped_matmul as gm
     from distributed_pytorch_tpu.ops.attention_core import _naive_sdpa
     from distributed_pytorch_tpu.ops.block_pool import (kv_lanes, merge_heads,
                                                         paged_gather)
-    from distributed_pytorch_tpu.ops.losses import unchunked_cross_entropy
     from distributed_pytorch_tpu.ops.quant import dequantize_int8, quantize_kv
 
     bf16, f32 = jnp.bfloat16, jnp.float32
@@ -624,7 +620,7 @@ def kernel_numerics(seed: int, platform: str, w: dict = FLAGSHIP_WIDTHS,
             assert np.isfinite(got).all(), f"{name} {label} is not finite"
             peak = float(np.abs(ref).max())
             e = float(np.abs(got - ref).max())
-            rel = e if kind == "abs" else e / peak
+            rel = e / peak
             row[label] = {"max_abs_err": e, "ref_abs_max": peak,
                           "measured": rel, "bound": KERNEL_BOUNDS[kind],
                           "kind": kind}
@@ -667,23 +663,6 @@ def kernel_numerics(seed: int, platform: str, w: dict = FLAGSHIP_WIDTHS,
                 "dk": (dk, rk, "grad"), "dv": (dv, rv, "grad")},
                time.perf_counter() - t0)
         del q, k, v, g, out, dq, dk, dv, ro, rq, rk, rv
-
-    # ---- streaming cross-entropy forward + backward
-    t0 = time.perf_counter()
-    x = normal((w["ce_rows"], w["T"], w["C"]))
-    emb = normal((w["V"], w["C"]), std=0.02)
-    tgt = jax.random.randint(next(keys), (w["ce_rows"], w["T"]), 0, w["V"])
-    tgt = tgt.at[:, ::17].set(-1)                   # some ignored rows
-    vg = lambda fn: jax.jit(jax.value_and_grad(  # noqa: E731
-        fn, argnums=(0, 1)))(x, emb)
-    loss, (dx, de) = vg(lambda a, e: fused_ce.pallas_cross_entropy(a, e, tgt))
-    rl, (rx, re_) = vg(lambda a, e: unchunked_cross_entropy(
-        a.astype(f32), e.astype(f32), tgt))
-    record("pallas_cross_entropy fwd+bwd", [w["ce_rows"] * w["T"], w["C"],
-                                            w["V"]],
-           {"loss": (loss, rl, "abs"), "dx": (dx, rx, "grad"),
-            "dembedding": (de, re_, "grad")}, time.perf_counter() - t0)
-    del x, emb, dx, de, rx, re_
 
     # ---- decode kernels: contiguous, paged, paged chunk prefill
     S, slots = w["S"], w["slots"]

@@ -95,11 +95,7 @@ def knobs_table() -> str:
 # --- kernel tile sizes (read at import by their owner modules). The
 # flash-attention tiles and the scoped-VMEM limit are constants since the
 # chip chose them (ops/flash_attention.py, compat.py); each of these
-# becomes one with the ROADMAP item that measures it (S4, R2, S5) ---
-register_knob("CE_BLOCK_N", "512", int,
-              "pallas fused-CE token tile (ops/fused_ce.py)")
-register_knob("CE_BLOCK_V", "2048", int,
-              "pallas fused-CE vocab tile")
+# becomes one with the ROADMAP item that measures it (R2, S5) ---
 register_knob("GMM_BLOCK_M", "128", int,
               "grouped-matmul token-row tile (ops/grouped_matmul.py)")
 register_knob("GMM_BLOCK_N", "512", int,
@@ -412,11 +408,10 @@ class LLMConfig:
     act_recomp_policy: str = "block"  # 'block' | 'attn'
 
     # loss path: 'fused' computes CE blockwise over T without materializing
-    # the (B, T, V) logits (ops/losses.py — the round-3 MFU fix); 'pallas'
-    # streams (token, vocab) tiles through VMEM so logits never touch HBM
-    # at all (ops/fused_ce.py; falls back to 'fused' when unusable —
-    # tp/sp live, odd shapes, non-TPU); 'unchunked' is the full-logits
-    # semantics oracle. loss_chunk: T-chunk size for 'fused', 0 = auto.
+    # the (B, T, V) logits (ops/losses.py — the round-3 MFU fix);
+    # 'unchunked' is the full-logits semantics oracle. Which of them a
+    # program ran, and under what mesh, is `ops/losses.py tied_head_loss`'s
+    # to say. loss_chunk: T-chunk size for 'fused', 0 = auto.
     loss_impl: str = "fused"
     loss_chunk: int = 0
 
@@ -560,7 +555,15 @@ class LLMConfig:
         assert self.capacity_factor > 0
         assert self.act_recomp_policy in ("block", "attn"), \
             f"unknown act_recomp_policy {self.act_recomp_policy!r}"
-        assert self.loss_impl in ("fused", "unchunked", "pallas"), \
+        if self.loss_impl == "pallas":
+            # a stored config may still name it: never run 'fused' under
+            # the kernel's name
+            raise ValueError(
+                "loss_impl='pallas': the streaming CE kernel left the tree "
+                "at PR 48; 'fused' is what ran faster in the train cell "
+                "(loss_ms.train 27.85 against 34.72, PERF.md section 6): "
+                "say loss_impl='fused'")
+        assert self.loss_impl in ("fused", "unchunked"), \
             f"unknown loss_impl {self.loss_impl!r}"
         if self.loss_chunk > 0:
             # a non-dividing chunk would silently fall back to the

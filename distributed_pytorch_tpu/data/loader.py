@@ -49,12 +49,42 @@ def make_synthetic_bin(path: str, n_tokens: int = 2 ** 20,
     return path
 
 
+_M0 = np.uint64(0xD2511F53)
+_M1 = np.uint64(0xCD9E8D57)
+_MASK32 = np.uint64(0xFFFFFFFF)
+
+
+def philox_offsets(seed: int, step: int, rows: np.ndarray,
+                   hi: int) -> np.ndarray:
+    """Philox4x32-10 offsets in [0, hi) for global batch-row ids `rows` at
+    (seed, step): counter (row, step lo, step hi, 0), key (seed lo, seed
+    hi), the draw the counter's first two words."""
+    rows = np.asarray(rows, np.uint32)
+    c0 = rows.astype(np.uint64)
+    c1 = np.full_like(c0, np.uint64(step & 0xFFFFFFFF))
+    c2 = np.full_like(c0, np.uint64((step >> 32) & 0xFFFFFFFF))
+    c3 = np.zeros_like(c0)
+    k0 = seed & 0xFFFFFFFF          # python ints: explicit mod-2^32 adds
+    k1 = (seed >> 32) & 0xFFFFFFFF
+    for _ in range(10):
+        p0 = _M0 * c0          # 64-bit products (c in [0, 2^32))
+        p1 = _M1 * c2
+        hi0, lo0 = p0 >> np.uint64(32), p0 & _MASK32
+        hi1, lo1 = p1 >> np.uint64(32), p1 & _MASK32
+        c0, c1, c2, c3 = (hi1 ^ c1 ^ np.uint64(k0), lo1,
+                          hi0 ^ c3 ^ np.uint64(k1), lo0)
+        k0 = (k0 + 0x9E3779B9) & 0xFFFFFFFF
+        k1 = (k1 + 0xBB67AE85) & 0xFFFFFFFF
+    u = (c1 << np.uint64(32)) | c0
+    return (u % np.uint64(hi)).astype(np.int64)
+
+
 class DataLoader:
     """Random-offset batch sampler over a uint16 token memmap."""
 
     def __init__(self, file_path: str, batch_size: int, block_size: int, *,
                  grad_accum: int = 1, seed: int = 1729,
-                 mesh=None, pspec=None, backend: str = "auto"):
+                 mesh=None, pspec=None):
         self.tokens = np.memmap(file_path, dtype=np.uint16, mode="r")
         assert len(self.tokens) > block_size + 1, (
             f"dataset {file_path} too small: {len(self.tokens)} tokens "
@@ -66,33 +96,12 @@ class DataLoader:
         self.pspec = pspec
         self._sharding = (NamedSharding(mesh, pspec)
                          if mesh is not None and pspec is not None else None)
-        # native C++ sampler (csrc/sampler.cpp: mmap + threaded gather +
-        # background prefetch); the numpy path computes the SAME
-        # Philox4x32-10 stream, so the backends are interchangeable
-        assert backend in ("auto", "native", "numpy")
-        self._native = None
-        if backend in ("auto", "native"):
-            from distributed_pytorch_tpu.data import native
-            try:
-                self._native = native.NativeSampler(file_path)
-            except OSError:
-                if backend == "native":
-                    raise
-        self.backend = "native" if self._native is not None else "numpy"
 
     def _sample(self, step: int, rows: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
         """Gather (len(rows), T) x/y pairs for global batch-row ids `rows` at
         `step`. Counter-based (Philox4x32-10) keyed on (seed, step, row): any
         process can materialize any subset of the global batch
         deterministically."""
-        rows = np.asarray(rows)
-        if self._native is not None:
-            full = len(rows) == self.A * self.B and \
-                np.array_equal(rows, np.arange(self.A * self.B))
-            if full:  # contiguous global batch: prefetched path
-                return self._native.sample(self.seed, step, len(rows), self.T)
-            return self._native.sample_rows(self.seed, step, rows, self.T)
-        from distributed_pytorch_tpu.data.native import philox_offsets
         hi = len(self.tokens) - self.T - 1
         offsets = philox_offsets(self.seed, step, rows, hi)
         idx = offsets[:, None] + np.arange(self.T + 1)[None, :]

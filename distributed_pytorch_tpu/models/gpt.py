@@ -39,10 +39,7 @@ from distributed_pytorch_tpu.models.mlp import MLP, MoE, RoutedExperts
 from distributed_pytorch_tpu.models.shortconv import (ShortConv,
                                                       init_conv_cache)
 from distributed_pytorch_tpu.models.ssm import Mamba2, init_ssm_cache
-from distributed_pytorch_tpu.obs import paths
-from distributed_pytorch_tpu.ops.losses import (fused_cross_entropy,
-                                                sp_fused_cross_entropy,
-                                                unchunked_cross_entropy)
+from distributed_pytorch_tpu.ops.losses import tied_head_loss
 from distributed_pytorch_tpu.ops.rope import precompute_rope_freqs, slice_rows
 
 _EMBED_INIT = nn.initializers.normal(stddev=0.02)
@@ -402,90 +399,11 @@ class LLM(nn.Module):
         if targets is not None:
             assert head is None and cfg.logits_div == 1.0, \
                 "the training loss runs the tied head only, logits undivided"
-            # Weight-tied CE with ignore_index=-1 (reference :559-560, :689),
-            # fp32-accumulated. The fused path never materializes the
-            # (B, T, V) logits (ops/losses.py); under a live 'seq' axis the
-            # chunk scan runs per-device over the local T shard inside
-            # shard_map (sp_fused_cross_entropy).
             # scope `loss` (obs/trace.py SCOPES): head matmul + CE, every impl
             with jax.named_scope("loss"):
-                from distributed_pytorch_tpu.parallel import context
-                emb_mat = tkn_emb.embedding.astype(dt)  # (V, C)
-                loss_impl = cfg.loss_impl
-
-                def logits_fn(x_c, emb):
-                    # lm-head gather as a collective matmul (the (V, C)
-                    # embedding is the largest single param ZeRO-3 shards):
-                    # under OVERLAP=on the per-chunk logits matmul rings the
-                    # vocab shards; the dispatcher declines everywhere else
-                    # and the default plain matmul is bit-identical
-                    from distributed_pytorch_tpu.ops.collective_matmul import (
-                        maybe_overlap_matmul)
-                    from distributed_pytorch_tpu.ops.losses import \
-                        _default_logits
-                    y = maybe_overlap_matmul(x_c, emb,
-                                             names=("tkn_emb", "embedding"),
-                                             transpose_b=True,
-                                             out_dtype=jnp.float32)
-                    return y if y is not None else _default_logits(x_c, emb)
-                if loss_impl == "pallas":
-                    # Streaming-kernel gates: no vocab-parallel embedding (tp
-                    # shards V and the kernel's logsumexp is per-shard-local),
-                    # no live 'seq' axis (T is sequence-sharded), shapes the
-                    # kernel tiles, and a TPU backend (interpret on CPU is
-                    # test-only slow). The kernel was asked for BY NAME, so a
-                    # gate that declines is an error naming it, never a quiet
-                    # 'fused' run under the kernel's name.
-                    from distributed_pytorch_tpu.ops.fused_ce import (
-                        pallas_ce_decline, pallas_cross_entropy)
-                    mesh = context.get_mesh()
-                    tp = mesh.shape.get("model", 1) if mesh is not None else 1
-                    dp = mesh.shape.get("data", 1) if mesh is not None else 1
-                    if jax.default_backend() != "tpu":
-                        why = f"backend is {jax.default_backend()}, not tpu"
-                    elif context.seq_axis_size() > 1 or tp > 1:
-                        why = (f"live 'seq' ({context.seq_axis_size()}) or "
-                               f"'model' ({tp}) mesh axis")
-                    elif x.shape[0] % dp != 0:
-                        why = f"batch {x.shape[0]} not divisible by dp={dp}"
-                    else:
-                        why = pallas_ce_decline(
-                            (x.shape[0] // dp) * x.shape[1], x.shape[-1],
-                            x.dtype)
-                    if why is not None:
-                        raise paths.declined("loss_impl='pallas'",
-                                             "pallas_ce_usable", why)
-                    paths.note("loss", "pallas streaming CE",
-                               "loss_impl=pallas")
-                    main_loss = pallas_cross_entropy(x, emb_mat, targets)
-                else:
-                    # 'fused': the chunk scan replaces this note with the
-                    # rule it ran, gradients in the forward scan under a
-                    # gradient and the plain scan otherwise (ops/losses.py)
-                    paths.note("loss", loss_impl, f"loss_impl={loss_impl}")
-                if loss_impl == "fused" and context.seq_axis_size() > 1:
-                    # live 'seq' axis: chunk over the LOCAL T shard inside
-                    # shard_map (ops/losses.py sp_fused_cross_entropy) instead
-                    # of materializing seq-sharded full logits. Gates: no
-                    # vocab-parallel embedding, B divisible by dp, T by sp.
-                    mesh = context.get_mesh()
-                    tp = mesh.shape.get("model", 1)
-                    dp = mesh.shape.get("data", 1)
-                    sp = context.seq_axis_size()
-                    if (tp == 1 and x.shape[0] % dp == 0
-                            and x.shape[1] % sp == 0):
-                        main_loss = sp_fused_cross_entropy(
-                            x, emb_mat, targets, chunk=cfg.loss_chunk)
-                    else:
-                        main_loss = unchunked_cross_entropy(
-                            x, emb_mat, targets, logits_fn=logits_fn)
-                elif loss_impl == "fused":
-                    main_loss = fused_cross_entropy(
-                        x, emb_mat, targets, chunk=cfg.loss_chunk,
-                        logits_fn=logits_fn)
-                elif loss_impl != "pallas":
-                    main_loss = unchunked_cross_entropy(
-                        x, emb_mat, targets, logits_fn=logits_fn)
+                main_loss = tied_head_loss(
+                    x, tkn_emb.embedding.astype(dt), targets,
+                    impl=cfg.loss_impl, chunk=cfg.loss_chunk)
                 loss = main_loss + total_aux / cfg.n_layer
             # full logits stay available to callers (tests, analysis); when
             # unused — as in the trainer, which takes only `loss` — XLA

@@ -62,7 +62,7 @@ TPU-first design (SURVEY §7 hard part (a)):
 from __future__ import annotations
 
 import math
-from typing import Any, Callable
+from typing import Any
 
 import flax.linen as nn
 import jax
@@ -71,68 +71,9 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distributed_pytorch_tpu.config import LLMConfig
+from distributed_pytorch_tpu.ops.activations import activation, is_gated
 
 _DENSE_INIT = nn.initializers.normal(stddev=0.02)
-
-
-@jax.custom_vjp
-def gelu_exact(x: jnp.ndarray) -> jnp.ndarray:
-    """Exact gelu, `0.5 x erfc(-x / sqrt 2)`, with a differentiation rule of
-    its own. Undifferentiated (serving, evaluation) it IS
-    `jax.nn.gelu(x, approximate=False)`. Left to autodiff under a gradient,
-    the compiler saves x and clones the whole `erfc` expansion (~66 float32
-    vector ops, two divides and an `exp` an element) into every consumer:
-    the down-projection's forward, its weight gradient and its dgrad each
-    ran at the vector unit's pace, not the MXU's (PERF.md section 6, PR 46).
-    Here forward evaluates the expansion once and writes what backward
-    needs, x and `erfc(-x / sqrt 2)` in x's dtype; gelu and gelu' are three
-    and ten ops from that pair, cheap enough to ride in the matmuls that
-    read them."""
-    return jax.nn.gelu(x, approximate=False)
-
-
-def _gelu_exact_fwd(x):
-    # jax.nn.gelu's own expression, its erfc kept: the value is the primal's
-    e = jax.lax.erfc(-x * np.sqrt(0.5).astype(x.dtype))
-    # the pair EXISTS in HBM: without the barrier the compiler saves x alone
-    # and evaluates the expansion again inside each consumer
-    x, e = jax.lax.optimization_barrier((x, e))
-    return 0.5 * x * e, (x, e)
-
-
-def _gelu_exact_bwd(res, da):
-    # gelu'(x) = 0.5 erfc(-x / sqrt 2) + x exp(-x^2 / 2) / sqrt(2 pi)
-    x, e = res
-    xf = x.astype(jnp.float32)
-    g = 0.5 * e.astype(jnp.float32) + xf * jnp.exp(-0.5 * xf * xf) * (
-        1.0 / math.sqrt(2.0 * math.pi))
-    return ((da.astype(jnp.float32) * g).astype(x.dtype),)
-
-
-gelu_exact.defvjp(_gelu_exact_fwd, _gelu_exact_bwd)
-
-
-def _activation(name: str) -> Callable[[jnp.ndarray], jnp.ndarray]:
-    name = name.lower()
-    table = {
-        "relu": jax.nn.relu,
-        "gelu": gelu_exact,
-        "swish": jax.nn.silu,
-        "silu": jax.nn.silu,
-        "mish": jax.nn.mish,
-        "selu": jax.nn.selu,
-        "celu": jax.nn.celu,
-        "elu": jax.nn.elu,
-        "sigmoid": jax.nn.sigmoid,
-        "lrelu": lambda x: jax.nn.leaky_relu(x, negative_slope=0.01),
-        "tanh": jnp.tanh,
-        "relu2": lambda x: jnp.square(jax.nn.relu(x)),
-    }
-    return table.get(name, gelu_exact)
-
-
-def _is_gated(name: str) -> bool:
-    return name.lower() in ("swiglu", "glu")
 
 
 def mlp_apply(x: jnp.ndarray, w_fc: jnp.ndarray, w_proj: jnp.ndarray,
@@ -167,13 +108,13 @@ def mlp_apply(x: jnp.ndarray, w_fc: jnp.ndarray, w_proj: jnp.ndarray,
         h = maybe_overlap_matmul(x, w_fc, names=("c_fc",))
     if h is None:
         h = x @ w_fc
-    if _is_gated(non_linearity):
+    if is_gated(non_linearity):
         x1, x2 = jnp.split(h, 2, axis=-1)
         gate = jax.nn.silu(x1) if non_linearity.lower() == "swiglu" \
             else jax.nn.sigmoid(x1)
         h = gate * x2
     else:
-        h = _activation(non_linearity)(h)
+        h = activation(non_linearity)(h)
     y = None
     if qnames is not None:
         from distributed_pytorch_tpu.ops.quant import maybe_quantized_matmul
@@ -202,7 +143,7 @@ class MLP(nn.Module):
     def __call__(self, x, *, deterministic: bool = True):
         cfg = self.config
         C, up = cfg.n_embd, self.up_dim or cfg.up_dim
-        fc_out = 2 * up if _is_gated(cfg.non_linearity) else up
+        fc_out = 2 * up if is_gated(cfg.non_linearity) else up
         w_fc = self.param("c_fc", _DENSE_INIT, (C, fc_out), self.param_dtype)
         w_proj = self.param("c_proj", _DENSE_INIT, (up, C), self.param_dtype)
         y = mlp_apply(x, w_fc.astype(x.dtype), w_proj.astype(x.dtype),
@@ -310,7 +251,7 @@ class MoE(nn.Module):
         up = cfg.up_dim
         n_exp, n_shared = cfg.n_exp, cfg.n_shared
         n_routed, k = cfg.n_routed, cfg.n_act_routed
-        fc_out = 2 * up if _is_gated(cfg.non_linearity) else up
+        fc_out = 2 * up if is_gated(cfg.non_linearity) else up
         dt = x.dtype
 
         experts_fc = self.param("experts_fc", _DENSE_INIT,
@@ -519,7 +460,7 @@ class RoutedExperts(nn.Module):
         Fs = cfg.shared_up_dim or F
         first, n_held = cfg.experts_held or (0, cfg.n_routed)
         nl = cfg.non_linearity.lower()
-        fan = 2 if _is_gated(nl) else 1
+        fan = 2 if is_gated(nl) else 1
         sigmoid = cfg.router == "sigmoid"
         gate = self.param("gate", _DENSE_INIT, (C, cfg.n_routed), pd)
         if sigmoid:

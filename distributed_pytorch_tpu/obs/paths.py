@@ -9,7 +9,7 @@ fallback under the kernel's name, so:
   serve CLI print beside each program they compile (they `reset()` right
   before lowering it).
 * `declined(what, gate, why)` is the error for a path that was asked for
-  BY NAME (`attn_impl='pallas'`, `loss_impl='pallas'`, `FLASH_DECODE=on`)
+  BY NAME (`attn_impl='pallas'`, `FLASH_DECODE=on`)
   and cannot run: it names the gate that declined.
 * `compile_and_describe(jitted, *args)` is what the train loop and the
   engine call on each program they serve with; its record is what

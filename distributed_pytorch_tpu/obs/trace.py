@@ -349,7 +349,7 @@ SCOPES = {
                  "for MLA (models/attention.py)",
     "lm_head": "the inference logits matmul (models/gpt.py)",
     "loss": "the cross-entropy branch, head matmul included, every "
-            "loss_impl (models/gpt.py)",
+            "loss_impl (ops/losses.py tied_head_loss)",
     "optimizer": "tx.update + apply_updates (train/step.py)",
     "grad_norm": "optax.global_norm of the gradients (train/step.py)",
     "sample": "each sample_fn call of the engine's programs",

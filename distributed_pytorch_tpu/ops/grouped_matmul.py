@@ -19,7 +19,7 @@ write, so the scatter-add back to (N, C) is the only HBM round trip on
 the return path.
 
 Kernels (all f32-accumulated; operands stay in the input dtype so the MXU
-runs at full rate; structure mirrors ops/fused_ce.py):
+runs at full rate):
 
 * forward  — grid (token_tiles, n_tiles): one (bm, K) x tile and the
   owning expert's (K, bn) weight tile are resident; output written once,
@@ -65,6 +65,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from distributed_pytorch_tpu import compat, config
+from distributed_pytorch_tpu.ops.activations import activation, is_gated
 from distributed_pytorch_tpu.parallel import context
 
 DEFAULT_BLOCK_M = config.knob("GMM_BLOCK_M")   # token rows
@@ -268,8 +269,7 @@ def _gmm_bwd(static, res, dy):
     ds = None
     if scales is not None:
         # gate cotangent needs the unscaled product; recompute it rather
-        # than storing a second (P, N) buffer from forward (same
-        # recompute-over-store trade as fused_ce's lse-based backward)
+        # than storing a second (P, N) buffer from forward
         y_us = _fwd_call(x_pad, w, None, tile_group, bm, interpret)
         ds = jnp.sum(dy.astype(jnp.float32) * y_us.astype(jnp.float32),
                      axis=-1, keepdims=True)
@@ -345,14 +345,13 @@ def _pack_rows(x_flat, flat_e, flat_t, flat_g, n_groups, bm):
 
 def _apply_activation(h: jnp.ndarray, non_linearity: str) -> jnp.ndarray:
     """The MLP nonlinearity on the packed hidden buffer (models/mlp.py
-    mlp_apply semantics; imported lazily to avoid an ops<->models cycle)."""
-    from distributed_pytorch_tpu.models.mlp import _activation, _is_gated
-    if _is_gated(non_linearity):
+    mlp_apply semantics)."""
+    if is_gated(non_linearity):
         x1, x2 = jnp.split(h, 2, axis=-1)
         gate = jax.nn.silu(x1) if non_linearity.lower() == "swiglu" \
             else jax.nn.sigmoid(x1)
         return gate * x2
-    return _activation(non_linearity)(h)
+    return activation(non_linearity)(h)
 
 
 def _local_grouped_dispatch(x_flat, topk_idx, topk_gates, experts_fc,
@@ -408,8 +407,7 @@ def _local_grouped_dispatch(x_flat, topk_idx, topk_gates, experts_fc,
 
 def grouped_usable(cfg, batch_size: int, dtype) -> bool:
     """Static gate for the grouped path. False -> callers fall back to the
-    'dense' combine (identical dropless semantics, E/k x the FLOPs) — the
-    same degrade-don't-crash contract as loss_impl='pallas' (gpt.py)."""
+    'dense' combine (identical dropless semantics, E/k x the FLOPs)."""
     if getattr(cfg, "pp_stages", 1) > 1:
         # the pipeline vmaps Blocks over the layer axis; neither shard_map
         # nor pallas_call composes with that on this jax

@@ -30,7 +30,11 @@ loop-invariant) is unchanged. Under a live 'seq' axis
 `sp_fused_cross_entropy` runs the same chunk scan per device over the
 LOCAL T shard inside shard_map, each over the psum of the valid counts,
 and psums the per-device sums — no seq-sharded full-logits
-materialization (gpt.py routes on `context.seq_axis_size()`).
+materialization.
+
+`tied_head_loss` is what the model calls: which of the three a program runs
+(`loss_impl`, the mesh's axes, the shapes) is decided there and nowhere
+else.
 """
 
 from __future__ import annotations
@@ -39,15 +43,18 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from distributed_pytorch_tpu import compat
 from distributed_pytorch_tpu.obs import paths
+from distributed_pytorch_tpu.ops.collective_matmul import maybe_overlap_matmul
+from distributed_pytorch_tpu.parallel import context
 
 
 def _default_logits(x: jnp.ndarray, embedding: jnp.ndarray) -> jnp.ndarray:
     """x (..., C) @ embedding^T (V, C) -> (..., V) fp32 — the plain GSPMD
-    lm-head matmul. Callers may override with `logits_fn` (gpt.py routes
-    the collective-matmul ring through it under OVERLAP=on)."""
+    lm-head matmul. Callers may override with `logits_fn` (`tied_head_loss`
+    routes the collective-matmul ring through it under OVERLAP=on)."""
     return jax.lax.dot_general(
         x, embedding, (((x.ndim - 1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -206,9 +213,7 @@ def sp_fused_cross_entropy(x: jnp.ndarray, embedding: jnp.ndarray,
 
     Callers gate on: live 'seq' axis, no vocab-parallel embedding (tp —
     the replicated in_spec would all-gather a 'model'-sharded embedding),
-    and B divisible by dp (gpt.py)."""
-    from distributed_pytorch_tpu.parallel import context
-
+    and B divisible by dp (`tied_head_loss`)."""
     mesh = context.get_mesh()
     assert mesh is not None and context.seq_axis_size() > 1
 
@@ -232,8 +237,6 @@ def sp_fused_cross_entropy(x: jnp.ndarray, embedding: jnp.ndarray,
             s = _block_nll_sum(x_l, emb, t_l, ignore_index, None) / denom
         return jax.lax.psum(s, ("data", "seq"))
 
-    from jax.sharding import PartitionSpec as P
-
     fn = compat.shard_map(
         local_body, mesh=mesh,
         in_specs=(P("data", "seq", None), P(None, None), P("data", "seq")),
@@ -253,7 +256,8 @@ def fused_cross_entropy(x: jnp.ndarray, embedding: jnp.ndarray,
     targets: (B, T) int with `ignore_index` masking. `chunk=0` picks a
     divisor of T automatically (or falls back to the unchunked oracle when
     chunking can't help). `logits_fn(x_chunk, embedding)` overrides the
-    per-chunk lm-head matmul (collective-matmul routing, gpt.py).
+    per-chunk lm-head matmul (collective-matmul routing,
+    `tied_head_loss`).
     """
     chunk = _resolve_chunk(x.shape[1], embedding.shape[0], chunk)
     if not chunk:
@@ -263,3 +267,43 @@ def fused_cross_entropy(x: jnp.ndarray, embedding: jnp.ndarray,
     denom = jnp.maximum((targets != ignore_index).sum(), 1)
     return _scan_mean_nll(x, embedding, targets, denom, ignore_index, chunk,
                           logits_fn)
+
+
+def _ring_or_plain_logits(x_c: jnp.ndarray, emb: jnp.ndarray) -> jnp.ndarray:
+    """lm-head gather as a collective matmul (the (V, C) embedding is the
+    largest single param ZeRO-3 shards): under OVERLAP=on the per-chunk
+    logits matmul rings the vocab shards; the dispatcher declines
+    everywhere else and the default plain matmul is bit-identical."""
+    y = maybe_overlap_matmul(x_c, emb, names=("tkn_emb", "embedding"),
+                             transpose_b=True, out_dtype=jnp.float32)
+    return y if y is not None else _default_logits(x_c, emb)
+
+
+def tied_head_loss(x: jnp.ndarray, embedding: jnp.ndarray,
+                   targets: jnp.ndarray, *, impl: str,
+                   chunk: int) -> jnp.ndarray:
+    """Mean CE of the weight-tied head over valid targets (ignore_index
+    -1, reference model.py:559-560, :689), fp32-accumulated: `impl`
+    (`LLMConfig.loss_impl`) 'fused' or 'unchunked', `chunk` the T-chunk of
+    'fused' (0 = auto). The census entry `loss` says what ran: the chunk
+    scan replaces the note below with the rule it ran, gradients in the
+    forward scan under a gradient and the plain scan otherwise.
+
+    Under a live 'seq' axis 'fused' chunks over the LOCAL T shard inside
+    shard_map (`sp_fused_cross_entropy`) instead of materializing
+    seq-sharded full logits; its gates: no vocab-parallel embedding, B
+    divisible by dp, T by sp. Where one declines, the full-logits oracle
+    runs."""
+    paths.note("loss", impl, f"loss_impl={impl}")
+    sp = context.seq_axis_size()
+    if impl == "fused" and sp > 1:
+        mesh = context.get_mesh()
+        tp, dp = mesh.shape.get("model", 1), mesh.shape.get("data", 1)
+        if tp == 1 and x.shape[0] % dp == 0 and x.shape[1] % sp == 0:
+            return sp_fused_cross_entropy(x, embedding, targets, chunk=chunk)
+        impl = "unchunked"
+    if impl == "fused":
+        return fused_cross_entropy(x, embedding, targets, chunk=chunk,
+                                   logits_fn=_ring_or_plain_logits)
+    return unchunked_cross_entropy(x, embedding, targets,
+                                   logits_fn=_ring_or_plain_logits)

@@ -72,8 +72,8 @@ DEFAULT_DIR = os.path.join("runs", "aot_store")
 #: never change the traced program and would break cross-process key
 #: stability.
 PROGRAM_KNOBS = (
-    "CE_BLOCK_N", "CE_BLOCK_V", "GMM_BLOCK_M", "GMM_BLOCK_N", "GMM_BLOCK_K",
-    "FLASH_DECODE_BLOCK", "FLASH_DECODE", "OVERLAP", "OVERLAP_RING",
+    "GMM_BLOCK_M", "GMM_BLOCK_N", "GMM_BLOCK_K", "FLASH_DECODE_BLOCK",
+    "FLASH_DECODE", "OVERLAP", "OVERLAP_RING",
     "QUANT_KV", "QUANT_W", "SPEC_DECODE", "SPEC_K", "KV_HOST_TIER",
     "KV_HOST_BLOCKS", "TRAIN_POISON_IT",
 )

@@ -18,8 +18,8 @@ closes the training half (ISSUE 10), reusing the round-14 primitives:
   allocation — the same disabled-mode bound obs/trace.py holds itself
   to.
 * `TrainMetrics` — step-phase histograms + counters + live gauges on
-  the serve/metrics.py machinery (same Histogram, same info-gauge
-  idiom), rendered as Prometheus text. Unlike ServeMetrics it takes a
+  the obs/prom.py machinery (the serving metrics' Histogram and
+  info-gauge idiom), rendered as Prometheus text. Unlike ServeMetrics it takes a
   lock: the train loop writes from the main thread while the telemetry
   HTTP thread renders.
 * `TelemetryServer` — an opt-in stdlib HTTP thread (`--metrics_port`)
@@ -48,8 +48,8 @@ from typing import Callable, Optional
 
 from distributed_pytorch_tpu.obs import flight as obs_flight
 from distributed_pytorch_tpu.obs.flight import FlightRecorder
-from distributed_pytorch_tpu.serve.metrics import (Histogram, _render_info,
-                                                   render_families)
+from distributed_pytorch_tpu.obs.prom import (Histogram, _render_info,
+                                              render_families)
 
 # Train steps span ~1 ms (tiny CPU smoke) to tens of seconds (1.5B with
 # remat); the serve grid covers the same decades.
@@ -58,7 +58,7 @@ STEP_SECONDS_BUCKETS = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
 
 
 class TrainMetrics:
-    """Prometheus registry for the training loop (serve/metrics.py
+    """Prometheus registry for the training loop (obs/prom.py
     Histogram + info-gauge machinery, plus a lock — the loop observes
     from the main thread while the TelemetryServer thread renders)."""
 

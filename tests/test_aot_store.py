@@ -104,12 +104,12 @@ def test_any_skew_changes_the_key(tmp_path):
 
 
 def test_knob_skew_changes_the_key(tmp_path, monkeypatch):
-    """PROGRAM_KNOBS are key material: flipping one (here a fused-CE tile
-    size that changes the compiled kernel) re-keys every program."""
+    """PROGRAM_KNOBS are key material: flipping one (here a grouped-matmul
+    tile that changes the compiled kernel) re-keys every program."""
     s = AOTStore(str(tmp_path))
     _, avals = _trivial()
     base = s.key("step", avals, {"kind": "engine"})
-    monkeypatch.setenv("CE_BLOCK_N", "128")  # default is 512
+    monkeypatch.setenv("GMM_BLOCK_M", "256")  # default is 128
     assert s.key("step", avals, {"kind": "engine"}) != base
 
 

@@ -7,8 +7,8 @@ blocks — so what Mosaic refuses costs a test failure instead of a chip
 call. Interpret mode (every other kernel test) runs none of this: it never
 sees the 128-lane tile padding, the scoped-VMEM limit, or a relayout the
 hardware has no instruction for. Before this file the flash backward, the
-CE backward, the int8 contiguous decode and the 512-row chunk prefill all
-passed their interpret tests and were refused by the compiler.
+int8 contiguous decode and the 512-row chunk prefill all passed their
+interpret tests and were refused by the compiler.
 
 A compile that passes is not a chip run; numerics and times come from
 `chip_smoke.py`.
@@ -32,7 +32,6 @@ from distributed_pytorch_tpu.obs import paths  # noqa: E402
 from distributed_pytorch_tpu.ops import block_pool as bp  # noqa: E402
 from distributed_pytorch_tpu.ops import flash_attention as fa  # noqa: E402
 from distributed_pytorch_tpu.ops import flash_decode as fd  # noqa: E402
-from distributed_pytorch_tpu.ops import fused_ce  # noqa: E402
 from distributed_pytorch_tpu.ops import grouped_matmul as gm  # noqa: E402
 
 BF16, I8, F32, I32 = jnp.bfloat16, jnp.int8, jnp.float32, jnp.int32
@@ -69,17 +68,6 @@ def _flash(B, T, grad, nh=NH, hs=HS):
     def bwd(q, k, v):
         return jax.grad(lambda *a: fwd(*a).astype(F32).sum(),
                         argnums=(0, 1, 2))(q, k, v)
-    return (bwd if grad else fwd), shapes
-
-
-def _ce(grad):
-    shapes = [((16, 1024, C), BF16), ((V, C), BF16), ((16, 1024), I32)]
-
-    def fwd(x, e, t):
-        return fused_ce.pallas_cross_entropy(x, e, t)
-
-    def bwd(x, e, t):
-        return jax.grad(lambda a, b: fwd(a, b, t), argnums=(0, 1))(x, e)
     return (bwd if grad else fwd), shapes
 
 
@@ -157,8 +145,6 @@ CASES = {
     "flash_bwd_2x1024_8x128": (lambda: _flash(2, 1024, True, 8, 128),
                                ["flash_fwd", "flash_bwd_dq",
                                 "flash_bwd_dkv"]),
-    "ce_fwd": (lambda: _ce(False), ["ce_fwd"]),
-    "ce_bwd": (lambda: _ce(True), ["ce_fwd", "ce_bwd_dx", "ce_bwd_dw"]),
     "flash_decode_bf16": (lambda: _decode(8, False), ["flash_decode"]),
     "flash_decode_int8_32slots": (lambda: _decode(32, True),
                                   ["flash_decode_q8"]),
@@ -355,6 +341,9 @@ def test_the_up_projection_writes_the_pair_and_nothing_float32(
 # the head's cross-entropy under a gradient: dx and dW in the forward scan
 # ---------------------------------------------------------------------------
 
+_LOSS_SHAPES = [((16, 1024, C), BF16), ((V, C), BF16), ((16, 1024), I32)]
+
+
 def test_the_loss_builds_each_logits_block_once(v5e):
     """Value-and-grad of the chunked loss at the train cell's shapes (16 x
     1024 x 768 bf16, a bf16 embedding of 50,304 rows, 8 chunks of 128):
@@ -369,9 +358,7 @@ def test_the_loss_builds_each_logits_block_once(v5e):
             lambda a, e: fused_cross_entropy(a, e, t), argnums=(0, 1))(
             x, emb)
 
-    compiled = _compile(value_and_grad, [((16, 1024, C), BF16),
-                                         ((V, C), BF16),
-                                         ((16, 1024), I32)], v5e)
+    compiled = _compile(value_and_grad, _LOSS_SHAPES, v5e)
     text = compiled.as_text()
     assert len(re.findall(r" while\(", text)) == 1
     # the loss has no other matmul: every convolution is the head's
@@ -380,6 +367,45 @@ def test_the_loss_builds_each_logits_block_once(v5e):
     assert all("/while/body/" in ln for ln in head), head
     assert f"f32[16,1024,{V}]" not in text, "the whole logits in the program"
     assert compiled.memory_analysis().temp_size_in_bytes <= 496_329_216
+
+
+def test_the_models_loss_call_runs_the_rule_in_pr_47s_temp(v5e):
+    """What `LLM.__call__` calls (`tied_head_loss`, 'fused', chunk 0 =
+    auto) under a gradient at the train cell's shapes: the census names the
+    rule, and the program's temporaries stay where PR 47 left them
+    (463,027,712 B: `dx`, `dW` and one float32 block)."""
+    from distributed_pytorch_tpu.ops.losses import tied_head_loss
+
+    def value_and_grad(x, emb, t):
+        return jax.value_and_grad(
+            lambda a, e: tied_head_loss(a, e, t, impl="fused", chunk=0),
+            argnums=(0, 1))(x, emb)
+
+    paths.reset()
+    compiled = _compile(value_and_grad, _LOSS_SHAPES, v5e)
+    assert paths.choices()["loss"] == (
+        "fused, gradients in the forward scan (8 chunks of 128 tokens)")
+    paths.reset()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert abs(temp / 463_027_712 - 1) <= 0.05, temp
+
+
+def test_the_undifferentiated_loss_is_one_matmul_a_chunk(v5e):
+    """Evaluation and the benchmark's check take no gradient: one loop, one
+    head matmul a chunk, nothing kept."""
+    from distributed_pytorch_tpu.ops.losses import tied_head_loss
+
+    paths.reset()
+    text = _compile(
+        lambda x, emb, t: tied_head_loss(x, emb, t, impl="fused", chunk=0),
+        _LOSS_SHAPES, v5e).as_text()
+    assert paths.choices()["loss"] == (
+        "fused, plain scan (8 chunks of 128 tokens)")
+    paths.reset()
+    assert len(re.findall(r" while\(", text)) == 1
+    head = [ln for ln in text.splitlines() if " convolution(" in ln]
+    assert len(head) == 1 and "/while/body/" in head[0], head
+    assert f"f32[16,1024,{V}]" not in text, "the whole logits in the program"
 
 
 # ---------------------------------------------------------------------------

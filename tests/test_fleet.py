@@ -28,10 +28,9 @@ import urllib.request
 import pytest
 
 from distributed_pytorch_tpu.obs.flight import FlightRecorder
+from distributed_pytorch_tpu.obs.prom import LATENCY_BUCKETS, Histogram
 from distributed_pytorch_tpu.obs.slo import SLOTarget, SLOTracker
-from distributed_pytorch_tpu.serve.metrics import (Histogram,
-                                                   LATENCY_BUCKETS,
-                                                   ServeMetrics,
+from distributed_pytorch_tpu.serve.metrics import (ServeMetrics,
                                                    merge_histograms,
                                                    render_fleet,
                                                    render_hist_snap)

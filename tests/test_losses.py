@@ -25,9 +25,11 @@ def _data(B=2, T=32, C=16, V=64, seed=0):
     return x, emb, tgt
 
 
-@pytest.mark.parametrize("chunk", [4, 8, 16])
-def test_fused_matches_unchunked(chunk):
-    x, emb, tgt = _data()
+@pytest.mark.parametrize("chunk, V", [(4, 64), (8, 64), (16, 64),
+                                      # off the lane grid, and a chunk of 2
+                                      (2, 64), (2, 96), (2, 100)])
+def test_fused_matches_unchunked(chunk, V):
+    x, emb, tgt = _data(V=V)
     ref = unchunked_cross_entropy(x, emb, tgt)
     got = fused_cross_entropy(x, emb, tgt, chunk=chunk)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-6)
@@ -226,6 +228,53 @@ def test_rule_inside_a_scan_over_microbatches():
                                    rtol=1e-5, atol=1e-6)
 
 
+def test_rule_at_the_real_padded_vocabulary():
+    """GPT-2's 50,304 rows (393 x 128, no multiple of a power of two past
+    128), tiny N and C: value and dW of the rule against the full logits."""
+    x, emb, tgt = _data(B=2, T=32, C=128, V=50304)
+    ref, g_ref = jax.value_and_grad(
+        lambda e: unchunked_cross_entropy(x, e, tgt))(emb)
+    got, g_got = jax.value_and_grad(
+        lambda e: fused_cross_entropy(x, e, tgt, chunk=8))(emb)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(g_got), np.asarray(g_ref),
+                               rtol=2e-5, atol=2e-6)
+
+
+def test_rule_under_a_data_axis_shard_map():
+    """Each of 8 devices runs the rule over its own rows inside a
+    `shard_map` over 'data' (the embedding replicated, its cotangent summed
+    by the map's transpose): value and both gradients of the unsharded
+    call."""
+    from jax.sharding import PartitionSpec as P
+
+    from distributed_pytorch_tpu import compat
+    from distributed_pytorch_tpu.parallel.mesh import mesh_for
+
+    x, emb, tgt = _data(B=8, T=32)      # nothing masked: equal counts
+
+    def loss(a, e, t):
+        return fused_cross_entropy(a, e, t, chunk=8)
+
+    sharded = compat.shard_map(
+        lambda a, e, t: jax.lax.pmean(loss(a, e, t), "data"),
+        mesh=mesh_for("dp"), in_specs=(P("data"), P(), P("data")),
+        out_specs=P())
+    ref, g_ref = jax.value_and_grad(loss, argnums=(0, 1))(x, emb, tgt)
+    got, g_got = jax.value_and_grad(sharded, argnums=(0, 1))(x, emb, tgt)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-6)
+    for r, g in zip(g_ref, g_got):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=2e-5, atol=2e-6)
+
+
+def test_the_kernels_name_is_refused_and_fused_is_named():
+    """A stored config that still says 'pallas' fails at construction: it
+    never runs 'fused' under the kernel's name."""
+    with pytest.raises(ValueError, match="PR 48.*'fused'"):
+        LLMConfig(loss_impl="pallas")
+
+
 _V = 8192
 
 
@@ -287,91 +336,7 @@ def test_the_census_names_the_rule_that_ran(differentiated):
     paths.reset()
 
 
-# ---------------------------------------------------------------------------
-# Pallas streaming CE (ops/fused_ce.py) vs the oracle, interpret mode on CPU
-# ---------------------------------------------------------------------------
-
-from distributed_pytorch_tpu.ops.fused_ce import (pallas_ce_usable,
-                                                  pallas_cross_entropy)
-
-
-def _pdata(B=2, T=32, C=128, V=100, seed=0, dtype=jnp.float32):
-    kx, ke, kt = jax.random.split(jax.random.PRNGKey(seed), 3)
-    x = jax.random.normal(kx, (B, T, C), dtype)
-    emb = (jax.random.normal(ke, (V, C), jnp.float32) * 0.1).astype(dtype)
-    tgt = jax.random.randint(kt, (B, T), 0, V)
-    return x, emb, tgt
-
-
-@pytest.mark.parametrize("V", [100, 64, 96])   # 100: vocab-padding path
-def test_pallas_ce_matches_unchunked(V):
-    x, emb, tgt = _pdata(V=V)
-    ref = unchunked_cross_entropy(x, emb, tgt)
-    got = pallas_cross_entropy(x, emb, tgt, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-6)
-
-
-def test_pallas_ce_gradients_match():
-    x, emb, tgt = _pdata()
-    g_ref = jax.grad(lambda a, e: unchunked_cross_entropy(a, e, tgt),
-                     argnums=(0, 1))(x, emb)
-    g_got = jax.grad(
-        lambda a, e: pallas_cross_entropy(a, e, tgt, interpret=True),
-        argnums=(0, 1))(x, emb)
-    for r, g in zip(g_ref, g_got):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
-                                   rtol=2e-5, atol=2e-6)
-
-
-def test_pallas_ce_ignore_index():
-    x, emb, tgt = _pdata()
-    tgt = tgt.at[:, -5:].set(-1)
-    ref = unchunked_cross_entropy(x, emb, tgt)
-    got = pallas_cross_entropy(x, emb, tgt, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-6)
-    # ignored rows must contribute zero gradient
-    g = jax.grad(
-        lambda a: pallas_cross_entropy(a, emb, tgt, interpret=True))(x)
-    np.testing.assert_allclose(np.asarray(g[:, -5:]), 0.0, atol=1e-7)
-
-
-def test_pallas_ce_bf16():
-    x, emb, tgt = _pdata(dtype=jnp.bfloat16)
-    ref = unchunked_cross_entropy(x, emb, tgt)
-    got = pallas_cross_entropy(x, emb, tgt, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=2e-2, atol=2e-2)
-
-
-def test_pallas_ce_usable_gate():
-    assert pallas_ce_usable(16384, 768, jnp.bfloat16)
-    assert not pallas_ce_usable(16384, 120, jnp.bfloat16)   # C not lane-mult
-    assert not pallas_ce_usable(16384, 768, jnp.float16)
-
-
-def test_pallas_ce_dp_shard_map_parity():
-    """The shard_map('data') wrapper path: same value + grads as the
-    oracle when the ambient mesh shards the batch over 8 devices."""
-    import jax
-    from distributed_pytorch_tpu.parallel import context
-    from distributed_pytorch_tpu.parallel.mesh import mesh_for
-
-    x, emb, tgt = _pdata(B=8, T=16)
-    mesh = mesh_for("dp")
-    ref, g_ref = jax.value_and_grad(
-        lambda a, e: unchunked_cross_entropy(a, e, tgt), argnums=(0, 1))(
-        x, emb)
-    with context.use_mesh(mesh):
-        got, g_got = jax.value_and_grad(
-            lambda a, e: pallas_cross_entropy(a, e, tgt, interpret=True),
-            argnums=(0, 1))(x, emb)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-6)
-    for r, g in zip(g_ref, g_got):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
-                                   rtol=2e-5, atol=2e-6)
-
-
-def _sp_fused_ce_against_the_oracle(chunk):
+def _sp_fused_loss_against_the_oracle(chunk):
     from distributed_pytorch_tpu.ops.losses import sp_fused_cross_entropy
     from distributed_pytorch_tpu.parallel import context
     from distributed_pytorch_tpu.parallel.mesh import mesh_for
@@ -393,15 +358,15 @@ def _sp_fused_ce_against_the_oracle(chunk):
 
 
 @pytest.mark.parametrize("chunk", [0, 8])
-def test_sp_fused_ce_matches_oracle(chunk):
+def test_sp_fused_loss_matches_oracle(chunk):
     """Sequence-parallel chunked CE (round-5: replaces the unchunked
     fallback under a live 'seq' axis): value and grads must match the
     full-logits oracle on a data=4 x seq=2 mesh, with and without an
     explicit chunk size, including masked targets."""
-    _sp_fused_ce_against_the_oracle(chunk)
+    _sp_fused_loss_against_the_oracle(chunk)
 
 
-def test_sp_fused_ce_under_a_checked_shard_map(monkeypatch):
+def test_sp_fused_loss_under_a_checked_shard_map(monkeypatch):
     """The same under `check_vma=True` (the repo's shard_map leaves it off):
     the dW carry, the pulled-back scalar and the embedding are typed to
     vary as the shard's rows do, so the rule's types close."""
@@ -411,7 +376,7 @@ def test_sp_fused_ce_under_a_checked_shard_map(monkeypatch):
 
     monkeypatch.setattr(compat, "shard_map",
                         functools.partial(compat.shard_map, check=True))
-    _sp_fused_ce_against_the_oracle(8)
+    _sp_fused_loss_against_the_oracle(8)
 
 
 def test_sp_train_step_uses_chunked_loss():
@@ -445,18 +410,3 @@ def test_sp_train_step_uses_chunked_loss():
         _, m_sp = step2(state2, x, y)
     np.testing.assert_allclose(float(m_sp["loss"]), float(m_ref["loss"]),
                                rtol=2e-5)
-
-
-def test_pallas_ce_real_vocab_padding():
-    """GPT-2 vocab 50304 pads to 51200 (25 x 2048 tiles): the production
-    padding path with the last tile 1152-valid, tiny N/C to keep
-    interpret mode fast."""
-    x, emb, tgt = _pdata(B=2, T=32, C=128, V=50304)
-    ref = unchunked_cross_entropy(x, emb, tgt)
-    got = pallas_cross_entropy(x, emb, tgt, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-6)
-    g_ref = jax.grad(lambda e: unchunked_cross_entropy(x, e, tgt))(emb)
-    g_got = jax.grad(
-        lambda e: pallas_cross_entropy(x, e, tgt, interpret=True))(emb)
-    np.testing.assert_allclose(np.asarray(g_got), np.asarray(g_ref),
-                               rtol=2e-5, atol=2e-6)

@@ -1,4 +1,4 @@
-"""The exact gelu's differentiation rule (models/mlp.py `gelu_exact`):
+"""The exact gelu's differentiation rule (ops/activations.py `gelu_exact`):
 forward evaluates the `erfc` expansion once and keeps (x, erfc) for
 backward; the value is `jax.nn.gelu(x, approximate=False)` itself."""
 
@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from distributed_pytorch_tpu.models import mlp
+from distributed_pytorch_tpu.ops import activations
 from distributed_pytorch_tpu.ops.grouped_matmul import _apply_activation
 
 F32, BF16 = jnp.float32, jnp.bfloat16
@@ -57,8 +58,8 @@ def test_forward_is_jax_nn_gelu_bitwise(name, dtype, jit):
     """`"gelu"` and the table's default are one function, and its value,
     differentiated or not, is the plain expression's, bit for bit: the
     trainer's forward and the engine's are one function of h."""
-    act = mlp._activation(name)
-    assert act is mlp.gelu_exact
+    act = activations.activation(name)
+    assert act is activations.gelu_exact
     # a grid over both branches of erfc and its tails, and random draws
     x = jnp.concatenate([
         jnp.linspace(-12.0, 12.0, 4097),
@@ -102,7 +103,7 @@ def test_ffn_gradients_bfloat16_no_further_than_the_plain_expression(
 @pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
 def test_derivative_is_gelu_prime(dtype):
     x = jnp.linspace(-9.0, 9.0, 2049).astype(dtype)
-    got = jax.vmap(jax.grad(mlp.gelu_exact))(x)
+    got = jax.vmap(jax.grad(activations.gelu_exact))(x)
     xf = np.asarray(x, np.float64)
     want = np.array([0.5 * erfc(-v / sqrt(2)) + v * exp(-v * v / 2)
                      / sqrt(2 * pi) for v in xf])
@@ -155,7 +156,7 @@ def test_second_derivative_is_right():
     """Backward builds gelu' from the saved pair with ordinary ops, so a
     second derivative goes through them: gelu''(x) = (2 - x^2) pdf(x)."""
     x = jnp.linspace(-6.0, 6.0, 513)
-    got = jax.vmap(jax.grad(jax.grad(mlp.gelu_exact)))(x)
+    got = jax.vmap(jax.grad(jax.grad(activations.gelu_exact)))(x)
     want = jax.vmap(jax.grad(jax.grad(plain_gelu)))(x)
     xf = np.asarray(x, np.float64)
     closed = (2.0 - xf ** 2) * np.exp(-xf ** 2 / 2) / np.sqrt(2 * np.pi)
@@ -166,7 +167,7 @@ def test_second_derivative_is_right():
 def test_the_expert_layers_entry_reaches_the_rule():
     x = jax.random.normal(jax.random.PRNGKey(0), (8, 128), BF16)
     got = jax.grad(lambda v: _apply_activation(v, "gelu").astype(F32).sum())(x)
-    want = jax.grad(lambda v: mlp.gelu_exact(v).astype(F32).sum())(x)
+    want = jax.grad(lambda v: activations.gelu_exact(v).astype(F32).sum())(x)
     np.testing.assert_array_equal(np.asarray(got, np.float32),
                                   np.asarray(want, np.float32))
     text = jax.jit(jax.grad(

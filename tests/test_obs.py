@@ -468,23 +468,6 @@ def test_attn_impl_pallas_declined_is_an_error_naming_the_gate():
     assert out.shape == q.shape
 
 
-def test_loss_impl_pallas_on_a_cpu_is_an_error_not_fused():
-    import jax
-    import jax.numpy as jnp
-    import pytest
-    from distributed_pytorch_tpu.config import LLMConfig
-    from distributed_pytorch_tpu.models.gpt import LLM
-    from distributed_pytorch_tpu.obs import paths
-    cfg = LLMConfig(vocab_size=64, block_size=16, n_embd=128, n_head=4,
-                    n_kv_heads=4, attn="mha", n_layer=1, up_dim=32,
-                    loss_impl="pallas")
-    x = jnp.zeros((2, 16), jnp.int32)
-    with pytest.raises(paths.PathDeclined,
-                       match="pallas_ce_usable declined: backend is cpu"):
-        jax.eval_shape(lambda r: LLM(cfg).init({"params": r, "dropout": r},
-                                               x, x), jax.random.PRNGKey(0))
-
-
 def test_flash_decode_on_declined_is_an_error_on_a_tpu(monkeypatch):
     """FLASH_DECODE=on that its gate declines: an error on a TPU backend;
     off-TPU ('on' = interpret mode for the parity tests) the reference path

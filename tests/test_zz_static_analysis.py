@@ -8,6 +8,7 @@ sorting it last keeps tier-1's wall-clock budget spent on the
 compile-heavy kernel/recipe parity suites first.
 """
 
+import ast
 import importlib.util
 import json
 import pathlib
@@ -44,7 +45,7 @@ _spec.loader.exec_module(lint)
 # ---------------------------------------------------------------------------
 
 def test_knob_defaults_read_without_env():
-    assert config.knob("CE_BLOCK_N") == 512
+    assert config.knob("GMM_BLOCK_N") == 512
     assert config.knob("TRACE_GUARD") == "warn"
     assert config.knob("FLASH_DECODE") == "auto"
 
@@ -52,15 +53,15 @@ def test_knob_defaults_read_without_env():
 def test_knob_env_override_is_live(monkeypatch):
     """Knob.read consults os.environ per call, so monkeypatch.setenv works
     mid-process — the property the tests depend on."""
-    monkeypatch.setenv("CE_BLOCK_N", "128")
-    assert config.knob("CE_BLOCK_N") == 128
-    monkeypatch.delenv("CE_BLOCK_N")
-    assert config.knob("CE_BLOCK_N") == 512
+    monkeypatch.setenv("GMM_BLOCK_N", "128")
+    assert config.knob("GMM_BLOCK_N") == 128
+    monkeypatch.delenv("GMM_BLOCK_N")
+    assert config.knob("GMM_BLOCK_N") == 512
 
 
 def test_knob_unregistered_name_fails_loudly():
     with pytest.raises(KeyError):
-        config.knob("CE_BLOK_N")  # typo'd name must not silently default
+        config.knob("GMM_BLOK_N")  # typo'd name must not silently default
 
 
 def test_knob_onoff_validation(monkeypatch):
@@ -72,12 +73,12 @@ def test_knob_onoff_validation(monkeypatch):
 
 
 def test_knobs_table_marks_overrides(monkeypatch):
-    monkeypatch.setenv("CE_BLOCK_V", "1024")
+    monkeypatch.setenv("GMM_BLOCK_M", "1024")
     table = config.knobs_table()
     lines = {ln.split()[0]: ln for ln in table.splitlines()[1:]}
     assert set(lines) == set(config.ENV_KNOBS)
-    assert "1024*" in lines["CE_BLOCK_V"]         # override marker
-    assert "*" not in lines["CE_BLOCK_N"].split()[2]
+    assert "1024*" in lines["GMM_BLOCK_M"]         # override marker
+    assert "*" not in lines["GMM_BLOCK_N"].split()[2]
 
 
 def test_register_knob_round_trip(monkeypatch):
@@ -771,3 +772,62 @@ def test_golden_covers_shardcheck_matrix_plus_engine_cells():
     # 1 offload + 4 engine cells
     assert len(keys) == 6 * (9 * 3 + 1) + 2 + 1 + 4
     assert golden["errors"] == 0 and golden["ok"]
+
+
+# ---------------------------------------------------------------------------
+# the package graph: every import inside the program points down
+# ---------------------------------------------------------------------------
+
+#: lowest first; packages of one tier are not ordered among themselves
+LAYERS = (("obs", "data"), ("parallel",), ("ops",), ("models",),
+          ("train", "engine"), ("serve",))
+_TIER = {pkg: i for i, tier in enumerate(LAYERS) for pkg in tier}
+#: the tools that audit or store the WHOLE program from below it: they
+#: build the model, the train step and the engine to read their programs.
+#: By file name; a file that stops needing its place here must leave it.
+UPWARD_TOOLS = {"parallel/shardcheck.py", "parallel/commscheck.py",
+                "parallel/aot_store.py"}
+
+
+def _imported_packages(node, pkg):
+    """Sub-packages of distributed_pytorch_tpu an import statement names,
+    lazy imports and relative ones (resolved from `pkg`) included."""
+    top = "distributed_pytorch_tpu"
+    if isinstance(node, ast.Import):
+        names = [a.name for a in node.names]
+    elif isinstance(node, ast.ImportFrom):
+        base = node.module or ""
+        if node.level:      # a module of `pkg`: level 1 is pkg, 2 the root
+            prefix = [top, pkg][:3 - node.level]
+            base = ".".join(prefix + ([base] if base else []))
+        # `from distributed_pytorch_tpu import ops` names one too
+        names = [base] + [f"{base}.{a.name}" for a in node.names]
+    else:
+        return set()
+    parts = (n.split(".") for n in names)
+    return {p[1] for p in parts if len(p) > 1 and p[0] == top} & set(_TIER)
+
+
+def _upward_imports(pkg):
+    """{file: [what points up]} over every module of the package."""
+    root = REPO / "distributed_pytorch_tpu"
+    up = {}
+    for path in sorted((root / pkg).rglob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text())):
+            for target in sorted(_imported_packages(node, pkg)):
+                if _TIER[target] > _TIER[pkg]:
+                    up.setdefault(str(path.relative_to(root)), []).append(
+                        f"line {node.lineno} imports {target}")
+    return up
+
+
+@pytest.mark.parametrize("pkg", list(_TIER))
+def test_no_import_points_up_the_layers(pkg):
+    up = _upward_imports(pkg)
+    offenders = {f: v for f, v in up.items() if f not in UPWARD_TOOLS}
+    assert not offenders, (
+        f"{pkg} sits below what it imports (order {LAYERS}): move the "
+        f"shared piece down, do not add to UPWARD_TOOLS: {offenders}")
+    stale = {f for f in UPWARD_TOOLS if f.startswith(pkg + "/")} - set(up)
+    assert not stale, f"no upward import left, take out of UPWARD_TOOLS: " \
+                      f"{stale}"

@@ -437,7 +437,9 @@ class LLMConfig:
     # 'C' a gated short-convolution mixer (models/shortconv.py), 'E' routed
     # experts of which this chip holds a share (models/mlp.py
     # RoutedExperts; `router` says how), 'F' a dense FFN of its own width
-    # `dense_up_dim` (models/mlp.py MLP), '*' attention (GQA). Empty = the
+    # `dense_up_dim` (models/mlp.py MLP), '*' attention (GQA), 'W'
+    # attention over a window of the last `window` positions (GQA at
+    # `window_heads` query heads; ops/window_attention.py). Empty = the
     # attention + FFN block above for every layer. `n_layer` is its
     # length. A patterned model has RMSNorms, no FFN biases, and its
     # parameters are created in `LLM.param_dtype`.
@@ -457,6 +459,28 @@ class LLMConfig:
     qk_norm: bool = False
     rope_theta: float = 10000.0
     rope_pairing: str = "adjacent"
+    # the '*' layers' angles beyond the plain ones (ops/rope.py):
+    # `rotary_frac` of a head's lanes, the first ones, are rotated and
+    # the rest pass; a `rope_factor` over 1 is YaRN's: every frequency is
+    # blended between itself and itself over the factor by where its
+    # wavelength lies against `rope_original_len` (`ops/rope.py`), and
+    # cos and sin are multiplied by `rope_attn_factor`. `attn_gate`: a
+    # gate a query head, sigmoid of a linear map of the block's normed
+    # input (leaf `c_gate`), on the head's output before `c_proj`, in
+    # '*' and 'W' layers alike.
+    rotary_frac: float = 1.0
+    rope_factor: float = 1.0
+    rope_original_len: int = 0
+    rope_attn_factor: float = 1.0
+    attn_gate: bool = False
+    # 'W' layers: a query at position i sees keys j with 0 <= i - j <
+    # `window`, its own included; `window_heads` query heads over the
+    # same `n_kv_heads`; plain RoPE over all lanes at `window_rope_theta`.
+    # What such a layer keeps a slot is a ring of the window's rows, not
+    # blocks of the pool (models/gpt.py init_paged_cache).
+    window: int = 0
+    window_heads: int = 0
+    window_rope_theta: float = 10000.0
     # 'E' layers: `n_exp` - `n_shared` is the ROUTER's width and `n_act` -
     # `n_shared` its top-k, as above; `experts_held` = (first id, count) is
     # the slice of routed experts this chip holds (empty: all), what the
@@ -495,8 +519,11 @@ class LLMConfig:
     def __post_init__(self):
         object.__setattr__(self, "experts_held", tuple(self.experts_held))
         assert self.rope_pairing in ("adjacent", "half"), self.rope_pairing
+        assert self.rope_factor >= 1.0, "rope_factor is at least 1"
+        assert self.rope_factor == 1.0 or self.rope_original_len > 0, \
+            "yarn (a rope_factor over 1) needs rope_original_len"
         if self.layer_pattern:
-            assert set(self.layer_pattern) <= set("MCEF*"), \
+            assert set(self.layer_pattern) <= set("MCEF*W"), \
                 self.layer_pattern
             assert len(self.layer_pattern) == self.n_layer, (
                 f"layer_pattern has {len(self.layer_pattern)} layers, "
@@ -510,6 +537,22 @@ class LLMConfig:
                 assert self.conv_len >= 2
             if "F" in self.layer_pattern:
                 assert self.dense_up_dim > 0
+            if "W" in self.layer_pattern:
+                assert self.window > 0 and self.window_heads > 0, \
+                    "a 'W' layer needs `window` and `window_heads`"
+                assert self.window_heads % self.n_kv_heads == 0, \
+                    "window_heads must be divisible by n_kv_heads"
+                assert self.attn in ("mha", "mqa", "gqa") \
+                    and self.pos_emb == "rope", \
+                    "a 'W' layer is GQA with rotary positions"
+            else:
+                assert not self.window and not self.window_heads, \
+                    "`window` / `window_heads` without a 'W' layer"
+            frac = self.rotary_frac * self.head_size
+            assert 0 < self.rotary_frac <= 1.0 and frac == int(frac) \
+                and int(frac) % 2 == 0, \
+                f"rotary_frac {self.rotary_frac} of a head of " \
+                f"{self.head_size} is no even number of lanes"
             if "E" in self.layer_pattern:
                 assert self.n_act > self.n_shared and \
                     self.n_exp > self.n_shared
@@ -522,6 +565,11 @@ class LLMConfig:
                     self.logits_div) == (1.0, 1.0, 0.0, 1.0), \
                 "the multipliers are a patterned model's"
             assert not self.qk_norm, "QK-norm is a patterned model's"
+            assert not (self.window or self.window_heads or self.attn_gate
+                        or self.rope_factor != 1.0
+                        or self.rotary_frac != 1.0
+                        or self.rope_attn_factor != 1.0), \
+                "windows, gates and scaled RoPE are a patterned model's"
         # Cross-field normalization, mirroring reference
         # single-gpu/train.py:198-206 (mha -> n_kv_heads=n_head, mqa -> 1,
         # mla requires latent dims; rope-mla additionally rope_head_dim).
@@ -594,6 +642,16 @@ class LLMConfig:
         convolution mixer's tail alone (what prefix reuse, the host tier
         and speculative roll-back cannot snapshot yet)."""
         return any(kind in "MC" for kind in self.layer_pattern)
+
+    @property
+    def slot_state(self) -> str:
+        """What the layers keep a SLOT that is no block of the paged
+        cache, in words ("" = nothing): the engine says it where prefix
+        reuse, the host tier and speculation stand down for it."""
+        kinds = [name for name, held in (
+            ("recurrent layers", self.recurrent),
+            ("window layers", "W" in self.layer_pattern)) if held]
+        return " and ".join(kinds)
 
     @property
     def n_routed(self) -> int:

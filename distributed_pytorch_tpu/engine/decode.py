@@ -10,7 +10,10 @@ actual sequence length, and a full prefill per request even when
 thousands of requests share a system prompt:
 
 * **Paged pool + block tables**: ONE (n_blocks, block_size, ...) pool set
-  per layer lives for the engine's lifetime — float k/v pools as
+  per layer that keeps a sequence's whole history lives for the engine's
+  lifetime (a patterned model's window layers keep a ring a slot instead,
+  no block of any pool and no column of the table: models/gpt.py
+  `init_paged_cache`, ops/window_attention.py) — float k/v pools as
   (n_blocks, block_size, L), the kv heads merged into L = n_kv * head_size
   lanes rounded up to 128, the one shape the donated argument, the
   in-place row write and the kernels all hold in the same dense layout,
@@ -554,6 +557,13 @@ class _Program:
     # is a sequence and holds every one of its live tiles
     # (`DecodeEngine.decode_tiles_per_grid_step`)
     live_tiles: int = 0
+    # key rows ONE layer's attention calls of this program read, by the
+    # planned lengths: {"decode" | "chunk": (of a whole history, of the
+    # window)}; a model with window layers alone
+    kv_rows: Optional[dict] = None
+    # (query, key) pairs ONE layer's chunk call lets through: (under the
+    # causal mask, under the window's too) (`_causal_pairs`)
+    chunk_pairs: tuple = (0, 0)
     # the fused chunk: (slot, seq_id, take, prefill rows after it)
     chunk: Optional[tuple] = None
     # a speculative step: (draft, draft_len, device accept lengths)
@@ -569,6 +579,14 @@ class _Program:
     # the device arrays its expert layers' routing counts arrive in
     state_reset: bool = False
     expert_stats: Any = None
+
+
+def _causal_pairs(off: int, take: int, window: int = 0) -> int:
+    """(query, key) pairs of a chunk of `take` rows behind `off` cached
+    ones: the query at position off + t sees off + t + 1 keys, its own
+    included, at most `window` of them where there is one."""
+    whole = min(take, max(window - off, 0)) if window else take
+    return whole * off + whole * (whole + 1) // 2 + (take - whole) * window
 
 
 class _WouldPreempt(Exception):
@@ -664,8 +682,8 @@ class DecodeEngine:
         self.spec_decode = (quant.resolve_gate(knob("SPEC_DECODE"),
                                                bool(spec_decode))
                             and self.spec_k > 0 and temperature == 0.0)
-        # a model with recurrent layers (`cfg.recurrent`) keeps per-slot
-        # state that is no block of the pool. Resident blocks are then
+        # a model with recurrent or window layers (`cfg.slot_state`) keeps
+        # per-slot state that is no block of the pool. Resident blocks are then
         # NOT a prefix's state, and a rejected draft cannot be rolled
         # back: until state snapshots exist, prefix reuse, the host tier
         # and speculation stand down, aloud (`features_declined`, the
@@ -675,7 +693,7 @@ class DecodeEngine:
         self.features_declined: list[str] = []
         self.prefix_reuse_declined = 0
         self._prefix_asked = bool(prefix_cache)
-        if cfg.recurrent:
+        if cfg.slot_state:
             assert mesh is None, \
                 "per-slot state leaves have no sharding rule yet"
             for name, asked in (("prefix_cache", prefix_cache),
@@ -684,8 +702,8 @@ class DecodeEngine:
                 if asked:
                     self.features_declined.append(name)
                     paths.note(name, "declined",
-                               "recurrent layers keep per-slot state with "
-                               "no snapshot or roll-back yet")
+                               f"{cfg.slot_state} keep per-slot state "
+                               "with no snapshot or roll-back yet")
             prefix_cache, host_tier, host_blocks = False, False, 0
             self.spec_decode = False
         self._rng = rng if rng is not None else jax.random.PRNGKey(0)
@@ -892,6 +910,22 @@ class DecodeEngine:
         # the grid steps that held them (one a decoding sequence)
         self.decode_live_tiles = 0
         self.decode_live_steps = 0
+        # a model with window layers ('W'): key/value rows the attention
+        # calls of the drained programs had to read, by the planned
+        # lengths, a layer's call each, by what the call was ("decode":
+        # every live slot's token | "chunk"), in the layers that keep the
+        # whole history (`kv_rows_read_full`) and in the window layers
+        # (`kv_rows_read_window`); `window_rows_saved` = what the window
+        # layers' calls would have read of a whole history, less what
+        # they read; `chunk_attn_pairs_by`: (query, key) pairs the chunk
+        # calls' masks let through, a chunk's REAL rows alone (a last
+        # chunk is partial), in the "full" and the "window" layers
+        self._n_full = cfg.layer_pattern.count("*")
+        self._n_window = cfg.layer_pattern.count("W")
+        self.kv_rows_read_full_by = {"chunk": 0, "decode": 0}
+        self.kv_rows_read_window_by = {"chunk": 0, "decode": 0}
+        self.window_rows_saved = 0
+        self.chunk_attn_pairs_by = {"full": 0, "window": 0}
         # tokens computed for an occupant that had left by the drain: an
         # `eos` seen one program late, a cancel while its program ran
         self.overrun_tokens = 0
@@ -1842,6 +1876,9 @@ class DecodeEngine:
                 seq.pos += 1
                 # the rows its attention call reads, this token's included
                 prog.live_tiles += -(-seq.pos // self.block_size)
+                if self._n_window:
+                    self._plan_kv_rows(prog, "decode", seq.pos,
+                                       min(seq.pos, self.cfg.window))
                 self._plan_retirement(slot, seq, prog.retiring)
         if chunk is not None:
             slot_c, take = chunk
@@ -1856,6 +1893,14 @@ class DecodeEngine:
             chunk_done = not self._is_partial(seq_c)
             prog.chunk = (slot_c, seq_c.seq_id, take, seq_c.pos)
             prog.state_reset = self.cfg.recurrent and off == 0
+            if self._n_window:
+                # the chunk's `take` queries see the `off` rows before
+                # them; of those a window layer's see the last window - 1
+                self._plan_kv_rows(prog, "chunk", off + take,
+                                   min(off, self.cfg.window - 1) + take)
+                prog.chunk_pairs = (_causal_pairs(off, take),
+                                    _causal_pairs(off, take,
+                                                  self.cfg.window))
             chunk_in = (
                 jnp.asarray(buf + [0] * (self.prefill_chunk - take),
                             jnp.int32)[None],
@@ -1867,6 +1912,38 @@ class DecodeEngine:
                 self._plan_retirement(slot_c, seq_c, prog.retiring)
         prog.inputs = (self.block_tables, live_in, chunk_in)
         return prog
+
+    def _plan_kv_rows(self, prog: _Program, what: str, whole: int,
+                      windowed: int) -> None:
+        """Add one attention call's key rows to the program's count: of
+        a layer that keeps the `whole` history, and of a window layer."""
+        rows = prog.kv_rows = prog.kv_rows or {}
+        a, b = rows.get(what, (0, 0))
+        rows[what] = (a + whole, b + windowed)
+
+    @property
+    def kv_rows_read_full(self) -> int:
+        return sum(self.kv_rows_read_full_by.values())
+
+    @property
+    def kv_rows_read_window(self) -> int:
+        return sum(self.kv_rows_read_window_by.values())
+
+    @property
+    def resident_bytes_by_kind(self) -> dict:
+        """Bytes the engine holds between programs, by kind of state:
+        the weights, the block pools of the layers that keep a whole
+        history, the window layers' rings, the other per-slot leaves."""
+        def nbytes(tree):
+            return sum(a.size * a.dtype.itemsize
+                       for a in jax.tree_util.tree_leaves(tree))
+        kinds = self.cfg.layer_pattern or "*" * self.cfg.n_layer
+        by = {"weights": nbytes(self.variables), "pools": 0, "window": 0,
+              "slot_state": 0}
+        for kind, leaf in zip(kinds, self.caches):
+            by[{"*": "pools", "W": "window"}.get(kind, "slot_state")] += \
+                nbytes(leaf)
+        return by
 
     def _dispatch(self, prog: _Program) -> None:
         """Enqueue a planned program behind whatever the device is
@@ -1989,6 +2066,18 @@ class DecodeEngine:
             live_steps = prog.n_live if prog.spec is None else 0
             self.decode_live_tiles += prog.live_tiles
             self.decode_live_steps += live_steps
+            kv_full = kv_window = 0
+            for what, (whole, windowed) in (prog.kv_rows or {}).items():
+                full, window = whole * self._n_full, windowed * self._n_window
+                self.kv_rows_read_full_by[what] += full
+                self.kv_rows_read_window_by[what] += window
+                self.window_rows_saved += whole * self._n_window - window
+                kv_full += full
+                kv_window += window
+            self.chunk_attn_pairs_by["full"] += \
+                prog.chunk_pairs[0] * self._n_full
+            self.chunk_attn_pairs_by["window"] += \
+                prog.chunk_pairs[1] * self._n_window
             emitted: dict[int, list] = {}
             retired: dict[int, Retired] = dict(prog.preempted)
             drafted = accepted = overrun = prefill_tokens = 0
@@ -2083,7 +2172,10 @@ class DecodeEngine:
                 **({"experts_hit": hit, "absent_assignments": absent,
                     "expert_calls": calls, "expert_second_tiles": second,
                     "state_reset": int(prog.state_reset)}
-                   if self.cfg.layer_pattern else {}))
+                   if self.cfg.layer_pattern else {}),
+                **({"kv_rows_read_full": kv_full,
+                    "kv_rows_read_window": kv_window}
+                   if self._n_window else {}))
         return StepResult(emitted=emitted, retired=retired,
                           prefill_tokens=prefill_tokens,
                           drafted=drafted, accepted=accepted)

@@ -61,7 +61,8 @@ import jax.numpy as jnp
 
 from distributed_pytorch_tpu.config import LLMConfig
 from distributed_pytorch_tpu.ops.attention_core import sdpa
-from distributed_pytorch_tpu.ops.rope import (apply_rotary_emb, rope_angles,
+from distributed_pytorch_tpu.ops.rope import (apply_partial_rotary,
+                                              apply_rotary_emb, rope_angles,
                                               slice_rows)
 
 Cache = dict[str, jnp.ndarray]
@@ -158,20 +159,36 @@ class GQA(nn.Module):
     head (leaves `q_norm`, `k_norm`, a head-size vector each) BEFORE the
     positions; RoPE pairs the lanes as `cfg.rope_pairing` says, and where
     no table is handed in (`freqs` None) takes its angles from the rows'
-    own positions at `cfg.rope_theta` (ops/rope.py). Keys go into the
-    cache normed and rotated.
+    own positions at `cfg.rope_theta` (ops/rope.py): of the first
+    `cfg.rotary_frac` of the lanes, YaRN's where `cfg.rope_factor` is over 1.
+    Keys go into the cache normed and rotated. With `cfg.attn_gate` every
+    query head's output is multiplied by a gate of its own, the sigmoid of
+    a linear map (leaf `c_gate`, (C, heads)) of the layer's input, before
+    `c_proj`.
+
+    `kind` 'W' is the pattern's window layer, the same class at
+    `cfg.window_heads` query heads, plain RoPE at `cfg.window_rope_theta`
+    over all lanes, and a mask that ends `cfg.window` keys back. Its cache
+    is no block pool but a ring a slot, {"k", "v"}: (n_slots, R, L)
+    (ops/window_attention.py), addressed by the engine's `state_ctx` as
+    the per-slot leaves of the 'M' and 'C' layers are: one token of every
+    slot (`live`), or a chunk of one (`slot`, `valid_len`).
     """
 
     config: LLMConfig
     attn_impl: str = "auto"
     param_dtype: Any = jnp.float32
+    kind: str = "*"
 
     @nn.compact
     def __call__(self, x, freqs, cache: Optional[Cache] = None, pos=0, *,
-                 deterministic: bool = True, block_tables=None):
+                 deterministic: bool = True, block_tables=None,
+                 state_ctx: Optional[dict] = None):
         cfg = self.config
         B, T, C = x.shape
-        nh, nkvh, hs = cfg.n_head, cfg.n_kv_heads, cfg.head_size
+        windowed = self.kind == "W"
+        nh = cfg.window_heads if windowed else cfg.n_head
+        nkvh, hs = cfg.n_kv_heads, cfg.head_size
         qw = nh * hs            # = C unless the config sets `head_dim`
         dense = dict(use_bias=cfg.attn_bias, param_dtype=self.param_dtype)
 
@@ -191,13 +208,25 @@ class GQA(nn.Module):
                     "k_norm", ones, (hs,), self.param_dtype), cfg.norm_eps)
         if cfg.pos_emb == "rope":
             with jax.named_scope("rope"):
-                # no table (a patterned model's context is its cache's,
-                # models/gpt.py): the angles of the rows' own positions
-                f = rope_angles(pos, T, hs, cfg.rope_theta) \
-                    if freqs is None else slice_rows(freqs, pos, T)
+                if windowed:
+                    f = rope_angles(pos, T, hs, cfg.window_rope_theta)
+                elif freqs is None:
+                    # no table (a patterned model's context is its
+                    # cache's, models/gpt.py): the angles of the rows' own
+                    # positions, over the lanes that rotate
+                    yarn = (cfg.rope_factor, cfg.rope_original_len) \
+                        if cfg.rope_factor > 1.0 else ()
+                    f = rope_angles(pos, T, int(hs * cfg.rotary_frac),
+                                    cfg.rope_theta, yarn=yarn,
+                                    attn_factor=cfg.rope_attn_factor)
+                else:
+                    f = slice_rows(freqs, pos, T)
                 half = cfg.rope_pairing == "half"
-                q = apply_rotary_emb(q, f, half=half)
-                k = apply_rotary_emb(k, f, half=half)
+                q = apply_partial_rotary(q, f, half=half)
+                k = apply_partial_rotary(k, f, half=half)
+        if windowed:
+            y = self._window(q, k, v, cache, pos, state_ctx or {})
+            return self._project(x, *y, dense, deterministic)
 
         new_cache = None
         q_offset = 0
@@ -246,10 +275,61 @@ class GQA(nn.Module):
                      k_scale=k_scale, v_scale=v_scale,
                      block_tables=block_tables, n_kv_heads=nkvh,
                      scale=cfg.attn_scale or None)
-        y = y.reshape(B, T, qw)
-        y = _OverlapDense(C, x.dtype, name="c_proj", **dense)(y)
+        return self._project(x, y, new_cache, dense, deterministic)
+
+    def _project(self, x, y, new_cache, dense: dict, deterministic: bool):
+        """The heads' outputs (B, T, heads, hs), each times its gate where
+        the configuration has one, through `c_proj`."""
+        cfg = self.config
+        B, T, nh, hs = y.shape
+        if cfg.attn_gate:
+            with jax.named_scope("attn_gate"):
+                gate = _OverlapDense(nh, x.dtype, name="c_gate",
+                                     use_bias=False,
+                                     param_dtype=self.param_dtype)(x)
+                y = y * jax.nn.sigmoid(
+                    gate.astype(jnp.float32)).astype(y.dtype)[..., None]
+        y = _OverlapDense(x.shape[-1], x.dtype, name="c_proj",
+                          **dense)(y.reshape(B, T, nh * hs))
         y = nn.Dropout(cfg.dropout, deterministic=deterministic)(y)
         return y, new_cache
+
+    def _window(self, q, k, v, cache, pos, ctx: dict):
+        """A 'W' layer's attention and what it leaves in the slot's ring
+        (ops/window_attention.py): -> (heads' outputs, new cache)."""
+        from distributed_pytorch_tpu.ops import window_attention as wa
+        cfg = self.config
+        kw = dict(window=cfg.window,
+                  scale=cfg.attn_scale or 1.0 / cfg.head_size ** 0.5)
+        if cache is None:
+            with jax.named_scope("attn_window"):
+                return wa.window_attention(q, k, v, **kw), None
+        kw["n_kv_heads"] = cfg.n_kv_heads
+        rk, rv = cache["k"], cache["v"]
+        if "live" in ctx:
+            assert q.shape[1] == 1, "one token a slot"
+            with jax.named_scope("kv_update_window"):
+                rk = wa.ring_write_token(rk, k, pos, ctx["live"])
+                rv = wa.ring_write_token(rv, v, pos, ctx["live"])
+            with jax.named_scope("attn_window"):
+                y = wa.window_decode(q, rk, rv, pos, ctx["live"], **kw)
+            return y, {"k": rk, "v": rv}
+        assert q.shape[0] == 1, "a chunk is one sequence's"
+        slot, valid = ctx["slot"], ctx["valid_len"][0]
+        R, L = rk.shape[1:]
+        with jax.named_scope("kv_update_window"):
+            from distributed_pytorch_tpu.ops.block_pool import merge_heads
+            keys, values = (jnp.concatenate([
+                wa.ring_logical(jax.lax.dynamic_index_in_dim(
+                    ring, slot, 0, keepdims=False), pos),
+                merge_heads(new.astype(ring.dtype), L)[0]])
+                for ring, new in ((rk, k), (rv, v)))
+            rk, rv = (jax.lax.dynamic_update_index_in_dim(
+                ring, wa.ring_after(rows, R, pos, valid), slot, 0)
+                for ring, rows in ((rk, keys), (rv, values)))
+        with jax.named_scope("attn_window"):
+            y = wa.window_chunk(q, keys, values, pos, **kw)
+        return y, {"k": rk, "v": rv}
 
 
 def _qmm(mod: nn.Module, x: jnp.ndarray, kernel: jnp.ndarray,
@@ -521,6 +601,20 @@ def init_attn_cache(config: LLMConfig, batch_size: int, max_len: int,
     if config.pos_emb == "rope":
         cache["k_r"] = jnp.zeros((B, S, 1, config.rope_head_dim), dtype)
     return cache
+
+
+def init_window_cache(config: LLMConfig, n_slots: int, block_size: int,
+                      dtype=jnp.float32) -> Cache:
+    """A 'W' layer's state: a ring a slot of the window's rows in whole
+    blocks, merged lanes (ops/window_attention.py). Its bytes do not know
+    `max_len`."""
+    from distributed_pytorch_tpu.ops.block_pool import kv_lanes
+    from distributed_pytorch_tpu.ops.window_attention import ring_rows
+    assert jnp.dtype(dtype) != jnp.int8, \
+        "a window layer's ring has no int8 form yet"
+    shape = (n_slots, ring_rows(config.window, block_size),
+             kv_lanes(config.n_kv_heads, config.head_size))
+    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
 def init_paged_attn_cache(config: LLMConfig, n_blocks: int, block_size: int,

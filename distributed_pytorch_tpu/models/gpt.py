@@ -34,7 +34,8 @@ import jax.numpy as jnp
 
 from distributed_pytorch_tpu.config import LLMConfig
 from distributed_pytorch_tpu.models.attention import (GQA, Attention,
-                                                      init_attn_cache)
+                                                      init_attn_cache,
+                                                      init_window_cache)
 from distributed_pytorch_tpu.models.mlp import MLP, MoE, RoutedExperts
 from distributed_pytorch_tpu.models.shortconv import (ShortConv,
                                                       init_conv_cache)
@@ -100,16 +101,17 @@ class MixerBlock(nn.Module):
     """One layer of a patterned model: `x + r * mixer(RMSNorm(x))` (r =
     `cfg.resid_mult`), the mixer one of 'M' (models/ssm.py), 'C'
     (models/shortconv.py), 'E' (models/mlp.py RoutedExperts), 'F'
-    (models/mlp.py MLP at `cfg.dense_up_dim`) or '*' (GQA). What each
-    keeps between calls sits in the layer's cache slot: per-slot state
-    leaves ('M': tail and state, 'C': tail), this program's routing
-    counts, block pools, nothing ('F').
+    (models/mlp.py MLP at `cfg.dense_up_dim`), '*' (GQA) or 'W' (GQA over
+    a window of the last `cfg.window` positions). What each keeps between
+    calls sits in the layer's cache slot: per-slot state leaves ('M': tail
+    and state, 'C': tail, 'W': a ring of the window's keys and values),
+    this program's routing counts, block pools ('*'), nothing ('F').
     `state_ctx` (the engine's: which rows are live, or which slot a chunk
-    belongs to and how many of its rows are real) reaches the two kinds
-    that have no null block to land a pad in.
+    belongs to and how many of its rows are real) reaches the kinds that
+    have no null block to land a pad in.
 
     `xs` are the hidden rows of the program's row sets (`rows`, one or
-    several). An 'M', 'C', 'F' or '*' layer takes them in turn, the cache
+    several). An 'M', 'C', 'F', '*' or 'W' layer takes them in turn, the cache
     flowing from one to the next; an 'E' layer is position-wise and makes
     ONE call over all their rows, so its experts' matrices are read
     once."""
@@ -145,6 +147,7 @@ class MixerBlock(nn.Module):
                 "C": lambda: ShortConv(cfg, pd, name="conv"),
                 "F": lambda: MLP(cfg, cfg.dense_up_dim, pd, name="mlp"),
                 "*": lambda: GQA(cfg, self.attn_impl, pd, name="attn"),
+                "W": lambda: GQA(cfg, self.attn_impl, pd, "W", name="attn"),
             }[self.kind]()
             ys, new_cache = [], cache
             for h, r in zip(hs, rows):
@@ -155,9 +158,12 @@ class MixerBlock(nn.Module):
                     elif self.kind == "F":
                         y = mixer(h)
                     else:
+                        # '*' reads its rows through the table, 'W' its
+                        # slot's ring through the state context
                         y, new_cache = mixer(
                             h, freqs, new_cache, r.pos, deterministic=True,
-                            block_tables=r.block_tables)
+                            block_tables=r.block_tables,
+                            state_ctx=r.state_ctx)
                 ys.append(y)
         out = []
         for x, y, r in zip(xs, ys, rows):
@@ -435,23 +441,30 @@ def init_cache(config: LLMConfig, batch_size: int,
 def init_paged_cache(config: LLMConfig, n_blocks: int, block_size: int,
                      dtype=jnp.float32, n_slots: int = 0):
     """Per-layer paged KV-cache pytree: one (n_blocks, block_size, ...)
-    pool set per layer, shared by every sequence through per-sequence
-    block tables (engine/decode.py owns the tables; one table serves all
-    layers because block ids are allocated for the whole layer stack at
-    once). Pass the tables to `LLM.__call__(block_tables=...)`.
+    pool set per layer that keeps a sequence's whole history, shared by
+    every sequence through per-sequence block tables (engine/decode.py
+    owns the tables; one table serves all those layers because block ids
+    are allocated for all of them at once). Pass the tables to
+    `LLM.__call__(block_tables=...)`.
 
     A patterned model holds two kinds of state in the one tree: block
-    pools for its '*' layers, a row a slot (`n_slots`) of convolution tail
-    and state for its 'M' layers (models/ssm.py) or of the tail alone for
-    its 'C' layers (models/shortconv.py), nothing for 'F' and 'E' layers
-    (an 'E' slot carries a program's routing counts out, never in)."""
+    pools for its '*' layers, and leaves with a row a slot (`n_slots`)
+    that no table addresses: convolution tail and state for its 'M'
+    layers (models/ssm.py), the tail alone for its 'C' layers
+    (models/shortconv.py), a ring of the last `window` keys and values
+    for its 'W' layers (models/attention.py `init_window_cache`: whatever
+    the pools' `n_blocks` and the engine's `max_len` are), nothing for
+    'F' and 'E' layers (an 'E' slot carries a program's routing counts
+    out, never in)."""
     from distributed_pytorch_tpu.models.attention import init_paged_attn_cache
     if config.layer_pattern:
-        assert n_slots > 0 or not config.recurrent, \
-            "state-space and convolution layers keep a row a slot: pass " \
-            "n_slots"
+        assert n_slots > 0 or not config.slot_state, \
+            "state-space, convolution and window layers keep a row a " \
+            "slot: pass n_slots"
         make = {"M": lambda: init_ssm_cache(config, n_slots, dtype),
                 "C": lambda: init_conv_cache(config, n_slots, dtype),
+                "W": lambda: init_window_cache(config, n_slots, block_size,
+                                               dtype),
                 "*": lambda: init_paged_attn_cache(config, n_blocks,
                                                    block_size, dtype)}
         return [make[kind]() if kind in make else None

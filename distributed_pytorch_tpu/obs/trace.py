@@ -402,6 +402,19 @@ MIXER_SCOPES = {
             "rows' own positions at `cfg.rope_theta`, a classic one's "
             "rows of its table) and the rotation of q and k in the "
             "pairing `cfg.rope_pairing` names (ops/rope.py)",
+    # a window layer's ('W', PR 49), so that a device trace tells it from
+    # a layer that keeps the whole history (`attn_core`, `kv_update`)
+    "attn_window": "a window layer's attention core over the slot's ring: "
+                   "window_flash_decode, or window_flash_prefill over the "
+                   "ring in position order and the chunk's own rows "
+                   "(ops/window_attention.py)",
+    "kv_update_window": "a window layer's ring write: one row a live slot, "
+                        "or the last `window` real rows a chunk leaves "
+                        "(ops/window_attention.py ring_write_token, "
+                        "ring_logical, ring_after)",
+    "attn_gate": "the per-head output gate: its projection, the sigmoid "
+                 "and the product with the heads' outputs "
+                 "(models/attention.py GQA, `cfg.attn_gate`)",
     "moe_route": "the router: sigmoid scores, bias-corrected top-k, "
                  "renormalised weights (models/mlp.py route_sigmoid), or "
                  "the top-k logits and their softmax (route_softmax_topk)",

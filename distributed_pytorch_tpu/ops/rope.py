@@ -40,16 +40,66 @@ def precompute_rope_freqs(dim: int, max_seq_len: int, base: float = 10000.0,
     return jnp.stack([jnp.cos(freqs), jnp.sin(freqs)], axis=-1).astype(dtype)
 
 
-def rope_angles(pos, length: int, dim: int, base: float) -> jnp.ndarray:
+# YaRN's (fast, slow) turns over the original context, between which a
+# frequency is blended: the published default of every configuration run
+YARN_BETA = (32.0, 1.0)
+
+
+def yarn_ramp(dim: int, base: float, original_len: int,
+              beta: tuple = YARN_BETA) -> tuple:
+    """(low, high, ramp) of YaRN over the dim//2 frequencies of `dim`
+    rotated lanes, the published Hugging Face rule with `truncate` on:
+    frequency i turns `original_len` base^(-2i/dim) / 2 pi times over
+    the original context; `low` is the last index that still makes
+    beta[0] (fast) turns, rounded down, `high` the first that makes no
+    more than beta[1] (slow), rounded up, and the ramp runs 0 .. 1
+    between them: 0 keeps a frequency, 1 divides it by the factor."""
+    import math
+
+    def index_of(turns: float) -> float:
+        return dim * math.log(original_len / (turns * 2 * math.pi)) \
+            / (2 * math.log(base))
+    low = max(math.floor(index_of(beta[0])), 0)
+    high = min(math.ceil(index_of(beta[1])), dim - 1)
+    span = max(high - low, 1e-3)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low) / span,
+                    0.0, 1.0)
+    return low, high, ramp
+
+
+def rope_angles(pos, length: int, dim: int, base: float, *,
+                yarn: tuple = (), attn_factor: float = 1.0) -> jnp.ndarray:
     """The (cos, sin) of positions pos .. pos + length - 1 in the table's
     format, computed: (length, dim//2, 2) for a scalar `pos` (static or
-    traced), (B, length, dim//2, 2) for a per-sequence (B,) array."""
+    traced), (B, length, dim//2, 2) for a per-sequence (B,) array.
+    `yarn` = (factor, original_len) blends each frequency between
+    itself and itself / factor along `yarn_ramp`;
+    `attn_factor` multiplies cos and sin (YaRN's attention temperature,
+    folded into the rotation as the published code folds it)."""
     assert dim % 2 == 0, "head dimension must be even"
     theta = 1.0 / (base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    if yarn:
+        factor, original_len = yarn
+        ramp = yarn_ramp(dim, base, original_len)[2]
+        theta = (1.0 - ramp) * theta + ramp * theta / factor
     p = jnp.asarray(pos, jnp.int32)
     p = (p[:, None] if p.ndim else p) + jnp.arange(length, dtype=jnp.int32)
     ang = p.astype(jnp.float32)[..., None] * theta
-    return jnp.stack([jnp.cos(ang), jnp.sin(ang)], axis=-1)
+    out = jnp.stack([jnp.cos(ang), jnp.sin(ang)], axis=-1)
+    return out * attn_factor if attn_factor != 1.0 else out
+
+
+def apply_partial_rotary(x: jnp.ndarray, freqs: jnp.ndarray, *,
+                         half: bool = False) -> jnp.ndarray:
+    """`apply_rotary_emb` on the first 2 * freqs.shape[-2] lanes of every
+    head; the lanes behind them pass as they are (a published
+    `partial_rotary_factor`). All lanes: `apply_rotary_emb` itself."""
+    rd = 2 * freqs.shape[-2]
+    if rd == x.shape[-1]:
+        return apply_rotary_emb(x, freqs, half=half)
+    return jnp.concatenate(
+        [apply_rotary_emb(x[..., :rd], freqs, half=half), x[..., rd:]],
+        axis=-1)
 
 
 def slice_rows(table: jnp.ndarray, pos, length: int) -> jnp.ndarray:

@@ -386,6 +386,27 @@ class Scheduler:
             lambda: getattr(eng, "prefix_reuse_declined", 0),
             "admissions refused a prefix match: recurrent state has no "
             "snapshot")
+        # a model with window layers (engine/decode.py): key rows the
+        # attention calls read, in the layers that keep a whole history
+        # and in the window layers, the rows the window spared, and the
+        # bytes held by kind of state. The rows 0 for every other model.
+        for name, attr, text in (
+                ("serve_kv_rows_read_full_total", "kv_rows_read_full",
+                 "key rows read by the attention calls of layers that "
+                 "keep the whole history"),
+                ("serve_kv_rows_read_window_total", "kv_rows_read_window",
+                 "key rows read by the window layers' attention calls"),
+                ("serve_window_rows_saved_total", "window_rows_saved",
+                 "key rows a whole history would have cost the window "
+                 "layers, less the rows they read")):
+            self.metrics.register_gauge(
+                name, lambda attr=attr: getattr(eng, attr, 0), text)
+        for kind in ("weights", "pools", "window", "slot_state"):
+            self.metrics.register_gauge(
+                f"serve_resident_bytes_{kind}",
+                lambda kind=kind: getattr(
+                    eng, "resident_bytes_by_kind", {}).get(kind, 0),
+                f"bytes held between programs: {kind}")
         # host-RAM KV tier (ops/kv_tier.py via engine.host_tier): live
         # occupancy/save-rate gauges here, block-movement counters
         # delta-synced in _tier_sync() after every engine call. Tier

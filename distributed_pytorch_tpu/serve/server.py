@@ -317,7 +317,17 @@ class ServeApp:
             "merged_program_share":
                 getattr(eng, "merged_program_share", 0.0),
             "chunk_programs_per_prompt":
-                getattr(eng, "chunk_programs_per_prompt", 0.0)})
+                getattr(eng, "chunk_programs_per_prompt", 0.0),
+            # a model with window layers: key rows its attention calls
+            # read so far by kind of layer, rows the window spared, the
+            # (query, key) pairs its chunk calls' masks let through, and
+            # what the engine holds by kind of state (0 / absent: none)
+            "kv_rows_read_full": getattr(eng, "kv_rows_read_full", 0),
+            "kv_rows_read_window": getattr(eng, "kv_rows_read_window", 0),
+            "window_rows_saved": getattr(eng, "window_rows_saved", 0),
+            "chunk_attn_pairs_by": getattr(eng, "chunk_attn_pairs_by", {}),
+            "resident_bytes_by_kind":
+                getattr(eng, "resident_bytes_by_kind", {})})
 
     async def _admin_profile(self, writer, query: dict) -> None:
         """`POST /admin/profile?duration_ms=N`: capture a jax.profiler

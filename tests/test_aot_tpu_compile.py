@@ -33,6 +33,7 @@ from distributed_pytorch_tpu.ops import block_pool as bp  # noqa: E402
 from distributed_pytorch_tpu.ops import flash_attention as fa  # noqa: E402
 from distributed_pytorch_tpu.ops import flash_decode as fd  # noqa: E402
 from distributed_pytorch_tpu.ops import grouped_matmul as gm  # noqa: E402
+from distributed_pytorch_tpu.ops import window_attention as wa  # noqa: E402
 
 BF16, I8, F32, I32 = jnp.bfloat16, jnp.int8, jnp.float32, jnp.int32
 NH, HS, C, V, BS = 12, 64, 768, 50304, 128     # flagship widths
@@ -90,7 +91,14 @@ def _pool(n_blocks, nh, q8):
     return ((n_blocks, BS, bp.kv_lanes(nh, HS)), BF16)
 
 
-def _paged_decode(B, q8, nh=NH):
+def _paged_decode(B, q8, nh=NH, nkv=0, hs=HS, width=8):
+    """`nkv` (float pools): fewer kv heads than query heads, of `hs`."""
+    if nkv:
+        pool = ((B * 8, BS, bp.kv_lanes(nkv, hs)), BF16)
+        shapes = [((B, nh, hs), BF16), pool, pool, ((B, width), I32),
+                  ((B,), I32)]
+        return (lambda q, k, v, bt, cl: fd.paged_flash_decode(
+            q, k, v, bt, cl, scale=hs ** -0.5, n_kv_heads=nkv)), shapes
     pool = _pool(B * 8, nh, q8)
     shapes = [((B, nh, HS), BF16), pool, pool, ((B, 8), I32), ((B,), I32)]
     if q8:
@@ -101,7 +109,13 @@ def _paged_decode(B, q8, nh=NH):
         q, k, v, bt, cl, scale=SCALE, n_kv_heads=nh)), shapes
 
 
-def _paged_prefill(T, q8, nh=NH):
+def _paged_prefill(T, q8, nh=NH, nkv=0, hs=HS, width=8):
+    if nkv:
+        pool = ((64, BS, bp.kv_lanes(nkv, hs)), BF16)
+        shapes = [((1, T, nh, hs), BF16), pool, pool, ((1, width), I32),
+                  ((), I32)]
+        return (lambda q, k, v, bt, o: fd.paged_flash_prefill(
+            q, k, v, bt, o, scale=hs ** -0.5, n_kv_heads=nkv)), shapes
     pool = _pool(64, nh, q8)
     shapes = [((1, T, nh, HS), BF16), pool, pool, ((1, 8), I32), ((), I32)]
     if q8:
@@ -110,6 +124,20 @@ def _paged_prefill(T, q8, nh=NH):
             q, k, v, bt, o, scale=SCALE, k_scale=ks, v_scale=vs)), shapes
     return (lambda q, k, v, bt, o: fd.paged_flash_prefill(
         q, k, v, bt, o, scale=SCALE, n_kv_heads=nh)), shapes
+
+
+def _window_decode(B, nh, ring, nkv=8, hs=128):
+    leaf = ((B, ring, bp.kv_lanes(nkv, hs)), BF16)
+    return (lambda q, k, v, pos: wa.window_flash_decode(
+        q, k, v, pos, window=ring, scale=hs ** -0.5, n_kv_heads=nkv)), [
+            ((B, nh, hs), BF16), leaf, leaf, ((B,), I32)]
+
+
+def _window_prefill(T, nh, ring, nkv=8, hs=128):
+    keys = ((ring + T, bp.kv_lanes(nkv, hs)), BF16)
+    return (lambda q, k, v, off: wa.window_flash_prefill(
+        q, k, v, off, window=ring, scale=hs ** -0.5, n_kv_heads=nkv)), [
+            ((1, T, nh, hs), BF16), keys, keys, ((), I32)]
 
 
 def _gmm(grad):
@@ -196,6 +224,20 @@ CASES = {
                                                       **LFM2_EXPERTS),
                                 ["expert_matmul_gated_up",
                                  "expert_matmul_down"]),
+    # Laguna-S-2.1's published attention (PR 49): 8 KV heads of 128 in 1,024
+    # lanes; a sliding layer's 72 query heads over a ring of 512 rows a
+    # slot, a full layer's 48 over the pools at a table 136 blocks wide;
+    # a decode batch of 64 and a chunk of 1,024 rows
+    "window_decode_64x72x128": (lambda: _window_decode(64, 72, 512),
+                                ["window_flash_decode"]),
+    "window_prefill_1024x72x128": (lambda: _window_prefill(1024, 72, 512),
+                                   ["window_flash_prefill"]),
+    "paged_decode_bf16_48x128": (lambda: _paged_decode(64, False, 48, 8,
+                                                       128, 136),
+                                 ["paged_flash_decode"]),
+    "paged_prefill_1024_48x128": (lambda: _paged_prefill(1024, False, 48, 8,
+                                                         128, 136),
+                                  ["paged_flash_prefill"]),
 }
 
 GRANITE_EXPERTS = dict(C=4096, F=768, held=36, k=10, n_routed=72, gated=True)

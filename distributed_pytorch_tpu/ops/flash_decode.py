@@ -46,12 +46,17 @@ single-token attention):
   and 1.9 us a live tile came of 1.04 of DMA. PERF.md section 6, PR 44.)
 * **Chunked-prefill variant** (`paged_flash_prefill`, round 12): the
   paged decode kernel generalized from one query row per sequence to a
-  (T, rep)-packed query tile of ONE sequence — a prefill chunk written
+  (t, rep)-packed query tile of ONE sequence — a prefill chunk written
   at an arbitrary block-aligned offset attends the sequence's own prior
   blocks plus its in-chunk causal prefix, with per-row global positions
   in the mask. This is the device half of the engine's fused
   chunk+decode step (engine/decode.py `prefill_chunk`); bf16 and int8
-  pools ride the same block-table index map.
+  pools ride the same block-table index map. A grid step of the float
+  body holds a query tile that fits (`_chunk_shape`) against a key tile
+  of several pool blocks, each block a view of the pool with an index
+  map of its own, under ONE masked softmax update; the key axis of the
+  grid is as long as the call's offset needs (measurements: PERF.md
+  section 6, PR 50).
 * **The paged kernels read merged-lane pools** (ops/block_pool.py
   `kv_lanes`): a float k/v pool is (n_blocks, bs, L), every kv head's hs
   lanes side by side and L rounded up to a multiple of 128, because that
@@ -465,6 +470,31 @@ def _zero_extend_q(q: jnp.ndarray, nkv: int, g_pad: int,
     return qz.reshape(B, rep * g_pad, lanes)
 
 
+def _lane_group_q(q3: jnp.ndarray, lanes: int) -> tuple:
+    """(t, rep)-packed chunk rows a kv head, q3 (n_kv, rows, hs), laid out
+    for the chunk kernels: (n_groups, heads a group, rows, gl), a lane
+    group the gl = max(hs, 128) lanes of a merged-lane tile that hold whole
+    heads, each head's rows zero-extended to them (own hs lanes, zeros in
+    its neighbours'); heads past n_kv are the tile's pad lanes. Returns it
+    with gl."""
+    nkv, rows, hs = q3.shape
+    gl = max(hs, 128)
+    hpg, n_groups = gl // hs, lanes // gl
+    q3 = jnp.pad(q3, ((0, n_groups * hpg - nkv), (0, 0), (0, 0))) \
+        .reshape(n_groups, hpg, rows, hs)
+    own = jnp.eye(hpg, dtype=bool)[None, :, None, :, None]
+    return jnp.where(own, q3[:, :, :, None, :], 0) \
+        .reshape(n_groups, hpg, rows, gl), gl
+
+
+def _heads_of_lanes(out: jnp.ndarray, T: int, nh: int, nkv: int,
+                    hs: int) -> jnp.ndarray:
+    """A chunk kernel's (T * rep, L) result, lane l of row (t, r) head
+    (l // hs) * rep + r, as (1, T, nh, hs)."""
+    return out[:, :nkv * hs].reshape(T, nh // nkv, nkv, hs) \
+        .transpose(0, 2, 1, 3).reshape(1, T, nh, hs)
+
+
 # The two paged entry points are jitted on their own: a model calls them
 # once a layer, and a jitted callee is traced and lowered once per program
 # where a plain function is once per call site (48 layers x up to two
@@ -595,62 +625,124 @@ def paged_flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         .transpose(0, 2, 1, 3).reshape(B, nh, hs)
 
 
-def _prefill_kernel(meta_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
-                    acc_ref, m_ref, l_ref, *, scale: float, bs: int,
-                    hs: int, rep: int):
-    """Chunked-prefill body over MERGED-LANE pools: T queries of ONE
-    sequence against its own paged blocks, causal against the global
-    positions `off + t`. Grid (lane groups, logical blocks): a group is
-    the max(hs, 128) lanes of a k/v tile that hold whole heads (two
-    64-wide heads, or one head of 128+), so a step moves a (bs, lanes)
-    tile of exactly the heads it computes. Per head of the group the
-    (t, rep)-packed query rows arrive zero-extended to the group's lanes
-    (`_paged_kernel`'s trick at group width: the contraction is one MXU
-    pass deep either way), the online-softmax state is indexed by head,
-    and the output block is written lane-dense, each head's own lanes
-    picked out of its accumulator."""
-    j = pl.program_id(1)
-    off = meta_ref[0]
-    hpg, n_rows = q_ref.shape[1], q_ref.shape[2]    # heads a group, T * rep
-    T = n_rows // rep
-    last_j = jax.lax.div(jnp.maximum(off + T, 1) - 1, bs)
+#: the chunk kernel's step (`_chunk_shape`): pool blocks one softmax update
+#: takes side by side, and what ONE float32 score tile may take of the
+#: scoped VMEM
+_CHUNK_BLOCKS = 8
+_CHUNK_SCORE_BYTES = VMEM_LIMIT_BYTES // 4
 
-    @pl.when(j == 0)
-    def _():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
 
-    @pl.when(j <= last_j)
-    def _():
-        k, v = k_ref[0], v_ref[0]               # (bs, lanes)
-        qpos = off + jax.lax.div(
-            jax.lax.broadcasted_iota(jnp.int32, (n_rows, bs), 0), rep)
-        kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (n_rows, bs), 1)
-        visible = kpos <= qpos
-        for i in range(hpg):
-            s = jax.lax.dot_general(
-                q_ref[0, i], k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # (T*rep, bs)
-            s = jnp.where(visible, s, _NEG_INF)
-            m_prev, l_prev = m_ref[i], l_ref[i]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            m_ref[i] = m_new
-            l_ref[i] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc_ref[i] = acc_ref[i] * alpha + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+def _chunk_shape(T: int, rep: int, n_max: int, bs: int) -> tuple[int, int]:
+    """(tq, group) of `_prefill_kernel`'s grid step, from the shapes of the
+    call alone: a key tile is `group` pool blocks under ONE softmax update,
+    `_CHUNK_BLOCKS` where the table is that wide and the whole table where
+    it is not; a query tile is `tq` of the chunk's T positions (`tq * rep`
+    packed rows), the largest divisor of T in whole sublanes whose float32
+    score tile against that key tile stays inside `_CHUNK_SCORE_BYTES`. A
+    256-row chunk over a table of 6-10 blocks is one query tile against
+    one key tile; 1,024 x 6 rows over 136 blocks are two query tiles of
+    3,072 rows against 1,024 keys a step; a table of one block gives
+    (T, 1)."""
+    group = max(1, min(n_max, _CHUNK_BLOCKS))
+    rows = _CHUNK_SCORE_BYTES // (group * bs * 4)
+    return _pick_block(T, max(rows // rep, 8), 8) or T, group
 
-    @pl.when(j == pl.num_programs(1) - 1)
+
+def _stack_tiles(tiles) -> jnp.ndarray:
+    """A step's (rows, lanes) views of the keys, one behind the other in
+    position order: its key tile."""
+    return tiles[0] if len(tiles) == 1 else jnp.concatenate(tiles, axis=0)
+
+
+def _softmax_update(q, k, v, visible, acc_ref, m_ref, l_ref, h, *,
+                    scale: float, drop_masked: bool = False):
+    """ONE online-softmax update of head `h`'s state over the whole key
+    tile: q (rows, lanes) against k, v (keys, lanes) under `visible`
+    (rows, keys). Operands go to the MXU in their own dtype, scores /
+    state / accumulator are float32, `p` is cast to v's dtype.
+    `drop_masked`: a row may have seen nothing yet (a window's tile), so a
+    masked p is zeroed, not trusted to underflow."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale         # (rows, keys)
+    s = jnp.where(visible, s, _NEG_INF)
+    m_prev = m_ref[h]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    if drop_masked:
+        p = jnp.where(visible, p, 0.0)
+    m_ref[h] = m_new
+    l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def _softmax_init(acc_ref, m_ref, l_ref):
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+
+
+def _softmax_out(o_ref, acc_ref, l_ref, hs: int):
+    """The lane group's output block, lane-dense: each head's own hs lanes
+    picked out of its normalised accumulator."""
+    head = _lane_head(o_ref.shape, hs)
+    out = jnp.zeros(o_ref.shape, jnp.float32)
+    for h in range(acc_ref.shape[0]):
+        out = jnp.where(
+            head == h, acc_ref[h] / jnp.maximum(l_ref[h], 1e-30), out)
+    o_ref[:] = out.astype(o_ref.dtype)
+
+
+def _prefill_kernel(meta_ref, bt_ref, q_ref, *refs, scale: float, bs: int,
+                    hs: int, rep: int, group: int):
+    """Chunked-prefill body over MERGED-LANE pools: the queries of ONE
+    sequence's chunk against its own paged blocks, causal against the
+    global positions `off + t`. Grid (lane groups, query tiles, key
+    tiles): a lane group is the max(hs, 128) lanes of a k/v tile that hold
+    whole heads (two 64-wide heads, or one head of 128+); a query tile is
+    `tq` positions of the chunk, (t, rep)-packed rows zero-extended to the
+    group's lanes per head (`_paged_kernel`'s trick at group width: the
+    contraction is one MXU pass deep either way); a key tile is `group`
+    pool blocks, each a (1, bs, lanes) view of the pool whose index map
+    resolves its own logical block through the prefetched table
+    (`_chunk_shape` says both sizes). The step stacks the views and makes
+    ONE masked softmax update a head over all their keys: one row max, one
+    `alpha`, one rescale of the accumulator for `group * bs` keys. A key
+    tile whose first key lies past the query tile's last row is neither
+    fetched nor computed (views past the tile's last needed block hold
+    that block again and are masked with it), and the grid's third axis,
+    a scalar of the call, ends with the chunk's last row. The
+    online-softmax state is indexed by head and the output block written
+    lane-dense, each head's own lanes picked out of its accumulator."""
+    k_refs, v_refs = refs[:group], refs[group:2 * group]
+    o_ref, acc_ref, m_ref, l_ref = refs[2 * group:]
+    i, j = pl.program_id(1), pl.program_id(2)
+    hpg, n_rows = q_ref.shape[1], q_ref.shape[2]    # heads a group, tq * rep
+    tq, keys = n_rows // rep, group * bs
+    first = meta_ref[0] + i * tq                    # the tile's first query
+
+    pl.when(j == 0)(functools.partial(_softmax_init, acc_ref, m_ref, l_ref))
+
+    @pl.when(j * keys < first + tq)
     def _():
-        head = _lane_head(o_ref.shape, hs)
-        out = jnp.zeros(o_ref.shape, jnp.float32)
-        for i in range(hpg):
-            out = jnp.where(
-                head == i, acc_ref[i] / jnp.maximum(l_ref[i], 1e-30), out)
-        o_ref[:] = out.astype(o_ref.dtype)
+        k = _stack_tiles([r[0] for r in k_refs])            # (keys, lanes)
+        v = _stack_tiles([r[0] for r in v_refs])
+        # key c of the tile is visible to packed row r (position
+        # first + r // rep) iff (j * keys + c - first) * rep <= r:
+        # a row and a column of int32, no divide over the tile
+        kcol = (j * keys - first + jax.lax.broadcasted_iota(
+            jnp.int32, (1, keys), 1)) * rep
+        visible = kcol <= jax.lax.broadcasted_iota(
+            jnp.int32, (n_rows, 1), 0)
+        for h in range(hpg):
+            _softmax_update(q_ref[0, h], k, v, visible, acc_ref, m_ref,
+                            l_ref, h, scale=scale)
+
+    pl.when(j == pl.num_programs(2) - 1)(
+        functools.partial(_softmax_out, o_ref, acc_ref, l_ref, hs))
 
 
 def _prefill_kernel_q8(meta_ref, bt_ref, q_ref, k_ref, ks_ref, v_ref,
@@ -748,10 +840,10 @@ def paged_flash_prefill(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     q3 = q[0].reshape(T, nkv, rep, hs).transpose(1, 0, 2, 3) \
         .reshape(nkv, rows, hs)
 
-    def last_block(meta_ref):
-        return jax.lax.div(jnp.maximum(meta_ref[0] + T, 1) - 1, bs)
-
     if quantized:
+        def last_block(meta_ref):
+            return jax.lax.div(jnp.maximum(meta_ref[0] + T, 1) - 1, bs)
+
         def q_idx(j, meta_ref, bt_ref):
             return (0, 0, 0)
 
@@ -788,51 +880,55 @@ def paged_flash_prefill(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             .reshape(1, T, nh, hs)
 
     L = k.shape[2]
-    gl = max(hs, 128)                           # lanes of one head group
-    hpg, n_groups = gl // hs, L // gl
-    # zero-extend each head's rows to its group's lanes (own hs lanes,
-    # zeros in its neighbours'); heads past n_kv are the pool's pad lanes
-    q3 = jnp.pad(q3, ((0, n_groups * hpg - nkv), (0, 0), (0, 0))) \
-        .reshape(n_groups, hpg, rows, hs)
-    own = jnp.eye(hpg, dtype=bool)[None, :, None, :, None]
-    qz = jnp.where(own, q3[:, :, :, None, :], 0) \
-        .reshape(n_groups, hpg, rows, gl)
+    qz, gl = _lane_group_q(q3, L)
+    n_groups, hpg = qz.shape[:2]
+    tq, group = _chunk_shape(T, rep, n_max, bs)
+    rows_q = tq * rep
 
-    def q_idx(g, j, meta_ref, bt_ref):
-        return (g, 0, 0, 0)
+    def q_idx(g, i, j, meta_ref, bt_ref):
+        return (g, 0, i, 0)
 
-    def kv_idx(g, j, meta_ref, bt_ref):
-        return (bt_ref[jnp.minimum(j, last_block(meta_ref))], 0, g)
+    def kv_idx(t):
+        def idx(g, i, j, meta_ref, bt_ref):
+            # view t of key tile j holds logical block j * group + t while
+            # query tile i needs it, and the tile's last needed block past
+            # that: a key tile the diagonal ends in fetches no block the
+            # chunk cannot see
+            last = jax.lax.div(meta_ref[0] + (i + 1) * tq - 1, bs)
+            return (bt_ref[jnp.minimum(j * group + t,
+                                       jnp.minimum(last, n_max - 1))], 0, g)
+        return idx
 
-    def o_idx(g, j, meta_ref, bt_ref):
-        return (0, g)
+    def o_idx(g, i, j, meta_ref, bt_ref):
+        return (i, g)
 
+    kv_specs = [pl.BlockSpec((1, bs, gl), kv_idx(t)) for t in range(group)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(n_groups, n_max),
-        in_specs=[pl.BlockSpec((1, hpg, rows, gl), q_idx),
-                  pl.BlockSpec((1, bs, gl), kv_idx),
-                  pl.BlockSpec((1, bs, gl), kv_idx)],
-        out_specs=pl.BlockSpec((rows, gl), o_idx),
+        # the third axis ends with the chunk's last row: as many key tiles
+        # as the call's offset needs, not as the table is wide
+        grid=(n_groups, T // tq, jnp.minimum(
+            jax.lax.div(meta[0] + T - 1, group * bs) + 1,
+            -(-n_max // group))),
+        in_specs=[pl.BlockSpec((1, hpg, rows_q, gl), q_idx)] + 2 * kv_specs,
+        out_specs=pl.BlockSpec((rows_q, gl), o_idx),
         scratch_shapes=[
-            pltpu.VMEM((hpg, rows, gl), jnp.float32),
-            pltpu.VMEM((hpg, rows, 1), jnp.float32),
-            pltpu.VMEM((hpg, rows, 1), jnp.float32),
+            pltpu.VMEM((hpg, rows_q, gl), jnp.float32),
+            pltpu.VMEM((hpg, rows_q, 1), jnp.float32),
+            pltpu.VMEM((hpg, rows_q, 1), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(_prefill_kernel, scale=float(scale), bs=bs,
-                          hs=hs, rep=rep),
+                          hs=hs, rep=rep, group=group),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, L), q.dtype),
         compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         name="paged_flash_prefill",
         interpret=interpret,
-    )(meta, bt, qz, k, v)
-    # (T * rep, L): lane l of row (t, r) is head (l // hs) * rep + r
-    return out[:, :nkv * hs].reshape(T, rep, nkv, hs) \
-        .transpose(0, 2, 1, 3).reshape(1, T, nh, hs)
+    )(meta, bt, qz, *(group * [k] + group * [v]))
+    return _heads_of_lanes(out, T, nh, nkv, hs)
 
 
 def _common_decline(q, k, nh, nkv, hs, bs, what: str):
@@ -884,14 +980,28 @@ def _pool_heads(k, n_kv_heads: int) -> int:
     return k.shape[2] if k.ndim == 4 else n_kv_heads
 
 
+def _chunk_vmem_bytes(rows: int, keys: int, heads: int, lanes: int,
+                      q_item: int, kv_item: int) -> int:
+    """What one grid step of a chunk kernel holds in VMEM: the query tile
+    and the output block, double-buffered; the k/v views of the key tile,
+    double-buffered, and the two stacked tiles made of them; a head's
+    float32 accumulator with its `m` and `l` columns (a column is padded
+    to 128 lanes there); three score-sized float32 temporaries (scores,
+    `p`, the mask or the cast)."""
+    return (2 * heads * rows * lanes * q_item + 2 * rows * lanes * q_item
+            + (2 * 2 + 2) * keys * lanes * kv_item
+            + heads * rows * (lanes + 2 * 128) * 4 + 3 * rows * keys * 4)
+
+
 def paged_flash_prefill_decline(q, k, v, block_tables, n_kv_heads: int = 0):
     """Why the chunk-prefill kernel cannot take this call (None = it
     can), mirroring `paged_flash_decode_decline`: one sequence's
     (1, T>1, nh, hs) chunk, whole-block pool pages the hardware tiles, T
     a multiple of the sublane step, heads that tile the merged lanes in
-    whole 128-lane groups, and one group's query tile + f32 accumulator
-    within the VMEM budget. The fallback is paged_gather + the naive
-    masked path — identical semantics."""
+    whole 128-lane groups, and one grid step (`_chunk_shape`'s query tile
+    against its key tile of several blocks, `_chunk_vmem_bytes`) within
+    the VMEM budget. The fallback is paged_gather + the naive masked
+    path — identical semantics."""
     if q.ndim != 4 or q.shape[0] != 1 or q.shape[1] <= 1:
         return f"query shape {q.shape} is not one sequence's (1, T>1) chunk"
     _, T, nh, hs = q.shape
@@ -901,20 +1011,21 @@ def paged_flash_prefill_decline(q, k, v, block_tables, n_kv_heads: int = 0):
     why = _common_decline(q, k, nh, nkv, hs, bs, f"pool block size {bs}")
     if why is not None:
         return why
-    rows = T * (nh // nkv)
+    rep = nh // nkv
     if k.ndim == 4:                 # int8 head-major tiles: every head a step
-        heads, qw, width = nkv, hs, nkv * hs
-    elif 128 % hs != 0 and hs % 128 != 0:
+        rows = T * rep
+        qtile = nkv * rows * hs * jnp.dtype(q.dtype).itemsize
+        scratch = nkv * rows * (hs + 2) * 4
+        return _budget_decline(_kv_tile_bytes(k, bs, nkv * hs) + qtile
+                               + scratch + 3 * nkv * rows * bs * 4)
+    if 128 % hs != 0 and hs % 128 != 0:
         return (f"head size {hs} neither divides nor is a multiple of the "
                 "128 lanes a head group is cut by")
-    else:                           # one lane group: its heads, one at a time
-        width = qw = max(hs, 128)
-        heads = width // hs
-    qtile = heads * rows * qw * jnp.dtype(q.dtype).itemsize
-    scratch = heads * rows * (qw + 2) * 4
-    scores = 3 * (heads if k.ndim == 4 else 1) * rows * bs * 4
-    return _budget_decline(_kv_tile_bytes(k, bs, width) + qtile + scratch
-                           + scores)
+    lanes = max(hs, 128)            # one lane group: its heads, one at a time
+    tq, group = _chunk_shape(T, rep, block_tables.shape[1], bs)
+    return _budget_decline(_chunk_vmem_bytes(
+        tq * rep, group * bs, lanes // hs, lanes,
+        jnp.dtype(q.dtype).itemsize, jnp.dtype(k.dtype).itemsize))
 
 
 def paged_flash_decode_decline(q, k, v, block_tables, n_kv_heads: int = 0):

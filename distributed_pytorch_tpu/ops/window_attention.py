@@ -48,18 +48,26 @@ from jax.experimental.pallas import tpu as pltpu
 from distributed_pytorch_tpu.compat import tpu_compiler_params
 from distributed_pytorch_tpu.obs import paths
 from distributed_pytorch_tpu.ops.block_pool import merge_heads
-from distributed_pytorch_tpu.ops.flash_decode import (_NEG_INF,
+from distributed_pytorch_tpu.ops.flash_decode import (_CHUNK_SCORE_BYTES,
+                                                      _NEG_INF,
                                                       _budget_decline,
+                                                      _chunk_vmem_bytes,
                                                       _common_decline,
+                                                      _heads_of_lanes,
+                                                      _lane_group_q,
                                                       _lane_head,
                                                       _pick_block,
+                                                      _softmax_init,
+                                                      _softmax_out,
+                                                      _softmax_update,
+                                                      _stack_tiles,
                                                       _zero_extend_q)
 
-#: rows of a ring tile a decode grid step moves, and of the key and query
-#: tiles of the chunk kernel, on the chip (the CPU tests tile by 8)
+#: rows of a ring tile a decode grid step moves, and of a key view and a
+#: query tile of the chunk kernel, on the chip (the CPU tests tile by 8)
 _DECODE_TILE = 512
 _CHUNK_TILE_K = 128
-_CHUNK_TILE_Q = 256
+_CHUNK_TILE_Q = 128
 
 
 def ring_rows(window: int, block_size: int) -> int:
@@ -304,62 +312,67 @@ def window_decode(q, ring_k, ring_v, pos, live, *, window: int,
 # a chunk of one sequence
 # ---------------------------------------------------------------------------
 
-def _chunk_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
-                  l_ref, *, scale: float, tq: int, tk: int, ring: int,
-                  window: int, hs: int, rep: int, first_tile):
-    """`_prefill_kernel`'s body over keys at CONSECUTIVE positions (row i
-    of the keys is position off - ring + i). Grid (lane groups, query
-    tiles, key tiles of a query tile's window)."""
+def _chunk_kernel(off_ref, q_ref, *refs, scale: float, tq: int, tk: int,
+                  group: int, ring: int, window: int, hs: int, rep: int,
+                  first_tile):
+    """`_prefill_kernel`'s step over keys at CONSECUTIVE positions (row c
+    of the keys is position off - ring + c). Grid (lane groups, query
+    tiles, key tiles of a query tile's window): a key tile is `group`
+    (tk, lanes) views of the keys, stacked, under ONE softmax update a
+    head (`flash_decode._softmax_update`); `_chunk_tiles` opens as many
+    views as a query tile's window spans where their score tile fits, and
+    the third grid axis is then one step long: no running state is
+    rescaled at all. Both of the band's edges (causal, and `window` keys
+    back) run through such a tile for every query tile, so its one body
+    masks: by a row and two columns of int32, no divide over the tile."""
+    k_refs, v_refs = refs[:group], refs[group:2 * group]
+    o_ref, acc_ref, m_ref, l_ref = refs[2 * group:]
     i, j = pl.program_id(1), pl.program_id(2)
     off = off_ref[0]
     hpg, n_rows = q_ref.shape[1], q_ref.shape[2]
+    keys = group * tk
 
-    @pl.when(j == 0)
-    def _():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    pl.when(j == 0)(functools.partial(_softmax_init, acc_ref, m_ref, l_ref))
 
-    k, v = k_ref[...], v_ref[...]                           # (tk, lanes)
-    qpos = off + i * tq + jax.lax.div(
-        jax.lax.broadcasted_iota(jnp.int32, (n_rows, tk), 0), rep)
-    kpos = off - ring + (first_tile(i) + j) * tk \
-        + jax.lax.broadcasted_iota(jnp.int32, (n_rows, tk), 1)
-    back = qpos - kpos
-    visible = (kpos >= 0) & (back >= 0) & (back < window)
+    k = _stack_tiles([r[...] for r in k_refs])              # (keys, lanes)
+    v = _stack_tiles([r[...] for r in v_refs])
+    # key c of the tile lies d = its position less the tile's first
+    # query's ahead; packed row r (r // rep positions into the tile) sees
+    # it iff 0 <= r // rep - d < window and its position is none a slot's
+    # last occupant left: d * rep <= r < (d + window) * rep
+    d = (first_tile(i) + j * group) * tk - ring - i * tq \
+        + jax.lax.broadcasted_iota(jnp.int32, (1, keys), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (n_rows, 1), 0)
+    lo = jnp.where(off + i * tq + d >= 0, d * rep, n_rows)
+    visible = (lo <= row) & (row < (d + window) * rep)
     for h in range(hpg):
-        s = jax.lax.dot_general(
-            q_ref[0, h], k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale     # (rows, tk)
-        s = jnp.where(visible, s, _NEG_INF)
-        m_prev, l_prev = m_ref[h], l_ref[h]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
         # a row that has seen nothing yet keeps exp(0) out of its sum
-        pr = jnp.where(visible, jnp.exp(s - m_new), 0.0)
-        m_ref[h] = m_new
-        l_ref[h] = l_prev * alpha + jnp.sum(pr, axis=-1, keepdims=True)
-        acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
-            pr.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        _softmax_update(q_ref[0, h], k, v, visible, acc_ref, m_ref, l_ref,
+                        h, scale=scale, drop_masked=True)
 
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _():
-        head = _lane_head(o_ref.shape, hs)
-        out = jnp.zeros(o_ref.shape, jnp.float32)
-        for h in range(hpg):
-            out = jnp.where(
-                head == h, acc_ref[h] / jnp.maximum(l_ref[h], 1e-30), out)
-        o_ref[:] = out.astype(o_ref.dtype)
+    pl.when(j == pl.num_programs(2) - 1)(
+        functools.partial(_softmax_out, o_ref, acc_ref, l_ref, hs))
 
 
-def _chunk_tiles(T: int, ring: int, interpret: bool) -> tuple:
-    """(query tile, key tile) in rows: the key tile divides the ring and
-    the chunk (the keys are the one behind the other), the query tile the
-    chunk."""
+def _chunk_tiles(T: int, ring: int, window: int, rep: int,
+                 interpret: bool) -> tuple:
+    """(query tile, key view, views a step, steps) of the chunk kernel, in
+    rows, from the call's shapes: a view divides the ring, the chunk (the
+    keys are the one behind the other) and the query tile (so that every
+    query tile's window starts at the same view of its own); a step opens
+    as many views as a query tile's window spans, fewer where the float32
+    score tile of all of them would pass `flash_decode`'s budget for one.
+    A 0 among the first two: no tile split."""
     step = 8 if interpret else 128
-    return (_pick_block(T, _CHUNK_TILE_Q, step),
-            _pick_block(math.gcd(ring, T), _CHUNK_TILE_K, step))
+    tq = _pick_block(T, _CHUNK_TILE_Q, step)
+    tk = _pick_block(math.gcd(ring, tq), _CHUNK_TILE_K, step) if tq else 0
+    if not tq or not tk:
+        return 0, 0, 0, 0
+    # the views from the one that holds the key `window` - 1 behind a
+    # tile's first query to the one that holds its last query's own
+    n_k = (tq - 1 + ring) // tk - (ring - window + 1) // tk + 1
+    group = max(1, min(n_k, _CHUNK_SCORE_BYTES // (tq * rep * tk * 4)))
+    return tq, tk, group, -(-n_k // group)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "scale",
@@ -371,54 +384,47 @@ def window_flash_prefill(q: jnp.ndarray, keys: jnp.ndarray,
     """q (1, T, nh, hs), a chunk of one sequence at positions off ..
     off + T - 1, against `keys` / `values` (R + T, L): positions off - R
     .. off + T - 1 in order, merged lanes. Returns (1, T, nh, hs). A
-    query tile i walks the key tiles from the one that holds the key
+    query tile i walks the key views from the one that holds the key
     `window` - 1 behind its first query to the one that holds its last
-    query's own: a static map, positions enter the mask alone."""
+    query's own, several a grid step (`_chunk_tiles`): a static map,
+    positions enter the mask alone."""
     _, T, nh, hs = q.shape
     S, L = keys.shape
     R = S - T
     nkv = n_kv_heads
     rep = nh // nkv
-    tq, tk = _chunk_tiles(T, R, interpret)
+    tq, tk, group, n_steps = _chunk_tiles(T, R, window, rep, interpret)
     assert tq and tk, (T, R)
     rows = tq * rep
 
-    def first_tile(i):                  # of query tile i, in key tiles
-        at = i * tq + (R - window + 1)
-        return at // tk if isinstance(i, int) else jax.lax.div(at, tk)
+    def first_tile(i):                  # of query tile i, in key views
+        return i * (tq // tk) + (R - window + 1) // tk
 
-    # every query tile walks as many key tiles as the one that needs most
-    n_k = max((i * tq + tq - 1 + R) // tk - first_tile(i) + 1
-              for i in range(T // tq))
     last_tile = S // tk - 1
 
     # (t, rep)-packed query rows a kv head, zero-extended to their lane
     # group, as `paged_flash_prefill` lays them out
-    q3 = q[0].reshape(T, nkv, rep, hs).transpose(1, 0, 2, 3) \
-        .reshape(nkv, T * rep, hs)
-    gl = max(hs, 128)
-    hpg, n_groups = gl // hs, L // gl
-    q3 = jnp.pad(q3, ((0, n_groups * hpg - nkv), (0, 0), (0, 0))) \
-        .reshape(n_groups, hpg, T * rep, hs)
-    own = jnp.eye(hpg, dtype=bool)[None, :, None, :, None]
-    qz = jnp.where(own, q3[:, :, :, None, :], 0) \
-        .reshape(n_groups, hpg, T * rep, gl)
+    qz, gl = _lane_group_q(q[0].reshape(T, nkv, rep, hs).transpose(
+        1, 0, 2, 3).reshape(nkv, T * rep, hs), L)
+    n_groups, hpg = qz.shape[:2]
 
     def q_idx(g, i, j, off_ref):
         return (g, 0, i, 0)
 
-    def kv_idx(g, i, j, off_ref):
-        return (jnp.minimum(first_tile(i) + j, last_tile), g)
+    def kv_idx(t):
+        def idx(g, i, j, off_ref):
+            return (jnp.minimum(first_tile(i) + j * group + t, last_tile),
+                    g)
+        return idx
 
     def o_idx(g, i, j, off_ref):
         return (i, g)
 
+    kv_specs = [pl.BlockSpec((tk, gl), kv_idx(t)) for t in range(group)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(n_groups, T // tq, n_k),
-        in_specs=[pl.BlockSpec((1, hpg, rows, gl), q_idx),
-                  pl.BlockSpec((tk, gl), kv_idx),
-                  pl.BlockSpec((tk, gl), kv_idx)],
+        grid=(n_groups, T // tq, n_steps),
+        in_specs=[pl.BlockSpec((1, hpg, rows, gl), q_idx)] + 2 * kv_specs,
         out_specs=pl.BlockSpec((rows, gl), o_idx),
         scratch_shapes=[pltpu.VMEM((hpg, rows, gl), jnp.float32),
                         pltpu.VMEM((hpg, rows, 1), jnp.float32),
@@ -426,26 +432,28 @@ def window_flash_prefill(q: jnp.ndarray, keys: jnp.ndarray,
     )
     out = pl.pallas_call(
         functools.partial(_chunk_kernel, scale=float(scale), tq=tq, tk=tk,
-                          ring=R, window=window, hs=hs, rep=rep,
-                          first_tile=first_tile),
+                          group=group, ring=R, window=window, hs=hs,
+                          rep=rep, first_tile=first_tile),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T * rep, L), q.dtype),
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         name="window_flash_prefill",
         interpret=interpret,
-    )(jnp.reshape(jnp.asarray(off, jnp.int32), (1,)), qz, keys, values)
-    return out[:, :nkv * hs].reshape(T, rep, nkv, hs) \
-        .transpose(0, 2, 1, 3).reshape(1, T, nh, hs)
+    )(jnp.reshape(jnp.asarray(off, jnp.int32), (1,)), qz,
+      *(group * [keys] + group * [values]))
+    return _heads_of_lanes(out, T, nh, nkv, hs)
 
 
-def window_flash_prefill_decline(q, keys, n_kv_heads: int):
+def window_flash_prefill_decline(q, keys, n_kv_heads: int, window: int):
     """Why the chunk kernel cannot take this call (None = it can)."""
     if q.ndim != 4 or q.shape[0] != 1 or q.shape[1] <= 1:
         return f"query shape {q.shape} is not one sequence's (1, T>1) chunk"
     _, T, nh, hs = q.shape
     S, L = keys.shape
-    tq, tk = _chunk_tiles(T, S - T, jax.default_backend() != "tpu")
+    rep = nh // max(n_kv_heads, 1)
+    tq, tk, group, _ = _chunk_tiles(T, S - T, window, rep,
+                                    jax.default_backend() != "tpu")
     if not tq or not tk:
         return (f"a chunk of {T} rows behind a ring of {S - T} has no "
                 "tile split")
@@ -457,15 +465,15 @@ def window_flash_prefill_decline(q, keys, n_kv_heads: int):
         return (f"head size {hs} neither divides nor is a multiple of the "
                 "128 lanes a head group is cut by")
     gl = max(hs, 128)
-    heads, rows = gl // hs, tq * (nh // n_kv_heads)
     item = jnp.dtype(q.dtype).itemsize
-    return _budget_decline(2 * 2 * tk * gl * item + 2 * heads * rows * gl
-                           * item + heads * rows * (gl + 2) * 4
-                           + 2 * rows * gl * item + 3 * rows * tk * 4)
+    return _budget_decline(_chunk_vmem_bytes(
+        tq * rep, group * tk, gl // hs, gl, item,
+        jnp.dtype(keys.dtype).itemsize))
 
 
-def window_flash_prefill_usable(q, keys, n_kv_heads: int) -> bool:
-    return window_flash_prefill_decline(q, keys, n_kv_heads) is None
+def window_flash_prefill_usable(q, keys, n_kv_heads: int,
+                                window: int) -> bool:
+    return window_flash_prefill_decline(q, keys, n_kv_heads, window) is None
 
 
 def window_chunk(q, keys, values, off, *, window: int, scale: float,
@@ -477,7 +485,7 @@ def window_chunk(q, keys, values, off, *, window: int, scale: float,
         _decode_kernel_wanted, _on_tpu)
     if _decode_kernel_wanted(
             "window_flash_prefill",
-            window_flash_prefill_decline(q, keys, n_kv_heads)):
+            window_flash_prefill_decline(q, keys, n_kv_heads, window)):
         return window_flash_prefill(
             q, keys, values, off, window=window, scale=scale,
             n_kv_heads=n_kv_heads, interpret=not _on_tpu())

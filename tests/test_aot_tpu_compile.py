@@ -271,6 +271,11 @@ def test_kernel_compiles_for_v5e(case, v5e):
         assert census.get(name), (
             f"{case}: compiled, but no tpu_custom_call named {name!r} in "
             f"the program (census {census})")
+        # a chunk call is ONE kernel of its name a layer, whatever its grid
+        # step holds: the benchmark's roofline reader divides the summed
+        # device time of the ops of that name by their count
+        if name in ("paged_flash_prefill", "window_flash_prefill"):
+            assert census[name] == 1, (case, census)
 
 
 # ---------------------------------------------------------------------------

@@ -524,29 +524,111 @@ def _mk_chunk(T, n_max, bs, nh, nkv, hs, seed=0, merged=True):
     return q, kp, vp, bt, kl, vl
 
 
-@pytest.mark.parametrize("off", [0, 8, 24], ids=lambda o: f"off{o}")
-@pytest.mark.parametrize(
-    "shape", [(8, 8, 16), (8, 4, 16), (8, 1, 16), (25, 25, 64), (24, 6, 64),
-              (4, 2, 128)], ids=_ids)
-def test_chunk_prefill_parity_offsets(shape, off):
+# (shape, off, T, table width, float32 score-tile budget or None, real rows
+# or None): the six head shapes at a fresh sequence, one prior block and
+# three, a 16-row chunk over a table of 8 (ONE key tile of 8 blocks = 64
+# keys, one query tile: `_chunk_shape` at 8-row blocks); then walks of
+# several key tiles and, with the budget cut to a few rows, several query
+# tiles (`_CHUNK_SCORE_BYTES` patched: no caller sets a tile)
+_CHUNK_SHAPES = [(8, 8, 16), (8, 4, 16), (8, 1, 16), (25, 25, 64),
+                 (24, 6, 64), (4, 2, 128)]
+_CHUNK_WALKS = {
+    # key tile 0 (keys 0-63) lies whole under the chunk at 72-87, tile 1
+    # holds the diagonal and 3 live blocks of 8, tile 2 is dead: 11 live
+    # blocks are no multiple of the group, the table is wider than they
+    "whole_and_edge_rep4": ((24, 6, 64), 72, 16, 24, None, None),
+    "whole_and_edge_25x64": ((25, 25, 64), 72, 16, 24, None, None),
+    # the same walk under four query tiles of 8 positions: rep 6 (48 rows
+    # a tile), rep 1, and 25 heads of 64 (the last lane group half pads)
+    "query_tiles_rep6": ((12, 2, 128), 40, 32, 16, 64 * 64 * 4, None),
+    "query_tiles_rep1": ((8, 8, 16), 40, 32, 16, 8 * 64 * 4, None),
+    "query_tiles_25x64": ((25, 25, 64), 104, 32, 24, 8 * 64 * 4, None),
+    # offset 0 under four query tiles: tile i sees its own i + 1 blocks
+    "query_tiles_off0": ((12, 2, 128), 0, 32, 16, 64 * 64 * 4, None),
+    # a prompt's last chunk: 21 real rows of 32, the table's columns past
+    # them on the null block as the engine leaves them
+    "partial_last_chunk": ((24, 6, 64), 64, 32, 16, 32 * 64 * 4, 21),
+    # a table of ONE block: the whole chunk against one block a step
+    "one_block_table": ((8, 4, 16), 0, 8, 1, None, None),
+}
+_CHUNK_CASES = [pytest.param(shape, off, 16, 8, None, None,
+                             id=f"{_ids(shape)}-off{off}")
+                for shape in _CHUNK_SHAPES for off in (0, 8, 24)] + \
+    [pytest.param(*case, id=name) for name, case in _CHUNK_WALKS.items()]
+
+
+@pytest.mark.parametrize("shape,off,T,n_max,score,real", _CHUNK_CASES)
+def test_chunk_prefill_parity_offsets(shape, off, T, n_max, score, real,
+                                      monkeypatch):
     """paged_flash_prefill vs the naive path on the gathered logical
-    view: a 16-row chunk at block-aligned offsets (fresh sequence, one
-    prior block, three prior blocks) attends its prior context plus its
-    own in-chunk causal prefix — MHA through MQA, shuffled tables; 25 x 64
-    ends in a half-pad lane group, 6 x 64 packs rep 4 into the rows, 2 x
-    128 is one head a group."""
-    from distributed_pytorch_tpu.ops.flash_decode import (
-        paged_flash_prefill, paged_flash_prefill_usable)
+    view: a chunk at block-aligned offsets (fresh sequence, prior blocks)
+    attends its prior context plus its own in-chunk causal prefix — MHA
+    through MQA, shuffled tables; 25 x 64 ends in a half-pad lane group,
+    6 x 64 packs rep 4 into the rows, 2 x 128 is one head a group. Every
+    pool block the chunk does not need is poisoned: a view of a
+    part-filled key tile, a dead key tile and the table's tail read none
+    of them."""
+    from distributed_pytorch_tpu.ops import flash_decode as fd
     nh, nkv, hs = shape
-    T, n_max, bs = 16, 8, 8
+    bs = 8
     q, kp, vp, bt, kl, vl = _mk_chunk(T, n_max, bs, nh, nkv, hs, seed=off)
-    assert paged_flash_prefill_usable(q, kp, vp, bt, nkv)
-    out = paged_flash_prefill(q, kp, vp, bt, jnp.int32(off),
-                              scale=hs ** -0.5, n_kv_heads=nkv,
-                              interpret=True)
+    call = fd.paged_flash_prefill
+    if score is not None:
+        # a jitted callee is cached by its shapes: a patched budget takes
+        # the function under the jit
+        monkeypatch.setattr(fd, "_CHUNK_SCORE_BYTES", score)
+        call = jax.jit(call.__wrapped__,
+                       static_argnames=("scale", "n_kv_heads", "interpret"))
+    rows = T if real is None else real
+    needed = -(-(off + rows) // bs)
+    if real is not None:
+        bt = bt.at[0, needed:].set(0)
+        from distributed_pytorch_tpu.ops.block_pool import paged_gather
+        kl, vl = (paged_gather(pool, bt, (nkv, hs)) for pool in (kp, vp))
+    assert fd.paged_flash_prefill_usable(q, kp, vp, bt, nkv)
+    keep = jnp.isin(jnp.arange(kp.shape[0]), bt[0, :needed])
+    if real is not None:
+        keep = keep.at[0].set(True)             # the pads' null block
+    kp = jnp.where(keep[:, None, None], kp, jnp.nan)
+    vp = jnp.where(keep[:, None, None], vp, jnp.inf)
+    tq, group = fd._chunk_shape(T, nh // nkv, n_max, bs)
+    assert (T // tq > 1) == (score is not None)
+    out = call(q, kp, vp, bt, jnp.int32(off), scale=hs ** -0.5,
+               n_kv_heads=nkv, interpret=True)
     ref = _naive_sdpa(q, kl, vl, scale=hs ** -0.5, q_offset=off)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+    np.testing.assert_allclose(np.asarray(out)[:, :rows],
+                               np.asarray(ref)[:, :rows],
                                atol=1e-5, rtol=1e-5)
+
+
+# (T, rep, table width) of a chunk call at the accepted serving cells (a
+# table is max_len / 128 + a chunk's blocks wide) -> (tq, group)
+_CELL_CHUNKS = {
+    "gpt2xl_serve_closed24": ((256, 1, 10), (256, 8)),
+    "nemotron_h_serve_closed64": ((256, 16, 6), (256, 6)),
+    "granite4h_serve_closed64": ((256, 4, 6), (256, 6)),
+    "lfm2moe_serve_closed128": ((256, 4, 10), (256, 8)),
+    "laguna_serve_closed64_long": ((1024, 6, 136), (512, 8)),
+    "one_block_table": ((256, 4, 1), (256, 1)),
+}
+
+
+@pytest.mark.parametrize("cell", list(_CELL_CHUNKS))
+def test_chunk_shape_at_the_cells(cell):
+    """`_chunk_shape` on plain ints: the query tile divides the chunk in
+    whole sublanes, the key tile is whole blocks of the table, its score
+    tile and the whole step fit their budgets, and a table of one block
+    gives the (whole chunk, 1 block) step."""
+    from distributed_pytorch_tpu.compat import VMEM_LIMIT_BYTES
+    from distributed_pytorch_tpu.ops import flash_decode as fd
+    (T, rep, n_max), want = _CELL_CHUNKS[cell]
+    tq, group = fd._chunk_shape(T, rep, n_max, 128)
+    assert (tq, group) == want
+    assert T % tq == 0 and tq % 8 == 0 and 1 <= group <= n_max
+    assert tq * rep * group * 128 * 4 <= fd._CHUNK_SCORE_BYTES
+    for hs in (64, 128):
+        assert fd._chunk_vmem_bytes(tq * rep, group * 128, 128 // hs, 128,
+                                    2, 2) <= VMEM_LIMIT_BYTES
 
 
 def test_chunk_prefill_parity_int8():

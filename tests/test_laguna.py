@@ -253,6 +253,27 @@ def test_the_engine_emits_the_references_tokens(mv):
     assert "kv_rows_read_window" in last and "kv_rows_read_full" in last
 
 
+def test_a_chunk_with_no_tile_split_takes_the_masked_path(mv, monkeypatch):
+    """A window model whose chunk the kernel's tiles cannot cut (12 rows
+    in blocks of 4: no multiple of the 8-row step) still builds its engine
+    under FLASH_DECODE=on: the gate says why, the masked path carries the
+    call, and the tokens are those of the gather path."""
+    from distributed_pytorch_tpu.obs import paths
+    cfg, model, variables = mv
+    kw = dict(block_size=4, prefill_chunk=12, min_bucket=4, max_len=64)
+    assert wa._chunk_tiles(12, wa.ring_rows(20, 4), 20, 3, True)[:2] == (0, 0)
+    prompts = _prompts((30, 17), seed=5)
+    monkeypatch.setenv("FLASH_DECODE", "off")
+    want = _engine(model, variables, **kw).run(prompts, 4)
+    monkeypatch.setenv("FLASH_DECODE", "on")
+    eng = _engine(model, variables, **kw)
+    assert eng.run(prompts, 4) == want
+    q = jnp.zeros((1, 12, 6, 32))
+    assert "no tile split" in wa.window_flash_prefill_decline(
+        q, jnp.zeros((20 + 12, 128)), 2, 20)
+    assert "gather+naive" in str(paths.choices()["decode_attention"])
+
+
 def test_prefix_reuse_stands_down_for_the_window_state(mv):
     cfg, model, variables = mv
     eng = _engine(model, variables, prefix_cache=True)
@@ -306,20 +327,60 @@ def test_window_decode_kernel(window, ring):
             rtol=2e-5)
 
 
-@pytest.mark.parametrize("window,ring,off", [(20, 24, 0), (20, 24, 16),
-                                             (20, 24, 48), (16, 16, 32),
-                                             (40, 40, 64)])
-def test_window_chunk_kernel(window, ring, off):
-    T, nh, nkv, hs = 16, 6, 2, 64
-    key = jax.random.split(jax.random.PRNGKey(off + window), 3)
+# (window, ring, off, T, query tile or None, float32 score budget or None):
+# a 16-row chunk is one query tile whose five key views share ONE softmax
+# update (`_chunk_tiles` at 8-row views); then `off` < ring (the views left
+# of position 0 are masked, nothing was zeroed), a chunk whose slot's ring
+# had wrapped (`ring_logical` from row off % ring), and, with the tile and
+# the budget patched down (no caller sets them), four query tiles of 8 rows
+# whose windows take several steps: of each tile's four views two lie whole
+# inside the band of all its rows, one holds the causal edge, one the far
+_WINDOW_CHUNKS = [(20, 24, 0, 16, None, None), (20, 24, 16, 16, None, None),
+                  (20, 24, 48, 16, None, None), (16, 16, 32, 16, None, None),
+                  (40, 40, 64, 16, None, None), (20, 24, 8, 16, None, None),
+                  (20, 24, 40, 16, None, None), (24, 24, 64, 32, 8, 1),
+                  (24, 24, 8, 32, 8, 3 * 8 * 3 * 8 * 4),
+                  (20, 24, 72, 32, 8, None)]
+
+
+@pytest.mark.parametrize("window,ring,off,T,tile_q,score", _WINDOW_CHUNKS)
+def test_window_chunk_kernel(window, ring, off, T, tile_q, score,
+                             monkeypatch):
+    nh, nkv, hs = 6, 2, 64
+    key = jax.random.split(jax.random.PRNGKey(off + window), 4)
     q = jax.random.normal(key[0], (1, T, nh, hs))
-    keys = jax.random.normal(key[1], (ring + T, 128))
-    values = jax.random.normal(key[2], (ring + T, 128))
+    # the slot's ring as the engine holds it, position p at row p mod ring
+    # (rows of positions under 0: whatever the last occupant left)
+    held = jax.random.normal(key[3], (2, ring, 128))
+    keys, values = (jnp.concatenate([wa.ring_logical(held[i], off), rows])
+                    for i, rows in enumerate(
+                        jax.random.normal(key[1 + j], (T, 128))
+                        for j in range(2)))
+    call = wa.window_flash_prefill
+    if tile_q:
+        monkeypatch.setattr(wa, "_CHUNK_TILE_Q", tile_q)
+        if score:
+            monkeypatch.setattr(wa, "_CHUNK_SCORE_BYTES", score)
+        call = jax.jit(call.__wrapped__, static_argnames=(
+            "window", "scale", "n_kv_heads", "interpret"))
+        tq, tk, group, steps = wa._chunk_tiles(T, ring, window, 3, True)
+        assert (tq, tk) == (8, 8) and T // tq == 4
+        assert (group, steps) == {1: (1, 4), None: (4, 1)}.get(
+            score, (3, 2))
     kw = dict(window=window, scale=0.125, n_kv_heads=nkv)
-    got = wa.window_flash_prefill(q, keys, values, jnp.int32(off),
-                                  interpret=True, **kw)
+    got = call(q, keys, values, jnp.int32(off), interpret=True, **kw)
     want = wa.window_chunk(q, keys, values, jnp.int32(off), **kw)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    # and the XLA path against positions written out, one head
+    pos = off - ring + np.arange(ring + T)
+    for t in (0, T - 1):
+        seen = (pos >= 0) & (pos <= off + t) & (pos > off + t - window)
+        k0 = np.asarray(keys[:, :hs])
+        s_ = (k0 @ np.asarray(q[0, t, 0])) * 0.125
+        w = np.where(seen, np.exp(s_ - s_[seen].max()), 0.0)
+        np.testing.assert_allclose(
+            want[0, t, 0], (w / w.sum()) @ np.asarray(values[:, :hs]),
+            atol=2e-5, rtol=2e-5)
 
 
 def test_the_ring_keeps_the_last_real_rows():

@@ -389,9 +389,14 @@ def sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         return flash_attention_decline(q, k, v, causal=causal)
 
     def run_flash():
-        from distributed_pytorch_tpu.ops.flash_attention import \
-            flash_attention
-        paths.note("attention", "pallas flash", f"attn_impl={impl}")
+        from distributed_pytorch_tpu.ops.flash_attention import (
+            flash_attention, slab_plan)
+        plan, share = slab_plan(q.shape[1], k.shape[1], causal)
+        slabs = (f"{plan[1]} causal slabs of {plan[0]} rows a diagonal tile"
+                 if plan else "no slabs")
+        paths.note("attention", "pallas flash",
+                   f"attn_impl={impl}; {slabs}: {share:.1%} of the score "
+                   "square computed")
         if use_dropout:
             def fn(a, b, c, rng):
                 return flash_attention(a, b, c, scale=scale, causal=causal,

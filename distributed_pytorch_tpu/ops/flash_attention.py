@@ -25,7 +25,18 @@ single-gpu/model.py:149). Design (per the Pallas TPU playbook):
   buffer): the zero tail is always masked. Blocks strictly above the
   causal frontier are skipped: compute is predicated with `pl.when` and
   their index maps clamp to the last visible block so no fresh DMA is
-  issued for skipped tiles.
+  issued for skipped tiles. INSIDE a diagonal tile of a square causal
+  tiling the same skip is static: the tile is worked in row slabs of
+  `SLAB_W` rows that stop at the diagonal (`slab_plan`) — forward and dq
+  query slab c against keys [0, (c + 1) w), dkv key slab c against query
+  rows [c w, block_q) — plain slices of the VMEM refs unrolled at trace
+  time, no grid step, BlockSpec or DMA of their own. Four slabs of 256
+  compute 62.5% of a 1024 x 1024 tile. A call that is not causal, a
+  rectangular tiling, a tile below the diagonal and a tile under two slabs
+  keep the whole masked tile. A call of ONE tile (every training call up to
+  T = 1024) carries no state from tile to tile and writes its outputs
+  straight from the tile math: the m / l / accumulator scratch is for calls
+  of several tiles.
 * Backward = two kernels (FlashAttention-2): dq accumulates over kv tiles;
   dk/dv accumulate over q tiles; both recompute p from the saved
   logsumexp instead of storing probabilities.
@@ -72,9 +83,16 @@ from distributed_pytorch_tpu.compat import (VMEM_LIMIT_BYTES,
 # (`_pick_group`, the VMEM limit). The `block_q/k/h` arguments are for the
 # parity tests, which hold the kernels to the oracle at tilings small
 # enough to exercise the tile-to-tile paths; the program passes none.
+# SLAB_W: the rows of a causal slab inside a diagonal tile (`slab_plan`).
+# At the same shape the three kernels took 0.516 / 0.613 / 0.775 ms a call
+# (forward / dq / dkv) with slabs of 256 rows, 0.591 / 0.611 / 0.915 with
+# 128 (56% of the elements, but a slab's fixed cost eight times) and 0.500 /
+# 0.640 / 0.847 with 512, against 0.664 / 0.880 / 1.224 for the whole masked
+# tile (PERF.md section 6, PR 51).
 BLOCK_Q = 1024
 BLOCK_K = 1024
 BLOCK_H = 1
+SLAB_W = 256
 
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps masked rows NaN-free
 
@@ -92,13 +110,13 @@ def _first_visible_q(j, block_q: int, block_k: int):
     return jax.lax.div(j * block_k, block_q)
 
 
-def _mask_scores(s, i, j, block_q, block_k):
-    """Causal mask for one (g, block_q, block_k) score tile. Positions are
-    absolute: qpos = i*block_q + row, kpos = j*block_k + col; a query
-    attends keys with kpos <= qpos (reference model.py:225-226 triu
+def _mask_scores(s, q0, k0):
+    """Causal mask for one (g, rows, cols) score tile whose first row is
+    query position `q0` and first column key position `k0`, both absolute:
+    a query attends keys with kpos <= qpos (reference model.py:225-226 triu
     semantics with offset 0)."""
-    qpos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    kpos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+    qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
     return jnp.where(qpos >= kpos, s, _NEG_INF)
 
 
@@ -163,16 +181,14 @@ def _dropout_bits(seed0, seed1, row0, q0, k0, shape):
     return _mix_bits(seed0, seed1, row, qp, kp)
 
 
-def _dropout_mask(seed_ref, r, i, j, shape, block_q: int, block_k: int,
-                  rate: float):
-    """Scaled keep-mask for one (g, block_q, block_k) score tile,
+def _dropout_mask(seed_ref, at, shape, rate: float):
+    """Scaled keep-mask for one (g, rows, cols) score tile that starts `at`
+    = (attention row, query position, key position), all absolute;
     regenerated bit-identically in forward and both backward kernels.
     P(drop) = rate via a uint32 threshold; survivors are pre-scaled by
     1/(1-rate) (inverted dropout, the reference's
     F.scaled_dot_product_attention semantics)."""
-    g = shape[0]
-    bits = _dropout_bits(seed_ref[0], seed_ref[1], r * g, i * block_q,
-                         j * block_k, shape)
+    bits = _dropout_bits(seed_ref[0], seed_ref[1], *at, shape)
     return ((bits >= dropout_threshold(rate)).astype(jnp.float32)
             / (1.0 - rate))
 
@@ -263,102 +279,162 @@ _SHRINK_WARNED: set = set()
 # ---------------------------------------------------------------------------
 # tile math (the FlashAttention-2 numerics; the kernels below load and store)
 # ---------------------------------------------------------------------------
+# `at` = (attention row, query position, key position) of the first element
+# of the operands' score tile, absolute: what the causal mask and the
+# dropout bits are keyed by, so a tile and a slab of it are the same call on
+# other operands.
 
-def _fwd_tile(q, k, v, r, i, j, seed_ref, m_ref, l_ref, acc_ref, *, scale,
-              block_q, block_k, causal, rate):
-    """Online-softmax update for one (g, bq, D)x(g, bk, D) tile pair.
-    Operands stay in input dtype (bf16 on TPU): the MXU accumulates in f32
-    via preferred_element_type — casting inputs up would force slow fp32
-    MXU passes."""
+def _fwd_tile(q, k, v, at, seed_ref, prev, *, scale, causal, rate):
+    """Online-softmax update for one (g, bq, D)x(g, bk, D) tile pair:
+    `prev` = the rows' running (max m, normalizer l, f32 accumulator) after
+    the kv tiles before this one, None where this is the first; returns the
+    three after it. Operands stay in input dtype (bf16 on TPU): the MXU
+    accumulates in f32 via preferred_element_type — casting inputs up would
+    force slow fp32 MXU passes."""
     s = _bdot(q, k, trans_b=True) * scale               # (g, bq, bk) f32
     if causal:
-        s = _mask_scores(s, i, j, block_q, block_k)
-    m_prev, l_prev = m_ref[:], l_ref[:]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    m_ref[:] = m_new
+        s = _mask_scores(s, at[1], at[2])
+    m = jnp.max(s, axis=-1, keepdims=True)
+    if prev is not None:
+        m = jnp.maximum(prev[0], m)
+    p = jnp.exp(s - m)
     # normalizer accumulates the UNdropped p (torch drops the
     # already-normalized attention weights); only the value accumulation
     # sees the mask
-    l_ref[:] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    l = jnp.sum(p, axis=-1, keepdims=True)
     if rate > 0.0:
-        p = p * _dropout_mask(seed_ref, r, i, j, p.shape, block_q,
-                              block_k, rate)
-    acc_ref[:] = acc_ref[:] * alpha + _bdot(p.astype(v.dtype), v)
+        p = p * _dropout_mask(seed_ref, at, p.shape, rate)
+    acc = _bdot(p.astype(v.dtype), v)
+    if prev is not None:
+        alpha = jnp.exp(prev[0] - m)
+        l, acc = prev[1] * alpha + l, prev[2] * alpha + acc
+    return m, l, acc
 
 
-def _fwd_finalize(m_ref, l_ref, acc_ref):
+def _fwd_finalize(m, l, acc):
     """(normalized out (g, bq, D) f32, lse (g, bq, 1) f32)."""
-    l_safe = jnp.maximum(l_ref[:], 1e-30)
-    return acc_ref[:] / l_safe, m_ref[:] + jnp.log(l_safe)
+    l_safe = jnp.maximum(l, 1e-30)
+    return acc / l_safe, m + jnp.log(l_safe)
 
 
-def _dq_tile(q, k, v, do, lse, delta, r, i, j, seed_ref, dq_acc, *, scale,
-             block_q, block_k, causal, rate):
-    """dq accumulation for one tile: ds = p * (M/(1-r) * (dO V^T) - delta);
-    rowsum(dP*P) still equals rowsum(dO*O) = delta because O was computed
-    with the SAME mask."""
+def _dq_tile(q, k, v, do, lse, delta, at, seed_ref, *, scale, causal, rate):
+    """One tile's part of dq (unscaled, f32): ds = p * (M/(1-r) * (dO V^T)
+    - delta); rowsum(dP*P) still equals rowsum(dO*O) = delta because O was
+    computed with the SAME mask."""
     s = _bdot(q, k, trans_b=True) * scale
     if causal:
-        s = _mask_scores(s, i, j, block_q, block_k)
+        s = _mask_scores(s, at[1], at[2])
     p = jnp.exp(s - lse)                                # (g, bq, bk) f32
     dp = _bdot(do, v, trans_b=True)
     if rate > 0.0:
-        dp = dp * _dropout_mask(seed_ref, r, i, j, dp.shape, block_q,
-                                block_k, rate)
+        dp = dp * _dropout_mask(seed_ref, at, dp.shape, rate)
     ds = p * (dp - delta)
-    dq_acc[:] = dq_acc[:] + _bdot(ds.astype(k.dtype), k)
+    return _bdot(ds.astype(k.dtype), k)
 
 
-def _dkv_tile(q, k, v, do, lse, delta, r, i, j, seed_ref, dk_acc, dv_acc,
-              *, scale, block_q, block_k, causal, rate):
-    """dk/dv accumulation for one tile; the dropout mask is regenerated
-    with the same canonical (r, i, j) coords as forward/dq, NOT this
+def _dkv_tile(q, k, v, do, lse, delta, at, seed_ref, *, scale, causal,
+              rate):
+    """One tile's parts of (dk unscaled, dv), f32; the dropout mask is
+    regenerated from the same absolute coordinates as forward/dq, NOT this
     kernel's transposed grid order."""
     s = _bdot(q, k, trans_b=True) * scale               # (g, bq, bk) f32
     if causal:
-        s = _mask_scores(s, i, j, block_q, block_k)
+        s = _mask_scores(s, at[1], at[2])
     p = jnp.exp(s - lse)
+    dp = _bdot(do, v, trans_b=True)
     if rate > 0.0:
-        mask = _dropout_mask(seed_ref, r, i, j, p.shape, block_q, block_k,
-                             rate)
-        dv_acc[:] = dv_acc[:] + _bdot_t((p * mask).astype(do.dtype), do)
-        dp = _bdot(do, v, trans_b=True) * mask
+        mask = _dropout_mask(seed_ref, at, p.shape, rate)
+        dv = _bdot_t((p * mask).astype(do.dtype), do)
+        dp = dp * mask
     else:
-        dv_acc[:] = dv_acc[:] + _bdot_t(p.astype(do.dtype), do)
-        dp = _bdot(do, v, trans_b=True)
+        dv = _bdot_t(p.astype(do.dtype), do)
     ds = p * (dp - delta)
-    dk_acc[:] = dk_acc[:] + _bdot_t(ds.astype(q.dtype), q)
+    return _bdot_t(ds.astype(q.dtype), q), dv
+
+
+def _query_slabs(block: int, w: int) -> list:
+    """Forward and dq, a diagonal tile cut by query rows: slab c is rows
+    [c w, (c + 1) w) against keys [0, (c + 1) w). Each entry is (rows, keys,
+    the slab's first query and key position within the tile); none where
+    the call has no slab plan (`w` 0)."""
+    return [(pl.ds(c * w, w), pl.ds(0, (c + 1) * w), c * w, 0)
+            for c in range(block // w)] if w else []
+
+
+def _key_slabs(block: int, w: int) -> list:
+    """dkv, the same tile cut by keys: slab c is keys [c w, (c + 1) w)
+    under query rows [c w, block)."""
+    return [(pl.ds(c * w, block - c * w), pl.ds(c * w, w), c * w, c * w)
+            for c in range(block // w)] if w else []
+
+
+def _visit(i, j, visible, one_tile: bool, update, at, slabs: list):
+    """Emit the body of tile (q tile i, kv tile j): `update(rows, keys, at)`
+    slab by slab on a diagonal tile of a call that has a slab plan, over the
+    whole masked tile on every other tile the causal frontier lets through. A call of ONE tile is known at trace time: one
+    body is emitted, unpredicated."""
+    def whole():
+        update(slice(None), slice(None), at)
+
+    def by_slab():
+        for rows, keys, q0, k0 in slabs:
+            update(rows, keys, (at[0], at[1] + q0, at[2] + k0))
+
+    if one_tile:
+        (by_slab if slabs else whole)()
+    elif not slabs:
+        pl.when(visible)(whole)
+    else:
+        pl.when(jnp.logical_and(visible, i != j))(whole)
+        pl.when(i == j)(by_slab)
 
 
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
+# A call of one tile (`one_tile`: the whole sequence, every training call up
+# to T = BLOCK_Q) has no state to carry from tile to tile: its kernels write
+# their outputs straight from the tile math and leave the scratch alone.
 
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
-                m_ref, l_ref, *, scale, block_q, block_k, causal, rate):
+                m_ref, l_ref, *, scale, block_q, block_k, causal, rate,
+                slab, one_tile):
     r, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     last_j = _last_visible_kv(i, block_q, block_k) if causal \
         else pl.num_programs(2) - 1
+    at = (r * q_ref.shape[0], i * block_q, j * block_k)
 
-    @pl.when(j == 0)
-    def _():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    def update(rows, keys, at):
+        """One softmax update of `rows` of the q tile over `keys` of the
+        kv tile."""
+        prev = None if one_tile else (m_ref[:, rows], l_ref[:, rows],
+                                      acc_ref[:, rows])
+        m, l, acc = _fwd_tile(q_ref[:, rows], k_ref[:, keys], v_ref[:, keys],
+                              at, seed_ref, prev, scale=scale, causal=causal,
+                              rate=rate)
+        if one_tile:
+            o, lse = _fwd_finalize(m, l, acc)
+            o_ref[:, rows] = o.astype(o_ref.dtype)
+            lse_ref[:, rows] = lse
+        else:
+            m_ref[:, rows], l_ref[:, rows], acc_ref[:, rows] = m, l, acc
 
-    @pl.when(j <= last_j)
-    def _():
-        _fwd_tile(q_ref[:], k_ref[:], v_ref[:], r, i, j, seed_ref, m_ref,
-                  l_ref, acc_ref, scale=scale, block_q=block_q,
-                  block_k=block_k, causal=causal, rate=rate)
+    if not one_tile:
+        @pl.when(j == 0)
+        def _():
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+            m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+            l_ref[:] = jnp.zeros_like(l_ref)
 
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _():
-        o, lse = _fwd_finalize(m_ref, l_ref, acc_ref)
-        o_ref[:] = o.astype(o_ref.dtype)
-        lse_ref[:] = lse
+    _visit(i, j, j <= last_j, one_tile, update, at,
+           _query_slabs(block_q, slab))
+
+    if not one_tile:
+        @pl.when(j == pl.num_programs(2) - 1)
+        def _():
+            o, lse = _fwd_finalize(m_ref[:], l_ref[:], acc_ref[:])
+            o_ref[:] = o.astype(o_ref.dtype)
+            lse_ref[:] = lse
 
 
 _SEED_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
@@ -370,15 +446,16 @@ _SEED_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 # — 12 layers x 3 kernels in model.init, the memory plan's shape probe and
 # the train step cost the train cell 3.5-4 s of cached `setup_s`.
 _KERNEL_STATICS = ("scale", "block_q", "block_k", "g", "interpret", "causal",
-                   "rate")
+                   "rate", "slab")
 
 
 @functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
 def _fwd(q, k, v, seed, scale, block_q, block_k, g, interpret, causal=True,
-         rate=0.0):
+         rate=0.0, slab=0):
     """q (N, T, D) rows = flattened (B, H); k/v (Nkv, S, D) with
     rep = N // Nkv -> out (N, T, D), lse (N, T, 1). `seed` (2,) int32
-    feeds the in-kernel dropout PRNG (ignored at rate == 0)."""
+    feeds the in-kernel dropout PRNG (ignored at rate == 0). `slab`: rows
+    a slab of a diagonal tile (`slab_plan`), 0 for none."""
     N, T, D = q.shape
     S, Nkv = k.shape[1], k.shape[0]
     rep = N // Nkv
@@ -387,7 +464,8 @@ def _fwd(q, k, v, seed, scale, block_q, block_k, g, interpret, causal=True,
     kv_spec = _kv_spec(rep, g, block_q, block_k, D, causal)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, block_q=block_q,
-                          block_k=block_k, causal=causal, rate=rate),
+                          block_k=block_k, causal=causal, rate=rate,
+                          slab=slab, one_tile=nq == nk == 1),
         grid=(N // g, nq, nk),
         in_specs=[
             _SEED_SPEC,
@@ -424,53 +502,73 @@ def _fwd(q, k, v, seed, scale, block_q, block_k, g, interpret, causal=True,
 
 def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                    delta_ref, dq_ref, dq_acc, *, scale, block_q, block_k,
-                   causal, rate):
+                   causal, rate, slab, one_tile):
     r, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     last_j = _last_visible_kv(i, block_q, block_k) if causal \
         else pl.num_programs(2) - 1
+    at = (r * q_ref.shape[0], i * block_q, j * block_k)
 
-    @pl.when(j == 0)
-    def _():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
+    def update(rows, keys, at):
+        dq = _dq_tile(q_ref[:, rows], k_ref[:, keys], v_ref[:, keys],
+                      do_ref[:, rows], lse_ref[:, rows], delta_ref[:, rows],
+                      at, seed_ref, scale=scale, causal=causal, rate=rate)
+        if one_tile:
+            dq_ref[:, rows] = (dq * scale).astype(dq_ref.dtype)
+        else:
+            dq_acc[:, rows] = dq_acc[:, rows] + dq
 
-    @pl.when(j <= last_j)
-    def _():
-        _dq_tile(q_ref[:], k_ref[:], v_ref[:], do_ref[:], lse_ref[:],
-                 delta_ref[:], r, i, j, seed_ref, dq_acc, scale=scale,
-                 block_q=block_q, block_k=block_k, causal=causal, rate=rate)
+    if not one_tile:
+        @pl.when(j == 0)
+        def _():
+            dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _():
-        dq_ref[:] = (dq_acc[:] * scale).astype(dq_ref.dtype)
+    _visit(i, j, j <= last_j, one_tile, update, at,
+           _query_slabs(block_q, slab))
+
+    if not one_tile:
+        @pl.when(j == pl.num_programs(2) - 1)
+        def _():
+            dq_ref[:] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale,
-                    block_q, block_k, causal, rate):
+                    block_q, block_k, causal, rate, slab, one_tile):
     r, j, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     first_i = _first_visible_q(j, block_q, block_k) if causal else 0
+    at = (r * q_ref.shape[0], i * block_q, j * block_k)
 
-    @pl.when(i == 0)
-    def _():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+    def update(rows, keys, at):
+        dk, dv = _dkv_tile(q_ref[:, rows], k_ref[:, keys], v_ref[:, keys],
+                           do_ref[:, rows], lse_ref[:, rows],
+                           delta_ref[:, rows], at, seed_ref, scale=scale,
+                           causal=causal, rate=rate)
+        if one_tile:
+            dk_ref[:, keys] = (dk * scale).astype(dk_ref.dtype)
+            dv_ref[:, keys] = dv.astype(dv_ref.dtype)
+        else:
+            dk_acc[:, keys] = dk_acc[:, keys] + dk
+            dv_acc[:, keys] = dv_acc[:, keys] + dv
 
-    @pl.when(i >= first_i)
-    def _():
-        _dkv_tile(q_ref[:], k_ref[:], v_ref[:], do_ref[:], lse_ref[:],
-                  delta_ref[:], r, i, j, seed_ref, dk_acc, dv_acc,
-                  scale=scale, block_q=block_q, block_k=block_k,
-                  causal=causal, rate=rate)
+    if not one_tile:
+        @pl.when(i == 0)
+        def _():
+            dk_acc[:] = jnp.zeros_like(dk_acc)
+            dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    @pl.when(i == pl.num_programs(2) - 1)
-    def _():
-        dk_ref[:] = (dk_acc[:] * scale).astype(dk_ref.dtype)
-        dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
+    _visit(i, j, i >= first_i, one_tile, update, at,
+           _key_slabs(block_k, slab))
+
+    if not one_tile:
+        @pl.when(i == pl.num_programs(2) - 1)
+        def _():
+            dk_ref[:] = (dk_acc[:] * scale).astype(dk_ref.dtype)
+            dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
-def _bwd_impl(scale, block_q, block_k, g, interpret, causal, rate, res, do,
-              dlse=None):
+def _bwd_impl(scale, block_q, block_k, g, interpret, causal, rate, slab,
+              res, do, dlse=None):
     """Shared backward: dlse (N, T, 1) is the cotangent of the logsumexp
     output when the caller differentiates through it (the ring merge does;
     plain flash_attention passes None). Math: with L = sum(do*out) +
@@ -493,7 +591,8 @@ def _bwd_impl(scale, block_q, block_k, g, interpret, causal, rate, res, do,
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, block_q=block_q,
-                          block_k=block_k, causal=causal, rate=rate),
+                          block_k=block_k, causal=causal, rate=rate,
+                          slab=slab, one_tile=nq == nk == 1),
         grid=(N // g, nq, nk),
         in_specs=[
             _SEED_SPEC,
@@ -528,7 +627,8 @@ def _bwd_impl(scale, block_q, block_k, g, interpret, causal, rate, res, do,
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, block_q=block_q,
-                          block_k=block_k, causal=causal, rate=rate),
+                          block_k=block_k, causal=causal, rate=rate,
+                          slab=slab, one_tile=nq == nk == 1),
         grid=(N // g, nk, nq),
         in_specs=[
             _SEED_SPEC,
@@ -572,25 +672,26 @@ def _bwd_impl(scale, block_q, block_k, g, interpret, causal, rate, res, do,
 # to plain FlashAttention-2). `seed` is a traced (2,) int32 operand (no
 # cotangent); `rate` is static.
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11))
 def _flash_lse(q, k, v, seed, scale, block_q, block_k, g, interpret,
-               causal, rate):
+               causal, rate, slab):
     return _fwd(q, k, v, seed, scale, block_q, block_k, g, interpret,
-                causal, rate)
+                causal, rate, slab)
 
 
 def _flash_lse_fwd(q, k, v, seed, scale, block_q, block_k, g, interpret,
-                   causal, rate):
+                   causal, rate, slab):
     out, lse = _fwd(q, k, v, seed, scale, block_q, block_k, g, interpret,
-                    causal, rate)
+                    causal, rate, slab)
     return (out, lse), (q, k, v, seed, out, lse)
 
 
 def _flash_lse_bwd(scale, block_q, block_k, g, interpret, causal, rate,
-                   res, cts):
+                   slab, res, cts):
     do, dlse = cts
     return _bwd_impl(scale, block_q, block_k, g, interpret, causal, rate,
-                     res, do, dlse=dlse)
+                     slab, res, do, dlse=dlse)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
@@ -606,6 +707,30 @@ def _pick_block(n: int, preferred: int) -> int:
     while b > 8 and (n % b != 0):
         b -= 8
     return b if n % b == 0 else 0
+
+
+def slab_plan(T: int, S: int, causal: bool = True, block_q: int = 0,
+              block_k: int = 0):
+    """(plan, share) of a call over T queries and S keys. `plan` is
+    `(w, n_slabs)` where the call's diagonal tiles are worked in `n_slabs`
+    row slabs of `w` = SLAB_W rows that stop at the diagonal, and None where
+    they are not: a call that is not causal, a tiling that is not square, a
+    tile that `w` does not cut into two slabs or more. `share` is the part
+    of the T x S score square the kernels compute: every tile the causal
+    frontier lets through, a slabbed diagonal tile counted at (n + 1) / 2n
+    of its square. Static; the kernels, the dispatcher's path note and the
+    tests read this one function."""
+    bq = block_q or _pick_block(T, BLOCK_Q)
+    bk = block_k or _pick_block(S, BLOCK_K)
+    nq, nk = T // bq, S // bk
+    if not causal:
+        return None, 1.0
+    tiles = sum(min(nk, (i * bq + bq - 1) // bk + 1) for i in range(nq))
+    n = bq // SLAB_W
+    if bq != bk or bq % SLAB_W or n < 2:
+        return None, tiles / (nq * nk)
+    skipped = min(nq, nk) * (n - 1) / (2 * n)
+    return (SLAB_W, n), (tiles - skipped) / (nq * nk)
 
 
 def flash_attention_decline(q, k, v, *, causal: bool = True):
@@ -693,8 +818,9 @@ def flash_attention_lse(q, k, v, *, scale: float, causal: bool = True,
     qt = jnp.transpose(q, (0, 2, 1, 3)).reshape(B * nh, T, hs)
     kt = jnp.transpose(k, (0, 2, 1, 3)).reshape(B * nkv, S, hs)
     vt = jnp.transpose(v, (0, 2, 1, 3)).reshape(B * nkv, S, hs)
+    plan, _ = slab_plan(T, S, causal, block_q, block_k)
     out, lse = _flash_lse(qt, kt, vt, seed, float(scale), block_q, block_k,
-                          g, interpret, causal, rate)
+                          g, interpret, causal, rate, plan[0] if plan else 0)
     out = jnp.transpose(out.reshape(B, nh, T, hs), (0, 2, 1, 3))
     lse = jnp.transpose(lse.reshape(B, nh, T), (0, 2, 1))
     return out, lse

@@ -170,6 +170,10 @@ CASES = {
     "flash_bwd_1x8192": (lambda: _flash(1, 8192, True),
                          ["flash_bwd_dq", "flash_bwd_dkv"]),
     # a full-lane head: 8 heads of 128 (twice the tile bytes of 64-wide)
+    # two q tiles: the slabbed diagonal body and the whole masked body
+    # under `pl.when` in ONE kernel, the smallest shape that holds both
+    "flash_bwd_4x2048": (lambda: _flash(4, 2048, True),
+                         ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]),
     "flash_bwd_2x1024_8x128": (lambda: _flash(2, 1024, True, 8, 128),
                                ["flash_fwd", "flash_bwd_dq",
                                 "flash_bwd_dkv"]),
@@ -299,7 +303,9 @@ def test_auto_trains_on_the_flash_kernels(shape, v5e, monkeypatch):
 
     paths.reset()
     text = _compile(bwd, [(shape, BF16)] * 3, v5e).as_text()
-    assert paths.choices()["attention"] == "pallas flash (attn_impl=auto)"
+    assert paths.choices()["attention"] == (
+        "pallas flash (attn_impl=auto; 4 causal slabs of 256 rows a diagonal "
+        "tile: 62.5% of the score square computed)")
     census = paths.kernel_census(text)
     for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
         assert census.get(name) == 1, census

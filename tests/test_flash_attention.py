@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from distributed_pytorch_tpu.ops import flash_attention as fa
 from distributed_pytorch_tpu.ops.attention_core import _naive_sdpa
 from distributed_pytorch_tpu.ops.flash_attention import (
     flash_attention, flash_attention_usable)
@@ -25,18 +26,40 @@ def rand_qkv(key, B, T, S, nh, nkv, hs, dtype=jnp.float32):
     return q, k, v
 
 
+def slabs_of(monkeypatch, w, T, S, block_q=0, block_k=0, causal=True):
+    """Patch the slab width to `w` rows (0: leave the module's) and return
+    the call's slab plan. The kernels take the width as a static argument,
+    so a patched constant is seen whatever an earlier test traced."""
+    if w:
+        monkeypatch.setattr(fa, "SLAB_W", w)
+    return fa.slab_plan(T, S, causal, block_q, block_k)[0]
+
+
 CASES = [
-    # (T, S, nh, nkv, hs, block)
-    (128, 128, 4, 4, 32, 64),     # MHA, small head dim
-    (256, 256, 4, 2, 64, 128),    # GQA group 2
-    (128, 128, 4, 1, 64, 64),     # MQA
-    (64, 256, 2, 2, 64, 64),      # prefill: S > T (cache buffer tail masked)
-    (96, 96, 2, 2, 64, 32),       # non-power-of-two T, odd block split
+    # (T, S, nh, nkv, hs, block, slab rows (0: SLAB_W as it is), slabs a
+    #  diagonal tile (0: the whole masked tile))
+    (128, 128, 4, 4, 32, 64, 0, 0),     # MHA, small head dim
+    (256, 256, 4, 2, 64, 128, 0, 0),    # GQA group 2
+    (128, 128, 4, 1, 64, 64, 0, 0),     # MQA
+    (64, 256, 2, 2, 64, 64, 0, 0),      # prefill: S > T (cache tail masked)
+    (96, 96, 2, 2, 64, 32, 0, 0),       # non-power-of-two T, odd block split
+    # causal row slabs inside a diagonal tile
+    (256, 256, 4, 4, 32, 256, 128, 2),  # ONE tile of two slabs, MHA
+    (512, 512, 2, 2, 64, 512, 0, 2),    # ONE tile at the module's own width
+    (256, 256, 4, 2, 64, 128, 32, 4),   # GQA, a diagonal and a lower tile
+    (128, 128, 4, 1, 64, 64, 16, 4),    # MQA, likewise
+    (128, 256, 2, 2, 32, 64, 32, 2),    # S > T, equal blocks: tiles past
+                                        # the diagonal one are skipped
+    (96, 96, 2, 2, 64, 96, 32, 3),      # three slabs
+    (64, 64, 2, 2, 32, 32, 32, 0),      # a tile under two slabs: whole
 ]
 
 
-@pytest.mark.parametrize("T,S,nh,nkv,hs,block", CASES)
-def test_forward_matches_naive(T, S, nh, nkv, hs, block):
+@pytest.mark.parametrize("T,S,nh,nkv,hs,block,slab,n_slabs", CASES)
+def test_forward_matches_naive(T, S, nh, nkv, hs, block, slab, n_slabs,
+                               monkeypatch):
+    plan = slabs_of(monkeypatch, slab, T, S, block, block)
+    assert (plan[1] if plan else 0) == n_slabs
     q, k, v = rand_qkv(jax.random.PRNGKey(0), 2, T, S, nh, nkv, hs)
     scale = 1.0 / hs ** 0.5
     out = flash_attention(q, k, v, scale=scale, block_q=block, block_k=block,
@@ -46,16 +69,29 @@ def test_forward_matches_naive(T, S, nh, nkv, hs, block):
                                rtol=2e-5, atol=2e-5)
 
 
+# id -> (block_q, block_k, slab rows, slabs a diagonal tile)
+BWD_TILINGS = {
+    "64x32": (64, 32, 16, 0),              # rectangular: no slabs by the rule
+    "one_tile_4_slabs": (128, 128, 32, 4),
+    "two_tiles_2_slabs": (64, 64, 32, 2),  # a diagonal and a lower tile:
+                                           # both bodies in one kernel
+}
+
+
+@pytest.mark.parametrize("tiling", list(BWD_TILINGS))
 @pytest.mark.parametrize("nh,nkv,hs", [(4, 4, 32), (4, 2, 64), (8, 1, 16)],
                          ids=["mha_32", "gqa2_64", "mqa_16"])
-def test_backward_matches_naive(nh, nkv, hs):
+def test_backward_matches_naive(nh, nkv, hs, tiling, monkeypatch):
     T = 128
+    bq, bk, slab, n_slabs = BWD_TILINGS[tiling]
+    plan = slabs_of(monkeypatch, slab, T, T, bq, bk)
+    assert (plan[1] if plan else 0) == n_slabs
     q, k, v = rand_qkv(jax.random.PRNGKey(1), 2, T, T, nh, nkv, hs)
     scale = 1.0 / hs ** 0.5
     w = jax.random.normal(jax.random.PRNGKey(2), q.shape)
 
     def loss_flash(q, k, v):
-        out = flash_attention(q, k, v, scale=scale, block_q=64, block_k=32,
+        out = flash_attention(q, k, v, scale=scale, block_q=bq, block_k=bk,
                               interpret=True)
         return jnp.sum(out * w)
 
@@ -158,10 +194,13 @@ def _naive_out_lse(q, k, v, scale, causal):
 
 
 @pytest.mark.parametrize("causal", [True, False])
-def test_flash_lse_matches_naive(causal):
+def test_flash_lse_matches_naive(causal, monkeypatch):
     """(out, lse) parity for both masking modes — lse is the ring merge's
-    contract (ops/ring_attention.py)."""
+    contract (ops/ring_attention.py). The causal call's one tile is worked
+    in four slabs (lse stays the true logsumexp), the full one in none."""
     from distributed_pytorch_tpu.ops.flash_attention import flash_attention_lse
+    plan = slabs_of(monkeypatch, 16, 64, 64, causal=causal)
+    assert plan == ((16, 4) if causal else None)
     q, k, v = rand_qkv(jax.random.PRNGKey(3), 2, 64, 64, 4, 2, 16)
     scale = 0.25
     ref_o, ref_l = _naive_out_lse(q, k, v, scale, causal)
@@ -173,11 +212,15 @@ def test_flash_lse_matches_naive(causal):
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("slab", [0, 8], ids=["whole", "slabs_of_8"])
 @pytest.mark.parametrize("causal", [True, False])
-def test_flash_lse_gradients_including_dlse(causal):
+def test_flash_lse_gradients_including_dlse(causal, slab, monkeypatch):
     """A loss that touches BOTH outputs: the custom vjp must fold d/dlse
-    into the delta term correctly (ds = p*(dp - delta + dlse))."""
+    into the delta term correctly (ds = p*(dp - delta + dlse)), in a
+    slabbed tile's backward as in a whole one's."""
     from distributed_pytorch_tpu.ops.flash_attention import flash_attention_lse
+    plan = slabs_of(monkeypatch, slab, 32, 32, causal=causal)
+    assert plan == ((8, 4) if causal and slab else None)
     q, k, v = rand_qkv(jax.random.PRNGKey(4), 1, 32, 32, 2, 2, 16)
     scale = 0.25
     w = jax.random.normal(jax.random.PRNGKey(5), q.shape)
@@ -200,11 +243,13 @@ def test_flash_lse_gradients_including_dlse(causal):
 
 
 @pytest.mark.parametrize("bq,bk", [(32, 64), (64, 32), (128, 64)])
-def test_rectangular_blocks_fwd_bwd(bq, bk):
+def test_rectangular_blocks_fwd_bwd(bq, bk, monkeypatch):
     """block_q != block_k exercises the causal-frontier math on
     rectangular tiles (_last_visible_kv/_first_visible_q and the
-    DMA-clamp index maps)."""
+    DMA-clamp index maps). No tile of a rectangular tiling is slabbed,
+    however narrow the slab."""
     T, nh, nkv, hs = 128, 4, 2, 32
+    assert slabs_of(monkeypatch, 16, T, T, bq, bk) is None
     q, k, v = rand_qkv(jax.random.PRNGKey(5), 2, T, T, nh, nkv, hs)
     scale = 1.0 / hs ** 0.5
     w = jax.random.normal(jax.random.PRNGKey(6), q.shape)
@@ -226,11 +271,15 @@ def test_rectangular_blocks_fwd_bwd(bq, bk):
                                    rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("bh", [2, 4, 8])
-def test_row_group_blocking_fwd_bwd(bh):
+@pytest.mark.parametrize("bh,slab", [(2, 0), (4, 0), (8, 0), (2, 16),
+                                     (4, 32)])
+def test_row_group_blocking_fwd_bwd(bh, slab, monkeypatch):
     """block_h > 1 batches several (batch, head) rows per grid step (the
-    grid-overhead fix, PERF.md round 4); MHA only — parity incl. grads."""
+    grid-overhead fix, PERF.md round 4); MHA only — parity incl. grads.
+    A slab of a diagonal tile rides the same leading row axis."""
     B, T, nh, hs = 2, 128, 4, 32
+    plan = slabs_of(monkeypatch, slab, T, T, 64, 64)
+    assert plan == ((slab, 64 // slab) if slab else None)
     q, k, v = rand_qkv(jax.random.PRNGKey(7), B, T, T, nh, nh, hs)
     scale = 1.0 / hs ** 0.5
     w = jax.random.normal(jax.random.PRNGKey(8), q.shape)
@@ -342,9 +391,13 @@ class TestDropout:
                                    atol=0.05)
         assert np.abs(mean - np.asarray(base)).mean() < 0.15
 
+    @pytest.mark.parametrize("slab", [0, 8], ids=["whole", "slabs_of_8"])
     @pytest.mark.parametrize("nh,nkv", [(4, 4), (4, 2)])
-    def test_dropout_grads_vs_finite_differences(self, nh, nkv):
+    def test_dropout_grads_vs_finite_differences(self, nh, nkv, slab,
+                                                 monkeypatch):
         from jax.test_util import check_grads
+        plan = slabs_of(monkeypatch, slab, 32, 32, 16, 16)
+        assert plan == ((8, 2) if slab else None)
         q, k, v = rand_qkv(jax.random.PRNGKey(6), 1, 32, 32, nh, nkv, 32)
         rng = jax.random.PRNGKey(11)
 
@@ -399,21 +452,62 @@ class TestDropout:
                                    atol=2e-5)
 
 
-@pytest.mark.parametrize("bq,bk,bh", [(32, 32, 1), (16, 64, 4), (64, 16, 8)])
-def test_dropout_same_mask_whatever_the_tiling(bq, bk, bh):
+@pytest.mark.parametrize("bq,bk,bh,slab", [
+    (32, 32, 1, 0), (16, 64, 4, 0), (64, 16, 8, 0),
+    (64, 64, 1, 16),    # the one tile itself, in four slabs
+    (32, 32, 2, 16),    # two slabs a diagonal tile, a whole tile below
+])
+def test_dropout_same_mask_whatever_the_tiling(bq, bk, bh, slab, monkeypatch):
     """The dropout bits are keyed on the absolute (row, query, key)
-    position, so the q/kv tile sizes and the row group must not move the
-    mask: every tiling drops the weights the one-tile call drops. (A
-    different mask moves outputs by O(1); the tolerance is the online
-    softmax's rounding between tilings.)"""
+    position, so the q/kv tile sizes, the row group and a tile's slabs must
+    not move the mask: every tiling drops the weights the one-tile call
+    drops. (A different mask moves outputs by O(1); the tolerance is the
+    online softmax's rounding between tilings.)"""
     q, k, v = rand_qkv(jax.random.PRNGKey(5), 2, 64, 64, 4, 4, 32)
     f = functools.partial(flash_attention, q, k, v, scale=0.18,
                           dropout_rate=0.3, dropout_rng=jax.random.PRNGKey(9),
                           interpret=True)
+    assert fa.slab_plan(64, 64, True, 64, 64)[0] is None
     one_tile = f(block_q=64, block_k=64, block_h=1)
+    plan = slabs_of(monkeypatch, slab, 64, 64, bq, bk)
+    assert plan == ((slab, bq // slab) if slab else None)
     np.testing.assert_allclose(
         np.asarray(f(block_q=bq, block_k=bk, block_h=bh)),
         np.asarray(one_tile), rtol=2e-5, atol=2e-5)
+
+
+# id -> ((T, S, causal, block_q, block_k), plan, share of the T x S square)
+PLAN_CASES = {
+    "train_cell": ((1024, 1024, True, 0, 0), (256, 4), 0.625),
+    "two_tiles": ((2048, 2048, True, 0, 0), (256, 4), 0.5625),
+    "longer_buffer": ((1024, 2048, True, 0, 0), (256, 4), 0.3125),
+    "T512_two_slabs": ((512, 512, True, 0, 0), (256, 2), 0.75),
+    "T768_three_slabs": ((768, 768, True, 0, 0), (256, 3), 2 / 3),
+    "not_causal": ((1024, 1024, False, 0, 0), None, 1.0),
+    "rectangular": ((1024, 1024, True, 512, 1024), None, 1.0),
+    "rectangular_skips_tiles": ((1024, 1024, True, 256, 512), None, 0.75),
+    "under_two_slabs": ((256, 256, True, 0, 0), None, 1.0),
+    "tile_not_cut_by_the_slab": ((640, 640, True, 0, 0), None, 1.0),
+    "parity_tiles_32": ((64, 64, True, 32, 32), None, 0.75),
+}
+
+
+@pytest.mark.parametrize("case", list(PLAN_CASES))
+def test_slab_plan(case):
+    """The one static function the kernels, the dispatcher's note and these
+    tests read: which calls work their diagonal tiles in slabs, and how
+    much of the score square a call computes."""
+    args, plan, share = PLAN_CASES[case]
+    got_plan, got_share = fa.slab_plan(*args)
+    assert got_plan == plan
+    assert got_share == pytest.approx(share)
+
+
+@pytest.mark.parametrize("w,share", [(256, 0.625), (128, 0.5625),
+                                     (512, 0.75)])
+def test_slab_plan_share_by_width(w, share, monkeypatch):
+    monkeypatch.setattr(fa, "SLAB_W", w)
+    assert fa.slab_plan(1024, 1024) == ((w, 1024 // w), share)
 
 
 def test_pallas_dp_mesh_shard_map_wrap(monkeypatch):
@@ -470,18 +564,19 @@ def test_pallas_dp_mesh_shard_map_wrap(monkeypatch):
 # id -> (mesh plan | None, q/k/v shape overrides, sdpa kwargs,
 #        expected "attention" choice: exact string, or (path, reason part),
 #        or None for "no note")
+# the kernel's note says how far the causal slabs engaged (`slab_plan`)
+FLASH = ("pallas flash (attn_impl=auto; {} causal slabs of 256 rows a "
+         "diagonal tile: {} of the score square computed)")
 AUTO_CASES = {
-    "train_no_mesh": (None, {}, {}, "pallas flash (attn_impl=auto)"),
-    "train_one_device_mesh": (dict(), {}, {},
-                              "pallas flash (attn_impl=auto)"),
-    "train_data_mesh": (dict(data=4), {}, {},
-                        "pallas flash (attn_impl=auto)"),
-    "train_T512": (None, dict(T=512), {}, "pallas flash (attn_impl=auto)"),
+    "train_no_mesh": (None, {}, {}, FLASH.format(4, "62.5%")),
+    "train_one_device_mesh": (dict(), {}, {}, FLASH.format(4, "62.5%")),
+    "train_data_mesh": (dict(data=4), {}, {}, FLASH.format(4, "62.5%")),
+    "train_T512": (None, dict(T=512), {},
+                   FLASH.format(2, "75.0%")),
     "train_T256": (None, dict(T=256), {},
                    ("xla", "256 keys < 512: XLA's fused attention measured "
                            "faster")),
-    "gpt2xl_25_heads": (None, dict(B=2, nh=25), {},
-                        "pallas flash (attn_impl=auto)"),
+    "gpt2xl_25_heads": (None, dict(B=2, nh=25), {}, FLASH.format(4, "62.5%")),
     "model_axis_live": (dict(data=2, model=2), {}, {},
                         ("xla", "mesh axis 'model' is live")),
     "pipe_axis_live": (dict(data=2, pipe=2), {}, {},
@@ -495,9 +590,9 @@ AUTO_CASES = {
                     ("xla", "head dim 60 is not a sublane (8) multiple")),
     # beyond the XLA memory guard the unmeasured calls take the kernel too
     "model_axis_live_8192_keys": (dict(data=2, model=2), dict(B=2, T=8192),
-                                  {}, "pallas flash (attn_impl=auto)"),
+                                  {}, FLASH.format(4, "51.6%")),
     "decode_8192_keys": (None, dict(B=2, T=8192), dict(decode=True),
-                         "pallas flash (attn_impl=auto)"),
+                         FLASH.format(4, "51.6%")),
 }
 
 

@@ -4,7 +4,9 @@
 
 One process, which imports JAX itself and starts no child. It loads, warms
 up, measures for `--seconds`, prints one JSON object as the last line of
-its standard output and exits 0. Without an accelerator of a kind the
+its standard output (its last key, `compared`, and the last lines on standard
+error: every number `correct` was decided from beside its limit) and exits
+0. Without an accelerator of a kind the
 peaks table knows, or with fewer chips than the cell asks for, it prints no
 result and exits 3.
 """
@@ -30,7 +32,7 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args(argv)
 
-    from benchmark.lib import harness, peaks
+    from benchmark.lib import compared, harness, peaks
     _say("interpreter up")
     bench = harness.load_benchmark()
     try:
@@ -83,8 +85,13 @@ def main(argv=None) -> int:
         device["busy_s"] = tr["busy_s"]
         device["window_s"] = tr["window_s"]
         line["breakdown"] = tr["breakdown"]
+    # every number `correct` was decided from beside its limit: the last
+    # key of the line and the last lines on standard error
+    line["compared"] = compared.of_line(out["compared"])
     sys.stdout.flush()
     print(json.dumps(line), flush=True)
+    print("\n".join(compared.lines(out["compared"])), file=sys.stderr,
+          flush=True)
     return 0
 
 

@@ -34,7 +34,7 @@ import numpy as np
 
 from benchmark.lib import flops as flops_lib
 from benchmark.lib import compiles, harness, peaks, reference, stats, synth
-from benchmark.lib import trace_reduce
+from benchmark.lib import compared, trace_reduce
 
 # imported with the runner, which run.py resolves BEFORE it touches the
 # chip: Python imports run 2.5x slower once the TPU runtime's threads are up
@@ -343,6 +343,8 @@ def run(ctx: dict) -> dict:
         f"{LOGIT_TOLERANCE}); top-1 agrees on {ref['top1_agree']} of "
         f"{ref['tokens']}")
     return {"correct": bool(ref["ok"] and short == 0),
+            "compared": compared.budgets(short) + [compared.entry(
+                "worst_gap", ref["worst_gap"], LOGIT_TOLERANCE, "at_most")],
             "attempted": attempted, "failed": failed,
             "end_to_end": e2e, "observations": obs,
             "memory_peak_bytes": marks["memory_peak"]}

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmark.lib import flops_hybrid, reference_hybrid, stats, synth
-from benchmark.lib import compiles, harness, trace_reduce
+from benchmark.lib import compared, compiles, harness, trace_reduce
 from benchmark.runners.serve_closed import (TimedEngine, _drive,
                                             warm_programs)
 
@@ -445,6 +445,8 @@ def run(ctx: dict) -> dict:
         f"{[round(x, 5) for x in path['by_sequence']]}, worst position "
         f"{path['worst']:.4f}")
     return {"correct": bool(ref["ok"] and path["ok"] and short == 0),
+            "compared": compared.budgets(short)
+            + compared.engine_tokens(ref, lim) + compared.cache_path(path, lim),
             "attempted": attempted, "failed": failed,
             "end_to_end": e2e, "observations": obs,
             "memory_peak_bytes": marks["memory_peak"]}

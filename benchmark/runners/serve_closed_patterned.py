@@ -43,7 +43,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmark.lib import compiles, harness, peaks, stats, synth, trace_reduce
+from benchmark.lib import (compared, compiles, harness, peaks, stats, synth,
+                           trace_reduce)
 from benchmark.runners.serve_closed import (_spaced, warm_programs)
 from benchmark.runners.serve_closed_granite import (GraniteCounts,
                                                     expert_counters)
@@ -320,10 +321,9 @@ def cache_path_check(ctx, model, llm: dict, variables, vocab: int,
     by_sequence = [float(np.median(e)) for e in errs]
     errs = np.concatenate(errs)
     lim = t["reference_limits"]
-    return {"median": float(np.median(errs)), "worst": float(errs.max()),
-            "positions": int(errs.size), "by_sequence": by_sequence,
-            "ok": float(np.median(errs)) <= lim["logit_error_median"]
-            and max(by_sequence) <= lim["logit_error_sequence"]}
+    got = {"median": float(np.median(errs)), "worst": float(errs.max()),
+           "positions": int(errs.size), "by_sequence": by_sequence}
+    return {**got, "ok": all(c["ok"] for c in compared.cache_path(got, lim))}
 
 
 _MIXER_MODULES = {"M": "ssm", "C": "conv", "E": "moe", "F": "mlp",
@@ -488,14 +488,13 @@ def step_programs_check(ctx, engine, llm: dict, variables, vocab: int,
         for form, e in b.items():
             by_kind.setdefault(kind, {}).setdefault(form, 0.0)
             by_kind[kind][form] = max(by_kind[kind][form], e)
-    lim = ctx["traffic"]["reference_limits"]["step_error_median"]
-    return {"by_block": by_block, "by_kind": by_kind,
-            "programs": made["programs"],
-            "rows": {form: sum(len(k[0][0]) for k in kept)
-                     for form, kept in rows.items()},
-            "judged_slots": sorted(history),
-            "ok": all(e <= lim[kind] for kind, b in by_kind.items()
-                      for e in b.values())}
+    got = {"by_block": by_block, "by_kind": by_kind,
+           "programs": made["programs"],
+           "rows": {form: sum(len(k[0][0]) for k in kept)
+                    for form, kept in rows.items()},
+           "judged_slots": sorted(history)}
+    return {**got, "ok": all(c["ok"] for c in compared.step_programs(
+        got, ctx["traffic"]["reference_limits"]))}
 
 
 def engine_outputs(ctx, engine, vocab: int):
@@ -555,16 +554,15 @@ def reference_check(ctx, engine, llm: dict, variables, vocab: int,
     mean_gap = float(np.mean(np.minimum(np.concatenate(gaps),
                                         lim["gap_cap"])))
     tokens = n_new * len(gaps)
-    return {"worst_gap": float(max(g.max() for g in gaps)),
-            "top1_agree": agree, "tokens": tokens,
-            "echo_share": echoed / tokens,
-            "shares": shares, "share": share, "mean_gap": mean_gap,
-            "repeat_share": repeat,
-            "ok": share >= lim["token_share"]
-            and min(shares) >= lim["sequence_share"]
-            and mean_gap <= lim["mean_gap"]
-            and repeat >= lim["repeat_share"]
-            and echoed / tokens <= lim["echo_share"]}
+    got = {"worst_gap": float(max(g.max() for g in gaps)),
+           "top1_agree": agree, "tokens": tokens,
+           "echo_share": echoed / tokens,
+           "shares": shares, "share": share, "mean_gap": mean_gap,
+           "repeat_share": repeat}
+    # held to the limits the mix names (`compared.engine_tokens`): a mix
+    # that names no `sequence_share` reports the sequences and holds none
+    return {**got, "ok": all(c["ok"]
+                             for c in compared.engine_tokens(got, lim))}
 
 
 def _say_engine_tokens(say, lim: dict, ref: dict) -> None:
@@ -572,7 +570,8 @@ def _say_engine_tokens(say, lim: dict, ref: dict) -> None:
         f"within {lim['logit_tolerance']} deviations of the reference "
         f"maximum {ref['share']:.4f} (at least {lim['token_share']}), by "
         f"sequence {[round(x, 3) for x in ref['shares']]} (each at least "
-        f"{lim['sequence_share']}); mean gap, each capped at "
+        f"{lim.get('sequence_share', 'nothing: not held')}); mean gap, each "
+        f"capped at "
         f"{lim['gap_cap']}, {ref['mean_gap']:.5f} (within "
         f"{lim['mean_gap']}); the two repeated prompts emit the first "
         f"run's tokens again in {ref['repeat_share']:.4f} of their "
@@ -606,13 +605,17 @@ def _say_step_programs(say, lim: dict, got: dict) -> None:
 
 
 #: What a traffic file's `reference_procedures` may name: (the check over
-#: (ctx, engine, llm, variables, vocab), how its reading is said).
+#: (ctx, engine, llm, variables, vocab), how its reading is said, its
+#: numbers beside their limits for the result line).
 PROCEDURES = {
-    "engine_tokens_full_house": (reference_check, _say_engine_tokens),
+    "engine_tokens_full_house": (reference_check, _say_engine_tokens,
+                                 compared.engine_tokens),
     "cache_path": (lambda ctx, engine, llm, variables, vocab:
                    cache_path_check(ctx, engine.model, llm, variables,
-                                    vocab), _say_cache_path),
-    "step_programs": (step_programs_check, _say_step_programs),
+                                    vocab), _say_cache_path,
+                   compared.cache_path),
+    "step_programs": (step_programs_check, _say_step_programs,
+                      compared.step_programs),
 }
 
 
@@ -624,7 +627,7 @@ def window_stalls(t_open: float, t_close: float) -> list:
     """The stalled turns the program's always-on step ring booked for the
     engine INSIDE the window (`obs.flight.stall_log`, on the benchmark's
     clock): what a run that made fewer steps than its neighbours lost them
-    to. The `stall_*.lfm2` metrics read the process's life and a traced run
+    to. The `stall_*.serve` metrics read the process's life and a traced run
     alone; this is said in every run. Empty where the program keeps no log."""
     try:
         from distributed_pytorch_tpu.obs import flight
@@ -784,12 +787,14 @@ def run(ctx: dict) -> dict:
 
     lim = t["reference_limits"]
     ok = short == 0
+    held = compared.budgets(short)
     for name in t["reference_procedures"]:
-        check, tell = PROCEDURES[name]
+        check, tell, numbers = PROCEDURES[name]
         reading = check(ctx, engine, llm, variables, vocab)
         tell(say, lim, reading)
         ok = ok and reading["ok"]
-    return {"correct": bool(ok),
+        held += numbers(reading, lim)
+    return {"correct": bool(ok), "compared": held,
             "attempted": attempted, "failed": failed,
             "end_to_end": e2e, "observations": obs,
             "memory_peak_bytes": marks["memory_peak"]}

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 
 from benchmark.lib import compiles, harness, peaks, reference, stats, synth
 from benchmark.lib import flops as flops_lib
-from benchmark.lib import trace_reduce
+from benchmark.lib import compared, trace_reduce
 
 # imported with the runner, which run.py resolves BEFORE it touches the
 # chip: the trainer's import chain (orbax -> google.cloud.logging) took 42 s
@@ -271,6 +271,15 @@ def run(ctx: dict) -> dict:
             say)
 
     return {"correct": bool(ref["ok"] and not bad and falling),
+            "compared": [
+                compared.entry("logit_error_worst", ref["logit_error_worst"],
+                               LOGIT_ERROR_TOLERANCE, "at_most"),
+                compared.entry("loss_delta", ref["loss_delta"],
+                               LOSS_TOLERANCE, "at_most"),
+                compared.entry("losses_not_finite", len(bad), 0, "at_most"),
+                # the last window's mean loss under the first step's
+                compared.entry("loss_fall", first_loss - last_mean, 0.0,
+                               "at_least")],
             "attempted": len(losses), "failed": len(bad),
             "end_to_end": {"train_tokens_per_s": rate, "setup_s": setup_s},
             "observations": obs,

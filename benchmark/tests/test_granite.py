@@ -75,6 +75,8 @@ def test_granite_runner_end_to_end(tmp_path, back_to_cwd):
     ctx, said = _ctx(tmp_path)
     out = runner.run(ctx)
     assert out["correct"], said
+    assert len(out["compared"]) > 2 and all(
+        c["ok"] for c in out["compared"]), out["compared"]
     assert out["attempted"] > 0 and out["failed"] == 0, said
     for k in ("serve_tokens_per_s", "itl_p95_ms", "setup_s"):
         assert out["end_to_end"][k] > 0

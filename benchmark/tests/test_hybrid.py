@@ -74,6 +74,8 @@ def test_hybrid_runner_end_to_end(tmp_path, back_to_cwd):
     ctx, said = _ctx(tmp_path)
     out = runner.run(ctx)
     assert out["correct"], said
+    assert len(out["compared"]) > 2 and all(
+        c["ok"] for c in out["compared"]), out["compared"]
     assert out["attempted"] > 0 and out["failed"] == 0, said
     for k in ("serve_tokens_per_s", "itl_p95_ms", "setup_s"):
         assert out["end_to_end"][k] > 0
@@ -275,7 +277,7 @@ def test_named_reader_reads_nothing_where_there_is_nothing(tmp_path,
     from benchmark.readers import trace_scope_named_ms
     os.chdir(tmp_path)
     with open(os.path.join(harness.BENCH_DIR, "layer_metrics",
-                           "ssm_ms.hybrid.json")) as f:
+                           "ssm_ms.json")) as f:
         args = json.load(f)["args"]
     assert trace_scope_named_ms.read({}, args) is None
     assert trace_scope_named_ms.read({"trace": {"busy_s": 1}}, args) is None

@@ -2,7 +2,7 @@
 size: the runner end to end (paths, arguments, control flow; no number it
 produces is a device number), the configuration file's arithmetic against
 the tree and a hand count, the fixed schedule, the resolution of the cell
-and of every `.laguna` metric, and what the comparison sees: it passes the
+and of every metric that lists it, and what the comparison sees: it passes the
 program and fails each term spoilt in the REFERENCE
 (`reference_laguna.FAULTS`)."""
 
@@ -83,6 +83,8 @@ def test_window_runner_end_to_end(tmp_path, back_to_cwd):
     ctx, said = _ctx(tmp_path)
     out = runner.run(ctx)
     assert out["correct"], said
+    assert len(out["compared"]) > 2 and all(
+        c["ok"] for c in out["compared"]), out["compared"]
     assert out["attempted"] > 0 and out["failed"] == 0, said
     for k in ("serve_tokens_per_s", "itl_p95_ms", "setup_s"):
         assert out["end_to_end"][k] > 0
@@ -318,22 +320,22 @@ def test_the_traffic_is_the_issues():
 
 def test_every_laguna_metric_resolves_on_an_accepted_reader():
     bench = harness.load_benchmark()
-    mine = [m for m in bench["per_layer"] if m["name"].endswith(".laguna")]
-    # 27 of the issue's 32: the contract of BENCHMARK.json allows 128
-    # per-layer metrics in all and 101 were accepted (PERF.md section 3
-    # names the five left out)
-    assert len(mine) == 27
-    assert {"stall_share_pct.laguna", "stall_max_ms.laguna",
-            "batch_occupancy_pct.laguna"} <= {m["name"] for m in mine}
+    # the cell's metrics are the entries that LIST it, whatever their names:
+    # a reading it shares with other cells is one entry over all of them.
+    # 27 + 2 of issue 49's 32 (PERF.md section 3 names the three left out)
+    mine = harness.metrics_of_cell(bench, "per_layer", CELL)
+    assert len(mine) == 27 + 2
+    assert {"stall_share_pct.serve", "stall_max_ms.serve",
+            "batch_occupancy_pct", "engine_step_mean_ms",
+            "expert_second_tiles_pct"} <= {m["name"] for m in mine}
     accepted = {"counter", "client_clock", "trace_scope_ms",
                 "trace_scope_named_ms", "trace_roofline_pct",
                 "trace_idle_pct", "trace_idle_owner", "trace_span_ms",
                 "flight_stalls"}
     for m in mine:
-        assert m["workloads"] == [CELL]
         spec, reader = harness.load_layer_metric(m["name"])
-        assert spec["reader"] in accepted and spec["kinds"] == [
-            "serve_closed_window"]
+        assert spec["reader"] in accepted \
+            and "serve_closed_window" in spec["kinds"]
         assert reader.read({}, spec.get("args", {})) is None
     for m in bench["end_to_end"]:
         assert (CELL in m.get("workloads", [CELL])) == (

@@ -54,6 +54,8 @@ def test_train_runner_single(tmp_path, back_to_cwd):
     assert out["end_to_end"]["train_tokens_per_s"] > 0
     assert out["end_to_end"]["setup_s"] > 0
     assert out["correct"], said
+    assert len(out["compared"]) >= 2 and all(
+        c["ok"] for c in out["compared"]), out["compared"]
     obs = out["observations"]
     assert obs["counters"]["compiles_in_window"] == 0
     assert all("step_ms" in e for e in obs["timeline"])
@@ -86,6 +88,8 @@ def test_serve_closed_runner(tmp_path, back_to_cwd):
     ctx, said = _ctx(tmp_path, "tiny_serve", traffic, seconds=2.0)
     out = serve_closed.run(ctx)
     assert out["correct"], said
+    assert len(out["compared"]) >= 2 and all(
+        c["ok"] for c in out["compared"]), out["compared"]
     assert out["attempted"] > 0 and out["failed"] == 0, said
     for k in ("serve_tokens_per_s", "itl_p95_ms", "setup_s"):
         assert out["end_to_end"][k] > 0

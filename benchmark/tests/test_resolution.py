@@ -61,6 +61,14 @@ def test_contract_shape():
         assert set(m) <= {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
         assert "_roofline" not in m["name"] or m["unit"] == "%"
+    # the driver's contract for BENCHMARK.json (the builder's instructions:
+    # "`per_layer`: 1 to 128 metrics of single layers", "`workloads`: 1 to
+    # 24 cells"; a file outside either is refused before a single run). No
+    # line of the harness needs them: they stand here so that the repo sees
+    # how much room is left (PERF.md section 7 says when the next fold is
+    # due)
+    assert 1 <= len(BENCH["per_layer"]) <= 128
+    assert 1 <= len(BENCH["workloads"]) <= 24
     four = [w for w in BENCH["workloads"] if w["chips"] == 4]
     assert len(four) <= max(1, len(BENCH["workloads"]) // 4)
     for w in BENCH["workloads"]:
@@ -69,6 +77,21 @@ def test_contract_shape():
         assert c["file"].startswith(tuple(p + "/" for p in BENCH["paths"]))
     assert os.path.getsize(os.path.join(harness.ROOT,
                                         "BENCHMARK.json")) < 64 * 1024
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
+def test_no_cell_reads_one_reading_twice(cell):
+    """Metrics that differ in name alone are ONE entry that lists their
+    cells. A cell listed in such an entry AND in a twin left behind would
+    report one reading under two names: within a cell no two per-layer
+    metrics share (reader, args)."""
+    seen = {}
+    for m in harness.metrics_of_cell(BENCH, "per_layer", cell):
+        spec, _ = harness.load_layer_metric(m["name"])
+        key = (spec["reader"], json.dumps(spec.get("args", {}),
+                                          sort_keys=True))
+        assert key not in seen, (m["name"], seen[key])
+        seen[key] = m["name"]
 
 
 def test_missing_pieces_name_their_path(tmp_path):

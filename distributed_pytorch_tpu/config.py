@@ -340,6 +340,15 @@ ACTIVATIONS = (
 
 ATTENTION_KINDS = ("mha", "mqa", "gqa", "mla")
 ROUTER_KINDS = ("sigmoid", "softmax_topk")   # of a patterned model's 'E'
+#: What a layer of each kind of `LLMConfig.layer_pattern` keeps for a
+#: sequence between calls: "pools" (every row of its history, in blocks of
+#: the paged pool), "window" (a ring of the last rows, a slot), "slot_state"
+#: (leaves with a row a slot: a state-space layer's state and tail, a
+#: convolution's tail). A 'P' layer keeps two of them, in a cache slot
+#: keyed by these names (models/gpt.py init_paged_cache).
+LAYER_KEEPS = {"M": ("slot_state",), "C": ("slot_state",), "E": (), "F": (),
+               "*": ("pools",), "W": ("window",),
+               "P": ("pools", "slot_state")}
 POS_EMB_KINDS = ("learn", "sin", "rope", "none")
 # The reference realizes these as five separate trainer scripts
 # (single-gpu/train.py, multi-gpu/ddp/train.py, kaggle-zero1.py,
@@ -432,17 +441,21 @@ class LLMConfig:
     pp_schedule: str = "auto"  # 'auto' | 'carry' | '1f1b'
     pp_vpp: int = 0
 
-    # a per-layer pattern of ONE-mixer blocks, `x + mixer(norm(x))`, one
-    # character a layer: 'M' a Mamba-2 state-space mixer (models/ssm.py),
+    # a per-layer pattern of blocks `x + mixer(norm(x))`, one character
+    # a layer: 'M' a Mamba-2 state-space mixer (models/ssm.py),
     # 'C' a gated short-convolution mixer (models/shortconv.py), 'E' routed
     # experts of which this chip holds a share (models/mlp.py
     # RoutedExperts; `router` says how), 'F' a dense FFN of its own width
     # `dense_up_dim` (models/mlp.py MLP), '*' attention (GQA), 'W'
     # attention over a window of the last `window` positions (GQA at
-    # `window_heads` query heads; ops/window_attention.py). Empty = the
-    # attention + FFN block above for every layer. `n_layer` is its
-    # length. A patterned model has RMSNorms, no FFN biases, and its
-    # parameters are created in `LLM.param_dtype`.
+    # `window_heads` query heads; ops/window_attention.py), 'P' TWO
+    # mixers side by side on the one normed input, a Mamba-2 mixer and
+    # GQA, `x + a_out * attn(a_in * h) + s_out * ssm(s_in * h)` (the
+    # multipliers below; models/gpt.py MixerBlock): the one kind whose
+    # cache slot is of two kinds, a slot's state and tail AND blocks of
+    # the pool. Empty = the attention + FFN block above for every layer.
+    # `n_layer` is its length. A patterned model has RMSNorms, no FFN
+    # biases, and its parameters are created in `LLM.param_dtype`.
     layer_pattern: str = ""
     norm_eps: float = 1e-5       # the RMSNorms of a patterned model
     tie_head: bool = True        # False: an `lm_head` (V, C) of its own
@@ -503,6 +516,23 @@ class LLMConfig:
     resid_mult: float = 1.0
     attn_scale: float = 0.0
     logits_div: float = 1.0
+    # the published multipliers of a model parametrised for width
+    # transfer, each applied where it is published and in float32
+    # (ops/mup.py): a 'P' block's branches take `attn_in_mult` / `ssm_in_mult`
+    # times the normed input and add `attn_out_mult` / `ssm_out_mult`
+    # times their output; every GQA's keys are `key_mult` times W_k u,
+    # before the positions and the cache; `ssm_mults` = five numbers
+    # spread over the segments [z | x | B | C | dt] of a Mamba-2
+    # in-projection's output (empty: none); an 'F' block is
+    # `mlp_down_mult * W_down(silu(mlp_gate_mult * W_gate u) * W_up u)`
+    attn_in_mult: float = 1.0
+    attn_out_mult: float = 1.0
+    key_mult: float = 1.0
+    ssm_in_mult: float = 1.0
+    ssm_out_mult: float = 1.0
+    ssm_mults: tuple = ()
+    mlp_gate_mult: float = 1.0
+    mlp_down_mult: float = 1.0
     # 'M' layers (Mamba-2): heads x head size = d_inner, groups share B/C
     ssm_heads: int = 0
     ssm_head_dim: int = 0
@@ -518,21 +548,27 @@ class LLMConfig:
 
     def __post_init__(self):
         object.__setattr__(self, "experts_held", tuple(self.experts_held))
+        object.__setattr__(self, "ssm_mults", tuple(self.ssm_mults))
+        assert len(self.ssm_mults) in (0, 5), \
+            "ssm_mults: one number a segment of [z | x | B | C | dt]"
         assert self.rope_pairing in ("adjacent", "half"), self.rope_pairing
         assert self.rope_factor >= 1.0, "rope_factor is at least 1"
         assert self.rope_factor == 1.0 or self.rope_original_len > 0, \
             "yarn (a rope_factor over 1) needs rope_original_len"
         if self.layer_pattern:
-            assert set(self.layer_pattern) <= set("MCEF*W"), \
+            assert set(self.layer_pattern) <= set(LAYER_KEEPS), \
                 self.layer_pattern
             assert len(self.layer_pattern) == self.n_layer, (
                 f"layer_pattern has {len(self.layer_pattern)} layers, "
                 f"n_layer is {self.n_layer}")
             assert self.pp_stages == 1 and not self.moe
-            if "M" in self.layer_pattern:
+            if set("MP") & set(self.layer_pattern):
                 assert self.ssm_heads and self.ssm_head_dim and \
                     self.ssm_state and \
                     self.ssm_heads % self.ssm_groups == 0
+            if "P" in self.layer_pattern:
+                assert self.attn in ("mha", "mqa", "gqa"), \
+                    "a 'P' layer's attention branch is GQA"
             if "C" in self.layer_pattern:
                 assert self.conv_len >= 2
             if "F" in self.layer_pattern:
@@ -562,7 +598,12 @@ class LLMConfig:
                     assert 0 <= lo and n >= 1 and lo + n <= self.n_routed
         else:
             assert (self.embed_mult, self.resid_mult, self.attn_scale,
-                    self.logits_div) == (1.0, 1.0, 0.0, 1.0), \
+                    self.logits_div) == (1.0, 1.0, 0.0, 1.0) \
+                and not self.ssm_mults and all(
+                    m == 1.0 for m in (
+                        self.attn_in_mult, self.attn_out_mult,
+                        self.key_mult, self.ssm_in_mult, self.ssm_out_mult,
+                        self.mlp_gate_mult, self.mlp_down_mult)), \
                 "the multipliers are a patterned model's"
             assert not self.qk_norm, "QK-norm is a patterned model's"
             assert not (self.window or self.window_heads or self.attn_gate
@@ -641,7 +682,18 @@ class LLMConfig:
         the paged cache: a state-space layer's state and tail, or a
         convolution mixer's tail alone (what prefix reuse, the host tier
         and speculative roll-back cannot snapshot yet)."""
-        return any(kind in "MC" for kind in self.layer_pattern)
+        return self.layers_keeping("slot_state") > 0
+
+    @property
+    def layer_keeps(self) -> tuple:
+        """`LAYER_KEEPS` of every layer, in order; a classic model's
+        layers each keep their history in the pools."""
+        return tuple(LAYER_KEEPS[kind] for kind in
+                     self.layer_pattern or "*" * self.n_layer)
+
+    def layers_keeping(self, what: str) -> int:
+        """How many layers keep `what` (a name of `LAYER_KEEPS`)."""
+        return sum(what in keeps for keeps in self.layer_keeps)
 
     @property
     def slot_state(self) -> str:
@@ -650,7 +702,7 @@ class LLMConfig:
         reuse, the host tier and speculation stand down for it."""
         kinds = [name for name, held in (
             ("recurrent layers", self.recurrent),
-            ("window layers", "W" in self.layer_pattern)) if held]
+            ("window layers", self.layers_keeping("window"))) if held]
         return " and ".join(kinds)
 
     @property

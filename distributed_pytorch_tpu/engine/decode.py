@@ -920,8 +920,12 @@ class DecodeEngine:
         # they read; `chunk_attn_pairs_by`: (query, key) pairs the chunk
         # calls' masks let through, a chunk's REAL rows alone (a last
         # chunk is partial), in the "full" and the "window" layers
-        self._n_full = cfg.layer_pattern.count("*")
-        self._n_window = cfg.layer_pattern.count("W")
+        self._n_full = cfg.layers_keeping("pools")
+        self._n_window = cfg.layers_keeping("window")
+        # the rows are planned where the layers differ in what they keep
+        # (window layers, or a layer that keeps two kinds: 'P')
+        self._plans_kv_rows = bool(self._n_window) or any(
+            len(keeps) > 1 for keeps in cfg.layer_keeps)
         self.kv_rows_read_full_by = {"chunk": 0, "decode": 0}
         self.kv_rows_read_window_by = {"chunk": 0, "decode": 0}
         self.window_rows_saved = 0
@@ -941,6 +945,14 @@ class DecodeEngine:
         # weights that fell on held experts (`held_gate_share`): both of
         # softmax-routed programs alone, 0 elsewhere
         self.state_resets = 0
+        # float32 state the state-space layers' calls of the drained
+        # programs read and wrote back: the planned decoding slots (a
+        # chunk: its one slot) x layers x a slot's state, in and out
+        self.ssm_state_bytes_by = {"chunk": 0, "decode": 0}
+        self._state_bytes_slot = sum(
+            leaf.size * leaf.dtype.itemsize // n_slots
+            for path, leaf in jax.tree_util.tree_flatten_with_path(
+                self.caches)[0] if getattr(path[-1], "key", None) == "ssm")
         self.expert_calls = 0
         self.experts_hit = 0
         self.held_assignments = 0
@@ -1609,6 +1621,7 @@ class DecodeEngine:
                     jnp.int32(slot), rng)
             self.caches, self.tok, self.pos, self.live, first = out
             self.state_resets += int(self.cfg.recurrent)
+            self.ssm_state_bytes_by["chunk"] += 2 * self._state_bytes_slot
             # THE admit sync boundary: the first sampled token must reach the
             # host to stream it to the caller (a patterned model's routing
             # counts ride the same transfer)
@@ -1876,7 +1889,7 @@ class DecodeEngine:
                 seq.pos += 1
                 # the rows its attention call reads, this token's included
                 prog.live_tiles += -(-seq.pos // self.block_size)
-                if self._n_window:
+                if self._plans_kv_rows:
                     self._plan_kv_rows(prog, "decode", seq.pos,
                                        min(seq.pos, self.cfg.window))
                 self._plan_retirement(slot, seq, prog.retiring)
@@ -1893,7 +1906,7 @@ class DecodeEngine:
             chunk_done = not self._is_partial(seq_c)
             prog.chunk = (slot_c, seq_c.seq_id, take, seq_c.pos)
             prog.state_reset = self.cfg.recurrent and off == 0
-            if self._n_window:
+            if self._plans_kv_rows:
                 # the chunk's `take` queries see the `off` rows before
                 # them; of those a window layer's see the last window - 1
                 self._plan_kv_rows(prog, "chunk", off + take,
@@ -1930,6 +1943,10 @@ class DecodeEngine:
         return sum(self.kv_rows_read_window_by.values())
 
     @property
+    def ssm_state_bytes(self) -> int:
+        return sum(self.ssm_state_bytes_by.values())
+
+    @property
     def resident_bytes_by_kind(self) -> dict:
         """Bytes the engine holds between programs, by kind of state:
         the weights, the block pools of the layers that keep a whole
@@ -1937,12 +1954,12 @@ class DecodeEngine:
         def nbytes(tree):
             return sum(a.size * a.dtype.itemsize
                        for a in jax.tree_util.tree_leaves(tree))
-        kinds = self.cfg.layer_pattern or "*" * self.cfg.n_layer
         by = {"weights": nbytes(self.variables), "pools": 0, "window": 0,
               "slot_state": 0}
-        for kind, leaf in zip(kinds, self.caches):
-            by[{"*": "pools", "W": "window"}.get(kind, "slot_state")] += \
-                nbytes(leaf)
+        for keeps, leaf in zip(self.cfg.layer_keeps, self.caches):
+            for what in keeps:
+                # a layer that keeps two kinds keys its slot by them
+                by[what] += nbytes(leaf[what] if len(keeps) > 1 else leaf)
         return by
 
     def _dispatch(self, prog: _Program) -> None:
@@ -2063,6 +2080,10 @@ class DecodeEngine:
                 ("chunk",) * (prog.chunk is not None) + ("decode",))
             calls = self.expert_calls - calls_before
             self.state_resets += int(prog.state_reset)
+            state_bytes = 2 * self._state_bytes_slot
+            self.ssm_state_bytes_by["decode"] += state_bytes * prog.n_live
+            self.ssm_state_bytes_by["chunk"] += \
+                state_bytes * (prog.chunk is not None)
             live_steps = prog.n_live if prog.spec is None else 0
             self.decode_live_tiles += prog.live_tiles
             self.decode_live_steps += live_steps
@@ -2171,11 +2192,13 @@ class DecodeEngine:
                 decode_live_steps=live_steps,
                 **({"experts_hit": hit, "absent_assignments": absent,
                     "expert_calls": calls, "expert_second_tiles": second,
-                    "state_reset": int(prog.state_reset)}
+                    "state_reset": int(prog.state_reset),
+                    "ssm_state_bytes": state_bytes * (
+                        prog.n_live + (prog.chunk is not None))}
                    if self.cfg.layer_pattern else {}),
                 **({"kv_rows_read_full": kv_full,
                     "kv_rows_read_window": kv_window}
-                   if self._n_window else {}))
+                   if self._plans_kv_rows else {}))
         return StepResult(emitted=emitted, retired=retired,
                           prefill_tokens=prefill_tokens,
                           drafted=drafted, accepted=accepted)

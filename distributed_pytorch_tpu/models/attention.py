@@ -61,6 +61,7 @@ import jax.numpy as jnp
 
 from distributed_pytorch_tpu.config import LLMConfig
 from distributed_pytorch_tpu.ops.attention_core import sdpa
+from distributed_pytorch_tpu.ops.mup import times
 from distributed_pytorch_tpu.ops.rope import (apply_partial_rotary,
                                               apply_rotary_emb, rope_angles,
                                               slice_rows)
@@ -161,7 +162,8 @@ class GQA(nn.Module):
     no table is handed in (`freqs` None) takes its angles from the rows'
     own positions at `cfg.rope_theta` (ops/rope.py): of the first
     `cfg.rotary_frac` of the lanes, YaRN's where `cfg.rope_factor` is over 1.
-    Keys go into the cache normed and rotated. With `cfg.attn_gate` every
+    Keys go into the cache normed and rotated, and times `cfg.key_mult`
+    (ops/mup.py) from the projection on. With `cfg.attn_gate` every
     query head's output is multiplied by a gate of its own, the sigmoid of
     a linear map (leaf `c_gate`, (C, heads)) of the layer's input, before
     `c_proj`.
@@ -198,6 +200,7 @@ class GQA(nn.Module):
         q = q.reshape(B, T, nh, hs)
         k = k.reshape(B, T, nkvh, hs)
         v = v.reshape(B, T, nkvh, hs)
+        k = times(k, cfg.key_mult)
 
         if cfg.qk_norm:
             with jax.named_scope("qk_norm"):

@@ -41,6 +41,7 @@ from distributed_pytorch_tpu.models.shortconv import (ShortConv,
                                                       init_conv_cache)
 from distributed_pytorch_tpu.models.ssm import Mamba2, init_ssm_cache
 from distributed_pytorch_tpu.ops.losses import tied_head_loss
+from distributed_pytorch_tpu.ops.mup import times
 from distributed_pytorch_tpu.ops.rope import precompute_rope_freqs, slice_rows
 
 _EMBED_INIT = nn.initializers.normal(stddev=0.02)
@@ -97,15 +98,34 @@ def _real_rows(x, state_ctx: dict):
     return jnp.arange(x.shape[1]) < state_ctx["valid_len"][0]
 
 
+class MixerSum(nn.Module):
+    """What a 'P' block adds to the residual stream: `attn_out_mult * a +
+    ssm_out_mult * s`, one float32 sum of its two branches' outputs. A
+    module without parameters, so that the sum has a name of its own
+    (`mixer_sum`) in a device trace and for whoever taps the modules."""
+
+    config: LLMConfig
+
+    def __call__(self, a, s):
+        cfg = self.config
+        return (a.astype(jnp.float32) * cfg.attn_out_mult
+                + s.astype(jnp.float32) * cfg.ssm_out_mult).astype(a.dtype)
+
+
 class MixerBlock(nn.Module):
     """One layer of a patterned model: `x + r * mixer(RMSNorm(x))` (r =
     `cfg.resid_mult`), the mixer one of 'M' (models/ssm.py), 'C'
     (models/shortconv.py), 'E' (models/mlp.py RoutedExperts), 'F'
-    (models/mlp.py MLP at `cfg.dense_up_dim`), '*' (GQA) or 'W' (GQA over
-    a window of the last `cfg.window` positions). What each keeps between
+    (models/mlp.py MLP at `cfg.dense_up_dim`), '*' (GQA), 'W' (GQA over
+    a window of the last `cfg.window` positions) or 'P': TWO mixers on
+    the one normed input h, `mixer_sum(attn(a_in * h), ssm(s_in * h))`
+    (`MixerSum`; modules `attn` and `ssm` as in a '*' and an 'M' block).
+    What each keeps between
     calls sits in the layer's cache slot: per-slot state leaves ('M': tail
     and state, 'C': tail, 'W': a ring of the window's keys and values),
-    this program's routing counts, block pools ('*'), nothing ('F').
+    this program's routing counts, block pools ('*'), nothing ('F'),
+    pools AND state leaves ('P': {"pools", "slot_state"}, so the rows'
+    block table and their state context reach the same block).
     `state_ctx` (the engine's: which rows are live, or which slot a chunk
     belongs to and how many of its rows are real) reaches the kinds that
     have no null block to land a pad in.
@@ -142,6 +162,8 @@ class MixerBlock(nn.Module):
             new_cache = None if stats is None \
                 else merge_expert_stats(cache, stats)
         else:
+            if self.kind == "P":
+                return self._parallel(xs, hs, rows, freqs, cache)
             mixer = {
                 "M": lambda: Mamba2(cfg, pd, name="ssm"),
                 "C": lambda: ShortConv(cfg, pd, name="conv"),
@@ -174,6 +196,28 @@ class MixerBlock(nn.Module):
                          * cfg.resid_mult).astype(y.dtype)
                 out.append(x + y)
         return out, new_cache
+
+    def _parallel(self, xs, hs, rows, freqs, cache):
+        """A 'P' block from its normed inputs on: both mixers on every
+        row set in turn, each with its own half of the cache slot."""
+        cfg = self.config
+        attn = GQA(cfg, self.attn_impl, self.param_dtype, name="attn")
+        ssm = Mamba2(cfg, self.param_dtype, name="ssm")
+        add = MixerSum(cfg, name="mixer_sum")
+        pools, state = (None, None) if cache is None else \
+            (cache["pools"], cache["slot_state"])
+        out = []
+        for x, h, r in zip(xs, hs, rows):
+            with _scope(r.scope):
+                a, pools = attn(
+                    times(h, cfg.attn_in_mult), freqs, pools, r.pos,
+                    deterministic=True, block_tables=r.block_tables,
+                    state_ctx=r.state_ctx)
+                s, state = ssm(times(h, cfg.ssm_in_mult), state, r.pos,
+                               r.state_ctx)
+                out.append(x + add(a, s))
+        return out, None if cache is None else \
+            {"pools": pools, "slot_state": state}
 
 
 class Block(nn.Module):
@@ -455,7 +499,9 @@ def init_paged_cache(config: LLMConfig, n_blocks: int, block_size: int,
     for its 'W' layers (models/attention.py `init_window_cache`: whatever
     the pools' `n_blocks` and the engine's `max_len` are), nothing for
     'F' and 'E' layers (an 'E' slot carries a program's routing counts
-    out, never in)."""
+    out, never in). A 'P' layer holds BOTH kinds, keyed by what they are
+    (`config.LAYER_KEEPS`): {"pools": its attention branch's block pools,
+    "slot_state": its state-space branch's tail and state}."""
     from distributed_pytorch_tpu.models.attention import init_paged_attn_cache
     if config.layer_pattern:
         assert n_slots > 0 or not config.slot_state, \
@@ -466,7 +512,9 @@ def init_paged_cache(config: LLMConfig, n_blocks: int, block_size: int,
                 "W": lambda: init_window_cache(config, n_slots, block_size,
                                                dtype),
                 "*": lambda: init_paged_attn_cache(config, n_blocks,
-                                                   block_size, dtype)}
+                                                   block_size, dtype),
+                "P": lambda: {"pools": make["*"](),
+                              "slot_state": make["M"]()}}
         return [make[kind]() if kind in make else None
                 for kind in config.layer_pattern]
     return [init_paged_attn_cache(config, n_blocks, block_size, dtype)

@@ -72,13 +72,15 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distributed_pytorch_tpu.config import LLMConfig
 from distributed_pytorch_tpu.ops.activations import activation, is_gated
+from distributed_pytorch_tpu.ops.mup import times
 
 _DENSE_INIT = nn.initializers.normal(stddev=0.02)
 
 
 def mlp_apply(x: jnp.ndarray, w_fc: jnp.ndarray, w_proj: jnp.ndarray,
               non_linearity: str, *, overlap: bool = False,
-              qnames: tuple | None = None) -> jnp.ndarray:
+              qnames: tuple | None = None, gate_mult: float = 1.0,
+              down_mult: float = 1.0) -> jnp.ndarray:
     """Apply one MLP given its kernels; shared by dense MLP and experts.
 
     Gated variants ('swiglu'/'glu'): w_fc is (C, 2*up_dim), split in half,
@@ -97,6 +99,10 @@ def mlp_apply(x: jnp.ndarray, w_fc: jnp.ndarray, w_proj: jnp.ndarray,
     per-output-channel scales (applied before the gating split — exact,
     the scale is per column of the fused fc output); elsewhere the lookup
     misses and nothing changes.
+
+    `gate_mult` multiplies a gated variant's x1 inside its activation and
+    `down_mult` the result (`LLMConfig.mlp_gate_mult`, `mlp_down_mult`;
+    ops/mup.py); at 1 they add no op.
     """
     h = None
     if qnames is not None:
@@ -110,6 +116,7 @@ def mlp_apply(x: jnp.ndarray, w_fc: jnp.ndarray, w_proj: jnp.ndarray,
         h = x @ w_fc
     if is_gated(non_linearity):
         x1, x2 = jnp.split(h, 2, axis=-1)
+        x1 = times(x1, gate_mult)
         gate = jax.nn.silu(x1) if non_linearity.lower() == "swiglu" \
             else jax.nn.sigmoid(x1)
         h = gate * x2
@@ -125,7 +132,7 @@ def mlp_apply(x: jnp.ndarray, w_fc: jnp.ndarray, w_proj: jnp.ndarray,
         y = maybe_overlap_matmul(h, w_proj, names=("c_proj",))
     if y is None:
         y = h @ w_proj
-    return y
+    return times(y, down_mult)
 
 
 class MLP(nn.Module):
@@ -133,7 +140,8 @@ class MLP(nn.Module):
     of a patterned model is this block at a width of its own (`up_dim`,
     0 = `cfg.up_dim`: `cfg.dense_up_dim` beside experts of `cfg.up_dim`)
     with its leaves in `param_dtype`; gated, `c_fc` is [a | b] by columns
-    and the block `(silu(a) * b) c_proj`."""
+    and the block `m_down * (silu(m_gate * a) * b) c_proj` (the two
+    multipliers 1 but for a configuration that publishes them)."""
 
     config: LLMConfig
     up_dim: int = 0
@@ -148,7 +156,9 @@ class MLP(nn.Module):
         w_proj = self.param("c_proj", _DENSE_INIT, (up, C), self.param_dtype)
         y = mlp_apply(x, w_fc.astype(x.dtype), w_proj.astype(x.dtype),
                       cfg.non_linearity, overlap=True,
-                      qnames=((*self.path, "c_fc"), (*self.path, "c_proj")))
+                      qnames=((*self.path, "c_fc"), (*self.path, "c_proj")),
+                      gate_mult=cfg.mlp_gate_mult,
+                      down_mult=cfg.mlp_down_mult)
         return nn.Dropout(cfg.dropout, deterministic=deterministic)(y)
 
 

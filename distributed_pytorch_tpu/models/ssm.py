@@ -2,6 +2,8 @@
 patterned model (`LLMConfig.layer_pattern` 'M').
 
     [z | xBC | dt] = x W_in                  C -> d_inner + conv_dim + H
+                     (times `cfg.ssm_mults`, a number a segment of
+                     [z | x | B | C | dt], where the configuration has them)
     xBC = silu(causal depthwise conv1d(xBC, width K, bias))
     x, B, C = split(xBC)                     H x P | G x N | G x N
     dt = softplus(dt + dt_bias),  A = -exp(A_log)
@@ -34,6 +36,7 @@ import jax.numpy as jnp
 
 from distributed_pytorch_tpu.config import LLMConfig
 from distributed_pytorch_tpu.ops import ssm_scan
+from distributed_pytorch_tpu.ops.mup import segment_times
 
 _DENSE_INIT = nn.initializers.normal(stddev=0.02)
 
@@ -110,7 +113,9 @@ class Mamba2(nn.Module):
         norm_w = self.param("norm_w", nn.initializers.ones, (d_inner,), pd)
         w_out = self.param("out_proj", _DENSE_INIT, (d_inner, C), pd)
 
-        zxd = x @ w_in.astype(dt_)
+        zxd = segment_times(x @ w_in.astype(dt_),
+                            (d_inner, d_inner, G * N, G * N, H),
+                            cfg.ssm_mults)
         z, xbc, dt_raw = jnp.split(zxd, [d_inner, d_inner + conv_dim], axis=-1)
         dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + dt_bias)
         A = -jnp.exp(a_log)

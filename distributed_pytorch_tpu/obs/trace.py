@@ -371,6 +371,11 @@ MIXER_MODULES = {
             "two gates, B * x' and C * c (models/shortconv.py)",
     "moe": "an expert layer outside its scopes (models/mlp.py)",
     "norm": "the RMSNorm in front of every mixer (models/gpt.py)",
+    "mixer_sum": "a 'P' block's sum of its two branches, `attn` and `ssm` "
+                 "under the names and scopes they have alone, each times "
+                 "its output multiplier, in float32 (models/gpt.py "
+                 "MixerSum): the one place that tells where the branches "
+                 "of one block meet",
 }
 
 #: The scopes of a patterned model's mixers (`LLMConfig.layer_pattern`),

@@ -398,7 +398,11 @@ class Scheduler:
                  "key rows read by the window layers' attention calls"),
                 ("serve_window_rows_saved_total", "window_rows_saved",
                  "key rows a whole history would have cost the window "
-                 "layers, less the rows they read")):
+                 "layers, less the rows they read"),
+                ("serve_ssm_state_bytes_total", "ssm_state_bytes",
+                 "float32 state the state-space layers' calls read and "
+                 "wrote back (slots x layers x a slot's state, in and "
+                 "out)")):
             self.metrics.register_gauge(
                 name, lambda attr=attr: getattr(eng, attr, 0), text)
         for kind in ("weights", "pools", "window", "slot_state"):

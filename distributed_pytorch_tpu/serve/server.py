@@ -326,6 +326,8 @@ class ServeApp:
             "kv_rows_read_window": getattr(eng, "kv_rows_read_window", 0),
             "window_rows_saved": getattr(eng, "window_rows_saved", 0),
             "chunk_attn_pairs_by": getattr(eng, "chunk_attn_pairs_by", {}),
+            # state-space layers: float32 state their calls moved, by call
+            "ssm_state_bytes_by": getattr(eng, "ssm_state_bytes_by", {}),
             "resident_bytes_by_kind":
                 getattr(eng, "resident_bytes_by_kind", {})})
 

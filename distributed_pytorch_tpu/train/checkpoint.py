@@ -52,6 +52,9 @@ def _write_meta(path: str, state: TrainState, model_cfg, train_cfg) -> None:
         "step": int(jax.device_get(state.step)),
     }
     if jax.process_index() == 0:
+        # an async save may not have made the step's directory yet (orbax
+        # creates it on a background thread; under load the write lost)
+        os.makedirs(path, exist_ok=True)
         with open(os.path.join(path, "config.json"), "w") as f:
             json.dump(meta, f, indent=2)
 

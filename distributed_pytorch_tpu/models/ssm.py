@@ -14,7 +14,8 @@ patterned model (`LLMConfig.layer_pattern` 'M').
 d_inner = heads x head size (not an expansion of C), conv_dim = d_inner +
 2 G N. What a sequence carries from token to token is no block of a paged
 cache but a leaf a slot: the convolution's last K - 1 inputs (compute
-dtype) and the state h (float32), `init_ssm_cache`. Three ways in:
+dtype) and the state h (float32, state-major (N, H x P): ops/ssm_scan.py),
+`init_ssm_cache`. Three ways in:
 
 * no cache: a whole sequence from a zero state (training shape, tests);
 * `state_ctx["live"]`: one token of every slot, the one-token recurrence;
@@ -52,8 +53,9 @@ def init_ssm_cache(cfg: LLMConfig, n_slots: int, dtype) -> dict:
     """One slot's row of each: the convolution tail and the state."""
     _, conv_dim, _ = ssm_dims(cfg)
     return {"conv": jnp.zeros((n_slots, cfg.ssm_conv - 1, conv_dim), dtype),
-            "ssm": jnp.zeros((n_slots, cfg.ssm_heads, cfg.ssm_head_dim,
-                              cfg.ssm_state), jnp.float32)}
+            "ssm": jnp.zeros((n_slots, *ssm_scan.state_shape(
+                cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)),
+                jnp.float32)}
 
 
 def chunk_start(leaf: jnp.ndarray, slot, pos) -> jnp.ndarray:

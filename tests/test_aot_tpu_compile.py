@@ -518,9 +518,10 @@ def _serving_shapes(kind, nh, n_blocks, n_slots, width):
     return shapes
 
 
-def _pool_copies(text, pool_shape):
-    """The compiled program's `copy` ops that produce a whole bf16 pool."""
-    pool_type = "bf16[%s]" % ",".join(map(str, pool_shape))
+def _pool_copies(text, pool_shape, dtype="bf16"):
+    """The compiled program's `copy` ops that produce a whole bf16 pool (or
+    a whole leaf of another `dtype`)."""
+    pool_type = "%s[%s]" % (dtype, ",".join(map(str, pool_shape)))
     return [ln.strip()[:160] for ln in text.splitlines()
             if f"= {pool_type}" in ln and " copy(" in ln]
 
@@ -670,28 +671,115 @@ def test_the_combine_gathers_a_tokens_rows_and_scatters_none(n_tokens,
                          r"moe_combine/gather", text), (n, k, C)
 
 
-@pytest.mark.parametrize("H, G", [(64, 8), (128, 1)],
-                         ids=["64heads_8groups", "128heads_1group"])
-def test_the_one_token_recurrence_updates_its_state_in_place(H, G, v5e):
+@pytest.mark.parametrize("line, H, P, G, N", [
+    ("xla", 64, 64, 8, 128), ("xla", 128, 64, 1, 128),
+    ("kernel", 64, 64, 8, 128), ("kernel", 128, 64, 1, 128),
+    ("kernel", 32, 128, 2, 256)],
+    ids=["64heads_8groups", "128heads_1group", "kernel_64heads_8groups",
+         "kernel_128heads_1group", "kernel_32heads_of_128_state_256"])
+def test_the_one_token_recurrence_updates_its_state_in_place(line, H, P, G,
+                                                             N, v5e):
     """The state-space decode step at the published sizes (64 slots x 64
-    heads x 64 x 128 float32 = 134 MB a layer; at 128 heads and one group
-    268 MB, 4.19 MB a slot) with the state donated: aliased in to out, no
-    copy of it, temporaries far below one state."""
+    heads x 64 x 128 float32 = 134 MB a layer; at 128 heads and one group,
+    and at 32 heads of 128 over a state of 256 in two groups, 268 MB, 4.19
+    MB a slot) with the state donated, by the jax.numpy line and by the
+    kernel: aliased in to out, no copy of it, temporaries far below one
+    state; the kernel ONE custom call that leaves the state in HBM and
+    moves it itself (two phases of 16 MB in VMEM)."""
     from distributed_pytorch_tpu.ops import ssm_scan
-    S, P, N = 64, 64, 128
-    shapes = [((S, H, P, N), F32), ((S, H, P), BF16), ((S, H), F32),
+    S = 64
+    shapes = [((S, N, H * P), F32), ((S, H, P), BF16), ((S, H), F32),
               ((H,), F32), ((S, G, N), BF16), ((S, G, N), BF16), ((H,), F32),
               ((S,), jnp.bool_)]
     avals = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in shapes]
-    compiled = jax.jit(ssm_scan.ssm_step,
-                       donate_argnums=(0,)).lower(*avals).compile()
+    step = ssm_scan.ssm_step_xla if line == "xla" \
+        else ssm_scan.ssm_step_kernel
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(*avals).compile()
     state_bytes = 4 * S * H * P * N
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= state_bytes
     assert mem.temp_size_in_bytes < state_bytes // 4, mem.temp_size_in_bytes
-    assert " copy(" not in "".join(
-        ln for ln in compiled.as_text().splitlines()
-        if f"f32[64,{H},64,128]" in ln)
+    text = compiled.as_text()
+    assert not _pool_copies(text, (S, N, H * P), "f32")
+    assert paths.kernel_census(text) == (
+        {} if line == "xla" else {"ssm_state_step": 1})
+    if line == "kernel":
+        assert ssm_scan.ssm_step_kernel_decline(*avals[:2], avals[4],
+                                                interpret=True) is None
+
+
+#: one block of the two cells whose largest op is the recurrence, at the
+#: published widths: Falcon-H1's 'P' (32 heads of 128 over a state of 256,
+#: GQA 20 over 4 beside it), granite's 'M' (128 heads of 64, one group)
+STATE_BLOCKS = {
+    "falcon_P": dict(n_embd=5120, layer_pattern="P", pos_emb="rope",
+                     rope_pairing="half", n_head=20, n_kv_heads=4,
+                     head_dim=128, ssm_heads=32, ssm_head_dim=128,
+                     ssm_groups=2, ssm_state=256, ssm_chunk=128),
+    "granite_M": dict(n_embd=4096, layer_pattern="M", pos_emb="none",
+                      n_head=32, n_kv_heads=8, head_dim=128, ssm_heads=128,
+                      ssm_head_dim=64, ssm_groups=1, ssm_state=128,
+                      ssm_chunk=256)}
+
+
+@pytest.mark.parametrize("program", ["step", "fused_step"])
+@pytest.mark.parametrize("block", list(STATE_BLOCKS))
+def test_the_engines_step_programs_hold_the_state_kernel(block, program, v5e,
+                                                         monkeypatch):
+    """The engine's own step functions over ONE block at 64 slots, the
+    cache tree donated, compiled for the described chip (the gate told it
+    is on a TPU): the program holds one `ssm_state_step`, the state leaf
+    is aliased in to out, no `copy` makes an array of its size (the chunk
+    path of a fused step hands ONE slot's state in and out), and the
+    `paths` note names the kernel and its phase."""
+    from distributed_pytorch_tpu.config import LLMConfig
+    from distributed_pytorch_tpu.engine import decode as dec
+    from distributed_pytorch_tpu.models.gpt import LLM, init_paged_cache
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = LLMConfig(vocab_size=1024, block_size=4096, n_layer=1, attn="gqa",
+                    attn_bias=False, tie_head=False, non_linearity="swiglu",
+                    up_dim=1024, ssm_conv=4, **STATE_BLOCKS[block])
+    model = LLM(cfg, compute_dtype=BF16, attn_impl="auto", param_dtype=BF16)
+    n_slots, chunk, width = 64, 256, 6
+
+    def sds(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=v5e)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, I32, sharding=v5e)
+
+    key = jax.random.PRNGKey(0)
+    variables = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda k: model.init({"params": k}, jnp.zeros((1, 8), I32)), key))
+    caches = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda: init_paged_cache(cfg, 264, BS, dtype=BF16, n_slots=n_slots)))
+    slot = caches[0]           # a 'P' block's slot also holds its pools
+    state = slot.get("slot_state", slot)["ssm"]
+    assert state.dtype == F32 and state.shape == (
+        n_slots, cfg.ssm_state, cfg.ssm_heads * cfg.ssm_head_dim)
+
+    def sample(logits, rng):
+        return jnp.argmax(logits, axis=-1).astype(I32)
+
+    args = [variables, caches, i32(n_slots), i32(n_slots),
+            jax.ShapeDtypeStruct((n_slots,), jnp.bool_, sharding=v5e),
+            i32(n_slots, width), sds(key), i32(), None]
+    if program == "step":
+        fn = dec.make_step_fn(model, sample)
+    else:
+        fn = dec.make_fused_step_fn(model, sample, n_slots, width)
+        args += [i32(1, chunk), i32(), i32(), i32(1),
+                 jax.ShapeDtypeStruct((), jnp.bool_, sharding=v5e)]
+    paths.reset()
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    assert paths.choices()["ssm_step"] == \
+        "ssm_state_step (state in place, 4 slots a phase)"
+    text = compiled.as_text()
+    assert paths.kernel_census(text).get("ssm_state_step") == 1
+    state_bytes = 4 * state.size
+    assert compiled.memory_analysis().alias_size_in_bytes >= state_bytes
+    assert compiled.memory_analysis().temp_size_in_bytes < state_bytes // 4
+    assert not _pool_copies(text, state.shape, "f32")
 
 
 def test_gates_decline_what_the_compiler_refuses(v5e):

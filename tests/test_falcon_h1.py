@@ -112,7 +112,8 @@ def test_the_tree_and_the_cache_slot_of_two_kinds(mv):
     slot = caches[0]
     assert sorted(slot["pools"]) == ["k", "v"] \
         and slot["pools"]["k"].shape == (5, 8, 128)      # 2 x 16 -> 128
-    assert slot["slot_state"]["ssm"].shape == (3, 4, 16, 32) \
+    # state-major: 32 state rows, 4 heads x 16 side by side on the lanes
+    assert slot["slot_state"]["ssm"].shape == (3, 32, 64) \
         and slot["slot_state"]["ssm"].dtype == jnp.float32
     assert slot["slot_state"]["conv"].shape == (3, 3, 192)
 

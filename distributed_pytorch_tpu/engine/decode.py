@@ -2176,8 +2176,8 @@ class DecodeEngine:
             traces, seen = self._n_traces(), self._traces_seen
             self._traces_seen = traces
             self.flight.record_turn(
-                "engine", parts, t_rec, compiled=traces != seen,
-                step=prog.t + 1,
+                "engine", parts, t_rec, kind=prog.kind,
+                compiled=traces != seen, step=prog.t + 1,
                 step_ms=round((t_rec - t_step0) * 1e3, 3),
                 **{f"{k}_ms": round(v, 3) for k, v in parts.items()},
                 n_live=prog.n_live, prefill_tokens=prefill_tokens,

@@ -44,17 +44,21 @@ from the same stamps.** They answer questions a capture is too short for:
   what else ran in it: `gc_ms` / `gc_gen` (collector pauses, from one
   process-wide `gc.callbacks` hook that also writes each pause into the
   profiler's trace as `host.gc`, `obs.trace.HOST_EVENTS`), `cpu_ms` (the
-  writer thread's own CPU time), `capturing`. A turn over 3x
-  the running median of the last 256, or one that compiled, is STALLED:
-  booked with its `owner` (`gap` or a phase), its `cause` (`compile` |
-  `capture` | `gc` | `host_busy` | `blocked` | `caller` | `mixed`) and its
-  excess into a process-wide log of 256 that ordinary records never evict
-  (`obs.flight.stall_log()`, `stall_totals()`). It is read at
-  `GET /debug/timeline` (`stalls`, `stall_totals`), at `/metrics`
-  (`serve_engine_stalls_total{cause}`, `..._stall_seconds_total{cause}`,
-  `serve_host_gc_pause_seconds_total{generation}`; `train_*` twins) and by
-  the benchmark's `stall_share_pct.*`, `stall_max_ms.*`,
-  `host_gc_ms_per_s.*`.
+  writer thread's own CPU time), where it stood while off the CPU
+  (`sched_delay_ms`, `steal_ms`, `nivcsw`), `capturing`. A turn over 3x
+  the running median of the last 256 of its KIND (the drained program's
+  `decode` | `fused` | `spec`), or one that compiled, is STALLED:
+  booked with its `kind`, its `owner` (`gap` or a phase), its `cause`
+  (`compile` | `capture` | `gc` | `descheduled` | `caller` | `host_busy` |
+  `blocked` | `mixed`) and its excess into a process-wide log of 256 that
+  ordinary records never evict (`obs.flight.stall_log()`,
+  `stall_totals()`). It is read at `GET /debug/timeline` (`stalls`,
+  `stall_totals`), at `/metrics` (`serve_engine_stalls_total{cause}`,
+  `..._stall_seconds_total{cause}`, `serve_host_gc_pause_seconds_total
+  {generation}`, `serve_host_sched_delay_seconds_total{reason}`; `train_*`
+  twins) and by the benchmark's `stall_share_pct.*`, `stall_max_ms.*`,
+  `host_gc_ms_per_s.*` and, laid on a capture by the program number,
+  `idle_stalled_pct.serve`.
 * `obs.trace.TraceRecorder` — per-REQUEST spans on `perf_counter` (router
   dispatch, queue wait, prefill, decode, failover, retire) under an
   `X-Trace-Id`, recorded at terminal events only, exportable as

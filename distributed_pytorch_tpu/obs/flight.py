@@ -29,15 +29,23 @@ turn's record also says what else ran in it: `gc_ms` / `gc_gen` (collector
 pauses that ended inside it, any thread, from one process-wide
 `gc.callbacks` hook that also writes each pause into the profiler's trace
 as `host.gc`), `cpu_ms` (the writer thread's own CPU time over the turn:
-computing or asleep) and `capturing` (a profiler capture at either end, or
-one that went off inside the turn before: writing it out takes seconds). A
-turn longer than `STALL_FACTOR` x the running median of the recorder's last
-`MEDIAN_TURNS` unstalled turns, or one that compiled, is STALLED: booked
-with its `owner` (its largest part), its `cause` (`CAUSES`) and its
-`excess_ms` over the median into a process-wide log that ordinary records
-never evict (`stall_log()`), beside process-wide totals (`stall_totals()`).
-Process-wide because what is measured is: a collection or a descheduling
-stops every thread.
+computing or asleep), where the thread STOOD while it was off the CPU
+(`sched_delay_ms`, `steal_ms`, `nivcsw`: `_stood`) and `capturing` (a
+profiler capture at either end, or one that went off inside the turn
+before: writing it out takes seconds).
+
+A turn is judged against the turns of its own KIND (`record_turn(kind=)`:
+the engine hands the drained program's `decode` | `fused` | `spec`, whose
+times lie 2.3-4.3x apart in the cells with long chunks; the trainer hands
+none and its turns are one kind, `ONE_KIND`). Per kind the recorder keeps
+the last `MEDIAN_TURNS` unstalled turns and their running median; a turn
+longer than `STALL_FACTOR` x the median of its kind, or one that compiled,
+is STALLED: booked with its `kind`, its `owner` (its largest part), its
+`cause` (`CAUSES`) and its `excess_ms` over that `median_ms` into a
+process-wide log that ordinary records never evict (`stall_log()`), beside
+process-wide totals (`stall_totals()`). A kind without a median yet gets no
+verdict. Process-wide because what is measured is: a collection or a
+descheduling stops every thread.
 """
 
 from __future__ import annotations
@@ -51,12 +59,15 @@ import threading
 import time
 from typing import Optional
 
+try:
+    import resource
+except ImportError:             # no such module off POSIX: no `nivcsw`
+    resource = None
+
 from distributed_pytorch_tpu.obs.trace import HOST_GC, TraceAnnotation
 
-#: A turn is stalled beyond this many running medians. A chunk-carrying
-#: step program is 1.4-1.9x a plain one in every serving cell (24.4 / 17.5,
-#: 37.7 / 22.7, 27.2 / 14.6 ms; PERF.md section 5) and must never be
-#: flagged; the stalls hunted are 5-200x.
+#: A turn is stalled beyond this many running medians of its kind; the
+#: stalls hunted are 5-200x.
 STALL_FACTOR = 3.0
 MEDIAN_TURNS = 256      # unstalled turns the running median looks back over
 MEDIAN_EVERY = 64       # ... and is taken anew every so many of them
@@ -69,15 +80,23 @@ MIN_TURNS = 16
 REGIME_TURNS = 16
 STALL_LOG = 256         # stalled turns the process keeps
 
+#: the kind of a source whose turns are all one (the trainer's log windows)
+ONE_KIND = "turn"
+
 #: Why a turn stalled, the first that holds. `compile`: a trace guard fired
-#: inside it. `capture`: a profiler capture started, ran or stopped. `gc`:
-#: collector pauses of at least half the excess. `host_busy` / `blocked`:
-#: the owner is a phase of the writer's thread, which spent at least half /
-#: under a tenth of it on the CPU (Python computing / asleep in the runtime,
-#: on a lock, or off the CPU). `caller`: the owner is the gap between two
-#: calls. `mixed`: none of them.
-CAUSES = ("compile", "capture", "gc", "host_busy", "blocked", "caller",
-          "mixed")
+#: inside it. `capture`: a profiler capture started or stopped in it or in
+#: the turn before (a turn that ran under one from end to end is judged as
+#: any other). `gc`: collector pauses of at least half the excess.
+#: `descheduled`: the writer thread stood runnable without a CPU, or the
+#: machine's CPUs were stolen from it, for at least half the excess
+#: (`sched_delay_ms` + `steal_ms`): the host's scheduler, a quota, a
+#: hypervisor. `caller`: the owner is the gap between two calls.
+#: `host_busy` / `blocked`: the owner is a phase of the writer's thread,
+#: which spent at least half / under a tenth of it on the CPU (Python
+#: computing / asleep with a CPU to be had: waiting on the runtime, the
+#: device or a lock). `mixed`: none of them.
+CAUSES = ("compile", "capture", "gc", "descheduled", "caller", "host_busy",
+          "blocked", "mixed")
 
 # process-wide: the collector's pauses, the stalled turns of every recorder
 # and the totals they are shares of
@@ -87,6 +106,73 @@ _totals: dict[str, dict] = {}           # source -> turns, seconds, causes
 _gc_seconds = [0.0, 0.0, 0.0]           # pause seconds by generation
 _gc_pauses = [0, 0, 0]
 _gc_open: Optional[tuple] = None        # (start stamp, annotation | None)
+_TICKS_PER_S = os.sysconf("SC_CLK_TCK") if hasattr(os, "sysconf") else 100
+
+
+# where a thread stood: the kernel's own counts, read on descriptors opened
+# once (None = not tried yet, False = the platform has none)
+_SCHEDSTAT = "/proc/thread-self/schedstat"
+_PROC_STAT = "/proc/stat"
+_tls = threading.local()                # .schedstat: the thread's own file
+_proc_stat = None                       # the machine's
+
+
+def _opened(path: str):
+    try:
+        return open(path, "rb", buffering=0)
+    except OSError:
+        return False
+
+
+def _field(f, n: int) -> Optional[int]:
+    """Field `n` of the first line of an open `/proc` file."""
+    if not f:
+        return None
+    try:
+        return int(os.pread(f.fileno(), 192, 0).split(None, n + 1)[n])
+    except (OSError, IndexError, ValueError):
+        return None
+
+
+def _stood() -> tuple:
+    """Where the calling thread has stood so far while it was off the CPU,
+    as the kernel counts it: (`delay_s`, `steal_s`, `nivcsw`), each None
+    where the platform keeps no such count.
+
+    `delay_s`: seconds the thread was runnable and had no CPU
+    (`/proc/thread-self/schedstat`, second field, ns): the host's scheduler
+    had others to run, or the CPU quota of the process's group was spent.
+    `steal_s`: seconds of CPU time stolen from the machine (`/proc/stat`,
+    the `cpu` line's `steal`, clock ticks of 10 ms), SUMMED over its CPUs
+    as that line has it: a hypervisor ran someone else on them. A stop of
+    the whole machine reads as many times its length as CPUs had work, and
+    a stolen CPU other than the thread's counts too; a thread asleep through
+    a theft has no run-queue wait to show, so this is the only sign of it.
+    `nivcsw`: the thread's involuntary context switches (`getrusage(
+    RUSAGE_THREAD)`).
+
+    Two `pread`s and one system call, fewer where a count is not kept. The
+    thread's file is opened by the thread itself, once (the path resolves to
+    whoever opens it)."""
+    global _proc_stat
+    sched = getattr(_tls, "schedstat", None)
+    if sched is None:
+        sched = _tls.schedstat = _opened(_SCHEDSTAT)
+    if _proc_stat is None:
+        _proc_stat = _opened(_PROC_STAT)
+        # a `cpu` line of zeros from end to end is a kernel that counts
+        # nothing (a sandboxed one, as on the benchmark's chip machines:
+        # PERF.md section 6, PR 57): no count, and not read again
+        if _proc_stat and not any(_field(_proc_stat, n)
+                                  for n in range(1, 9)):
+            _proc_stat.close()
+            _proc_stat = False
+    delay, steal = _field(sched, 1), _field(_proc_stat, 8)
+    nivcsw = None
+    if resource is not None and hasattr(resource, "RUSAGE_THREAD"):
+        nivcsw = resource.getrusage(resource.RUSAGE_THREAD).ru_nivcsw
+    return (None if delay is None else delay / 1e9,
+            None if steal is None else steal / _TICKS_PER_S, nivcsw)
 
 
 def _on_gc(when: str, info: dict) -> None:
@@ -110,8 +196,8 @@ def _on_gc(when: str, info: dict) -> None:
 
 def stall_log() -> list[dict]:
     """The process's stalled turns (the last `STALL_LOG`), newest last: the
-    whole flight record plus `source`, `owner`, `cause`, `excess_ms`,
-    `median_ms`."""
+    whole flight record (its `kind` and where its thread stood among it)
+    plus `source`, `owner`, `cause`, `excess_ms`, `median_ms` (its kind's)."""
     with _lock:
         return list(_stalls)
 
@@ -119,11 +205,15 @@ def stall_log() -> list[dict]:
 def stall_totals() -> dict:
     """What the stalls are shares of. Per source (`engine` | `train`):
     `turns`, `turn_seconds`, `gc_seconds` (pauses that ended inside its
-    turns) and per cause `count`, `excess_seconds`, `longest_ms`; for the
+    turns), `sched_delay_seconds` / `steal_seconds` (`_stood`, over its
+    turns), per cause `count`, `excess_seconds`, `longest_ms` and per kind
+    of turn `kinds: {kind: {turns, turn_seconds, median_ms}}` (the running
+    median its turns are judged by now, None before there is one); for the
     process the collector's pause seconds and pauses by generation."""
     with _lock:
-        return {"sources": {src: {**tot, "causes": {
-                    c: dict(v) for c, v in tot["causes"].items()}}
+        return {"sources": {src: {**tot, **{
+                    by: {k: dict(v) for k, v in tot[by].items()}
+                    for by in ("causes", "kinds")}}
                             for src, tot in _totals.items()},
                 "gc_pause_seconds": list(_gc_seconds),
                 "gc_pauses": list(_gc_pauses)}
@@ -132,11 +222,16 @@ def stall_totals() -> dict:
 def metric_families(source: str, prefix: str, host_prefix: str) -> dict:
     """The `/metrics` view of the totals, for `register_family` of a
     registry: `<prefix>_stalls_total{cause}`, `<prefix>_stall_seconds_total
-    {cause}` (excess seconds) of `source`'s turns, and the process's
+    {cause}` (excess seconds) of `source`'s turns, `<host_prefix>_sched_
+    delay_seconds_total{reason}` (seconds over those turns that their thread
+    stood runnable without a CPU, `run_queue`, and that were stolen from
+    the machine's CPUs, `steal`: `_stood`) and the process's
     `<host_prefix>_gc_pause_seconds_total{generation}`."""
+    def mine():
+        return stall_totals()["sources"].get(source, {})
+
     def causes(key):
-        return {c: v[key] for c, v in stall_totals()["sources"].get(
-            source, {}).get("causes", {}).items()}
+        return {c: v[key] for c, v in mine().get("causes", {}).items()}
     return {
         f"{prefix}_stalls_total": (
             "cause", lambda: causes("count"),
@@ -149,17 +244,27 @@ def metric_families(source: str, prefix: str, host_prefix: str) -> dict:
             "generation",
             lambda: dict(enumerate(stall_totals()["gc_pause_seconds"])),
             "seconds the cyclic collector paused the process"),
+        f"{host_prefix}_sched_delay_seconds_total": (
+            "reason",
+            lambda: {"run_queue": mine().get("sched_delay_seconds", 0.0),
+                     "steal": mine().get("steal_seconds", 0.0)},
+            f"seconds of the {source} turns their thread stood runnable "
+            "without a CPU (run_queue) and CPU seconds stolen from the "
+            "machine meanwhile (steal, summed over its CPUs)"),
     }
 
 
 def _cause(owner: str, owner_ms: float, excess_ms: float, rec: dict,
-           compiled: bool) -> str:
+           compiled: bool, capture_edge: bool) -> str:
     if compiled:
         return "compile"
-    if rec["capturing"]:
+    if capture_edge:
         return "capture"
     if rec["gc_ms"] >= 0.5 * excess_ms:
         return "gc"
+    if rec.get("sched_delay_ms", 0.0) + rec.get("steal_ms", 0.0) \
+            >= 0.5 * excess_ms:
+        return "descheduled"
     if owner == "gap":
         return "caller"
     if rec["cpu_ms"] >= 0.5 * owner_ms:
@@ -167,6 +272,46 @@ def _cause(owner: str, owner_ms: float, excess_ms: float, rec: dict,
     if rec["cpu_ms"] < 0.1 * owner_ms:
         return "blocked"
     return "mixed"
+
+
+class _Running:
+    """The unstalled turns of one kind and their running median, in ms."""
+
+    __slots__ = ("turns", "fed", "median", "stalled_in_a_row")
+
+    def __init__(self):
+        self.turns: collections.deque = collections.deque(
+            maxlen=MEDIAN_TURNS)
+        self.fed = 0
+        self.median: Optional[float] = None
+        self.stalled_in_a_row = 0
+
+    def judge(self, turn_ms: float, compiled: bool) -> Optional[float]:
+        """The turn's excess over the running median where it is stalled,
+        else None; an unstalled turn feeds the median."""
+        if compiled:
+            return max(turn_ms - (self.median or 0.0), 0.0)
+        if self.median is not None and turn_ms > STALL_FACTOR * self.median:
+            self.stalled_in_a_row += 1
+            excess = turn_ms - self.median
+            if self.stalled_in_a_row >= REGIME_TURNS:
+                self.turns.clear()
+                self.fed, self.median = 0, None
+            return excess
+        self.stalled_in_a_row = 0
+        self.turns.append(turn_ms)
+        self.fed += 1
+        # early on whenever the count doubles, so that a young recorder
+        # judges by more than its first few turns
+        if self.fed % MEDIAN_EVERY == 0 or (
+                MIN_TURNS <= self.fed < MEDIAN_EVERY
+                and self.fed & (self.fed - 1) == 0):
+            self.median = statistics.median(self.turns)
+        return None
+
+
+def _grown(now, was) -> Optional[float]:
+    return None if now is None or was is None else max(now - was, 0)
 
 
 class FlightRecorder:
@@ -197,15 +342,14 @@ class FlightRecorder:
         self._t0: Optional[float] = None
         self._t_in = 0.0
         self._cpu0 = 0.0
+        self._stood0: tuple = (None, None, None)
         self._gc0 = (0.0, (0, 0, 0))
-        # a capture is the open turn's | ran at the writer's last stamp
-        self._capturing = self._capture_on = False
-        # the running median of the unstalled turns, in ms
-        self._turns: collections.deque = collections.deque(
-            maxlen=MEDIAN_TURNS)
-        self._fed = 0
-        self._median: Optional[float] = None
-        self._stalled_in_a_row = 0
+        # a capture is the open turn's | ran at the writer's last stamp |
+        # has run at every stamp of the open turn
+        self._capturing = self._capture_on = self._capture_through = False
+        # the running median of the unstalled turns, a kind of turn
+        self._running: dict[str, _Running] = collections.defaultdict(
+            _Running)
         if _on_gc not in gc.callbacks:
             gc.callbacks.append(_on_gc)
 
@@ -235,27 +379,34 @@ class FlightRecorder:
         if not waited or self._t0 is None:
             self._t0 = t
             self._cpu0 = time.thread_time()
+            self._stood0 = _stood()
             self._gc0 = (sum(_gc_seconds), tuple(_gc_pauses))
-            self._capturing = on
+            self._capturing = self._capture_through = on
         else:
             # a capture that ran at the last record and was stopped in the
             # gap (writing it out takes seconds) is this turn's too
             self._capturing = self._capturing or on
+            self._capture_through = self._capture_through and on
         return t
 
     def record_turn(self, source: str, phases: dict, t1: float, *,
-                    compiled: bool = False, **fields) -> None:
+                    kind: Optional[str] = None, compiled: bool = False,
+                    **fields) -> None:
         """Close the open turn at stamp `t1` with one record of `fields`
-        plus the turn's own (module docstring), judge it, and open the next
-        at `t1`. `phases` = the ms of each part an `owner` can be
+        plus the turn's own (module docstring), judge it among the turns of
+        its `kind` (None: the source's turns are one kind), and open the
+        next at `t1`. `phases` = the ms of each part an `owner` can be
         (`gap` is added here); `compiled` = a trace guard fired inside."""
         if not self.enabled:
             return
         turn_ms = (t1 - self._t0) * 1e3
         cpu = time.thread_time()
+        stood = _stood()
         on = TraceAnnotation.is_enabled()
         gc_s, gc_n = sum(_gc_seconds), tuple(_gc_pauses)
         rec = fields
+        if kind is not None:
+            rec["kind"] = kind
         rec["t0"] = round(self._t0, 6)
         rec["turn_ms"] = round(turn_ms, 3)
         if self._t_in > self._t0:
@@ -269,32 +420,56 @@ class FlightRecorder:
         # growth since the last record, its caller's gap on this thread
         # included
         rec["cpu_ms"] = round(max(cpu - self._cpu0, 0.0) * 1e3, 3)
+        # where the thread stood meanwhile (`_stood`), on the same stamp:
+        # absent where the platform keeps no such count
+        delay, steal, nivcsw = map(_grown, stood, self._stood0)
+        if delay is not None:
+            rec["sched_delay_ms"] = round(delay * 1e3, 3)
+        if steal is not None:
+            rec["steal_ms"] = round(steal * 1e3, 3)
+        if nivcsw is not None:
+            rec["nivcsw"] = nivcsw
         rec["capturing"] = self._capturing or on
+        # started or stopped in this turn or stopped in the one before: a
+        # turn that ran under a capture from end to end is judged as any
+        capture_edge = rec["capturing"] and not (self._capture_through
+                                                 and on)
         # the next turn opens here, whether or not its writer says so. A
         # capture that went off since the writer's last stamp (another
         # thread stopped it) is written out for seconds yet: the next
         # turn's too
         self._t0 = self._t_in = t1
-        self._cpu0, self._gc0 = cpu, (gc_s, gc_n)
+        self._cpu0, self._stood0, self._gc0 = cpu, stood, (gc_s, gc_n)
         self._capturing, self._capture_on = on or self._capture_on, on
-        median = self._median or 0.0
-        stall = self._judge(turn_ms, compiled)
+        self._capture_through = on
+        kind = ONE_KIND if kind is None else kind
+        running = self._running[kind]
+        median = running.median or 0.0
+        stall = running.judge(turn_ms, compiled)
         if stall is not None:
             parts = dict(phases, gap=rec.get("gap_ms", 0.0))
             owner = max(parts, key=parts.get)
             rec.update(source=source, owner=owner,
                        cause=_cause(owner, parts[owner], stall, rec,
-                                    compiled),
+                                    compiled, capture_edge),
                        excess_ms=round(stall, 3),
                        median_ms=round(median, 3))
         self._append(rec)
         with _lock:
             tot = _totals.setdefault(source, {
                 "turns": 0, "turn_seconds": 0.0, "gc_seconds": 0.0,
-                "causes": {}})
+                "sched_delay_seconds": 0.0, "steal_seconds": 0.0,
+                "causes": {}, "kinds": {}})
             tot["turns"] += 1
             tot["turn_seconds"] += turn_ms / 1e3
             tot["gc_seconds"] += rec["gc_ms"] / 1e3
+            tot["sched_delay_seconds"] += delay or 0.0
+            tot["steal_seconds"] += steal or 0.0
+            of = tot["kinds"].setdefault(kind, {
+                "turns": 0, "turn_seconds": 0.0, "median_ms": None})
+            of["turns"] += 1
+            of["turn_seconds"] += turn_ms / 1e3
+            of["median_ms"] = running.median and round(running.median, 3)
             if stall is not None:
                 by = tot["causes"].setdefault(rec["cause"], {
                     "count": 0, "excess_seconds": 0.0, "longest_ms": 0.0})
@@ -302,30 +477,6 @@ class FlightRecorder:
                 by["excess_seconds"] += stall / 1e3
                 by["longest_ms"] = max(by["longest_ms"], rec["excess_ms"])
                 _stalls.append(rec)
-
-    def _judge(self, turn_ms: float, compiled: bool) -> Optional[float]:
-        """The turn's excess over the running median where it is stalled,
-        else None; an unstalled turn feeds the median."""
-        if compiled:
-            return max(turn_ms - (self._median or 0.0), 0.0)
-        if self._median is not None \
-                and turn_ms > STALL_FACTOR * self._median:
-            self._stalled_in_a_row += 1
-            excess = turn_ms - self._median
-            if self._stalled_in_a_row >= REGIME_TURNS:
-                self._turns.clear()
-                self._fed, self._median = 0, None
-            return excess
-        self._stalled_in_a_row = 0
-        self._turns.append(turn_ms)
-        self._fed += 1
-        # early on whenever the count doubles, so that a young recorder
-        # judges by more than its first few turns
-        if self._fed % MEDIAN_EVERY == 0 or (
-                MIN_TURNS <= self._fed < MEDIAN_EVERY
-                and self._fed & (self._fed - 1) == 0):
-            self._median = statistics.median(self._turns)
-        return None
 
     def __len__(self) -> int:
         return len(self._ring)

@@ -1,9 +1,11 @@
 """Turns and stalls of the always-on step ring (obs/flight.py): what a
 record gains (the caller's gap, the collector's pauses, the thread's CPU
-time), which turns are judged stalled, and who owns each injected fault on
-a small CPU engine."""
+time and where it stood off the CPU), which turns are judged stalled among
+the turns of their kind, and who owns each injected fault on a small CPU
+engine."""
 
 import gc
+import statistics
 import threading
 import time
 
@@ -35,6 +37,9 @@ class Clock:
     def __init__(self):
         self.now = 100.0
         self.cpu = 0.0
+        # what `flight._stood` reads: seconds on a run queue, seconds
+        # stolen from the machine, involuntary switches
+        self.delay, self.steal, self.nivcsw = 5.0, 6421.69, 40
 
     def perf_counter(self):
         return self.now
@@ -42,17 +47,24 @@ class Clock:
     def thread_time(self):
         return self.cpu
 
+    def stood(self):
+        return self.delay, self.steal, self.nivcsw
+
     monotonic = time = perf_counter
 
 
 def _turn(fl, clock, ms, *, gap_ms=0.0, cpu_ms=0.0, source="engine",
-          compiled=False, phases=PHASES, waited=True, **fields):
+          compiled=False, phases=PHASES, waited=True, kind=None,
+          delay_ms=0.0, steal_ms=0.0, **fields):
     """One scripted engine turn of `gap_ms` + `ms`."""
     clock.now += gap_ms / 1e3
     fl.begin_turn(waited)
     clock.now += ms / 1e3
     clock.cpu += cpu_ms / 1e3
-    fl.record_turn(source, phases, clock.now, compiled=compiled,
+    clock.delay += delay_ms / 1e3
+    clock.steal += steal_ms / 1e3
+    clock.nivcsw += bool(delay_ms)
+    fl.record_turn(source, phases, clock.now, kind=kind, compiled=compiled,
                    step_ms=ms, **fields)
     return fl.entries()[-1]
 
@@ -61,32 +73,88 @@ def _turn(fl, clock, ms, *, gap_ms=0.0, cpu_ms=0.0, source="engine",
 def scripted(monkeypatch):
     clock = Clock()
     monkeypatch.setattr(flight, "time", clock)
+    monkeypatch.setattr(flight, "_stood", clock.stood)
     return FlightRecorder(capacity=64), clock
 
 
 # ---- which turns are stalled ---------------------------------------------
 
+@pytest.mark.parametrize("kind", ["decode", "fused"])
 @pytest.mark.parametrize("factor, stalled", [(1.9, False), (2.9, False),
                                              (3.2, True), (40.0, True)])
 def test_a_long_turn_is_flagged_and_a_chunk_carrying_one_never(
-        scripted, factor, stalled):
-    """Plain programs of 10 ms with every other one 1.9x (a chunk-carrying
-    program): the median lies between, and only a turn over 3x it is
-    booked, with its excess over the median."""
+        scripted, factor, stalled, kind):
+    """Plain programs of 10 ms with every other one a chunk-carrying
+    program of 2.9x (Laguna's 10.134 / 29.208 ms, ledger PR 56): each kind
+    has its own median, and only a turn over 3x the median of ITS kind is
+    booked, with its excess over that median."""
     fl, clock = scripted
-    for i in range(80):
-        rec = _turn(fl, clock, 19.0 if i % 2 else 10.0)
-        assert "cause" not in rec
-    rec = _turn(fl, clock, 14.5 * factor)
+    medians = {"decode": 10.0, "fused": 29.0}
+    for i in range(160):
+        k = "fused" if i % 2 else "decode"
+        rec = _turn(fl, clock, medians[k], kind=k)
+        assert "cause" not in rec and rec["kind"] == k
+    rec = _turn(fl, clock, medians[kind] * factor, kind=kind)
     assert ("cause" in rec) == stalled
     assert len(flight.stall_log()) == int(stalled)
     if stalled:
-        assert rec["median_ms"] == 14.5
-        assert rec["excess_ms"] == pytest.approx(14.5 * (factor - 1))
+        assert rec["median_ms"] == medians[kind] and rec["kind"] == kind
+        assert rec["excess_ms"] == pytest.approx(
+            medians[kind] * (factor - 1))
         assert flight.stall_log()[-1] is rec and rec["source"] == "engine"
     tot = flight.stall_totals()["sources"]["engine"]
-    assert tot["turns"] == 81
+    assert tot["turns"] == 161
     assert sum(c["count"] for c in tot["causes"].values()) == int(stalled)
+    assert {k: v["median_ms"] for k, v in tot["kinds"].items()} == medians
+    assert sum(v["turns"] for v in tot["kinds"].values()) == 161
+
+
+#: (plain ms, chunk-carrying ms from .. to) of the ledger's two-program
+#: loops: Laguna (PR 56: 10.134 / 29.208, far-offset chunks to 48) and the
+#: long-context cell PR 53 was refused with (15.172 / 64.864 = 4.3x)
+LOOPS = {"laguna_2.9x": (10.0, 30.0, 48.0), "pr53_4.3x": (15.0, 65.0, 65.0)}
+
+
+def _two_program_loop(fl, clock, shape, turns=1000):
+    plain, lo, hi = LOOPS[shape]
+    for i in range(turns):
+        if i % 4 == 3:      # a chunk-carrying program every fourth
+            ms, k = lo + (hi - lo) * ((i // 4) % 7) / 6.0, "fused"
+        else:
+            ms, k = plain, "decode"
+        assert "cause" not in _turn(fl, clock, ms, kind=k)
+    return statistics.median(
+        lo + (hi - lo) * j / 6.0 for j in range(7)) if hi > lo else lo
+
+
+@pytest.mark.parametrize("kind", ["decode", "fused"])
+@pytest.mark.parametrize("shape", list(LOOPS))
+def test_a_loop_of_two_programs_books_the_stop_alone(scripted, shape, kind):
+    """1,000 turns of a loop whose chunk-carrying program is 2.9-4.8x or
+    4.3x its plain one book nothing (one median over both booked 16-21% of
+    their seconds: ledger, PR 53 and Laguna before PR 50), and a stop of
+    120 ms in a turn of either kind is booked with its excess over the
+    median of its own kind."""
+    fl, clock = scripted
+    fused = _two_program_loop(fl, clock, shape)
+    assert not flight.stall_log()
+    median = {"decode": LOOPS[shape][0], "fused": fused}[kind]
+    rec = _turn(fl, clock, median + 120.0, kind=kind)
+    if median + 120.0 > flight.STALL_FACTOR * median:
+        assert rec["kind"] == kind and rec["median_ms"] == median
+        assert rec["excess_ms"] == pytest.approx(120.0)
+        assert flight.stall_log() == [rec]
+    else:
+        # a 65 ms program with the stop inside it is 2.85x: under the
+        # factor, as any turn of one kind of that length
+        assert (shape, kind) == ("pr53_4.3x", "fused")
+        assert "cause" not in rec
+        assert _turn(fl, clock, 3.1 * median, kind=kind)["median_ms"] \
+            == median
+    by = flight.stall_totals()["sources"]["engine"]["kinds"]
+    assert by["decode"]["turns"] + by["fused"]["turns"] == 1001 + (
+        "cause" not in rec)
+    assert by["decode"]["median_ms"] == LOOPS[shape][0]
 
 
 def test_no_median_no_verdict_and_a_stall_does_not_feed_it(scripted):
@@ -98,6 +166,43 @@ def test_no_median_no_verdict_and_a_stall_does_not_feed_it(scripted):
         _turn(fl, clock, 10.0)
     for _ in range(flight.REGIME_TURNS - 1):
         assert _turn(fl, clock, 100.0)["median_ms"] == 10.0
+
+
+def test_a_kind_is_young_on_its_own(scripted):
+    """A kind's first `MIN_TURNS` turns get no verdict, however old the
+    recorder is by its other kind's turns; from then on they do."""
+    fl, clock = scripted
+    for _ in range(200):
+        _turn(fl, clock, 10.0, kind="decode")
+    for _ in range(flight.MIN_TURNS - 1):
+        assert "cause" not in _turn(fl, clock, 30.0, kind="fused")
+    assert "cause" not in _turn(fl, clock, 500.0, kind="fused")
+    assert _turn(fl, clock, 500.0, kind="decode")["median_ms"] == 10.0
+    assert "cause" not in _turn(fl, clock, 30.0, kind="fused")
+    rec = _turn(fl, clock, 500.0, kind="fused")
+    assert (rec["kind"], rec["median_ms"]) == ("fused", 30.0)
+    assert flight.stall_totals()["sources"]["engine"]["kinds"]["fused"] == {
+        "turns": flight.MIN_TURNS + 2, "median_ms": 30.0,
+        "turn_seconds": pytest.approx(
+            (flight.MIN_TURNS * 30.0 + 1000.0) / 1e3)}
+
+
+def test_another_load_of_one_kind_leaves_the_others_median_alone(scripted):
+    """`REGIME_TURNS` long turns in a row of ONE kind (longer chunks from
+    here on) relearn that kind's median; the plain programs between them
+    neither break the row nor lose their own."""
+    fl, clock = scripted
+    for chunk_ms, turns in ((30.0, 280), (100.0, 400)):
+        for i in range(turns):
+            if i % 4 == 3:
+                _turn(fl, clock, chunk_ms, kind="fused")
+            else:
+                _turn(fl, clock, 10.0, kind="decode")
+    log = flight.stall_log()
+    assert len(log) == flight.REGIME_TURNS
+    assert {s["kind"] for s in log} == {"fused"}
+    assert _turn(fl, clock, 400.0, kind="fused")["median_ms"] == 100.0
+    assert _turn(fl, clock, 31.0, kind="decode")["median_ms"] == 10.0
 
 
 def test_another_load_is_learnt_anew(scripted):
@@ -123,7 +228,18 @@ def test_another_load_is_learnt_anew(scripted):
     (dict(cpu_ms=90.0), ("dispatch", "mixed")),
     (dict(gap=True, cpu_ms=1.0), ("gap", "caller")),
     (dict(gap=True, gc=160.0), ("gap", "gc")),
-], ids=lambda v: v[1] if isinstance(v, tuple) else None)
+    # where the thread stood: on a run queue, or the machine's CPUs stolen
+    (dict(cpu_ms=20.0, delay_ms=150.0), ("dispatch", "descheduled")),
+    (dict(cpu_ms=20.0, steal_ms=150.0), ("dispatch", "descheduled")),
+    (dict(cpu_ms=20.0, delay_ms=80.0, steal_ms=70.0),
+     ("dispatch", "descheduled")),
+    (dict(cpu_ms=20.0, delay_ms=70.0, steal_ms=70.0),
+     ("dispatch", "blocked")),
+    (dict(cpu_ms=250.0, delay_ms=40.0), ("dispatch", "host_busy")),
+    (dict(gc=160.0, delay_ms=200.0), ("dispatch", "gc")),
+    (dict(gap=True, delay_ms=290.0), ("gap", "descheduled")),
+    (dict(capturing=True, delay_ms=290.0), ("dispatch", "capture")),
+], ids=lambda v: None if isinstance(v, tuple) else "-".join(v))
 def test_cause_is_the_first_that_holds(scripted, monkeypatch, case, want):
     """A 310 ms turn against a median of 10: excess 300."""
     fl, clock = scripted
@@ -140,21 +256,93 @@ def test_cause_is_the_first_that_holds(scripted, monkeypatch, case, want):
         monkeypatch.setattr(flight, "_gc_seconds", seconds)
         monkeypatch.setattr(flight, "_gc_pauses", pauses)
     long = dict(PHASES, dispatch=301.0)
+    stood = {k: case.get(k, 0.0) for k in ("cpu_ms", "delay_ms", "steal_ms")}
     if case.get("gap"):
-        rec = _turn(fl, clock, 10.0, gap_ms=300.0,
-                    cpu_ms=case.get("cpu_ms", 0.0))
+        rec = _turn(fl, clock, 10.0, gap_ms=300.0, **stood)
     else:
-        rec = _turn(fl, clock, 310.0, phases=long,
-                    cpu_ms=case.get("cpu_ms", 0.0),
+        rec = _turn(fl, clock, 310.0, phases=long, **stood,
                     compiled=case.get("compiled", False))
     assert (rec["owner"], rec["cause"]) == want
     assert rec["excess_ms"] == pytest.approx(300.0)
+    assert rec["sched_delay_ms"] == pytest.approx(stood["delay_ms"])
+    assert rec["steal_ms"] == pytest.approx(stood["steal_ms"])
+    assert rec["nivcsw"] == bool(stood["delay_ms"])
     if "gc" in case:
         assert rec["gc_ms"] == pytest.approx(case["gc"])
         assert rec["gc_gen"] == 2
-    by = flight.stall_totals()["sources"]["engine"]["causes"][want[1]]
-    assert by == {"count": 1, "excess_seconds": pytest.approx(0.3),
-                  "longest_ms": pytest.approx(300.0)}
+    tot = flight.stall_totals()["sources"]["engine"]
+    assert tot["causes"][want[1]] == {
+        "count": 1, "excess_seconds": pytest.approx(0.3),
+        "longest_ms": pytest.approx(300.0)}
+    assert tot["sched_delay_seconds"] == pytest.approx(
+        stood["delay_ms"] / 1e3)
+    assert tot["steal_seconds"] == pytest.approx(stood["steal_ms"] / 1e3)
+
+
+def test_a_turn_under_a_capture_from_end_to_end_is_judged_as_any(
+        scripted, monkeypatch):
+    """`capture` is the capture's start and stop (each takes seconds). A
+    stop in the middle of a traced slice has another owner: the record says
+    `capturing`, the cause is what the turn's own fields say."""
+    fl, clock = scripted
+    for _ in range(flight.MIN_TURNS):
+        _turn(fl, clock, 10.0)
+    monkeypatch.setattr(flight.TraceAnnotation, "is_enabled",
+                        staticmethod(lambda: True))
+    first = _turn(fl, clock, 130.0)             # it came on in this turn
+    assert (first["capturing"], first["cause"]) == (True, "capture")
+    assert "cause" not in _turn(fl, clock, 10.0)
+    rec = _turn(fl, clock, 130.0, delay_ms=118.0)
+    assert (rec["capturing"], rec["cause"]) == (True, "descheduled")
+    rec = _turn(fl, clock, 130.0, phases=dict(PHASES, wait=127.0))
+    assert (rec["capturing"], rec["owner"], rec["cause"]) == (
+        True, "wait", "blocked")
+
+
+def test_a_platform_without_the_counts_books_no_field(monkeypatch, tmp_path):
+    """No `/proc/thread-self/schedstat`, no `/proc/stat`, no
+    `RUSAGE_THREAD`: a record has none of the three fields, a stalled turn
+    is judged by the rest, and nothing raises. Where the files are there
+    (Linux) the real reads give numbers that never shrink."""
+    real = flight._stood()
+    again = flight._stood()
+    for a, b in zip(real, again):
+        assert (a is None) == (b is None) and (a is None or b >= a >= 0)
+    monkeypatch.setattr(flight, "_tls", threading.local())
+    monkeypatch.setattr(flight, "_proc_stat", None)
+    monkeypatch.setattr(flight, "_SCHEDSTAT", str(tmp_path / "none"))
+    monkeypatch.setattr(flight, "_PROC_STAT", str(tmp_path / "none"))
+    monkeypatch.setattr(flight, "resource", None)
+    assert flight._stood() == (None, None, None)
+    # a file that is there and holds something else is no count either,
+    # nor is the `cpu` line of a sandboxed kernel that counts nothing (the
+    # benchmark's chip machines): zeros from end to end
+    for text in ("cpu  1 2 3\n", "cpu  0 0 0 0 0 0 0 0 0 0\ncpu0 0 0\n"):
+        (tmp_path / "stat").write_text(text)
+        monkeypatch.setattr(flight, "_proc_stat", None)
+        monkeypatch.setattr(flight, "_PROC_STAT", str(tmp_path / "stat"))
+        assert flight._stood() == (None, None, None)
+    assert flight._proc_stat is False           # not read again
+    (tmp_path / "stat").write_text("cpu  5 0 7 900 1 0 2 300 0 0\n")
+    monkeypatch.setattr(flight, "_proc_stat", None)
+    assert flight._stood() == (None, 300 / flight._TICKS_PER_S, None)
+    monkeypatch.setattr(flight, "_proc_stat", None)
+    monkeypatch.setattr(flight, "_PROC_STAT", str(tmp_path / "none"))
+    fl = FlightRecorder(capacity=64)
+    for _ in range(flight.MIN_TURNS + 1):
+        fl.begin_turn(True)
+        time.sleep(0.002)
+        fl.record_turn("engine", PHASES, time.perf_counter(), kind="decode")
+    fl.begin_turn(True)
+    time.sleep(0.05)
+    fl.record_turn("engine", dict(PHASES, wait=50.0), time.perf_counter(),
+                   kind="decode")
+    rec = fl.entries()[-1]
+    assert (rec["owner"], rec["cause"]) == ("wait", "blocked")
+    for r in fl.entries():
+        assert not {"sched_delay_ms", "steal_ms", "nivcsw"} & set(r)
+    tot = flight.stall_totals()["sources"]["engine"]
+    assert tot["sched_delay_seconds"] == tot["steal_seconds"] == 0.0
 
 
 @pytest.mark.parametrize("waited, inside, want", [
@@ -374,6 +562,30 @@ def test_an_injected_fault_names_its_owner(monkeypatch, fault, want):
         assert rec["median_ms"] < 0.2 * FAULT_S * 1e3
 
 
+def test_an_engines_records_carry_their_programs_kind():
+    """The engine hands the drained program's kind: a chunk-carrying
+    program's record says `fused`, a plain one's `decode`, the stalled
+    ones among them (the first call compiles) too, and the totals
+    count the turns kind by kind; on Linux every record also says where
+    the thread stood."""
+    eng = _engine()
+    _warm(eng)
+    recs = eng.flight.entries()
+    assert {r["kind"] for r in recs} == {"decode", "fused"}
+    for r in recs:
+        assert (r["kind"] == "fused") == (r["prefill_tokens"] > 0)
+    log = flight.stall_log()
+    assert log and log[0]["cause"] == "compile"
+    assert all(s["kind"] in ("decode", "fused") for s in log)
+    by = flight.stall_totals()["sources"]["engine"]["kinds"]
+    assert {k: v["turns"] for k, v in by.items()} == {
+        k: sum(r["kind"] == k for r in recs) for k in ("decode", "fused")}
+    if flight._stood() != (None, None, None):
+        for r in recs:
+            assert r["sched_delay_ms"] >= 0 and r["steal_ms"] >= 0
+            assert r["nivcsw"] >= 0
+
+
 def test_a_first_call_that_traces_is_a_compile_and_idle_time_no_gap():
     """The first step of an engine traces its program: booked as `compile`
     whatever the median. A sleep while NO slot is live is nobody's gap:
@@ -430,6 +642,7 @@ def test_metric_families_render_the_totals(scripted):
     for _ in range(flight.MIN_TURNS):
         _turn(fl, clock, 10.0)
     _turn(fl, clock, 10.0, gap_ms=190.0)
+    _turn(fl, clock, 10.0, delay_ms=150.0, steal_ms=30.0)
     metrics = ServeMetrics()
     for name, family in flight.metric_families(
             "engine", "serve_engine", "serve_host").items():
@@ -443,3 +656,8 @@ def test_metric_families_render_the_totals(scripted):
     for gen in "012":
         assert f'serve_host_gc_pause_seconds_total{{generation="{gen}"}}' \
             in text
+    assert "# TYPE serve_host_sched_delay_seconds_total counter" in text
+    for reason, want in (("run_queue", 0.15), ("steal", 0.03)):
+        line = next(ln for ln in text.splitlines() if ln.startswith(
+            f'serve_host_sched_delay_seconds_total{{reason="{reason}"}}'))
+        assert float(line.split()[-1]) == pytest.approx(want)

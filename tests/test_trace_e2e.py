@@ -331,15 +331,27 @@ def test_debug_timeline_after_load_burst(mv):
                 "emitted", "blocks_in_use", "preemptions",
                 "overlapped", "drain_reason", "overrun",
                 # the turn (obs/flight.py)
-                "t0", "turn_ms", "gc_ms", "cpu_ms", "capturing"} <= set(e)
+                "t0", "turn_ms", "gc_ms", "cpu_ms", "capturing",
+                "kind"} <= set(e)
     # the process's stalled turns ride beside the ring, newest last: this
     # engine's first step traced its program
     stalls = [s for s in body["stalls"] if s["source"] == "engine"]
     assert stalls and stalls == sorted(stalls, key=lambda s: s["t"])
     assert {"owner", "cause", "excess_ms", "median_ms"} <= set(stalls[-1])
     assert "compile" in {s["cause"] for s in stalls}
+    # each says its program's kind and, where the platform counts it
+    # (Linux), where its thread stood
+    stood = {"sched_delay_ms", "steal_ms", "nivcsw"}
+    for s in stalls:
+        assert s["kind"] in ("decode", "fused", "spec")
+        assert stood <= set(s) or not stood & set(entries[-1])
     totals = body["stall_totals"]
     assert totals["sources"]["engine"]["turns"] >= len(entries)
+    kinds = totals["sources"]["engine"]["kinds"]
+    assert sum(k["turns"] for k in kinds.values()) \
+        == totals["sources"]["engine"]["turns"]
+    assert 'serve_host_sched_delay_seconds_total{reason="run_queue"}' \
+        in metrics
     assert len(totals["gc_pause_seconds"]) == 3
     assert "# TYPE serve_engine_stalls_total counter" in metrics
     assert 'serve_engine_stalls_total{cause="compile"}' in metrics

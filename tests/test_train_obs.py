@@ -245,6 +245,11 @@ def test_metrics_endpoint_serves_mid_run(in_tmp):
         assert first["owner"] in ("data", "dispatch", "sync", "drain")
         assert tl["stall_totals"]["sources"]["train"]["turns"] >= 1
         assert 'train_stalls_total{cause="compile"}' in text
+        assert 'train_host_sched_delay_seconds_total{reason="steal"}' \
+            in text
+        # the trainer hands no kind: its log windows are one
+        assert list(tl["stall_totals"]["sources"]["train"]["kinds"]) \
+            == ["turn"] and "kind" not in first
         assert "train_stall_seconds_total" in text
         assert 'train_host_gc_pause_seconds_total{generation="0"}' in text
 

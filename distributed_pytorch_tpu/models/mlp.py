@@ -542,9 +542,13 @@ class RoutedExperts(nn.Module):
         if row_mask is not None:
             local = idx - first
             held = (local >= 0) & (local < n_held) & row_mask[:, None]
-            tokens = jnp.zeros((n_held,), jnp.int32).at[
-                jnp.where(held, local, n_held).reshape(-1)].add(
-                    1, mode="drop")
+            # a one-hot's column sums, as the packing counts them
+            # (ops/grouped_matmul.py held_packing): the chip walks a
+            # scatter-add index by index
+            tokens = jnp.sum(
+                jnp.where(held, local, -1).reshape(-1, 1)
+                == np.arange(n_held, dtype=np.int32), axis=0,
+                dtype=jnp.int32)
             stats = {"tokens": tokens[None],
                      "absent": (jnp.sum(row_mask) * idx.shape[1]
                                 - jnp.sum(tokens)).astype(jnp.int32)[None]}

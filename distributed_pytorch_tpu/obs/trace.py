@@ -429,10 +429,11 @@ MIXER_SCOPES = {
                    "(ops/grouped_matmul.py held_experts_ffn)",
     "moe_shared": "the shared expert's two matmuls (models/mlp.py)",
     # inside `moe_experts`, around what is not a kernel (PR 38)
-    "moe_pack": "the sort of the assignments by held expert, the counts "
-                "and tile table, and the gather of the rows into the "
-                "packed (P, C) buffer (ops/grouped_matmul.py "
-                "held_experts_ffn)",
+    "moe_pack": "where each assignment to a held expert goes: counts, "
+                "ranks and slots from the one-hot of the assignments, the "
+                "tile table, ONE scatter for the packing's inverse "
+                "(ops/grouped_matmul.py held_packing), and the gather of "
+                "the rows into the packed (P, C) buffer (held_experts_ffn)",
     "moe_combine": "the token-side sum: one gather of every token's k "
                    "rows of the packed float32 result, by the packing's "
                    "inverse, added in the router's order by written-out "

@@ -61,6 +61,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -622,6 +623,95 @@ def held_tile_rows(n_tokens: int, k: int, n_routed: int) -> int:
         math.log2(m + 3.0 * math.sqrt(m))))))
 
 
+_RANK_BLOCK = 128    # assignments a block of the running count
+
+
+def _earlier(n: int):
+    """(n, n) numpy 0/1: [i, j] = 1 where i < j. `sum_i x[i] * [i, j]` is
+    the exclusive running sum of x."""
+    return np.arange(n)[:, None] < np.arange(n)[None, :]
+
+
+def held_packing(topk_idx: jnp.ndarray, topk_gates: jnp.ndarray, *,
+                 first: int, n_held: int, bm: int):
+    """Where each assignment to a held expert goes in the packed buffer, and
+    what each packed row and tile is. Returns `row_tok` (P,) int32 the token
+    a row holds, `row_gate` (P, 1) float32 its gate, `group` (n_tiles,)
+    int32 the expert a tile belongs to, `n_used` (1,) int32 the tiles that
+    hold rows, `slot_of` (N, k) int32 the packing's inverse in the router's
+    order: the row assignment j of token t got, P = none. P = n_tiles x
+    `bm`, n_tiles = ceil(N k / bm) + n_held. An expert's rows fill tiles of
+    its own, the experts in order, an expert's rows in the order of the
+    flattened (N, k) assignments (what a stable sort by expert gives); a
+    pad row keeps token 0 with gate 0.0; the tiles past `n_used` repeat the
+    last used tile's expert, so that they move no bytes.
+
+    No sort, and no indexing an element at a time but ONE scatter: the chip
+    walks an int32 gather or scatter index by index (3-6 ns an element: 35
+    to 65 us each at the 10,880 assignments of a 1,088-row call, and a
+    call had eight to ten beside a sort, my chip run, PR 58), and every
+    one of them stood between the router and the first byte of expert
+    weights. What is left are dense ops over the one-hot of the
+    assignments, (n_held, blocks, 128) with the assignments on the lanes.
+    An assignment's rank among its expert's is the exclusive running count
+    along its expert's row of the one-hot: inside a block of 128 a product
+    with a strictly triangular 0/1 matrix (exact at any matmul precision:
+    the operands are 0 or 1 and float32 adds them), across blocks the
+    running sum of the blocks' int32 totals. Its slot is its expert's
+    first row + its rank, picked by the one-hot itself, and is already in
+    the router's order: `slot_of` is a reshape. The one scatter writes
+    (token, the gate's bits) of every kept assignment at its slot."""
+    N, k = topk_idx.shape
+    A = N * k
+    n_tiles = -(-A // bm) + n_held
+    P = n_tiles * bm
+    assert P < 2 ** 24, P     # float32 holds every slot exactly
+    B = _RANK_BLOCK
+    nb = -(-A // B)
+    i32, f32 = jnp.int32, jnp.float32
+
+    def before(x):
+        """Exclusive running sum along the last axis."""
+        return jnp.sum(jnp.where(_earlier(x.shape[-1]), x[..., :, None], 0),
+                       axis=-2)
+
+    # an absent expert, a masked row (-1) and the last block's pads match
+    # no held expert: a column of zeros
+    e = jnp.pad(topk_idx.reshape(-1).astype(i32) - first,
+                (0, nb * B - A), constant_values=-1).reshape(nb, B)
+    hot = e[None] == np.arange(n_held, dtype=np.int32)[:, None, None]
+    in_block = jnp.sum(hot, axis=2, dtype=i32)            # (n_held, nb)
+    counts = jnp.sum(in_block, axis=1)
+    padded = -(-counts // bm) * bm                        # whole tiles
+    pstart = before(padded)                               # (n_held,)
+    rank = jnp.einsum("gbj,ji->gbi", hot.astype(f32),
+                      _earlier(B).astype(np.float32),
+                      preferred_element_type=f32)
+    base = (pstart[:, None] + before(in_block))[:, :, None].astype(f32)
+    slot = jnp.sum(jnp.where(hot, base + rank, 0.0), axis=0).astype(i32)
+    slot = jnp.where((e >= 0) & (e < n_held), slot, P)    # P: dropped
+    slot = slot.reshape(-1)[:A]
+
+    tok = np.arange(A, dtype=np.int32) // k
+    gate_bits = jax.lax.bitcast_convert_type(
+        topk_gates.reshape(-1).astype(f32), i32)
+    rows = jnp.zeros((P, 2), i32).at[slot].set(
+        jnp.stack([tok, gate_bits], axis=1), mode="drop")
+    row_tok = rows[:, 0]
+    row_gate = jax.lax.bitcast_convert_type(rows[:, 1:], f32)
+
+    tile_start = pstart // bm
+    n_used = jnp.sum(padded) // bm
+    # a tile's expert: the last whose first tile is not past it; an unused
+    # tile asks as the last used one
+    t = jnp.minimum(np.arange(n_tiles, dtype=np.int32),
+                    jnp.maximum(n_used - 1, 0))
+    group = jnp.clip(
+        jnp.sum(tile_start[None, :] <= t[:, None], axis=1, dtype=i32) - 1,
+        0, n_held - 1)
+    return row_tok, row_gate, group, n_used.reshape(1), slot.reshape(N, k)
+
+
 def held_experts_ffn(x_flat: jnp.ndarray, topk_idx: jnp.ndarray,
                      topk_gates: jnp.ndarray, w_up: jnp.ndarray,
                      w_down: jnp.ndarray, *, first: int, n_routed: int,
@@ -661,43 +751,14 @@ def held_experts_ffn(x_flat: jnp.ndarray, topk_idx: jnp.ndarray,
     k = topk_idx.shape[1]
     n_held = w_up.shape[0]
     bm = held_tile_rows(N, k, n_routed)
-    A = N * k
-    n_tiles = -(-A // bm) + n_held
-    P = n_tiles * bm
 
     # the two scopes (obs/trace.py MIXER_SCOPES) are names on the ops and
     # nothing else
     with jax.named_scope("moe_pack"):
-        e = topk_idx.reshape(-1).astype(jnp.int32) - first
-        held = (e >= 0) & (e < n_held)
-        e = jnp.where(held, e, n_held)        # absent: sorted last, dropped
-        tok = jnp.repeat(jnp.arange(N, dtype=jnp.int32), k)
-        order = jnp.argsort(e, stable=True)
-        se = e[order]
-        counts = jnp.zeros((n_held + 1,), jnp.int32).at[e].add(1)
-        padded = -(-counts // bm) * bm
-        pstart = jnp.cumsum(padded) - padded
-        starts = jnp.cumsum(counts) - counts
-        slot = pstart[se] + jnp.arange(A, dtype=jnp.int32) - starts[se]
-        slot = jnp.where(se < n_held, slot, P)    # out of range: dropped
-        row_tok = jnp.zeros((P,), jnp.int32).at[slot].set(tok[order],
-                                                          mode="drop")
-        row_gate = jnp.zeros((P, 1), jnp.float32).at[slot, 0].set(
-            topk_gates.reshape(-1).astype(jnp.float32)[order], mode="drop")
-        tile_start = pstart // bm
-        n_used = tile_start[n_held]
-        t = jnp.arange(n_tiles, dtype=jnp.int32)
-        group = jnp.clip(
-            jnp.searchsorted(tile_start[:n_held], t, side="right") - 1,
-            0, n_held - 1).astype(jnp.int32)
-        group = jnp.where(t < n_used, group,
-                          group[jnp.maximum(n_used - 1, 0)])
-        n_used = n_used.reshape(1)
+        row_tok, row_gate, group, n_used, slot_of = held_packing(
+            topk_idx, topk_gates, first=first, n_held=n_held, bm=bm)
         packed = x_flat[row_tok]
-        # the packing's inverse, in the router's order: where assignment j
-        # of token t went (P: nowhere)
-        slot_of = jnp.zeros((A,), jnp.int32).at[order].set(
-            slot, unique_indices=True).reshape(N, k)
+    P = packed.shape[0]
 
     dt = x_flat.dtype
     h = _held_up_call(packed, w_up.astype(dt), group, n_used, bm, interpret,

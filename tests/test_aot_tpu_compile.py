@@ -319,6 +319,22 @@ def test_auto_trains_on_the_flash_kernels(shape, v5e, monkeypatch):
 FFN_ACT = "16,1024,3072"            # the train cell's activation, B x T x up
 
 
+def _computations(text):
+    """{computation: its instructions' lines} of a compiled program's text;
+    the entry computation under "ENTRY"."""
+    bodies, name = {}, None
+    for ln in text.splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", ln)
+        if head:
+            name = "ENTRY" if head.group(1) else head.group(2)
+            bodies[name] = []
+        elif name and ln.startswith("}"):
+            name = None
+        elif name:
+            bodies[name].append(ln)
+    return bodies
+
+
 @pytest.fixture(scope="module")
 def ffn_step_fusions(v5e):
     """Value-and-grad of one FFN at the train cell's shapes (16 x 1024 x
@@ -334,16 +350,7 @@ def ffn_step_fusions(v5e):
     text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)),
                     [((16, 1024, C), BF16), ((C, 3072), F32),
                      ((3072, C), F32), ((16, 1024, C), F32)], v5e).as_text()
-    bodies, name = {}, None
-    for ln in text.splitlines():
-        head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", ln)
-        if head:
-            name = "ENTRY" if head.group(1) else head.group(2)
-            bodies[name] = []
-        elif name and ln.startswith("}"):
-            name = None
-        elif name:
-            bodies[name].append(ln)
+    bodies = _computations(text)
     fusions = {}
     for ln in bodies["ENTRY"]:
         m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) fusion\(.*"
@@ -669,6 +676,67 @@ def test_the_combine_gathers_a_tokens_rows_and_scatters_none(n_tokens,
         assert f"f32[{n},{k},{C}]" not in text
         assert re.search(rf"= f32\[{n * k},{C}\]\S* fusion\(.*"
                          r"moe_combine/gather", text), (n, k, C)
+
+
+def _entry_ops_under(text, scope):
+    """[(opcode, result type, opcodes inside)] of the ENTRY computation's
+    instructions whose `op_name` holds `scope`; `opcodes inside` a fusion
+    are those of the computation it calls and of the ones that one calls."""
+    comps = _computations(text)
+    inst = re.compile(
+        r"\s+(?:ROOT )?%?[\w.\-]+ = (\(.*?\)|\S+) ([\w\-]+)\(")
+
+    def inside(ln):
+        found = set()
+        for name in re.findall(r"(?:calls|to_apply)=%?([\w.\-]+)", ln):
+            for inner in comps.get(name, []):
+                m = inst.match(inner)
+                if m:
+                    found |= {m.group(2)} | inside(inner)
+        return found
+
+    ops = []
+    for ln in comps["ENTRY"]:
+        m = inst.match(ln)
+        if m and f"/{scope}/" in ln:
+            ops.append((m.group(2), m.group(1), inside(ln)))
+    return ops
+
+
+# the ceiling: what a call holds today, the row gather and the scatter among
+# them (17 a decode call, 20 a merged one; the parent held 23 to 26, counted
+# the same way, AND a sort and a loop)
+PACK_FUSIONS = 20
+
+
+@EXPERT_WIDTHS
+@pytest.mark.parametrize("n_tokens", [64, (256, 64)], ids=["64", "256+64"])
+def test_the_packing_walks_no_index_but_one_scatters(n_tokens, widths, v5e):
+    """Under `moe_pack` a call holds no sort, no loop, ONE scatter (the
+    packing's inverse), the row gather and no other gather beyond two: the
+    chip walks an int32 gather or scatter index by index (35 to 65 us each
+    at 10,880 assignments, my chip run, PR 58); eight to ten of them, a
+    sort and a loop stood between the router and the first byte of expert
+    weights, and each had come in as one convenient `.at[]` or `[order]`.
+    The small dense fusions that replaced them read 0.01 to 0.6 us each;
+    their count has a ceiling all the same, so that the chain cannot grow
+    back."""
+    text, shapes = _compiled_experts(n_tokens, widths, v5e)
+    C = shapes[0][0][1]
+    ops = _entry_ops_under(text, "moe_pack")
+    assert ops, "no op of the program carries the scope"
+    walked = [(op, typ) for op, typ, inside in ops
+              if ({op} | inside) & {"sort", "while"}]
+    assert not walked, walked
+    fusions = [(typ, inside) for op, typ, inside in ops if op == "fusion"]
+    scatters = [typ for typ, inside in fusions if "scatter" in inside]
+    gathers = [typ for typ, inside in fusions if "gather" in inside]
+    rows = [typ for typ in gathers if re.match(rf"bf16\[\d+,{C}\]", typ)]
+    assert len(scatters) <= 1, scatters
+    assert len(rows) == 1 and len(gathers) - 1 <= 2, gathers
+    assert len(fusions) <= PACK_FUSIONS, (len(fusions), fusions)
+    loose = [op for op, _, _ in ops if op in ("scatter", "gather")]
+    assert not loose, loose
 
 
 @pytest.mark.parametrize("line, H, P, G, N", [

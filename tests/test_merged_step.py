@@ -360,6 +360,98 @@ def test_a_token_with_no_held_expert_gets_exactly_zero(combined):
     assert out[~nothing].any(axis=1).all()
 
 
+# (c'') the packing, against a counting sort written out ---------------------
+
+def _plain_packing(idx, gates, first, n_held, bm):
+    """What `gm.held_packing` returns, the plain way: a stable counting
+    sort of the flattened assignments by held expert, each expert's rows
+    from a tile boundary; the tile table beside it."""
+    N, k = idx.shape
+    n_tiles = -(-N * k // bm) + n_held
+    P = n_tiles * bm
+    local = idx.reshape(-1) - first
+    row_tok = np.zeros(P, np.int32)
+    row_gate = np.zeros((P, 1), np.float32)
+    slot_of = np.full(N * k, P, np.int32)
+    tiles, at = [], 0
+    for g in range(n_held):
+        mine = np.flatnonzero(local == g)           # in assignment order
+        row_tok[at:at + mine.size] = mine // k
+        row_gate[at:at + mine.size, 0] = gates.reshape(-1)[mine]
+        slot_of[mine] = at + np.arange(mine.size)
+        tiles += [g] * -(-mine.size // bm)
+        at = len(tiles) * bm
+    # an unused tile repeats the last used one's expert (none: the last)
+    last = tiles[-1] if tiles else n_held - 1
+    group = np.array(tiles + [last] * (n_tiles - len(tiles)), np.int32)
+    return (row_tok, row_gate, group, np.array([len(tiles)], np.int32),
+            slot_of.reshape(N, k))
+
+
+# the four MoE cells' plain and chunk-carrying calls: rows, top k, router
+# width, experts held (ISSUE 58's table)
+PACKED_CALLS = {
+    "nemotron_64": (64, 6, 128, 64), "nemotron_320": (320, 6, 128, 64),
+    "granite_64": (64, 10, 72, 36), "granite_320": (320, 10, 72, 36),
+    "lfm2_128": (128, 4, 64, 64), "lfm2_384": (384, 4, 64, 64),
+    "laguna_64": (64, 10, 256, 32), "laguna_1088": (1088, 10, 256, 32),
+}
+PACKED_FIRST = 5           # held: ids 5 .. 5 + n_held - 1
+
+
+def _routing(call, kind):
+    """(N, k) ids and gates. `mixed`: a router's distinct top k a row, with
+    masked rows (-1), absent experts on both sides of the held range, one
+    held expert nobody picks and one that three rows in four pick (more
+    than a tile); `none`: every assignment absent or masked; `repeats`:
+    ids drawn with replacement, so a row names an expert twice."""
+    N, k, n_routed, n_held = PACKED_CALLS[call]
+    rng = np.random.default_rng(N * k + n_held)
+    gates = rng.random((N, k), dtype=np.float32) + 0.1
+    if kind == "repeats":
+        return rng.integers(-1, n_routed, (N, k)).astype(np.int32), gates
+    empty, crowded = PACKED_FIRST + 2, PACKED_FIRST + 1
+    idx = np.argsort(rng.random((N, n_routed - 1)), axis=1)[:, :k]
+    idx = (idx + (idx >= empty)).astype(np.int32)   # nobody picks `empty`
+    for t in range(3 * N // 4):
+        if crowded not in idx[t]:
+            idx[t, t % k] = crowded
+    idx[rng.random(N) < 0.1] = -1
+    if kind == "none":
+        idx = np.where((idx >= PACKED_FIRST)
+                       & (idx < PACKED_FIRST + n_held), -1, idx)
+    return idx, gates
+
+
+@pytest.mark.parametrize("kind", ["mixed", "none", "repeats"])
+@pytest.mark.parametrize("call", list(PACKED_CALLS))
+def test_the_packing_is_the_counting_sort_slot_for_slot(call, kind):
+    """`row_tok`, `row_gate`, `group`, `n_used` and `slot_of`, array_equal:
+    the dense ops place every assignment where the stable sort placed it
+    (PR 58), at the eight call shapes of the cells."""
+    N, k, n_routed, n_held = PACKED_CALLS[call]
+    bm = gm.held_tile_rows(N, k, n_routed)
+    idx, gates = _routing(call, kind)
+    want = _plain_packing(idx, gates, PACKED_FIRST, n_held, bm)
+    got = jax.jit(functools.partial(gm.held_packing, first=PACKED_FIRST,
+                                    n_held=n_held, bm=bm))(idx, gates)
+    for name, g, w in zip(("row_tok", "row_gate", "group", "n_used",
+                           "slot_of"), got, want):
+        g = np.asarray(g)
+        assert (g.shape, g.dtype) == (w.shape, w.dtype), name
+        np.testing.assert_array_equal(g, w, err_msg=name)
+    local = idx - PACKED_FIRST
+    counts = np.bincount(local[(local >= 0) & (local < n_held)],
+                         minlength=n_held)
+    if kind == "mixed":     # the routing holds what the case is about
+        assert counts.max() > bm and counts[2] == 0 and (idx == -1).any()
+        assert ((idx >= 0) & (idx < PACKED_FIRST)).any()
+        assert (idx >= PACKED_FIRST + n_held).any() or n_held == n_routed
+    if kind == "none":
+        assert not counts.any() and int(got[3][0]) == 0
+        assert (np.asarray(got[4]) == want[0].size).all()
+
+
 # (d) the tile at the merged call's rows -------------------------------------
 
 @pytest.mark.parametrize("rows, k, n_routed, tile", [

@@ -345,10 +345,12 @@ ROUTER_KINDS = ("sigmoid", "softmax_topk")   # of a patterned model's 'E'
 #: the paged pool), "window" (a ring of the last rows, a slot), "slot_state"
 #: (leaves with a row a slot: a state-space layer's state and tail, a
 #: convolution's tail). A 'P' layer keeps two of them, in a cache slot
-#: keyed by these names (models/gpt.py init_paged_cache).
+#: keyed by these names (models/gpt.py init_paged_cache). A latent layer
+#: ('L') keeps pools too, of another row: one latent row a position with no
+#: head axis (ops/latent_attention.py), addressed by the same block table.
 LAYER_KEEPS = {"M": ("slot_state",), "C": ("slot_state",), "E": (), "F": (),
                "*": ("pools",), "W": ("window",),
-               "P": ("pools", "slot_state")}
+               "P": ("pools", "slot_state"), "L": ("pools",)}
 POS_EMB_KINDS = ("learn", "sin", "rope", "none")
 # The reference realizes these as five separate trainer scripts
 # (single-gpu/train.py, multi-gpu/ddp/train.py, kaggle-zero1.py,
@@ -403,10 +405,23 @@ class LLMConfig:
     n_head: int = 8
     n_kv_heads: int = 4
     # MLA only (defaults match reference ModelConfig, train.py:128-131, so
-    # `--attn mla` works out of the box):
+    # `--attn mla` works out of the box). TWO latent attentions read them.
+    # The classic block's (`layer_pattern` empty; models/attention.py
+    # NaiveMLA / FullMLA, the reference's teaching version): ONE
+    # `head_size` for the content, rotary-free and value widths, no norm
+    # on either latent, decode over a gathered copy of the latent view. The
+    # pattern's 'L' layer (models/attention.py LatentAttention, the
+    # published DeepSeek-V2/V3 form): an RMSNorm on each latent, a head's
+    # query `[qk_nope_head_dim | rope_head_dim]` wide and its value
+    # `v_head_dim` (0 = `head_size`, each), ONE rotated key head of
+    # `rope_head_dim` shared by all query heads, the scale 1 / sqrt(nope +
+    # rope), a paged pool of latent rows with a decode and a chunk kernel
+    # of its own (ops/latent_attention.py).
     q_latent_dim: Optional[int] = 32
     kv_latent_dim: Optional[int] = 32
     rope_head_dim: Optional[int] = 16
+    qk_nope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # memory subsystem: activation recomputation (jax.remat). Two
     # granularities, mirroring the reference's two variants: 'block' remats
@@ -453,9 +468,11 @@ class LLMConfig:
     # GQA, `x + a_out * attn(a_in * h) + s_out * ssm(s_in * h)` (the
     # multipliers below; models/gpt.py MixerBlock): the one kind whose
     # cache slot is of two kinds, a slot's state and tail AND blocks of
-    # the pool. Empty = the attention + FFN block above for every layer.
-    # `n_layer` is its length. A patterned model has RMSNorms, no FFN
-    # biases, and its parameters are created in `LLM.param_dtype`.
+    # the pool; 'L' latent attention (models/attention.py LatentAttention:
+    # `attn` 'mla', the widths beside it above), whose pools hold one
+    # latent row a position. Empty = the attention + FFN block above for
+    # every layer. `n_layer` is its length. A patterned model has RMSNorms,
+    # no FFN biases, and its parameters are created in `LLM.param_dtype`.
     layer_pattern: str = ""
     norm_eps: float = 1e-5       # the RMSNorms of a patterned model
     tie_head: bool = True        # False: an `lm_head` (V, C) of its own
@@ -571,6 +588,16 @@ class LLMConfig:
                     "a 'P' layer's attention branch is GQA"
             if "C" in self.layer_pattern:
                 assert self.conv_len >= 2
+            if "L" in self.layer_pattern:
+                assert self.attn == "mla" and self.pos_emb == "rope" \
+                    and not set("*WP") & set(self.layer_pattern), \
+                    "a pattern with latent layers says attn 'mla', " \
+                    "pos_emb 'rope', and has no GQA layer beside them"
+                assert self.q_latent_dim and self.kv_latent_dim \
+                    and self.rope_head_dim and self.rope_head_dim % 2 == 0
+            else:
+                assert self.attn != "mla", \
+                    "a pattern's latent attention is its 'L' layers'"
             if "F" in self.layer_pattern:
                 assert self.dense_up_dim > 0
             if "W" in self.layer_pattern:
@@ -606,6 +633,8 @@ class LLMConfig:
                         self.mlp_gate_mult, self.mlp_down_mult)), \
                 "the multipliers are a patterned model's"
             assert not self.qk_norm, "QK-norm is a patterned model's"
+            assert not (self.qk_nope_head_dim or self.v_head_dim), \
+                "separate nope and value widths are an 'L' layer's"
             assert not (self.window or self.window_heads or self.attn_gate
                         or self.rope_factor != 1.0
                         or self.rotary_frac != 1.0

@@ -922,10 +922,16 @@ class DecodeEngine:
         # chunk is partial), in the "full" and the "window" layers
         self._n_full = cfg.layers_keeping("pools")
         self._n_window = cfg.layers_keeping("window")
+        # latent layers ('L') keep the whole history too, in rows of
+        # another kind: `latent_rows_read_by` is what their calls had to
+        # read, counted among `kv_rows_read_full_by` as well
+        self._n_latent = cfg.layer_pattern.count("L")
         # the rows are planned where the layers differ in what they keep
-        # (window layers, or a layer that keeps two kinds: 'P')
-        self._plans_kv_rows = bool(self._n_window) or any(
+        # (window layers, or a layer that keeps two kinds: 'P'), or where
+        # a kernel's roofline reads them (latent layers)
+        self._plans_kv_rows = bool(self._n_window or self._n_latent) or any(
             len(keeps) > 1 for keeps in cfg.layer_keeps)
+        self.latent_rows_read_by = {"chunk": 0, "decode": 0}
         self.kv_rows_read_full_by = {"chunk": 0, "decode": 0}
         self.kv_rows_read_window_by = {"chunk": 0, "decode": 0}
         self.window_rows_saved = 0
@@ -1947,6 +1953,10 @@ class DecodeEngine:
         return sum(self.ssm_state_bytes_by.values())
 
     @property
+    def latent_rows_read(self) -> int:
+        return sum(self.latent_rows_read_by.values())
+
+    @property
     def resident_bytes_by_kind(self) -> dict:
         """Bytes the engine holds between programs, by kind of state:
         the weights, the block pools of the layers that keep a whole
@@ -2092,6 +2102,7 @@ class DecodeEngine:
                 full, window = whole * self._n_full, windowed * self._n_window
                 self.kv_rows_read_full_by[what] += full
                 self.kv_rows_read_window_by[what] += window
+                self.latent_rows_read_by[what] += whole * self._n_latent
                 self.window_rows_saved += whole * self._n_window - window
                 kv_full += full
                 kv_window += window

@@ -1,5 +1,6 @@
 """Attention flavors: GQA (unifying MHA/MQA/GQA) and Multi-head Latent
-Attention (MLA), with and without decoupled RoPE.
+Attention (MLA): the classic block's two teaching versions, with and
+without decoupled RoPE, and the pattern's published one.
 
 Reference parity map:
 * `GQA`      — reference single-gpu/model.py:98-155 (fused qkv projection,
@@ -11,8 +12,17 @@ Reference parity map:
                head; scores scaled by 1/sqrt(hs+dhr); cache {'c_kv','k_r'}).
 * `Attention` — dispatch (model.py:347-363): mha/mqa/gqa -> GQA; mla ->
                NaiveMLA (pos_emb != 'rope') or FullMLA (pos_emb == 'rope').
+* `LatentAttention` — no reference twin: a patterned model's 'L' layer, MLA
+               as DeepSeek-V2/V3 and their descendants publish it (an
+               RMSNorm on each latent, a head `[nope | rope]` / value wide,
+               one shared rotated key head), over a paged pool of latent
+               rows with kernels of its own (ops/latent_attention.py).
 
-TPU-first design notes (intentional divergences, documented per SURVEY §7):
+TPU-first design notes (intentional divergences, documented per SURVEY §7;
+notes 1 and 2 are about `NaiveMLA` / `FullMLA`, the classic block's MLA.
+`LatentAttention` has the two forms too, and chooses by what a call is, not
+by train / eval: one token of every slot attends ABSORBED over cached rows,
+a chunk UP-PROJECTS the rows it reads, and a test holds the two equal):
 
 1. **Training path materializes per-head K/V** from the latents and calls the
    fused SDPA/flash kernel — large batched matmuls that tile onto the MXU —
@@ -48,7 +58,10 @@ TPU-first design notes (intentional divergences, documented per SURVEY §7):
    operand layout are one and the same dense row-major order, so a
    serving step never copies a pool (`block_pool.kv_lanes` has the
    reasoning). The contiguous path below stays for training and the
-   one-shot generate oracle.
+   one-shot generate oracle. The classic MLA's latent pools keep their
+   two leaves and reach no kernel; an 'L' layer's pool is ONE leaf of
+   latent rows, `[c | rope(k_r) | 0]` in whole 128-lane tiles and with no
+   head axis (`ops.latent_attention.row_lanes`), for the same reason.
 """
 
 from __future__ import annotations
@@ -561,6 +574,131 @@ class FullMLA(nn.Module):
         y = _qmm(self, y, ks["W_o"], "W_o")
         y = nn.Dropout(cfg.dropout, deterministic=deterministic)(y)
         return y, new_cache
+
+
+class LatentAttention(nn.Module):
+    """A patterned model's 'L' layer: latent attention as published (HF
+    `deepseek_v3`-style modules), no biases. For a normed input h (B, T, C):
+
+      c_q = RMSNorm(h W_qa) (`q_latent_dim`); q = c_q W_qb, a head's
+            `[q_nope (qk_nope_head_dim) | q_rope (rope_head_dim)]`
+      [c_kv | k_r] = h W_kva; c = RMSNorm(c_kv) (`kv_latent_dim`); k_r is
+            ONE key head every query head shares
+      RoPE on q_rope and k_r at the rows' own positions (`rope_theta`,
+            `rope_pairing`; a lane permutation common to both leaves
+            every score as it was)
+      [k_nope_n | v_n] = c W_kvb,n; score_n = (q_nope_n . k_nope_n +
+            q_rope_n . k_r) / sqrt(nope + rope); y = [o_0 .. o_nh] W_o
+
+    What is cached is the row `[c | rope(k_r) | 0]` (ops/latent_attention.py
+    `cache_rows`), one a position with no head axis, in the layer's ONE
+    pool leaf (n_blocks, bs, L), written through the block table under
+    scope `kv_update`. Scopes: `latent_q` (the query path, and for one
+    token of every slot the absorption q~ = q_nope W_kvb^K^T), `latent_kv`,
+    `kv_update`, `attn_latent` (latent_flash_decode | latent_flash_prefill,
+    or their XLA twins), `latent_out` (W_kvb^V where the attention ran
+    absorbed, and W_o). Without a cache the T rows attend to themselves,
+    up-projected."""
+
+    config: LLMConfig
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, cache=None, pos=0, *, block_tables=None):
+        from distributed_pytorch_tpu.ops import latent_attention as la
+        from distributed_pytorch_tpu.ops.quant import maybe_dequantized_param
+        cfg = self.config
+        B, T, C = x.shape
+        nh, dr = cfg.n_head, cfg.rope_head_dim
+        dn = cfg.qk_nope_head_dim or cfg.head_size
+        dv = cfg.v_head_dim or cfg.head_size
+        nlq, lc = cfg.q_latent_dim, cfg.kv_latent_dim
+        lanes = la.row_lanes(lc, dr)
+        scale = 1.0 / float(dn + dr) ** 0.5
+
+        def mat(name, *shape):
+            return self.param(name, _DENSE_INIT, shape, self.param_dtype)
+
+        def vec(name, n):
+            return self.param(name, nn.initializers.ones, (n,),
+                              self.param_dtype)
+
+        w_qa, w_qb = mat("W_qa", C, nlq), mat("W_qb", nlq, nh * (dn + dr))
+        w_kva = mat("W_kva", C, lc + dr)
+        w_kvb = maybe_dequantized_param(
+            (*self.path, "W_kvb"), mat("W_kvb", lc, nh * (dn + dv))
+        ).astype(x.dtype).reshape(lc, nh, dn + dv)
+        w_o = mat("W_o", nh * dv, C)
+        q_norm, kv_norm = vec("q_norm", nlq), vec("kv_norm", lc)
+        f = rope_angles(pos, T, dr, cfg.rope_theta)
+        half = cfg.rope_pairing == "half"
+        # one token of every slot attends absorbed; everything else
+        # up-projects the rows it reads
+        absorbed = cache is not None and T == 1
+
+        with jax.named_scope("latent_q"):
+            c_q = _head_rms_norm(_qmm(self, x, w_qa, "W_qa"), q_norm,
+                                 cfg.norm_eps)
+            q = _qmm(self, c_q, w_qb, "W_qb").reshape(B, T, nh, dn + dr)
+            q_nope = q[..., :dn]
+            q_rope = apply_rotary_emb(q[..., dn:], f, half=half)
+            if absorbed:
+                q_rows = la.cache_rows(
+                    jnp.einsum("btnd,lnd->btnl", q_nope, w_kvb[..., :dn]),
+                    q_rope, lanes)
+        with jax.named_scope("latent_kv"):
+            ckr = _qmm(self, x, w_kva, "W_kva")
+            rows = la.cache_rows(
+                _head_rms_norm(ckr[..., :lc], kv_norm, cfg.norm_eps),
+                apply_rotary_emb(ckr[:, :, None, lc:], f, half=half)[:, :, 0],
+                lanes)
+
+        if cache is None:
+            with jax.named_scope("attn_latent"):
+                y = la.attend_rows(q_nope, q_rope, rows, w_kvb,
+                                   la.causal_visible(0, T, T), scale)
+        else:
+            assert block_tables is not None, \
+                "a latent layer's cache is a paged pool"
+            from distributed_pytorch_tpu.ops.block_pool import (paged_gather,
+                                                                paged_update)
+            with jax.named_scope("kv_update"):
+                cache = paged_update(cache, rows, pos, block_tables)
+            with jax.named_scope("attn_latent"):
+                if absorbed:
+                    o_lat = la.latent_decode(
+                        q_rows[:, 0], cache, block_tables,
+                        jnp.broadcast_to(jnp.reshape(jnp.asarray(
+                            pos, jnp.int32), (-1,)) + 1, (B,)),
+                        scale=scale, lc=lc)
+                elif B == 1:
+                    y = la.latent_chunk(
+                        q_nope, q_rope, cache, w_kvb, block_tables,
+                        jnp.reshape(jnp.asarray(pos, jnp.int32), (-1,))[0],
+                        scale=scale)
+                else:
+                    # several rows of several sequences (a verify window)
+                    view = paged_gather(cache, block_tables)
+                    y = la.attend_rows(
+                        q_nope, q_rope, view, w_kvb,
+                        la.causal_visible(pos, T, view.shape[1]), scale)
+        with jax.named_scope("latent_out"):
+            if absorbed:
+                y = jnp.einsum("bnl,lnv->bnv", o_lat, w_kvb[..., dn:])[:, None]
+            y = _qmm(self, y.reshape(B, T, nh * dv), w_o, "W_o")
+        return y, cache
+
+
+def init_latent_cache(config: LLMConfig, n_blocks: int, block_size: int,
+                      dtype=jnp.float32) -> jnp.ndarray:
+    """An 'L' layer's pool: ONE leaf (n_blocks, bs, L) of latent rows
+    `[c | rope(k_r) | 0]` (ops/latent_attention.py `row_lanes`), no head
+    axis; block 0 is the null block."""
+    from distributed_pytorch_tpu.ops.latent_attention import row_lanes
+    assert jnp.dtype(dtype) != jnp.int8, \
+        "a latent pool has no int8 form (quant_kv_usable declines it)"
+    return jnp.zeros((n_blocks, block_size, row_lanes(
+        config.kv_latent_dim, config.rope_head_dim)), dtype)
 
 
 def Attention(config: LLMConfig, attn_impl: str = "auto",

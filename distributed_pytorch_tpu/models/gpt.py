@@ -34,7 +34,9 @@ import jax.numpy as jnp
 
 from distributed_pytorch_tpu.config import LLMConfig
 from distributed_pytorch_tpu.models.attention import (GQA, Attention,
+                                                      LatentAttention,
                                                       init_attn_cache,
+                                                      init_latent_cache,
                                                       init_window_cache)
 from distributed_pytorch_tpu.models.mlp import MLP, MoE, RoutedExperts
 from distributed_pytorch_tpu.models.shortconv import (ShortConv,
@@ -117,7 +119,8 @@ class MixerBlock(nn.Module):
     `cfg.resid_mult`), the mixer one of 'M' (models/ssm.py), 'C'
     (models/shortconv.py), 'E' (models/mlp.py RoutedExperts), 'F'
     (models/mlp.py MLP at `cfg.dense_up_dim`), '*' (GQA), 'W' (GQA over
-    a window of the last `cfg.window` positions) or 'P': TWO mixers on
+    a window of the last `cfg.window` positions), 'L' (LatentAttention,
+    module `latent_attn`: pools of latent rows) or 'P': TWO mixers on
     the one normed input h, `mixer_sum(attn(a_in * h), ssm(s_in * h))`
     (`MixerSum`; modules `attn` and `ssm` as in a '*' and an 'M' block).
     What each keeps between
@@ -131,10 +134,10 @@ class MixerBlock(nn.Module):
     have no null block to land a pad in.
 
     `xs` are the hidden rows of the program's row sets (`rows`, one or
-    several). An 'M', 'C', 'F', '*' or 'W' layer takes them in turn, the cache
-    flowing from one to the next; an 'E' layer is position-wise and makes
-    ONE call over all their rows, so its experts' matrices are read
-    once."""
+    several). An 'M', 'C', 'F', '*', 'W' or 'L' layer takes them in turn,
+    the cache flowing from one to the next; an 'E' layer is position-wise
+    and makes ONE call over all their rows, so its experts' matrices are
+    read once."""
 
     config: LLMConfig
     kind: str
@@ -170,6 +173,7 @@ class MixerBlock(nn.Module):
                 "F": lambda: MLP(cfg, cfg.dense_up_dim, pd, name="mlp"),
                 "*": lambda: GQA(cfg, self.attn_impl, pd, name="attn"),
                 "W": lambda: GQA(cfg, self.attn_impl, pd, "W", name="attn"),
+                "L": lambda: LatentAttention(cfg, pd, name="latent_attn"),
             }[self.kind]()
             ys, new_cache = [], cache
             for h, r in zip(hs, rows):
@@ -179,6 +183,9 @@ class MixerBlock(nn.Module):
                                              r.state_ctx)
                     elif self.kind == "F":
                         y = mixer(h)
+                    elif self.kind == "L":
+                        y, new_cache = mixer(h, new_cache, r.pos,
+                                             block_tables=r.block_tables)
                     else:
                         # '*' reads its rows through the table, 'W' its
                         # slot's ring through the state context
@@ -499,7 +506,9 @@ def init_paged_cache(config: LLMConfig, n_blocks: int, block_size: int,
     for its 'W' layers (models/attention.py `init_window_cache`: whatever
     the pools' `n_blocks` and the engine's `max_len` are), nothing for
     'F' and 'E' layers (an 'E' slot carries a program's routing counts
-    out, never in). A 'P' layer holds BOTH kinds, keyed by what they are
+    out, never in). An 'L' layer's pool is ONE leaf of latent rows with no
+    head axis (models/attention.py `init_latent_cache`), addressed by the
+    same tables. A 'P' layer holds BOTH kinds, keyed by what they are
     (`config.LAYER_KEEPS`): {"pools": its attention branch's block pools,
     "slot_state": its state-space branch's tail and state}."""
     from distributed_pytorch_tpu.models.attention import init_paged_attn_cache
@@ -513,6 +522,8 @@ def init_paged_cache(config: LLMConfig, n_blocks: int, block_size: int,
                                                dtype),
                 "*": lambda: init_paged_attn_cache(config, n_blocks,
                                                    block_size, dtype),
+                "L": lambda: init_latent_cache(config, n_blocks, block_size,
+                                               dtype),
                 "P": lambda: {"pools": make["*"](),
                               "slot_state": make["M"]()}}
         return [make[kind]() if kind in make else None

@@ -376,6 +376,8 @@ MIXER_MODULES = {
                  "its output multiplier, in float32 (models/gpt.py "
                  "MixerSum): the one place that tells where the branches "
                  "of one block meet",
+    "latent_attn": "an 'L' block's latent attention outside its scopes "
+                   "(models/attention.py LatentAttention)",
 }
 
 #: The scopes of a patterned model's mixers (`LLMConfig.layer_pattern`),
@@ -441,6 +443,22 @@ MIXER_SCOPES = {
                    "beside it by construction (held_experts_ffn); and the "
                    "add of the shared expert's output, one a row set "
                    "(models/mlp.py)",
+    # a latent layer's ('L', PR 59), module `latent_attn`; its cache write
+    # is `kv_update` as every other layer's
+    "latent_q": "the query path: W_qa, the RMSNorm of the query latent, "
+                "W_qb, the rotation of every head's rotary lanes, and for "
+                "one token of every slot the absorption q_nope W_kvb^K^T "
+                "and the lay-out over a cached row's lanes "
+                "(models/attention.py LatentAttention)",
+    "latent_kv": "W_kva, the RMSNorm of the key/value latent, the rotation "
+                 "of the one shared key head, the cached row `[c | k_r | 0]`",
+    "attn_latent": "the attention core over the pool of latent rows: "
+                   "latent_flash_decode (absorbed, every live row read "
+                   "once) or latent_flash_prefill (a chunk, its key tiles "
+                   "up-projected in the kernel), or their XLA twins "
+                   "(ops/latent_attention.py)",
+    "latent_out": "W_kvb^V on `sum p c` where the attention ran absorbed, "
+                  "and W_o",
 }
 
 

@@ -38,7 +38,8 @@ Three layers, smallest first:
   slices and reshapes on the way out; the indices are the same. Pad
   lanes stay zero and no head's slice reads them. Leaves whose trailing
   shape already equals the rows' (MLA latents, the int8 codes and their
-  scale sidecars, which keep (n_blocks, bs, n_kv, ...)) pass through
+  scale sidecars, which keep (n_blocks, bs, n_kv, ...); a latent layer's
+  rows, which come in whole tiles: ops/latent_attention.py) pass through
   unchanged.
 * **`BlockPool`**: free-list allocator with per-block refcounts. Blocks
   referenced by live sequences can be shared (a reused prefix); blocks at
@@ -107,7 +108,13 @@ def paged_update(pool: jnp.ndarray, new: jnp.ndarray, pos,
     logical positions [pos, pos+T) of each sequence, addressed through
     `block_tables` (B, max_blocks) int32. A merged-lane pool
     (n_blocks, bs, L) takes (B, T, n_kv, hs) rows: heads flattened into
-    lanes, zero-padded to L (module docstring).
+    lanes, zero-padded to L (module docstring). Rows whose trailing shape
+    IS the pool's are written as they are: the int8 codes and sidecars,
+    the classic MLA's latents, and a latent layer's rows (B, T, L), which
+    have no head axis: `[c | rope(k_r) | 0]`, the normed key/value latent,
+    the one rotated key head all query heads share, and zeros up to whole
+    128-lane tiles, laid out by the caller
+    (ops/latent_attention.py `cache_rows`).
 
     Three shapes, mirroring `_update_cache`'s prefill/decode split plus
     the spec-verify short window:

@@ -402,7 +402,10 @@ class Scheduler:
                 ("serve_ssm_state_bytes_total", "ssm_state_bytes",
                  "float32 state the state-space layers' calls read and "
                  "wrote back (slots x layers x a slot's state, in and "
-                 "out)")):
+                 "out)"),
+                ("serve_latent_rows_read_total", "latent_rows_read",
+                 "live latent rows the latent-attention layers' decode "
+                 "and chunk calls had to read")):
             self.metrics.register_gauge(
                 name, lambda attr=attr: getattr(eng, attr, 0), text)
         for kind in ("weights", "pools", "window", "slot_state"):

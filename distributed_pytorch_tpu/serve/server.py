@@ -328,6 +328,8 @@ class ServeApp:
             "chunk_attn_pairs_by": getattr(eng, "chunk_attn_pairs_by", {}),
             # state-space layers: float32 state their calls moved, by call
             "ssm_state_bytes_by": getattr(eng, "ssm_state_bytes_by", {}),
+            # latent layers: live rows their calls had to read, by call
+            "latent_rows_read_by": getattr(eng, "latent_rows_read_by", {}),
             "resident_bytes_by_kind":
                 getattr(eng, "resident_bytes_by_kind", {})})
 

@@ -33,6 +33,7 @@ from distributed_pytorch_tpu.ops import block_pool as bp  # noqa: E402
 from distributed_pytorch_tpu.ops import flash_attention as fa  # noqa: E402
 from distributed_pytorch_tpu.ops import flash_decode as fd  # noqa: E402
 from distributed_pytorch_tpu.ops import grouped_matmul as gm  # noqa: E402
+from distributed_pytorch_tpu.ops import latent_attention as la  # noqa: E402
 from distributed_pytorch_tpu.ops import window_attention as wa  # noqa: E402
 
 BF16, I8, F32, I32 = jnp.bfloat16, jnp.int8, jnp.float32, jnp.int32
@@ -140,6 +141,23 @@ def _window_prefill(T, nh, ring, nkv=8, hs=128):
             ((1, T, nh, hs), BF16), keys, keys, ((), I32)]
 
 
+def _latent(chunk, nh=32, lc=512, dn=128, dr=64, dv=128, width=136):
+    """JoyAI-LLM-Flash's published latent attention (PR 59): 32 heads of
+    128 + 64 / 128 over rows of 512 + 64 in 640 lanes, 64 slots at a table
+    136 blocks wide; a decode batch, or a chunk of `chunk` rows."""
+    pool = ((8200, BS, la.row_lanes(lc, dr)), BF16)
+    scale = (dn + dr) ** -0.5
+    if not chunk:
+        return (lambda q, p, bt, cl: la.latent_flash_decode(
+            q, p, bt, cl, scale=scale, lc=lc)), [
+                ((64, nh, pool[0][2]), BF16), pool, ((64, width), I32),
+                ((64,), I32)]
+    return (lambda qn, qr, p, w, bt, o: la.latent_flash_prefill(
+        qn, qr, p, w, bt, o, scale=scale)), [
+            ((1, chunk, nh, dn), BF16), ((1, chunk, nh, dr), BF16), pool,
+            ((lc, nh, dn + dv), BF16), ((1, width), I32), ((), I32)]
+
+
 def _gmm(grad):
     # the bench MoE's widths (C=768, 8 experts incl. 1 shared, top-2
     # routed, swiglu up 1024 -> fused fc_out 2048); 2048 tokens keep the
@@ -242,6 +260,9 @@ CASES = {
     "paged_prefill_1024_48x128": (lambda: _paged_prefill(1024, False, 48, 8,
                                                          128, 136),
                                   ["paged_flash_prefill"]),
+    "latent_decode_64x32x640": (lambda: _latent(0), ["latent_flash_decode"]),
+    "latent_prefill_1024x32": (lambda: _latent(1024),
+                               ["latent_flash_prefill"]),
 }
 
 GRANITE_EXPERTS = dict(C=4096, F=768, held=36, k=10, n_routed=72, gated=True)
@@ -278,7 +299,8 @@ def test_kernel_compiles_for_v5e(case, v5e):
         # a chunk call is ONE kernel of its name a layer, whatever its grid
         # step holds: the benchmark's roofline reader divides the summed
         # device time of the ops of that name by their count
-        if name in ("paged_flash_prefill", "window_flash_prefill"):
+        if name in ("paged_flash_prefill", "window_flash_prefill",
+                    "latent_flash_prefill"):
             assert census[name] == 1, (case, census)
 
 

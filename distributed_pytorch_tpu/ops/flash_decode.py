@@ -39,11 +39,11 @@ single-token attention):
   tiles of the whole batch, so fetches are in flight across a sequence's
   end and no step exists for a dead block; up to `group` tiles of a
   sequence share ONE softmax update. `_walk_shape` reads both from a
-  tile's bytes and the table's width. (With one (1, bs, L) BlockSpec tile
-  a grid step (B, max_blocks), the pipeline looked one step ahead and the
-  dead steps' clamp held it on the sequence's own last block: every
-  sequence's first fetch and last update ran with nothing beside them,
-  and 1.9 us a live tile came of 1.04 of DMA. PERF.md section 6, PR 44.)
+  tile's bytes and the table's width: (4, 8) in every accepted cell. The
+  latent kernel (ops/latent_attention.py) walks so with a shape of its
+  own, `_latent_walk`: its 160 KB tiles want (8, 16), PR 60. (One BlockSpec
+  tile a grid step (B, max_blocks) looked one step ahead and held on a
+  sequence's last block: 1.9 us a live tile of 1.04 of DMA. PERF.md, PR 44.)
 * **Chunked-prefill variant** (`paged_flash_prefill`, round 12): the
   paged decode kernel generalized from one query row per sequence to a
   (t, rep)-packed query tile of ONE sequence — a prefill chunk written

@@ -22,6 +22,14 @@ the engine's programs need them:
   sequence's live tiles as `flash_decode._paged_kernel` does (fetches of
   its own into a ring, running ahead into the next sequence, no fetch for
   a dead block) and reads every live row ONCE, for scores and values both.
+  The walk's shape is the kernel's own (`_latent_walk`, from the tile's
+  bytes and the table's width): a latent tile is key and value at once and
+  a third to a fifth of a k/v pair's bytes, so the paged walk's (4, 8) put
+  0.65 MB through a softmax update and paid the update's fixed costs three
+  times as often a byte. Eight tiles an update, sixteen in the ring, the
+  ring topped up by what an update freed, and a sequence's remainder in
+  power-of-two updates: the fetches' own pace, 737 GB/s (PERF.md section 6,
+  PR 60, has the grid).
 * a chunk of T rows of ONE sequence at an offset (`latent_chunk`),
   UP-PROJECTED: a key tile's rows are taken through W_kvb,n inside the
   kernel, `[k_nope_n | v_n] = c W_kvb,n`, and a head attends as any head of
@@ -58,11 +66,14 @@ from distributed_pytorch_tpu.ops.flash_decode import (_CHUNK_SCORE_BYTES,
                                                       _common_decline,
                                                       _pick_block,
                                                       _softmax_init,
-                                                      _stack_tiles,
-                                                      _walk_shape)
+                                                      _stack_tiles)
 
 #: pool blocks one softmax update of the chunk kernel takes side by side
 _CHUNK_GROUP = 4
+#: tiles one softmax update of the decode kernel takes at most, and the
+#: bytes they may weigh together
+_DECODE_GROUP = 8
+_DECODE_UPDATE_BYTES = 2 * 2 ** 20
 
 
 def row_lanes(lc: int, dr: int) -> int:
@@ -153,17 +164,38 @@ def latent_chunk_xla(q_nope, q_rope, pool, w_kvb, block_tables, off, *,
 # one token of every slot
 # ---------------------------------------------------------------------------
 
+def _latent_walk(n_max: int, tile_bytes: int) -> tuple[int, int]:
+    """(group, depth) of `_decode_kernel`'s walk, from the shapes of the
+    call alone. One softmax update takes `group` tiles: a power of two (a
+    sequence's remainder goes through the halves below it), as many as
+    weigh `_DECODE_UPDATE_BYTES` together, `_DECODE_GROUP` at most and
+    never more than a sequence can hold. The ring holds `depth` = two
+    updates: one computed, one in flight behind it. 128 x 640 bf16 tiles
+    under a table 136 wide give (8, 16): 1.3 MB an update, 2.6 MB in the
+    ring, which is where the kernel alone reaches the pace of its fetches
+    (PERF.md section 6, PR 60: (4, 8), `_walk_shape`'s answer, stood 30%
+    under it, and (16, 32) is no faster)."""
+    most = min(_DECODE_GROUP, n_max, _DECODE_UPDATE_BYTES // tile_bytes)
+    group = 1 << (max(most, 1).bit_length() - 1)
+    return group, 2 * group
+
+
 def _decode_kernel(cl_ref, bt_ref, q_ref, pool_hbm, o_ref, buf, sem, cur,
                    acc_ref, m_ref, l_ref, *, scale: float, bs: int, lc: int,
                    group: int):
-    """A grid step is ONE SEQUENCE and walks all its live tiles
-    (`flash_decode._paged_kernel`'s walk: the pool stays in HBM, the
-    kernel starts the fetches itself into a ring of `depth` (bs, L) tiles,
-    the cursor `cur` = (sequence, block, tiles issued, tiles done) running
-    ahead over the live tiles of the whole batch; up to `group` tiles of
-    the sequence share one softmax update). A tile is key and value both:
-    its L lanes against the absorbed query rows (nh, L) give every head's
-    scores, its first `lc` lanes the values."""
+    """A grid step is ONE SEQUENCE and walks all its live tiles: the pool
+    stays in HBM, the kernel starts the fetches itself into a ring of
+    `depth` (bs, L) tiles, the cursor `cur` = (sequence, block, tiles
+    issued, tiles done) running ahead over the live tiles of the whole
+    batch. The batch's first step fills the ring; from then on an update
+    starts as many fetches as it freed, so the ring stays full up to the
+    batch's last tile and no turn asks whether there is room. `group`
+    tiles of the sequence share one softmax update; what is left of the
+    sequence, fewer than `group`, goes through updates of group / 2, ..,
+    1 tiles, each at most once: a row's sum and its order are its own
+    length's doing alone. A tile is key and value both: its L lanes
+    against the absorbed query rows (nh, L) give every head's scores, its
+    first `lc` lanes the values."""
     b = pl.program_id(0)
     depth, n_max = buf.shape[0], bt_ref.shape[1]
 
@@ -178,7 +210,7 @@ def _decode_kernel(cl_ref, bt_ref, q_ref, pool_hbm, o_ref, buf, sem, cur,
     def issue(_, carry):
         pb, pj, issued = cur[0], cur[1], cur[2]
 
-        @pl.when((pb < pl.num_programs(0)) & (issued - cur[3] < depth))
+        @pl.when(pb < pl.num_programs(0))
         def _():
             fetch(bt_ref[pb, pj], jax.lax.rem(issued, depth)).start()
             cur[2] = issued + 1
@@ -191,11 +223,13 @@ def _decode_kernel(cl_ref, bt_ref, q_ref, pool_hbm, o_ref, buf, sem, cur,
     def _():
         for i in range(4):
             cur[i] = 0
+        jax.lax.fori_loop(0, depth, issue, 0)
 
     _softmax_init(acc_ref, m_ref, l_ref)
     n, nb = cl_ref[b], n_blocks(b)
 
-    def update(live, first, done):
+    def update(live, first):
+        done = cur[3]
         slots = [jax.lax.rem(done + t, depth) for t in range(live)]
         for slot in slots:
             fetch(0, slot).wait()
@@ -225,18 +259,19 @@ def _decode_kernel(cl_ref, bt_ref, q_ref, pool_hbm, o_ref, buf, sem, cur,
         m_ref[:] = m_new
         l_ref[:] = l_new
         acc_ref[:] = acc_ref[:] * alpha + pv
+        cur[3] = done + live
+        jax.lax.fori_loop(0, live, issue, 0)
 
-    def body(g, carry):
-        jax.lax.fori_loop(0, depth, issue, 0)
-        done, first = cur[3], g * group
-        size = jax.lax.min(nb - first, group)
-        for live in range(1, group + 1):
-            pl.when(size == live)(
-                functools.partial(update, live, first, done))
-        cur[3] = done + size
+    def whole(g, carry):
+        update(group, g * group)
         return carry
 
-    jax.lax.fori_loop(0, jax.lax.div(nb + group - 1, group), body, 0)
+    jax.lax.fori_loop(0, jax.lax.div(nb, group), whole, 0)
+    part = group // 2
+    while part:
+        left = jax.lax.rem(nb, 2 * part)     # tiles behind the larger updates
+        pl.when(left >= part)(functools.partial(update, part, nb - left))
+        part //= 2
     o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)).astype(o_ref.dtype)
 
 
@@ -257,7 +292,7 @@ def latent_flash_decode(q: jnp.ndarray, pool: jnp.ndarray, block_tables,
     B, nh, L = q.shape
     bs = pool.shape[1]
     n_max = block_tables.shape[1]
-    group, depth = _walk_shape(n_max, _tile_bytes(pool))
+    group, depth = _latent_walk(n_max, _tile_bytes(pool))
 
     def q_idx(b, cl_ref, bt_ref):
         return (b, 0, 0)
@@ -306,6 +341,19 @@ def _shape_decline(q, pool):
     return _common_decline(q, pool, 1, 1, 8, bs, f"pool block size {bs}")
 
 
+def _decode_vmem_bytes(q, pool, block_tables, lc: int) -> int:
+    """VMEM one grid step of `latent_flash_decode` holds: the ring of
+    `_latent_walk`'s depth, the double-buffered query and output blocks,
+    the float32 softmax state, and an update's scores, probabilities and
+    partial sums."""
+    _, nh, L = q.shape
+    group, depth = _latent_walk(block_tables.shape[1], _tile_bytes(pool))
+    item = jnp.dtype(q.dtype).itemsize
+    return (depth * _tile_bytes(pool) + 2 * nh * (L + lc) * item
+            + nh * (lc + 2 * 128) * 4 + 2 * nh * lc * 4
+            + 3 * group * nh * pool.shape[1] * 4)
+
+
 def latent_flash_decode_decline(q, pool, block_tables, lc: int):
     """Why `latent_flash_decode` cannot take this call (None = it can)."""
     if q.ndim != 3:
@@ -318,12 +366,7 @@ def latent_flash_decode_decline(q, pool, block_tables, lc: int):
     if L != pool.shape[2] or lc % step != 0 or lc > L or nh % 8 != 0:
         return (f"{nh} query rows of {L} lanes, {lc} of them the latent, "
                 f"against rows of {pool.shape[2]}")
-    group, depth = _walk_shape(block_tables.shape[1], _tile_bytes(pool))
-    item = jnp.dtype(q.dtype).itemsize
-    return _budget_decline(
-        depth * _tile_bytes(pool) + 2 * nh * (L + lc) * item
-        + nh * (lc + 2 * 128) * 4 + 2 * nh * lc * 4
-        + 3 * group * nh * pool.shape[1] * 4)
+    return _budget_decline(_decode_vmem_bytes(q, pool, block_tables, lc))
 
 
 def latent_flash_decode_usable(q, pool, block_tables, lc: int) -> bool:

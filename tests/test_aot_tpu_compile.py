@@ -302,6 +302,12 @@ def test_kernel_compiles_for_v5e(case, v5e):
         if name in ("paged_flash_prefill", "window_flash_prefill",
                     "latent_flash_prefill"):
             assert census[name] == 1, (case, census)
+    if case == "latent_decode_64x32x640":
+        # what the gate sums for the walk Mosaic took the body at
+        # (`_latent_walk`: (8, 16) here) is inside the limit it is handed
+        q, pool, bt, _ = (jax.ShapeDtypeStruct(s, d) for s, d in shapes)
+        assert la._decode_vmem_bytes(q, pool, bt, 512) \
+            < fd.VMEM_LIMIT_BYTES
 
 
 # ---------------------------------------------------------------------------

@@ -320,6 +320,31 @@ def test_paged_walk_follows_the_tile(bs, want):
                                atol=1e-5, rtol=1e-5)
 
 
+# cell -> (kv heads, head size, table width = (max_len + prefill_chunk) /
+# 128): the four accepted cells whose step programs hold
+# `paged_flash_decode`, bf16 pools of 128-row blocks
+_CELL_WALKS = {
+    "gpt2xl_serve_closed24": (25, 64, 10),          # 852 KB a pair
+    "lfm2moe_serve_closed128": (8, 64, 10),         # 256 KB
+    "laguna_serve_closed64_long": (8, 128, 136),    # 512 KB
+    "falcon_h1_serve_closed64": (4, 128, 20),       # 256 KB
+}
+
+
+@pytest.mark.parametrize("cell", list(_CELL_WALKS))
+def test_paged_walk_at_the_accepted_cells(cell):
+    """`_walk_shape` answers (4, 8) at every accepted cell's pair bytes and
+    table width: a change of the rule (the latent kernel's walk has one of
+    its own since PR 60, ops/latent_attention.py `_latent_walk`) cannot
+    move their kernels unseen."""
+    from distributed_pytorch_tpu.ops import block_pool as bp
+    from distributed_pytorch_tpu.ops import flash_decode as fd
+    nkv, hs, width = _CELL_WALKS[cell]
+    k = jax.ShapeDtypeStruct((9, 128, bp.kv_lanes(nkv, hs)), jnp.bfloat16)
+    assert fd._pair_bytes(k) in (851968, 524288, 262144)
+    assert fd._walk_shape(width, fd._pair_bytes(k)) == (4, 8)
+
+
 @pytest.mark.parametrize("n_own", [1, 8, 33, 64],
                          ids=lambda n: f"own{n}rows")
 @pytest.mark.parametrize("shape", [(25, 25, 64), (8, 2, 16)], ids=_ids)

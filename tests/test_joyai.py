@@ -210,33 +210,109 @@ def _tables(B, per_slot, width):
     return jnp.asarray(bt)
 
 
-@pytest.mark.parametrize("lens", [
-    (5, 64, 17, 33),        # a partial last tile, a full table, odd lengths
-    (1, 8, 9, 72),          # one row; one tile exactly; past the walk's group
-    (0, 40, 0, 3),          # dead slots: no block of theirs is fetched
-], ids=["partial", "edges", "dead"])
-def test_decode_kernel_against_its_twin(lens):
-    pool = _pool(jax.random.PRNGKey(4))
-    bt = _tables(4, 9, 10)
+# (table width, live blocks a slot, lengths). 8-row tiles under a table 10
+# wide walk as (8, 16), under 20 as (8, 16) too (`_latent_walk`: eight tiles
+# = 64 rows an update, sixteen in the ring), under 3 as (2, 4)
+_DECODE_LENS = {
+    # a partial last tile, a full table, odd lengths
+    "partial": (10, 9, (5, 64, 17, 33)),
+    # one row; one tile exactly; one tile past an update
+    "edges": (10, 9, (1, 8, 9, 72)),
+    # dead slots: their one fetch is the null block
+    "dead": (10, 9, (0, 40, 0, 3)),
+    # exactly one update, one tile more, one tile short of two updates, two
+    "update_edges": (20, 19, (64, 72, 120, 128)),
+    # every remainder's halves: 7 = 4 + 2 + 1 tiles, 5 = 4 + 1, 6 = 4 + 2, 3
+    "remainders": (20, 19, (56, 40, 48, 24)),
+    # shorter than the ring behind one longer than it, and the other way
+    "ring": (20, 19, (152, 17, 150, 127)),
+    # dead slots between live ones, each live one across an update's edge
+    "dead_between": (20, 19, (0, 152, 0, 65)),
+    # a table narrower than an update: (2, 4)
+    "narrow": (3, 3, (24, 1, 9, 17)),
+}
+
+
+def _decode_operands(case):
+    """(q, pool, block tables, lengths) of four slots, eight heads."""
+    width, per_slot, lens = _DECODE_LENS[case]
+    pool = _pool(jax.random.PRNGKey(4), n_blocks=4 * per_slot + 4)
     k = jax.random.split(jax.random.PRNGKey(5), 2)
     q = la.cache_rows(jax.random.normal(k[0], (4, 8, LC)),
                       jax.random.normal(k[1], (4, 8, DR)),
                       la.row_lanes(LC, DR))
-    cl = jnp.asarray(lens, jnp.int32)
+    return q, pool, _tables(4, per_slot, width), jnp.asarray(lens, jnp.int32)
+
+
+@pytest.mark.parametrize("case", list(_DECODE_LENS))
+def test_decode_kernel_against_its_twin(case):
+    q, pool, bt, cl = _decode_operands(case)
+    lens, width = np.asarray(cl), bt.shape[1]
     assert la.latent_flash_decode_decline(q, pool, bt, LC) is None
     got = la.latent_flash_decode(q, pool, bt, cl, scale=0.2, lc=LC,
                                  interpret=True)
     want = la.latent_decode_xla(q, pool, bt, cl, scale=0.2, lc=LC)
-    live = np.asarray(lens) > 0
+    live = lens > 0
     np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
                                atol=2e-5)
     # a dead block is the null block of a zeroed table row: the same rows
     # behind ANOTHER table's dead tail read the same
-    bt2 = bt.at[:, 9:].set(7)
+    dead = np.arange(width)[None, :] * 8 >= np.maximum(lens, 1)[:, None]
+    bt2 = jnp.where(dead, 2, bt)
     again = la.latent_flash_decode(q, pool, bt2, cl, scale=0.2, lc=LC,
                                    interpret=True)
     np.testing.assert_array_equal(np.asarray(got)[live],
                                   np.asarray(again)[live])
+
+
+@pytest.mark.parametrize("order", [(3, 2, 1, 0), (1, 3, 0, 2)])
+def test_decode_row_is_blind_to_its_neighbours(order):
+    """A sequence's output BITS are its own query's, blocks' and length's
+    doing: not its row's in the batch nor what shares the ring with it (the
+    repeat share of `correct` replays a prompt in another slot, beside
+    other neighbours)."""
+    q, pool, bt, cl = _decode_operands("ring")
+    run = functools.partial(la.latent_flash_decode, scale=0.2, lc=LC,
+                            interpret=True)
+    o = jnp.asarray(order)
+    np.testing.assert_array_equal(
+        np.asarray(run(q, pool, bt, cl))[np.asarray(order)],
+        np.asarray(run(q[o], pool, bt[o], cl[o])))
+
+
+@pytest.mark.parametrize("n_max,tile,want", [
+    (136, 128 * 640 * 2, (8, 16)),      # the cell's call: 1.3 MB an update
+    (136, 256 * 640 * 2, (4, 8)),       # tiles twice as heavy: half as many
+    (136, 8 * 2 ** 20, (1, 2)),         # a tile over the update's bytes
+    (20, 8 * 256 * 4, (8, 16)),         # the tests' tiles: the count binds
+    (5, 8 * 256 * 4, (4, 8)),           # a power of two inside the table
+    (3, 8 * 256 * 4, (2, 4)),
+    (1, 8 * 256 * 4, (1, 2)),
+])
+def test_the_walk_follows_the_tile_and_the_table(n_max, tile, want):
+    assert la._latent_walk(n_max, tile) == want
+
+
+def test_the_gates_vmem_sum_is_the_walks(monkeypatch):
+    """At the cell's call (64 slots, 32 heads, 128 x 640 bf16 tiles, a table
+    136 wide) the walk is what PERF.md section 7 records, (8, 16), and the
+    gate's VMEM sum is computed from it: the ring is sixteen tiles of it,
+    an update's float32 scores eight tiles'."""
+    from distributed_pytorch_tpu.compat import VMEM_LIMIT_BYTES
+    bf = jnp.bfloat16
+    q = jax.ShapeDtypeStruct((64, 32, 640), bf)
+    pool = jax.ShapeDtypeStruct((8200, 128, 640), bf)
+    bt = jax.ShapeDtypeStruct((64, 136), jnp.int32)
+    rest = 2 * 32 * (640 + 512) * 2 + 32 * (512 + 256) * 4 + 2 * 32 * 512 * 4
+    need = la._decode_vmem_bytes(q, pool, bt, 512)
+    assert need == 16 * 163840 + rest + 3 * 8 * 32 * 128 * 4
+    assert need < VMEM_LIMIT_BYTES // 8
+    monkeypatch.setattr(la, "_latent_walk", lambda n_max, tile: (4, 8))
+    assert la._decode_vmem_bytes(q, pool, bt, 512) \
+        == 8 * 163840 + rest + 3 * 4 * 32 * 128 * 4
+    monkeypatch.setattr(la, "_latent_walk", lambda n_max, tile: (64, 512))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert "MiB of VMEM" in la.latent_flash_decode_decline(q, pool, bt, 512)
 
 
 @pytest.mark.parametrize("off,T", [(0, 16), (8, 16), (40, 16), (24, 8),

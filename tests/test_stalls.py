@@ -24,11 +24,17 @@ PHASES = {"prepare": 1.0, "dispatch": 1.0, "wait": 7.0, "retire": 1.0}
 
 @pytest.fixture(autouse=True)
 def fresh_log():
-    """The log and totals are the process's: each test starts them empty."""
-    with flight._lock:
-        flight._stalls.clear()
-        flight._totals.clear()
+    """The log and totals are the process's: each test starts them empty,
+    and leaves them empty for the file that shares its worker next (a
+    scripted stall of no kind stood in `/debug/timeline` of
+    tests/test_trace_e2e.py whenever that file followed this one)."""
+    def clear():
+        with flight._lock:
+            flight._stalls.clear()
+            flight._totals.clear()
+    clear()
     yield
+    clear()
 
 
 class Clock:

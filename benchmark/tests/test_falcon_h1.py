@@ -194,16 +194,19 @@ def test_the_cuts_arithmetic_is_the_issues():
 
 def test_every_falcon_metric_resolves():
     bench = harness.load_benchmark()
+    # the cell's metrics are the entries that LIST it, whatever their names:
+    # a reading it shares with other cells is one entry over all of them
+    # (24 = the 6 no accepted entry repeats + 18 shared; the length of
+    # `per_layer` is held in one place, test_resolution.py)
     mine = harness.metrics_of_cell(bench, "per_layer", CELL)
-    assert len(mine) == 23 and all(m["name"].endswith(".falcon")
-                                   and m["workloads"] == [CELL]
-                                   for m in mine)
-    assert len(bench["per_layer"]) == 71 + 23
+    own = [m for m in mine if m["name"].endswith(".falcon")]
+    assert len(mine) == 24 and len(own) == 6
+    assert all(m["workloads"] == [CELL] for m in own)
     readers = set()
     for m in mine:
         spec, reader = harness.load_layer_metric(m["name"])
         readers.add(spec["reader"])
-        assert spec["kinds"] == ["serve_closed_parallel"]
+        assert "serve_closed_parallel" in spec["kinds"]
         assert reader.read({}, spec.get("args", {})) is None
     assert "trace_scope_roofline_pct" in readers
     for m in bench["end_to_end"]:
@@ -219,15 +222,15 @@ def test_every_falcon_metric_resolves():
     from distributed_pytorch_tpu.obs.trace import MIXER_MODULES, MIXER_SCOPES
     assert set(json.loads(names.pop())) <= set(MIXER_MODULES) | set(
         MIXER_SCOPES)
-    # the twins read what the accepted entries read
-    twins = {"engine_step_mean_ms": "engine_step_mean_ms",
-             "stall_share_pct": "stall_share_pct.serve",
-             "paged_decode_roofline": "paged_decode_roofline",
-             "unscoped_pct": "unscoped_pct.serve"}
-    for mine_, theirs in twins.items():
-        a = harness.load_layer_metric(f"{mine_}.falcon")[0]
-        b = harness.load_layer_metric(theirs)[0]
-        assert (a["reader"], a.get("args")) == (b["reader"], b.get("args"))
+    # what the cell shares it reads under the accepted entry's own name:
+    # no twin under a suffix is left beside it
+    listed = {m["name"] for m in mine}
+    everything = {m["name"] for m in bench["per_layer"]}
+    for shared in ("engine_step_mean_ms", "stall_share_pct.serve",
+                   "idle_stalled_pct.serve", "paged_decode_roofline",
+                   "state_resets", "unscoped_pct.serve"):
+        assert shared in listed
+        assert shared.split(".")[0] + ".falcon" not in everything
 
 
 def test_the_scope_roofline_reader(monkeypatch):

@@ -225,14 +225,17 @@ def test_the_cuts_arithmetic_is_the_issues():
 
 def test_every_joyai_metric_resolves():
     bench = harness.load_benchmark()
+    # the cell's metrics are the entries that LIST it, whatever their names:
+    # a reading it shares with other cells is one entry over all of them
+    # (29 = the 9 no accepted entry repeats + 20 shared; the length of
+    # `per_layer` is held in one place, test_resolution.py)
     mine = harness.metrics_of_cell(bench, "per_layer", CELL)
-    assert len(mine) == 29 and all(m["name"].endswith(".joyai")
-                                   and m["workloads"] == [CELL]
-                                   for m in mine)
-    assert len(bench["per_layer"]) == 95 + 29 <= 128
+    own = [m for m in mine if m["name"].endswith(".joyai")]
+    assert len(mine) == 29 and len(own) == 9
+    assert all(m["workloads"] == [CELL] for m in own)
     for m in mine:
         spec, reader = harness.load_layer_metric(m["name"])
-        assert spec["kinds"] == ["serve_closed_latent"]
+        assert "serve_closed_latent" in spec["kinds"]
         assert reader.read({}, spec.get("args", {})) is None
         assert (spec["unit"], spec["moves"], spec["layer"]) == (
             m["unit"], m["moves"], m["layer"])
@@ -254,22 +257,22 @@ def test_every_joyai_metric_resolves():
     assert named <= set(MIXER_MODULES) | set(MIXER_SCOPES) | set(SCOPE_NAMES)
     assert {"latent_q", "latent_kv", "attn_latent", "latent_out",
             "latent_attn"} <= named
-    # the twins read what the accepted entries read
-    twins = {"engine_step_mean_ms": "engine_step_mean_ms.falcon",
-             "stall_share_pct": "stall_share_pct.serve",
-             "idle_stalled_pct": "idle_stalled_pct.serve",
-             "expert_matmul_up_roofline": "expert_matmul_up_roofline",
-             "experts_hit_pct": "experts_hit_pct.laguna",
-             "moe_pack_ms": "moe_pack_ms.laguna",
-             "unscoped_pct": "unscoped_pct.serve"}
-    for mine_, theirs in twins.items():
-        a = harness.load_layer_metric(f"{mine_}.joyai")[0]
-        b = harness.load_layer_metric(theirs)[0]
-        assert a["reader"] == b["reader"]
-        if "names" not in a.get("args", {}):
-            assert a.get("args") == b.get("args")
-        else:
-            assert a["args"]["scopes"] == b["args"]["scopes"]
+    # what the cell shares it reads under the accepted entry's own name:
+    # no twin under a suffix is left beside it
+    listed = {m["name"] for m in mine}
+    everything = {m["name"] for m in bench["per_layer"]}
+    for shared in ("engine_step_mean_ms", "stall_share_pct.serve",
+                   "idle_stalled_pct.serve", "expert_matmul_up_roofline",
+                   "experts_hit_pct.load", "unscoped_pct.serve"):
+        assert shared in listed
+        assert shared.split(".")[0] + ".joyai" not in everything
+    # a scope time of its own reads the scopes the Laguna cell's entry
+    # reads, over its own vocabulary of names: another reading
+    a = harness.load_layer_metric("moe_pack_ms.joyai")[0]
+    b = harness.load_layer_metric("moe_pack_ms.laguna")[0]
+    assert a["reader"] == b["reader"]
+    assert a["args"]["scopes"] == b["args"]["scopes"]
+    assert a["args"]["names"] != b["args"]["names"]
 
 
 def test_kernel_work_of_the_slice():

@@ -323,15 +323,18 @@ def test_every_laguna_metric_resolves_on_an_accepted_reader():
     # the cell's metrics are the entries that LIST it, whatever their names:
     # a reading it shares with other cells is one entry over all of them.
     # 27 + 2 of issue 49's 32 (PERF.md section 3 names the three left out)
+    # + PR 57's `idle_stalled_pct.serve`; the length of `per_layer` is held
+    # in one place, test_resolution.py
     mine = harness.metrics_of_cell(bench, "per_layer", CELL)
-    assert len(mine) == 27 + 2
+    assert len(mine) == 27 + 2 + 1
     assert {"stall_share_pct.serve", "stall_max_ms.serve",
             "batch_occupancy_pct", "engine_step_mean_ms",
-            "expert_second_tiles_pct"} <= {m["name"] for m in mine}
+            "expert_second_tiles_pct", "idle_stalled_pct.serve",
+            "experts_hit_pct.load"} <= {m["name"] for m in mine}
     accepted = {"counter", "client_clock", "trace_scope_ms",
                 "trace_scope_named_ms", "trace_roofline_pct",
                 "trace_idle_pct", "trace_idle_owner", "trace_span_ms",
-                "flight_stalls"}
+                "flight_stalls", "trace_idle_stalled_pct"}
     for m in mine:
         spec, reader = harness.load_layer_metric(m["name"])
         assert spec["reader"] in accepted \

@@ -16,6 +16,7 @@ import pytest
 
 from benchmark.lib import flops_lfm2, harness, reference_lfm2
 from benchmark.runners import serve_closed_patterned as runner
+from benchmark.runners import serve_closed_window as window
 from distributed_pytorch_tpu.config import LLMConfig
 from distributed_pytorch_tpu.engine import DecodeEngine
 from distributed_pytorch_tpu.models import ssm as ssm_mod
@@ -77,7 +78,25 @@ def _ctx(tmp_path, seconds=2.0, seed=2 ** 31 + 12345):
             "say": said.append}, said
 
 
-def test_patterned_runner_end_to_end(tmp_path, back_to_cwd):
+def _probe_waits():
+    """`step_program_rows` advances its numpy `pos` in place right behind a
+    jitted call, and on the CPU a `jnp.asarray` of an aligned numpy array
+    IS that array: a program still running reads the next step's positions
+    (its own assert fails, one run in a few under load; PERF.md section 7).
+    For the length of the `with`, the probe's results are waited for, as
+    the window runner does."""
+    probed = runner._probed
+    return window._patched(
+        _probed=lambda step: window._waited(probed(step)))
+
+
+@pytest.fixture
+def probe_waits():
+    with _probe_waits():
+        yield
+
+
+def test_patterned_runner_end_to_end(tmp_path, back_to_cwd, probe_waits):
     ctx, said = _ctx(tmp_path)
     # the runner's own draw, N(0, 0.02) at 64 wide: the layers add next to
     # nothing to E[id], which meets itself in the tied head, and greedy
@@ -226,13 +245,17 @@ def test_every_lfm2_metric_resolves_on_an_accepted_reader():
     # the entries that LIST the cell, whatever their names: a reading it
     # shares with other cells is one entry over all of them
     mine = harness.metrics_of_cell(bench, "per_layer", CELL)
-    assert len(mine) == 28 and {"kv_update_ms.lfm2", "engine_step_mean_ms",
-                                "stall_share_pct.serve"} <= {
+    # (29 since PR 57 listed the cell in `idle_stalled_pct.serve`; the
+    # length of `per_layer` is held in one place, test_resolution.py)
+    assert len(mine) == 29 and {"kv_update_ms.lfm2", "engine_step_mean_ms",
+                                "stall_share_pct.serve",
+                                "idle_stalled_pct.serve",
+                                "experts_hit_pct.load"} <= {
         m["name"] for m in mine}
     accepted = {"counter", "client_clock", "trace_scope_ms",
                 "trace_scope_named_ms", "trace_roofline_pct",
                 "trace_idle_pct", "trace_idle_owner", "trace_span_ms",
-                "flight_stalls"}
+                "flight_stalls", "trace_idle_stalled_pct"}
     for m in mine:
         spec, reader = harness.load_layer_metric(m["name"])
         assert spec["reader"] in accepted \
@@ -313,7 +336,7 @@ def driven(setup, tmp_path_factory):
     cfg, model, _, variables = setup
     eng = DecodeEngine(model, variables, **TRAFFIC["engine"])
     ctx, _ = _ctx(tmp_path_factory.mktemp("d"))
-    with jax.default_matmul_precision("highest"):
+    with _probe_waits(), jax.default_matmul_precision("highest"):
         return runner.step_program_rows(ctx, eng, TINY, 512)
 
 
@@ -368,7 +391,7 @@ def test_a_spoilt_reference_fails(setup, driven, tmp_path, fault):
             assert (err > 0.005) == (k in kinds), (fault, res["layers"])
 
 
-def test_a_tail_not_zeroed_fails(setup, tmp_path, monkeypatch):
+def test_a_tail_not_zeroed_fails(setup, tmp_path, monkeypatch, probe_waits):
     """The PROGRAM's fault: a first chunk that starts from what the slot's
     last occupant left. The sequences that went into a used slot read
     wrong; the first, into a fresh one, reads right."""

@@ -344,13 +344,19 @@ ROUTER_KINDS = ("sigmoid", "softmax_topk")   # of a patterned model's 'E'
 #: sequence between calls: "pools" (every row of its history, in blocks of
 #: the paged pool), "window" (a ring of the last rows, a slot), "slot_state"
 #: (leaves with a row a slot: a state-space layer's state and tail, a
-#: convolution's tail). A 'P' layer keeps two of them, in a cache slot
+#: convolution's tail, a linear-attention layer's matrix-valued state a head
+#: and tail). A 'P' layer keeps two of them, in a cache slot
 #: keyed by these names (models/gpt.py init_paged_cache). A latent layer
 #: ('L') keeps pools too, of another row: one latent row a position with no
 #: head axis (ops/latent_attention.py), addressed by the same block table.
+#: Layers of different letters stand in ONE cache tree, a leaf a layer:
+#: per-slot leaves ('M', 'C', 'K') beside pools ('*', 'L') beside rings
+#: ('W'); the one rule between them is the 'L' assertion's below (a latent
+#: pool and a GQA pool differ in row, so not both in one pattern).
 LAYER_KEEPS = {"M": ("slot_state",), "C": ("slot_state",), "E": (), "F": (),
                "*": ("pools",), "W": ("window",),
-               "P": ("pools", "slot_state"), "L": ("pools",)}
+               "P": ("pools", "slot_state"), "L": ("pools",),
+               "K": ("slot_state",)}
 POS_EMB_KINDS = ("learn", "sin", "rope", "none")
 # The reference realizes these as five separate trainer scripts
 # (single-gpu/train.py, multi-gpu/ddp/train.py, kaggle-zero1.py,
@@ -411,7 +417,8 @@ class LLMConfig:
     # `head_size` for the content, rotary-free and value widths, no norm
     # on either latent, decode over a gathered copy of the latent view. The
     # pattern's 'L' layer (models/attention.py LatentAttention, the
-    # published DeepSeek-V2/V3 form): an RMSNorm on each latent, a head's
+    # published DeepSeek-V2/V3 form; `q_latent_dim` 0 there = no query
+    # latent, `q = h W_q` in one matrix): an RMSNorm on each latent, a head's
     # query `[qk_nope_head_dim | rope_head_dim]` wide and its value
     # `v_head_dim` (0 = `head_size`, each), ONE rotated key head of
     # `rope_head_dim` shared by all query heads, the scale 1 / sqrt(nope +
@@ -470,7 +477,10 @@ class LLMConfig:
     # cache slot is of two kinds, a slot's state and tail AND blocks of
     # the pool; 'L' latent attention (models/attention.py LatentAttention:
     # `attn` 'mla', the widths beside it above), whose pools hold one
-    # latent row a position. Empty = the attention + FFN block above for
+    # latent row a position; 'K' delta-rule linear attention with a decay
+    # a channel (KDA; models/linear_attention.py, the `kda_*` widths
+    # below), whose slot keeps a (heads, d_k, d_v) float32 state and a
+    # convolution tail. Empty = the attention + FFN block above for
     # every layer. `n_layer` is its length. A patterned model has RMSNorms,
     # no FFN biases, and its parameters are created in `LLM.param_dtype`.
     layer_pattern: str = ""
@@ -521,10 +531,17 @@ class LLMConfig:
     # 'softmax_topk' (the top k LOGITS, softmax over those k, no bias and
     # no scale). A gated `non_linearity` ('swiglu', 'glu') makes the routed
     # and the shared experts gated: an up stack of 2 x up_dim, [a | b].
+    # `n_group` > 1 limits the sigmoid router's choice to groups (the
+    # `deepseek_v3` rule): the routed experts are `n_group` groups of
+    # consecutive ids, a group scores the sum of its two largest s + b, the
+    # `topk_group` best groups are kept and the top k taken inside them
+    # (models/mlp.py route_sigmoid); 1 = no limit, the ops as without it.
     experts_held: tuple = ()
     shared_up_dim: int = 0
     routed_scale: float = 1.0
     router: str = "sigmoid"
+    n_group: int = 1
+    topk_group: int = 1
     # a patterned model's scalar multipliers (1 = the program is as
     # without them): the embedding's output times `embed_mult`, every
     # block `x + resid_mult * mixer(norm(x))`, attention's softmax at
@@ -562,6 +579,17 @@ class LLMConfig:
     # 'F' layers: the dense FFN's width, gated as `non_linearity` says
     conv_len: int = 3
     dense_up_dim: int = 0
+    # 'K' layers (KDA): `kda_heads` heads of a (`kda_head_dim`,
+    # `kda_head_dim`) state, d_k = d_v; a depthwise convolution of
+    # `kda_conv` taps over [q' | k' | v'] (a slot carries its last
+    # `kda_conv` - 1 rows); the log decay a channel is `kda_lower_bound` x
+    # sigmoid(...), so it lies in (`kda_lower_bound`, 0): the bound is what
+    # lets a chunked form invert a span's decay in float32
+    # (ops/delta_rule.py). One sigmoid output gate a head.
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 4
+    kda_lower_bound: float = -5.0
 
     def __post_init__(self):
         object.__setattr__(self, "experts_held", tuple(self.experts_held))
@@ -588,12 +616,23 @@ class LLMConfig:
                     "a 'P' layer's attention branch is GQA"
             if "C" in self.layer_pattern:
                 assert self.conv_len >= 2
+            if "K" in self.layer_pattern:
+                assert self.kda_heads > 0 and self.kda_head_dim > 0 \
+                    and self.kda_conv >= 2, \
+                    "a 'K' layer needs `kda_heads`, `kda_head_dim` and a " \
+                    "convolution of at least 2 taps"
+                assert -88.0 / 16 < self.kda_lower_bound < 0.0, \
+                    "the log decay's bound times a sub-chunk of 16 rows " \
+                    "has to fit float32's exponent (ops/delta_rule.py)"
             if "L" in self.layer_pattern:
                 assert self.attn == "mla" and self.pos_emb == "rope" \
                     and not set("*WP") & set(self.layer_pattern), \
                     "a pattern with latent layers says attn 'mla', " \
-                    "pos_emb 'rope', and has no GQA layer beside them"
-                assert self.q_latent_dim and self.kv_latent_dim \
+                    "pos_emb 'rope', and has no GQA layer beside them " \
+                    "('*', 'W', 'P'): any other kind may stand there " \
+                    "(per-slot state 'M', 'C', 'K'; 'F'; 'E')"
+                # `q_latent_dim` 0 (or None): no query latent, q = h W_q
+                assert self.kv_latent_dim \
                     and self.rope_head_dim and self.rope_head_dim % 2 == 0
             else:
                 assert self.attn != "mla", \
@@ -620,6 +659,18 @@ class LLMConfig:
                 assert self.n_act > self.n_shared and \
                     self.n_exp > self.n_shared
                 assert self.router in ROUTER_KINDS, self.router
+                assert self.n_group >= 1 and \
+                    1 <= self.topk_group <= self.n_group and \
+                    self.n_routed % self.n_group == 0, \
+                    "n_group divides the routed experts, topk_group of " \
+                    "them are kept"
+                if self.n_group > 1:
+                    assert self.router == "sigmoid" and \
+                        self.n_routed // self.n_group >= 2 and \
+                        self.topk_group * (self.n_routed // self.n_group) \
+                        >= self.n_act_routed, \
+                        "a group limit is the sigmoid router's; a group " \
+                        "scores its two best; the kept groups hold top k"
                 if self.experts_held:
                     lo, n = self.experts_held
                     assert 0 <= lo and n >= 1 and lo + n <= self.n_routed
@@ -651,7 +702,9 @@ class LLMConfig:
             assert self.n_head % self.n_kv_heads == 0, \
                 "n_head must be divisible by n_kv_heads"
         elif self.attn == "mla":
-            assert self.q_latent_dim is not None and self.kv_latent_dim is not None, \
+            assert self.kv_latent_dim is not None and (
+                self.q_latent_dim is not None
+                or "L" in self.layer_pattern), \
                 "Either q_latent_dim or kv_latent_dim is missing"
             if self.pos_emb == "rope":
                 assert self.rope_head_dim is not None, "Need dim of Rotary heads"
@@ -708,9 +761,9 @@ class LLMConfig:
     @property
     def recurrent(self) -> bool:
         """Whether some layer keeps per-sequence state that is no block of
-        the paged cache: a state-space layer's state and tail, or a
-        convolution mixer's tail alone (what prefix reuse, the host tier
-        and speculative roll-back cannot snapshot yet)."""
+        the paged cache: a state-space or linear-attention layer's state
+        and tail, or a convolution mixer's tail alone (what prefix reuse,
+        the host tier and speculative roll-back cannot snapshot yet)."""
         return self.layers_keeping("slot_state") > 0
 
     @property

@@ -959,6 +959,12 @@ class DecodeEngine:
             leaf.size * leaf.dtype.itemsize // n_slots
             for path, leaf in jax.tree_util.tree_flatten_with_path(
                 self.caches)[0] if getattr(path[-1], "key", None) == "ssm")
+        # linear-attention layers ('K'): what their calls of the drained
+        # programs had to step, booked at the plan: "decode" = the planned
+        # decoding slots x 'K' layers (a slot's state read once and
+        # written once a layer), "chunk" = a chunk's real rows x 'K' layers
+        self._n_kda = cfg.layer_pattern.count("K")
+        self.kda_slot_steps_by = {"chunk": 0, "decode": 0}
         self.expert_calls = 0
         self.experts_hit = 0
         self.held_assignments = 0
@@ -1628,6 +1634,7 @@ class DecodeEngine:
             self.caches, self.tok, self.pos, self.live, first = out
             self.state_resets += int(self.cfg.recurrent)
             self.ssm_state_bytes_by["chunk"] += 2 * self._state_bytes_slot
+            self.kda_slot_steps_by["chunk"] += len(suffix) * self._n_kda
             # THE admit sync boundary: the first sampled token must reach the
             # host to stream it to the caller (a patterned model's routing
             # counts ride the same transfer)
@@ -2094,6 +2101,10 @@ class DecodeEngine:
             self.ssm_state_bytes_by["decode"] += state_bytes * prog.n_live
             self.ssm_state_bytes_by["chunk"] += \
                 state_bytes * (prog.chunk is not None)
+            self.kda_slot_steps_by["decode"] += prog.n_live * self._n_kda
+            if prog.chunk is not None:
+                self.kda_slot_steps_by["chunk"] += \
+                    prog.chunk[2] * self._n_kda
             live_steps = prog.n_live if prog.spec is None else 0
             self.decode_live_tiles += prog.live_tiles
             self.decode_live_steps += live_steps

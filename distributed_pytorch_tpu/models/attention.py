@@ -581,7 +581,10 @@ class LatentAttention(nn.Module):
     `deepseek_v3`-style modules), no biases. For a normed input h (B, T, C):
 
       c_q = RMSNorm(h W_qa) (`q_latent_dim`); q = c_q W_qb, a head's
-            `[q_nope (qk_nope_head_dim) | q_rope (rope_head_dim)]`
+            `[q_nope (qk_nope_head_dim) | q_rope (rope_head_dim)]`; with
+            `q_latent_dim` 0 there is no query latent and no query norm:
+            q = h W_q in ONE matrix (leaf `W_q`; published `q_lora_rank`
+            null)
       [c_kv | k_r] = h W_kva; c = RMSNorm(c_kv) (`kv_latent_dim`); k_r is
             ONE key head every query head shares
       RoPE on q_rope and k_r at the rows' own positions (`rope_theta`,
@@ -623,13 +626,18 @@ class LatentAttention(nn.Module):
             return self.param(name, nn.initializers.ones, (n,),
                               self.param_dtype)
 
-        w_qa, w_qb = mat("W_qa", C, nlq), mat("W_qb", nlq, nh * (dn + dr))
+        if nlq:
+            w_qa, w_qb = mat("W_qa", C, nlq), mat("W_qb", nlq,
+                                                  nh * (dn + dr))
+        else:
+            w_q = mat("W_q", C, nh * (dn + dr))
         w_kva = mat("W_kva", C, lc + dr)
         w_kvb = maybe_dequantized_param(
             (*self.path, "W_kvb"), mat("W_kvb", lc, nh * (dn + dv))
         ).astype(x.dtype).reshape(lc, nh, dn + dv)
         w_o = mat("W_o", nh * dv, C)
-        q_norm, kv_norm = vec("q_norm", nlq), vec("kv_norm", lc)
+        q_norm = vec("q_norm", nlq) if nlq else None
+        kv_norm = vec("kv_norm", lc)
         f = rope_angles(pos, T, dr, cfg.rope_theta)
         half = cfg.rope_pairing == "half"
         # one token of every slot attends absorbed; everything else
@@ -637,9 +645,13 @@ class LatentAttention(nn.Module):
         absorbed = cache is not None and T == 1
 
         with jax.named_scope("latent_q"):
-            c_q = _head_rms_norm(_qmm(self, x, w_qa, "W_qa"), q_norm,
-                                 cfg.norm_eps)
-            q = _qmm(self, c_q, w_qb, "W_qb").reshape(B, T, nh, dn + dr)
+            if nlq:
+                c_q = _head_rms_norm(_qmm(self, x, w_qa, "W_qa"), q_norm,
+                                     cfg.norm_eps)
+                q = _qmm(self, c_q, w_qb, "W_qb")
+            else:
+                q = _qmm(self, x, w_q, "W_q")
+            q = q.reshape(B, T, nh, dn + dr)
             q_nope = q[..., :dn]
             q_rope = apply_rotary_emb(q[..., dn:], f, half=half)
             if absorbed:

@@ -38,6 +38,8 @@ from distributed_pytorch_tpu.models.attention import (GQA, Attention,
                                                       init_attn_cache,
                                                       init_latent_cache,
                                                       init_window_cache)
+from distributed_pytorch_tpu.models.linear_attention import (KDA,
+                                                             init_kda_cache)
 from distributed_pytorch_tpu.models.mlp import MLP, MoE, RoutedExperts
 from distributed_pytorch_tpu.models.shortconv import (ShortConv,
                                                       init_conv_cache)
@@ -120,13 +122,14 @@ class MixerBlock(nn.Module):
     (models/shortconv.py), 'E' (models/mlp.py RoutedExperts), 'F'
     (models/mlp.py MLP at `cfg.dense_up_dim`), '*' (GQA), 'W' (GQA over
     a window of the last `cfg.window` positions), 'L' (LatentAttention,
-    module `latent_attn`: pools of latent rows) or 'P': TWO mixers on
-    the one normed input h, `mixer_sum(attn(a_in * h), ssm(s_in * h))`
-    (`MixerSum`; modules `attn` and `ssm` as in a '*' and an 'M' block).
-    What each keeps between
-    calls sits in the layer's cache slot: per-slot state leaves ('M': tail
-    and state, 'C': tail, 'W': a ring of the window's keys and values),
-    this program's routing counts, block pools ('*'), nothing ('F'),
+    module `latent_attn`: pools of latent rows), 'K' (KDA, module `kda`,
+    models/linear_attention.py) or 'P': TWO mixers on the one normed
+    input h, `mixer_sum(attn(a_in * h), ssm(s_in * h))` (`MixerSum`;
+    modules `attn` and `ssm` as in a '*' and an 'M' block). What each
+    keeps between calls sits in the layer's cache slot: per-slot state
+    leaves ('M': tail and state, 'C': tail, 'K': a matrix-valued state a
+    head and tail, 'W': a ring of the window's keys and values), this
+    program's routing counts, block pools ('*'), nothing ('F'),
     pools AND state leaves ('P': {"pools", "slot_state"}, so the rows'
     block table and their state context reach the same block).
     `state_ctx` (the engine's: which rows are live, or which slot a chunk
@@ -134,8 +137,8 @@ class MixerBlock(nn.Module):
     have no null block to land a pad in.
 
     `xs` are the hidden rows of the program's row sets (`rows`, one or
-    several). An 'M', 'C', 'F', '*', 'W' or 'L' layer takes them in turn,
-    the cache flowing from one to the next; an 'E' layer is position-wise
+    several). An 'M', 'C', 'K', 'F', '*', 'W' or 'L' layer takes them in
+    turn, the cache flowing from one to the next; an 'E' layer is position-wise
     and makes ONE call over all their rows, so its experts' matrices are
     read once."""
 
@@ -170,6 +173,7 @@ class MixerBlock(nn.Module):
             mixer = {
                 "M": lambda: Mamba2(cfg, pd, name="ssm"),
                 "C": lambda: ShortConv(cfg, pd, name="conv"),
+                "K": lambda: KDA(cfg, pd, name="kda"),
                 "F": lambda: MLP(cfg, cfg.dense_up_dim, pd, name="mlp"),
                 "*": lambda: GQA(cfg, self.attn_impl, pd, name="attn"),
                 "W": lambda: GQA(cfg, self.attn_impl, pd, "W", name="attn"),
@@ -178,7 +182,7 @@ class MixerBlock(nn.Module):
             ys, new_cache = [], cache
             for h, r in zip(hs, rows):
                 with _scope(r.scope):
-                    if self.kind in "MC":
+                    if self.kind in "MCK":
                         y, new_cache = mixer(h, new_cache, r.pos,
                                              r.state_ctx)
                     elif self.kind == "F":
@@ -506,18 +510,22 @@ def init_paged_cache(config: LLMConfig, n_blocks: int, block_size: int,
     for its 'W' layers (models/attention.py `init_window_cache`: whatever
     the pools' `n_blocks` and the engine's `max_len` are), nothing for
     'F' and 'E' layers (an 'E' slot carries a program's routing counts
-    out, never in). An 'L' layer's pool is ONE leaf of latent rows with no
-    head axis (models/attention.py `init_latent_cache`), addressed by the
-    same tables. A 'P' layer holds BOTH kinds, keyed by what they are
+    out, never in), a float32 state (heads, d_k, d_v) and a convolution
+    tail for its 'K' layers (models/linear_attention.py), which may stand
+    beside 'L' layers' pools in this one tree. An 'L' layer's pool is ONE
+    leaf of latent rows with no head axis (models/attention.py
+    `init_latent_cache`), addressed by the same tables. A 'P' layer holds
+    BOTH kinds, keyed by what they are
     (`config.LAYER_KEEPS`): {"pools": its attention branch's block pools,
     "slot_state": its state-space branch's tail and state}."""
     from distributed_pytorch_tpu.models.attention import init_paged_attn_cache
     if config.layer_pattern:
         assert n_slots > 0 or not config.slot_state, \
-            "state-space, convolution and window layers keep a row a " \
-            "slot: pass n_slots"
+            "state-space, convolution, linear-attention and window layers " \
+            "keep a row a slot: pass n_slots"
         make = {"M": lambda: init_ssm_cache(config, n_slots, dtype),
                 "C": lambda: init_conv_cache(config, n_slots, dtype),
+                "K": lambda: init_kda_cache(config, n_slots, dtype),
                 "W": lambda: init_window_cache(config, n_slots, block_size,
                                                dtype),
                 "*": lambda: init_paged_attn_cache(config, n_blocks,

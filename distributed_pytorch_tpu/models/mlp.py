@@ -380,19 +380,44 @@ class MoE(nn.Module):
         return y, aux_loss.astype(jnp.float32) * sw
 
 
+def limit_to_groups(biased: jnp.ndarray, n_group: int,
+                    topk_group: int) -> jnp.ndarray:
+    """The group limit of the `deepseek_v3` router on s + b (N, E): the
+    experts are `n_group` groups of E / n_group consecutive ids, a group
+    scores the sum of its two largest s + b, the `topk_group` best groups
+    are kept (ties to the lower group, as `top_k` breaks them) and every
+    s + b outside them becomes -inf, so that the top k that follows lies
+    inside the kept groups."""
+    N, E = biased.shape
+    by_group = biased.reshape(N, n_group, E // n_group)
+    score = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)
+    _, kept = jax.lax.top_k(score, topk_group)               # (N, topk_group)
+    keep = jnp.any(kept[:, :, None] == jnp.arange(n_group), axis=1)
+    return jnp.where(keep[:, :, None], by_group, -jnp.inf).reshape(N, E)
+
+
 def route_sigmoid(scores_in: jnp.ndarray, gate: jnp.ndarray,
-                  bias: jnp.ndarray, k: int, scale: float):
+                  bias: jnp.ndarray, k: int, scale: float,
+                  n_group: int = 1, topk_group: int = 1):
     """The router of the 'E' layers. s = sigmoid(x W_g) in float32 over
     every routed expert; the top k of s + b are chosen (the correction
     bias moves the SELECTION only); their weights are the unbiased s of
-    the chosen, divided by their sum, times `scale`. Returns (ids (N, k),
-    weights (N, k) float32)."""
+    the chosen, divided by their sum, times `scale`. With `n_group` > 1
+    the choice is group-limited first (`limit_to_groups`: groups of
+    consecutive ids, a group's score the sum of its two largest s + b,
+    the `topk_group` best groups kept, the rest masked before the top k);
+    `n_group` 1 is the path as it was, not an op more. Returns (ids
+    (N, k), weights (N, k) float32)."""
     # float32 in earnest: a TPU rounds a float32 product's operands to
     # bfloat16 unless told otherwise, and the selection is discontinuous
     s = jax.nn.sigmoid(jnp.dot(scores_in.astype(jnp.float32),
                                gate.astype(jnp.float32),
                                precision=jax.lax.Precision.HIGHEST))
-    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), k)
+    biased = s + bias.astype(jnp.float32)
+    if n_group > 1:
+        with jax.named_scope("route_groups"):
+            biased = limit_to_groups(biased, n_group, topk_group)
+    _, idx = jax.lax.top_k(biased, k)
     w = jnp.take_along_axis(s, idx, axis=1)
     return idx, w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20) * scale
 
@@ -490,7 +515,8 @@ class RoutedExperts(nn.Module):
         flats = [x.reshape(-1, C) for x in xs]
         with jax.named_scope("moe_route"):
             routes = [route_sigmoid(x_flat, gate, bias, cfg.n_act_routed,
-                                    cfg.routed_scale) if sigmoid else
+                                    cfg.routed_scale, cfg.n_group,
+                                    cfg.topk_group) if sigmoid else
                       route_softmax_topk(x_flat, gate, cfg.n_act_routed)
                       for x_flat in flats]
         if many:

@@ -25,6 +25,12 @@ dtype) and the state h (float32, state-major (N, H x P): ops/ssm_scan.py),
   whatever the slot held (a reused slot is the classic fault), a later
   one from the slot's state; rows past `valid_len` are pads and advance
   neither the state (dt = 0) nor the tail.
+
+The recurrent mixers of a patterned model, by module: this one ('M', and a
+'P' block's state-space branch; recurrence in ops/ssm_scan.py), the gated
+short convolution ('C', models/shortconv.py: a tail and no state) and KDA
+('K', models/linear_attention.py: recurrence in ops/delta_rule.py).
+`chunk_start` below is the one rule all three start a chunk by.
 """
 
 from __future__ import annotations

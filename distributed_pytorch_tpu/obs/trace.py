@@ -378,6 +378,8 @@ MIXER_MODULES = {
                  "of one block meet",
     "latent_attn": "an 'L' block's latent attention outside its scopes "
                    "(models/attention.py LatentAttention)",
+    "kda": "a 'K' block's linear attention outside its scopes "
+           "(models/linear_attention.py KDA)",
 }
 
 #: The scopes of a patterned model's mixers (`LLMConfig.layer_pattern`),
@@ -459,6 +461,27 @@ MIXER_SCOPES = {
                    "(ops/latent_attention.py)",
     "latent_out": "W_kvb^V on `sum p c` where the attention ran absorbed, "
                   "and W_o",
+    # a linear-attention layer's ('K', PR 62), module `kda`
+    "kda_proj": "the projections of the normed input: W_qkv, W_a (the "
+                "decay's) and W_bg (beta and the output gate, a scalar a "
+                "head each) (models/linear_attention.py)",
+    "kda_conv": "the depthwise causal convolution over [q' | k' | v'] and "
+                "its silu, chunk and one-token forms, the tail's shift "
+                "(ops/ssm_scan.py causal_conv, conv_step)",
+    "kda_gate": "the L2 norms of q and k, the bounded log decay a channel, "
+                "beta and the output gate's sigmoid",
+    "attn_kda": "the delta rule on the slot's state: kda_state_step (one "
+                "token of every live slot, the state in place) or the "
+                "chunked WY form of a prefill chunk, or their XLA twins "
+                "(ops/delta_rule.py)",
+    "kda_chunk": "inside `attn_kda`: the chunked WY form of a cached "
+                 "prefill chunk alone, fused XLA with no kernel name of "
+                 "its own (ops/delta_rule.py kda_chunk)",
+    "kda_out": "the heads' RMSNorm, the head-wise gate and W_o",
+    # inside `moe_route`, where `cfg.n_group` > 1 alone (PR 62)
+    "route_groups": "the group limit of the sigmoid router: a group's "
+                    "score (its two largest s + b), the best groups kept, "
+                    "the rest masked (models/mlp.py limit_to_groups)",
 }
 
 

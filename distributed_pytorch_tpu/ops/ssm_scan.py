@@ -45,7 +45,14 @@ of rows pass `compat.VMEM_LIMIT_BYTES`. The choice is from shapes and the
 backend alone; which way a program went is in its `paths` line
 (obs/paths.py, kind `ssm_step`).
 `causal_conv` / `conv_step` are the depthwise width-K convolution in front
-of it, with the last K - 1 inputs as its carried tail.
+of it, with the last K - 1 inputs as its carried tail; three mixers share
+them (models/ssm.py, models/shortconv.py, models/linear_attention.py).
+
+Which recurrence lives where: THIS file is the scalar decay a head (Mamba-2:
+a state of (N, H * P), one exp(dt A) a head and step). The matrix-valued
+state with a decay a CHANNEL and the delta rule's write (KDA: a state of
+(H, d_k, d_v)) is ops/delta_rule.py, whose step kernel follows
+`ssm_state_step`'s turns.
 """
 
 from __future__ import annotations

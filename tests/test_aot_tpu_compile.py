@@ -30,6 +30,7 @@ from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 from distributed_pytorch_tpu.obs import paths  # noqa: E402
 from distributed_pytorch_tpu.ops import block_pool as bp  # noqa: E402
+from distributed_pytorch_tpu.ops import delta_rule as dr  # noqa: E402
 from distributed_pytorch_tpu.ops import flash_attention as fa  # noqa: E402
 from distributed_pytorch_tpu.ops import flash_decode as fd  # noqa: E402
 from distributed_pytorch_tpu.ops import grouped_matmul as gm  # noqa: E402
@@ -263,7 +264,19 @@ CASES = {
     "latent_decode_64x32x640": (lambda: _latent(0), ["latent_flash_decode"]),
     "latent_prefill_1024x32": (lambda: _latent(1024),
                                ["latent_flash_prefill"]),
+    # Ling-3.0-flash's published KDA state (PR 62): 32 heads of a 128 x 128
+    # float32 state a slot, 192 slots a call, 8 slots (16 MB) a phase
+    "kda_step_192x32x128x128": (lambda: _kda_step(192),
+                                ["kda_state_step"]),
 }
+
+
+def _kda_step(n, H=32, d=128):
+    shapes = [((n, H, d, d), F32)] + [((n, H, d), F32)] * 4 \
+        + [((n, H), F32), ((n,), jnp.bool_)]
+    return (lambda S, q, k, v, g, b, live: dr.kda_step_kernel(
+        S, q, k, v, g, b, live)), shapes
+
 
 GRANITE_EXPERTS = dict(C=4096, F=768, held=36, k=10, n_routed=72, gated=True)
 LFM2_EXPERTS = dict(C=2048, F=1536, held=64, k=4, n_routed=64, gated=True)

@@ -12,19 +12,25 @@ g (d_k,) in (`lower_bound`, 0) and write strength beta in (0, 1):
 `kda_step` is those three lines for one token of every slot (decode): the
 state is read once and written once, in place. It sends the call to
 `kda_step_kernel` where `kda_step_kernel_decline` finds nothing against it:
-ONE Pallas call, `kda_state_step`, after `ssm_state_step`'s pattern
-(ops/ssm_scan.py: the state stays in HBM and moves by the kernel's own DMAs
-IN TURNS, a phase of slots read while the phase before it is worked on in
-place in VMEM, then that phase written back alone). What a head needs as a
-COLUMN over the state's rows (exp(g), k, beta k, q: d_k values each) comes
-in as one (d_k, 4 H) block a slot, heads along the lanes, transposed
-outside the kernel: a column is then one lane of it, broadcast along the
-lanes, and both sums over d_k are sums over ROWS, vector adds with no
-traffic between lanes. `kda_step_xla`, the same three lines in jax.numpy,
-is the twin of the parity tests and runs every call the gate declines (the
-backend is no TPU; the state is not float32 (S, H, d_k, d_v); d_k is no
-multiple of 8 or d_v no whole lane tile; a multi-device mesh is live; two
-phases pass the VMEM budget).
+ONE Pallas call, `kda_state_step`: the state stays in HBM and moves by the
+kernel's own DMAs IN TURNS through a RING OF THREE phase buffers in VMEM.
+While a phase of slots is worked on in place, the next phase is read under
+the first half of its slots and the phase before it is written back under
+the second half: a phase's arithmetic (~7 vector ops an element: longer than
+either DMA, shorter than both) hides under a read AND a write, and no DMA
+waits for the vector unit. (`ssm_state_step`, ops/ssm_scan.py, whose few ops
+an element fit under the read alone, keeps two buffers and writes a phase
+back alone: under that schedule ~10 us of this kernel's arithmetic would
+stand bare in every phase.) What a head needs as a COLUMN over the state's
+rows (exp(g), k, beta k, q: d_k values each) comes in as one (d_k, 4 H)
+block a slot, heads along the lanes, transposed outside the kernel: a column
+is then one lane of it, broadcast along the lanes, and both sums over d_k
+are sums over ROWS, vector adds with no traffic between lanes.
+`kda_step_xla`, the same three lines in jax.numpy, is the twin of the parity
+tests and runs every call the gate declines (the backend is no TPU; the
+state is not float32 (S, H, d_k, d_v); d_k is no multiple of 8 or d_v no
+whole lane tile; a multi-device mesh is live; the ring's three phases pass
+the VMEM budget).
 
 `kda_chunk` is the same recurrence over a prefill chunk's T rows of ONE
 sequence, from the slot's state at the chunk's start to the state at its
@@ -68,8 +74,10 @@ from distributed_pytorch_tpu.obs import paths
 from distributed_pytorch_tpu.ops.flash_decode import _budget_decline
 
 #: bytes of state a phase of the step kernel reads, works on and writes
-#: back (two such buffers live in VMEM; ops/ssm_scan.py measured the turns)
+#: back (ops/ssm_scan.py measured the turns)
 _PHASE_BYTES = 16 << 20
+#: phase buffers in VMEM: one read into, one worked on, one written from
+_RING = 3
 #: rows of a sub-chunk of the chunked form: 16 x |lower_bound| < 88
 SUB_CHUNK = 16
 _HI = jax.lax.Precision.HIGHEST
@@ -106,9 +114,9 @@ def _step_slots(S: int, H: int, dk: int, dv: int) -> int:
 
 
 def _step_vmem_bytes(k: int, H: int, dk: int, dv: int) -> int:
-    """Two phases of state; the blocks of columns (lanes in whole tiles),
-    of v and of o, each twice."""
-    return 2 * k * H * dk * dv * 4 + 2 * 4 * k * (
+    """The ring's three phases of state; the blocks of columns (lanes in
+    whole tiles), of v and of o, each twice."""
+    return _RING * k * H * dk * dv * 4 + 2 * 4 * k * (
         dk * -(-4 * H // 128) * 128 + 2 * -(-H // 8) * 8 * dv)
 
 
@@ -130,11 +138,22 @@ def _slot_step(hv, r, cols_ref, v_ref, o_ref, *, H: int):
 
 def _step_kernel(live_ref, s_hbm, cols_ref, v_ref, o_hbm, o_ref, buf, sem,
                  *, k: int, H: int):
-    """Phase i of the call: the states of slots i k .. i k + k - 1, read
-    into one buffer while the phase before it is worked on in the other,
-    then written back alone (`s_hbm` and `o_hbm` are one buffer)."""
+    """Phase i of the call: the states of slots i k .. i k + k - 1, worked
+    on in place in buffer i % 3 of the ring. Phase i + 1 is read into the
+    next buffer under the first half of the slots; at the TURN (slot
+    k // 2) that read is waited for and phase i - 1 starts back out of the
+    third buffer, under the second half, and is waited for at the step's
+    end: one DMA at a time, and none waits for the arithmetic. The edges:
+    the first phase has nothing to write, so its read has all k slots over
+    it; the last has nothing to read, so the write behind it starts at its
+    first slot, and the phase itself leaves after its last (`s_hbm` and
+    `o_hbm` are one buffer)."""
     i, n = pl.program_id(0), pl.num_programs(0)
-    b = jax.lax.rem(i, 2)
+    b = jax.lax.rem(i, _RING)
+    ahead = jax.lax.rem(i + 1, _RING)
+    behind = jax.lax.rem(i + _RING - 1, _RING)
+    more, begun = i + 1 < n, i >= 1
+    turn_at = jnp.where(begun, jnp.where(more, k // 2, 0), k)
 
     def read(p, into):
         return pltpu.make_async_copy(s_hbm.at[pl.ds(p * k, k)], buf.at[into],
@@ -149,11 +168,18 @@ def _step_kernel(live_ref, s_hbm, cols_ref, v_ref, o_hbm, o_ref, buf, sem,
         read(0, 0).start()
         read(0, 0).wait()
 
-    @pl.when(i + 1 < n)
+    @pl.when(more)
     def _():
-        read(i + 1, 1 - b).start()
+        read(i + 1, ahead).start()
 
     def slot(kk, carry):
+        @pl.when(kk == turn_at)
+        def _():
+            @pl.when(more)
+            def _():
+                read(i + 1, ahead).wait()
+            write(i - 1, behind).start()
+
         alive = live_ref[i * k + kk] != 0
 
         @pl.when(alive)
@@ -168,12 +194,18 @@ def _step_kernel(live_ref, s_hbm, cols_ref, v_ref, o_hbm, o_ref, buf, sem,
 
     jax.lax.fori_loop(0, k, slot, 0)
 
-    @pl.when(i + 1 < n)
+    @pl.when(jnp.logical_and(more, jnp.logical_not(begun)))
     def _():
-        read(i + 1, 1 - b).wait()
+        read(i + 1, ahead).wait()
 
-    write(i, b).start()
-    write(i, b).wait()
+    @pl.when(begun)
+    def _():
+        write(i - 1, behind).wait()
+
+    @pl.when(jnp.logical_not(more))
+    def _():
+        write(i, b).start()
+        write(i, b).wait()
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -181,8 +213,9 @@ def kda_step_kernel(S, q, k, v, g, beta, live=None, *,
                     interpret: bool = False):
     """`kda_step` as ONE Pallas call, `kda_state_step`: grid (phases of
     slots,), the state left in HBM (`pl.ANY`), read once and written in
-    place by the kernel's own DMAs; a phase's columns, its rows of v and
-    of o come and go through BlockSpecs."""
+    place by the kernel's own DMAs through a ring of three phase buffers;
+    a phase's columns, its rows of v and of o come and go through
+    BlockSpecs."""
     n, H, dk, dv = S.shape
     f32 = jnp.float32
     ks = _step_slots(n, H, dk, dv)
@@ -205,7 +238,7 @@ def kda_step_kernel(S, q, k, v, g, beta, live=None, *,
                                    lambda i, live_ref: (i, 0, 0)),
                       rows],
             out_specs=[in_hbm, rows],
-            scratch_shapes=[pltpu.VMEM((2, ks, H, dk, dv), f32),
+            scratch_shapes=[pltpu.VMEM((_RING, ks, H, dk, dv), f32),
                             pltpu.SemaphoreType.DMA((2,))]),
         out_shape=[jax.ShapeDtypeStruct(S.shape, f32),
                    jax.ShapeDtypeStruct((n, H, dv), f32)],
@@ -251,7 +284,8 @@ def kda_step(S, q, k, v, g, beta, live=None):
     why = kda_step_kernel_decline(S)
     if why is None:
         paths.note("kda_step", "kda_state_step",
-                   "state in place, %d slots a phase" % _step_slots(*S.shape))
+                   "state in place, %d slots a phase, a ring of %d"
+                   % (_step_slots(*S.shape), _RING))
         return kda_step_kernel(S, q, k, v, g, beta, live)
     paths.note("kda_step", "xla", f"kda_step_kernel_decline: {why}")
     return kda_step_xla(S, q, k, v, g, beta, live)

@@ -51,8 +51,8 @@ them (models/ssm.py, models/shortconv.py, models/linear_attention.py).
 Which recurrence lives where: THIS file is the scalar decay a head (Mamba-2:
 a state of (N, H * P), one exp(dt A) a head and step). The matrix-valued
 state with a decay a CHANNEL and the delta rule's write (KDA: a state of
-(H, d_k, d_v)) is ops/delta_rule.py, whose step kernel follows
-`ssm_state_step`'s turns.
+(H, d_k, d_v)) is ops/delta_rule.py, whose step kernel takes these turns
+through a ring of THREE buffers (its arithmetic outlasts a phase's read).
 """
 
 from __future__ import annotations

@@ -265,7 +265,8 @@ CASES = {
     "latent_prefill_1024x32": (lambda: _latent(1024),
                                ["latent_flash_prefill"]),
     # Ling-3.0-flash's published KDA state (PR 62): 32 heads of a 128 x 128
-    # float32 state a slot, 192 slots a call, 8 slots (16 MB) a phase
+    # float32 state a slot, 192 slots a call, 8 slots (16 MB) a phase, three
+    # phases in VMEM (PR 63: 49.5 MiB by the gate's count, under the 64 MiB)
     "kda_step_192x32x128x128": (lambda: _kda_step(192),
                                 ["kda_state_step"]),
 }

@@ -228,14 +228,44 @@ def test_one_token_form_is_the_literal_recurrence(live):
 
 # (3) the step kernel in interpret mode against its twin ---------------------
 
-@pytest.mark.parametrize("n,H,d,phase_bytes,live", [
-    (6, 2, 8, 16 << 20, (1, 1, 0, 1, 1, 1)),     # one phase, a dead slot
-    (8, 2, 8, 2 * 2 * 8 * 8 * 4, (1, 0, 1, 1, 1, 1, 0, 1)),   # 4 phases of 2
-    (5, 3, 16, 3 * 16 * 16 * 4, None),           # a slot a phase, all live
-    (4, 1, 8, 16 << 20, (0, 0, 0, 0)),           # nothing live
+def _a_slot(H, d):
+    return H * d * d * 4
+
+
+#: the interpreter that runs a DMA at its WAIT, the latest the kernel allows
+#: it, and watches for races: a buffer of the ring read before its read was
+#: waited for, or refilled before its write was, reads stale rows there
+_LATE = dict(dma_execution_mode="on_wait", detect_races=True)
+
+
+@pytest.mark.parametrize("n,H,d,phase_bytes,live,late", [
+    (6, 2, 8, 16 << 20, (1, 1, 0, 1, 1, 1), False),   # one phase, a dead slot
+    (8, 2, 8, 2 * _a_slot(2, 8), (1, 0, 1, 1, 1, 1, 0, 1), False),  # 4 of 2
+    (5, 3, 16, _a_slot(3, 16), None, False),     # a slot a phase, all live
+    (4, 1, 8, 16 << 20, (0, 0, 0, 0), False),    # nothing live
+    # the ring's edges: 1, 2, 3 and 5 phases
+    (4, 2, 8, 16 << 20, None, True),                          # 1 phase
+    (8, 2, 8, 4 * _a_slot(2, 8), None, True),                 # 2 phases
+    (12, 2, 8, 4 * _a_slot(2, 8), None, True),                # 3 phases
+    (10, 2, 8, 2 * _a_slot(2, 8), None, True),                # 5 phases
+    # one slot a phase (the first half of a phase is empty): 1, 2, 3 phases
+    (1, 2, 8, _a_slot(2, 8), None, True),
+    (2, 2, 8, _a_slot(2, 8), (0, 1), True),
+    (3, 2, 8, _a_slot(2, 8), (1, 0, 1), True),
+    # an odd count of slots a phase: 3 phases of 5, 2 phases of 3
+    (15, 1, 8, 5 * _a_slot(1, 8), None, True),
+    (6, 2, 8, 3 * _a_slot(2, 8), (1, 0, 1, 0, 1, 1), False),
+    # dead slots in the first half of every phase, in the second half, a
+    # whole dead phase in the middle, at the start and at the end
+    (12, 2, 8, 4 * _a_slot(2, 8), (0, 0, 1, 1) * 3, True),
+    (12, 2, 8, 4 * _a_slot(2, 8), (1, 1, 0, 0) * 3, True),
+    (12, 2, 8, 4 * _a_slot(2, 8), (1,) * 4 + (0,) * 4 + (1,) * 4, True),
+    (10, 2, 8, 2 * _a_slot(2, 8), (0, 0) + (1,) * 8, False),
+    (10, 2, 8, 2 * _a_slot(2, 8), (1,) * 8 + (0, 0), True),
 ])
 def test_step_kernel_against_its_twin(monkeypatch, n, H, d, phase_bytes,
-                                      live):
+                                      live, late):
+    from jax.experimental.pallas import tpu as pltpu
     monkeypatch.setattr(dr, "_PHASE_BYTES", phase_bytes)
     jax.clear_caches()
     q, k, v, g, beta, _ = _operands(n, H=H, d=d, seed=n)
@@ -243,14 +273,36 @@ def test_step_kernel_against_its_twin(monkeypatch, n, H, d, phase_bytes,
     mask = None if live is None else jnp.asarray(live, bool)
     assert dr.kda_step_kernel_decline(S, interpret=True) is None
     want_o, want_S = dr.kda_step_xla(S, q, k, v, g, beta, mask)
-    o, Sn = dr.kda_step_kernel(S, q, k, v, g, beta, mask, interpret=True)
+    o, Sn = dr.kda_step_kernel(
+        S, q, k, v, g, beta, mask,
+        interpret=pltpu.InterpretParams(**_LATE) if late else True)
     alive = np.ones(n, bool) if live is None else np.asarray(live, bool)
     np.testing.assert_allclose(np.asarray(o)[alive],
                                np.asarray(want_o)[alive], atol=2e-6)
     np.testing.assert_allclose(Sn, want_S, atol=2e-6)
     assert np.array_equal(np.asarray(Sn)[~alive], np.asarray(S)[~alive])
     assert not np.asarray(o)[~alive].any()        # a dead slot's o is zeros
+    if late:
+        from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+        assert not interpret_pallas_call.races.races_found
     jax.clear_caches()
+
+
+def test_a_phase_of_the_step_kernel_and_the_rings_bytes():
+    """16 MB of whole slots that divide the call, and THREE such buffers in
+    the gate's count: at the published shape 8 slots a phase and 49.5 MiB,
+    inside the 64 MiB scoped limit."""
+    from distributed_pytorch_tpu.compat import VMEM_LIMIT_BYTES
+    assert dr._PHASE_BYTES == 16 << 20 and dr._RING == 3
+    assert dr._step_slots(192, 32, 128, 128) == 8
+    assert dr._step_slots(6, 32, 128, 128) == 6
+    assert dr._step_slots(7, 32, 128, 128) == 7
+    assert dr._step_slots(18, 32, 128, 128) == 6
+    assert dr._step_slots(5, 64, 256, 256) == 1       # one at least
+    state = 8 * 32 * 128 * 128 * 4
+    blocks = 2 * 4 * 8 * (128 * 128 + 2 * 32 * 128)   # columns, v, o: twice
+    assert dr._step_vmem_bytes(8, 32, 128, 128) == 3 * state + blocks \
+        == 51_904_512 < VMEM_LIMIT_BYTES
 
 
 # (4) the gates and the paths line -------------------------------------------
@@ -263,6 +315,9 @@ def test_step_kernel_against_its_twin(monkeypatch, n, H, d, phase_bytes,
     (dict(shape=(4, 2, 8, 12)), "no whole tiles of 8 x 8"),
     (dict(lane128=True), "no whole tiles of 8 x 128"),
     (dict(shape=(2, 128, 256, 256)), "VMEM"),
+    # 24 MiB a slot: two buffers would fit the 64 MiB, the ring's three do not
+    (dict(shape=(2, 96, 256, 256)), "needs 73 MiB of VMEM"),
+    (dict(shape=(2, 64, 256, 256)), None),           # 16 MiB a slot: 3 fit
     (dict(), None),
 ])
 def test_step_gate(monkeypatch, change, told):
@@ -328,6 +383,25 @@ def test_the_paths_line_says_which_ran(mv):
     assert chosen["kda_chunk"] == ("xla_wy (forward substitution in "
                                    "sub-chunks of 16 rows)")
     assert _rel(got, want) < 3e-5
+
+
+def test_the_kernels_note_names_the_phase_and_the_ring(monkeypatch):
+    """Where the gate lets a call through, the note says the phase AND the
+    ring: the record of which schedule a program's six calls ran."""
+    monkeypatch.setattr(dr, "_PHASE_BYTES", 2 * _a_slot(2, 128))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(dr, "kda_step_kernel",
+                        functools.partial(dr.kda_step_kernel, interpret=True))
+    jax.clear_caches()
+    q, k, v, g, beta, _ = _operands(6, H=2, d=128, seed=1)
+    S = jax.random.normal(jax.random.PRNGKey(2), (6, 2, 128, 128))
+    paths.reset()
+    o, Sn = dr.kda_step(S, q, k, v, g, beta)
+    assert paths.choices()["kda_step"] == \
+        "kda_state_step (state in place, 2 slots a phase, a ring of 3)"
+    np.testing.assert_allclose(Sn, dr.kda_step_xla(S, q, k, v, g, beta)[1],
+                               atol=2e-6)
+    jax.clear_caches()
 
 
 # (5) the group-limited choice -----------------------------------------------

@@ -156,6 +156,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from distributed_pytorch_tpu.engine.counts import (RETIRE_REASONS,
+                                                   EngineCounts)
 from distributed_pytorch_tpu.models.generate import sample_token
 from distributed_pytorch_tpu.models.gpt import Rows, init_paged_cache
 from distributed_pytorch_tpu.obs import paths
@@ -166,11 +168,6 @@ from distributed_pytorch_tpu.ops import kv_tier
 from distributed_pytorch_tpu.ops.block_pool import (BlockPool, NoFreeBlocks,
                                                     _child_digest, chain_keys)
 from distributed_pytorch_tpu.parallel import context
-
-
-#: Why a sequence left its slot — the serving layer routes on these.
-#: 'preempted' carries partial output that callers REQUEUE, never drop.
-RETIRE_REASONS = ("eos", "budget", "cache_full", "cancelled", "preempted")
 
 
 # ----------------------------------------------------------------------
@@ -555,7 +552,7 @@ class _Program:
     # cache tiles of `block_size` rows its decode attention call walks for
     # those slots, from the planned lengths: the paged kernel's grid step
     # is a sequence and holds every one of its live tiles
-    # (`DecodeEngine.decode_tiles_per_grid_step`)
+    # (`EngineCounts.decode_tiles_per_grid_step`)
     live_tiles: int = 0
     # key rows ONE layer's attention calls of this program read, by the
     # planned lengths: {"decode" | "chunk": (of a whole history, of the
@@ -625,9 +622,9 @@ class DecodeEngine:
     buffers), `quantize_weights=True` runs decode matmuls on int8 codes.
 
     The stable accounting surface a scheduler reads: `n_free`/`occupancy`
-    /`retire_counts` plus the paged additions `block_utilization`/
-    `block_fragmentation`/`prefix_hit_rate`/`prefilled_tokens` (never the
-    private `_slots`).
+    plus the paged additions `block_utilization`/`block_fragmentation`
+    (never the private `_slots`), and as attributes of the engine every
+    count of `counts` (engine/counts.py: `retire_counts`, ...).
     """
 
     def __init__(self, model, variables: dict, *, n_slots: int = 8,
@@ -884,97 +881,8 @@ class DecodeEngine:
             aot_store = resolve_store()
         self.aot_store = aot_store or None   # False = explicitly off
         self._aot_origin = "runtime"
-        # lifetime counters — the stable occupancy/accounting surface a
-        # scheduler reads instead of poking _slots
-        self.n_admitted = 0
-        self.retire_counts = dict.fromkeys(RETIRE_REASONS, 0)
-        # prefix-cache accounting (bench + /metrics read these)
-        self.prompt_tokens = 0        # prompt tokens across admissions
-        self.prefix_hit_tokens = 0    # of those, served from cached blocks
-        self.prefilled_tokens = 0     # suffix tokens actually prefilled
-        # speculative-decoding accounting (bench + /metrics read these)
-        self.spec_drafted_tokens = 0  # drafter proposals sent to verify
-        self.spec_accepted_tokens = 0  # of those, accepted by the target
-        self.emitted_tokens = 0       # tokens emitted across all steps
-        # lookahead accounting (`overlap_share`, /metrics, flight record)
-        self.overlapped_programs = 0  # dispatched behind a running one
-        self.drain_reasons: dict[str, int] = {}  # the others, by why not
-        # chunk accounting (`chunk_fill_share`, /metrics, /debug/timeline)
-        self.chunk_programs = 0       # drained programs that carried a chunk
-        self.chunked_prompts = 0      # prompts whose last chunk has run
-        # of those programs, the ones whose expert layers made ONE call
-        # over the chunk's rows and the decode rows (`make_fused_step_fn`)
-        self.merged_programs = 0
-        # what the programs' decode attention calls walked, by the host's
-        # own lengths (`decode_tiles_per_grid_step`): live cache tiles and
-        # the grid steps that held them (one a decoding sequence)
-        self.decode_live_tiles = 0
-        self.decode_live_steps = 0
-        # a model with window layers ('W'): key/value rows the attention
-        # calls of the drained programs had to read, by the planned
-        # lengths, a layer's call each, by what the call was ("decode":
-        # every live slot's token | "chunk"), in the layers that keep the
-        # whole history (`kv_rows_read_full`) and in the window layers
-        # (`kv_rows_read_window`); `window_rows_saved` = what the window
-        # layers' calls would have read of a whole history, less what
-        # they read; `chunk_attn_pairs_by`: (query, key) pairs the chunk
-        # calls' masks let through, a chunk's REAL rows alone (a last
-        # chunk is partial), in the "full" and the "window" layers
-        self._n_full = cfg.layers_keeping("pools")
-        self._n_window = cfg.layers_keeping("window")
-        # latent layers ('L') keep the whole history too, in rows of
-        # another kind: `latent_rows_read_by` is what their calls had to
-        # read, counted among `kv_rows_read_full_by` as well
-        self._n_latent = cfg.layer_pattern.count("L")
-        # the rows are planned where the layers differ in what they keep
-        # (window layers, or a layer that keeps two kinds: 'P'), or where
-        # a kernel's roofline reads them (latent layers)
-        self._plans_kv_rows = bool(self._n_window or self._n_latent) or any(
-            len(keeps) > 1 for keeps in cfg.layer_keeps)
-        self.latent_rows_read_by = {"chunk": 0, "decode": 0}
-        self.kv_rows_read_full_by = {"chunk": 0, "decode": 0}
-        self.kv_rows_read_window_by = {"chunk": 0, "decode": 0}
-        self.window_rows_saved = 0
-        self.chunk_attn_pairs_by = {"full": 0, "window": 0}
-        # tokens computed for an occupant that had left by the drain: an
-        # `eos` seen one program late, a cancel while its program ran
-        self.overrun_tokens = 0
-        # what a patterned model's layers did (/metrics, flight record):
-        # a slot's recurrent state began anew (an admission's first
-        # chunk); per call of an expert layer: how many held experts were
-        # hit; assignments of real rows to held and to absent experts;
-        # tiles of the expert kernels beyond a hit expert's first (each
-        # reads the expert's matrices again; the kernels' own count,
-        # carried out of the program), the calls that had one, and the
-        # calls, by what the call carried ("chunk": a chunk's rows, alone
-        # or with the decode rows | "decode": those alone); the routing
-        # weights that fell on held experts (`held_gate_share`): both of
-        # softmax-routed programs alone, 0 elsewhere
-        self.state_resets = 0
-        # float32 state the state-space layers' calls of the drained
-        # programs read and wrote back: the planned decoding slots (a
-        # chunk: its one slot) x layers x a slot's state, in and out
-        self.ssm_state_bytes_by = {"chunk": 0, "decode": 0}
-        self._state_bytes_slot = sum(
-            leaf.size * leaf.dtype.itemsize // n_slots
-            for path, leaf in jax.tree_util.tree_flatten_with_path(
-                self.caches)[0] if getattr(path[-1], "key", None) == "ssm")
-        # linear-attention layers ('K'): what their calls of the drained
-        # programs had to step, booked at the plan: "decode" = the planned
-        # decoding slots x 'K' layers (a slot's state read once and
-        # written once a layer), "chunk" = a chunk's real rows x 'K' layers
-        self._n_kda = cfg.layer_pattern.count("K")
-        self.kda_slot_steps_by = {"chunk": 0, "decode": 0}
-        self.expert_calls = 0
-        self.experts_hit = 0
-        self.held_assignments = 0
-        self.absent_assignments = 0
-        self.expert_calls_by = {"chunk": 0, "decode": 0}
-        self.expert_second_tiles_by = {"chunk": 0, "decode": 0}
-        self.expert_second_tile_calls_by = {"chunk": 0, "decode": 0}
-        self.held_gate_sum = 0.0
-        n_held = (cfg.experts_held or (0, cfg.n_routed))[1]
-        self.expert_tokens = np.zeros((n_held,), np.int64)
+        # all the engine counts; a name this class lacks is read off it
+        self.counts = EngineCounts(cfg, self.caches, n_slots, prefill_chunk)
         # step-level flight recorder (obs/flight.py): one record per
         # fused step in a bounded ring — the /debug/timeline payload and
         # the runs/*.jsonl post-hoc artifact
@@ -1241,59 +1149,11 @@ class DecodeEngine:
     def _n_traces(self) -> int:
         return sum(g.count for g in self.trace_guards.values())
 
-    @property
-    def accepted_token_rate(self) -> float:
-        """Lifetime fraction of drafted tokens the verify accepted."""
-        return (self.spec_accepted_tokens / self.spec_drafted_tokens
-                if self.spec_drafted_tokens else 0.0)
-
-    @property
-    def tokens_per_step(self) -> float:
-        """Lifetime mean tokens emitted per fused step — the speculative
-        multiplier on step throughput (1.0 when spec is off or missing)."""
-        return self.emitted_tokens / self._t if self._t else 0.0
-
-    @property
-    def overlap_share(self) -> float:
-        """Lifetime fraction of step programs dispatched behind a running
-        one — the device had its next program queued before it finished
-        the last. The rest are counted by cause in `drain_reasons`."""
-        return self.overlapped_programs / self._t if self._t else 0.0
-
-    @property
-    def chunk_fill_share(self) -> float:
-        """Lifetime share of the chunk rows the fused programs computed
-        that held a real prompt id: ids prefilled / (chunk-carrying
-        programs x `prefill_chunk`). The rest were pads, computed all the
-        same. 0 for a wave engine."""
-        rows = self.chunk_programs * self.prefill_chunk
-        return self.prefilled_tokens / rows if rows else 0.0
-
-    @property
-    def merged_program_share(self) -> float:
-        """Lifetime share of the chunk-carrying programs that read the held
-        experts once (one expert call a layer): 1.0 for a patterned model,
-        0 for a classic or a quantised engine, which run the model twice."""
-        return (self.merged_programs / self.chunk_programs
-                if self.chunk_programs else 0.0)
-
-    @property
-    def decode_tiles_per_grid_step(self) -> float:
-        """Lifetime live cache tiles a grid step of the paged decode
-        kernel held (ops/flash_decode.py: a grid step is one sequence and
-        walks all its live tiles with several fetches in flight), from
-        the planned lengths: 1.0 = every decoding sequence held one
-        tile, the kernel had nothing to overlap; at most the block
-        table's width. 0 before the first decode call."""
-        return (self.decode_live_tiles / self.decode_live_steps
-                if self.decode_live_steps else 0.0)
-
-    @property
-    def chunk_programs_per_prompt(self) -> float:
-        """Lifetime chunk-carrying programs per prompt chunked in: 1 when
-        no prompt's suffix is longer than `prefill_chunk`."""
-        return (self.chunk_programs / self.chunked_prompts
-                if self.chunked_prompts else 0.0)
+    def __getattr__(self, name: str):
+        """A name the engine itself lacks: one of its counts."""
+        if name == "counts":        # not built yet
+            raise AttributeError(name)
+        return getattr(self.counts, name)
 
     @property
     def free_slots(self) -> list[int]:
@@ -1349,12 +1209,6 @@ class DecodeEngine:
         used = sum(min(s.pos, len(s.blocks) * self.block_size)
                    for s in self._slots.values())
         return 1.0 - used / (live_blocks * self.block_size)
-
-    @property
-    def prefix_hit_rate(self) -> float:
-        """Lifetime fraction of prompt tokens served from cached blocks."""
-        return (self.prefix_hit_tokens / self.prompt_tokens
-                if self.prompt_tokens else 0.0)
 
     @property
     def n_steps(self) -> int:
@@ -1464,7 +1318,7 @@ class DecodeEngine:
 
     def _retire(self, slot: int, reason: str) -> Retired:
         seq = self._slots.pop(slot)
-        self.retire_counts[reason] += 1
+        self.counts.retired(reason)
         self._live_dirty = True
         # publish the sequence's full blocks into the prefix cache before
         # releasing: refcount-0 registered blocks land on the LRU, so a
@@ -1632,24 +1486,19 @@ class DecodeEngine:
                     jnp.asarray([len(suffix)], jnp.int32),
                     jnp.int32(slot), rng)
             self.caches, self.tok, self.pos, self.live, first = out
-            self.state_resets += int(self.cfg.recurrent)
-            self.ssm_state_bytes_by["chunk"] += 2 * self._state_bytes_slot
-            self.kda_slot_steps_by["chunk"] += len(suffix) * self._n_kda
             # THE admit sync boundary: the first sampled token must reach the
             # host to stream it to the caller (a patterned model's routing
             # counts ride the same transfer)
             first, stats = jax.device_get(  # lint: allow(host-sync)
                 (first, self._take_expert_stats()))
-            self._count_experts(stats, ("chunk",))
             first_tok = int(first[0])
             self._slots[slot] = _Slot(seq_id=seq_id, tokens=toks + [first_tok],
                                       prompt_len=L, n_new=1,
                                       max_new=max_new_tokens, pos=L,
-                                      blocks=blocks, order=self.n_admitted)
-            self.n_admitted += 1
-            self.prompt_tokens += L
-            self.prefix_hit_tokens += prefix_len
-            self.prefilled_tokens += len(suffix)
+                                      blocks=blocks,
+                                      order=self.counts.n_admitted)
+            self.counts.admitted(L, prefix_len, wave_prefilled=len(suffix),
+                                 stats=stats)
             # publish the prompt's full blocks now — immutable as of this
             # prefill — so concurrent same-prefix requests hit immediately
             self._register_blocks(toks, L // bs, blocks)
@@ -1699,11 +1548,9 @@ class DecodeEngine:
         self._slots[slot] = _Slot(
             seq_id=seq_id, tokens=list(toks), prompt_len=L, n_new=0,
             max_new=max_new_tokens, pos=prefix_len, blocks=blocks,
-            order=self.n_admitted, suffix=suffix, suffix_done=0,
+            order=self.counts.n_admitted, suffix=suffix, suffix_done=0,
             prefix_len=prefix_len)
-        self.n_admitted += 1
-        self.prompt_tokens += L
-        self.prefix_hit_tokens += prefix_len
+        self.counts.admitted(L, prefix_len)
         return Admission(seq_id=seq_id, first_token=None,
                          prefix_len=prefix_len, prefilled=len(suffix))
 
@@ -1877,11 +1724,7 @@ class DecodeEngine:
                   else "spec" if spec is not None else "decode"))
         self._t += 1
         self._pools_rewritten = False
-        if ahead:
-            self.overlapped_programs += 1
-        else:
-            self.drain_reasons[reason] = \
-                self.drain_reasons.get(reason, 0) + 1
+        self.counts.planned(self._t, reason)
         # what the program runs with, fixed now: a later plan may move
         # the host's tables and mask before this one is enqueued. A mask
         # is rebuilt only where the planned live set moved outside the
@@ -1902,7 +1745,7 @@ class DecodeEngine:
                 seq.pos += 1
                 # the rows its attention call reads, this token's included
                 prog.live_tiles += -(-seq.pos // self.block_size)
-                if self._plans_kv_rows:
+                if self.counts.plans_kv_rows:
                     self._plan_kv_rows(prog, "decode", seq.pos,
                                        min(seq.pos, self.cfg.window))
                 self._plan_retirement(slot, seq, prog.retiring)
@@ -1919,7 +1762,7 @@ class DecodeEngine:
             chunk_done = not self._is_partial(seq_c)
             prog.chunk = (slot_c, seq_c.seq_id, take, seq_c.pos)
             prog.state_reset = self.cfg.recurrent and off == 0
-            if self._plans_kv_rows:
+            if self.counts.plans_kv_rows:
                 # the chunk's `take` queries see the `off` rows before
                 # them; of those a window layer's see the last window - 1
                 self._plan_kv_rows(prog, "chunk", off + take,
@@ -1946,22 +1789,6 @@ class DecodeEngine:
         rows = prog.kv_rows = prog.kv_rows or {}
         a, b = rows.get(what, (0, 0))
         rows[what] = (a + whole, b + windowed)
-
-    @property
-    def kv_rows_read_full(self) -> int:
-        return sum(self.kv_rows_read_full_by.values())
-
-    @property
-    def kv_rows_read_window(self) -> int:
-        return sum(self.kv_rows_read_window_by.values())
-
-    @property
-    def ssm_state_bytes(self) -> int:
-        return sum(self.ssm_state_bytes_by.values())
-
-    @property
-    def latent_rows_read(self) -> int:
-        return sum(self.latent_rows_read_by.values())
 
     @property
     def resident_bytes_by_kind(self) -> dict:
@@ -2018,54 +1845,6 @@ class DecodeEngine:
         self.caches = caches
         return stats
 
-    def _count_experts(self, stats: Optional[list],
-                       kinds: tuple = ()) -> tuple[int, int, int]:
-        """Fold one program's FETCHED routing counts (host arrays) into
-        the lifetime counters; (experts hit, absent assignments, second
-        tiles) of the program. A layer's leaves hold a row a
-        CALL of its kernels: one a program, its chunk's rows and its decode
-        rows together where it carried a chunk, or two of a fused step
-        that ran the model twice. `kinds` = what each of a layer's calls
-        carried, in the program's order: "chunk" (a call with a chunk's
-        rows in it) | "decode". A layer that carries its kernels' tile
-        count out (a `tiles` leaf) has its second tiles counted: the tiles
-        beyond one an expert hit."""
-        hit = absent = second = 0
-        for layer in stats or ():
-            tokens = layer["tokens"]                        # (calls, held)
-            self.expert_calls += tokens.shape[0]
-            self.expert_tokens += tokens.sum(axis=0)
-            self.held_assignments += int(tokens.sum())
-            hits = (tokens > 0).sum(axis=1)                 # (calls,)
-            hit += int(hits.sum())
-            absent += int(layer["absent"].sum())
-            self.held_gate_sum += float(layer["held_gate"].sum()) \
-                if "held_gate" in layer else 0.0
-            if "tiles" not in layer:
-                continue
-            for what, extra in zip(kinds, layer["tiles"] - hits):
-                self.expert_calls_by[what] += 1
-                self.expert_second_tiles_by[what] += int(extra)
-                self.expert_second_tile_calls_by[what] += int(extra > 0)
-                second += int(extra)
-        self.experts_hit += hit
-        self.absent_assignments += absent
-        return hit, absent, second
-
-    @property
-    def expert_second_tiles(self) -> int:
-        return sum(self.expert_second_tiles_by.values())
-
-    @property
-    def held_gate_share(self) -> float:
-        """Mean share of a real row's routing weights that fell on experts
-        held here: the part of an expert layer's routed output this chip
-        computes. 0 where the router renormalises nothing to compare with
-        (`route_sigmoid` carries no such count)."""
-        rows = (self.held_assignments + self.absent_assignments) \
-            / max(self.cfg.n_act_routed, 1)
-        return self.held_gate_sum / rows if rows else 0.0
-
     def _holds(self, slot: int, seq_id: int) -> bool:
         """Whether the occupant a program was planned for still holds its
         slot."""
@@ -2082,6 +1861,7 @@ class DecodeEngine:
         # THE step sync boundary: every slot's sampled token drains to the
         # host once per program (plus the per-slot accept lengths of a
         # speculative one — one transfer, not two)
+        stats = None
         with phase("engine.wait", acc, step=step, program=prog.t):
             if prog.spec is not None:
                 draft_h, dlen_h, acc_dev = prog.spec
@@ -2091,36 +1871,6 @@ class DecodeEngine:
                 sampled, stats = jax.device_get(  # lint: allow(host-sync)
                     (prog.tok, prog.expert_stats))
         with phase("engine.retire", acc, step=step) as retire:
-            calls_before = self.expert_calls
-            hit, absent, second = self._count_experts(
-                stats if prog.spec is None else None,
-                ("chunk",) * (prog.chunk is not None) + ("decode",))
-            calls = self.expert_calls - calls_before
-            self.state_resets += int(prog.state_reset)
-            state_bytes = 2 * self._state_bytes_slot
-            self.ssm_state_bytes_by["decode"] += state_bytes * prog.n_live
-            self.ssm_state_bytes_by["chunk"] += \
-                state_bytes * (prog.chunk is not None)
-            self.kda_slot_steps_by["decode"] += prog.n_live * self._n_kda
-            if prog.chunk is not None:
-                self.kda_slot_steps_by["chunk"] += \
-                    prog.chunk[2] * self._n_kda
-            live_steps = prog.n_live if prog.spec is None else 0
-            self.decode_live_tiles += prog.live_tiles
-            self.decode_live_steps += live_steps
-            kv_full = kv_window = 0
-            for what, (whole, windowed) in (prog.kv_rows or {}).items():
-                full, window = whole * self._n_full, windowed * self._n_window
-                self.kv_rows_read_full_by[what] += full
-                self.kv_rows_read_window_by[what] += window
-                self.latent_rows_read_by[what] += whole * self._n_latent
-                self.window_rows_saved += whole * self._n_window - window
-                kv_full += full
-                kv_window += window
-            self.chunk_attn_pairs_by["full"] += \
-                prog.chunk_pairs[0] * self._n_full
-            self.chunk_attn_pairs_by["window"] += \
-                prog.chunk_pairs[1] * self._n_window
             emitted: dict[int, list] = {}
             retired: dict[int, Retired] = dict(prog.preempted)
             drafted = accepted = overrun = prefill_tokens = 0
@@ -2130,14 +1880,6 @@ class DecodeEngine:
                 # first-writer-wins, so re-publishing earlier ones is a
                 # no-op)
                 slot_c, sid_c, prefill_tokens, rows = prog.chunk
-                self.prefilled_tokens += prefill_tokens
-                self.chunk_programs += 1
-                self.merged_programs += int(
-                    bool(self._expert_layers)
-                    and calls == len(self._expert_layers))
-                # only a prompt's last chunk makes its slot an occupant
-                self.chunked_prompts += int(
-                    prog.occupants.get(slot_c) == sid_c)
                 if self._holds(slot_c, sid_c):
                     seq_c = self._slots[slot_c]
                     full = min(rows, len(seq_c.blocks) * self.block_size) \
@@ -2174,16 +1916,16 @@ class DecodeEngine:
                     reason = "eos"
                 if reason is not None:
                     retired[sid] = self._retire(slot, reason)
-            n_emitted = sum(len(v) for v in emitted.values())
-            self.emitted_tokens += n_emitted
-            self.overrun_tokens += overrun
             if prog.spec is not None:
                 drafted = int(dlen_h.sum())
-                self.spec_drafted_tokens += drafted
-                self.spec_accepted_tokens += accepted
+            # the program's share of every count, booked once; what comes
+            # back is its flight record's fields (engine/counts.py)
+            record = self.counts.drained(
+                prog, stats, emitted=sum(len(v) for v in emitted.values()),
+                overrun=overrun, drafted=drafted, accepted=accepted,
+                retired=len(retired) - len(prog.preempted))
             # one record per drained program: `step` counts completed
-            # programs (this one's number + 1), its own n_live, chunk,
-            # `overlapped` and `drain_reason`; the four times are this
+            # programs (this one's number + 1); the four times are this
             # CALL's phases (prepare and dispatch: of the program the call
             # queued, this one's on a drained turn), and retire_ms runs
             # to this stamp, so they sum to step_ms less the few
@@ -2202,25 +1944,7 @@ class DecodeEngine:
                 compiled=traces != seen, step=prog.t + 1,
                 step_ms=round((t_rec - t_step0) * 1e3, 3),
                 **{f"{k}_ms": round(v, 3) for k, v in parts.items()},
-                n_live=prog.n_live, prefill_tokens=prefill_tokens,
-                emitted=n_emitted,
-                retired=len(retired) - len(prog.preempted),
-                blocks_in_use=self.block_pool.n_referenced,
-                preemptions=len(prog.preempted),
-                drafted=drafted, accepted=accepted,
-                overlapped=prog.overlapped,
-                drain_reason=prog.drain_reason, overrun=overrun,
-                decode_live_tiles=prog.live_tiles,
-                decode_live_steps=live_steps,
-                **({"experts_hit": hit, "absent_assignments": absent,
-                    "expert_calls": calls, "expert_second_tiles": second,
-                    "state_reset": int(prog.state_reset),
-                    "ssm_state_bytes": state_bytes * (
-                        prog.n_live + (prog.chunk is not None))}
-                   if self.cfg.layer_pattern else {}),
-                **({"kv_rows_read_full": kv_full,
-                    "kv_rows_read_window": kv_window}
-                   if self._plans_kv_rows else {}))
+                blocks_in_use=self.block_pool.n_referenced, **record)
         return StepResult(emitted=emitted, retired=retired,
                           prefill_tokens=prefill_tokens,
                           drafted=drafted, accepted=accepted)
@@ -2254,7 +1978,7 @@ class DecodeEngine:
             # everyone it ran for was cancelled meanwhile: nothing to
             # hand out (its writes precede, in device order, whatever
             # comes next)
-            self.overrun_tokens += len(cur.occupants)
+            self.counts.overran(len(cur.occupants))
             cur = None
         if cur is None and not self._slots:
             self._work_waits = False
@@ -2306,7 +2030,7 @@ class DecodeEngine:
             # program that retires the last slot; a program that ran on
             # because an `eos` showed only now served no one else
             if nxt is not None:
-                self.overrun_tokens += len(nxt.occupants)
+                self.counts.overran(len(nxt.occupants))
             nxt = why = None
         self._inflight, self._declined = nxt, why
         self._work_waits = bool(self._slots)

@@ -72,6 +72,7 @@ import time
 from typing import Optional
 
 from distributed_pytorch_tpu.config import knob
+from distributed_pytorch_tpu.engine import counts
 from distributed_pytorch_tpu.engine.decode import Retired
 from distributed_pytorch_tpu.obs import flight as obs_flight
 from distributed_pytorch_tpu.obs import trace as obs_trace
@@ -265,8 +266,7 @@ class Scheduler:
             "serve_slots_free", lambda: self.engine.n_free,
             "free decode slots")
         # paged-cache observability (engine/decode.py properties): how full
-        # the block pool runs, how much of it is partial-tail waste, and
-        # how often prompts resolve to cached prefix blocks
+        # the block pool runs and how much of it is partial-tail waste
         self.metrics.register_gauge(
             "serve_block_utilization", lambda: self.engine.block_utilization,
             "referenced fraction of the KV block pool")
@@ -274,9 +274,6 @@ class Scheduler:
             "serve_block_fragmentation",
             lambda: self.engine.block_fragmentation,
             "unwritten fraction of referenced KV block rows")
-        self.metrics.register_gauge(
-            "serve_prefix_hit_rate", lambda: self.engine.prefix_hit_rate,
-            "lifetime fraction of prompt tokens served from cached blocks")
         # retrace guards (obs/retrace.py): total compiled traces and the
         # over-budget excess per program family — excess > 0 means the
         # one-trace serving invariant broke (the silent recompile cliff)
@@ -288,126 +285,24 @@ class Scheduler:
             "serve_engine_retrace_excess",
             lambda: sum(g.excess for g in self.engine.trace_guards.values()),
             "engine traces past budget — should be 0")
-        # speculative decoding (engine/decode.py): what fraction of
-        # drafted tokens the verify step accepted, and how many tokens
-        # each fused step delivered on average (1.0 with spec off)
-        self.metrics.register_gauge(
-            "serve_spec_accepted_token_rate",
-            lambda: getattr(self.engine, "accepted_token_rate", 0.0),
-            "accepted/drafted fraction of speculative draft tokens")
-        self.metrics.register_gauge(
-            "serve_engine_tokens_per_step",
-            lambda: getattr(self.engine, "tokens_per_step", 1.0),
-            "mean tokens emitted per fused step (spec decode > 1)")
-        # one program in flight (DecodeEngine.step): the share of step
-        # programs queued behind a running one. Near 1 the device never
-        # waits for this loop's emit/yield/admit turn; the flight record
-        # (/debug/timeline) says per program why one was not
-        self.metrics.register_gauge(
-            "serve_engine_overlap_share",
-            lambda: getattr(self.engine, "overlap_share", 0.0),
-            "fraction of step programs dispatched behind a running one")
+        # what the engine counts: a gauge for every entry of
+        # engine/counts.py's table that names one, read off the engine
+        eng = self.engine
+        for gauge in counts.gauges(eng):
+            self.metrics.register_gauge(*gauge)
         # the turns the engine's flight recorder judged stalled and the
         # collector's pauses (obs/flight.py; process-wide, as what they
         # measure is); each stall is in /debug/timeline's `stalls`
         for name, family in obs_flight.metric_families(
                 "engine", "serve_engine", "serve_host").items():
             self.metrics.register_family(name, *family)
-        # chunked prefill (DecodeEngine._next_chunk): a chunk-carrying
-        # program computes `prefill_chunk` rows whatever they hold. The
-        # share of them that held a prompt id, and how many such programs
-        # a prompt took (1 while prompts fit the chunk)
-        self.metrics.register_gauge(
-            "serve_chunk_fill_share",
-            lambda: getattr(self.engine, "chunk_fill_share", 0.0),
-            "prompt ids prefilled / chunk rows the fused programs computed")
-        self.metrics.register_gauge(
-            "serve_chunk_programs_per_prompt",
-            lambda: getattr(self.engine, "chunk_programs_per_prompt", 0.0),
-            "chunk-carrying step programs per prompt chunked in")
-        # which fused program the engine runs (make_fused_step_fn): 1.0
-        # where a chunk-carrying program walks a patterned model's layers
-        # once and its expert layers make one call, 0 where it runs the
-        # model twice (a classic model, a quantised engine)
-        self.metrics.register_gauge(
-            "serve_merged_program_share",
-            lambda: getattr(self.engine, "merged_program_share", 0.0),
-            "chunk-carrying step programs that read the held experts once")
-        # the paged decode kernel's grid step is a sequence and walks all
-        # its live cache tiles (ops/flash_decode.py): how many it held, by
-        # the planned lengths. 1.0 = one tile a sequence, nothing for the
-        # kernel's fetches in flight to overlap
-        self.metrics.register_gauge(
-            "serve_decode_tiles_per_grid_step",
-            lambda: getattr(self.engine, "decode_tiles_per_grid_step", 0.0),
-            "live cache tiles a grid step of the paged decode kernel held")
-        # a patterned model's layers (engine/decode.py): how many of the
-        # held experts a call of an expert layer hits (the weight bytes
-        # it must read), how evenly the held experts are loaded, what
-        # share of the routing falls on experts another chip holds, how
-        # often a slot's recurrent state began anew, and how many
-        # admissions were refused a prefix match because resident blocks
-        # are not a recurrent model's state. 0 for every other model.
-        eng = self.engine
-        self.metrics.register_gauge(
-            "serve_experts_hit_per_call",
-            lambda: getattr(eng, "experts_hit", 0)
-            / max(getattr(eng, "expert_calls", 0), 1),
-            "held experts that received a token, per expert-layer call")
-        self.metrics.register_gauge(
-            "serve_expert_tokens_max_over_mean",
-            lambda: (float(eng.expert_tokens.max())
-                     / max(float(eng.expert_tokens.mean()), 1.0))
-            if getattr(eng, "expert_calls", 0) else 0.0,
-            "most-loaded held expert's tokens over the mean of the held")
-        self.metrics.register_gauge(
-            "serve_expert_absent_assignments_share",
-            lambda: getattr(eng, "absent_assignments", 0)
-            / max(getattr(eng, "absent_assignments", 0)
-                  + getattr(eng, "held_assignments", 0), 1),
-            "share of routed assignments that fall on experts not held")
-        self.metrics.register_gauge(
-            "serve_expert_second_tiles_per_call",
-            lambda: getattr(eng, "expert_second_tiles", 0)
-            / max(getattr(eng, "expert_calls", 0), 1),
-            "expert-kernel tiles beyond a hit expert's first (each reads "
-            "the expert's matrices again), per expert-layer call")
-        self.metrics.register_gauge(
-            "serve_expert_held_gate_share",
-            lambda: getattr(eng, "held_gate_share", 0.0),
-            "mean share of a token's routing weights that fell on held "
-            "experts (routers that do not renormalise over the held)")
-        self.metrics.register_gauge(
-            "serve_state_resets_total",
-            lambda: getattr(eng, "state_resets", 0),
-            "recurrent state started anew (one per admission's first chunk)")
+        # state of the engine, no count of its programs: admissions refused
+        # a prefix match (a recurrent model's state is no block), bytes held
         self.metrics.register_gauge(
             "serve_prefix_reuse_declined_total",
             lambda: getattr(eng, "prefix_reuse_declined", 0),
             "admissions refused a prefix match: recurrent state has no "
             "snapshot")
-        # a model with window layers (engine/decode.py): key rows the
-        # attention calls read, in the layers that keep a whole history
-        # and in the window layers, the rows the window spared, and the
-        # bytes held by kind of state. The rows 0 for every other model.
-        for name, attr, text in (
-                ("serve_kv_rows_read_full_total", "kv_rows_read_full",
-                 "key rows read by the attention calls of layers that "
-                 "keep the whole history"),
-                ("serve_kv_rows_read_window_total", "kv_rows_read_window",
-                 "key rows read by the window layers' attention calls"),
-                ("serve_window_rows_saved_total", "window_rows_saved",
-                 "key rows a whole history would have cost the window "
-                 "layers, less the rows they read"),
-                ("serve_ssm_state_bytes_total", "ssm_state_bytes",
-                 "float32 state the state-space layers' calls read and "
-                 "wrote back (slots x layers x a slot's state, in and "
-                 "out)"),
-                ("serve_latent_rows_read_total", "latent_rows_read",
-                 "live latent rows the latent-attention layers' decode "
-                 "and chunk calls had to read")):
-            self.metrics.register_gauge(
-                name, lambda attr=attr: getattr(eng, attr, 0), text)
         for kind in ("weights", "pools", "window", "slot_state"):
             self.metrics.register_gauge(
                 f"serve_resident_bytes_{kind}",

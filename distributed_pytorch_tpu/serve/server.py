@@ -34,9 +34,8 @@ Observability plane (ISSUE 9):
 * `GET /debug/timeline` — the engine's step-level flight recorder: the
   last N fused steps' `{step_ms, n_live, prefill_tokens, emitted,
   blocks_in_use, preemptions}` records (`?n=` bounds the count), beside
-  the engine's lifetime `overlap_share`, `chunk_fill_share`,
-  `decode_tiles_per_grid_step`, `merged_program_share` and
-  `chunk_programs_per_prompt`.
+  the engine's lifetime readings that engine/counts.py's table marks for
+  the timeline.
 * `POST /admin/profile?duration_ms=N` — on-demand `jax.profiler` capture
   on a live replica (obs/profile.py, output under `runs/.../profile`);
   one capture at a time — a concurrent request gets 409.
@@ -59,6 +58,7 @@ import time
 import urllib.parse
 from typing import Optional
 
+from distributed_pytorch_tpu.engine import counts
 from distributed_pytorch_tpu.obs import flight as obs_flight
 from distributed_pytorch_tpu.obs import profile as obs_profile
 from distributed_pytorch_tpu.obs import trace as obs_trace
@@ -308,28 +308,9 @@ class ServeApp:
             # records never evict, newest last, and what they are shares of
             "stalls": obs_flight.stall_log(),
             "stall_totals": obs_flight.stall_totals(),
-            # the engine's lifetime shares, beside the per-program
-            # `overlapped` / `prefill_tokens` they are made of
-            "overlap_share": getattr(eng, "overlap_share", 0.0),
-            "chunk_fill_share": getattr(eng, "chunk_fill_share", 0.0),
-            "decode_tiles_per_grid_step":
-                getattr(eng, "decode_tiles_per_grid_step", 0.0),
-            "merged_program_share":
-                getattr(eng, "merged_program_share", 0.0),
-            "chunk_programs_per_prompt":
-                getattr(eng, "chunk_programs_per_prompt", 0.0),
-            # a model with window layers: key rows its attention calls
-            # read so far by kind of layer, rows the window spared, the
-            # (query, key) pairs its chunk calls' masks let through, and
-            # what the engine holds by kind of state (0 / absent: none)
-            "kv_rows_read_full": getattr(eng, "kv_rows_read_full", 0),
-            "kv_rows_read_window": getattr(eng, "kv_rows_read_window", 0),
-            "window_rows_saved": getattr(eng, "window_rows_saved", 0),
-            "chunk_attn_pairs_by": getattr(eng, "chunk_attn_pairs_by", {}),
-            # state-space layers: float32 state their calls moved, by call
-            "ssm_state_bytes_by": getattr(eng, "ssm_state_bytes_by", {}),
-            # latent layers: live rows their calls had to read, by call
-            "latent_rows_read_by": getattr(eng, "latent_rows_read_by", {}),
+            # the engine's lifetime readings (engine/counts.py's table)
+            # and what it holds by kind of state
+            **counts.timeline(eng),
             "resident_bytes_by_kind":
                 getattr(eng, "resident_bytes_by_kind", {})})
 

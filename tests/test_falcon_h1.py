@@ -132,7 +132,7 @@ def test_resident_bytes_split_a_slot_and_state_does_not_know_max_len(mv):
             x.size * x.dtype.itemsize
             for x in jax.tree_util.tree_leaves(eng.caches))
     assert b["pools"] > 2 * a["pools"]
-    assert short._state_bytes_slot == 3 * 4 * 16 * 32 * 4
+    assert short.state_bytes_slot == 3 * 4 * 16 * 32 * 4
 
 
 # (2) the whole forward pass, what each term is worth -----------------------
@@ -275,7 +275,7 @@ def test_engine_matches_the_reference_through_reused_slots(mv):
     # branches' rows and the state-space branches' bytes, 3 layers each
     assert eng.state_resets == 5 and eng.prefix_reuse_declined == 0
     assert eng.features_declined == []
-    assert eng._plans_kv_rows and eng._n_full == 3 and eng._n_window == 0
+    assert eng.plans_kv_rows and eng.n_full == 3 and eng.n_window == 0
     state = 3 * 4 * 16 * 32 * 4
     chunks = sum(-(-len(p) // 16) for p in prompts)
     assert eng.ssm_state_bytes_by["chunk"] == 2 * state * chunks
@@ -457,8 +457,8 @@ def test_the_accepted_shapes_are_asked_nothing_new():
             v, jnp.zeros((1, 8), jnp.int32)).as_text(debug_info=True)
         assert "mixer_sum" not in text
         eng = _engine(model, v, n_slots=2)
-        assert eng._plans_kv_rows == ("W" in cfg.layer_pattern)
-        assert eng._n_full == cfg.layer_pattern.count("*")
+        assert eng.plans_kv_rows == ("W" in cfg.layer_pattern)
+        assert eng.n_full == cfg.layer_pattern.count("*")
         by = eng.resident_bytes_by_kind
         assert (by["pools"] > 0) == ("*" in cfg.layer_pattern)
         assert (by["window"] > 0) == ("W" in cfg.layer_pattern)

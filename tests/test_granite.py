@@ -367,7 +367,7 @@ def test_second_tiles_are_counted_by_call_kind(mv):
     stats = [{"tokens": tokens, "absent": np.asarray([5, 4]),
               "held_gate": np.asarray([10.0, 5.0], np.float32),
               "tiles": np.asarray([2 + 1 + 1, 1 + 3])}]
-    hit, absent, second = eng._count_experts(stats, ("chunk", "decode"))
+    hit, absent, second = eng.count_experts(stats, ("chunk", "decode"))
     assert (hit, absent, second) == (5, 9, 1 + 2)
     assert eng.expert_second_tiles_by == {"chunk": 1, "decode": 2}
     assert eng.expert_second_tile_calls_by == {"chunk": 1, "decode": 1}
@@ -376,7 +376,7 @@ def test_second_tiles_are_counted_by_call_kind(mv):
     assert eng.held_gate_share == pytest.approx(15.0 / (79 / 3))
     # a layer without the leaf (the sigmoid-routed programs) counts none
     del stats[0]["tiles"]
-    assert eng._count_experts(stats, ("chunk", "decode")) == (5, 9, 0)
+    assert eng.count_experts(stats, ("chunk", "decode")) == (5, 9, 0)
     assert eng.expert_second_tiles == 3
 
 

@@ -831,3 +831,54 @@ def test_no_import_points_up_the_layers(pkg):
     stale = {f for f in UPWARD_TOOLS if f.startswith(pkg + "/")} - set(up)
     assert not stale, f"no upward import left, take out of UPWARD_TOOLS: " \
                       f"{stale}"
+
+
+# ---------------------------------------------------------------------------
+# what the engine counts is named and booked in engine/counts.py alone
+# ---------------------------------------------------------------------------
+
+def _count_names():
+    """(the table's attribute names, every total and derived reading)."""
+    from distributed_pytorch_tpu.engine import counts
+    held = counts.EngineCounts(config.LLMConfig(), [], 1, 0)
+    mine = {n for n in vars(held) if not n.startswith("_")} | {
+        n for n, v in vars(counts.EngineCounts).items()
+        if isinstance(v, property)}
+    table = {r.name for r in counts.READINGS}
+    assert table <= mine and len(table) == len(counts.READINGS)
+    return table, mine
+
+
+@pytest.mark.parametrize("reader", ["serve/scheduler.py", "serve/server.py",
+                                    "engine/decode.py"])
+def test_a_counter_is_named_in_the_table_and_booked_in_counts(reader):
+    """The readers walk the table: not one of its names stands in their
+    text, comments and docstrings included (a gauge's name may hold one:
+    `serve_engine_overlap_share`). The engine reads counts and calls the
+    booking methods; it assigns to none, on itself or on `counts`, and
+    defines none (either would shadow the forwarded name)."""
+    import re
+    table, mine = _count_names()
+    path = REPO / "distributed_pytorch_tpu" / reader
+    text = path.read_text()
+    if reader.startswith("serve/"):
+        named = {n for n in table if re.search(rf"\b{n}\b", text)}
+        assert not named, f"{reader} names a counter of the table: {named}"
+        assert "counts." in text
+        return
+    stores, defined = [], []
+    for node in ast.walk(ast.parse(text)):
+        if isinstance(node, (ast.FunctionDef, ast.ClassDef)) \
+                and node.name in mine:
+            defined.append(node.name)
+        targets = node.targets if isinstance(node, ast.Assign) else \
+            [node.target] if isinstance(node, (ast.AugAssign,
+                                               ast.AnnAssign)) else []
+        for target in targets:
+            for sub in ast.walk(target):
+                if isinstance(sub, ast.Attribute) and sub.attr in mine:
+                    stores.append(f"line {node.lineno}: {sub.attr}")
+    assert not stores and not defined, (stores, defined)
+    assert text.count("EngineCounts(") == 1 \
+        and text.count("self.counts.drained(") == 1 \
+        and text.count(".record_turn(") == 1 and "**record)" in text

@@ -39,7 +39,14 @@ the engine's programs need them:
   (PERF.md section 6, PR 59, has both measured alone). `latent_flash_prefill`:
   grid (heads, query tiles, key tiles), a key tile `_CHUNK_GROUP` pool
   blocks under one masked softmax update, a key tile past the query tile's
-  last row neither fetched nor computed.
+  last row neither fetched nor computed. A grid step makes the FRONT of its
+  key tile (up-projection, one contraction over `[k_nope | k_r | 0]` for
+  the scores, mask, the rows' maxima, all left in VMEM) and the BACK of the
+  tile before it (`exp`, rescale, `p @ v`): the two halves of one tile are
+  a dependence chain, the halves of two tiles are not, and in one basic
+  block the MXU works the one while the vector unit works the other
+  (PERF.md section 6, PR 65: a grid step 5.21 -> 3.46 us by the device's
+  clock; the chain was 7,500 bundles, the shared step is 4,950).
 
 Each kernel has its decline function and its plain XLA twin, the same
 lines in `jax.numpy` over a `paged_gather`ed view: what the CPU tests and
@@ -404,53 +411,79 @@ def _chunk_tiles(T: int, n_max: int, bs: int) -> tuple[int, int]:
     return _pick_block(T, max(rows, 8), 8) or T, group
 
 
-def _prefill_kernel(meta_ref, bt_ref, qn_ref, qr_ref, w_ref, *refs,
-                    scale: float, bs: int, lc: int, dn: int, group: int):
-    """Grid (heads, query tiles, key tiles). A step takes head n's query
-    tile (tq, dn) and (tq, L - lc), the rotated part zero-extended to the
-    row's last lanes, against `group` pool blocks stacked into one key
-    tile: the tile's latents through the head's W_kvb,n (lc, dn + dv) give
-    its keys and values, the scores are q_nope k_nope^T + q_rope k_r^T
-    under the causal mask of the rows' global positions, ONE softmax update
-    over the tile. A key tile whose first key lies past the query tile's
-    last row is neither fetched (views past the last needed block hold it
-    again) nor computed; the grid's third axis ends with the chunk's last
-    row."""
+def _prefill_kernel(meta_ref, bt_ref, q_ref, w_ref, *refs, scale: float,
+                    bs: int, lc: int, dn: int, group: int):
+    """Grid (heads, query tiles, key tiles). A key tile is worked in two
+    halves a step apart, so that the MXU's half of one tile and the vector
+    unit's half of the tile before it are ONE basic block and run side by
+    side:
+
+    * the front of key tile j: `group` pool blocks stacked into one tile,
+      its latents through the head's W_kvb,n (lc, dn + dv) for its keys
+      and values, ONE contraction of the query tile `[q_nope | q_rope | 0]`
+      with `[k_nope | k_r | 0]` (the row's last lanes as cached) for the
+      scores under the causal mask of the rows' global positions. Scores,
+      values and the rows' maxima are left in VMEM (`s_ref`, `v_ref`,
+      `top_ref`);
+    * the back of key tile j - 1: ONE softmax update from what its front
+      left: `exp` over the tile, the rescale, `p @ v`.
+
+    Step 0 is a front alone, the query tile's last live step ends with the
+    back of its own tile and writes the output. A key tile whose first key
+    lies past the query tile's last row is neither fetched (views past the
+    last needed block hold it again) nor computed; the grid's third axis
+    ends with the chunk's last row."""
     c_refs = refs[:group]
-    o_ref, acc_ref, m_ref, l_ref = refs[group:]
+    o_ref, acc_ref, m_ref, l_ref, s_ref, v_ref, top_ref = refs[group:]
     i, j = pl.program_id(1), pl.program_id(2)
-    tq, keys = qn_ref.shape[1], group * bs
+    tq, keys = q_ref.shape[1], group * bs
     first = meta_ref[0] + i * tq
+    # key tiles this query tile sees: those whose first key its last row sees
+    n_live = jnp.minimum(jax.lax.div(first + tq - 1, keys) + 1,
+                         pl.num_programs(2))
 
-    pl.when(j == 0)(functools.partial(_softmax_init, acc_ref, m_ref, l_ref))
-
-    @pl.when(j * keys < first + tq)
-    def _():
+    def front():
         rows = _stack_tiles([r[0] for r in c_refs])          # (keys, L)
         kv = jax.lax.dot_general(
             rows[:, :lc], w_ref[0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32).astype(rows.dtype)
         s = jax.lax.dot_general(
-            qn_ref[0], kv[:, :dn], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        s = (s + jax.lax.dot_general(
-            qr_ref[0], rows[:, lc:], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)) * scale     # (tq, keys)
+            q_ref[0], jnp.concatenate([kv[:, :dn], rows[:, lc:]], axis=1),
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale      # (tq, keys)
         kpos = j * keys + jax.lax.broadcasted_iota(jnp.int32, (1, keys), 1)
         qpos = first + jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
         s = jnp.where(kpos <= qpos, s, _NEG_INF)
+        s_ref[:] = s
+        v_ref[:] = kv[:, dn:]
+        top_ref[:] = jnp.max(s, axis=-1, keepdims=True)
+
+    def back():
         m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        m_new = jnp.maximum(m_prev, top_ref[:])
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
+        p = jnp.exp(s_ref[:] - m_new)
         m_ref[:] = m_new
         l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(kv.dtype), kv[:, dn:], (((1,), (0,)), ((), ())),
+            p.astype(v_ref.dtype), v_ref[:], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(j == 0)
     def _():
+        _softmax_init(acc_ref, m_ref, l_ref)
+        front()
+
+    @pl.when((j > 0) & (j < n_live))
+    def _():
+        # the back reads `s_ref` before the front writes it: said in this
+        # order, scheduled side by side
+        back()
+        front()
+
+    @pl.when(j == n_live - 1)
+    def _():
+        back()
         o_ref[:] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
                     ).astype(o_ref.dtype)
 
@@ -472,9 +505,10 @@ def latent_flash_prefill(q_nope, q_rope, pool, w_kvb, block_tables, off, *,
     tq, group = _chunk_tiles(T, n_max, bs)
     meta = jnp.reshape(jnp.asarray(off, jnp.int32), (1,))
     bt = jnp.asarray(block_tables, jnp.int32).reshape(n_max)
-    qn = q_nope[0].transpose(1, 0, 2)                       # (nh, T, dn)
-    qr = q_rope[0].transpose(1, 0, 2)
-    qr = jnp.pad(qr, ((0, 0), (0, 0), (0, L - lc - qr.shape[2])))
+    # a head's query rows against a key's `[k_nope | k_r | 0]`: the rotated
+    # part zero-extended to the row's last lanes, once a call
+    q = jnp.concatenate([q_nope[0], q_rope[0]], axis=-1).transpose(1, 0, 2)
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, dn + L - lc - q.shape[2])))
     w = w_kvb.transpose(1, 0, 2)                            # (nh, lc, dn+dv)
 
     def q_idx(n, i, j, meta_ref, bt_ref):
@@ -498,13 +532,15 @@ def latent_flash_prefill(q_nope, q_rope, pool, w_kvb, block_tables, off, *,
         grid=(nh, T // tq, jnp.minimum(
             jax.lax.div(meta[0] + T - 1, group * bs) + 1,
             -(-n_max // group))),
-        in_specs=[pl.BlockSpec((1, tq, dn), q_idx),
-                  pl.BlockSpec((1, tq, L - lc), q_idx),
+        in_specs=[pl.BlockSpec((1, tq, dn + L - lc), q_idx),
                   pl.BlockSpec((1, lc, dn + dv), w_idx)]
         + [pl.BlockSpec((1, bs, L), c_idx(t)) for t in range(group)],
         out_specs=pl.BlockSpec((tq, dv), o_idx),
         scratch_shapes=[pltpu.VMEM((tq, dv), jnp.float32),
                         pltpu.VMEM((tq, 1), jnp.float32),
+                        pltpu.VMEM((tq, 1), jnp.float32),
+                        pltpu.VMEM((tq, group * bs), jnp.float32),
+                        pltpu.VMEM((group * bs, dv), pool.dtype),
                         pltpu.VMEM((tq, 1), jnp.float32)],
     )
     out = pl.pallas_call(
@@ -516,8 +552,26 @@ def latent_flash_prefill(q_nope, q_rope, pool, w_kvb, block_tables, off, *,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         name="latent_flash_prefill",
         interpret=interpret,
-    )(meta, bt, qn, qr, w, *(group * [pool]))
+    )(meta, bt, q, w, *(group * [pool]))
     return out.reshape(1, T, nh, dv)
+
+
+def _prefill_vmem_bytes(q_nope, pool, w_kvb, block_tables) -> int:
+    """VMEM one grid step of `latent_flash_prefill` holds: the
+    double-buffered query, weight, output and pool blocks, the stacked key
+    tile and what its front makes of it (`[k_nope | v]` in float32 and the
+    pool's dtype, the scores' key operand), the float32 softmax state with
+    the rows' maxima, the values and the score tile a front leaves for the
+    next step's back, and a step's own score tile, `p` and its cast."""
+    _, T, _, dn = q_nope.shape
+    bs, L = pool.shape[1:]
+    lc, dv = w_kvb.shape[0], w_kvb.shape[2] - dn
+    tq, group = _chunk_tiles(T, block_tables.shape[1], bs)
+    keys, item = group * bs, jnp.dtype(q_nope.dtype).itemsize
+    return (2 * tq * (dn + L - lc + dv) * item + 2 * lc * (dn + dv) * item
+            + 3 * keys * L * item + keys * (dn + dv) * (4 + item)
+            + keys * (dn + L - lc + dv) * item
+            + tq * (dv + 3 * 128) * 4 + 4 * tq * keys * 4)
 
 
 def latent_flash_prefill_decline(q_nope, q_rope, pool, w_kvb, block_tables):
@@ -538,12 +592,8 @@ def latent_flash_prefill_decline(q_nope, q_rope, pool, w_kvb, block_tables):
     if any(d % step for d in (lc, dn, dv)) or lc + dr > L:
         return (f"a latent of {lc}, heads of {dn} + {dr} / {dv} against "
                 f"rows of {L} lanes: not whole tiles of {step}")
-    tq, group = _chunk_tiles(T, block_tables.shape[1], bs)
-    keys, item = group * bs, jnp.dtype(q_nope.dtype).itemsize
     return _budget_decline(
-        2 * tq * (dn + L - lc + dv) * item + 2 * lc * (dn + dv) * item
-        + 3 * keys * L * item + keys * (dn + dv) * (4 + item)
-        + tq * (dv + 2 * 128) * 4 + 3 * tq * keys * 4)
+        _prefill_vmem_bytes(q_nope, pool, w_kvb, block_tables))
 
 
 def latent_flash_prefill_usable(q_nope, q_rope, pool, w_kvb,

@@ -264,6 +264,9 @@ CASES = {
     "latent_decode_64x32x640": (lambda: _latent(0), ["latent_flash_decode"]),
     "latent_prefill_1024x32": (lambda: _latent(1024),
                                ["latent_flash_prefill"]),
+    # Ling-3.0-flash's chunk (PR 62): 256 rows under a table 42 blocks wide
+    "latent_prefill_256x32": (lambda: _latent(256, width=42),
+                              ["latent_flash_prefill"]),
     # Ling-3.0-flash's published KDA state (PR 62): 32 heads of a 128 x 128
     # float32 state a slot, 192 slots a call, 8 slots (16 MB) a phase, three
     # phases in VMEM (PR 63: 49.5 MiB by the gate's count, under the 64 MiB)
@@ -322,6 +325,13 @@ def test_kernel_compiles_for_v5e(case, v5e):
         q, pool, bt, _ = (jax.ShapeDtypeStruct(s, d) for s, d in shapes)
         assert la._decode_vmem_bytes(q, pool, bt, 512) \
             < fd.VMEM_LIMIT_BYTES
+    if case.startswith("latent_prefill"):
+        # and what the chunk gate sums for the two-halves body (PR 65: a
+        # front's score tile, values and row maxima wait in VMEM for the
+        # next step's back) is inside the limit Mosaic took the body at
+        qn, _, pool, w, bt, _ = (jax.ShapeDtypeStruct(s, d)
+                                 for s, d in shapes)
+        assert la._prefill_vmem_bytes(qn, pool, w, bt) < fd.VMEM_LIMIT_BYTES
 
 
 # ---------------------------------------------------------------------------

@@ -315,17 +315,71 @@ def test_the_gates_vmem_sum_is_the_walks(monkeypatch):
     assert "MiB of VMEM" in la.latent_flash_decode_decline(q, pool, bt, 512)
 
 
-@pytest.mark.parametrize("off,T", [(0, 16), (8, 16), (40, 16), (24, 8),
-                                   (0, 64)])
-def test_chunk_kernel_against_its_twin(off, T):
-    """A chunk at offset 0, at nonzero offsets (the key tiles before it
-    whole, the diagonal one masked), a chunk that is one key tile's part,
-    and one that fills the table."""
+# (offset, chunk rows, table width, live blocks). 8-row blocks, four to a key
+# tile where the table is that wide
+_CHUNKS = [
+    # a chunk at offset 0, at nonzero offsets (the key tiles before it
+    # whole, the diagonal one masked), a chunk that is one key tile's part,
+    # and one that fills the table
+    (0, 16, 10, 9), (8, 16, 10, 9), (40, 16, 10, 9), (24, 8, 10, 9),
+    (0, 64, 10, 9),
+    # Ling's shape, a chunk of two blocks under key tiles of four, at every
+    # place in a tile: the tile's later blocks wholly past the chunk's last
+    # row (offset 32, and 0 above), the chunk ending with its tile (16, 48)
+    (16, 16, 10, 9), (32, 16, 10, 9), (48, 16, 10, 9),
+    # one key tile a call (a front and its back in ONE grid step), two, three
+    (8, 8, 10, 9), (24, 16, 10, 9), (56, 16, 10, 9),
+    # a table narrower than `_CHUNK_GROUP` blocks: a key tile of three, of two
+    (0, 16, 3, 3), (8, 16, 3, 3), (16, 8, 3, 3), (0, 16, 2, 2), (8, 8, 2, 2),
+]
+
+
+def _chunk_call_operands(off, T, width, live):
     pool = _pool(jax.random.PRNGKey(6))
-    bt = _tables(4, 9, 10)[2:3]
+    bt = _tables(4, live, width)[2:3]
     q_nope, q_rope, _, w = _operands(jax.random.PRNGKey(7), 1, T, 1)
     assert la.latent_flash_prefill_decline(q_nope, q_rope, pool, w,
                                            bt) is None
+    return q_nope, q_rope, pool, w, bt
+
+
+@pytest.mark.parametrize("off,T,width,live", _CHUNKS)
+def test_chunk_kernel_against_its_twin(off, T, width, live):
+    q_nope, q_rope, pool, w, bt = _chunk_call_operands(off, T, width, live)
+    got = la.latent_flash_prefill(q_nope, q_rope, pool, w, bt,
+                                  jnp.int32(off), scale=0.2, interpret=True)
+    want = la.latent_chunk_xla(q_nope, q_rope, pool, w, bt, off, scale=0.2)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+@pytest.mark.parametrize("off,T,width,live", [
+    (0, 16, 10, 9), (32, 16, 10, 9), (8, 8, 10, 9), (24, 16, 10, 9),
+    (0, 16, 3, 3)])
+def test_chunk_kernel_never_reads_past_the_chunk(off, T, width, live):
+    """Every pool block past the chunk's last row holds NaN: the output is
+    finite and what it was. A key tile's blocks past the last needed one
+    are views of that one again, a key tile past it is not computed."""
+    q_nope, q_rope, pool, w, bt = _chunk_call_operands(off, T, width, live)
+    run = functools.partial(la.latent_flash_prefill, q_nope, q_rope,
+                            w_kvb=w, block_tables=bt, off=jnp.int32(off),
+                            scale=0.2, interpret=True)
+    want = run(pool=pool)
+    used = np.asarray(bt)[0, :-(-(off + T) // pool.shape[1])]
+    past = np.setdiff1d(np.arange(pool.shape[0]), used)
+    got = run(pool=pool.at[past].set(jnp.nan))
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("off", [0, 8, 24, 40])
+def test_chunk_kernel_with_several_query_tiles(off, monkeypatch):
+    """A score tile cut to 16 rows: a chunk of 32 is two query tiles over
+    ONE key axis, whose end is the last tile's. The first one's last live
+    key tile comes earlier: it ends with its own back and writes its rows
+    there, the steps after it do nothing."""
+    monkeypatch.setattr(la, "_CHUNK_SCORE_BYTES", 16 * 32 * 4)
+    assert la._chunk_tiles(32, 10, 8) == (16, 4)
+    q_nope, q_rope, pool, w, bt = _chunk_call_operands(off, 32, 10, 9)
     got = la.latent_flash_prefill(q_nope, q_rope, pool, w, bt,
                                   jnp.int32(off), scale=0.2, interpret=True)
     want = la.latent_chunk_xla(q_nope, q_rope, pool, w, bt, off, scale=0.2)
@@ -397,6 +451,33 @@ def _chunk_call(**kw):
 def test_chunk_gate(change, told):
     why = _chunk_call(**change)
     assert (why is None) if told is None else (told in why), why
+
+
+@pytest.mark.parametrize("T,width", [(1024, 136), (256, 42)],
+                         ids=["joyai", "ling"])
+def test_the_chunk_gates_vmem_sum_is_the_bodys(T, width):
+    """At the cell's call (a 1,024-row chunk of 32 heads, 128 x 640 bf16
+    blocks, a table 136 wide) and at Ling's (256 rows, 42 wide) the gate
+    sums what the two-halves body holds: the blocks the pipeline double
+    buffers, the scratch a front leaves for the next step's back (a float32
+    score tile, the values, the rows' maxima beside the softmax state) and
+    a step's own temporaries."""
+    from distributed_pytorch_tpu.compat import VMEM_LIMIT_BYTES
+    bf = jnp.bfloat16
+    q = jax.ShapeDtypeStruct((1, T, 32, 128), bf)
+    pool = jax.ShapeDtypeStruct((8200, 128, 640), bf)
+    w = jax.ShapeDtypeStruct((512, 32, 256), bf)
+    bt = jax.ShapeDtypeStruct((1, width), jnp.int32)
+    assert la._chunk_tiles(T, width, 128) == (T, 4)
+    blocks = 2 * (T * 256 + 512 * 256 + T * 128 + 4 * 128 * 640) * 2
+    scratch = (T * 128 + 3 * T * 128 + T * 512) * 4 + 512 * 128 * 2
+    # the stacked tile, [k_nope | v] in float32 and bf16, the scores' key
+    # operand; a score tile, p and p's cast in flight
+    temps = (512 * 640 * 2 + 512 * 256 * (4 + 2) + 512 * 256 * 2
+             + 3 * T * 512 * 4)
+    need = la._prefill_vmem_bytes(q, pool, w, bt)
+    assert need == blocks + scratch + temps
+    assert need < VMEM_LIMIT_BYTES // 2
 
 
 def test_a_mesh_declines_both_kernels(monkeypatch):

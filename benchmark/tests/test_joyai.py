@@ -227,11 +227,12 @@ def test_every_joyai_metric_resolves():
     bench = harness.load_benchmark()
     # the cell's metrics are the entries that LIST it, whatever their names:
     # a reading it shares with other cells is one entry over all of them
-    # (29 = the 9 no accepted entry repeats + 20 shared; the length of
-    # `per_layer` is held in one place, test_resolution.py)
+    # (29 = the 7 scope times under `.joyai` + 22 shared, the two latent
+    # rooflines among them since the Ling cell reads them too; the length
+    # of `per_layer` is held in one place, test_resolution.py)
     mine = harness.metrics_of_cell(bench, "per_layer", CELL)
     own = [m for m in mine if m["name"].endswith(".joyai")]
-    assert len(mine) == 29 and len(own) == 9
+    assert len(mine) == 29 and len(own) == 7
     assert all(m["workloads"] == [CELL] for m in own)
     for m in mine:
         spec, reader = harness.load_layer_metric(m["name"])
@@ -263,7 +264,8 @@ def test_every_joyai_metric_resolves():
     everything = {m["name"] for m in bench["per_layer"]}
     for shared in ("engine_step_mean_ms", "stall_share_pct.serve",
                    "idle_stalled_pct.serve", "expert_matmul_up_roofline",
-                   "experts_hit_pct.load", "unscoped_pct.serve"):
+                   "experts_hit_pct.load", "unscoped_pct.serve",
+                   "latent_decode_roofline", "latent_prefill_roofline"):
         assert shared in listed
         assert shared.split(".")[0] + ".joyai" not in everything
     # a scope time of its own reads the scopes the Laguna cell's entry

@@ -350,13 +350,17 @@ ROUTER_KINDS = ("sigmoid", "softmax_topk")   # of a patterned model's 'E'
 #: ('L') keeps pools too, of another row: one latent row a position with no
 #: head axis (ops/latent_attention.py), addressed by the same block table.
 #: Layers of different letters stand in ONE cache tree, a leaf a layer:
-#: per-slot leaves ('M', 'C', 'K') beside pools ('*', 'L') beside rings
+#: per-slot leaves ('M', 'C', 'K', 'G') beside pools ('*', 'L') beside rings
 #: ('W'); the one rule between them is the 'L' assertion's below (a latent
 #: pool and a GQA pool differ in row, so not both in one pattern).
 LAYER_KEEPS = {"M": ("slot_state",), "C": ("slot_state",), "E": (), "F": (),
                "*": ("pools",), "W": ("window",),
                "P": ("pools", "slot_state"), "L": ("pools",),
-               "K": ("slot_state",)}
+               "K": ("slot_state",), "G": ("slot_state",)}
+#: what `LLMConfig.attn_gate` may say: no gate, a gate a query HEAD from a
+#: leaf of its own (`True` reads as 'head'), a gate a CHANNEL that shares
+#: the query's projection
+ATTN_GATE_KINDS = (False, True, "head", "channel")
 POS_EMB_KINDS = ("learn", "sin", "rope", "none")
 # The reference realizes these as five separate trainer scripts
 # (single-gpu/train.py, multi-gpu/ddp/train.py, kaggle-zero1.py,
@@ -480,11 +484,18 @@ class LLMConfig:
     # latent row a position; 'K' delta-rule linear attention with a decay
     # a channel (KDA; models/linear_attention.py, the `kda_*` widths
     # below), whose slot keeps a (heads, d_k, d_v) float32 state and a
-    # convolution tail. Empty = the attention + FFN block above for
-    # every layer. `n_layer` is its length. A patterned model has RMSNorms,
-    # no FFN biases, and its parameters are created in `LLM.param_dtype`.
+    # convolution tail; 'G' the gated delta rule with a decay a HEAD
+    # (Gated DeltaNet; models/linear_attention.py GatedDeltaNet, the
+    # `gdn_*` widths below), fewer key heads than value heads, a slot's
+    # state and tail of the same kind. Empty = the attention + FFN block
+    # above for every layer. `n_layer` is its length. A patterned model has
+    # RMSNorms, no FFN biases, and its parameters are created in
+    # `LLM.param_dtype`.
     layer_pattern: str = ""
     norm_eps: float = 1e-5       # the RMSNorms of a patterned model
+    # `x_hat * (1 + w)` in place of `x_hat * w`: every block's norm, the
+    # final norm and the QK-norms (a mixer's own output norm keeps `w`)
+    norm_zero_centred: bool = False
     tie_head: bool = True        # False: an `lm_head` (V, C) of its own
     head_dim: int = 0            # attention head size; 0 = n_embd // n_head
     attn_bias: bool = True       # biases on the qkv and output projections
@@ -504,15 +515,18 @@ class LLMConfig:
     # the rest pass; a `rope_factor` over 1 is YaRN's: every frequency is
     # blended between itself and itself over the factor by where its
     # wavelength lies against `rope_original_len` (`ops/rope.py`), and
-    # cos and sin are multiplied by `rope_attn_factor`. `attn_gate`: a
-    # gate a query head, sigmoid of a linear map of the block's normed
-    # input (leaf `c_gate`), on the head's output before `c_proj`, in
-    # '*' and 'W' layers alike.
+    # cos and sin are multiplied by `rope_attn_factor`. `attn_gate`
+    # (`ATTN_GATE_KINDS`): True or 'head', a gate a query head, sigmoid of
+    # a linear map of the block's normed input (leaf `c_gate`), on the
+    # head's output before `c_proj`; 'channel', a gate a CHANNEL of every
+    # head, the sigmoid of as many further columns of the query's own
+    # projection (`c_attn` = [q | k | v | gate]); in '*' and 'W' layers
+    # alike.
     rotary_frac: float = 1.0
     rope_factor: float = 1.0
     rope_original_len: int = 0
     rope_attn_factor: float = 1.0
-    attn_gate: bool = False
+    attn_gate: Any = False
     # 'W' layers: a query at position i sees keys j with 0 <= i - j <
     # `window`, its own included; `window_heads` query heads over the
     # same `n_kv_heads`; plain RoPE over all lanes at `window_rope_theta`.
@@ -536,8 +550,11 @@ class LLMConfig:
     # consecutive ids, a group scores the sum of its two largest s + b, the
     # `topk_group` best groups are kept and the top k taken inside them
     # (models/mlp.py route_sigmoid); 1 = no limit, the ops as without it.
+    # `shared_gate`: the shared expert's output times sigmoid(h w_sg), one
+    # scalar a token (leaf `shared_gate`, (C, 1)).
     experts_held: tuple = ()
     shared_up_dim: int = 0
+    shared_gate: bool = False
     routed_scale: float = 1.0
     router: str = "sigmoid"
     n_group: int = 1
@@ -590,6 +607,17 @@ class LLMConfig:
     kda_head_dim: int = 0
     kda_conv: int = 4
     kda_lower_bound: float = -5.0
+    # 'G' layers (Gated DeltaNet): `gdn_heads` VALUE heads of a
+    # (`gdn_head_dim`, `gdn_head_dim`) state over `gdn_key_heads` key heads
+    # (value head j reads key head j // (heads / key heads)); a depthwise
+    # convolution of `gdn_conv` taps over [q' | k' | v']; ONE log decay a
+    # value head, -exp(A_log) softplus(.), with no lower bound: the chunked
+    # form takes exp(c_i - c_j), i >= j, of the cumulative decay and never
+    # inverts one (ops/delta_rule.py gdn_chunk). A silu gate a channel.
+    gdn_heads: int = 0
+    gdn_key_heads: int = 0
+    gdn_head_dim: int = 0
+    gdn_conv: int = 4
 
     def __post_init__(self):
         object.__setattr__(self, "experts_held", tuple(self.experts_held))
@@ -597,6 +625,8 @@ class LLMConfig:
         assert len(self.ssm_mults) in (0, 5), \
             "ssm_mults: one number a segment of [z | x | B | C | dt]"
         assert self.rope_pairing in ("adjacent", "half"), self.rope_pairing
+        assert self.attn_gate in ATTN_GATE_KINDS, \
+            f"attn_gate {self.attn_gate!r} is none of {ATTN_GATE_KINDS}"
         assert self.rope_factor >= 1.0, "rope_factor is at least 1"
         assert self.rope_factor == 1.0 or self.rope_original_len > 0, \
             "yarn (a rope_factor over 1) needs rope_original_len"
@@ -624,13 +654,25 @@ class LLMConfig:
                 assert -88.0 / 16 < self.kda_lower_bound < 0.0, \
                     "the log decay's bound times a sub-chunk of 16 rows " \
                     "has to fit float32's exponent (ops/delta_rule.py)"
+            if "G" in self.layer_pattern:
+                assert self.gdn_heads > 0 and self.gdn_key_heads > 0 \
+                    and self.gdn_head_dim > 0 and self.gdn_conv >= 2, \
+                    "a 'G' layer needs `gdn_heads`, `gdn_key_heads`, " \
+                    "`gdn_head_dim` and a convolution of at least 2 taps"
+                assert self.gdn_heads % self.gdn_key_heads == 0, \
+                    f"gdn_heads {self.gdn_heads} (value heads) is no " \
+                    f"multiple of gdn_key_heads {self.gdn_key_heads}"
+            else:
+                assert not (self.gdn_heads or self.gdn_key_heads
+                            or self.gdn_head_dim), \
+                    "`gdn_*` widths without a 'G' layer"
             if "L" in self.layer_pattern:
                 assert self.attn == "mla" and self.pos_emb == "rope" \
                     and not set("*WP") & set(self.layer_pattern), \
                     "a pattern with latent layers says attn 'mla', " \
                     "pos_emb 'rope', and has no GQA layer beside them " \
                     "('*', 'W', 'P'): any other kind may stand there " \
-                    "(per-slot state 'M', 'C', 'K'; 'F'; 'E')"
+                    "(per-slot state 'M', 'C', 'K', 'G'; 'F'; 'E')"
                 # `q_latent_dim` 0 (or None): no query latent, q = h W_q
                 assert self.kv_latent_dim \
                     and self.rope_head_dim and self.rope_head_dim % 2 == 0
@@ -674,6 +716,13 @@ class LLMConfig:
                 if self.experts_held:
                     lo, n = self.experts_held
                     assert 0 <= lo and n >= 1 and lo + n <= self.n_routed
+                assert not self.shared_gate or self.n_shared, \
+                    "`shared_gate` gates a shared expert: n_shared is 0"
+            else:
+                assert not self.shared_gate, \
+                    "`shared_gate` without an 'E' layer"
+            assert not self.attn_gate or set("*WP") & set(
+                self.layer_pattern), "`attn_gate` without a GQA layer"
         else:
             assert (self.embed_mult, self.resid_mult, self.attn_scale,
                     self.logits_div) == (1.0, 1.0, 0.0, 1.0) \
@@ -683,7 +732,10 @@ class LLMConfig:
                         self.key_mult, self.ssm_in_mult, self.ssm_out_mult,
                         self.mlp_gate_mult, self.mlp_down_mult)), \
                 "the multipliers are a patterned model's"
-            assert not self.qk_norm, "QK-norm is a patterned model's"
+            assert not (self.qk_norm or self.norm_zero_centred
+                        or self.shared_gate), \
+                "QK-norm, the zero-centred norm and the shared expert's " \
+                "gate are a patterned model's"
             assert not (self.qk_nope_head_dim or self.v_head_dim), \
                 "separate nope and value widths are an 'L' layer's"
             assert not (self.window or self.window_heads or self.attn_gate
@@ -757,6 +809,11 @@ class LLMConfig:
     @property
     def head_size(self) -> int:
         return self.head_dim or self.n_embd // self.n_head
+
+    @property
+    def attn_gate_kind(self) -> str:
+        """'' | 'head' | 'channel' (`attn_gate` True reads 'head')."""
+        return "head" if self.attn_gate is True else (self.attn_gate or "")
 
     @property
     def recurrent(self) -> bool:

@@ -72,8 +72,11 @@ class EngineCounts:
         self.n_window = cfg.layers_keeping("window")
         self.n_latent = cfg.layer_pattern.count("L")
         # planned where the layers differ in what they keep (window layers,
-        # or two kinds in one: 'P') or a kernel's roofline reads them ('L')
-        self.plans_kv_rows = bool(self.n_window or self.n_latent) or any(
+        # or two kinds in one: 'P') or a kernel's roofline reads them ('L';
+        # the pools beside 'G' layers, whose chunks reach 32k keys)
+        self.plans_kv_rows = bool(
+            self.n_window or self.n_latent
+            or cfg.layer_pattern.count("G")) or any(
             len(keeps) > 1 for keeps in cfg.layer_keeps)
         self.kv_rows_read_full_by = dict.fromkeys(KINDS, 0)
         self.kv_rows_read_window_by = dict.fromkeys(KINDS, 0)
@@ -89,9 +92,10 @@ class EngineCounts:
             for path, leaf in jax.tree_util.tree_flatten_with_path(
                 caches)[0] if getattr(path[-1], "key", None) == "ssm")
         self.ssm_state_bytes_by = dict.fromkeys(KINDS, 0)
-        # linear-attention layers ('K'): the planned decoding slots x layers
-        # (a state read and written once each) | a chunk's real rows x layers
-        self.n_kda = cfg.layer_pattern.count("K")
+        # linear-attention layers ('K', 'G'): the planned decoding slots x
+        # layers (a state read and written once each) | a chunk's real rows x
+        # layers
+        self.n_kda = sum(cfg.layer_pattern.count(kind) for kind in "KG")
         self.kda_slot_steps_by = dict.fromkeys(KINDS, 0)
         # expert layers ('E'): calls, held experts hit, real rows'
         # assignments to held and to absent experts; of softmax-routed

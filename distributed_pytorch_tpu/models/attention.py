@@ -124,13 +124,20 @@ class _OverlapDense(nn.Module):
         return y if bias is None else y + bias.astype(self.dtype)
 
 
-def _head_rms_norm(x: jnp.ndarray, weight: jnp.ndarray,
-                   eps: float) -> jnp.ndarray:
+#: a zero-centred norm's weights as drawn (`cfg.norm_zero_centred`): a
+#: trained model's are not zero, and zeros would leave `1 + w` and `1` the
+#: same program
+ZERO_CENTRED_INIT = nn.initializers.normal(stddev=0.1)
+
+
+def _head_rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float,
+                   zero_centred: bool = False) -> jnp.ndarray:
     """RMSNorm over the lanes of every head of (B, T, heads, hs), in
-    float32, times one learned (hs,) vector."""
+    float32, times one learned (hs,) vector w (`zero_centred`: 1 + w)."""
     xf = x.astype(jnp.float32)
     xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (xf * weight.astype(jnp.float32)).astype(x.dtype)
+    w = weight.astype(jnp.float32)
+    return (xf * (1.0 + w if zero_centred else w)).astype(x.dtype)
 
 
 def _update_cache(cache_arr: jnp.ndarray, new: jnp.ndarray, pos) -> jnp.ndarray:
@@ -176,10 +183,13 @@ class GQA(nn.Module):
     own positions at `cfg.rope_theta` (ops/rope.py): of the first
     `cfg.rotary_frac` of the lanes, YaRN's where `cfg.rope_factor` is over 1.
     Keys go into the cache normed and rotated, and times `cfg.key_mult`
-    (ops/mup.py) from the projection on. With `cfg.attn_gate` every
-    query head's output is multiplied by a gate of its own, the sigmoid of
-    a linear map (leaf `c_gate`, (C, heads)) of the layer's input, before
-    `c_proj`.
+    (ops/mup.py) from the projection on. With `cfg.attn_gate` True or
+    'head' every query head's output is multiplied by a gate of its own,
+    the sigmoid of a linear map (leaf `c_gate`, (C, heads)) of the layer's
+    input, before `c_proj`; with 'channel' by a gate a CHANNEL, the sigmoid
+    of nh x hs further columns of the query's projection (`c_attn` = [q | k
+    | v | gate], each head-major). `cfg.norm_zero_centred` makes the
+    QK-norms scale by 1 + w.
 
     `kind` 'W' is the pattern's window layer, the same class at
     `cfg.window_heads` query heads, plain RoPE at `cfg.window_rope_theta`
@@ -206,9 +216,16 @@ class GQA(nn.Module):
         nkvh, hs = cfg.n_kv_heads, cfg.head_size
         qw = nh * hs            # = C unless the config sets `head_dim`
         dense = dict(use_bias=cfg.attn_bias, param_dtype=self.param_dtype)
+        gate = None
 
-        qkv = _OverlapDense(qw + 2 * nkvh * hs, x.dtype, name="c_attn",
-                            **dense)(x)
+        if cfg.attn_gate_kind == "channel":
+            # the gate shares the query's projection: [q | k | v | gate]
+            qkv = _OverlapDense(2 * qw + 2 * nkvh * hs, x.dtype,
+                                name="c_attn", **dense)(x)
+            qkv, gate = qkv[..., :-qw], qkv[..., -qw:]
+        else:
+            qkv = _OverlapDense(qw + 2 * nkvh * hs, x.dtype, name="c_attn",
+                                **dense)(x)
         q, k, v = jnp.split(qkv, [qw, qw + nkvh * hs], axis=-1)
         q = q.reshape(B, T, nh, hs)
         k = k.reshape(B, T, nkvh, hs)
@@ -217,11 +234,14 @@ class GQA(nn.Module):
 
         if cfg.qk_norm:
             with jax.named_scope("qk_norm"):
-                ones = nn.initializers.ones
+                zc = cfg.norm_zero_centred
+                init = ZERO_CENTRED_INIT if zc else nn.initializers.ones
                 q = _head_rms_norm(q, self.param(
-                    "q_norm", ones, (hs,), self.param_dtype), cfg.norm_eps)
+                    "q_norm", init, (hs,), self.param_dtype), cfg.norm_eps,
+                    zc)
                 k = _head_rms_norm(k, self.param(
-                    "k_norm", ones, (hs,), self.param_dtype), cfg.norm_eps)
+                    "k_norm", init, (hs,), self.param_dtype), cfg.norm_eps,
+                    zc)
         if cfg.pos_emb == "rope":
             with jax.named_scope("rope"):
                 if windowed:
@@ -242,7 +262,7 @@ class GQA(nn.Module):
                 k = apply_partial_rotary(k, f, half=half)
         if windowed:
             y = self._window(q, k, v, cache, pos, state_ctx or {})
-            return self._project(x, *y, dense, deterministic)
+            return self._project(x, *y, dense, deterministic, gate)
 
         new_cache = None
         q_offset = 0
@@ -291,14 +311,21 @@ class GQA(nn.Module):
                      k_scale=k_scale, v_scale=v_scale,
                      block_tables=block_tables, n_kv_heads=nkvh,
                      scale=cfg.attn_scale or None)
-        return self._project(x, y, new_cache, dense, deterministic)
+        return self._project(x, y, new_cache, dense, deterministic, gate)
 
-    def _project(self, x, y, new_cache, dense: dict, deterministic: bool):
+    def _project(self, x, y, new_cache, dense: dict, deterministic: bool,
+                 gate=None):
         """The heads' outputs (B, T, heads, hs), each times its gate where
-        the configuration has one, through `c_proj`."""
+        the configuration has one (a head's from `c_gate`, or `gate`: a
+        channel's, the query projection's further columns), through
+        `c_proj`."""
         cfg = self.config
         B, T, nh, hs = y.shape
-        if cfg.attn_gate:
+        if gate is not None:
+            with jax.named_scope("attn_gate"):
+                y = y * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
+                    y.dtype).reshape(B, T, nh, hs)
+        elif cfg.attn_gate:
             with jax.named_scope("attn_gate"):
                 gate = _OverlapDense(nh, x.dtype, name="c_gate",
                                      use_bias=False,

@@ -33,13 +33,15 @@ import jax
 import jax.numpy as jnp
 
 from distributed_pytorch_tpu.config import LLMConfig
-from distributed_pytorch_tpu.models.attention import (GQA, Attention,
+from distributed_pytorch_tpu.models.attention import (GQA,
+                                                      ZERO_CENTRED_INIT,
+                                                      Attention,
                                                       LatentAttention,
                                                       init_attn_cache,
                                                       init_latent_cache,
                                                       init_window_cache)
-from distributed_pytorch_tpu.models.linear_attention import (KDA,
-                                                             init_kda_cache)
+from distributed_pytorch_tpu.models.linear_attention import (
+    KDA, GatedDeltaNet, init_gdn_cache, init_kda_cache)
 from distributed_pytorch_tpu.models.mlp import MLP, MoE, RoutedExperts
 from distributed_pytorch_tpu.models.shortconv import (ShortConv,
                                                       init_conv_cache)
@@ -52,19 +54,24 @@ _EMBED_INIT = nn.initializers.normal(stddev=0.02)
 
 
 class RMSNorm(nn.Module):
-    """x / rms(x) * weight, the mean in float32."""
+    """x / rms(x) * weight, the mean in float32; `zero_centred`
+    (`cfg.norm_zero_centred`): times 1 + weight, the sum in float32."""
 
     eps: float = 1e-5
     param_dtype: Any = jnp.float32
+    zero_centred: bool = False
 
     @nn.compact
     def __call__(self, x):
-        w = self.param("scale", nn.initializers.ones, (x.shape[-1],),
-                       self.param_dtype)
+        w = self.param("scale", ZERO_CENTRED_INIT if self.zero_centred
+                       else nn.initializers.ones, (x.shape[-1],),
+                       self.param_dtype).astype(jnp.float32)
+        if self.zero_centred:
+            w = 1.0 + w
         xf = x.astype(jnp.float32)
         xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
                                 + self.eps)
-        return (xf * w.astype(jnp.float32)).astype(x.dtype)
+        return (xf * w).astype(x.dtype)
 
 
 def merge_expert_stats(before: Optional[dict], new: dict) -> dict:
@@ -123,7 +130,9 @@ class MixerBlock(nn.Module):
     (models/mlp.py MLP at `cfg.dense_up_dim`), '*' (GQA), 'W' (GQA over
     a window of the last `cfg.window` positions), 'L' (LatentAttention,
     module `latent_attn`: pools of latent rows), 'K' (KDA, module `kda`,
-    models/linear_attention.py) or 'P': TWO mixers on the one normed
+    models/linear_attention.py), 'G' (GatedDeltaNet, module `gdn`, the
+    same file: a slot's leaves as 'K''s) or 'P': TWO mixers on the one
+    normed
     input h, `mixer_sum(attn(a_in * h), ssm(s_in * h))` (`MixerSum`;
     modules `attn` and `ssm` as in a '*' and an 'M' block). What each
     keeps between calls sits in the layer's cache slot: per-slot state
@@ -137,7 +146,7 @@ class MixerBlock(nn.Module):
     have no null block to land a pad in.
 
     `xs` are the hidden rows of the program's row sets (`rows`, one or
-    several). An 'M', 'C', 'K', 'F', '*', 'W' or 'L' layer takes them in
+    several). An 'M', 'C', 'K', 'G', 'F', '*', 'W' or 'L' layer takes them in
     turn, the cache flowing from one to the next; an 'E' layer is position-wise
     and makes ONE call over all their rows, so its experts' matrices are
     read once."""
@@ -151,7 +160,7 @@ class MixerBlock(nn.Module):
     def __call__(self, xs, rows, freqs, cache=None):
         cfg = self.config
         pd = self.param_dtype
-        norm = RMSNorm(cfg.norm_eps, pd, name="norm")
+        norm = RMSNorm(cfg.norm_eps, pd, cfg.norm_zero_centred, name="norm")
         hs = []
         for x, r in zip(xs, rows):
             with _scope(r.scope):
@@ -174,6 +183,7 @@ class MixerBlock(nn.Module):
                 "M": lambda: Mamba2(cfg, pd, name="ssm"),
                 "C": lambda: ShortConv(cfg, pd, name="conv"),
                 "K": lambda: KDA(cfg, pd, name="kda"),
+                "G": lambda: GatedDeltaNet(cfg, pd, name="gdn"),
                 "F": lambda: MLP(cfg, cfg.dense_up_dim, pd, name="mlp"),
                 "*": lambda: GQA(cfg, self.attn_impl, pd, name="attn"),
                 "W": lambda: GQA(cfg, self.attn_impl, pd, "W", name="attn"),
@@ -182,7 +192,7 @@ class MixerBlock(nn.Module):
             ys, new_cache = [], cache
             for h, r in zip(hs, rows):
                 with _scope(r.scope):
-                    if self.kind in "MCK":
+                    if self.kind in "MCKG":
                         y, new_cache = mixer(h, new_cache, r.pos,
                                              r.state_ctx)
                     elif self.kind == "F":
@@ -411,7 +421,8 @@ class LLM(nn.Module):
                     total_aux = total_aux + aux
                 new_caches.append(new_cache)
 
-        ln_f = RMSNorm(cfg.norm_eps, pd, name="ln_f") if patterned else \
+        ln_f = RMSNorm(cfg.norm_eps, pd, cfg.norm_zero_centred,
+                       name="ln_f") if patterned else \
             nn.LayerNorm(dtype=dt, param_dtype=jnp.float32, name="ln_f")
         if len(rows) == 1:
             x = ln_f(xs[0] if patterned else x)
@@ -511,8 +522,9 @@ def init_paged_cache(config: LLMConfig, n_blocks: int, block_size: int,
     the pools' `n_blocks` and the engine's `max_len` are), nothing for
     'F' and 'E' layers (an 'E' slot carries a program's routing counts
     out, never in), a float32 state (heads, d_k, d_v) and a convolution
-    tail for its 'K' layers (models/linear_attention.py), which may stand
-    beside 'L' layers' pools in this one tree. An 'L' layer's pool is ONE
+    tail for its 'K' and 'G' layers (models/linear_attention.py), which may
+    stand beside 'L' or '*' layers' pools in this one tree. An 'L' layer's
+    pool is ONE
     leaf of latent rows with no head axis (models/attention.py
     `init_latent_cache`), addressed by the same tables. A 'P' layer holds
     BOTH kinds, keyed by what they are
@@ -526,6 +538,7 @@ def init_paged_cache(config: LLMConfig, n_blocks: int, block_size: int,
         make = {"M": lambda: init_ssm_cache(config, n_slots, dtype),
                 "C": lambda: init_conv_cache(config, n_slots, dtype),
                 "K": lambda: init_kda_cache(config, n_slots, dtype),
+                "G": lambda: init_gdn_cache(config, n_slots, dtype),
                 "W": lambda: init_window_cache(config, n_slots, block_size,
                                                dtype),
                 "*": lambda: init_paged_attn_cache(config, n_blocks,

@@ -437,9 +437,10 @@ class RoutedExperts(nn.Module):
     """An 'E' layer of a patterned model: routed experts of which this
     chip holds a share, plus one shared expert of another width that every
     token takes (none, and no leaves or work for one, where `cfg.n_shared`
-    is 0). Router and expert kind are independent choices: `cfg.router`
-    picks the router (`route_sigmoid`, with its
-    `gate_bias` leaf, or `route_softmax_topk`, without); a gated
+    is 0; with `cfg.shared_gate` its output times sigmoid(h w_sg), leaf
+    `shared_gate` (C, 1), inside `moe_shared`). Router and expert kind are
+    independent choices: `cfg.router` picks the router (`route_sigmoid`,
+    with its `gate_bias` leaf, or `route_softmax_topk`, without); a gated
     `cfg.non_linearity` ('swiglu': silu(a) * b) makes both kinds of expert
     gated, their up matrices 2 x the width, [a | b].
 
@@ -511,6 +512,8 @@ class RoutedExperts(nn.Module):
         if cfg.n_shared:
             s_up = self.param("shared_up", _DENSE_INIT, (C, fan * Fs), pd)
             s_down = self.param("shared_down", _DENSE_INIT, (Fs, C), pd)
+            if cfg.shared_gate:
+                s_gate = self.param("shared_gate", _DENSE_INIT, (C, 1), pd)
 
         flats = [x.reshape(-1, C) for x in xs]
         with jax.named_scope("moe_route"):
@@ -557,6 +560,12 @@ class RoutedExperts(nn.Module):
             with jax.named_scope("moe_shared"):
                 shared = [_apply_activation(f @ s_up.astype(dt), nl)
                           @ s_down.astype(dt) for f in flats]
+                if cfg.shared_gate:
+                    # one scalar a token, its sigmoid in float32
+                    shared = [(sh.astype(jnp.float32) * jax.nn.sigmoid(
+                        jnp.dot(f, s_gate.astype(dt),
+                                preferred_element_type=jnp.float32))
+                               ).astype(dt) for sh, f in zip(shared, flats)]
         # under the combine's name: one float32 add a row set after the
         # token-side sum of `held_experts_ffn`, which the compiler may
         # fuse into that sum's last op (none where no expert is shared)

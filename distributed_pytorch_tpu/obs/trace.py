@@ -380,6 +380,8 @@ MIXER_MODULES = {
                    "(models/attention.py LatentAttention)",
     "kda": "a 'K' block's linear attention outside its scopes "
            "(models/linear_attention.py KDA)",
+    "gdn": "a 'G' block's gated delta rule outside its scopes "
+           "(models/linear_attention.py GatedDeltaNet)",
 }
 
 #: The scopes of a patterned model's mixers (`LLMConfig.layer_pattern`),
@@ -421,9 +423,11 @@ MIXER_SCOPES = {
                         "or the last `window` real rows a chunk leaves "
                         "(ops/window_attention.py ring_write_token, "
                         "ring_logical, ring_after)",
-    "attn_gate": "the per-head output gate: its projection, the sigmoid "
-                 "and the product with the heads' outputs "
-                 "(models/attention.py GQA, `cfg.attn_gate`)",
+    "attn_gate": "the output gate: a head's (its projection `c_gate`, "
+                 "the sigmoid, the product with the heads' outputs) or a "
+                 "channel's (the sigmoid of the query projection's further "
+                 "columns and the product; the columns themselves are "
+                 "`c_attn`'s) (models/attention.py GQA, `cfg.attn_gate`)",
     "moe_route": "the router: sigmoid scores, bias-corrected top-k, "
                  "renormalised weights (models/mlp.py route_sigmoid), or "
                  "the top-k logits and their softmax (route_softmax_topk)",
@@ -431,7 +435,9 @@ MIXER_SCOPES = {
                    "(expert_matmul_up or expert_matmul_gated_up, "
                    "expert_matmul_down), the combine "
                    "(ops/grouped_matmul.py held_experts_ffn)",
-    "moe_shared": "the shared expert's two matmuls (models/mlp.py)",
+    "moe_shared": "the shared expert's two matmuls and, with "
+                  "`cfg.shared_gate`, its scalar gate a token: w_sg, the "
+                  "sigmoid, the product (models/mlp.py)",
     # inside `moe_experts`, around what is not a kernel (PR 38)
     "moe_pack": "where each assignment to a held expert goes: counts, "
                 "ranks and slots from the one-hot of the assignments, the "
@@ -478,6 +484,25 @@ MIXER_SCOPES = {
                  "prefill chunk alone, fused XLA with no kernel name of "
                  "its own (ops/delta_rule.py kda_chunk)",
     "kda_out": "the heads' RMSNorm, the head-wise gate and W_o",
+    # a gated-delta-rule layer's ('G', PR 67), module `gdn`
+    "gdn_proj": "the projections of the normed input: W_qkvz (q', k', v' "
+                "and the output gate's z in one matrix) and W_ba (beta and "
+                "the decay's input, a scalar a value head each) "
+                "(models/linear_attention.py GatedDeltaNet)",
+    "gdn_conv": "the depthwise causal convolution over [q' | k' | v'] and "
+                "its silu, chunk and one-token forms, the tail's shift",
+    "gdn_gate": "the L2 norms of q and k, the key heads repeated under "
+                "their value heads, the unbounded log decay a head "
+                "(-exp(A_log) softplus(.)) and beta",
+    "attn_gdn": "the delta rule on the slot's state: kda_state_step with "
+                "the head's decay broadcast over its channels (one token "
+                "of every live slot, the state in place) or the chunked "
+                "form of a prefill chunk, or their XLA twins "
+                "(ops/delta_rule.py)",
+    "gdn_chunk": "inside `attn_gdn`: the chunked WY form of a cached "
+                 "prefill chunk alone, a decay a head, fused XLA with no "
+                 "kernel name of its own (ops/delta_rule.py gdn_chunk)",
+    "gdn_out": "the heads' RMSNorm, the silu gate a channel and W_o",
     # inside `moe_route`, where `cfg.n_group` > 1 alone (PR 62)
     "route_groups": "the group limit of the sigmoid router: a group's "
                     "score (its two largest s + b), the best groups kept, "

@@ -58,6 +58,15 @@ and writes nothing. Whether the chunk form deserves a kernel of its own is
 the next `perf_opt`'s to say from `kda_chunk_roofline.ling`; the choice
 between paths is from shapes and the backend alone, and which way a program
 went is in its `paths` line (obs/paths.py, kinds `kda_step`, `kda_chunk`).
+
+The same rule with ONE log decay a head (Gated DeltaNet, arXiv 2412.06464;
+models/linear_attention.py GatedDeltaNet) is the case g constant over a
+head's channels. One token: `kda_step` as it is, the decay broadcast over
+the channels by the caller (the kernel reads exp(g) a channel either way).
+A chunk: `gdn_chunk`, whose decay is UNBOUNDED below (-exp(A_log)
+softplus(.)): the inversion about a middle row above would overflow, and is
+not needed, because a scalar decay comes out of the sum over the channels
+(`gdn_chunk` has the lines; `paths` kind `gdn_chunk`).
 """
 
 from __future__ import annotations
@@ -333,7 +342,6 @@ def kda_chunk(q, k, v, g, beta, S0=None):
     paths.note("kda_chunk", "xla_wy",
                f"forward substitution in sub-chunks of {SUB_CHUNK} rows")
     T, H, dk = q.shape
-    dv = v.shape[-1]
     C = min(SUB_CHUNK, T)
     f32 = jnp.float32
     q, k, v, g, beta = (t.astype(f32) for t in (q, k, v, g, beta))
@@ -363,7 +371,18 @@ def kda_chunk(q, k, v, g, beta, S0=None):
     k_start, q_start = k * from_start, q * from_start
     total = from_start[:, :, -1]                            # (n, H, dk)
     k_end = k * jnp.exp(gam[:, :, -1:] - gam) * beta[..., None]
-    S0 = jnp.zeros((H, dk, dv), f32) if S0 is None else S0.astype(f32)
+    return _wy_rows(S0, k_start, q_start, v, Tm, P, k_end, total, T)
+
+
+def _wy_rows(S0, k_start, q_start, v, Tm, P, k_end, total, T: int):
+    """The last three lines of the chunked form (the header's), sub-chunk
+    after sub-chunk: operands (n, H, C, .), `Tm` = (I + A)^-1 and `P`
+    (n, H, C, C), `total` (n, H, d_k) what a sub-chunk's decays leave of a
+    state row, S0 (H, d_k, d_v) or None for zeros -> (o (T, H, d_v), S)."""
+    n, H, C, dk = k_start.shape
+    dv = v.shape[-1]
+    S0 = jnp.zeros((H, dk, dv), jnp.float32) if S0 is None \
+        else S0.astype(jnp.float32)
 
     def step(S, x):
         k_s, q_s, v_n, T_n, P_n, k_e, tot = x
@@ -377,3 +396,62 @@ def kda_chunk(q, k, v, g, beta, S0=None):
 
     S, o = jax.lax.scan(step, S0, (k_start, q_start, v, Tm, P, k_end, total))
     return o.swapaxes(1, 2).reshape(n * C, H, dv)[:T], S
+
+
+def gdn_scan(q, k, v, g, beta, S0=None):
+    """`kda_scan` for a decay a HEAD, g (T, H): the literal recurrence the
+    tests hold `gdn_chunk` and the step to; no program runs it."""
+    return kda_scan(q, k, v, jnp.broadcast_to(
+        g[..., None], (*g.shape, q.shape[-1])), beta, S0)
+
+
+def gdn_chunk(q, k, v, g, beta, S0=None):
+    """`kda_chunk`'s chunk of ONE sequence for the gated delta rule with ONE
+    log decay a head (Gated DeltaNet): g (T, H), ANY g <= 0; the rest as
+    there (q, k (T, H, d_k) a row a VALUE head, key heads repeated
+    already). With c the running sum of g inside a sub-chunk the header's
+    A, P and the state's update need only
+
+        D_ij = exp(c_i - c_j), i >= j        (0 above the diagonal)
+        exp(c_i),  exp(c_C - c_i)
+
+    every exponent <= 0: a scalar a head and row comes out of the sum over
+    the key channels, so nothing is factored about a middle row and
+    nothing is inverted. A decay of -80 a token underflows to an exact 0
+    where the literal recurrence's does. Fused XLA, float32, HIGHEST, in
+    sub-chunks of `SUB_CHUNK` rows as `kda_chunk`'s: a form that exploits
+    the scalar decay for SPEED (whole (C, C) products on the MXU at a wider
+    sub-chunk) is a later `perf_opt`'s to bring, from `gdn_chunk_roofline`."""
+    paths.note("gdn_chunk", "xla_wy",
+               f"a decay a head, exp(c_i - c_j) for i >= j alone, "
+               f"sub-chunks of {SUB_CHUNK} rows")
+    T, H, dk = q.shape
+    C = min(SUB_CHUNK, T)
+    f32 = jnp.float32
+    q, k, v, g, beta = (t.astype(f32) for t in (q, k, v, g, beta))
+    pad = (-T) % C
+    if pad:
+        q, k, v, g, beta = (jnp.pad(t, [(0, pad)] + [(0, 0)] * (t.ndim - 1))
+                            for t in (q, k, v, g, beta))
+    n = (T + pad) // C
+
+    def sub(t):                          # (T, H, .) -> (n, H, C, .)
+        return t.reshape(n, C, H, -1).swapaxes(1, 2)
+
+    q, k, v = sub(q), sub(k), sub(v)
+    g, beta = (t.reshape(n, C, H).swapaxes(1, 2) for t in (g, beta))
+    c = jnp.cumsum(g, axis=2)                               # (n, H, C)
+    lower = jnp.tril(jnp.ones((C, C), bool), -1)
+    upto = lower | jnp.eye(C, dtype=bool)
+    # masked BEFORE the exponential: above the diagonal c_i - c_j > 0
+    D = jnp.exp(jnp.where(upto, c[..., :, None] - c[..., None, :], -jnp.inf))
+    Db = D * beta[:, :, None]
+    A = jnp.where(lower, jnp.einsum("nhic,nhjc->nhij", k, k,
+                                    precision=_HI), 0.0) * Db
+    P = jnp.einsum("nhic,nhjc->nhij", q, k, precision=_HI) * Db
+    Tm = _unit_lower_inverse(A)
+    from_start = jnp.exp(c)[..., None]                      # <= 1
+    total = jnp.broadcast_to(from_start[:, :, -1], (n, H, dk))
+    k_end = k * (jnp.exp(c[:, :, -1:] - c) * beta)[..., None]
+    return _wy_rows(S0, k * from_start, q * from_start, v, Tm, P, k_end,
+                    total, T)

@@ -632,7 +632,8 @@ _CHUNK_BLOCKS = 8
 _CHUNK_SCORE_BYTES = VMEM_LIMIT_BYTES // 4
 
 
-def _chunk_shape(T: int, rep: int, n_max: int, bs: int) -> tuple[int, int]:
+def _chunk_shape(T: int, rep: int, n_max: int, bs: int, hs: int = 128,
+                 q_item: int = 2, kv_item: int = 2) -> tuple[int, int]:
     """(tq, group) of `_prefill_kernel`'s grid step, from the shapes of the
     call alone: a key tile is `group` pool blocks under ONE softmax update,
     `_CHUNK_BLOCKS` where the table is that wide and the whole table where
@@ -642,10 +643,21 @@ def _chunk_shape(T: int, rep: int, n_max: int, bs: int) -> tuple[int, int]:
     256-row chunk over a table of 6-10 blocks is one query tile against
     one key tile; 1,024 x 6 rows over 136 blocks are two query tiles of
     3,072 rows against 1,024 keys a step; a table of one block gives
-    (T, 1)."""
+    (T, 1). Where the step so chosen passes the scoped VMEM as a whole
+    (`_chunk_vmem_bytes`: 8 query heads a KV head of `hs` 256 lanes fill the
+    score tile to its last byte, and three of them stand beside wider
+    query, output and accumulator tiles) the query tile is halved until it
+    fits: 1,024 x 8 rows at 256 lanes are four tiles of 2,048 rows. Every
+    call that fitted before keeps its step."""
     group = max(1, min(n_max, _CHUNK_BLOCKS))
     rows = _CHUNK_SCORE_BYTES // (group * bs * 4)
-    return _pick_block(T, max(rows // rep, 8), 8) or T, group
+    tq = _pick_block(T, max(rows // rep, 8), 8) or T
+    lanes = max(hs, 128)
+    while tq % 16 == 0 and _chunk_vmem_bytes(
+            tq * rep, group * bs, lanes // hs, lanes, q_item,
+            kv_item) > VMEM_LIMIT_BYTES:
+        tq //= 2
+    return tq, group
 
 
 def _stack_tiles(tiles) -> jnp.ndarray:
@@ -882,7 +894,9 @@ def paged_flash_prefill(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     L = k.shape[2]
     qz, gl = _lane_group_q(q3, L)
     n_groups, hpg = qz.shape[:2]
-    tq, group = _chunk_shape(T, rep, n_max, bs)
+    tq, group = _chunk_shape(T, rep, n_max, bs, hs,
+                             jnp.dtype(q.dtype).itemsize,
+                             jnp.dtype(k.dtype).itemsize)
     rows_q = tq * rep
 
     def q_idx(g, i, j, meta_ref, bt_ref):
@@ -1022,10 +1036,10 @@ def paged_flash_prefill_decline(q, k, v, block_tables, n_kv_heads: int = 0):
         return (f"head size {hs} neither divides nor is a multiple of the "
                 "128 lanes a head group is cut by")
     lanes = max(hs, 128)            # one lane group: its heads, one at a time
-    tq, group = _chunk_shape(T, rep, block_tables.shape[1], bs)
+    items = (jnp.dtype(q.dtype).itemsize, jnp.dtype(k.dtype).itemsize)
+    tq, group = _chunk_shape(T, rep, block_tables.shape[1], bs, hs, *items)
     return _budget_decline(_chunk_vmem_bytes(
-        tq * rep, group * bs, lanes // hs, lanes,
-        jnp.dtype(q.dtype).itemsize, jnp.dtype(k.dtype).itemsize))
+        tq * rep, group * bs, lanes // hs, lanes, *items))
 
 
 def paged_flash_decode_decline(q, k, v, block_tables, n_kv_heads: int = 0):

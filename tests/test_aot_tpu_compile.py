@@ -902,6 +902,82 @@ def test_the_engines_step_programs_hold_the_state_kernel(block, program, v5e,
     assert not _pool_copies(text, state.shape, "f32")
 
 
+@pytest.mark.parametrize("program", ["step", "fused_step"])
+def test_the_gated_delta_trees_step_programs_compile(program, v5e,
+                                                     monkeypatch):
+    """One period G E * E of the Qwen3-Next tree at its published mixer
+    widths (32 value heads over 16 key heads of 128; 16 query heads over 2
+    KV heads of 256 lanes, a gate a channel; 8 experts of 512 held of 64)
+    through the engine's own step functions, 8 slots under a block table of
+    264 blocks (32,768 + a chunk of 1,024), the cache tree donated: both
+    programs compile for the described chip, the 'G' block steps its state
+    with `kda_state_step` in place, the '*' block reads its 512-lane pool
+    with `paged_flash_decode` and, in a chunk-carrying program,
+    `paged_flash_prefill` (whose step the gate has to shrink at 8 x 256
+    lanes: `_chunk_shape`), and nothing falls back to a gather."""
+    from distributed_pytorch_tpu.config import LLMConfig
+    from distributed_pytorch_tpu.engine import decode as dec
+    from distributed_pytorch_tpu.models.gpt import LLM, init_paged_cache
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = LLMConfig(
+        vocab_size=1024, block_size=32768, n_embd=2048, n_layer=4,
+        layer_pattern="GE*E", pos_emb="rope", rope_theta=1e7,
+        rope_pairing="half", rotary_frac=0.25, norm_eps=1e-6,
+        norm_zero_centred=True, tie_head=False, attn="gqa", n_head=16,
+        n_kv_heads=2, head_dim=256, qk_norm=True, attn_gate="channel",
+        attn_bias=False, non_linearity="swiglu", up_dim=512,
+        shared_up_dim=512, n_exp=65, n_shared=1, n_act=11,
+        router="softmax_topk", shared_gate=True, experts_held=(0, 8),
+        gdn_heads=32, gdn_key_heads=16, gdn_head_dim=128, gdn_conv=4)
+    model = LLM(cfg, compute_dtype=BF16, attn_impl="auto", param_dtype=BF16)
+    n_slots, chunk, width = 8, 1024, 264
+
+    def sds(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=v5e)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, I32, sharding=v5e)
+
+    key = jax.random.PRNGKey(0)
+    variables = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda k: model.init({"params": k}, jnp.zeros((1, 8), I32)), key))
+    caches = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda: init_paged_cache(cfg, 1 + n_slots * 256, BS, dtype=BF16,
+                                 n_slots=n_slots)))
+    state, pool = caches[0]["state"], caches[2]["k"]
+    assert state.dtype == F32 and state.shape == (n_slots, 32, 128, 128) \
+        and pool.shape == (1 + n_slots * 256, BS, 512)
+
+    def sample(logits, rng):
+        return jnp.argmax(logits, axis=-1).astype(I32)
+
+    args = [variables, caches, i32(n_slots), i32(n_slots),
+            jax.ShapeDtypeStruct((n_slots,), jnp.bool_, sharding=v5e),
+            i32(n_slots, width), sds(key), i32(), None]
+    kernels = {"kda_state_step": 1, "paged_flash_decode": 1}
+    if program == "step":
+        fn = dec.make_step_fn(model, sample)
+    else:
+        fn = dec.make_fused_step_fn(model, sample, n_slots, width)
+        args += [i32(1, chunk), i32(), i32(), i32(1),
+                 jax.ShapeDtypeStruct((), jnp.bool_, sharding=v5e)]
+        kernels["paged_flash_prefill"] = 1
+    paths.reset()
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    chosen = paths.choices()
+    assert chosen["kda_step"].startswith("kda_state_step (state in place")
+    assert not any("gather" in v for v in chosen.values()), chosen
+    if program == "fused_step":
+        assert chosen["gdn_chunk"].startswith("xla_wy (a decay a head")
+    text = compiled.as_text()
+    census = paths.kernel_census(text)
+    assert {k: census.get(k) for k in kernels} == kernels, census
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        4 * state.size + 2 * 2 * pool.size
+    assert not _pool_copies(text, state.shape, "f32") \
+        and not _pool_copies(text, pool.shape)
+
+
 def test_gates_decline_what_the_compiler_refuses(v5e):
     """The other direction: where Mosaic refuses a kernel, its usable gate
     must already say no — so the dispatcher never sends that shape, and

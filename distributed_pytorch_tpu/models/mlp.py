@@ -384,15 +384,28 @@ def limit_to_groups(biased: jnp.ndarray, n_group: int,
                     topk_group: int) -> jnp.ndarray:
     """The group limit of the `deepseek_v3` router on s + b (N, E): the
     experts are `n_group` groups of E / n_group consecutive ids, a group
-    scores the sum of its two largest s + b, the `topk_group` best groups
-    are kept (ties to the lower group, as `top_k` breaks them) and every
+    scores the sum of its two largest s + b (a value that stands twice
+    counts twice), the `topk_group` best groups are kept (of two groups
+    with equal scores the one with the lower id ranks first) and every
     s + b outside them becomes -inf, so that the top k that follows lies
-    inside the kept groups."""
+    inside the kept groups.
+
+    By maxima and comparisons alone, a few fused reductions: a `top_k`
+    here is a whole sort of each group's lanes on the chip, ten times the
+    time (5.3% of the Ling cell's device time: ledger, PR 67)."""
     N, E = biased.shape
     by_group = biased.reshape(N, n_group, E // n_group)
-    score = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)
-    _, kept = jax.lax.top_k(score, topk_group)               # (N, topk_group)
-    keep = jnp.any(kept[:, :, None] == jnp.arange(n_group), axis=1)
+    largest = jnp.max(by_group, axis=-1)
+    # the second largest: the maximum with ONE occurrence of the largest out
+    first = jnp.argmax(by_group, axis=-1)[..., None]
+    second = jnp.max(jnp.where(jnp.arange(E // n_group) == first, -jnp.inf,
+                               by_group), axis=-1)
+    score = largest + second                                 # (N, n_group)
+    # a group's rank is the number of groups ahead of it
+    g = jnp.arange(n_group)
+    mine, other = score[:, :, None], score[:, None, :]
+    ahead = (other > mine) | ((other == mine) & (g[None, :] < g[:, None]))
+    keep = jnp.sum(ahead, axis=-1) < topk_group
     return jnp.where(keep[:, :, None], by_group, -jnp.inf).reshape(N, E)
 
 
@@ -405,7 +418,8 @@ def route_sigmoid(scores_in: jnp.ndarray, gate: jnp.ndarray,
     the chosen, divided by their sum, times `scale`. With `n_group` > 1
     the choice is group-limited first (`limit_to_groups`: groups of
     consecutive ids, a group's score the sum of its two largest s + b,
-    the `topk_group` best groups kept, the rest masked before the top k);
+    the `topk_group` best groups kept, of equal scores the lower group
+    first, the rest masked before the top k, selected without a sort);
     `n_group` 1 is the path as it was, not an op more. Returns (ids
     (N, k), weights (N, k) float32)."""
     # float32 in earnest: a TPU rounds a float32 product's operands to

@@ -791,6 +791,26 @@ def test_the_packing_walks_no_index_but_one_scatters(n_tokens, widths, v5e):
     assert not loose, loose
 
 
+@pytest.mark.parametrize("rows", [192, 256])
+def test_the_group_limit_sorts_nothing(rows, v5e):
+    """The group-limited sigmoid router at the Ling cell's widths (2,560 ->
+    512 experts, 8 groups of which 4 are kept, top 8) and its two row
+    counts: ONE sort in the program, the final top 8 over the 512 lanes,
+    and no loop. Until PR 69 it held three: `f32[rows,8,64]`, a whole sort
+    of each group's 64 lanes for its two largest (0.13 to 0.18 ms a call,
+    5.3% of the cell's device time: ledger, PR 67), and `f32[rows,8]` for
+    the best groups."""
+    from distributed_pytorch_tpu.models.mlp import route_sigmoid
+    text = _compile(
+        lambda x, gate, bias: route_sigmoid(x, gate, bias, 8, 2.5, 8, 4),
+        [((rows, 2560), BF16), ((2560, 512), F32), ((512,), F32)],
+        v5e).as_text()
+    sorted_ = re.findall(r" = \((\w+\[[\d,]*\])\S*, .*?\) sort\(", text)
+    assert sorted_ == [f"f32[{rows},512]"], sorted_
+    assert not re.search(r" while\(", text)
+    assert "/route_groups/" in text
+
+
 @pytest.mark.parametrize("line, H, P, G, N", [
     ("xla", 64, 64, 8, 128), ("xla", 128, 64, 1, 128),
     ("kernel", 64, 64, 8, 128), ("kernel", 128, 64, 1, 128),

@@ -465,8 +465,8 @@ def test_group_limit_against_a_literal_loop(n_group, topk_group, k):
 
 
 def test_a_tie_between_groups_goes_to_the_lower_group():
-    """Two groups with the same two best scores: `top_k` keeps the lower
-    id, in the program and in the reference alike."""
+    """Two groups with the same two best scores: the lower id is kept, in
+    the program and in the reference alike."""
     biased = jnp.asarray([[.9, .8, .1, .1, .9, .8, .2, .2, .5, .4, .0, .0,
                            .3, .3, .3, .3]])          # 4 groups of 4
     kept = mlp_mod.limit_to_groups(biased, 4, 1)
@@ -475,6 +475,43 @@ def test_a_tie_between_groups_goes_to_the_lower_group():
     kept = mlp_mod.limit_to_groups(biased, 4, 2)
     assert np.isfinite(np.asarray(kept[0, :8])).all() \
         and np.isneginf(np.asarray(kept[0, 8:])).all()
+
+
+def _limit_by_top_k(biased, n_group, topk_group):
+    """`limit_to_groups` as it was until PR 69, line for line: the two
+    largest of a group and the best groups by `top_k`, which sorts."""
+    N, E = biased.shape
+    by_group = biased.reshape(N, n_group, E // n_group)
+    score = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)
+    _, kept = jax.lax.top_k(score, topk_group)               # (N, topk_group)
+    keep = jnp.any(kept[:, :, None] == jnp.arange(n_group), axis=1)
+    return jnp.where(keep[:, :, None], by_group, -jnp.inf).reshape(N, E)
+
+
+@pytest.mark.parametrize("levels", [(None, None, None), (1, 2, 4)],
+                         ids=["random", "few_levels"])
+@pytest.mark.parametrize("rows", [192, 256])
+@pytest.mark.parametrize("n_group,topk_group,E", [
+    (8, 4, 512), (4, 1, 64), (2, 2, 64), (8, 8, 512), (8, 1, 512)])
+def test_the_group_limit_is_the_top_k_forms_element_for_element(
+        n_group, topk_group, E, rows, levels):
+    """Maxima and a rank count against the sorts they replaced, on the
+    whole masked array. `few_levels` quantises s + b to 2, 3 and 5 values,
+    so a group's maximum stands twice or more, whole groups are equal
+    lanes and groups' scores tie: every tie rule is on the path."""
+    rng = np.random.default_rng(n_group * 1000 + topk_group * 10 + rows)
+    new = jax.jit(mlp_mod.limit_to_groups, static_argnums=(1, 2))
+    old = jax.jit(_limit_by_top_k, static_argnums=(1, 2))
+    for top in levels:
+        biased = rng.random((rows, E), dtype=np.float32)
+        if top:
+            biased = np.round(biased * top) / np.float32(8)
+        want = np.asarray(old(biased, n_group, topk_group))
+        got = np.asarray(new(biased, n_group, topk_group))
+        assert np.array_equal(got, want)
+        kept = np.isfinite(got).reshape(rows, n_group, -1)
+        assert (kept.all(-1) | ~kept.any(-1)).all()
+        assert (kept.all(-1).sum(-1) == topk_group).all()
 
 
 @pytest.mark.parametrize("chips", [8, 4])
